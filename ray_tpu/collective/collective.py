@@ -188,9 +188,7 @@ class XlaGroup:
 # driver code over materialized tensors. These helpers are the *traced*
 # counterpart — called INSIDE a shard_map/pmap program (e.g. the SPMD
 # train step's gradient reduction, train/spmd.py), where the axis names
-# of the enclosing mesh are in scope. They ride the same jax_compat
-# shims the XlaGroup programs compile through, so one spelling works on
-# every supported jax build.
+# of the enclosing mesh are in scope.
 
 
 def psum_tree(tree, axis_names):
@@ -216,12 +214,9 @@ def psum_tree(tree, axis_names):
 
 def pmean_tree(tree, axis_names):
     """Mean of every leaf over ``axis_names`` — the gradient reduction
-    of a data-parallel shard_map train step. The divisor comes from
-    :func:`ray_tpu.util.jax_compat.axis_size`, which folds to a
-    trace-time constant on every supported build."""
+    of a data-parallel shard_map train step. The divisor
+    (``jax.lax.axis_size``) is a trace-time constant."""
     import jax
-
-    from ray_tpu.util.jax_compat import axis_size
 
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
@@ -230,7 +225,7 @@ def pmean_tree(tree, axis_names):
         return tree
     denom = 1
     for ax in axis_names:
-        denom = denom * axis_size(ax)
+        denom = denom * jax.lax.axis_size(ax)
     return jax.tree.map(lambda x: x / denom, psum_tree(tree, axis_names))
 
 

@@ -83,7 +83,11 @@ def init(
         return rt
     from .config import global_config
     from .accelerators import detect_resources
+    from ray_tpu.util.compile_cache import configure as configure_cache
 
+    # one compile-cache directory for this driver (should it import jax)
+    # and for every process it spawns
+    configure_cache()
     if object_store_memory:
         global_config().object_store_memory = int(object_store_memory)
     total = detect_resources(num_cpus=num_cpus, num_tpus=num_tpus,

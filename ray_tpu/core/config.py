@@ -189,8 +189,8 @@ class Config:
     xla_storm_trigger_recompiles: int = 3
     xla_storm_clear_ticks: int = 2
     # roofline ceiling overrides for the xla report, in FLOP/s and
-    # bytes/s per chip; 0 = auto-detect from the device kind (TPU
-    # table) or fall back to nominal trend-only CPU values
+    # bytes/s per chip; 0 = look the TPU device kind up in the peak
+    # table (a device that is not in it has no peak: no MFU, no verdict)
     xla_peak_flops: float = 0.0
     xla_peak_hbm_bytes: float = 0.0
     # object/memory observability (core/ref_tracker.py): per-process

@@ -18,6 +18,7 @@ the CoreWorker in-process memory store (``store_provider/memory_store/``):
 from __future__ import annotations
 
 import contextlib
+import logging
 import mmap
 import os
 import threading
@@ -28,6 +29,9 @@ from typing import Dict, List, Optional, Tuple
 from .config import global_config
 from .exceptions import ObjectStoreFullError, ObjectLostError
 from .ids import ObjectID
+
+logger = logging.getLogger(__name__)
+
 # Store write traffic. The data-pipeline benches assert operator fusion
 # reduces per-stage materialization through these (puts = inline + arena
 # creations, bytes = payload bytes written). Imported lazily: this module
@@ -151,13 +155,36 @@ class FreeListAllocator:
             return (sum(sizes), len(sizes), max(sizes))
 
 
+_allocator_kind: Optional[str] = None  # what _make_allocator last chose
+
+
 def _make_allocator(capacity: int):
+    """The native arena allocator (built from ``_native/plasma_alloc.cpp``
+    on first use), else the Python free list. Why not the native one is
+    said once; :func:`allocator_kind` reports the choice afterwards."""
+    global _allocator_kind
     try:
         from ray_tpu._native.plasma import NativeAllocator
 
-        return NativeAllocator(capacity)
-    except Exception:
+        alloc = NativeAllocator(capacity)
+        _allocator_kind = "native"
+        return alloc
+    except Exception as e:  # noqa: BLE001 - no toolchain / build failure
+        if _allocator_kind != "python":
+            detail = getattr(e, "stderr", b"") or b""
+            logger.warning(
+                "native plasma allocator unavailable (%s: %s%s); using the "
+                "Python free-list allocator", type(e).__name__, e,
+                (": " + detail.decode(errors="replace")[-500:])
+                if detail else "")
+        _allocator_kind = "python"
         return FreeListAllocator(capacity)
+
+
+def allocator_kind() -> Optional[str]:
+    """"native" or "python": the arena allocator this process's stores
+    got (None before any store was created here)."""
+    return _allocator_kind
 
 
 # --------------------------------------------------------------------------- #

@@ -134,6 +134,10 @@ class WorkerRuntime:
         self._cancelled: set = set()
         self._shutdown = threading.Event()
         self.accelerator_binding: Dict[str, List[int]] = {}
+        # TPU chips the node spawned this process for (its environment
+        # already names them); None = a worker of the CPU pool
+        self.bound_chips: Optional[List[int]] = init_info.get("tpu_chips")
+        self._binding_verified = False
         # direct (head-bypass) path: this worker OWNS its eligible nested
         # submissions (reference: submitter-side TaskManager + memory
         # store). Arg pins are owner-side (the manager's pin table) plus
@@ -1131,21 +1135,44 @@ class WorkerRuntime:
             self._current_task.name = None
 
     def _apply_accelerator_binding(self, binding: Dict[str, List[int]]) -> None:
-        """Set accelerator visibility env vars before user code imports jax.
+        """Record the task's accelerator binding. TPU chips were fixed by
+        the environment this process was spawned with (the node starts one
+        process per chip-bound spec, accelerators.worker_env), so a TPU
+        binding is only checked here; GPU visibility is applied at exec
+        time (reference: nvidia_gpu.py sets CUDA_VISIBLE_DEVICES)."""
+        from .accelerators import AcceleratorBindingError
 
-        Reference: accelerators/tpu.py:155-195 sets TPU_VISIBLE_CHIPS etc;
-        nvidia_gpu.py sets CUDA_VISIBLE_DEVICES.
-        """
         self.accelerator_binding = binding
-        if "TPU" in binding and "jax" not in sys.modules:
-            chips = ",".join(str(i) for i in binding["TPU"])
-            os.environ.setdefault("TPU_VISIBLE_CHIPS", chips)
+        chips = list(binding.get("TPU") or ())
+        if chips and chips != list(self.bound_chips or ()):
+            raise AcceleratorBindingError(
+                f"task bound to TPU chips {chips} was handed to worker "
+                f"pid={os.getpid()}, which was spawned for chips "
+                f"{self.bound_chips}")
         if "GPU" in binding:
             os.environ.setdefault(
                 "CUDA_VISIBLE_DEVICES", ",".join(str(i) for i in binding["GPU"])
             )
 
+    def _verify_accelerator_binding(self) -> None:
+        """Once user code has initialised a JAX backend here, the devices
+        it sees must be exactly the chips this process was bound to: a
+        mismatch fails the task that is finishing. The runtime never
+        initialises the backend itself to find out."""
+        if self._binding_verified or "jax" not in sys.modules:
+            return
+        from ray_tpu.util.device_telemetry import jax_with_backend
+
+        jax = jax_with_backend()
+        if jax is None:
+            return
+        from .accelerators import check_devices_match_binding
+
+        check_devices_match_binding(self.bound_chips, jax.local_devices())
+        self._binding_verified = True
+
     def _finish(self, spec: TaskSpec, result: Any) -> None:
+        self._verify_accelerator_binding()
         if spec.streaming:
             self._finish_streaming(spec, result)
             return

@@ -508,7 +508,11 @@ class LlamaDecodeEngine:
 
         self.cfg = cfg or LlamaConfig.debug()
         if params is None:
-            params = init_params(self.cfg, jax.random.PRNGKey(seed))
+            # one jitted program, not a dozen eager ones: at 664.6M
+            # parameters the eager form spends 67 s on a v5e, nearly all of
+            # it compiling per-leaf RNG programs (measured, PR 21)
+            params = jax.jit(partial(init_params, self.cfg))(
+                jax.random.PRNGKey(seed))
         self.params = params
         self.page_size = int(page_size)
         self.pool = PagePool(n_pages, page_size)
@@ -600,12 +604,9 @@ def make_train_step(cfg: LlamaConfig, mesh, optimizer=None, rules=None):
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ray_tpu.util.jax_compat import ensure_sharding_invariant_rng
-
-    # init draws params THROUGH the shardings: the same seed must yield
-    # the same params on every mesh layout (test_parallelism_consistency)
-    ensure_sharding_invariant_rng()
-
+    # init draws params THROUGH the shardings: the same seed yields the
+    # same params on every mesh layout because jax.random is
+    # sharding-invariant (test_parallelism_consistency)
     rules = rules or DEFAULT_RULES
     optimizer = optimizer or optax.adamw(3e-4, b1=0.9, b2=0.95,
                                          weight_decay=0.1)

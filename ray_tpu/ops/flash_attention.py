@@ -26,15 +26,25 @@ long-context row names Pallas flash/splash attention as the TPU design).
 from __future__ import annotations
 
 import functools
+import logging
 import math
-from typing import Optional
+import threading
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+logger = logging.getLogger(__name__)
+
 NEG_INF = -1e30
 _LANE = 128
+
+# the three things flash_attention can run; which one a call got is
+# recorded (paths_taken) and logged, never left to be guessed
+PATH_PALLAS = "pallas"                      # the Mosaic-compiled kernel
+PATH_PALLAS_INTERPRET = "pallas_interpret"  # the kernel, interpreted
+PATH_REFERENCE = "reference"                # plain_attention, [B,H,T,T] f32
 
 
 def _pick_block(t: int) -> Optional[int]:
@@ -44,14 +54,63 @@ def _pick_block(t: int) -> Optional[int]:
     return None
 
 
-def _supported(q, k, block: Optional[int]) -> bool:
-    B, T, Hq, D = q.shape
-    Tk, Hkv = k.shape[1], k.shape[2]
-    if T != Tk or block is None or T % block != 0:
-        return False
+def _unsupported_reason(q_shape, k_shape, block: Optional[int]
+                        ) -> Optional[str]:
+    """Why the kernel cannot take these shapes, or None if it can."""
+    _, T, Hq, _ = q_shape
+    Tk, Hkv = k_shape[1], k_shape[2]
+    if T != Tk:
+        return f"q length {T} != k length {Tk} (self-attention only)"
+    if block is None or T % block != 0:
+        return (f"sequence length {T} is not a multiple of "
+                f"{block or 'any block in (512, 256, 128, 64)'}")
     if Hq % Hkv != 0:
-        return False
-    return True
+        return f"{Hq} q heads not a multiple of {Hkv} kv heads"
+    return None
+
+
+def attention_path(q_shape, k_shape, block: Optional[int] = None,
+                   interpret: bool = False) -> Tuple[str, str]:
+    """(path, reason) :func:`flash_attention` takes for these shapes in
+    this process: the kernel wherever it can run (compiled on a TPU
+    backend, interpreted on request), the reference otherwise. A backend
+    that cannot be initialised is an error here, not a reason."""
+    why_not = _unsupported_reason(q_shape, k_shape,
+                                  block or _pick_block(q_shape[1]))
+    if why_not is not None:
+        return PATH_REFERENCE, why_not
+    if interpret:
+        return PATH_PALLAS_INTERPRET, "interpret=True"
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return PATH_PALLAS, "tpu backend"
+    return PATH_REFERENCE, f"backend is {platform!r}, not tpu"
+
+
+_taken_lock = threading.Lock()
+_taken: Dict[tuple, dict] = {}
+
+
+def _record_path(q_shape, k_shape, dtype, path: str, reason: str) -> None:
+    key = (tuple(q_shape), tuple(k_shape), str(dtype), path)
+    with _taken_lock:
+        rec = _taken.get(key)
+        if rec is not None:
+            rec["calls"] += 1
+            return
+        _taken[key] = {"q_shape": list(q_shape), "k_shape": list(k_shape),
+                       "dtype": str(dtype), "path": path, "reason": reason,
+                       "calls": 1}
+    logger.info("flash_attention q%s k%s %s -> %s (%s)",
+                list(q_shape), list(k_shape), dtype, path, reason)
+
+
+def paths_taken() -> List[dict]:
+    """Every distinct (shapes, dtype, path) :func:`flash_attention` has
+    been traced with in this process, with its reason and call count: how
+    a run proves which attention it used."""
+    with _taken_lock:
+        return [dict(rec) for rec in _taken.values()]
 
 
 # --------------------------------------------------------------------------- #
@@ -299,14 +358,17 @@ def flash_attention(q, k, v, causal: bool = True,
 
     q: [B, T, Hq, D]; k, v: [B, T, Hkv, D] with Hq % Hkv == 0.
     Returns [B, T, Hq, D] in q.dtype. Differentiable (custom VJP with
-    Pallas backward kernels). Falls back to the exact jnp implementation
-    when shapes don't block cleanly or no TPU backend is present.
+    Pallas backward kernels). Shapes that do not block cleanly, and
+    backends other than TPU unless ``interpret`` is set, get the exact jnp
+    reference; :func:`attention_path` says which and why beforehand, and
+    :func:`paths_taken` afterwards.
     """
     B, T, Hq, D = q.shape
     blk = block or _pick_block(T)
-    use_pallas = interpret or _on_tpu()
-    if not use_pallas or not _supported(q, k, blk):
-        return _fallback(q, k, v, causal)
+    path, reason = attention_path(q.shape, k.shape, blk, interpret)
+    _record_path(q.shape, k.shape, q.dtype, path, reason)
+    if path == PATH_REFERENCE:
+        return _reference(q, k, v, causal)
     # pad head_dim to the 128-lane boundary (zeros don't affect scores)
     Dp = ((D + _LANE - 1) // _LANE) * _LANE
     qt = jnp.swapaxes(q, 1, 2)  # [B,Hq,T,D]
@@ -323,14 +385,7 @@ def flash_attention(q, k, v, causal: bool = True,
     return jnp.swapaxes(o, 1, 2)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
-def _fallback(q, k, v, causal):
+def _reference(q, k, v, causal):
     """Exact reference path (materializes scores) for small/odd shapes."""
     Hq, Hkv = q.shape[2], k.shape[2]
     if Hq != Hkv:

@@ -71,9 +71,7 @@ def pipelined_apply(stage_fn: Callable[[Any, Any], Any], stage_params,
     pipe axis must not shard the batch).
     Returns [M, mb, ...] outputs, valid on every stage.
     """
-    from ray_tpu.util.jax_compat import axis_size
-
-    P = axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     M = jax.tree.leaves(microbatches)[0].shape[0]
     rotate = [(i, (i + 1) % P) for i in range(P)]

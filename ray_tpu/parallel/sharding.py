@@ -134,16 +134,10 @@ def opt_state_shardings(optimizer, sample_params, param_shardings, default):
     return tree_map_with_path(match, opt_state)
 
 
-def constraint(x, logical_axes, mesh=None, rules=None):
+def constraint(x, logical_axes, mesh, rules=None):
     """with_sharding_constraint using logical names (inside jit)."""
     import jax
 
-    if mesh is None:
-        from jax.interpreters import pxla
-
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
-            return x
     return jax.lax.with_sharding_constraint(
         x, logical_sharding(logical_axes, mesh, rules))
 
@@ -185,6 +179,20 @@ def shard_device_put(x, sharding):
               for d, idx in index_map.items()]
     return jax.make_array_from_single_device_arrays(
         x.shape, sharding, shards)
+
+
+def shard_layout(x) -> dict:
+    """Where one sharded array actually lives: how many devices hold a
+    shard of it and how many of those shards are distinct slices. The
+    check that a mesh built from real devices spread a leaf over all of
+    them, and did not leave every shard on the first."""
+    shards = x.addressable_shards
+    return {
+        "shape": list(x.shape),
+        "devices": len({s.device.id for s in shards}),
+        "distinct_shards": len({str(s.index) for s in shards}),
+        "shard_shape": list(shards[0].data.shape),
+    }
 
 
 def param_residency_bytes(params, specs, mesh, mode: str = "upfront",

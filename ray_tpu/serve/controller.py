@@ -21,6 +21,15 @@ def _emit(severity: str, message: str, entity_id: str = "",
                  entity_id=entity_id, **attrs)
 
 
+# How long a replica may take to answer a health ping before it is killed
+# and replaced. A replica that reaches for the chip cannot answer for
+# seconds at a time: on a v5e, jax's backend initialisation holds the GIL
+# for 6.9 s while libtpu starts (measured, PR 21), which the 5 s this used
+# to be does not cover. 30 s is the reference's default (serve
+# health_check_timeout_s).
+_HEALTH_CHECK_TIMEOUT_S = 30.0
+
+
 @ray_tpu.remote
 class ServeController:
     """One detached actor per Serve instance. Runs a reconciliation thread:
@@ -376,7 +385,7 @@ class ServeController:
                     continue
                 try:
                     ok = ray_tpu.get(r["actor"].check_health.remote(),
-                                     timeout=5)
+                                     timeout=_HEALTH_CHECK_TIMEOUT_S)
                 except Exception:
                     ok = False
                 if not ok:

@@ -619,8 +619,17 @@ class ServeReplica:
                 "replica_tag": self._replica_tag,
                 "deployment": self._deployment, "ts": time.time()}
 
-    def check_health(self) -> bool:
+    async def check_health(self) -> bool:
+        # async, so that it is answered from the actor's event loop: this
+        # actor has async methods, which leaves its sync methods ONE
+        # thread, and an eager decode stream holds that thread for as
+        # long as the stream lasts. The first stream of a replica on the
+        # chip lasts as long as reaching the chip, building the engine and
+        # compiling do; a ping queued behind it timed out, and the
+        # controller killed every such replica (found on the chip, PR 21).
         if hasattr(self._callable, "check_health"):
             res = self._callable.check_health()
+            if inspect.isawaitable(res):
+                res = await res
             return bool(res) if res is not None else True
         return True

@@ -19,7 +19,7 @@ from typing import Any, Dict, List, Optional
 class ScalingConfig:
     num_workers: int = 1
     use_tpu: bool = False
-    chips_per_worker: int = 0  # 0 = all chips of a host when use_tpu
+    chips_per_worker: int = 0  # TPU chips each worker owns; 0 = one
     resources_per_worker: Optional[Dict[str, float]] = None
     placement_strategy: str = "PACK"
     # TPU topology hint, e.g. "v5e-64"; reserved for slice-head scheduling

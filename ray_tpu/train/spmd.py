@@ -49,8 +49,8 @@ Supported mesh axes here: the batch axes (``slice``/``data``) plus
 (head/mlp/vocab sharding through compute). Sequence/pipeline
 parallelism stay on the GSPMD/pipeline paths (``make_train_step`` /
 ``make_pipeline_train_step``), which this step matches numerically
-(same-seed loss parity is tested — both draw init through
-``ensure_sharding_invariant_rng``).
+(same-seed loss parity is tested: ``jax.random`` is sharding-invariant,
+so the same seed gives the same params on every mesh).
 """
 
 from __future__ import annotations
@@ -339,11 +339,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     )
     from ray_tpu.ops.layers import rms_norm
     from ray_tpu.parallel.sharding import opt_state_shardings
-    from ray_tpu.util.jax_compat import (
-        axis_size,
-        ensure_sharding_invariant_rng,
-        shard_map,
-    )
+    from ray_tpu.util.jax_compat import shard_map
 
     for ax in ("seq", "pipe", "expert"):
         if ax in mesh.axis_names and mesh.shape[ax] > 1:
@@ -367,7 +363,6 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
                 raise ValueError(
                     f"tensor axis size {t} does not divide cfg.{what}={n}")
 
-    ensure_sharding_invariant_rng()
     optimizer = optimizer or optax.adamw(3e-4, b1=0.9, b2=0.95,
                                          weight_decay=0.1)
 
@@ -441,7 +436,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
             g = jax.lax.psum(g, fsdp)
         denom = 1
         for ax in batch_axes:
-            denom = denom * axis_size(ax)
+            denom = denom * jax.lax.axis_size(ax)
         return g / denom
 
     # ---- per-layer machinery -------------------------------------------- #
@@ -809,6 +804,11 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
         next_tokens = _prefetched_synthetic(
             host, data_sharding, knobs.train_ingest_prefetch)
 
+    # every report names the device the steps ran on, as JAX reports it in
+    # THIS process: a run on the wrong platform is visible in its results
+    dev0 = jax.local_devices()[0]
+    ran_on = {"platform": dev0.platform, "device_kind": dev0.device_kind}
+
     t0 = time.perf_counter()
     tokens_done = 0
     loss = None
@@ -837,15 +837,40 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
             win_dt = max(now - win_t, 1e-9)
             _g_tokens_per_sec.set((tokens_done - win_tokens) / win_dt,
                                   tags={"loop": "spmd"})
-            _g_step_seconds.set(win_dt / max(i + 1 - win_step, 1),
-                               tags={"loop": "spmd"})
+            step_seconds = win_dt / max(i + 1 - win_step, 1)
+            _g_step_seconds.set(step_seconds, tags={"loop": "spmd"})
             win_t, win_tokens, win_step = now, tokens_done, i + 1
-            session.report({
+            report = {
                 "loss": lf,
                 "step": i + 1,
+                "step_seconds": step_seconds,  # mean since the last report
                 "tokens_per_sec": tokens_done / dt,
                 "tokens_per_sec_per_chip": tokens_done / dt / mesh.size,
                 "devices": mesh.size,
                 "mesh": dict(mesh.shape),
-            })
+                **ran_on,
+            }
+            if i == steps - 1:
+                report.update(_run_evidence(state))
+            session.report(report)
     return float(loss) if loss is not None else None
+
+
+def _run_evidence(state) -> Dict[str, Any]:
+    """What the last report adds so that a caller can check HOW the run
+    ran, not only that it ended: the process's device report (memory per
+    device, compile seconds, compile-cache hits and misses), where the
+    largest parameter leaf's shards live, and which attention path every
+    traced call took."""
+    import jax
+
+    from ray_tpu.ops.flash_attention import paths_taken
+    from ray_tpu.parallel.sharding import shard_layout
+    from ray_tpu.util.device_telemetry import process_device_report
+
+    largest = max(jax.tree.leaves(state["params"]), key=lambda a: a.size)
+    return {
+        "device_report": process_device_report(),
+        "param_shards": shard_layout(largest),
+        "attention_paths": paths_taken(),
+    }

@@ -13,9 +13,15 @@ Everything feeds the existing worker->head metrics channel (the local
 registry flushed by ``start_report_thread``), so the head's /metrics and
 /api/metrics/history expose cluster-wide device state with zero new wires.
 
-Laziness is load-bearing: the collector never imports jax itself — it waits
-until user code has (``"jax" in sys.modules``), so CPU-only workers that
-never touch jax pay nothing. Event listeners are nonetheless installed at
+Laziness is load-bearing twice over. The collector never imports jax
+itself: it waits until user code has (``"jax" in sys.modules``), so
+CPU-only workers that never touch jax pay nothing. And it never
+initialises a backend: on libtpu the first process to do that takes every
+chip it can see and keeps them until it exits, so a telemetry thread in
+the driver, the node daemon or a pooled worker calling ``jax.devices()``
+would take the chip from the worker the runtime bound to it. Devices are
+read only once user code has brought a backend up
+(:func:`jax_with_backend`). Event listeners are nonetheless installed at
 jax-import time (``observe_jax_import``'s meta-path hook), not on the first
 collection tick: compiles that fire between import and the first tick —
 the first train step's JIT, typically — would otherwise never be counted.
@@ -23,11 +29,12 @@ the first train step's JIT, typically — would otherwise never be counted.
 
 from __future__ import annotations
 
+import os
 import sys
 import threading
 from typing import List, Optional
 
-from ray_tpu.util.metrics import Counter, Gauge, Histogram
+from ray_tpu.util.metrics import Counter, Gauge, Histogram, registry
 
 _BYTES_IN_USE = Gauge("ray_tpu_device_bytes_in_use",
                       "accelerator memory currently allocated (bytes)")
@@ -76,15 +83,31 @@ def _on_jax_event_duration(event: str, duration: float,
         pass
 
 
-def install_jax_listeners() -> bool:
+def _imported_jax():
+    """The jax module once its import has COMPLETED, else None.
+    ``sys.modules`` holds a module from the moment its import starts, and
+    a runtime thread that reaches into jax's submodules while user code
+    is still inside ``import jax`` on another thread breaks that import
+    (the import system resolves the lock cycle by handing one thread a
+    half-initialised module)."""
+    jax = sys.modules.get("jax")
+    if jax is None or getattr(jax.__spec__, "_initializing", False):
+        return None
+    return jax
+
+
+def install_jax_listeners(import_just_finished: bool = False) -> bool:
     """Register jax.monitoring listeners once per process. Returns True if
-    listeners are (already) installed; False when jax is absent or its
-    monitoring seam moved (the API lives in jax._src.monitoring)."""
+    listeners are (already) installed; False when jax is absent (or, seen
+    from another thread, still being imported) or its monitoring seam
+    moved (the API lives in jax._src.monitoring).
+    ``import_just_finished``: the caller is the import hook, on the
+    importing thread, right after jax's module body ran."""
     global _listeners_installed
     with _listener_lock:
         if _listeners_installed:
             return True
-        if "jax" not in sys.modules:
+        if not import_just_finished and _imported_jax() is None:
             return False
         try:
             from jax._src import monitoring as _mon
@@ -121,7 +144,7 @@ class _ListenerInstallingLoader:
             self._loader.exec_module(module)
         finally:
             _unobserve_jax_import()
-            install_jax_listeners()
+        install_jax_listeners(import_just_finished=True)
 
 
 class _JaxImportObserver:
@@ -209,18 +232,87 @@ def collect_device_stats(devices: List, node_hex: str = "") -> int:
     return n
 
 
+def jax_with_backend():
+    """The jax module once user code in this process has imported it AND
+    initialised a backend, else None. Asking is never what initialises
+    one: this is the only way the runtime's own threads look at jax."""
+    jax = _imported_jax()
+    if jax is None:
+        return None
+    bridge = sys.modules["jax._src.xla_bridge"]  # loaded by ``import jax``
+    return jax if bridge.backends_are_initialized() else None
+
+
 def collect_once(node_hex: str = "") -> int:
     """One collection tick: install listeners if jax showed up, then read
-    every visible device's memory stats. Cheap no-op before jax loads."""
+    the memory stats of the devices this process already holds. A no-op
+    until user code has initialised a backend."""
     if "jax" not in sys.modules:
         return 0
     install_jax_listeners()
-    jax = sys.modules["jax"]
-    try:
-        devices = jax.devices()
-    except Exception:
+    jax = jax_with_backend()
+    if jax is None:
         return 0
-    return collect_device_stats(devices, node_hex)
+    return collect_device_stats(jax.local_devices(), node_hex)
+
+
+# jax.monitoring event names the compile-cache and compile-time columns of
+# process_device_report() read
+_EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_EV_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_EV_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def process_device_report() -> dict:
+    """What THIS process ran on and what compiling cost it so far: the
+    platform, device kind and device count as JAX reports them, the JAX
+    and libtpu versions, per-device memory stats, and the persistent
+    compile-cache hits / misses and backend compile seconds the
+    ``jax.monitoring`` listeners have counted. For a process that has
+    already initialised its backend (it raises otherwise, rather than be
+    the call that takes a chip)."""
+    jax = jax_with_backend()
+    if jax is None:
+        raise RuntimeError(
+            "process_device_report() is for a process whose JAX backend is "
+            "already initialised; it will not initialise one")
+    import importlib.metadata as md
+
+    devices = jax.local_devices()
+    try:
+        libtpu = md.version("libtpu")
+    except md.PackageNotFoundError:
+        libtpu = None
+
+    reg = registry()
+
+    def of_event(metric: str, event: str) -> list:
+        return [v for tags, v in reg.local_values(metric).items()
+                if dict(tags).get("event") == event]
+
+    def event_count(event: str) -> int:
+        return int(sum(of_event("ray_tpu_jax_events_total", event)))
+
+    compile_s = sum(h["sum"] for h in of_event(
+        "ray_tpu_jax_event_duration_seconds", _EV_BACKEND_COMPILE))
+    memory = []
+    for d in devices:
+        stats = d.memory_stats() or {}
+        memory.append({"id": int(d.id),
+                       "bytes_in_use": stats.get("bytes_in_use"),
+                       "peak_bytes_in_use": stats.get("peak_bytes_in_use")})
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "devices": len(devices),
+        "jax": jax.__version__,
+        "libtpu": libtpu,
+        "pid": os.getpid(),
+        "cache_hits": event_count(_EV_CACHE_HIT),
+        "cache_misses": event_count(_EV_CACHE_MISS),
+        "compile_s": round(compile_s, 3),
+        "memory": memory,
+    }
 
 
 def start_device_telemetry(node_hex: str = "",

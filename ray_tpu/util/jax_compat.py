@@ -1,13 +1,10 @@
-"""Version portability shims for the jax API surface we depend on.
+"""``shard_map`` with this tree's default: replication checking off.
 
-The runtime targets the modern spelling (``jax.shard_map`` with
-``check_vma=``), but the pinned toolchain in some environments only
-ships the staging spelling (``jax.experimental.shard_map.shard_map``
-with ``check_rep=``).  Every shard_map call site in the tree goes
-through :func:`shard_map` so the whole collective/parallel/model stack
-works on both — and when NEITHER spelling exists, callers get one
-uniform ``JaxFeatureUnavailable`` that the test suite's skip shim can
-distinguish from a real regression.
+Every shard_map call site in the tree goes through :func:`shard_map`. The
+collective programs differentiate inside the mapped body with hand-written
+collective pairs (``models/llama.tp_psum_pair``), which ``check_vma=True``
+(jax's default) rejects, so the default here is off and a caller that wants
+the check asks for it.
 """
 
 from __future__ import annotations
@@ -15,66 +12,6 @@ from __future__ import annotations
 import jax
 
 
-class JaxFeatureUnavailable(RuntimeError):
-    """An optional jax API this environment's jax build does not provide.
-
-    Tests convert this into a skip-with-reason (see
-    ``tests/conftest.py``) so tier-1 output separates environment
-    incompatibility from regressions.
-    """
-
-
-def ensure_sharding_invariant_rng() -> None:
-    """Force the partitionable threefry implementation.
-
-    Modern jax defaults ``jax_threefry_partitionable=True``, which makes
-    ``jax.random`` output independent of how the computation is sharded
-    — the property our "same seed, any mesh, same params" training-init
-    contract relies on.  Older builds default it to False, where a
-    jitted sharded init draws different bits per shard layout.  No-op
-    where the default is already True.
-    """
-    try:
-        if not jax.config.jax_threefry_partitionable:
-            jax.config.update("jax_threefry_partitionable", True)
-    except AttributeError:
-        pass  # flag removed: partitionable is the only implementation
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` across versions.
-
-    Older builds lack the helper; ``psum(1, axis)`` is the classic
-    spelling and folds to a trace-time constant, so there is no runtime
-    collective either way.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def shard_map(fn, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    ``check`` maps onto ``check_vma`` (modern) or ``check_rep``
-    (staging); we always pass it explicitly because the defaults differ
-    across versions and the collective programs rely on it being off.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check)
-        except TypeError:
-            # intermediate builds ship jax.shard_map with the OLD
-            # check_rep spelling — kwargs are validated at wrap time,
-            # so the fallback is safe to take here
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check)
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    except ImportError as e:
-        raise JaxFeatureUnavailable(
-            f"this jax build ({jax.__version__}) provides neither "
-            "jax.shard_map nor jax.experimental.shard_map") from e
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -58,6 +58,14 @@ class _Registry:
                                         for k, v in m["values"].items()}}
             return out
 
+    def local_values(self, name: str) -> Dict[_TagKey, object]:
+        """This process's own series of one metric (tag key -> value; a
+        histogram's value is its {"sum", "count", "le"} dict), without
+        the per-source tables a head merges in."""
+        with self._lock:
+            m = self.metrics.get(name)
+            return dict(m["values"]) if m else {}
+
     def retire(self, source_id: str) -> None:
         """A source (worker) died: fold its cumulative metrics (counters,
         histograms) into a retired accumulator so sums stay monotonic if

@@ -30,13 +30,15 @@ ray_tpu xla``, ``GET /api/xla`` and the registry gauges: it joins the
 analytic FLOPs/bytes with measured flight-recorder spans
 (``spmd.compute``, ``serve.decode_step``, ...) into per-program
 achieved-FLOPs/s, arithmetic intensity, MFU and a compute-bound vs
-memory-bound roofline verdict against per-platform peak tables (TPU
-peaks from the device kind; CPU numbers are nominal and trend-only —
-the PR-14 discipline — so the verdict is advisory there).
+memory-bound roofline verdict against the peak table, which is keyed by
+TPU ``device_kind``. A device that is not in the table has no peak: the
+lookup raises :class:`UnknownDeviceError`, and the report then carries
+the analytic and measured columns without MFU or verdict and says why.
 """
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -54,8 +56,11 @@ __all__ = [
     "format_xla",
     "peak_flops_per_chip",
     "peak_hbm_bytes_per_sec",
+    "UnknownDeviceError",
     "reset_for_tests",
 ]
+
+logger = logging.getLogger(__name__)
 
 _sp_compile = _fr.register_span("xla.compile", tag_keys=("program",))
 
@@ -287,51 +292,63 @@ def _record_compiled(name: str, fp: tuple, fp_str: str, compiled,
 class ObservedFunction:
     """AOT-caching wrapper around one jitted callable.
 
-    Any failure on the observation path (fingerprint, lower, compile,
-    or an executable rejecting a call — e.g. a sharding layout the aval
-    fingerprint cannot see) permanently falls back to the original
-    jitted function for this program: observation must never change
-    what a train step computes or whether it runs.
+    What XLA says reaches the caller as XLA said it: a failed ``lower()``
+    or ``compile()`` and a failed execution propagate unchanged. Neither
+    is retried through plain ``jit``: that would compile the whole program
+    a second time, and re-run a step on buffers the first attempt may
+    already have donated, so an out-of-memory on the chip would surface as
+    "array has been deleted".
+
+    Only a failure of the observation itself is absorbed, and it says so
+    once, with the exception: the aval fingerprint cannot be built, or the
+    AOT executable refuses the call's arguments before running anything (a
+    sharding or layout the fingerprint does not see; ``jit`` would have
+    re-specialised). From then on this program goes through the original
+    jitted function, unobserved.
     """
 
     def __init__(self, fn: Callable, name: str):
         self._fn = fn
         self.program_name = name
         self._cache: Dict[tuple, Any] = {}
-        self._fallback = False
+        self._unobserved = False
 
     def __getattr__(self, item):
         return getattr(self._fn, item)
 
+    def _stop_observing(self, what: str, exc: BaseException) -> None:
+        self._unobserved = True
+        logger.warning(
+            "xla observatory: %s for program %r (%s: %s); it runs "
+            "unobserved through jit from here on",
+            what, self.program_name, type(exc).__name__, exc)
+
     def __call__(self, *args, **kwargs):
-        if self._fallback or not global_config().xla_observatory_enabled:
+        if self._unobserved or not global_config().xla_observatory_enabled:
             return self._fn(*args, **kwargs)
         try:
             fp = _fingerprint(args, kwargs)
-        except Exception:
-            self._fallback = True
+        except Exception as e:  # noqa: BLE001 - observation, not the step
+            self._stop_observing("cannot fingerprint the arguments", e)
             return self._fn(*args, **kwargs)
         compiled = self._cache.get(fp)
         if compiled is None:
-            try:
-                t0 = time.monotonic()
-                lowered = self._fn.lower(*args, **kwargs)
-                compiled = lowered.compile()
-                dt = time.monotonic() - t0
-                _sp_compile.end(t0, self.program_name)
-                _record_compiled(self.program_name, fp, _describe(fp),
-                                 compiled, dt, lowered)
-                self._cache[fp] = compiled
-            except Exception:
-                self._fallback = True
-                return self._fn(*args, **kwargs)
+            t0 = time.monotonic()
+            lowered = self._fn.lower(*args, **kwargs)
+            compiled = lowered.compile()
+            dt = time.monotonic() - t0
+            _sp_compile.end(t0, self.program_name)
+            _record_compiled(self.program_name, fp, _describe(fp),
+                             compiled, dt, lowered)
+            self._cache[fp] = compiled
         try:
             return compiled(*args, **kwargs)
-        except Exception:
-            # donation makes a bare retry unsafe only if the executable
-            # ran; argument-layout rejections happen before any buffer
-            # is consumed, which is the case this path exists for
-            self._fallback = True
+        except (TypeError, ValueError) as e:
+            # raised by the executable's argument check, before any buffer
+            # is consumed; XLA's own run-time errors are JaxRuntimeError
+            # and are not caught here
+            self._stop_observing(
+                "the AOT executable rejected the call's arguments", e)
             return self._fn(*args, **kwargs)
 
 
@@ -392,67 +409,77 @@ def reset_for_tests() -> None:
 # Per-platform peaks (roofline ceilings)
 # --------------------------------------------------------------------------- #
 
-# bf16 peak FLOPs per chip by TPU generation (the bench.py table)
+# Published per-chip peaks by TPU generation (Google Cloud TPU system
+# architecture pages): bf16 FLOP/s and HBM bytes/s
 _TPU_PEAK_FLOPS = {"v4": 275e12, "v5e": 197e12, "v5p": 459e12,
                    "v6e": 918e12}
-# HBM bandwidth per chip, bytes/s
 _TPU_PEAK_HBM = {"v4": 1228e9, "v5e": 819e9, "v5p": 2765e9,
                  "v6e": 1638e9}
-# nominal CPU ceilings: trend-only, never an enforced verdict (PR-14
-# discipline — virtual/CPU devices make absolute numbers meaningless)
-_CPU_NOMINAL_FLOPS = 1e12
-_CPU_NOMINAL_HBM = 100e9
 
 
-def _device_info() -> Tuple[str, str]:
-    """(platform, device_kind) of the default backend; guards a missing
-    or unimportable jax."""
-    try:
-        import jax
-
-        dev = jax.devices()[0]
-        return dev.platform, getattr(dev, "device_kind", dev.platform)
-    except Exception:
-        return "cpu", "unknown"
+class UnknownDeviceError(LookupError):
+    """The local device has no entry in the peak table. There is no
+    default: a roofline against a guessed peak is a wrong number."""
 
 
-# device_kind strings as reported by the runtime -> generation key;
-# ordered (v5lite before v5: the bare "v5" kind is a v5p)
+def _device_info() -> Tuple[Optional[str], Optional[str], int]:
+    """(platform, device_kind, device count) of the backend this process
+    has ALREADY initialised, or (None, None, 0): the fold also runs in the
+    head (dashboard, CLI), which holds no device and must not take one by
+    asking."""
+    from ray_tpu.util.device_telemetry import jax_with_backend
+
+    jax = jax_with_backend()
+    if jax is None:
+        return None, None, 0
+    dev = jax.devices()[0]
+    return dev.platform, dev.device_kind, jax.device_count()
+
+
+# device_kind strings as the runtime reports them -> generation key;
+# ordered (v5lite before v5: the bare "v5" kind is a v5p). A v5e chip
+# reports "TPU v5 lite" (checked on the chip, PR 21).
 _TPU_KIND_ALIASES = (("v6lite", "v6e"), ("v6e", "v6e"),
                      ("v5lite", "v5e"), ("v5e", "v5e"),
                      ("v5p", "v5p"), ("v5", "v5p"), ("v4", "v4"))
 
 
-def _tpu_table_lookup(table: Dict[str, float], kind: str,
-                      default: float) -> float:
-    k = kind.lower().replace(" ", "")
-    for pat, gen in _TPU_KIND_ALIASES:
-        if pat in k:
-            return table.get(gen, default)
-    return default
+def _tpu_table_lookup(table: Dict[str, float], platform: Optional[str],
+                      kind: Optional[str]) -> float:
+    if platform is None:
+        raise UnknownDeviceError(
+            "this process has initialised no JAX backend, so it has no "
+            "device to look a peak up for (the head never takes one); set "
+            "xla_peak_flops / xla_peak_hbm_bytes to get MFU and verdicts")
+    if platform == "tpu":
+        k = kind.lower().replace(" ", "")
+        for pat, gen in _TPU_KIND_ALIASES:
+            if pat in k:
+                return table[gen]
+    raise UnknownDeviceError(
+        f"no peak-table entry for device platform={platform!r} "
+        f"device_kind={kind!r}; known TPU kinds: "
+        f"{sorted(set(g for _, g in _TPU_KIND_ALIASES))}. Set "
+        f"xla_peak_flops / xla_peak_hbm_bytes to measure against a "
+        f"peak of your own")
 
 
 def peak_flops_per_chip() -> float:
-    """bf16 peak FLOPs/s per chip (``xla_peak_flops`` overrides)."""
+    """bf16 peak FLOPs/s per chip (``xla_peak_flops`` overrides). Raises
+    :class:`UnknownDeviceError` for a device that is not in the table."""
     override = global_config().xla_peak_flops
     if override > 0:
         return float(override)
-    platform, kind = _device_info()
-    if platform == "tpu":
-        return _tpu_table_lookup(_TPU_PEAK_FLOPS, kind, 197e12)
-    return _CPU_NOMINAL_FLOPS
+    return _tpu_table_lookup(_TPU_PEAK_FLOPS, *_device_info()[:2])
 
 
 def peak_hbm_bytes_per_sec() -> float:
     """Memory bandwidth per chip in bytes/s (``xla_peak_hbm_bytes``
-    overrides)."""
+    overrides). Raises :class:`UnknownDeviceError` like the above."""
     override = global_config().xla_peak_hbm_bytes
     if override > 0:
         return float(override)
-    platform, kind = _device_info()
-    if platform == "tpu":
-        return _tpu_table_lookup(_TPU_PEAK_HBM, kind, 819e9)
-    return _CPU_NOMINAL_HBM
+    return _tpu_table_lookup(_TPU_PEAK_HBM, *_device_info()[:2])
 
 
 # --------------------------------------------------------------------------- #
@@ -525,17 +552,17 @@ def _measured_span_stats(head=None) -> Dict[str, Dict[str, float]]:
 def xla_report(head=None) -> Dict[str, Any]:
     """The compile-plane report: merged registry columns joined with
     measured spans, rooflined against the platform peaks."""
-    platform, kind = _device_info()
+    platform, kind, n_devices = _device_info()
+    peaks_unknown = None
     try:
-        import jax
-
-        n_devices = jax.device_count()
-    except Exception:
-        n_devices = 1
-    peak_f = peak_flops_per_chip()
-    peak_b = peak_hbm_bytes_per_sec()
-    ridge = peak_f / peak_b if peak_b > 0 else None
-    enforced = platform == "tpu"
+        peak_f = peak_flops_per_chip()
+        peak_b = peak_hbm_bytes_per_sec()
+        ridge = peak_f / peak_b
+    except UnknownDeviceError as e:
+        # the report still carries what was counted and measured; it has
+        # no MFU and no verdict, and says why
+        peak_f = peak_b = ridge = None
+        peaks_unknown = str(e)
 
     programs = _merged_program_columns()
     # head-process registry detail (avals, shardings, donation) for the
@@ -570,13 +597,12 @@ def xla_report(head=None) -> Dict[str, Any]:
                 # FLOPs/s rooflines against ONE chip's peak
                 achieved = flops / mean_s
                 row["achieved_flops_per_s"] = round(achieved, 2)
-                if peak_f > 0:
+                if peak_f is not None:
                     row["mfu"] = round(achieved / peak_f, 6)
         ai = row.get("arithmetic_intensity")
         if ai is not None and ridge is not None:
             row["verdict"] = ("compute-bound" if ai >= ridge
                               else "memory-bound")
-            row["verdict_enforced"] = enforced
     report: Dict[str, Any] = {
         "platform": platform,
         "device_kind": kind,
@@ -584,6 +610,7 @@ def xla_report(head=None) -> Dict[str, Any]:
         "peak_flops_per_chip": peak_f,
         "peak_hbm_bytes_per_sec": peak_b,
         "ridge_intensity": round(ridge, 4) if ridge else None,
+        "peaks_unknown": peaks_unknown,
         "programs": {k: programs[k] for k in sorted(programs)},
         "recompiles_total": int(recompiles_total),
     }
@@ -619,17 +646,21 @@ def _fmt_num(v: float) -> str:
 def format_xla(report: Dict[str, Any]) -> str:
     """Human rendering of :func:`xla_report` (the CLI view)."""
     lines = ["xla compile observatory", "-" * 23]
-    lines.append(
-        f"platform: {report['platform']} ({report['device_kind']}), "
-        f"{report['devices']} device(s)")
+    if report["platform"] is None:
+        lines.append("platform: this process holds no device")
+    else:
+        lines.append(
+            f"platform: {report['platform']} ({report['device_kind']}), "
+            f"{report['devices']} device(s)")
     ridge = report.get("ridge_intensity")
-    lines.append(
-        f"peaks: {_fmt_num(report['peak_flops_per_chip'])}FLOP/s, "
-        f"{_fmt_num(report['peak_hbm_bytes_per_sec'])}B/s"
-        + (f", ridge {ridge:.1f} FLOP/B" if ridge else ""))
-    if report["platform"] != "tpu":
-        lines.append("(non-TPU peaks are nominal: verdicts are "
-                     "trend-only, not enforced)")
+    if report.get("peaks_unknown"):
+        lines.append("peaks: unknown, so no MFU and no verdict ("
+                     + report["peaks_unknown"] + ")")
+    else:
+        lines.append(
+            f"peaks: {_fmt_num(report['peak_flops_per_chip'])}FLOP/s, "
+            f"{_fmt_num(report['peak_hbm_bytes_per_sec'])}B/s"
+            + (f", ridge {ridge:.1f} FLOP/B" if ridge else ""))
     progs = report.get("programs", {})
     if not progs:
         lines.append("no observed programs")

@@ -217,6 +217,8 @@ class TestCompiledDagExecutorDeath:
                     return os.getpid()
 
                 def inc(self, x):
+                    if x != 1:  # every round after the warm-up is still
+                        time.sleep(30)  # in the executor when the kill lands
                     return x + 1
 
             s = S.remote()
@@ -255,6 +257,8 @@ class TestCompiledDagExecutorDeath:
                     return os.getpid()
 
                 def inc(self, x):
+                    if x == 7:  # the round the kill must find in flight
+                        time.sleep(30)
                     return x + 1
 
             @ray_tpu.remote

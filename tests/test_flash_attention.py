@@ -2,7 +2,7 @@
 
 Runs the Pallas kernels in interpreter mode on the CPU test mesh (shapes
 kept tiny — interpret mode executes block-by-block in Python). The same
-kernels run compiled on real TPU via bench.py / the flagship model.
+kernels run compiled on the chip in chip_smoke.py, against the same reference.
 """
 
 import jax
@@ -65,13 +65,52 @@ def test_grads_match_reference():
                                    atol=5e-2, rtol=5e-2, err_msg=name)
 
 
-def test_fallback_on_odd_shapes():
-    # T=100 doesn't block: must silently use the exact path
+def test_odd_shapes_take_the_reference_and_say_so():
+    # T=100 doesn't block: the exact path runs, and the run can tell
+    from ray_tpu.ops import flash_attention as fa
+
     rng = np.random.RandomState(2)
     q = jnp.asarray(rng.randn(1, 100, 2, 32), jnp.float32)
     k = jnp.asarray(rng.randn(1, 100, 2, 32), jnp.float32)
     v = jnp.asarray(rng.randn(1, 100, 2, 32), jnp.float32)
-    out = flash_attention(q, k, v, causal=True)
+    path, reason = fa.attention_path(q.shape, k.shape, interpret=True)
+    assert path == fa.PATH_REFERENCE and "100" in reason
+    out = flash_attention(q, k, v, causal=True, interpret=True)
     expect = _ref(q, k, v, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-5, rtol=1e-5)
+    rec = [r for r in fa.paths_taken() if r["q_shape"] == [1, 100, 2, 32]]
+    assert len(rec) == 1 and rec[0]["path"] == fa.PATH_REFERENCE
+    assert rec[0]["reason"] == reason and rec[0]["calls"] >= 1
+
+
+def test_flash_attention_reports_its_path():
+    """Which attention ran is recorded per (shapes, dtype): the kernel
+    where it can run (interpreted here), the reference on a backend that
+    is not a TPU, and a backend that cannot be asked is an error."""
+    from ray_tpu.ops import flash_attention as fa
+
+    q_shape, k_shape = (1, 128, 4, 32), (1, 128, 2, 32)
+    assert fa.attention_path(q_shape, k_shape, interpret=True) == (
+        fa.PATH_PALLAS_INTERPRET, "interpret=True")
+    path, reason = fa.attention_path(q_shape, k_shape)  # CPU backend
+    assert path == fa.PATH_REFERENCE and "'cpu'" in reason
+
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(*q_shape), jnp.bfloat16)
+    k = jnp.asarray(rng.randn(*k_shape), jnp.bfloat16)
+    flash_attention(q, k, k, causal=True, interpret=True)
+    flash_attention(q, k, k, causal=True)
+    taken = {r["path"] for r in fa.paths_taken()
+             if r["q_shape"] == list(q_shape) and r["dtype"] == "bfloat16"}
+    assert taken == {fa.PATH_PALLAS_INTERPRET, fa.PATH_REFERENCE}
+
+    def broken_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    real, fa.jax.default_backend = fa.jax.default_backend, broken_backend
+    try:
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            fa.attention_path(q_shape, k_shape)
+    finally:
+        fa.jax.default_backend = real

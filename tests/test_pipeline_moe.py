@@ -116,10 +116,8 @@ class TestLlamaPipeline:
         tokens_np = np.asarray(jax.device_get(tokens))
         _, loss_pp = train_step(state, tokens)
         loss_ref = llama.loss_fn(cfg, flat, tokens_np)
-        # rtol: the staging shard_map (jax builds without jax.shard_map;
-        # see util/jax_compat) reorders the fp32 reductions across the
-        # pipe axis — measured ~1e-3 relative drift vs the serial
-        # reference on such builds, bit-tight on modern jax
+        # rtol: the pipelined program orders the fp32 reductions across
+        # the pipe axis differently from the serial reference
         np.testing.assert_allclose(float(loss_pp), float(loss_ref),
                                    rtol=2e-3, atol=2e-3)
 

@@ -362,3 +362,45 @@ class TestPrewarmPool:
         finally:
             cfg.serve_prewarm_pool_size = 0
             ray_tpu.shutdown()
+
+
+# --------------------------------------------------------------------------
+# health pings during a long eager stream (found on the chip, ISSUE 21)
+# --------------------------------------------------------------------------
+
+
+def test_health_ping_is_answered_while_an_eager_stream_runs():
+    """The replica is an async actor, so its sync methods share ONE
+    thread, and an eager decode stream holds it until the stream ends. On
+    the chip a replica's first stream lasts as long as reaching the chip
+    and compiling do; a health ping queued behind it timed out and the
+    controller killed the replica. The ping is answered from the event
+    loop instead."""
+    import cloudpickle
+
+    from ray_tpu.serve.replica import ServeReplica
+
+    class SlowLM:
+        def create_decode_engine(self):
+            from ray_tpu.serve.decode import ToyEngine
+
+            return ToyEngine(n_pages=64, page_size=4, step_delay_s=0.05)
+
+    ray_tpu.init(num_cpus=2, num_tpus=0)
+    try:
+        replica = ServeReplica.remote(cloudpickle.dumps(SlowLM), (), {},
+                                      None, "SlowLM", "SlowLM#t")
+        assert ray_tpu.get(replica.check_health.remote(), timeout=30)
+        stream = replica.handle_request_decode_stream.options(
+            num_returns="streaming").remote(
+            {"prompt": [1, 2, 3], "max_tokens": 60})  # ~3 s of steps
+        first = next(iter(stream))  # the stream is running now
+        t0 = time.time()
+        assert ray_tpu.get(replica.check_health.remote(), timeout=30)
+        waited = time.time() - t0
+        frames = [ray_tpu.get(first)] + [ray_tpu.get(r) for r in stream]
+        assert frames[-1][0] == "final"
+        assert waited < 1.5, (
+            f"health ping waited {waited:.2f}s behind the stream")
+    finally:
+        ray_tpu.shutdown()

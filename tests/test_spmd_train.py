@@ -103,9 +103,6 @@ def test_shard_gather_round_trip_byte_identical(cfg):
     """shard → gather is byte-identical per leaf, on two layouts."""
     import jax
 
-    from ray_tpu.util.jax_compat import ensure_sharding_invariant_rng
-
-    ensure_sharding_invariant_rng()
     params = jax.device_get(init_params(cfg, jax.random.PRNGKey(3)))
     specs = match_partition_rules(llama_partition_rules(), params)
     for mc in [MeshConfig(data=1, fsdp=8), MeshConfig(data=2, fsdp=4)]:
@@ -122,7 +119,7 @@ def test_shard_gather_round_trip_byte_identical(cfg):
 
 
 def test_same_seed_init_invariant_across_mesh_layouts(cfg):
-    """ensure_sharding_invariant_rng: the same seed yields bitwise-equal
+    """jax.random is sharding-invariant: the same seed yields bitwise-equal
     params whether the mesh is 1xN (fsdp=8) or Nx1 (data=8)."""
     import jax
 

@@ -121,21 +121,86 @@ def test_disabled_config_is_passthrough():
         cfg.xla_observatory_enabled = True
 
 
-def test_fallback_on_observation_failure():
+class _FakeJit:
+    """Stands in for a jitted callable: counts plain calls, and fails at
+    the stage the test names."""
+
+    def __init__(self, lower_error=None, compile_error=None, run_error=None):
+        self.lower_error, self.compile_error = lower_error, compile_error
+        self.run_error = run_error
+        self.plain_calls = 0
+
+    def __call__(self, *a, **k):
+        self.plain_calls += 1
+        return "ran through jit"
+
+    def lower(self, *a, **k):
+        if self.lower_error is not None:
+            raise self.lower_error
+        return self
+
+    def compile(self):
+        if self.compile_error is not None:
+            raise self.compile_error
+
+        def executable(*a, **k):
+            if self.run_error is not None:
+                raise self.run_error
+            return "ran compiled"
+
+        return executable
+
+
+def test_compile_error_reaches_the_caller_as_it_is():
+    """A failed lower()/compile() is XLA's answer: it propagates, and the
+    program is NOT compiled a second time through plain jit."""
     import jax.numpy as jnp
 
-    class NoLower:
-        def lower(self, *a, **k):
-            raise RuntimeError("no lowering for you")
+    for stage in ("lower_error", "compile_error"):
+        err = RuntimeError(f"RESOURCE_EXHAUSTED at {stage}")
+        fake = _FakeJit(**{stage: err})
+        f = xo.ObservedFunction(fake, f"obs.t5.{stage}")
+        with pytest.raises(RuntimeError) as ei:
+            f(jnp.zeros((1,)))
+        assert ei.value is err
+        assert fake.plain_calls == 0
+        assert xo.get_program(f"obs.t5.{stage}") is None
 
-        def __call__(self, *a, **k):
-            return "ran"
 
-    f = xo.ObservedFunction(NoLower(), "obs.t5")
-    assert f(jnp.zeros((1,))) == "ran"
-    assert f._fallback  # permanent: observation must never break a step
-    assert f(jnp.zeros((1,))) == "ran"
-    assert xo.get_program("obs.t5") is None
+def test_runtime_error_reaches_the_caller_without_a_retry():
+    """A failed execution is not retried on buffers the first attempt
+    may already have donated: the caller sees XLA's error, not "array
+    has been deleted" from a second run."""
+    import jax.numpy as jnp
+
+    err = RuntimeError("RESOURCE_EXHAUSTED: out of memory on the chip")
+    fake = _FakeJit(run_error=err)
+    f = xo.ObservedFunction(fake, "obs.t5.run")
+    for _ in range(2):  # and the failure does not switch observation off
+        with pytest.raises(RuntimeError) as ei:
+            f(jnp.zeros((1,)))
+        assert ei.value is err
+    assert fake.plain_calls == 0
+
+
+def test_argument_rejection_is_an_observation_failure_said_once(caplog):
+    """The AOT executable refusing the call's arguments (a sharding the
+    fingerprint cannot see) happens before anything runs; jit would have
+    re-specialised, so the program goes on through jit, unobserved, and
+    the log says so once, with the exception."""
+    import logging
+
+    import jax.numpy as jnp
+
+    fake = _FakeJit(run_error=ValueError("input sharding does not match"))
+    f = xo.ObservedFunction(fake, "obs.t5.args")
+    with caplog.at_level(logging.WARNING, logger=xo.logger.name):
+        assert f(jnp.zeros((1,))) == "ran through jit"
+        assert f(jnp.zeros((1,))) == "ran through jit"
+    said = [r for r in caplog.records if "obs.t5.args" in r.getMessage()]
+    assert len(said) == 1
+    assert "input sharding does not match" in said[0].getMessage()
+    assert fake.plain_calls == 2
 
 
 def test_lowered_input_compiles_and_records():
@@ -174,11 +239,28 @@ def test_xla_report_joins_measured_spans_and_rooflines():
             fn(x).block_until_ready()
             _sp_compute.end(t0)
 
-        report = xo.xla_report(None)
+        # a CPU is not in the peak table: the fold reports what was
+        # counted and measured, with no MFU and no verdict, and says why
+        bare = xo.xla_report(None)
+        cfg = global_config()
+        cfg.xla_peak_flops, cfg.xla_peak_hbm_bytes = 1e12, 100e9
+        try:
+            report = xo.xla_report(None)
+        finally:
+            cfg.xla_peak_flops = cfg.xla_peak_hbm_bytes = 0.0
     finally:
         fr.configure(min_span_us=prev_min)
-    assert report["platform"] == "cpu"
-    assert report["peak_flops_per_chip"] > 0
+    assert bare["platform"] == "cpu"
+    assert bare["peak_flops_per_chip"] is None
+    assert "no peak-table entry" in bare["peaks_unknown"]
+    bare_row = bare["programs"]["spmd.train_step"]
+    assert bare_row["achieved_flops_per_s"] > 0
+    assert "mfu" not in bare_row and "verdict" not in bare_row
+    assert "peaks: unknown" in xo.format_xla(bare)
+
+    # with peaks given (the overrides), the join rooflines
+    assert report["peaks_unknown"] is None
+    assert report["peak_flops_per_chip"] == 1e12
     assert report["ridge_intensity"] > 0
 
     row = report["programs"]["spmd.train_step"]
@@ -189,12 +271,10 @@ def test_xla_report_joins_measured_spans_and_rooflines():
     assert 0 < row["mfu"] < 1
     assert row["arithmetic_intensity"] > 0
     assert row["verdict"] in ("compute-bound", "memory-bound")
-    assert row["verdict_enforced"] is False  # CPU: trend-only, never enforced
 
     # ONE fold: the CLI rendering and the registry gauges agree with it
     text = xo.format_xla(report)
     assert "spmd.train_step" in text
-    assert "trend-only" in text           # the CPU-peaks disclaimer
     assert "measured: " in text
     flat = aggregate_series(registry())
     programs_gauge = dict(flat["ray_tpu_xla_programs"])[()]
@@ -211,12 +291,26 @@ def test_peak_table_overrides_and_kind_aliases():
     finally:
         cfg.xla_peak_flops = 0.0
         cfg.xla_peak_hbm_bytes = 0.0
-    # device-kind strings as the runtime spells them (bare "v5" is a v5p)
-    assert xo._tpu_table_lookup(xo._TPU_PEAK_FLOPS, "TPU v5e", 0) == 197e12
-    assert xo._tpu_table_lookup(xo._TPU_PEAK_FLOPS, "TPU v5 lite", 0) == 197e12
-    assert xo._tpu_table_lookup(xo._TPU_PEAK_FLOPS, "TPU v5", 0) == 459e12
-    assert xo._tpu_table_lookup(xo._TPU_PEAK_FLOPS, "TPU v4", 0) == 275e12
-    assert xo._tpu_table_lookup(xo._TPU_PEAK_FLOPS, "weird", 7.0) == 7.0
+    # device-kind strings as the runtime spells them (bare "v5" is a v5p;
+    # a v5e chip prints "TPU v5 lite")
+    flops = xo._TPU_PEAK_FLOPS
+    assert xo._tpu_table_lookup(flops, "tpu", "TPU v5e") == 197e12
+    assert xo._tpu_table_lookup(flops, "tpu", "TPU v5 lite") == 197e12
+    assert xo._tpu_table_lookup(flops, "tpu", "TPU v5") == 459e12
+    assert xo._tpu_table_lookup(flops, "tpu", "TPU v4") == 275e12
+
+
+def test_a_device_that_is_not_in_the_peak_table_is_an_error():
+    """No default for an unknown TPU kind, no nominal number for another
+    platform: a roofline against a guessed peak is a wrong number."""
+    with pytest.raises(xo.UnknownDeviceError, match="TPU v9"):
+        xo._tpu_table_lookup(xo._TPU_PEAK_FLOPS, "tpu", "TPU v9")
+    with pytest.raises(xo.UnknownDeviceError, match="platform='cpu'"):
+        xo._tpu_table_lookup(xo._TPU_PEAK_HBM, "cpu", "cpu")
+    with pytest.raises(xo.UnknownDeviceError):  # this process: CPU backend
+        xo.peak_flops_per_chip()
+    with pytest.raises(xo.UnknownDeviceError):
+        xo.peak_hbm_bytes_per_sec()
 
 
 # --------------------------------------------------------------------------- #
