@@ -588,7 +588,8 @@ class NetRingReader(_Endpoint):
                  resync: bool = False):
         super().__init__(ring_id, n_slots, capacity)
         self.r = 0
-        self._slots = [None] * n_slots  # (seq, tag, payload) | None
+        self._slots = [None] * n_slots  # (seq, tag, payload, stamp) | None
+        self.last_publish_mono = 0.0  # arrival stamp of the last read
         self.resyncing = resync  # the model's RESYNC pc
 
     # ---- protocol state ----
@@ -631,8 +632,10 @@ class NetRingReader(_Endpoint):
                     # ack cannot pin the writer's window shut
                     reack = self.r
                 else:
+                    # stamped on arrival: "entered this host's ring"
+                    # (the shm slot header's publish stamp, channel.py)
                     self._slots[(seq - 1) % self.n_slots] = \
-                        (seq, msg[2], msg[3])
+                        (seq, msg[2], msg[3], _fr.now())
                     self._ring_bell()
             if reack is not None and reply is not None:
                 _net_send(reply, "nra", reack)
@@ -655,7 +658,7 @@ class NetRingReader(_Endpoint):
             slot = self._slots[idx]
             if slot is None:
                 raise ChannelTimeout(f"{self.path}: nothing readable")
-            seq, tag, payload = slot
+            seq, tag, payload, self.last_publish_mono = slot
             if seq != self.r + 1:  # torn/stale stamp: protocol violation
                 raise ChannelClosed(
                     f"{self.path}: slot seq {seq} != expected {self.r + 1}")

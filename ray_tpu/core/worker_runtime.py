@@ -43,6 +43,9 @@ from ray_tpu.util import flight_recorder as _fr
 
 _sp_dag_exec = _fr.register_span("dag.exec", tag_keys=("method",))
 _sp_batch_drain = _fr.register_span("dag.batch_drain", tag_keys=("method",))
+# a stream request's residency in the lane's ring: publish stamp -> read
+_sp_stream_ingress = _fr.register_span(
+    "dag.stream_ingress", tag_keys=("method", "corr"), floor_exempt=True)
 
 
 class _BatchErrPayload:
@@ -976,7 +979,12 @@ class WorkerRuntime:
                     # upstream error passthrough: the request dies before
                     # admission, but its stream must still complete
                     send(corr, STREAM_F_FINAL | STREAM_F_ERROR, payload)
-                elif tag == TAG_TENSOR or tag == TAG_BYTES:
+                    continue
+                # how long the request sat in the ring unread (nothing
+                # when the stamp is 0: the writer's recorder was off)
+                _sp_stream_ingress.end(ch.last_publish_mono, method_name,
+                                       corr)
+                if tag == TAG_TENSOR or tag == TAG_BYTES:
                     entries.append((corr, payload))
                 else:
                     entries.append((corr,

@@ -13,7 +13,16 @@ Ring layout (v2 — generalizes the original single-slot rendezvous):
     global header (32 B):
         [write_seq u64][read_seq u64][n_slots u64][slot_cap u64]
     then n_slots slots of (24 B header + slot_cap payload):
-        [seq u64][msg_len u64][tag u8][pad 7]
+        [seq u64][msg_len u64][tag u8][publish stamp, 56 bits]
+
+The publish stamp is the flight recorder's clock at ``_publish``, in whole
+microseconds (0 when the recorder is off); ``read`` leaves it on the
+channel as ``last_publish_mono`` (seconds). ``time.monotonic()`` is
+CLOCK_MONOTONIC, which the processes of one host share, so the reader may
+subtract it from its own clock. A message that crossed hosts
+(``core/net_ring.py``) is stamped when it lands in the receiving process's
+slots: the stamp always means "entered this host's ring", never a remote
+clock.
 
 Each endpoint writes ONLY its own fields: the writer owns ``write_seq``
 and every slot header it publishes; the reader owns ``read_seq``. The
@@ -58,7 +67,9 @@ _RSEQ = struct.Struct("<Q")     # at offset 8 (reader-owned)
 _OFF_READER_PARKED = 32
 _OFF_WRITER_PARKED = 40
 _HDR_SIZE = 48
-_SHDR = struct.Struct("<QQB7x")  # per-slot: seq, msg_len, tag (writer-owned)
+# per-slot, writer-owned: seq, msg_len, then one word whose low byte is the
+# tag and whose upper 56 bits are the publish stamp (microseconds)
+_SHDR = struct.Struct("<QQQ")
 TAG_DATA = 0
 TAG_STOP = 1
 TAG_ERROR = 2
@@ -293,6 +304,9 @@ class ShmChannel:
             base = base[len("raytpu_chan_"):]
             base = base.split("_", 1)[-1]
         self._metric_name = base
+        # publish stamp of the message `read` returned last (seconds on
+        # this host's monotonic clock; 0.0 = the writer's recorder was off)
+        self.last_publish_mono = 0.0
         flags = os.O_RDWR | (os.O_CREAT if create else 0)
         self._fd = os.open(path, flags, 0o600)
         self._mm = None
@@ -457,7 +471,8 @@ class ShmChannel:
         w, _ = self._seqs()
         off = self._slot_off(w)
         fill(self._mm, off + _SHDR.size)
-        _SHDR.pack_into(self._mm, off, w + 1, total_len, tag)
+        _SHDR.pack_into(self._mm, off, w + 1, total_len,
+                        tag | int(_fr.now() * 1e6) << 8)
         _WSEQ.pack_into(self._mm, 0, w + 1)
         if self._mm[_OFF_READER_PARKED]:
             self._ring(self._bell_rdy)
@@ -516,10 +531,12 @@ class ShmChannel:
                    timeout)
         _, r = self._seqs()
         off = self._slot_off(r)
-        seq, length, tag = _SHDR.unpack_from(self._mm, off)
+        seq, length, word = _SHDR.unpack_from(self._mm, off)
         if seq != r + 1:  # writer crashed mid-publish / stale mapping
             raise ChannelClosed(
                 f"{self.path}: slot seq {seq} != expected {r + 1}")
+        tag = word & 0xFF
+        self.last_publish_mono = (word >> 8) / 1e6
         body = off + _SHDR.size
         if tag == TAG_TENSOR:
             value = self._read_tensor(body, to_device)

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from ray_tpu.ops.layers import rms_norm, rotary_embedding
 from ray_tpu.parallel.ring_attention import plain_attention, ring_attention_local
 from ray_tpu.parallel.sharding import DEFAULT_RULES, logical_sharding
+from ray_tpu.util import flight_recorder as _fr
 from ray_tpu.util.metrics import Gauge
 from ray_tpu.util.xla_observatory import observe_compiled
 
@@ -42,6 +43,23 @@ _g_decode_buckets = Gauge(
     "ray_tpu_serve_decode_buckets",
     "Distinct padded KV lengths (compile buckets) the decode engine "
     "has served", tag_keys=("kind",))
+
+# LlamaDecodeEngine's calls taken apart (one registration site per name):
+# the device program against the host copies on either side of it. The
+# three prefill spans lie inside the scheduler's serve.prefill; the three
+# decode parts inside engine.decode (one sequence, one token).
+_sp_prefill_program = _fr.register_span("engine.prefill_program",
+                                        tag_keys=("pages",))
+_sp_prefill_kv = _fr.register_span("engine.prefill_kv", tag_keys=("pages",))
+_sp_prefill_logits = _fr.register_span("engine.prefill_logits",
+                                       tag_keys=("pages",))
+_sp_decode = _fr.register_span("engine.decode", tag_keys=("pages",))
+_sp_decode_upload = _fr.register_span("engine.decode_upload",
+                                      tag_keys=("pages",))
+_sp_decode_program = _fr.register_span("engine.decode_program",
+                                       tag_keys=("pages",))
+_sp_decode_readback = _fr.register_span("engine.decode_readback",
+                                        tag_keys=("pages",))
 
 
 @dataclass(frozen=True)
@@ -545,11 +563,18 @@ class LlamaDecodeEngine:
         np = self._np
         self.prefill_calls += 1
         T = len(tokens)
-        tpad = len(pages) * self.page_size
+        n_pages = len(pages)
+        tpad = n_pages * self.page_size
         self._note_bucket("prefill", tpad)
+        _t = _fr.now()
         toks = np.zeros((1, tpad), np.int32)
         toks[0, :T] = tokens
-        logits, ks, vs = self._prefill_fn(self.params, jnp.asarray(toks))
+        # the reads below would wait for the results anyway: waiting here
+        # puts the device's time in its own span
+        logits, ks, vs = jax.block_until_ready(
+            self._prefill_fn(self.params, jnp.asarray(toks)))
+        _sp_prefill_program.end(_t, n_pages)
+        _t = _fr.now()
         ks = np.asarray(ks, np.float32)  # [L, 1, Tpad, nkv, hd]
         vs = np.asarray(vs, np.float32)
         for pi, page in enumerate(pages):
@@ -562,13 +587,19 @@ class LlamaDecodeEngine:
                 ks[:, 0, lo:hi], (1, 0, 2, 3))
             self.v_store[page, :hi - lo] = np.transpose(
                 vs[:, 0, lo:hi], (1, 0, 2, 3))
-        return np.asarray(logits, np.float32)[0, T - 1].copy()
+        _sp_prefill_kv.end(_t, n_pages)
+        _t = _fr.now()
+        last = np.asarray(logits, np.float32)[0, T - 1].copy()
+        _sp_prefill_logits.end(_t, n_pages)
+        return last
 
     def decode(self, pos, token, pages):
         np = self._np
         self.decode_calls += 1
-        tpad = len(pages) * self.page_size
+        n_pages = len(pages)
+        tpad = n_pages * self.page_size
         self._note_bucket("decode", tpad)
+        _t_call = _t = _fr.now()
         # gather [n_seq_pages, page_size, L, nkv, hd] -> [L, Tpad, nkv, hd]
         kc = np.transpose(
             self.k_store[pages].reshape(tpad, *self.k_store.shape[2:]),
@@ -576,13 +607,23 @@ class LlamaDecodeEngine:
         vc = np.transpose(
             self.v_store[pages].reshape(tpad, *self.v_store.shape[2:]),
             (1, 0, 2, 3))
-        logits, kn, vn = self._decode_fn(
+        # the program cannot start before its inputs are on the device,
+        # and the reads wait for its results: the two waits add none
+        kc, vc = jax.block_until_ready((jnp.asarray(kc), jnp.asarray(vc)))
+        _sp_decode_upload.end(_t, n_pages)
+        _t = _fr.now()
+        logits, kn, vn = jax.block_until_ready(self._decode_fn(
             self.params, jnp.asarray([int(token)], jnp.int32),
-            jnp.int32(pos), jnp.asarray(kc), jnp.asarray(vc))
+            jnp.int32(pos), kc, vc))
+        _sp_decode_program.end(_t, n_pages)
+        _t = _fr.now()
         pg, off = divmod(pos, self.page_size)
         self.k_store[pages[pg], off] = np.asarray(kn, np.float32)
         self.v_store[pages[pg], off] = np.asarray(vn, np.float32)
-        return np.asarray(logits, np.float32).copy()
+        out = np.asarray(logits, np.float32).copy()
+        _sp_decode_readback.end(_t, n_pages)
+        _sp_decode.end(_t_call, n_pages)
+        return out
 
     def copy_page(self, src: int, dst: int) -> None:
         self.k_store[dst] = self.k_store[src]

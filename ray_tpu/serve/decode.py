@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from collections import OrderedDict, deque
 from typing import Dict, List, Optional, Tuple
 
@@ -46,10 +47,20 @@ from ray_tpu.serve.kv_cache import (
 from ray_tpu.serve import observability as _obs
 from ray_tpu.util import flight_recorder as _fr
 
-# one registration site per span name (graftlint metrics-hygiene)
-_sp_prefill = _fr.register_span("serve.prefill", tag_keys=("deployment",))
+# one registration site per span name (graftlint metrics-hygiene).
+# A request's way to its first token is one span per stop, joined by
+# ``corr`` (the stream loop's dag.stream_ingress comes before them); the
+# two waits occur once per request and are short when the replica is
+# idle, so the duration floor must not pick which requests are counted.
+_sp_sched_wait = _fr.register_span(
+    "serve.sched_wait", tag_keys=("deployment", "corr"), floor_exempt=True)
+_sp_prefill = _fr.register_span("serve.prefill",
+                                tag_keys=("deployment", "corr"))
+_sp_first_token_hold = _fr.register_span(
+    "serve.first_token_hold", tag_keys=("deployment", "corr"),
+    floor_exempt=True)
 _sp_decode_step = _fr.register_span("serve.decode_step",
-                                    tag_keys=("deployment",))
+                                    tag_keys=("deployment", "tokens"))
 
 _GAUGE_INTERVAL_S = 0.25
 
@@ -113,7 +124,7 @@ class DecodeScheduler:
         self.max_batch = max(1, int(max_batch))
         self.max_tokens_cap = max_tokens_cap
         self._lock = threading.Lock()
-        self.waiting: deque = deque()           # (corr, req, eager)
+        self.waiting: deque = deque()   # (corr, req, eager, t_submit)
         self.running: "OrderedDict[object, _Seq]" = OrderedDict()
         self._eager_out: Dict[object, deque] = {}
         self._next_gauge = 0.0
@@ -135,7 +146,7 @@ class DecodeScheduler:
         with self._lock:
             if eager:
                 self._eager_out.setdefault(corr, deque())
-            self.waiting.append((corr, req, eager))
+            self.waiting.append((corr, req, eager, _fr.now()))
         return None
 
     def drain_eager(self, corr) -> List[tuple]:
@@ -175,12 +186,18 @@ class DecodeScheduler:
         while any sequence is running or waiting."""
         with self._lock:
             replies: List[tuple] = []
-            self._admit_locked(replies)
+            admitted: List[tuple] = []  # (corr, when its prefill ended)
+            self._admit_locked(replies, admitted)
             self._decode_iteration_locked(replies)
             self.steps += 1
             self._flush_gauges_locked()
             out = [r for r in replies if not self._route_eager(r)]
             active = bool(self.running) or bool(self.waiting)
+            # a first token leaves only now, with the whole step's
+            # replies: behind every later prefill of this step and one
+            # decode call per running sequence
+            for corr, t_first in admitted:
+                _sp_first_token_hold.end(t_first, self.deployment, corr)
             return out, active
 
     def _route_eager(self, reply: tuple) -> bool:
@@ -192,8 +209,6 @@ class DecodeScheduler:
         return True
 
     def _flush_gauges_locked(self) -> None:
-        import time
-
         now = time.monotonic()
         if now < self._next_gauge:
             return
@@ -205,13 +220,16 @@ class DecodeScheduler:
 
     # -------------------------------------------------------- admission
 
-    def _admit_locked(self, replies: List[tuple]) -> None:
+    def _admit_locked(self, replies: List[tuple],
+                      admitted: List[tuple]) -> None:
         """Admit waiting prefills into the RUNNING batch, prefix-cache
         first. A prefill that cannot get pages (even after evicting idle
         prefixes) stays queued — admission stops for this iteration so
-        arrival order is preserved under memory pressure."""
+        arrival order is preserved under memory pressure. ``admitted``
+        collects ``(corr, t)`` of each sequence admitted, ``t`` the
+        moment its first token existed."""
         while self.waiting and len(self.running) < self.max_batch:
-            corr, req, eager = self.waiting[0]
+            corr, req, eager, t_submit = self.waiting[0]
             prompt = req["prompt"]
             key = tuple(prompt)
             n_prompt = len(prompt)
@@ -256,8 +274,14 @@ class DecodeScheduler:
             seq.generated.append(first)
             self.running[corr] = seq
             self.admitted += 1
-            _sp_prefill.end(_t0, self.deployment)
-            seq.last_chunk_ts = _fr.now()
+            if t_submit:
+                _sp_sched_wait.end_at(t_submit, _t0 - t_submit,
+                                      self.deployment, corr)
+            _sp_prefill.end(_t0, self.deployment, corr)
+            # the ITL anchor is the monotonic clock itself, not the
+            # recorder's (which reads 0.0 when the recorder is off)
+            seq.last_chunk_ts = time.monotonic()
+            admitted.append((corr, seq.last_chunk_ts))
             if _obs.enabled():
                 _obs.TOKENS_GENERATED.inc(
                     tag_key=_obs.dep_key(self.deployment))
@@ -312,7 +336,7 @@ class DecodeScheduler:
             seq.pos += 1
             nxt = int(np.argmax(logits))
             seq.generated.append(nxt)
-            _now = _fr.now()
+            _now = time.monotonic()
             if seq.last_chunk_ts is not None:
                 itl_samples.append(_now - seq.last_chunk_ts)
             seq.last_chunk_ts = _now
@@ -322,7 +346,7 @@ class DecodeScheduler:
                                            len(seq.generated) - 1)))
             if self._finished(seq, nxt):
                 self._retire_locked(seq, replies)
-        _sp_decode_step.end(_t0, self.deployment)
+        _sp_decode_step.end(_t0, self.deployment, n_tokens)
         if n_tokens and _obs.enabled():
             key = _obs.dep_key(self.deployment)
             _obs.TOKENS_GENERATED.inc(float(n_tokens), tag_key=key)
@@ -407,8 +431,6 @@ class ToyEngine:
     def prefill(self, tokens: List[int], pages: List[int]) -> np.ndarray:
         self.prefill_calls += 1
         if self.step_delay_s:
-            import time
-
             time.sleep(self.step_delay_s)
         for pos, t in enumerate(tokens):
             self._write(pos, int(t), pages)
@@ -419,8 +441,6 @@ class ToyEngine:
     def decode(self, pos: int, token: int, pages: List[int]) -> np.ndarray:
         self.decode_calls += 1
         if self.step_delay_s:
-            import time
-
             time.sleep(self.step_delay_s)
         self._write(pos, int(token), pages)
         nxt = (self._history_sum(pos + 1, pages) * 31 + pos + 1) \

@@ -72,10 +72,6 @@ _sp_compute = _fr.register_span("spmd.compute")
 # name keeps the badput ledger's compile column honest instead of
 # folding a multi-second outlier into spmd.compute
 _sp_compile = _fr.register_span("spmd.compile")
-# one-shot probe timings of the step's collective seams (see
-# make_collective_probes) — you cannot time an op inside the fused jit
-_sp_gather = _fr.register_span("spmd.gather")
-_sp_scatter = _fr.register_span("spmd.scatter")
 
 # Throughput/step-time gauges feeding the head's metrics-history rings
 # (session.report only buffers to the driver's result log) — the series
@@ -569,10 +565,10 @@ def make_collective_probes(cfg, mesh, rules=None):
     reduce-scatters a same-shaped full tree — the backward's
     psum_scatter. Each returns a scalar that depends on every
     collective's output so nothing constant-folds or DCEs away.
-    ``spmd_train_loop`` times them once per run into the
-    ``spmd.gather``/``spmd.scatter`` spans; ``timeline --attribute``
-    then shows whether the schedule hides that cost inside
-    ``spmd.compute`` (streamed) or pays it serially (upfront)."""
+    ``bench.py measure_sharded`` times them; the train loop does not
+    (a one-shot gather of the whole tree is a program the streamed step
+    never runs: what the step's collectives cost is in the device
+    trace)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -764,21 +760,6 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
     init, step_fn, data_sharding, _ = make_spmd_train_step(
         cfg, mesh, optimizer=optimizer, donate=donate, gather=gather)
     state = init(jax.random.PRNGKey(seed))
-
-    if _fr.enabled() and "fsdp" in mesh.axis_names:
-        # price the collective seams once per run (outside the fused
-        # step) so `timeline --attribute` can compare spmd.gather /
-        # spmd.scatter against spmd.compute; pure read of the params —
-        # the loop's state and step count are untouched
-        gather_probe, scatter_probe = make_collective_probes(cfg, mesh)
-        jax.block_until_ready(gather_probe(state["params"]))   # compile
-        _t = _fr.now()
-        jax.block_until_ready(gather_probe(state["params"]))
-        _sp_gather.end(_t)
-        jax.block_until_ready(scatter_probe(state["params"]))  # compile
-        _t = _fr.now()
-        jax.block_until_ready(scatter_probe(state["params"]))
-        _sp_scatter.end(_t)
 
     try:
         shard = session.get_dataset_shard("train")

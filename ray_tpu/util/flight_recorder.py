@@ -35,7 +35,10 @@ comes with a timeline. Gated by the ``RAY_TPU_FLIGHT_RECORDER`` config
 knob; spans shorter than ``flight_recorder_min_span_us`` (default
 500 us) stop at the duration compare so microsecond-rate dispatch pays
 only the clock reads — the on/off overhead is bench-gated in
-BENCH_TRACE.json (``bench_core.py --trace-bench``).
+BENCH_TRACE.json (``bench_core.py --trace-bench``). Spans registered
+``floor_exempt`` (one per REQUEST, not per dispatch: a request's queue
+waits) are recorded however short, so their median is over every
+request and not over the ones that waited.
 """
 
 from __future__ import annotations
@@ -93,7 +96,8 @@ _dump_dir: List[Optional[str]] = [None]
 _dump_window_s = [10.0]
 # duration floor (seconds): sub-floor spans cost only the clock reads.
 # Stall COUNTERS (channel.STALLS) still see every wait; instants are
-# exempt (parks already imply a ms-scale spin elapsed).
+# exempt (parks already imply a ms-scale spin elapsed), and so are spans
+# registered floor_exempt.
 _min_dur = [500e-6]
 
 
@@ -191,12 +195,14 @@ class Span:
     ring-wait paths time their stall anyway for the stall counters);
     ``instant`` records a point event."""
 
-    __slots__ = ("name", "tag_keys", "sid")
+    __slots__ = ("name", "tag_keys", "sid", "floor_exempt")
 
-    def __init__(self, name: str, tag_keys: Tuple[str, ...], sid: int):
+    def __init__(self, name: str, tag_keys: Tuple[str, ...], sid: int,
+                 floor_exempt: bool = False):
         self.name = name
         self.tag_keys = tag_keys
         self.sid = sid
+        self.floor_exempt = floor_exempt
 
     def end(self, t0: float, *tags, _mono=_mono) -> None:
         # _record() inlined and the clock bound as a default: this and
@@ -205,7 +211,7 @@ class Span:
         # dispatch rates the clock reads are all the recorder may cost.
         if t0 and _on[0]:
             dur = _mono() - t0
-            if dur >= _min_dur[0]:
+            if dur >= _min_dur[0] or self.floor_exempt:
                 i = next(_seq)
                 _slots[i & _mask] = (i, self.sid, KIND_SPAN, t0, dur,
                                      tags)
@@ -215,7 +221,7 @@ class Span:
         on = _on[0]
         if on is None:
             on = _resolve_enabled()
-        if on and dur >= _min_dur[0]:
+        if on and (dur >= _min_dur[0] or self.floor_exempt):
             i = next(_seq)
             _slots[i & _mask] = (i, self.sid, KIND_SPAN, t0, dur, tags)
             _hi[0] = i
@@ -239,26 +245,33 @@ def _sid_for(name: str) -> int:
     return zlib.crc32(name.encode())
 
 
-def register_span(name: str, tag_keys: Tuple[str, ...] = ()) -> Span:
+def register_span(name: str, tag_keys: Tuple[str, ...] = (),
+                  floor_exempt: bool = False) -> Span:
     """Register one span name with its (fixed) tag key set. Idempotent
     for an identical re-registration (module reload); a conflicting tag
     set raises — one name, one tag set, registered once (enforced
-    statically by graftlint metrics-hygiene as well)."""
+    statically by graftlint metrics-hygiene as well). ``floor_exempt``
+    records the span however short it is: for spans that occur once per
+    request, where dropping the short ones would bias every statistic
+    toward the requests that waited."""
     tag_keys = tuple(tag_keys)
     with _DEF_LOCK:
         have = _DEFS.get(name)
         if have is not None:
-            if have.tag_keys != tag_keys:
+            if have.tag_keys != tag_keys \
+                    or have.floor_exempt != floor_exempt:
                 raise ValueError(
                     f"span {name!r} already registered with tag_keys="
-                    f"{have.tag_keys!r} (got {tag_keys!r})")
+                    f"{have.tag_keys!r}, floor_exempt="
+                    f"{have.floor_exempt} (got {tag_keys!r}, "
+                    f"{floor_exempt})")
             return have
         sid = _sid_for(name)
         for sp in _DEFS.values():
             if sp.sid == sid:
                 raise ValueError(
                     f"span id collision: {name!r} vs {sp.name!r}")
-        sp = Span(name, tag_keys, sid)
+        sp = Span(name, tag_keys, sid, floor_exempt)
         _DEFS[name] = sp
         return sp
 
@@ -513,6 +526,58 @@ def cluster_trace(head, include_tasks: bool = True) -> List[Dict[str, Any]]:
 # span-name groups the attribution folds over
 _PIPE_BUSY = ("pipe.fwd", "pipe.bwd", "pipe.loss_bwd")
 _RING_WAIT = ("ring.wait_read", "ring.wait_write")
+# a request's way to its first token inside a decode replica, in order:
+# (report key, span, human label). The three engine parts lie inside
+# serve.prefill.
+_TTFT_PARTS = (
+    ("ingress", "dag.stream_ingress", "in the lane's ring"),
+    ("sched_wait", "serve.sched_wait", "scheduler wait"),
+    ("prefill", "serve.prefill", "prefill"),
+    ("prefill_program", "engine.prefill_program", "  device program"),
+    ("prefill_kv", "engine.prefill_kv", "  keys/values to pages"),
+    ("prefill_logits", "engine.prefill_logits", "  logits to host"),
+    ("first_token_hold", "serve.first_token_hold", "first token held"),
+)
+
+
+def _serving_attribution(by_name: Dict[str, List[dict]]
+                         ) -> Dict[str, Dict[str, Any]]:
+    """Per decode deployment: median and p95 (ms) of each stop on a
+    request's way to its first token, and tokens per decode step. The
+    scheduler's spans name their deployment; the stream loop's and the
+    engine's do not, and go to the deployment whose scheduler recorded
+    in the same process (a replica serves one)."""
+    dep_of: Dict[Any, str] = {}
+    for name in ("serve.sched_wait", "serve.prefill", "serve.decode_step"):
+        for ev in by_name.get(name, ()):
+            args = ev.get("args") or {}
+            dep_of.setdefault(args.get("source"),
+                              str(args.get("deployment", "")))
+    out: Dict[str, Dict[str, Any]] = {}
+    for key, name, _label in _TTFT_PARTS:
+        durs: Dict[str, List[float]] = {}
+        for ev in by_name.get(name, ()):
+            args = ev.get("args") or {}
+            dep = args.get("deployment")
+            if dep is None:
+                dep = dep_of.get(args.get("source"))
+            if dep is not None:
+                durs.setdefault(str(dep), []).append(
+                    ev.get("dur", 0.0) / 1e3)
+        for dep, ms in durs.items():
+            ms.sort()
+            out.setdefault(dep, {})[key] = {
+                "n": len(ms),
+                "p50_ms": round(ms[(len(ms) - 1) // 2], 3),
+                "p95_ms": round(ms[min(len(ms) - 1,
+                                       int(0.95 * len(ms)))], 3)}
+    for ev in by_name.get("serve.decode_step", ()):
+        args = ev.get("args") or {}
+        rec = out.setdefault(str(args.get("deployment", "")), {})
+        rec["decode_steps"] = rec.get("decode_steps", 0) + 1
+        rec["decode_tokens"] = rec.get("decode_tokens", 0) + int(
+            args.get("tokens") or 0)
+    return out
 
 
 def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
@@ -552,8 +617,6 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     ring_stall_s = total_s(_RING_WAIT)
     ingest_s = total_s(("spmd.ingest_wait",))
     spmd_compute_s = total_s(("spmd.compute",))
-    spmd_gather_s = total_s(("spmd.gather",))
-    spmd_scatter_s = total_s(("spmd.scatter",))
     exec_s = total_s(("dag.exec",))
     serve_s = total_s(("serve.batch_drain",))
     compile_s = total_s(("spmd.compile",))
@@ -579,8 +642,6 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         "ring_stall_s": round(ring_stall_s, 6),
         "ingest_wait_s": round(ingest_s, 6),
         "spmd_compute_s": round(spmd_compute_s, 6),
-        "spmd_gather_s": round(spmd_gather_s, 6),
-        "spmd_scatter_s": round(spmd_scatter_s, 6),
         "dag_exec_s": round(exec_s, 6),
         "serve_batch_s": round(serve_s, 6),
         "compile_s": round(compile_s, 6),
@@ -590,24 +651,15 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
                 "compile_s": round(r["compile_s"], 6)}
             for p, r in sorted(xla_compile.items())},
     }
-    # spmd.gather/spmd.scatter are ONE-SHOT probe timings of the full
-    # param-tree collectives (train/spmd.py make_collective_probes),
-    # not per-step accumulations: compare them against ONE mean compute
-    # span. A streamed schedule keeps that cost overlapped inside
-    # spmd.compute instead of extending it.
-    n_spmd = len(by_name.get("spmd.compute", ()))
-    if n_spmd and (spmd_gather_s or spmd_scatter_s) and spmd_compute_s:
-        report["spmd_steps"] = n_spmd
-        report["spmd_collective_probe_s"] = round(
-            spmd_gather_s + spmd_scatter_s, 6)
-        report["spmd_collective_vs_step"] = round(
-            (spmd_gather_s + spmd_scatter_s) / (spmd_compute_s / n_spmd), 4)
     if denom:
         report["compute_pct"] = round(100.0 * eff, 2) if per_stage else \
             round(100.0 * spmd_compute_s / denom, 2)
         report["ring_stall_pct"] = round(
             100.0 * ring_stall_s / (k * denom), 2)
         report["ingest_pct"] = round(100.0 * ingest_s / denom, 2)
+    serving = _serving_attribution(by_name)
+    if serving:
+        report["serving"] = serving
     return report
 
 
@@ -632,14 +684,6 @@ def format_attribution(report: Dict[str, Any]) -> str:
     lines.append(f"ring stall         : {report['ring_stall_s']:.4f}s")
     if report.get("ingest_wait_s"):
         lines.append(f"ingest wait        : {report['ingest_wait_s']:.4f}s")
-    if report.get("spmd_gather_s"):
-        lines.append(f"param gather probe : {report['spmd_gather_s']:.4f}s")
-    if report.get("spmd_scatter_s"):
-        lines.append(f"grad scatter probe : {report['spmd_scatter_s']:.4f}s")
-    if report.get("spmd_collective_vs_step") is not None:
-        lines.append(
-            f"collectives/step   : {report['spmd_collective_vs_step']:.2f}x "
-            f"one compute span (probe cost; streamed hides it in compute)")
     if report.get("compile_s"):
         lines.append(f"compile (1st step) : {report['compile_s']:.4f}s")
     for prog, rec in (report.get("xla_compile_s") or {}).items():
@@ -651,4 +695,18 @@ def format_attribution(report: Dict[str, Any]) -> str:
         lines.append(f"dag executor busy  : {report['dag_exec_s']:.4f}s")
     if report.get("serve_batch_s"):
         lines.append(f"serve batch drain  : {report['serve_batch_s']:.4f}s")
+    for dep, rec in sorted((report.get("serving") or {}).items()):
+        lines += ["", f"where did the time to first token go: {dep}",
+                  "-" * 38]
+        for key, _name, label in _TTFT_PARTS:
+            part = rec.get(key)
+            if part:
+                lines.append(f"{label:<24}: p50 {part['p50_ms']:9.3f} ms"
+                             f"  p95 {part['p95_ms']:9.3f} ms"
+                             f"  (n={part['n']})")
+        if rec.get("decode_steps"):
+            lines.append(
+                f"{'decode steps':<24}: {rec['decode_steps']}, "
+                f"{rec['decode_tokens'] / rec['decode_steps']:.2f} "
+                f"tokens a step")
     return "\n".join(lines)

@@ -55,6 +55,8 @@ def clean_ring():
 
 SP_A = fr.register_span("test.fr_a", tag_keys=("k",))
 SP_B = fr.register_span("test.fr_b")
+SP_EXEMPT = fr.register_span("test.fr_exempt", tag_keys=("k",),
+                             floor_exempt=True)
 
 
 def _names_of(payload):
@@ -102,11 +104,31 @@ def test_duration_floor_filters_short_spans(clean_ring):
     assert len(fr.snapshot_payload()["events"]) == 4
 
 
+@pytest.mark.parametrize("how", ["end", "end_at"])
+def test_floor_exempt_span_is_recorded_however_short(clean_ring, how):
+    """A per-request span (a queue wait) is recorded at 1 us under the
+    default floor, where an ordinary span is dropped: its median must be
+    over every request, not over the ones that waited."""
+    fr.configure(min_span_us=500.0)
+    for sp in (SP_A, SP_EXEMPT):
+        if how == "end":
+            sp.end(fr.now(), "v")          # closed at once: ~1 us
+        else:
+            sp.end_at(fr.now(), 1e-6, "v")
+    snap = fr.snapshot_payload()
+    assert _names_of(snap) == ["test.fr_exempt"]
+    assert snap["events"][0][4] < 500e-6
+    with pytest.raises(ValueError):        # the flag is part of the name
+        fr.register_span("test.fr_exempt", tag_keys=("k",))
+
+
 def test_disabled_recorder_records_nothing(clean_ring):
     fr.configure(enabled=False)
     assert fr.now() == 0.0                 # begin side: one flag test
     SP_B.end(fr.now())
     SP_B.end_at(time.monotonic(), 0.5)
+    SP_EXEMPT.end_at(time.monotonic(), 0.5, "v")
+    SP_EXEMPT.end(time.monotonic(), "v")
     SP_B.instant()
     fr.configure(enabled=True)
     assert fr.snapshot_payload()["events"] == []
@@ -295,82 +317,53 @@ def test_attribute_trace_folds_step_budget():
     assert "bubble fraction" in text and "0.6000" in text
 
 
-def test_attribute_trace_spmd_collective_probes():
-    """spmd.gather/spmd.scatter probe spans fold into per-probe totals
-    and a collectives-per-compute-span ratio (the streamed-gather
-    overlap readout)."""
+def test_attribute_trace_serving_section():
+    """The spans on a request's way to its first token fold into one
+    section per decode deployment: median and p95 of each stop, the
+    engine's and the stream loop's spans (which name no deployment)
+    going to the deployment that recorded in the same process, and
+    tokens per decode step from the span's own count."""
 
-    def ev(name, ts_s, dur_s, **args):
-        return {"ph": "X", "cat": "span", "name": name,
-                "ts": ts_s * 1e6, "dur": dur_s * 1e6, "pid": "n",
-                "tid": name, "args": args}
+    def ev(name, dur_ms, source="worker:1", **args):
+        return {"ph": "X", "cat": "span", "name": name, "ts": 0.0,
+                "dur": dur_ms * 1e3, "pid": "n", "tid": name,
+                "args": dict(args, source=source)}
 
-    events = [
-        ev("spmd.gather", 0.0, 0.03),
-        ev("spmd.scatter", 0.1, 0.01),
-        ev("spmd.compute", 1.0, 0.2),
-        ev("spmd.compute", 1.3, 0.2),
-    ]
+    events = []
+    for corr, wait in enumerate([0.0, 1.0, 200.0]):
+        events += [
+            ev("dag.stream_ingress", wait, method="m", corr=corr),
+            ev("serve.sched_wait", 2 * wait, deployment="LM", corr=corr),
+            ev("serve.prefill", 300.0, deployment="LM", corr=corr),
+            ev("engine.prefill_program", 25.0, pages=4),
+            ev("engine.prefill_kv", 175.0, pages=4),
+            ev("engine.prefill_logits", 100.0, pages=4),
+            ev("serve.first_token_hold", 90.0, deployment="LM", corr=corr),
+        ]
+    events += [ev("serve.decode_step", 100.0, deployment="LM", tokens=2),
+               ev("serve.decode_step", 50.0, deployment="LM", tokens=1),
+               # another process's stream lane, serving no decode
+               ev("dag.stream_ingress", 7.0, source="worker:2",
+                  method="other", corr=0)]
     rep = fr.attribute_trace(events)
-    assert rep["spmd_gather_s"] == pytest.approx(0.03)
-    assert rep["spmd_scatter_s"] == pytest.approx(0.01)
-    assert rep["spmd_steps"] == 2
-    assert rep["spmd_collective_probe_s"] == pytest.approx(0.04)
-    # probe total / mean compute span = 0.04 / 0.2
-    assert rep["spmd_collective_vs_step"] == pytest.approx(0.2)
+    lm = rep["serving"]["LM"]
+    assert list(rep["serving"]) == ["LM"]
+    assert lm["ingress"] == {"n": 3, "p50_ms": 1.0, "p95_ms": 200.0}
+    assert lm["sched_wait"]["p50_ms"] == 2.0
+    assert lm["sched_wait"]["p95_ms"] == 400.0
+    assert lm["prefill"]["p50_ms"] == 300.0
+    assert lm["prefill_program"]["p50_ms"] == 25.0
+    assert lm["prefill_kv"]["p50_ms"] == 175.0
+    assert lm["prefill_logits"]["p50_ms"] == 100.0
+    assert lm["first_token_hold"]["n"] == 3
+    assert (lm["decode_steps"], lm["decode_tokens"]) == (2, 3)
     text = fr.format_attribution(rep)
-    assert "param gather probe" in text
-    assert "grad scatter probe" in text
-    assert "collectives/step" in text
-
-
-def test_streamed_gather_overlaps_into_compute(clean_ring):
-    """End-to-end proof of the streamed-gather tentpole: an fsdp-mesh
-    ``spmd_train_loop`` run prices the param-gather / grad-scatter
-    collectives as one-shot ``spmd.gather``/``spmd.scatter`` probe
-    spans, and the streamed schedule's steady-state ``spmd.compute``
-    span is NOT extended by that gather span sum — the per-layer
-    gathers hide inside compute instead of serializing before it.
-    The first step records as ``spmd.compile`` (the badput ledger's
-    compile column), so 4 steps land as 1 compile + 3 compute spans;
-    steady-state = the fastest compute span. Tolerance is generous
-    because CPU virtual devices time-slice."""
-    from ray_tpu.train.session import TrainContext, set_context
-    from ray_tpu.train.spmd import spmd_train_loop
-
-    def run(gather):
-        fr.reset_for_tests()
-        fr.configure(enabled=True, min_span_us=0.0)
-        set_context(TrainContext(1, 0, 0, 1, 0))
-        try:
-            spmd_train_loop({"steps": 4, "batch_per_device": 1,
-                             "seq": 32, "mesh": "fsdp=2",
-                             "report_every": 4, "gather": gather,
-                             "distinct_batches": 1})
-        finally:
-            set_context(None)
-        events = fr.build_span_events([fr.snapshot_payload()])
-        rep = fr.attribute_trace(events)
-        spans = sorted(e["dur"] / 1e6 for e in events
-                       if e.get("name") == "spmd.compute")
-        return rep, spans
-
-    up_rep, up_spans = run("upfront")
-    st_rep, st_spans = run("streamed")
-    for rep in (up_rep, st_rep):
-        # the one-shot probes and the per-step spans all landed: step 0
-        # under spmd.compile, the steady-state steps under spmd.compute
-        assert rep["spmd_steps"] == 3
-        assert rep["compile_s"] > 0
-        assert rep["spmd_gather_s"] > 0
-        assert rep["spmd_scatter_s"] > 0
-        assert rep["spmd_collective_vs_step"] is not None
-    probes = st_rep["spmd_gather_s"] + st_rep["spmd_scatter_s"]
-    st_step, up_step = st_spans[0], up_spans[0]
-    assert st_step <= up_step + probes + 0.5 * (up_step + probes), (
-        f"streamed compute span {st_step:.4f}s exceeds upfront "
-        f"{up_step:.4f}s + gather span sum {probes:.4f}s (with 50% "
-        f"slack) — gathers look serialized, not overlapped")
+    assert "where did the time to first token go: LM" in text
+    assert "scheduler wait" in text and "first token held" in text
+    assert "1.50 tokens a step" in text
+    # a trace with no decode replica has no such section
+    assert "serving" not in fr.attribute_trace(
+        [ev("spmd.compute", 10.0)])
 
 
 # --------------------------------------------------------------------------- #

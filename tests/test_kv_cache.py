@@ -320,3 +320,50 @@ class TestLlamaEngine:
         warm = _json.loads(_run_all(sched, [("w", req)])["w"][-1][1])
         assert warm["cached_prefix"] is True
         assert warm["tokens"] == cold["tokens"]
+
+    def test_prefill_and_decode_are_taken_apart_into_spans(self, engine):
+        """The engine's calls as flight-recorder spans: the device
+        program and the host copies on either side of it. The three
+        prefill parts lie inside the scheduler's ``serve.prefill`` and
+        sum to no more than it; the three decode parts lie inside
+        ``engine.decode``; all carry the call's page count."""
+        from ray_tpu.util import flight_recorder as fr
+
+        saved_on, saved_min = fr._on[0], fr._min_dur[0]
+        fr.reset_for_tests()
+        fr.configure(enabled=True, min_span_us=0.0)
+        try:
+            prompt = [3, 1, 4, 1, 5, 9]  # two pages of four
+            _run_all(DecodeScheduler(engine, deployment="lm"),
+                     [("c", {"prompt": prompt, "max_tokens": 3})])
+            events = fr.build_span_events([fr.snapshot_payload()])
+        finally:
+            fr.reset_for_tests()
+            fr._on[0], fr._min_dur[0] = saved_on, saved_min
+        spans = {}  # name -> [(start s, duration s, tags)]
+        for e in events:
+            tags = {k: v for k, v in e["args"].items() if k != "source"}
+            spans.setdefault(e["name"], []).append(
+                (e["ts"] / 1e6, e["dur"] / 1e6, tags))
+
+        def inside(inner, outer):
+            return outer[0] - 1e-5 <= inner[0] \
+                and inner[0] + inner[1] <= outer[0] + outer[1] + 1e-5
+
+        (whole,) = spans["serve.prefill"]
+        assert whole[2] == {"deployment": "lm", "corr": "c"}
+        parts = [spans[f"engine.prefill_{p}"]
+                 for p in ("program", "kv", "logits")]
+        assert all(len(p) == 1 and inside(p[0], whole)
+                   and p[0][2] == {"pages": 2} for p in parts)
+        assert sum(p[0][1] for p in parts) <= whole[1]
+        # in order, none overlapping the next
+        assert parts[0][0][0] + parts[0][0][1] <= parts[1][0][0] + 1e-5
+        assert parts[1][0][0] + parts[1][0][1] <= parts[2][0][0] + 1e-5
+        calls = spans["engine.decode"]
+        assert len(calls) == 2 == engine.decode_calls  # tokens 2 and 3
+        for name in ("upload", "program", "readback"):
+            got = spans[f"engine.decode_{name}"]
+            assert len(got) == 2
+            assert all(inside(g, c) and g[2] == c[2] == {"pages": 2}
+                       for g, c in zip(got, calls))

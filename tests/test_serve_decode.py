@@ -123,6 +123,179 @@ class TestIterationLevelAdmission:
 
 
 # --------------------------------------------------------------------------
+# a request's way to its first token, as flight-recorder spans
+# --------------------------------------------------------------------------
+
+
+@pytest.fixture
+def recorder():
+    """Fresh, enabled recorder with the DEFAULT floor: the per-request
+    spans must not need a floor of zero to be seen."""
+    from ray_tpu.util import flight_recorder as fr
+
+    saved_on, saved_min = fr._on[0], fr._min_dur[0]
+    fr.reset_for_tests()
+    fr.configure(enabled=True, min_span_us=500.0)
+    yield fr
+    fr.reset_for_tests()
+    fr._on[0], fr._min_dur[0] = saved_on, saved_min
+
+
+def _recorded(fr, name):
+    """This process's spans called ``name``, through the recorder's own
+    exporter: [(start s, duration s, tags dict)]."""
+    return [(e["ts"] / 1e6, e["dur"] / 1e6, e["args"])
+            for e in fr.build_span_events([fr.snapshot_payload()])
+            if e["name"] == name]
+
+
+class TestTimeToFirstTokenSpans:
+    def _run_two(self, delay=0.01):
+        from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
+
+        sched = DecodeScheduler(
+            ToyEngine(n_pages=64, page_size=4, step_delay_s=delay),
+            deployment="toy", max_batch=4)
+        sched.submit(7, {"prompt": [1, 2, 3], "max_tokens": 3})
+        sched.submit(8, {"prompt": [4, 5], "max_tokens": 4})
+        active = True
+        while active:
+            _, active = sched.step()
+        return sched
+
+    def test_second_request_waits_out_the_first_prefill(self, recorder):
+        """Two requests submitted together are prefilled back to back in
+        one step: the second's scheduler wait holds the first's whole
+        prefill, and the first's (an idle scheduler: microseconds) is
+        recorded all the same."""
+        self._run_two()
+        wait = {t["corr"]: d
+                for _, d, t in _recorded(recorder, "serve.sched_wait")}
+        prefill = {t["corr"]: d
+                   for _, d, t in _recorded(recorder, "serve.prefill")}
+        assert sorted(wait) == sorted(prefill) == [7, 8]
+        assert wait[8] >= prefill[7] >= 0.01
+        assert wait[7] < prefill[7]
+        assert all(t["deployment"] == "toy"
+                   for _, _, t in _recorded(recorder, "serve.sched_wait"))
+
+    def test_first_token_is_held_to_the_end_of_its_step(self, recorder):
+        """One hold per request, from the end of its prefill to the
+        step's return: the first request's holds the second's prefill
+        and both decode calls of the step, the second's the decode calls
+        alone."""
+        self._run_two()
+        holds = _recorded(recorder, "serve.first_token_hold")
+        assert sorted(t["corr"] for _, _, t in holds) == [7, 8]
+        hold = {t["corr"]: (t0, d) for t0, d, t in holds}
+        ends = {t["corr"]: t0 + d
+                for t0, d, t in _recorded(recorder, "serve.prefill")}
+        for corr in (7, 8):  # it starts where the prefill ended
+            assert hold[corr][0] == pytest.approx(ends[corr], abs=1e-3)
+        assert hold[8][1] >= 2 * 0.01          # two decode calls
+        assert hold[7][1] >= hold[8][1] + 0.01  # and the other prefill
+        # both were handed back at the same moment
+        assert hold[7][0] + hold[7][1] == pytest.approx(
+            hold[8][0] + hold[8][1], abs=1e-3)
+
+    def test_decode_step_spans_count_their_tokens(self, recorder):
+        sched = self._run_two()
+        steps = _recorded(recorder, "serve.decode_step")
+        generated = sum(n for _, n in sched.retired)
+        # every token but each request's first came from a decode step
+        assert sum(t["tokens"] for _, _, t in steps) \
+            == generated - len(sched.retired) == 5
+        assert [t["tokens"] for _, _, t in steps] == [2, 2, 1]
+
+    def test_itl_anchor_does_not_need_the_recorder(self, recorder):
+        """The gap between tokens is taken from the monotonic clock: with
+        the recorder off the anchors are still real times, not 0.0."""
+        from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
+
+        recorder.configure(enabled=False)
+        sched = DecodeScheduler(ToyEngine(n_pages=64, page_size=4))
+        sched.submit("a", {"prompt": [1], "max_tokens": 8})
+        before = time.monotonic()
+        sched.step()
+        assert before <= sched.running["a"].last_chunk_ts \
+            <= time.monotonic()
+        assert recorder.snapshot_payload()["events"] == []
+
+    def test_stream_lane_spans_share_the_request_corr(self, recorder):
+        """Through the worker's stream loop over a real ring: ingress,
+        scheduler wait, prefill and hold of one request carry the lane's
+        arrival counter, and a request that arrives while the replica is
+        busy is seen to have sat in the ring."""
+        import threading
+        import uuid
+
+        from ray_tpu.core.worker_runtime import WorkerRuntime
+        from ray_tpu.experimental import channel as chan
+        from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
+
+        sched = DecodeScheduler(
+            ToyEngine(n_pages=64, page_size=4, step_delay_s=0.01),
+            deployment="toy")
+
+        def invoke(args):
+            (entries,) = args
+            for corr, value in entries:
+                assert sched.submit(corr, value) is None
+            return sched.step()
+
+        uid = uuid.uuid4().hex[:8]
+        rings = []
+        for side in ("in", "out"):
+            path = chan.channel_path(f"ttft_{uid}_{side}")
+            rings.append((chan.ShmChannel(path, 4096, create=True,
+                                          n_slots=8),
+                          chan.ShmChannel(path)))
+        (in_w, in_r), (out_w, out_r) = rings
+        loop = threading.Thread(
+            target=WorkerRuntime._compiled_stream_loop,
+            args=(None, in_r, [out_w], lambda *a: None, invoke,
+                  lambda e: repr(e).encode(), 4, False, "decode"),
+            daemon=True)
+        try:
+            loop.start()
+            for prompt in ([1, 2, 3], [4, 5]):
+                in_w.write(json.dumps(
+                    {"prompt": prompt, "max_tokens": 2}).encode(),
+                    tag=chan.TAG_BYTES)
+                # the second lands mid-step: once the first's prefill
+                # has begun, its decode call (10 ms) is still to come
+                deadline = time.monotonic() + 30
+                while not sched.engine.prefill_calls:
+                    assert time.monotonic() < deadline
+                    time.sleep(0.0005)
+            finals = 0
+            while finals < 2:
+                _tag, frame = out_r.read(timeout=30)
+                _corr, flags, _body = chan.unpack_stream_frame(frame)
+                assert not flags & chan.STREAM_F_ERROR
+                finals += bool(flags & chan.STREAM_F_FINAL)
+            in_w.write(b"", tag=chan.TAG_STOP)
+            loop.join(10)
+            assert not loop.is_alive()
+        finally:
+            for w, r in rings:
+                r.close()
+                w.close(unlink=True)
+        by_span = {name: {t["corr"]: d
+                          for _, d, t in _recorded(recorder, name)}
+                   for name in ("dag.stream_ingress", "serve.sched_wait",
+                                "serve.prefill", "serve.first_token_hold")}
+        for name, by_corr in by_span.items():
+            assert sorted(by_corr) == [0, 1], name
+        ingress = _recorded(recorder, "dag.stream_ingress")
+        assert all(t["method"] == "decode" for _, _, t in ingress)
+        # request 1 was published with a decode call of 10 ms to come
+        assert by_span["dag.stream_ingress"][1] >= 0.01
+        assert by_span["dag.stream_ingress"][0] \
+            < by_span["dag.stream_ingress"][1]
+
+
+# --------------------------------------------------------------------------
 # streaming over the compiled plane
 # --------------------------------------------------------------------------
 
