@@ -45,17 +45,23 @@ _g_decode_buckets = Gauge(
     "has served", tag_keys=("kind",))
 
 # LlamaDecodeEngine's calls taken apart (one registration site per name):
-# the device program against the host copies on either side of it. The
+# the device program against what the host does on either side of it. The
 # three prefill spans lie inside the scheduler's serve.prefill; the three
-# decode parts inside engine.decode (one sequence, one token).
+# decode parts inside engine.decode (one sequence, one token). The pages
+# live on the device, so three of them time what is left of a host copy
+# (microseconds): floor_exempt, one record per call, so that they read as
+# a small number and not as a missing one.
 _sp_prefill_program = _fr.register_span("engine.prefill_program",
                                         tag_keys=("pages",))
-_sp_prefill_kv = _fr.register_span("engine.prefill_kv", tag_keys=("pages",))
+_sp_prefill_kv = _fr.register_span("engine.prefill_kv", tag_keys=("pages",),
+                                   floor_exempt=True)
 _sp_prefill_logits = _fr.register_span("engine.prefill_logits",
-                                       tag_keys=("pages",))
+                                       tag_keys=("pages",),
+                                       floor_exempt=True)
 _sp_decode = _fr.register_span("engine.decode", tag_keys=("pages",))
 _sp_decode_upload = _fr.register_span("engine.decode_upload",
-                                      tag_keys=("pages",))
+                                      tag_keys=("pages",),
+                                      floor_exempt=True)
 _sp_decode_program = _fr.register_span("engine.decode_program",
                                        tag_keys=("pages",))
 _sp_decode_readback = _fr.register_span("engine.decode_readback",
@@ -429,11 +435,53 @@ def _layer_kv(cfg: LlamaConfig, x, p, positions):
     return x, kk, vv
 
 
-def prefill_with_cache(cfg: LlamaConfig, params, tokens):
-    """tokens [1, T] int32 (right-padded is fine: causal masking keeps
-    pad garbage out of real positions) -> (logits [1, T, vocab] fp32,
-    k [L, 1, T, n_kv, head_dim], v [...]) — k/v are post-RoPE, i.e. the
-    bytes the paged cache stores."""
+def _page_slab(pages, page):
+    """The ``[L, 1, page_size, n_kv, head_dim]`` slab of one physical page
+    (``page`` traced) of a store laid out ``[L, n_pages, ...]``."""
+    L, _, ps, nkv, hd = pages.shape
+    return jax.lax.dynamic_slice(pages, (0, page, 0, 0, 0),
+                                 (L, 1, ps, nkv, hd))
+
+
+def _read_pages(pages, page_ids):
+    """The ``n`` physical pages ``page_ids``, in that order, as one
+    ``[L, n * page_size, n_kv, head_dim]`` view. One dynamic slice per
+    page (``n`` is static) and not ``pages[:, page_ids]``: XLA moves the
+    layers' conversion to the compute dtype in front of a gather and
+    then converts the WHOLE store on every call (6.8 of a 29.5 ms decode
+    call at 128 pages; my chip run, PR 25)."""
+    L, _, ps, nkv, hd = pages.shape
+    slabs = [_page_slab(pages, page_ids[i])
+             for i in range(page_ids.shape[0])]
+    return jnp.concatenate(slabs, axis=1).reshape(L, -1, nkv, hd)
+
+
+def _write_pages(pages, kv, page_ids):
+    """Write ``kv`` [L, n * page_size, n_kv, head_dim] into the ``n``
+    physical pages ``page_ids`` of ``pages``. One dynamic-update-slice per
+    page (``n`` is static): with the store donated each is in place."""
+    L, _, ps, nkv, hd = pages.shape
+    kv = kv.astype(pages.dtype).reshape(L, -1, ps, nkv, hd)
+    for i in range(kv.shape[1]):
+        pages = jax.lax.dynamic_update_slice(
+            pages, kv[:, i:i + 1], (0, page_ids[i], 0, 0, 0))
+    return pages
+
+
+def prefill_with_cache(cfg: LlamaConfig, params, k_pages, v_pages, tokens,
+                       page_ids, last):
+    """Prefill one sequence into its pages, inside the program.
+
+    ``k_pages`` / ``v_pages``: the engine's page stores ``[L, n_pages,
+    page_size, n_kv, head_dim]`` (donated by the engine, updated in place);
+    ``tokens`` [1, n * page_size] int32, right-padded (causal masking
+    keeps pad garbage out of real positions); ``page_ids`` [n] int32;
+    ``last`` int32 scalar, the last real position. Returns ``(k_pages,
+    v_pages, logits [vocab] fp32)``: the post-RoPE keys and values of ALL
+    ``n * page_size`` positions are written, the pad positions of the last
+    page included (they hold the pad token's keys: finite, and masked by
+    every decode until the sequence itself overwrites them), and the head
+    is applied to position ``last`` alone."""
     B, T = tokens.shape
     x = embed_tokens(cfg, params, tokens, None)
     positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
@@ -443,10 +491,14 @@ def prefill_with_cache(cfg: LlamaConfig, params, tokens):
         return h, (kk, vv)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
+    k_pages = _write_pages(k_pages, ks[:, 0], page_ids)
+    v_pages = _write_pages(v_pages, vs[:, 0], page_ids)
+    # final_norm and the head are per position: one row, not T
+    x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x.astype(cfg.dtype)
               @ _head(cfg, params).astype(cfg.dtype)).astype(jnp.float32)
-    return logits, ks, vs
+    return k_pages, v_pages, logits[0, 0]
 
 
 def _layer_decode(cfg: LlamaConfig, x, p, positions, k_cache, v_cache,
@@ -485,13 +537,22 @@ def _layer_decode(cfg: LlamaConfig, x, p, positions, k_cache, v_cache,
     return x, kk[:, 0], vv[:, 0]
 
 
-def decode_step_with_cache(cfg: LlamaConfig, params, token, pos, k_cache,
-                           v_cache):
-    """One decode step. token [1] int32; pos: scalar int32 (the KV write
-    position = tokens so far); k/v_cache [L, Tpad, n_kv, head_dim]
-    page-padded views -> (logits [vocab] fp32, k_new [L, n_kv, head_dim],
-    v_new [...]). pos is traced, so one compilation covers every step at
-    a given padded length — recompiles are bounded by the page count."""
+def decode_step_with_cache(cfg: LlamaConfig, params, k_pages, v_pages,
+                           token, pos, page_ids):
+    """One decode step of one sequence against the page stores.
+
+    ``token`` [1] int32; ``pos`` int32 scalar (the KV write position =
+    tokens so far); ``page_ids`` [n] int32, the sequence's page table in
+    order. The table's pages are gathered on the device into the
+    ``[L, n * page_size, n_kv, head_dim]`` view (positions >= ``pos`` are
+    masked), and the new position's keys and values are written at
+    ``(page_ids[pos // page_size], pos % page_size)``. Returns
+    ``(k_pages, v_pages, logits [vocab] fp32)``. ``pos`` and the page ids
+    are traced, so one compilation covers every step at a given page
+    count."""
+    ps = k_pages.shape[2]
+    k_cache = _read_pages(k_pages, page_ids)
+    v_cache = _read_pages(v_pages, page_ids)
     x = embed_tokens(cfg, params, token[None, :], None)
     positions = jnp.full((1, 1), pos, dtype=jnp.int32)
 
@@ -505,7 +566,22 @@ def decode_step_with_cache(cfg: LlamaConfig, params, token, pos, k_cache,
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x.astype(cfg.dtype)
               @ _head(cfg, params).astype(cfg.dtype)).astype(jnp.float32)
-    return logits[0, 0], kns[:, 0], vns[:, 0]
+    at = (0, page_ids[pos // ps], pos % ps, 0, 0)
+    k_pages = jax.lax.dynamic_update_slice(
+        k_pages, kns[:, :, None].astype(k_pages.dtype), at)
+    v_pages = jax.lax.dynamic_update_slice(
+        v_pages, vns[:, :, None].astype(v_pages.dtype), at)
+    return k_pages, v_pages, logits[0, 0]
+
+
+def copy_page_in_stores(k_pages, v_pages, src, dst):
+    """Physical page ``src`` duplicated into ``dst`` (both traced) in both
+    stores."""
+    at = (0, dst, 0, 0, 0)
+    return (jax.lax.dynamic_update_slice(k_pages, _page_slab(k_pages, src),
+                                         at),
+            jax.lax.dynamic_update_slice(v_pages, _page_slab(v_pages, src),
+                                         at))
 
 
 class LlamaDecodeEngine:
@@ -513,15 +589,25 @@ class LlamaDecodeEngine:
     engine protocol :class:`ray_tpu.serve.decode.DecodeScheduler` drives
     (prefill/decode/copy_page + pool/prefix_cache/page_size).
 
-    Physical pages live in two numpy stores indexed by pool page id:
-    ``[n_pages, page_size, L, n_kv, head_dim]``. prefill scatters the
-    scan's k/v into pages; decode gathers the sequence's page table into
-    a contiguous page-padded view (positions beyond the true length are
-    masked inside the kernel, so padded-length compilations are reused
-    across sequences and steps)."""
+    Physical pages live ON THE DEVICE, in two float32 arrays indexed by
+    pool page id: ``k_pages`` / ``v_pages`` ``[L, n_pages, page_size,
+    n_kv, head_dim]`` (``2 * n_pages * page_size * L * n_kv * head_dim *
+    4`` bytes beside the weights). They are read and written only inside
+    three jitted programs that take them donated and return them: prefill
+    writes the scan's k/v into the pages it is given, decode gathers the
+    sequence's page table into a page-padded view (positions beyond the
+    true length are masked, so a compilation per page count serves every
+    sequence and step) and writes the new position, copy_page duplicates
+    one page. A call moves token ids and page ids in and one ``[vocab]``
+    row of float32 logits out.
+
+    One caller at a time (the scheduler's lock covers a whole iteration):
+    a call hands the stores to its program and takes the returned ones."""
 
     def __init__(self, cfg: Optional[LlamaConfig] = None, params=None, *,
                  n_pages: int = 64, page_size: int = 8, seed: int = 0):
+        import numpy as np
+
         from ray_tpu.serve.kv_cache import PagePool, PrefixCache
 
         self.cfg = cfg or LlamaConfig.debug()
@@ -535,22 +621,28 @@ class LlamaDecodeEngine:
         self.page_size = int(page_size)
         self.pool = PagePool(n_pages, page_size)
         self.prefix_cache = PrefixCache(self.pool)
-        c = self.cfg
-        shape = (n_pages, page_size, c.n_layers, c.n_kv_heads, c.head_dim)
-        import numpy as np
-
         self._np = np
-        self.k_store = np.zeros(shape, np.float32)
-        self.v_store = np.zeros(shape, np.float32)
+        c = self.cfg
+        shape = (c.n_layers, n_pages, page_size, c.n_kv_heads, c.head_dim)
+        self.k_pages = jnp.zeros(shape, jnp.float32)
+        self.v_pages = jnp.zeros(shape, jnp.float32)
         self._prefill_fn = observe_compiled(
-            jax.jit(partial(prefill_with_cache, self.cfg)),
+            jax.jit(partial(prefill_with_cache, self.cfg),
+                    donate_argnums=(1, 2)),
             "llama.prefill")
         self._decode_fn = observe_compiled(
-            jax.jit(partial(decode_step_with_cache, self.cfg)),
+            jax.jit(partial(decode_step_with_cache, self.cfg),
+                    donate_argnums=(1, 2)),
             "llama.decode")
+        self._copy_fn = observe_compiled(
+            jax.jit(copy_page_in_stores, donate_argnums=(0, 1)),
+            "llama.copy_page")
         self.prefill_calls = 0
         self.decode_calls = 0
         self._buckets: Dict[str, set] = {"prefill": set(), "decode": set()}
+        # compiled here: a server warms prefill and decode by running them,
+        # but may never copy a page before its first prefix hit
+        self.copy_page(0, 0)
 
     def _note_bucket(self, kind: str, tpad: int) -> None:
         buckets = self._buckets[kind]
@@ -559,37 +651,37 @@ class LlamaDecodeEngine:
             _g_decode_buckets.set(float(len(buckets)),
                                   tags={"kind": kind})
 
+    # Every input below is a numpy array of a fixed dtype, and nothing on a
+    # call's path is an eager jnp operation: a Python int (a weak type) or
+    # an eager slice would be a compilation of its own the first time it
+    # is met, inside a server's measured window.
+
     def prefill(self, tokens, pages):
         np = self._np
         self.prefill_calls += 1
         T = len(tokens)
         n_pages = len(pages)
         tpad = n_pages * self.page_size
+        if not 0 < T <= tpad:
+            raise ValueError(f"{T} tokens do not fit {n_pages} pages of "
+                             f"{self.page_size}")
         self._note_bucket("prefill", tpad)
         _t = _fr.now()
         toks = np.zeros((1, tpad), np.int32)
         toks[0, :T] = tokens
-        # the reads below would wait for the results anyway: waiting here
+        # the read below would wait for the results anyway: waiting here
         # puts the device's time in its own span
-        logits, ks, vs = jax.block_until_ready(
-            self._prefill_fn(self.params, jnp.asarray(toks)))
+        k, v, logits = jax.block_until_ready(self._prefill_fn(
+            self.params, self.k_pages, self.v_pages, toks,
+            np.asarray(pages, np.int32), np.asarray(T - 1, np.int32)))
         _sp_prefill_program.end(_t, n_pages)
         _t = _fr.now()
-        ks = np.asarray(ks, np.float32)  # [L, 1, Tpad, nkv, hd]
-        vs = np.asarray(vs, np.float32)
-        for pi, page in enumerate(pages):
-            lo = pi * self.page_size
-            hi = min(lo + self.page_size, T)
-            if hi <= lo:
-                break
-            # [L, span, nkv, hd] -> store layout [span, L, nkv, hd]
-            self.k_store[page, :hi - lo] = np.transpose(
-                ks[:, 0, lo:hi], (1, 0, 2, 3))
-            self.v_store[page, :hi - lo] = np.transpose(
-                vs[:, 0, lo:hi], (1, 0, 2, 3))
+        # the stores were donated: only a call that returned hands back
+        # live ones, and only those replace the engine's
+        self.k_pages, self.v_pages = k, v
         _sp_prefill_kv.end(_t, n_pages)
         _t = _fr.now()
-        last = np.asarray(logits, np.float32)[0, T - 1].copy()
+        last = np.asarray(logits, np.float32)
         _sp_prefill_logits.end(_t, n_pages)
         return last
 
@@ -598,36 +690,33 @@ class LlamaDecodeEngine:
         self.decode_calls += 1
         n_pages = len(pages)
         tpad = n_pages * self.page_size
+        if not 0 <= pos < tpad:
+            raise ValueError(f"position {pos} lies outside {n_pages} pages "
+                             f"of {self.page_size}")
         self._note_bucket("decode", tpad)
         _t_call = _t = _fr.now()
-        # gather [n_seq_pages, page_size, L, nkv, hd] -> [L, Tpad, nkv, hd]
-        kc = np.transpose(
-            self.k_store[pages].reshape(tpad, *self.k_store.shape[2:]),
-            (1, 0, 2, 3))
-        vc = np.transpose(
-            self.v_store[pages].reshape(tpad, *self.v_store.shape[2:]),
-            (1, 0, 2, 3))
         # the program cannot start before its inputs are on the device,
-        # and the reads wait for its results: the two waits add none
-        kc, vc = jax.block_until_ready((jnp.asarray(kc), jnp.asarray(vc)))
+        # and the read waits for its results: the two waits add none
+        inputs = jax.block_until_ready(jax.device_put(
+            (np.asarray([token], np.int32), np.asarray(pos, np.int32),
+             np.asarray(pages, np.int32))))
         _sp_decode_upload.end(_t, n_pages)
         _t = _fr.now()
-        logits, kn, vn = jax.block_until_ready(self._decode_fn(
-            self.params, jnp.asarray([int(token)], jnp.int32),
-            jnp.int32(pos), kc, vc))
+        k, v, logits = jax.block_until_ready(self._decode_fn(
+            self.params, self.k_pages, self.v_pages, *inputs))
         _sp_decode_program.end(_t, n_pages)
         _t = _fr.now()
-        pg, off = divmod(pos, self.page_size)
-        self.k_store[pages[pg], off] = np.asarray(kn, np.float32)
-        self.v_store[pages[pg], off] = np.asarray(vn, np.float32)
-        out = np.asarray(logits, np.float32).copy()
+        self.k_pages, self.v_pages = k, v  # as in prefill
+        out = np.asarray(logits, np.float32)
         _sp_decode_readback.end(_t, n_pages)
         _sp_decode.end(_t_call, n_pages)
         return out
 
     def copy_page(self, src: int, dst: int) -> None:
-        self.k_store[dst] = self.k_store[src]
-        self.v_store[dst] = self.v_store[src]
+        np = self._np
+        self.k_pages, self.v_pages = self._copy_fn(
+            self.k_pages, self.v_pages, np.asarray(src, np.int32),
+            np.asarray(dst, np.int32))
 
 
 # --------------------------------------------------------------------------- #

@@ -36,9 +36,10 @@ knob; spans shorter than ``flight_recorder_min_span_us`` (default
 500 us) stop at the duration compare so microsecond-rate dispatch pays
 only the clock reads — the on/off overhead is bench-gated in
 BENCH_TRACE.json (``bench_core.py --trace-bench``). Spans registered
-``floor_exempt`` (one per REQUEST, not per dispatch: a request's queue
-waits) are recorded however short, so their median is over every
-request and not over the ones that waited.
+``floor_exempt`` (one per REQUEST or per engine call, not per dispatch:
+a request's queue waits, what is left of the decode engine's copies) are
+recorded however short, so their median is over every request and not
+over the ones that waited.
 """
 
 from __future__ import annotations

@@ -321,6 +321,179 @@ class TestLlamaEngine:
         assert warm["cached_prefix"] is True
         assert warm["tokens"] == cold["tokens"]
 
+    # ---- the page store on the device (PR 25)
+
+    @staticmethod
+    def _store(engine):
+        """Both page stores as numpy, ``[2, L, n_pages, page_size, ...]``."""
+        return np.stack([np.asarray(engine.k_pages),
+                         np.asarray(engine.v_pages)])
+
+    @staticmethod
+    def _forward_logits(engine, toks):
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models.llama import forward
+
+        return np.asarray(jax.jit(
+            lambda prm, t: forward(engine.cfg, prm, t))(
+                engine.params, jnp.asarray(np.asarray(toks, np.int32)[None])),
+            np.float32)[0]
+
+    @staticmethod
+    def _teacher_forced(engine, toks, n_prompt, pages):
+        """Prefill ``toks[:n_prompt]``, then decode the rest one by one;
+        the logits of positions ``n_prompt - 1 ..``, one row each."""
+        ps = engine.page_size
+        rows = [engine.prefill([int(t) for t in toks[:n_prompt]],
+                               pages[:pages_for(n_prompt, ps)])]
+        for pos in range(n_prompt, len(toks)):
+            rows.append(engine.decode(pos, int(toks[pos]),
+                                      pages[:pages_for(pos + 1, ps)]))
+        return np.stack(rows)
+
+    def test_scattered_page_table_matches_forward(self, engine):
+        """A prompt that ends inside a page, then four decoded positions
+        that cross a page boundary, through page ids that are neither
+        contiguous nor ascending: the same bytes as through ascending
+        ones (a gather in another order would unmask the pad positions
+        and mask real ones), and forward()'s logits at those positions."""
+        rng = np.random.RandomState(7)
+        toks = rng.randint(0, engine.cfg.vocab_size, size=10)
+        # stale keys in every page: a wrong page is not a page of zeros
+        junk = engine.pool.alloc(16)
+        for lo in range(0, 16, 4):
+            engine.prefill(list(rng.randint(0, 256, size=16)),
+                           junk[lo:lo + 4])
+        engine.pool.release(junk)
+        straight = self._teacher_forced(engine, toks, 6, [0, 1, 2])
+        scattered = self._teacher_forced(engine, toks, 6, [11, 3, 7])
+        np.testing.assert_array_equal(scattered, straight)
+        want = self._forward_logits(engine, toks)[5:]
+        assert scattered.shape == want.shape == (5, engine.cfg.vocab_size)
+        scale = float(np.max(np.abs(want)))
+        # bf16: forward() keeps its logits in bf16, the engine's are the
+        # same products accumulated to float32
+        assert float(np.max(np.abs(scattered - want))) / scale < 0.05
+
+    def test_alternating_sequences_do_not_touch_each_other(self, engine):
+        """Two sequences decoded in turn: each one's logits are what it
+        gives alone, and a decode changes exactly one slot of the store,
+        its own sequence's write position."""
+        rng = np.random.RandomState(11)
+        ps = engine.page_size
+        seqs = {"a": (rng.randint(0, 256, size=9), 5, [9, 2, 12]),
+                "b": (rng.randint(0, 256, size=11), 7, [4, 14, 1])}
+        alone = {}
+        for name, (toks, n_prompt, pages) in seqs.items():
+            alone[name] = self._teacher_forced(engine, toks, n_prompt, pages)
+        # again, interleaved, on other pages
+        seqs = {"a": seqs["a"][:2] + ([6, 13, 0],),
+                "b": seqs["b"][:2] + ([10, 5, 8],)}
+        got = {}
+        for name, (toks, n_prompt, pages) in seqs.items():
+            got[name] = [engine.prefill([int(t) for t in toks[:n_prompt]],
+                                        pages[:pages_for(n_prompt, ps)])]
+        for step in range(4):
+            for name, (toks, n_prompt, pages) in seqs.items():
+                pos = n_prompt + step
+                before = self._store(engine)
+                got[name].append(engine.decode(
+                    pos, int(toks[pos]), pages[:pages_for(pos + 1, ps)]))
+                after = self._store(engine)
+                changed = before != after
+                assert changed[:, :, pages[pos // ps], pos % ps].any()
+                changed[:, :, pages[pos // ps], pos % ps] = False
+                assert not changed.any()
+        for name in seqs:
+            np.testing.assert_array_equal(np.stack(got[name]), alone[name])
+
+    def test_prefix_hit_copies_the_partial_tail_page(self, engine):
+        """A prefix hit on a prompt that ends inside a page: the second
+        sequence decodes into a copy of the tail page, made by
+        ``copy_page``, and the entry's own pages keep their bytes."""
+        import json as _json
+
+        copies = []
+        inner = engine.copy_page
+        engine.copy_page = lambda src, dst: (copies.append((src, dst)),
+                                             inner(src, dst))[1]
+        sched = DecodeScheduler(engine)
+        req = {"prompt": [2, 7, 1, 8, 2, 8], "max_tokens": 5}  # 4 + 2
+        cold = _json.loads(_run_all(sched, [("c", req)])["c"][-1][1])
+        entry = engine.prefix_cache._entries[tuple(req["prompt"])]
+        before = self._store(engine)[:, :, entry.pages]
+        assert len(copies) == 1
+        warm = _json.loads(_run_all(sched, [("w", req)])["w"][-1][1])
+        assert warm["cached_prefix"] is True
+        assert warm["tokens"] == cold["tokens"]
+        assert engine.prefill_calls == 1
+        assert len(copies) == 2 and copies[1][0] == entry.pages[1] \
+            and copies[1][1] not in entry.pages
+        np.testing.assert_array_equal(
+            self._store(engine)[:, :, entry.pages], before)
+
+    def test_second_round_compiles_nothing(self, engine):
+        """The serving benchmark's in-window rule: once each page count
+        has been prefilled and decoded and one page copied, other tokens,
+        positions and page ids ask for no compilation (a cache hit is a
+        request too), whatever integer types the caller passes."""
+        from ray_tpu.util.device_telemetry import (install_jax_listeners,
+                                                   process_device_report)
+
+        assert install_jax_listeners()
+
+        def requests():
+            rep = process_device_report()
+            return rep["cache_hits"] + rep["cache_misses"]
+
+        ps = engine.page_size
+        start = requests()
+        for n in (1, 2, 3):
+            engine.prefill([1] * (n * ps), list(range(n)))
+            engine.decode(n * ps - 1, 1, list(range(n)))
+        engine.copy_page(0, 1)
+        warm = requests()
+        assert warm > start  # the counter sees this engine's programs
+        rng = np.random.RandomState(3)
+        for n in (3, 1, 2):
+            pages = [int(p) for p in rng.permutation(16)[:n]]
+            engine.prefill(list(rng.randint(0, 256, size=n * ps - 2)),
+                           pages)
+            engine.decode(np.int64(n * ps - 2), np.int32(5), pages)
+            engine.decode(n * ps - 1, 9, tuple(pages))
+        engine.copy_page(np.int64(5), 3)
+        assert requests() == warm
+
+    def test_failed_call_leaves_the_store_usable(self, engine):
+        """The scheduler fails one request and goes on: a call that
+        raises, in the wrapper's own checks or from the program's call,
+        has not given the stores away."""
+        toks = [5, 3, 9, 1, 4, 8, 2]
+        want = self._teacher_forced(engine, toks, 5, [3, 1])
+        with pytest.raises(ValueError):
+            engine.prefill(toks, [2])  # seven tokens, one page of four
+        with pytest.raises(ValueError):
+            engine.decode(8, 1, [2, 0])  # position 8 of 8
+        jitted = engine._prefill_fn, engine._decode_fn
+
+        def boom(*_a, **_kw):
+            raise RuntimeError("injected")
+
+        engine._prefill_fn = engine._decode_fn = boom
+        try:
+            with pytest.raises(RuntimeError):
+                engine.prefill(toks[:5], [6, 7])
+            with pytest.raises(RuntimeError):
+                engine.decode(5, toks[5], [3, 1])
+        finally:
+            engine._prefill_fn, engine._decode_fn = jitted
+        assert not engine.k_pages.is_deleted()
+        assert not engine.v_pages.is_deleted()
+        np.testing.assert_array_equal(
+            self._teacher_forced(engine, toks, 5, [6, 7]), want)
+
     def test_prefill_and_decode_are_taken_apart_into_spans(self, engine):
         """The engine's calls as flight-recorder spans: the device
         program and the host copies on either side of it. The three
