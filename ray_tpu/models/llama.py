@@ -83,6 +83,15 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16  # compute dtype (params stored fp32)
     remat: bool = True
     loss_chunk: int = 256  # seq-chunk for the xent head; 0 = unchunked
+    # routed experts in place of the dense MLP (0 = dense): mlp_dim is then
+    # the width of ONE expert, every token runs its experts_per_token best
+    num_experts: int = 0
+    experts_per_token: int = 0
+    norm_topk_prob: bool = False  # renormalise the chosen weights to sum 1
+    lb_loss_coef: float = 0.01    # router losses' weights (routed only)
+    z_loss_coef: float = 0.001
+    # RMSNorm over the WHOLE q / k projection, before heads and RoPE
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -142,8 +151,13 @@ class LlamaConfig:
 
     def num_params(self) -> int:
         d, v, l = self.dim, self.vocab_size, self.n_layers
-        attn = d * d + 2 * d * (self.n_kv_heads * self.head_dim) + d * d
+        kv = self.n_kv_heads * self.head_dim
+        attn = d * d + 2 * d * kv + d * d
+        if self.qk_norm:
+            attn += d + kv
         mlp = 3 * d * self.mlp_dim
+        if self.num_experts:
+            mlp = self.num_experts * mlp + d * self.num_experts
         per_layer = attn + mlp + 2 * d
         emb = v * d * (1 if self.tie_embeddings else 2)
         return emb + l * per_layer + d
@@ -167,6 +181,16 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "attn_norm": ("layers", None),
         "mlp_norm": ("layers", None),
     }
+    if cfg.num_experts:
+        # every expert on every device: no logical "expert" axis here
+        layer.update({
+            "router": ("layers", "embed", None),
+            "w_gate": ("layers", None, "embed", "mlp"),
+            "w_up": ("layers", None, "embed", "mlp"),
+            "w_down": ("layers", None, "mlp", "embed"),
+        })
+    if cfg.qk_norm:
+        layer.update({"q_norm": ("layers", None), "k_norm": ("layers", None)})
     out = {
         "embedding": ("vocab", "embed"),
         "layers": layer,
@@ -186,6 +210,10 @@ def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
         return (jax.random.normal(rng, shape, jnp.float32)
                 * (1.0 / math.sqrt(fan_in)))
 
+    # a routed config's MLP weights carry an expert dimension after the
+    # scan's; they are drawn from the keys the dense ones are drawn from
+    experts = (cfg.num_experts,) if cfg.num_experts else ()
+    f = cfg.mlp_dim
     params = {
         "embedding": dense(next(k), (cfg.vocab_size, d), d),
         "layers": {
@@ -193,9 +221,9 @@ def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
             "wk": dense(next(k), (L, d, nkv * hd), d),
             "wv": dense(next(k), (L, d, nkv * hd), d),
             "wo": dense(next(k), (L, nq * hd, d), nq * hd),
-            "w_gate": dense(next(k), (L, d, cfg.mlp_dim), d),
-            "w_up": dense(next(k), (L, d, cfg.mlp_dim), d),
-            "w_down": dense(next(k), (L, cfg.mlp_dim, d), cfg.mlp_dim),
+            "w_gate": dense(next(k), (L, *experts, d, f), d),
+            "w_up": dense(next(k), (L, *experts, d, f), d),
+            "w_down": dense(next(k), (L, *experts, f, d), f),
             "attn_norm": jnp.ones((L, d), jnp.float32),
             "mlp_norm": jnp.ones((L, d), jnp.float32),
         },
@@ -203,6 +231,14 @@ def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(k), (d, cfg.vocab_size), d)
+    # what a dense config lacks is drawn last, so that a dense config's
+    # parameters are what they were for the same key
+    layers = params["layers"]
+    if cfg.num_experts:
+        layers["router"] = dense(next(k), (L, d, cfg.num_experts), d)
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((L, nq * hd), jnp.float32)
+        layers["k_norm"] = jnp.ones((L, nkv * hd), jnp.float32)
     return params
 
 
@@ -251,23 +287,78 @@ def _attention(cfg: LlamaConfig, q, k, v, mesh):
     return flash_attention(q, k, v, causal=True)
 
 
+def _qkv(cfg: LlamaConfig, p, h, n_q: int, n_kv: int, positions):
+    """The attention half's inputs: ``h`` [B, T, dim] (normed, cfg.dtype)
+    through wq / wk / wv, QK-norm where the config has it (RMSNorm over the
+    whole projected vector, before the split into heads), heads split,
+    RoPE on q and k. ``n_q`` / ``n_kv``: the heads THESE weights hold."""
+    cd, hd = cfg.dtype, cfg.head_dim
+    B, T, _ = h.shape
+
+    def project(w, heads, norm=None):
+        y = h @ p[w].astype(cd)
+        if norm is not None:
+            y = rms_norm(y, p[norm], cfg.norm_eps)
+        return y.reshape(B, T, heads, hd)
+
+    q = project("wq", n_q, "q_norm" if cfg.qk_norm else None)
+    kk = project("wk", n_kv, "k_norm" if cfg.qk_norm else None)
+    vv = project("wv", n_kv)
+    q, kk = rotary_embedding(q, kk, positions, cfg.rope_theta)
+    return q, kk, vv
+
+
+def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=()):
+    """The MLP half on ``h`` [B, T, dim] (normed, cfg.dtype), before the
+    residual add (and before a row-parallel caller's psum): the dense
+    SwiGLU, or, where the config has experts, the dropless routed one
+    (:func:`ray_tpu.ops.moe.routed_mlp`). Returns ``(y, stats)``; ``stats``
+    is ``{}`` for a dense layer and the router's scalars for a routed one.
+    ``stat_axes``: see ``routed_mlp``."""
+    cd = cfg.dtype
+    if cfg.num_experts:
+        from ray_tpu.ops.moe import routed_mlp
+
+        y, stats = routed_mlp(
+            h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+            top_k=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
+            stat_axes=stat_axes)
+        return y.astype(cd), stats
+    g = jax.nn.silu(h @ p["w_gate"].astype(cd))
+    u = h @ p["w_up"].astype(cd)
+    return (g * u) @ p["w_down"].astype(cd), {}
+
+
+def add_router_losses(cfg: LlamaConfig, nll, stats):
+    """``stats``: what the layer scan stacked, ``[L]`` a leaf (``{}`` for a
+    dense model). Returns ``(total, report)``: the cross-entropy plus the
+    weighted router losses, each averaged over layers, and the scalars a
+    step reports (``{}`` for a dense model, whose total is ``nll``)."""
+    if not stats:
+        return nll, {}
+    report = {"lb_loss": stats["lb_loss"].mean(),
+              "z_loss": stats["z_loss"].mean(),
+              "max_load_ratio": stats["max_load_ratio"].max(),
+              "dropped": stats["dropped"].sum()}
+    total = (nll + cfg.lb_loss_coef * report["lb_loss"]
+             + cfg.z_loss_coef * report["z_loss"])
+    return total, report
+
+
 def _layer(cfg: LlamaConfig, mesh, x, layer_params, positions):
-    """One decoder layer. x: [B, T, dim] (residual stream, cfg.dtype)."""
+    """One decoder layer. x: [B, T, dim] (residual stream, cfg.dtype).
+    Returns ``(x, stats)``, ``stats`` as :func:`_mlp_half` gives them."""
     p = layer_params
     cd = cfg.dtype
     B, T, d = x.shape
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps).astype(cd)
-    q = (h @ p["wq"].astype(cd)).reshape(B, T, cfg.n_heads, cfg.head_dim)
-    kk = (h @ p["wk"].astype(cd)).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    vv = (h @ p["wv"].astype(cd)).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    q, kk = rotary_embedding(q, kk, positions, cfg.rope_theta)
+    q, kk, vv = _qkv(cfg, p, h, cfg.n_heads, cfg.n_kv_heads, positions)
     attn = _attention(cfg, q, kk, vv, mesh)
     attn = attn.reshape(B, T, cfg.n_heads * cfg.head_dim)
     x = x + (attn @ p["wo"].astype(cd)).astype(x.dtype)
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps).astype(cd)
-    g = jax.nn.silu(h @ p["w_gate"].astype(cd))
-    u = h @ p["w_up"].astype(cd)
-    x = x + ((g * u) @ p["w_down"].astype(cd)).astype(x.dtype)
+    y, stats = _mlp_half(cfg, p, h)
+    x = x + y.astype(x.dtype)
     if mesh is not None and mesh.size > 1:
         # pin the residual stream's layout at every block boundary:
         # without the constraint GSPMD is free to pick a different
@@ -276,7 +367,7 @@ def _layer(cfg: LlamaConfig, mesh, x, layer_params, positions):
         from ray_tpu.parallel.sharding import constraint
 
         x = constraint(x, ("batch", "seq", None), mesh)
-    return x
+    return x, stats
 
 
 def embed_tokens(cfg, params, tokens, mesh=None, table_sharded=None):
@@ -321,7 +412,8 @@ def embed_tokens(cfg, params, tokens, mesh=None, table_sharded=None):
 
 
 def _backbone(cfg: LlamaConfig, params, tokens, mesh=None):
-    """tokens [B, T] int32 -> final-normed hidden states [B, T, dim]."""
+    """tokens [B, T] int32 -> ``(final-normed hidden states [B, T, dim],
+    the layers' stats stacked [L])``."""
     B, T = tokens.shape
     x = embed_tokens(cfg, params, tokens, mesh)
     positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
@@ -331,10 +423,10 @@ def _backbone(cfg: LlamaConfig, params, tokens, mesh=None):
         layer_fn = jax.checkpoint(layer_fn, static_argnums=())
 
     def scan_body(carry, layer_params):
-        return layer_fn(carry, layer_params, positions), None
+        return layer_fn(carry, layer_params, positions)
 
-    x, _ = jax.lax.scan(scan_body, x, params["layers"])
-    return rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x, stats = jax.lax.scan(scan_body, x, params["layers"])
+    return rms_norm(x, params["final_norm"], cfg.norm_eps), stats
 
 
 def _head(cfg: LlamaConfig, params):
@@ -344,7 +436,7 @@ def _head(cfg: LlamaConfig, params):
 
 def forward(cfg: LlamaConfig, params, tokens, mesh=None):
     """tokens [B, T] int32 -> logits [B, T, vocab] (cfg.dtype)."""
-    x = _backbone(cfg, params, tokens, mesh)
+    x, _ = _backbone(cfg, params, tokens, mesh)
     return (x.astype(cfg.dtype) @ _head(cfg, params).astype(cfg.dtype))
 
 
@@ -390,13 +482,33 @@ def chunked_nll_mean(cfg: LlamaConfig, x, targets, chunk_nll):
     return total / (B * T)
 
 
-def loss_fn(cfg: LlamaConfig, params, tokens, mesh=None):
-    """Next-token cross-entropy; fp32 log-softmax. tokens [B, T+1].
-    See :func:`chunked_nll_mean` for the chunked-head memory story."""
+def loss_parts(cfg: LlamaConfig, params, tokens, mesh=None):
+    """``(total, report)``: what is trained on, and for a routed model the
+    router's scalars apart (:func:`add_router_losses`; the cross-entropy
+    is ``total`` less the weighted two). tokens [B, T+1]."""
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x = _backbone(cfg, params, inputs, mesh)
-    return chunked_nll_mean(cfg, x, targets,
-                            _plain_chunk_nll(cfg, _head(cfg, params)))
+    x, stats = _backbone(cfg, params, inputs, mesh)
+    nll = chunked_nll_mean(cfg, x, targets,
+                           _plain_chunk_nll(cfg, _head(cfg, params)))
+    return add_router_losses(cfg, nll, stats)
+
+
+def loss_fn(cfg: LlamaConfig, params, tokens, mesh=None):
+    """Next-token cross-entropy, fp32 log-softmax, plus a routed model's
+    weighted router losses. tokens [B, T+1]. See :func:`chunked_nll_mean`
+    for the chunked-head memory story."""
+    return loss_parts(cfg, params, tokens, mesh)[0]
+
+
+def _dense_only(cfg: LlamaConfig, who: str) -> None:
+    """Routed experts and QK-norm live in the training block (``_layer``,
+    ``_pp_layer``); a path that has its own copy of the block, or no way
+    out for the router's losses, says so instead of computing another
+    model."""
+    if cfg.num_experts or cfg.qk_norm:
+        raise NotImplementedError(
+            f"{who} runs the dense block without QK-norm; this config has "
+            f"num_experts={cfg.num_experts}, qk_norm={cfg.qk_norm}")
 
 
 # --------------------------------------------------------------------------- #
@@ -611,6 +723,7 @@ class LlamaDecodeEngine:
         from ray_tpu.serve.kv_cache import PagePool, PrefixCache
 
         self.cfg = cfg or LlamaConfig.debug()
+        _dense_only(self.cfg, "LlamaDecodeEngine")
         if params is None:
             # one jitted program, not a dozen eager ones: at 664.6M
             # parameters the eager form spends 67 s on a v5e, nearly all of
@@ -873,8 +986,11 @@ def vp_chunk_nll(cfg: LlamaConfig, head_local, axis, gp):
 
 
 def _pp_layer(cfg: LlamaConfig, x, p, positions, tensor_axis=None,
-              collectives=None):
+              collectives=None, stat_axes=()):
     """One decoder layer on *local* shards inside a manual shard_map.
+    Returns ``(x, stats)``, ``stats`` as :func:`_mlp_half` gives them
+    (``stat_axes``: the mesh axes that split the batch, for a routed
+    layer's load fractions).
 
     Head/mlp counts come from the shard shapes (Megatron-style manual TP:
     q/k/v/gate/up column-parallel — no comm; wo/down row-parallel — psum
@@ -899,20 +1015,15 @@ def _pp_layer(cfg: LlamaConfig, x, p, positions, tensor_axis=None,
     nq = p["wq"].shape[-1] // hd
     nkv = p["wk"].shape[-1] // hd
     h = col_in(rms_norm(x, p["attn_norm"], cfg.norm_eps).astype(cd))
-    q = (h @ p["wq"].astype(cd)).reshape(B, T, nq, hd)
-    kk = (h @ p["wk"].astype(cd)).reshape(B, T, nkv, hd)
-    vv = (h @ p["wv"].astype(cd)).reshape(B, T, nkv, hd)
-    q, kk = rotary_embedding(q, kk, positions, cfg.rope_theta)
+    q, kk, vv = _qkv(cfg, p, h, nq, nkv, positions)
     attn = flash_attention(q, kk, vv, causal=True)
     o = attn.reshape(B, T, nq * hd) @ p["wo"].astype(cd)
     o = row_out(o)
     x = x + o.astype(x.dtype)
     h = col_in(rms_norm(x, p["mlp_norm"], cfg.norm_eps).astype(cd))
-    g = jax.nn.silu(h @ p["w_gate"].astype(cd))
-    u = h @ p["w_up"].astype(cd)
-    y = (g * u) @ p["w_down"].astype(cd)
+    y, stats = _mlp_half(cfg, p, h, stat_axes)
     y = row_out(y)
-    return x + y.astype(x.dtype)
+    return x + y.astype(x.dtype), stats
 
 
 def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
@@ -940,6 +1051,7 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
 
     if "pipe" not in mesh.axis_names:
         raise ValueError("mesh has no 'pipe' axis")
+    _dense_only(cfg, "make_pipeline_train_step")
     n_stages = mesh.shape["pipe"]
     if cfg.n_layers % n_stages:
         raise ValueError(f"{cfg.n_layers} layers not divisible by "
@@ -1006,7 +1118,7 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
 
         def stage_fn(sp, act):
             def one_layer(carry, lp):
-                return _pp_layer(cfg, carry, lp, act["pos"], ta), None
+                return _pp_layer(cfg, carry, lp, act["pos"], ta)[0], None
 
             body = one_layer
             if cfg.remat:

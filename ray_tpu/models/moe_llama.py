@@ -27,6 +27,15 @@ from ray_tpu.parallel.sharding import DEFAULT_RULES, logical_sharding
 
 @dataclass(frozen=True)
 class MoEConfig(LlamaConfig):
+    """The capacity-based GSPMD variant (``ops/moe.moe_ffn``: drops tokens
+    over capacity, renormalises the kept gates; ``top_k`` here, not
+    ``experts_per_token``). It is NOT the measured path: the benchmark's
+    routed model (cell ``train-olmoe-1chip``) is a plain ``LlamaConfig``
+    with ``num_experts`` set, through ``models/llama.py`` and
+    ``make_spmd_train_step`` (dropless ``ops/moe.routed_mlp``). This class
+    and its step stay for the live ``expert`` mesh axis until the
+    four-chip expert-parallel cell replaces them (ROADMAP Design 1, 2)."""
+
     num_experts: int = 8
     top_k: int = 2
     capacity_factor: float = 1.25

@@ -176,15 +176,16 @@ def _llama_stage_fwd(cfg, sparams, x):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import _layer
+    from ray_tpu.models.llama import _dense_only, _layer
 
+    _dense_only(cfg, "the MPMD pipeline")
     if "embedding" in sparams:
         x = sparams["embedding"].astype(cfg.dtype)[x]
     B, T = x.shape[0], x.shape[1]
     positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
 
     def body(carry, lp):
-        return _layer(cfg, None, carry, lp, positions), None
+        return _layer(cfg, None, carry, lp, positions)[0], None
 
     x, _ = jax.lax.scan(body, x, sparams["layers"])
     return x

@@ -72,6 +72,16 @@ _sp_compute = _fr.register_span("spmd.compute")
 # name keeps the badput ledger's compile column honest instead of
 # folding a multi-second outlier into spmd.compute
 _sp_compile = _fr.register_span("spmd.compile")
+# a routed model's router, one instant a report (``timeline --attribute``
+# prints them): the two router losses, the heaviest expert's load over the
+# mean, and the assignments no expert computed (0: the routing is dropless)
+_ROUTER_GAUGES = {
+    "lb_loss": _fr.register_span("moe.lb_loss", tag_keys=("value",)),
+    "z_loss": _fr.register_span("moe.z_loss", tag_keys=("value",)),
+    "max_load_ratio": _fr.register_span("moe.max_load_ratio",
+                                        tag_keys=("value",)),
+    "dropped": _fr.register_span("moe.dropped", tag_keys=("value",)),
+}
 
 # Throughput/step-time gauges feeding the head's metrics-history rings
 # (session.report only buffers to the driver's result log) — the series
@@ -143,17 +153,27 @@ def match_partition_rules(rules, params, sep: str = "/"):
     return jax.tree.map(spec_for, names, params)
 
 
-def llama_partition_rules():
-    """Partition rules for the llama param tree (models/llama.py).
+def llama_partition_rules(routed: bool = False):
+    """Partition rules for the llama param tree (models/llama.py);
+    ``routed``: for a config with experts, whose w_gate / w_up / w_down
+    carry an expert dimension after the scan's.
 
     Mirrors ``parallel/sharding.DEFAULT_RULES``'s logical-axis mapping
     (embed→fsdp, heads/kv_heads/mlp/vocab→tensor) but keyed by name, so
     the table reads like the model: every projection shards its embed
     dim over ``fsdp`` and its heads/mlp dim over ``tensor``; the scan
-    ('layers') dim never shards."""
+    ('layers') dim never shards, nor does the expert dim (every expert on
+    every device; a live ``expert`` axis is refused)."""
     from jax.sharding import PartitionSpec as P
 
-    return (
+    experts = (
+        # router: (L, embed, E); expert weights: (L, E, embed, mlp) and
+        # (L, E, mlp, embed), first match wins
+        (r"layers/router$", P(None, "fsdp", None)),
+        (r"layers/w_(gate|up)$", P(None, None, "fsdp", "tensor")),
+        (r"layers/w_down$", P(None, None, "tensor", "fsdp")),
+    ) if routed else ()
+    return experts + (
         # embedding: (vocab, embed)
         (r"(^|/)embedding$", P("tensor", "fsdp")),
         # q/k/v and gate/up: (L, embed, heads*hd | mlp)
@@ -161,7 +181,7 @@ def llama_partition_rules():
         (r"layers/w_(gate|up)$", P(None, "fsdp", "tensor")),
         # output projections: (L, heads*hd | mlp, embed)
         (r"layers/(wo|w_down)$", P(None, "tensor", "fsdp")),
-        # norm scales: replicated
+        # norm scales (QK-norm's too): replicated
         (r"norm$", P()),
         # lm_head: (embed, vocab)
         (r"(^|/)lm_head$", P("fsdp", "tensor")),
@@ -262,7 +282,9 @@ def spmd_param_specs(cfg, mesh, rules=None):
     sample = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     specs = jax.tree.map(
         lambda s: _restrict_spec(s, mesh),
-        match_partition_rules(rules or llama_partition_rules(), sample),
+        match_partition_rules(
+            rules or llama_partition_rules(routed=bool(cfg.num_experts)),
+            sample),
         is_leaf=_is_spec)
     return sample, specs
 
@@ -296,6 +318,15 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     gathered. ``seq``/``pipe``/``expert`` still route to the GSPMD /
     pipeline steps.
 
+    A config with experts (``cfg.num_experts``) runs its routed MLP half
+    in the same layer (``_pp_layer`` -> ``ops/moe.routed_mlp``), every
+    expert on every device; the router's losses leave the layer stack as
+    the scan's stacked outputs under both gather schedules and are added
+    to the loss (``add_router_losses``). Its step returns a third value,
+    the router's scalars of the step (``lb_loss``, ``z_loss``,
+    ``max_load_ratio``, ``dropped``); a dense config's step and program
+    are what they were.
+
     A caller-supplied ``optimizer`` runs INSIDE shard_map on the
     fsdp/tensor shards, so per-leaf elementwise transforms (adam/adamw
     moments, per-leaf clipping, weight decay) are exact, but transforms
@@ -327,6 +358,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     from ray_tpu.models.llama import (
         _plain_chunk_nll,
         _pp_layer,
+        add_router_losses,
         chunked_nll_mean,
         init_params,
         tp_psum_pair,
@@ -358,6 +390,11 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
             if n % t:
                 raise ValueError(
                     f"tensor axis size {t} does not divide cfg.{what}={n}")
+        if cfg.num_experts or cfg.qk_norm:
+            raise ValueError(
+                "a live tensor axis splits the q / k vectors that QK-norm "
+                "normalises whole, and the routed MLP half has no "
+                "tensor-parallel form yet: use batch axes and fsdp")
 
     optimizer = optimizer or optax.adamw(3e-4, b1=0.9, b2=0.95,
                                          weight_decay=0.1)
@@ -446,7 +483,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         B, T, _ = x.shape
         positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
         return _pp_layer(cfg, x, lp, positions, tensor_axis=tensor,
-                         collectives=collectives)
+                         collectives=collectives, stat_axes=batch_axes)
 
     def gather_layer(shards):
         return jax.tree.map(gather_leaf, shards, lspecs1)
@@ -458,7 +495,9 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         a zero cotangent, so no gathered layer ever becomes a scan
         residual. The backward re-gathers the layer from its shards,
         recomputes the layer vjp (inherent per-layer remat), and
-        reduce-scatters the layer grad back to shards."""
+        reduce-scatters the layer grad back to shards. The layer's stats
+        (a routed layer's router losses) are a second output, and their
+        cotangent goes through the same recomputed vjp."""
 
         def apply_fn(x, cur_full, shards):
             return layer_fn(x, cur_full)
@@ -497,15 +536,14 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
                 # issue layer i+1's gather FIRST: XLA schedules the
                 # collective to overlap layer i's matmuls
                 nxt = gather_layer(nxt_sh)
-                h = streamed_apply(h, cur, cur_sh)
-                return (h, nxt), None
+                h, stats = streamed_apply(h, cur, cur_sh)
+                return (h, nxt), stats
 
-            (x, _), _ = jax.lax.scan(body, (x, first), xs)
-            return x
+            (x, _), stats = jax.lax.scan(body, (x, first), xs)
+            return x, stats
         full = jax.tree.map(gather_leaf, layer_shards, lspecs)
         body = jax.checkpoint(layer_fn) if cfg.remat else layer_fn
-        x, _ = jax.lax.scan(lambda c, lp: (body(c, lp), None), x, full)
-        return x
+        return jax.lax.scan(body, x, full)
 
     def local_loss(shards, tokens):
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
@@ -515,7 +553,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
             x = vp_embed(cfg, emb_local, inputs, tensor, gp)
         else:
             x = emb_local.astype(cfg.dtype)[inputs]
-        x = run_layers(x, shards["layers"])
+        x, stats = run_layers(x, shards["layers"])
         x = rms_norm(x, shards["final_norm"], cfg.norm_eps)
         if cfg.tie_embeddings:
             head_local = emb_local.T
@@ -523,15 +561,17 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
             head_local = gather_leaf(shards["lm_head"],
                                      param_specs["lm_head"])
         if tensor is not None:
-            return chunked_nll_mean(
+            nll = chunked_nll_mean(
                 cfg, fi(x), targets,
                 vp_chunk_nll(cfg, head_local, tensor, gp))
-        return chunked_nll_mean(cfg, x, targets,
-                                _plain_chunk_nll(cfg, head_local))
+        else:
+            nll = chunked_nll_mean(cfg, x, targets,
+                                   _plain_chunk_nll(cfg, head_local))
+        return add_router_losses(cfg, nll, stats)
 
     def sm_step(state, tokens):
-        loss, grads = jax.value_and_grad(
-            lambda p: local_loss(p, tokens))(state["params"])
+        (loss, router), grads = jax.value_and_grad(
+            lambda p: local_loss(p, tokens), has_aux=True)(state["params"])
         # params-major maps: the array tree's structure governs, so the
         # PartitionSpec leaves (tuple subclasses) are passed whole
         grads = jax.tree.map(reduce_leaf, grads, param_specs)
@@ -539,19 +579,24 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         updates, new_opt = optimizer.update(grads, state["opt_state"],
                                             state["params"])
         new_params = optax.apply_updates(state["params"], updates)
-        return ({"params": new_params, "opt_state": new_opt,
-                 "step": state["step"] + 1}, loss)
+        new_state = {"params": new_params, "opt_state": new_opt,
+                     "step": state["step"] + 1}
+        if not router:
+            return new_state, loss
+        return new_state, loss, pmean_tree(router, batch_axes)
 
+    # after the state: the loss, and a routed config's router scalars
+    scalars = 2 if cfg.num_experts else 1
     sharded_step = shard_map(
         sm_step, mesh=mesh,
         in_specs=(state_specs, data_spec),
-        out_specs=(state_specs, P()),
+        out_specs=(state_specs,) + (P(),) * scalars,
         check=False)
 
     train_step = observe_compiled(jax.jit(
         sharded_step,
         in_shardings=(state_shardings, data_sharding),
-        out_shardings=(state_shardings, repl),
+        out_shardings=(state_shardings,) + (repl,) * scalars,
         donate_argnums=(0,) if donate else (),
     ), "spmd.train_step")
     return init_jit, train_step, data_sharding, state_shardings
@@ -801,7 +846,7 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
         if toks is None:
             break
         _t = _fr.now()
-        state, loss = step_fn(state, toks)
+        state, loss, *router = step_fn(state, toks)
         if _t:
             # recorder on: close the span at data-ready, not dispatch
             # (the loop syncs on float(loss) at report time anyway)
@@ -812,7 +857,12 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
             _sp_compute.end(_t)
         tokens_done += int(toks.shape[0]) * (int(toks.shape[1]) - 1)
         if (i + 1) % report_every == 0 or i == steps - 1:
-            lf = float(loss)
+            # the router's scalars come with the loss: one fetch a report
+            lf, moe = jax.device_get((loss, router[0] if router else {}))
+            lf = float(lf)
+            moe = {k: float(v) for k, v in moe.items()}
+            for k, v in moe.items():
+                _ROUTER_GAUGES[k].instant(v)
             now = time.perf_counter()
             dt = max(now - t0, 1e-9)
             win_dt = max(now - win_t, 1e-9)
@@ -830,6 +880,7 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
                 "devices": mesh.size,
                 "mesh": dict(mesh.shape),
                 **ran_on,
+                **{f"moe_{k}": v for k, v in moe.items()},
             }
             if i == steps - 1:
                 report.update(_run_evidence(state))

@@ -661,6 +661,16 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     serving = _serving_attribution(by_name)
     if serving:
         report["serving"] = serving
+    # a routed model's router (train/spmd.py: one instant a report)
+    router: Dict[str, List[float]] = {}
+    for ev in events:
+        if ev.get("ph") == "i" and str(ev.get("name", "")).startswith("moe."):
+            router.setdefault(ev["name"], []).append(
+                float((ev.get("args") or {}).get("value", 0.0)))
+    if router:
+        report["router"] = {
+            name: {"n": len(v), "last": v[-1], "max": max(v)}
+            for name, v in sorted(router.items())}
     return report
 
 
@@ -710,4 +720,9 @@ def format_attribution(report: Dict[str, Any]) -> str:
                 f"{'decode steps':<24}: {rec['decode_steps']}, "
                 f"{rec['decode_tokens'] / rec['decode_steps']:.2f} "
                 f"tokens a step")
+    if report.get("router"):
+        lines += ["", "the router (routed experts)", "-" * 27]
+        for name, rec in report["router"].items():
+            lines.append(f"{name:<24}: last {rec['last']:.6g}  "
+                         f"max {rec['max']:.6g}  (n={rec['n']})")
     return "\n".join(lines)

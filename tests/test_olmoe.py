@@ -1,0 +1,408 @@
+"""OLMoE through the program's own block (``models/llama.py`` with experts
+and QK-norm switched on by the config) against the plain float32 reference
+the benchmark keeps (``benchmarks/reference/olmoe_decoder.py``), at small
+widths on the CPU: the routed MLP half and its gradients, the case where
+every token picks the same experts, QK-norm attention, the loss with its
+router losses APART, the controls a tolerance has to refuse, the trainer's
+step on one device against ``fsdp=4`` under both gather schedules, and the
+dense program, which must be what it was."""
+
+import dataclasses
+import hashlib
+import json
+import os
+
+import numpy as np
+import pytest
+
+from benchmarks.reference import olmoe_decoder as ref
+
+jax = pytest.importorskip("jax")
+jnp = jax.numpy
+
+from ray_tpu.models import llama  # noqa: E402
+from ray_tpu.models.llama import LlamaConfig  # noqa: E402
+from ray_tpu.ops.moe import routed_mlp  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG_FILE = os.path.join(ROOT, "benchmarks", "configs",
+                           "OLMoE-1B-7B-0125-Instruct.json")
+
+# the published shape, small: 2 layers, 4 heads on 4 (MHA), 16 experts top-4
+FILE = {"hidden_size": 64, "num_hidden_layers": 2, "num_attention_heads": 4,
+        "num_key_value_heads": 4, "head_dim": 16, "intermediate_size": 32,
+        "vocab_size": 256, "rope_theta": 10000, "rms_norm_eps": 1e-5,
+        "tie_word_embeddings": False, "num_experts": 16,
+        "num_experts_per_tok": 4, "norm_topk_prob": False}
+SEQ = 32
+
+
+def program_cfg(file=FILE, **over):
+    kw = dict(vocab_size=file["vocab_size"], dim=file["hidden_size"],
+              n_layers=file["num_hidden_layers"],
+              n_heads=file["num_attention_heads"],
+              n_kv_heads=file["num_key_value_heads"],
+              mlp_dim=file["intermediate_size"], max_seq_len=64,
+              rope_theta=file["rope_theta"], norm_eps=file["rms_norm_eps"],
+              num_experts=file["num_experts"],
+              experts_per_token=file["num_experts_per_tok"],
+              norm_topk_prob=file["norm_topk_prob"], qk_norm=True,
+              dtype=jnp.float32)
+    kw.update(over)
+    return LlamaConfig(**kw)
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.RandomState(3).randint(
+        0, FILE["vocab_size"], (4, SEQ + 1)).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def params():
+    p = llama.init_params(program_cfg(), jax.random.PRNGKey(11))
+    # norm scales away from one, so that a misplaced norm shows
+    rng = np.random.RandomState(5)
+    for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm"):
+        leaf = p["layers"][name]
+        p["layers"][name] = leaf * jnp.asarray(
+            rng.uniform(0.5, 1.5, leaf.shape), jnp.float32)
+    return p
+
+
+def layer0(params):
+    return jax.tree.map(lambda a: a[0], params["layers"])
+
+
+def mlp_inputs(n=2 * SEQ, seed=0):
+    return jax.random.normal(jax.random.PRNGKey(seed),
+                             (2, n // 2, FILE["hidden_size"]), jnp.float32)
+
+
+def tolerance():
+    with open(CONFIG_FILE) as f:
+        return json.load(f)["correct"]["train_loss_rel_tol"]
+
+
+# --- (a) the routed MLP half against the per-token form ------------------- #
+
+
+@pytest.mark.parametrize("norm_topk_prob", [False, True])
+def test_routed_mlp_matches_reference_outputs_and_gradients(params,
+                                                            norm_topk_prob):
+    p = layer0(params)
+    file = dict(FILE, norm_topk_prob=norm_topk_prob)
+    h = mlp_inputs()
+    target = jax.random.normal(jax.random.PRNGKey(1), h.shape)
+    names = ("router", "w_gate", "w_up", "w_down")
+
+    def program(h, *w):
+        y, stats = routed_mlp(h, *w, top_k=FILE["num_experts_per_tok"],
+                              norm_topk_prob=norm_topk_prob)
+        return jnp.sum(y * target) + stats["lb_loss"] + stats["z_loss"], \
+            (y, stats)
+
+    def reference(h, *w):
+        with jax.default_matmul_precision("highest"):
+            y, lb, z = ref.experts(file, h.reshape(-1, h.shape[-1]),
+                                   dict(zip(names, w)))
+        y = y.reshape(h.shape)
+        return jnp.sum(y * target) + lb + z, (y, {"lb_loss": lb, "z_loss": z})
+
+    args = (h,) + tuple(p[n] for n in names)
+    (_, (y, stats)), grads = jax.value_and_grad(
+        program, argnums=range(5), has_aux=True)(*args)
+    (_, (y_ref, stats_ref)), grads_ref = jax.value_and_grad(
+        reference, argnums=range(5), has_aux=True)(*args)
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-6)
+    for k in ("lb_loss", "z_loss"):
+        np.testing.assert_allclose(stats[k], stats_ref[k], rtol=1e-5)
+    assert float(stats["dropped"]) == 0.0
+    for name, g, g_ref in zip(("h",) + names, grads, grads_ref):
+        scale = float(jnp.abs(g_ref).max())
+        np.testing.assert_allclose(g, g_ref, rtol=1e-5, atol=1e-5 * scale,
+                                   err_msg=name)
+
+
+# --- (b) every token picks the same experts -------------------------------- #
+
+
+def test_total_imbalance_is_still_dropless(params):
+    p = dict(layer0(params))
+    E, K = FILE["num_experts"], FILE["num_experts_per_tok"]
+    # a constant direction in h that the router reads: experts 3, 7, 8, 12
+    # win for every token, in that order
+    favourites = [3, 7, 8, 12]
+    h = mlp_inputs(seed=2) + 4.0
+    router = np.array(p["router"]) * 0.01
+    for rank, e in enumerate(favourites):
+        router[:, e] += 1.0 - 0.1 * rank
+    p["router"] = jnp.asarray(router)
+    y, stats = routed_mlp(h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+                          top_k=K)
+    with jax.default_matmul_precision("highest"):
+        y_ref, lb, _ = ref.experts(FILE, h.reshape(-1, h.shape[-1]), p)
+    np.testing.assert_allclose(y, y_ref.reshape(h.shape), rtol=1e-5,
+                               atol=1e-5)
+    assert float(stats["dropped"]) == 0.0
+    assert float(stats["max_load_ratio"]) == pytest.approx(E / K)
+    np.testing.assert_allclose(stats["lb_loss"], lb, rtol=1e-5)
+
+
+# --- (c) QK-norm attention -------------------------------------------------- #
+
+
+def test_qk_norm_attention_matches_reference(params):
+    cfg = program_cfg(num_experts=0, experts_per_token=0)
+    p = dict(layer0(params))
+    d, f = FILE["hidden_size"], FILE["intermediate_size"]
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    p["w_gate"] = jax.random.normal(ks[0], (d, f)) / np.sqrt(d)
+    p["w_up"] = jax.random.normal(ks[1], (d, f)) / np.sqrt(d)
+    p["w_down"] = jax.random.normal(ks[2], (f, d)) / np.sqrt(f)
+    x = mlp_inputs(seed=6)
+    positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None].repeat(2, 0)
+    got, stats = llama._layer(cfg, None, x, p, positions)
+    assert stats == {}
+    with jax.default_matmul_precision("highest"):
+        for b in range(2):
+            x1 = x[b] + ref.attention(FILE, x[b], p)
+            h = ref._rms_norm(x1, p["mlp_norm"], FILE["rms_norm_eps"])
+            want = x1 + (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) \
+                @ p["w_down"]
+            np.testing.assert_allclose(got[b], want, rtol=2e-5, atol=2e-5)
+    # the norm is over the WHOLE projected vector: one per head is another
+    # model, and the reference tells them apart
+    per_head = dict(p, q_norm=p["q_norm"].at[:16].mul(3.0))
+    other, _ = llama._layer(cfg, None, x, per_head, positions)
+    assert float(jnp.abs(other - got).max()) > 1e-3
+
+
+# --- (d) the loss, its parts apart, and the controls ------------------------ #
+
+
+def program_parts(cfg, params, tokens):
+    total, rep = jax.jit(lambda p, t: llama.loss_parts(cfg, p, t))(
+        params, tokens)
+    lb, z = float(rep["lb_loss"]), float(rep["z_loss"])
+    return {"total": float(total), "lb_loss": lb, "z_loss": z,
+            "cross_entropy": float(total) - cfg.lb_loss_coef * lb
+            - cfg.z_loss_coef * z, "report": rep}
+
+
+def reference_parts(params, tokens, file=FILE):
+    out = jax.jit(lambda p, t: ref.loss_parts(file, p, t))(params, tokens)
+    return {k: float(v) for k, v in out.items()}
+
+
+def test_loss_parts_match_reference_float32(params, tokens):
+    got = program_parts(program_cfg(), params, tokens)
+    want = reference_parts(params, tokens)
+    for k in ("cross_entropy", "lb_loss", "z_loss", "total"):
+        assert got[k] == pytest.approx(want[k], rel=2e-5), k
+    assert float(got["report"]["dropped"]) == 0.0
+    assert got["total"] == pytest.approx(
+        float(llama.loss_fn(program_cfg(), params, tokens)), rel=1e-6)
+    assert want["total"] == pytest.approx(
+        float(ref.loss(FILE, params, tokens)), rel=1e-6)
+    # the router losses weigh in: left out, the total is another number
+    assert abs(want["total"] - want["cross_entropy"]) > 1e-2
+
+
+def test_loss_bfloat16_inside_the_files_tolerance(params):
+    # 1,024 positions: at 128 the mean of so few rounded logits swings by
+    # more than the tolerance, which was set at 16,384 (the cell's step)
+    tokens = np.random.RandomState(4).randint(
+        0, FILE["vocab_size"], (16, 2 * SEQ + 1)).astype(np.int32)
+    got = program_parts(program_cfg(dtype=jnp.bfloat16), params, tokens)
+    want = reference_parts(params, tokens)
+    assert abs(got["total"] - want["total"]) / want["total"] <= tolerance()
+    # each part on its own, so that a total near ln(vocab) hides nothing
+    assert got["cross_entropy"] == pytest.approx(want["cross_entropy"],
+                                                 rel=tolerance())
+    assert got["lb_loss"] == pytest.approx(want["lb_loss"], rel=5e-3)
+    assert got["z_loss"] == pytest.approx(want["z_loss"], rel=5e-3)
+
+
+def _skip_one_expert(params):
+    """The program's parameters with one expert's product gone, in every
+    layer."""
+    layers = dict(params["layers"])
+    layers["w_down"] = layers["w_down"].at[:, 5].set(0.0)
+    return dict(params, layers=layers)
+
+
+@pytest.mark.parametrize("control", ["eighth_choice_left_out",
+                                     "weights_renormalised",
+                                     "one_expert_skipped"])
+def test_the_tolerance_refuses_another_model(params, tokens, control):
+    """What a faster wrong program would compute must not pass for the
+    model: each control moves the total by more than the file's
+    tolerance (float32, so that nothing else moves it)."""
+    cfg, p = program_cfg(), params
+    if control == "eighth_choice_left_out":
+        cfg = program_cfg(experts_per_token=FILE["num_experts_per_tok"] - 1)
+    elif control == "weights_renormalised":
+        cfg = program_cfg(norm_topk_prob=True)
+    else:
+        p = _skip_one_expert(params)
+    got = program_parts(cfg, p, tokens)
+    want = reference_parts(params, tokens)
+    assert abs(got["total"] - want["total"]) / want["total"] > tolerance(), \
+        (control, got["total"], want["total"])
+
+
+# --- (e) the trainer's step ------------------------------------------------- #
+
+
+def _mesh(spec, n):
+    from ray_tpu.train.spmd import build_train_mesh
+
+    return build_train_mesh(spec, jax.devices()[:n])
+
+
+def _three_steps(cfg, spec, n, gather, tokens, optimizer=None):
+    """``(parameters at the start, after the first step, [(loss, router
+    scalars)] of three steps)``."""
+    from ray_tpu.train.spmd import make_spmd_train_step
+
+    init, step, sharding, _ = make_spmd_train_step(
+        cfg, _mesh(spec, n), optimizer=optimizer, donate=False, gather=gather)
+    state = init(jax.random.PRNGKey(0))
+    params, out = [jax.device_get(state["params"])], []
+    for _ in range(3):
+        state, loss, router = step(state, jax.device_put(tokens, sharding))
+        params.append(jax.device_get(state["params"]))
+        out.append((float(loss), {k: float(v) for k, v in router.items()}))
+    return params[0], params[1], out
+
+
+@pytest.mark.parametrize("gather", ["streamed", "upfront"])
+def test_spmd_step_one_device_against_fsdp4(tokens, gather):
+    import optax
+
+    cfg = program_cfg(remat=True)
+    sgd = optax.sgd(1.0)  # new = old - gradient: the step shows its gradient
+    runs = {"one device": _three_steps(cfg, "", 1, gather, tokens, sgd),
+            "fsdp=4": _three_steps(cfg, "fsdp=4", 4, gather, tokens, sgd)}
+    one, four = runs["one device"][2], runs["fsdp=4"][2]
+    for (l1, r1), (l4, r4) in zip(one, four):
+        assert l4 == pytest.approx(l1, rel=2e-5)
+        for k in ("lb_loss", "z_loss", "max_load_ratio", "dropped"):
+            assert r4[k] == pytest.approx(r1[k], rel=2e-5, abs=1e-7), k
+    assert one[0][1]["dropped"] == 0.0 and one[0][1]["lb_loss"] > 1.0
+    # the first step's gradient is jax.grad of loss_fn on the same batch
+    p0 = runs["one device"][0]
+    want = jax.grad(lambda p: llama.loss_fn(cfg, p, tokens))(p0)
+    for name, (before, after, _) in runs.items():
+        got = jax.tree.map(lambda a, b: np.asarray(a) - np.asarray(b),
+                           before, after)
+        for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                                jax.tree.leaves(want)):
+            scale = float(np.abs(w).max()) + 1e-12
+            np.testing.assert_allclose(
+                g, w, rtol=1e-3, atol=2e-5 * scale,
+                err_msg=f"{name} {jax.tree_util.keystr(path)}")
+
+
+def test_spmd_step_learns_with_adamw(tokens):
+    _, _, out = _three_steps(program_cfg(), "data=2,fsdp=2", 4, "streamed",
+                             tokens)
+    assert out[-1][0] < out[0][0], out
+
+
+def test_tensor_axis_is_refused():
+    from ray_tpu.train.spmd import make_spmd_train_step
+
+    with pytest.raises(ValueError, match="tensor"):
+        make_spmd_train_step(program_cfg(), _mesh("fsdp=2,tensor=2", 4))
+
+
+def test_paths_with_their_own_block_refuse_the_config():
+    with pytest.raises(NotImplementedError, match="dense block"):
+        llama.LlamaDecodeEngine(program_cfg())
+
+
+def test_loop_reports_the_router_and_records_its_gauges():
+    from ray_tpu.train.session import TrainContext, set_context
+    from ray_tpu.train.spmd import spmd_train_loop
+    from ray_tpu.util import flight_recorder as fr
+
+    fr.configure(enabled=True)
+    ctx = TrainContext(1, 0, 0, 1, 0)
+    set_context(ctx)
+    try:
+        spmd_train_loop({"llama_config": program_cfg(), "steps": 4,
+                         "batch_per_device": 2, "seq": SEQ, "mesh": "data=1",
+                         "report_every": 1, "lr": 0.01,
+                         "distinct_batches": 1})
+        reports = [r.metrics for r in ctx._drain()]
+    finally:
+        set_context(None)
+    assert len(reports) == 4
+    for r in reports:
+        assert r["moe_dropped"] == 0.0
+        assert r["moe_lb_loss"] > 1.0 and r["moe_z_loss"] > 0.0
+        assert 1.0 <= r["moe_max_load_ratio"] <= 16 / 4
+    assert reports[-1]["loss"] < reports[0]["loss"]
+    payload = fr.snapshot_payload()
+    payload.update(source="test", node_hex="", offset_s=0.0)
+    rep = fr.attribute_trace(fr.build_span_events([payload]))
+    assert set(rep["router"]) == {"moe.lb_loss", "moe.z_loss",
+                                  "moe.max_load_ratio", "moe.dropped"}
+    assert rep["router"]["moe.dropped"]["max"] == 0.0
+    assert rep["router"]["moe.lb_loss"]["last"] == pytest.approx(
+        reports[-1]["moe_lb_loss"])
+    assert "moe.max_load_ratio" in fr.format_attribution(rep)
+
+
+# --- (f) the dense program is what it was ----------------------------------- #
+
+# sha256 of LlamaConfig.debug()'s parameters (PRNGKey(7)) and of the lowered
+# text of its train step, taken on the commit before the routed half went
+# into the block (bea6b96). A PR that means to change the dense program
+# replaces them; one that does not has changed it by accident.
+DENSE_PARAMS = "9f1ff577332224ef3f7ea22a3b79964c1124efa2069a1b139b8b8193af1eed1f"
+DENSE_STEP = {
+    ("", 1, "streamed"):
+        "90f52bb7182988719693b307dea1b7af700e18813e501c295d3f917e99fe4f41",
+    ("fsdp=4", 4, "streamed"):
+        "999a6a502c954414038f8f83723808c52872d6a452a507db4bdbe24232dee8db",
+    ("fsdp=4", 4, "upfront"):
+        "3383c9e196be230a9964da69289bc26aae093a4df436c9cfbae7037c1882d336",
+}
+
+
+def test_dense_parameter_tree_is_what_it_was():
+    cfg = LlamaConfig.debug()
+    p = llama.init_params(cfg, jax.random.PRNGKey(7))
+    assert sorted(p["layers"]) == sorted(
+        ["wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "attn_norm",
+         "mlp_norm"])
+    assert sum(x.size for x in jax.tree.leaves(p)) == cfg.num_params()
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(p)[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest() == DENSE_PARAMS
+    routed = program_cfg()
+    assert sum(x.size for x in jax.tree.leaves(
+        jax.eval_shape(lambda: llama.init_params(
+            routed, jax.random.PRNGKey(0))))) == routed.num_params()
+    published = dataclasses.replace(
+        routed, vocab_size=50304, dim=2048, n_layers=1, n_heads=16,
+        n_kv_heads=16, mlp_dim=1024, num_experts=64, experts_per_token=8)
+    assert published.num_params() == 625_616_896
+
+
+@pytest.mark.parametrize("spec,n,gather", list(DENSE_STEP))
+def test_dense_step_lowers_to_the_same_text(spec, n, gather):
+    from ray_tpu.train.spmd import make_spmd_train_step
+
+    cfg = LlamaConfig.debug()
+    init, step, _, _ = make_spmd_train_step(cfg, _mesh(spec, n), gather=gather)
+    state = jax.eval_shape(init._fn, jax.random.PRNGKey(0))
+    text = step._fn.lower(
+        state, jax.ShapeDtypeStruct((4, 33), jnp.int32)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        DENSE_STEP[(spec, n, gather)]
