@@ -43,6 +43,14 @@ _g_decode_buckets = Gauge(
     "ray_tpu_serve_decode_buckets",
     "Distinct padded KV lengths (compile buckets) the decode engine "
     "has served", tag_keys=("kind",))
+# the engine's resident weights by dtype, set once when it is built: the
+# leaves its programs multiply are held in cfg.dtype, so at a bfloat16
+# config nearly every byte reads dtype=bfloat16 and the float32 norms are
+# the rest; weight bytes under float32 are bytes every call would convert
+_g_engine_weight_bytes = Gauge(
+    "ray_tpu_serve_engine_weight_bytes",
+    "Bytes of model weights the decode engine keeps on the device, by "
+    "the dtype they are held in", tag_keys=("dtype",))
 
 # LlamaDecodeEngine's calls taken apart (one registration site per name):
 # the device program against what the host does on either side of it. The
@@ -80,7 +88,10 @@ class LlamaConfig:
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
-    dtype: Any = jnp.bfloat16  # compute dtype (params stored fp32)
+    # compute dtype: init_params and the trainer keep float32 master
+    # weights and convert at each product; LlamaDecodeEngine holds what it
+    # multiplies in this dtype
+    dtype: Any = jnp.bfloat16
     remat: bool = True
     loss_chunk: int = 256  # seq-chunk for the xent head; 0 = unchunked
     # routed experts in place of the dense MLP (0 = dense): mlp_dim is then
@@ -696,10 +707,47 @@ def copy_page_in_stores(k_pages, v_pages, src, dst):
                                          at))
 
 
+# the leaves the serving programs multiply (each stands under an
+# ``.astype(cfg.dtype)`` in embed_tokens / the head and in _layer_kv /
+# _layer_decode). By name, not by rank: the stacked norms are
+# two-dimensional too, and rms_norm uses them in float32.
+_MATMUL_TOP = ("embedding", "lm_head")
+_MATMUL_LAYER = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def serving_params(cfg: LlamaConfig, params) -> Dict[str, Any]:
+    """``params`` with every leaf the serving programs multiply held in
+    ``cfg.dtype``: the round-to-nearest conversion the programs apply in
+    front of each product, made once, in one jitted call over the leaves
+    that need it. Every other leaf (the norms; a weight already in
+    ``cfg.dtype``) is the array it was, and a tree with nothing to convert
+    is returned as it is. Traced inside the program that builds the
+    weights, the float32 ones are that program's temporaries."""
+    cd = jnp.dtype(cfg.dtype)
+    top = {k: params[k] for k in _MATMUL_TOP
+           if k in params and params[k].dtype != cd}
+    layers = {k: params["layers"][k] for k in _MATMUL_LAYER
+              if params["layers"][k].dtype != cd}
+    if not (top or layers):
+        return params
+    top, layers = jax.jit(lambda tree: jax.tree.map(
+        lambda w: w.astype(cd), tree))((top, layers))
+    return {**params, **top, "layers": {**params["layers"], **layers}}
+
+
 class LlamaDecodeEngine:
     """Paged-KV decode engine over the functional llama model — the
     engine protocol :class:`ray_tpu.serve.decode.DecodeScheduler` drives
     (prefill/decode/copy_page + pool/prefix_cache/page_size).
+
+    ``params`` is the tree the programs run on: ``init_params``' layout
+    with the matmul weights (``embedding``, ``lm_head``, ``wq`` .. ``w_down``)
+    in ``cfg.dtype``, converted ONCE here and not inside every call, and
+    the norms in float32. A float32 tree passed in (a trainer's) is
+    converted and not kept; leaves already in ``cfg.dtype`` are kept as
+    they are. At bfloat16 that is two bytes a parameter on the device
+    (3.8 GB at 1.89B parameters), which
+    ``ray_tpu_serve_engine_weight_bytes{dtype}`` reports.
 
     Physical pages live ON THE DEVICE, in two float32 arrays indexed by
     pool page id: ``k_pages`` / ``v_pages`` ``[L, n_pages, page_size,
@@ -727,10 +775,22 @@ class LlamaDecodeEngine:
         if params is None:
             # one jitted program, not a dozen eager ones: at 664.6M
             # parameters the eager form spends 67 s on a v5e, nearly all of
-            # it compiling per-leaf RNG programs (measured, PR 21)
-            params = jax.jit(partial(init_params, self.cfg))(
-                jax.random.PRNGKey(seed))
+            # it compiling per-leaf RNG programs (measured, PR 21). The
+            # conversion is part of it: the float32 tree is never resident
+            params = jax.jit(lambda key: serving_params(
+                self.cfg, init_params(self.cfg, key)))(
+                    jax.random.PRNGKey(seed))
+        else:
+            params = serving_params(self.cfg, params)
         self.params = params
+        # both tags always: a float32 engine reads bfloat16 = 0, and not
+        # what an earlier engine of this process left there
+        by_dtype = {"bfloat16": 0, "float32": 0}
+        for leaf in jax.tree.leaves(self.params):
+            by_dtype[leaf.dtype.name] = (by_dtype.get(leaf.dtype.name, 0)
+                                         + leaf.nbytes)
+        for name, nbytes in by_dtype.items():
+            _g_engine_weight_bytes.set(float(nbytes), tags={"dtype": name})
         self.page_size = int(page_size)
         self.pool = PagePool(n_pages, page_size)
         self.prefix_cache = PrefixCache(self.pool)

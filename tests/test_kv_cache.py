@@ -540,3 +540,211 @@ class TestLlamaEngine:
             assert len(got) == 2
             assert all(inside(g, c) and g[2] == c[2] == {"pages": 2}
                        for g, c in zip(got, calls))
+
+    # ---- the weights in the dtype they are multiplied in (PR 27)
+
+    MATMUL_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+    @staticmethod
+    def _float32_tree(cfg, seed=0):
+        import jax
+
+        from ray_tpu.models.llama import init_params
+
+        return jax.jit(lambda key: init_params(cfg, key))(
+            jax.random.PRNGKey(seed))
+
+    @classmethod
+    def _matmul_leaves(cls, params):
+        found = {k: params["layers"][k] for k in cls.MATMUL_LEAVES}
+        found.update({k: params[k] for k in ("embedding", "lm_head")
+                      if k in params})
+        return found
+
+    @staticmethod
+    def _programs(engine):
+        """The engine's two programs as it jits them, and arguments for
+        one call of each on the engine's own stores (not donated here):
+        a prefill of two pages, a decode of the position after it."""
+        import jax
+        import jax.numpy as jnp
+        from functools import partial
+
+        from ray_tpu.models.llama import (decode_step_with_cache,
+                                          prefill_with_cache)
+
+        toks = np.random.RandomState(5).randint(
+            0, engine.cfg.vocab_size, size=(1, 8)).astype(np.int32)
+        # the decode reads what a prefill wrote, not pages of zeros
+        engine.prefill([int(t) for t in toks[0, :7]], [9, 4])
+        stores = (jnp.array(engine.k_pages), jnp.array(engine.v_pages))
+        return {
+            "prefill": (jax.jit(partial(prefill_with_cache, engine.cfg)),
+                        stores + (toks, np.asarray([6, 2], np.int32),
+                                  np.asarray(6, np.int32))),
+            "decode": (jax.jit(partial(decode_step_with_cache, engine.cfg)),
+                       stores + (np.asarray([17], np.int32),
+                                 np.asarray(7, np.int32),
+                                 np.asarray([9, 4], np.int32)))}
+
+    @pytest.mark.parametrize("kind", ["prefill", "decode"])
+    def test_converted_tree_gives_the_float32_trees_result(self, engine,
+                                                           kind):
+        """Moving the conversion out of the call changes no operand of
+        any product: the float32 tree (converted in front of every
+        product, inside the call) and the engine's tree (converted once)
+        give the same logits and the same page stores, bit for bit."""
+        fn, args = self._programs(engine)[kind]
+        want = fn(self._float32_tree(engine.cfg), *args)
+        got = fn(engine.params, *args)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype == np.float32
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert np.abs(np.asarray(got[2])).max() > 0
+
+    def test_engine_holds_matmul_weights_in_compute_dtype(self, engine):
+        """By name: every leaf the programs multiply is ``cfg.dtype`` and
+        is the float32 leaf rounded once; every norm is float32 (the
+        stacked norms are two-dimensional too); the gauge reads the
+        tree's bytes by dtype."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.util.metrics import registry
+
+        full = self._float32_tree(engine.cfg)
+        assert jax.tree.structure(engine.params) == jax.tree.structure(full)
+        matmul = self._matmul_leaves(engine.params)
+        assert len(matmul) == 9  # debug() unties the head
+        for name, leaf in matmul.items():
+            assert leaf.dtype == jnp.bfloat16 == engine.cfg.dtype, name
+            np.testing.assert_array_equal(
+                np.asarray(leaf.astype(jnp.float32)),
+                np.asarray(self._matmul_leaves(full)[name].astype(
+                    jnp.bfloat16).astype(jnp.float32)))
+        norms = [engine.params["final_norm"],
+                 engine.params["layers"]["attn_norm"],
+                 engine.params["layers"]["mlp_norm"]]
+        assert all(n.dtype == jnp.float32 for n in norms)
+        assert len(jax.tree.leaves(engine.params)) == len(matmul) + 3
+        got = registry().local_values("ray_tpu_serve_engine_weight_bytes")
+        assert got[(("dtype", "bfloat16"),)] \
+            == sum(leaf.nbytes for leaf in matmul.values()) \
+            == 2 * (engine.cfg.num_params()
+                    - sum(n.size for n in norms))
+        assert got[(("dtype", "float32"),)] \
+            == sum(n.nbytes for n in norms)
+
+    @pytest.mark.parametrize("given", ["float32", "bfloat16", "mixed"])
+    def test_given_tree_is_converted_once_or_passed_through(self, given):
+        """``params=``: float32 matmul leaves are converted and not kept,
+        leaves already in ``cfg.dtype`` and the norms are the very arrays
+        that were passed."""
+        import jax.numpy as jnp
+
+        from ray_tpu.models.llama import LlamaConfig, LlamaDecodeEngine
+
+        cfg = LlamaConfig.debug()
+        tree = self._float32_tree(cfg, seed=3)
+        if given != "float32":
+            ready = LlamaDecodeEngine(cfg, tree, n_pages=4,
+                                      page_size=4).params
+            if given == "mixed":
+                ready = dict(ready, lm_head=tree["lm_head"], layers=dict(
+                    ready["layers"], wk=tree["layers"]["wk"]))
+            tree = ready
+        engine = LlamaDecodeEngine(cfg, tree, n_pages=4, page_size=4)
+        if given == "bfloat16":
+            assert engine.params is tree
+        was, now = self._matmul_leaves(tree), \
+            self._matmul_leaves(engine.params)
+        for name in was:
+            assert now[name].dtype == jnp.bfloat16, name
+            if was[name].dtype == jnp.bfloat16:
+                assert now[name] is was[name], name
+            else:
+                np.testing.assert_array_equal(
+                    np.asarray(now[name].astype(jnp.float32)),
+                    np.asarray(was[name].astype(jnp.bfloat16).astype(
+                        jnp.float32)))
+        for name in ("attn_norm", "mlp_norm"):
+            assert engine.params["layers"][name] is tree["layers"][name]
+        assert engine.params["final_norm"] is tree["final_norm"]
+        assert engine.prefill([1, 2, 3], [0]).shape == (cfg.vocab_size,)
+
+    def test_float32_config_converts_nothing(self):
+        """The engine decides from what it observes, a leaf's name and
+        dtype against ``cfg.dtype``: at ``dtype=float32`` the tree is the
+        one that was given and the gauge reads no bfloat16 byte."""
+        import dataclasses
+
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models.llama import LlamaConfig, LlamaDecodeEngine
+        from ray_tpu.util.metrics import registry
+
+        cfg = dataclasses.replace(LlamaConfig.debug(), dtype=jnp.float32)
+        tree = self._float32_tree(cfg)
+        engine = LlamaDecodeEngine(cfg, tree, n_pages=4, page_size=4)
+        assert engine.params is tree
+        built = LlamaDecodeEngine(cfg, n_pages=4, page_size=4, seed=0)
+        for got, want in zip(jax.tree.leaves(built.params),
+                             jax.tree.leaves(tree)):
+            assert got.dtype == jnp.float32
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        got = registry().local_values("ray_tpu_serve_engine_weight_bytes")
+        assert got == {(("dtype", "float32"),): 4.0 * cfg.num_params(),
+                       (("dtype", "bfloat16"),): 0.0}
+
+    @pytest.mark.parametrize("kind", ["prefill", "decode"])
+    def test_no_weight_is_converted_inside_a_call(self, engine, kind):
+        """The lowered program for the engine's own ``params`` holds no
+        float32 tensor of any matmul weight's shape, stacked or one
+        layer's: nothing is left to convert in a call. The float32 tree's
+        program holds them all (the check can see what it looks for)."""
+        fn, args = self._programs(engine)[kind]
+
+        def f32_weights_in(params):
+            text = fn.lower(params, *args).as_text()
+            shapes = set()
+            for leaf in self._matmul_leaves(params).values():
+                shapes.add(leaf.shape)
+                shapes.add(leaf.shape[1:] if leaf.ndim == 3 else leaf.shape)
+            return {s for s in shapes
+                    if f"tensor<{'x'.join(map(str, s))}xf32>" in text}
+
+        assert f32_weights_in(engine.params) == set()
+        assert len(f32_weights_in(self._float32_tree(engine.cfg))) >= 9
+
+    def test_reference_reads_the_converted_tree(self, engine):
+        """The benchmark's float32 reference (it casts every leaf up
+        itself) runs on ``engine.params``, the weights the engine
+        multiplies, and the engine's teacher-forced rows stay within the
+        serving configuration's tolerance of it."""
+        import json
+        import os
+        from functools import partial
+
+        import jax
+
+        import benchmarks
+        from benchmarks.reference.llama_decoder import logits_one
+
+        with open(os.path.join(os.path.dirname(benchmarks.__file__),
+                               "configs", "internlm2-1.8b.json")) as f:
+            tol = json.load(f)["correct"]["serve_logits_rel_tol"]
+        c = engine.cfg
+        file_cfg = {"num_attention_heads": c.n_heads,
+                    "num_key_value_heads": c.n_kv_heads,
+                    "head_dim": c.head_dim, "rms_norm_eps": c.norm_eps,
+                    "rope_theta": c.rope_theta,
+                    "tie_word_embeddings": c.tie_embeddings}
+        toks = np.random.RandomState(13).randint(
+            0, c.vocab_size, size=10).astype(np.int32)
+        want = np.asarray(jax.jit(partial(logits_one, file_cfg))(
+            engine.params, toks))[5:]
+        assert want.dtype == np.float32
+        got = self._teacher_forced(engine, toks, 6, [5, 0, 8])
+        rel = np.max(np.abs(got - want), axis=-1) / np.max(np.abs(want))
+        assert rel.shape == (5,) and float(rel.max()) < tol
