@@ -8,7 +8,7 @@ task submit/round-trip rates, actor call rates, wait. Run:
 
 Prints one line per op; with --json, a JSON object of all results. These
 are the regression gates for the control/object planes (the tensor plane is
-bench.py's job).
+benchmarks/run.py's job, on the chip).
 """
 
 from __future__ import annotations
@@ -1006,7 +1006,8 @@ def _goodput_bench(reps: int, check: bool) -> int:
 # recompile, so the gate can't pass with observation accidentally off.
 # The child also cross-checks the observatory's analytic MFU (XLA
 # cost_analysis FLOPs over the measured spmd.compute span) against the
-# bench.py 6ND+attention estimate over the SAME measured step time:
+# 6ND+attention estimate (6 FLOPs a parameter a token, plus 6 * layers *
+# heads * head_dim * seq for causal attention) over the SAME step time:
 # the two FLOPs models must agree within XLA_MFU_TOLERANCE_X
 # (cost_analysis counts every HLO op — remat, rngs, softmax — so it
 # sits above the 6ND floor; docs/observability.md documents the bound).
@@ -1083,7 +1084,7 @@ def _xla_bench_child() -> dict:
     bench_rec = xo.snapshot().get("xla.bench_step", {})
 
     # -- MFU agreement: analytic (cost_analysis / measured span) vs the
-    # bench.py 6ND+attn formula over the SAME measured step time -------
+    # 6ND+attn formula over the SAME measured step time ----------------
     for _ in range(XLA_MFU_STEPS):
         t0 = flight_recorder.now()
         _, loss = step_on(state, toks)

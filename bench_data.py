@@ -1,6 +1,6 @@
 """Data ingest microbenchmarks: operator fusion + zero-copy rechunk.
 
-Prints ONE JSON line (same convention as bench.py / bench_serve.py):
+Prints ONE JSON line (same convention as bench_serve.py):
 
     {"bench": "data",
      "fused":   {"rows_per_s": .., "store_puts": ..},

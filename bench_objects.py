@@ -1,6 +1,6 @@
 """Object data-plane microbenchmark: put/get latency + 2-node transfer MB/s.
 
-Prints ONE JSON line (same convention as bench.py):
+Prints ONE JSON line:
 
     {"bench": "objects", "put_ms": {"1KB": .., "1MB": .., "64MB": ..},
      "get_ms": {...}, "transfer_MBps": {"1KB": .., "1MB": .., "64MB": ..},

@@ -1,7 +1,7 @@
 """Serve dispatch-plane benchmark: compiled rings vs eager remote(),
 plus a sustained RPS ramp with autoscaling and load-shedding gates.
 
-Prints ONE JSON line (same convention as bench.py / bench_objects.py)
+Prints ONE JSON line (same convention as bench_objects.py)
 and writes it to ``--out`` (BENCH_SERVE.json):
 
     {"bench": "serve",

@@ -191,8 +191,8 @@ def kernel_checks(p: dict) -> dict:
     out["decode_logits"] = {"prefill_rel_err": pre_err,
                             "decode_rel_err": dec_err,
                             "max_abs_logit": scale}
-    # bf16 tolerance: forward() keeps logits in bf16, the cache path
-    # returns the same matmuls accumulated to float32
+    # bf16 tolerance: forward() attends with the flash kernel over the
+    # whole sequence, the cache path with float32 scores over its pages
     if not max(dec_err, pre_err) < 0.05:
         raise AssertionError(f"cached decode logits differ from forward: "
                              f"{out['decode_logits']}")

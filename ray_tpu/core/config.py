@@ -178,7 +178,7 @@ class Config:
     # XLA compile observatory (util/xla_observatory.py): per-process
     # registry of observed jitted executables (compile wall time,
     # cost/memory analyses, aval fingerprints) feeding the standard
-    # metrics/span channels. The kill switch exists so bench.py
+    # metrics/span channels. The kill switch exists so bench_core.py
     # --xla-bench can measure the observation cost (BENCH_XLA.json,
     # <=1% of the spmd step)
     xla_observatory_enabled: bool = True

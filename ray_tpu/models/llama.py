@@ -15,7 +15,8 @@ model definition runs dp/fsdp/tp/sp via GSPMD. Design choices for the MXU:
   dynamic control flow.
 
 The reference delegates all of this to torch/DeepSpeed (SURVEY.md §2.3);
-here it is the in-framework flagship used by Train/Serve/bench.
+here it is the in-framework model that Train, Serve and the benchmark
+(``benchmarks/run.py``) run.
 """
 
 from __future__ import annotations
@@ -109,26 +110,10 @@ class LlamaConfig:
         return self.dim // self.n_heads
 
     @staticmethod
-    def llama3_8b() -> "LlamaConfig":
-        return LlamaConfig()
-
-    @staticmethod
-    def llama3_1b() -> "LlamaConfig":
-        return LlamaConfig(vocab_size=128256, dim=2048, n_layers=16,
-                           n_heads=32, n_kv_heads=8, mlp_dim=8192)
-
-    @staticmethod
     def small(vocab_size: int = 32000) -> "LlamaConfig":
         """~110M params — single-chip bench size."""
         return LlamaConfig(vocab_size=vocab_size, dim=768, n_layers=12,
                            n_heads=12, n_kv_heads=4, mlp_dim=2048,
-                           max_seq_len=2048)
-
-    @staticmethod
-    def medium(vocab_size: int = 32000) -> "LlamaConfig":
-        """~500M params — fills a single v5e chip's MXU better."""
-        return LlamaConfig(vocab_size=vocab_size, dim=1280, n_layers=20,
-                           n_heads=16, n_kv_heads=8, mlp_dim=5120,
                            max_seq_len=2048)
 
     @staticmethod
@@ -137,21 +122,6 @@ class LlamaConfig:
         padding in the flash kernel."""
         return LlamaConfig(vocab_size=vocab_size, dim=1536, n_layers=16,
                            n_heads=12, n_kv_heads=6, mlp_dim=6144,
-                           max_seq_len=2048)
-
-    @staticmethod
-    def flagship(vocab_size: int = 32000) -> "LlamaConfig":
-        """~1.04B params, head_dim=128 — the largest config that fits one
-        v5e chip (16 GB HBM) with remat and an adafactor optimizer
-        (factored second moment, bf16 momentum — the T5/PaLM TPU recipe):
-        peak ~10 B/param (fp32 params + fp32 grads + bf16 momentum)
-        ~= 10.4 GB, leaving headroom for remat activations + the chunked
-        xent head. adamw variants peak at 14 B/param (fp32 nu) and OOM
-        above ~950M. The 8B-on-64-chips projection extrapolates from this
-        config's per-chip MFU and the multi-mesh collective costs in
-        BENCH_MULTI.md."""
-        return LlamaConfig(vocab_size=vocab_size, dim=2048, n_layers=16,
-                           n_heads=16, n_kv_heads=8, mlp_dim=7168,
                            max_seq_len=2048)
 
     @staticmethod
@@ -356,20 +326,57 @@ def add_router_losses(cfg: LlamaConfig, nll, stats):
     return total, report
 
 
+def positions_of(batch: int, seq: int):
+    """``[batch, seq]`` int32: every row counts 0 .. seq - 1."""
+    return jnp.arange(seq, dtype=jnp.int32)[None, :].repeat(batch, axis=0)
+
+
+def decoder_block(cfg: LlamaConfig, x, p, positions, attend, *,
+                  col_in=None, row_out=None, stat_axes=()):
+    """THE decoder block, for the train steps and for the serving programs:
+    ``attn_norm`` -> q / k / v (:func:`_qkv`) -> RoPE -> ``attend`` -> ``wo``
+    -> residual -> ``mlp_norm`` -> MLP (:func:`_mlp_half`) -> residual.
+
+    ``x``: the residual stream ``[B, T, dim]``; ``p``: ONE layer's weights,
+    whole or a tensor-parallel shard (the head counts are read off ``wq`` /
+    ``wk``, which on whole weights are the config's); ``attend(q, k, v)``:
+    what the caller attends over, ``[B, T, n_q, head_dim]`` back, with
+    ``k`` / ``v`` rotated and not yet repeated for GQA. ``col_in`` /
+    ``row_out``: a manual tensor-parallel caller's collectives where the
+    normed stream enters the column-parallel products and where the
+    row-parallel products (``wo``, the MLP's last) leave them; None where
+    nothing is split. ``stat_axes``: see :func:`_mlp_half`.
+
+    Returns ``(x, stats, (k, v))``: the residual stream, the MLP half's
+    stats, and this call's rotated keys and values, which a cache writer
+    keeps and a train step drops."""
+    same = lambda a: a  # noqa: E731
+    col_in, row_out = col_in or same, row_out or same
+    cd, hd = cfg.dtype, cfg.head_dim
+    B, T, _ = x.shape
+    nq, nkv = p["wq"].shape[-1] // hd, p["wk"].shape[-1] // hd
+    h = col_in(rms_norm(x, p["attn_norm"], cfg.norm_eps).astype(cd))
+    q, k, v = _qkv(cfg, p, h, nq, nkv, positions)
+    attn = attend(q, k, v).reshape(B, T, nq * hd)
+    x = x + row_out(attn @ p["wo"].astype(cd)).astype(x.dtype)
+    h = col_in(rms_norm(x, p["mlp_norm"], cfg.norm_eps).astype(cd))
+    y, stats = _mlp_half(cfg, p, h, stat_axes)
+    return x + row_out(y).astype(x.dtype), stats, (k, v)
+
+
+def flash_causal(q, k, v):
+    """``attend`` of the steps that run on local shards: the flash kernel."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, causal=True)
+
+
 def _layer(cfg: LlamaConfig, mesh, x, layer_params, positions):
-    """One decoder layer. x: [B, T, dim] (residual stream, cfg.dtype).
-    Returns ``(x, stats)``, ``stats`` as :func:`_mlp_half` gives them."""
-    p = layer_params
-    cd = cfg.dtype
-    B, T, d = x.shape
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps).astype(cd)
-    q, kk, vv = _qkv(cfg, p, h, cfg.n_heads, cfg.n_kv_heads, positions)
-    attn = _attention(cfg, q, kk, vv, mesh)
-    attn = attn.reshape(B, T, cfg.n_heads * cfg.head_dim)
-    x = x + (attn @ p["wo"].astype(cd)).astype(x.dtype)
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps).astype(cd)
-    y, stats = _mlp_half(cfg, p, h)
-    x = x + y.astype(x.dtype)
+    """One decoder layer under GSPMD (or on one device, ``mesh`` None):
+    the block over :func:`_attention`. Returns ``(x, stats)``."""
+    x, stats, _ = decoder_block(
+        cfg, x, layer_params, positions,
+        lambda q, k, v: _attention(cfg, q, k, v, mesh))
     if mesh is not None and mesh.size > 1:
         # pin the residual stream's layout at every block boundary:
         # without the constraint GSPMD is free to pick a different
@@ -425,9 +432,8 @@ def embed_tokens(cfg, params, tokens, mesh=None, table_sharded=None):
 def _backbone(cfg: LlamaConfig, params, tokens, mesh=None):
     """tokens [B, T] int32 -> ``(final-normed hidden states [B, T, dim],
     the layers' stats stacked [L])``."""
-    B, T = tokens.shape
     x = embed_tokens(cfg, params, tokens, mesh)
-    positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
+    positions = positions_of(*tokens.shape)
 
     layer_fn = partial(_layer, cfg, mesh)
     if cfg.remat:
@@ -445,10 +451,21 @@ def _head(cfg: LlamaConfig, params):
             else params["lm_head"])
 
 
+def _logits(cfg: LlamaConfig, x, head):
+    """Final-normed hidden states through ``head`` [dim, vocab] (whole or a
+    vocabulary shard): the product in cfg.dtype, the logits float32."""
+    return (x.astype(cfg.dtype) @ head.astype(cfg.dtype)).astype(jnp.float32)
+
+
+def head_logits(cfg: LlamaConfig, x, final_norm, head):
+    """The model's end: final norm -> head -> float32 logits."""
+    return _logits(cfg, rms_norm(x, final_norm, cfg.norm_eps), head)
+
+
 def forward(cfg: LlamaConfig, params, tokens, mesh=None):
-    """tokens [B, T] int32 -> logits [B, T, vocab] (cfg.dtype)."""
+    """tokens [B, T] int32 -> logits [B, T, vocab] float32."""
     x, _ = _backbone(cfg, params, tokens, mesh)
-    return (x.astype(cfg.dtype) @ _head(cfg, params).astype(cfg.dtype))
+    return _logits(cfg, x, _head(cfg, params))
 
 
 def _plain_chunk_nll(cfg: LlamaConfig, head):
@@ -456,9 +473,7 @@ def _plain_chunk_nll(cfg: LlamaConfig, head):
     fp32 log-softmax over the whole vocab."""
 
     def chunk_nll(x_c, t_c):
-        logits = (x_c.astype(cfg.dtype)
-                  @ head.astype(cfg.dtype)).astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
+        logp = jax.nn.log_softmax(_logits(cfg, x_c, head), axis=-1)
         return -jnp.take_along_axis(logp, t_c[..., None], axis=-1)[..., 0]
 
     return chunk_nll
@@ -511,15 +526,14 @@ def loss_fn(cfg: LlamaConfig, params, tokens, mesh=None):
     return loss_parts(cfg, params, tokens, mesh)[0]
 
 
-def _dense_only(cfg: LlamaConfig, who: str) -> None:
-    """Routed experts and QK-norm live in the training block (``_layer``,
-    ``_pp_layer``); a path that has its own copy of the block, or no way
-    out for the router's losses, says so instead of computing another
-    model."""
+def _dense_only(cfg: LlamaConfig, who: str, why: str) -> None:
+    """Routed experts and QK-norm are the block's (:func:`decoder_block`),
+    so every path COMPUTES them; a path that cannot yet answer for the
+    result says what it lacks instead of running."""
     if cfg.num_experts or cfg.qk_norm:
         raise NotImplementedError(
-            f"{who} runs the dense block without QK-norm; this config has "
-            f"num_experts={cfg.num_experts}, qk_norm={cfg.qk_norm}")
+            f"{who} takes no config with num_experts={cfg.num_experts}, "
+            f"qk_norm={cfg.qk_norm} yet: {why}")
 
 
 # --------------------------------------------------------------------------- #
@@ -533,29 +547,6 @@ def _gqa_repeat(cfg: LlamaConfig, k, v):
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     return k, v
-
-
-def _layer_kv(cfg: LlamaConfig, x, p, positions):
-    """One decoder layer that also RETURNS its (rotated) k/v — the
-    prefill path of the KV cache. Single-host (mesh=None), plain fp32
-    attention: decode numerics never depend on prefill matching a fused
-    kernel, only on the cached k/v bytes themselves."""
-    cd = cfg.dtype
-    B, T, d = x.shape
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps).astype(cd)
-    q = (h @ p["wq"].astype(cd)).reshape(B, T, cfg.n_heads, cfg.head_dim)
-    kk = (h @ p["wk"].astype(cd)).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    vv = (h @ p["wv"].astype(cd)).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
-    q, kk = rotary_embedding(q, kk, positions, cfg.rope_theta)
-    kr, vr = _gqa_repeat(cfg, kk, vv)
-    attn = plain_attention(q, kr, vr, causal=True)
-    attn = attn.reshape(B, T, cfg.n_heads * cfg.head_dim)
-    x = x + (attn @ p["wo"].astype(cd)).astype(x.dtype)
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps).astype(cd)
-    g = jax.nn.silu(h @ p["w_gate"].astype(cd))
-    u = h @ p["w_up"].astype(cd)
-    x = x + ((g * u) @ p["w_down"].astype(cd)).astype(x.dtype)
-    return x, kk, vv
 
 
 def _page_slab(pages, page):
@@ -605,39 +596,33 @@ def prefill_with_cache(cfg: LlamaConfig, params, k_pages, v_pages, tokens,
     page included (they hold the pad token's keys: finite, and masked by
     every decode until the sequence itself overwrites them), and the head
     is applied to position ``last`` alone."""
-    B, T = tokens.shape
     x = embed_tokens(cfg, params, tokens, None)
-    positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
+    positions = positions_of(*tokens.shape)
+
+    def attend(q, k, v):
+        # plain attention, float32 scores: decode numerics never depend on
+        # prefill matching a fused kernel, only on the cached bytes
+        return plain_attention(q, *_gqa_repeat(cfg, k, v), causal=True)
 
     def body(carry, layer_params):
-        h, kk, vv = _layer_kv(cfg, carry, layer_params, positions)
-        return h, (kk, vv)
+        h, _, kv = decoder_block(cfg, carry, layer_params, positions, attend)
+        return h, kv
 
     x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
     k_pages = _write_pages(k_pages, ks[:, 0], page_ids)
     v_pages = _write_pages(v_pages, vs[:, 0], page_ids)
     # final_norm and the head are per position: one row, not T
     x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x.astype(cfg.dtype)
-              @ _head(cfg, params).astype(cfg.dtype)).astype(jnp.float32)
+    logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
     return k_pages, v_pages, logits[0, 0]
 
 
-def _layer_decode(cfg: LlamaConfig, x, p, positions, k_cache, v_cache,
-                  length):
-    """One decoder layer for a single new token against a gathered,
-    page-padded KV view. ``k_cache``/``v_cache``: [Tpad, n_kv, head_dim]
-    (positions >= ``length`` are pad garbage, masked out). Returns the
-    residual stream plus the new token's k/v for the cache write."""
+def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv):
+    """One new token (``q``, ``kk``, ``vv``: the block's ``attend``
+    arguments) against ONE layer's gathered, page-padded keys and values
+    ``[Tpad, n_kv, head_dim]``: positions >= ``length`` are pad garbage and
+    masked; the token attends to the history and itself. Float32 scores."""
     cd = cfg.dtype
-    h = rms_norm(x, p["attn_norm"], cfg.norm_eps).astype(cd)
-    q = (h @ p["wq"].astype(cd)).reshape(1, 1, cfg.n_heads, cfg.head_dim)
-    kk = (h @ p["wk"].astype(cd)).reshape(1, 1, cfg.n_kv_heads,
-                                          cfg.head_dim)
-    vv = (h @ p["wv"].astype(cd)).reshape(1, 1, cfg.n_kv_heads,
-                                          cfg.head_dim)
-    q, kk = rotary_embedding(q, kk, positions, cfg.rope_theta)
     Tpad = k_cache.shape[0]
     K = jnp.concatenate([k_cache.astype(cd)[None], kk], axis=1)
     V = jnp.concatenate([v_cache.astype(cd)[None], vv], axis=1)
@@ -649,15 +634,8 @@ def _layer_decode(cfg: LlamaConfig, x, p, positions, k_cache, v_cache,
     valid = (idx < length) | (idx == Tpad)  # history + the token itself
     s = jnp.where(valid[None, None, None, :], s, -1e30)
     probs = jax.nn.softmax(s, axis=-1)
-    attn = jnp.einsum("bhqk,bkhd->bqhd", probs,
+    return jnp.einsum("bhqk,bkhd->bqhd", probs,
                       V.astype(jnp.float32)).astype(cd)
-    attn = attn.reshape(1, 1, cfg.n_heads * cfg.head_dim)
-    x = x + (attn @ p["wo"].astype(cd)).astype(x.dtype)
-    h = rms_norm(x, p["mlp_norm"], cfg.norm_eps).astype(cd)
-    g = jax.nn.silu(h @ p["w_gate"].astype(cd))
-    u = h @ p["w_up"].astype(cd)
-    x = x + ((g * u) @ p["w_down"].astype(cd)).astype(x.dtype)
-    return x, kk[:, 0], vv[:, 0]
 
 
 def decode_step_with_cache(cfg: LlamaConfig, params, k_pages, v_pages,
@@ -681,14 +659,13 @@ def decode_step_with_cache(cfg: LlamaConfig, params, k_pages, v_pages,
 
     def body(carry, xs):
         p, kc, vc = xs
-        h, kn, vn = _layer_decode(cfg, carry, p, positions, kc, vc, pos)
-        return h, (kn, vn)
+        h, _, (kn, vn) = decoder_block(
+            cfg, carry, p, positions, partial(_attend_cached, cfg, kc, vc, pos))
+        return h, (kn[:, 0], vn[:, 0])
 
     x, (kns, vns) = jax.lax.scan(body, x,
                                  (params["layers"], k_cache, v_cache))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x.astype(cfg.dtype)
-              @ _head(cfg, params).astype(cfg.dtype)).astype(jnp.float32)
+    logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
     at = (0, page_ids[pos // ps], pos % ps, 0, 0)
     k_pages = jax.lax.dynamic_update_slice(
         k_pages, kns[:, :, None].astype(k_pages.dtype), at)
@@ -708,8 +685,8 @@ def copy_page_in_stores(k_pages, v_pages, src, dst):
 
 
 # the leaves the serving programs multiply (each stands under an
-# ``.astype(cfg.dtype)`` in embed_tokens / the head and in _layer_kv /
-# _layer_decode). By name, not by rank: the stacked norms are
+# ``.astype(cfg.dtype)`` in embed_tokens / the head and in the block's
+# _qkv / wo / dense _mlp_half). By name, not by rank: the stacked norms are
 # two-dimensional too, and rms_norm uses them in float32.
 _MATMUL_TOP = ("embedding", "lm_head")
 _MATMUL_LAYER = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
@@ -771,7 +748,11 @@ class LlamaDecodeEngine:
         from ray_tpu.serve.kv_cache import PagePool, PrefixCache
 
         self.cfg = cfg or LlamaConfig.debug()
-        _dense_only(self.cfg, "LlamaDecodeEngine")
+        _dense_only(
+            self.cfg, "LlamaDecodeEngine",
+            "no test compares its logits with the reference for such a "
+            "config, and serving_params and the page store's sizing know "
+            "the dense weights only")
         if params is None:
             # one jitted program, not a dozen eager ones: at 664.6M
             # parameters the eager form spends 67 s on a v5e, nearly all of
@@ -1022,8 +1003,7 @@ def vp_chunk_nll(cfg: LlamaConfig, head_local, axis, gp):
     vloc = head_local.shape[-1]
 
     def chunk_nll(x_c, t_c):
-        logits = (x_c.astype(cfg.dtype)
-                  @ head_local.astype(cfg.dtype)).astype(jnp.float32)
+        logits = _logits(cfg, x_c, head_local)
         m = jax.lax.pmax(jax.lax.stop_gradient(jnp.max(logits, -1)), axis)
         lse = jnp.log(gp(jnp.sum(jnp.exp(logits - m[..., None]), -1))) + m
         off = jax.lax.axis_index(axis) * vloc
@@ -1043,47 +1023,6 @@ def vp_chunk_nll(cfg: LlamaConfig, head_local, axis, gp):
 # --------------------------------------------------------------------------- #
 # Pipeline-parallel train step (pipe [+ tensor/data] mesh axes)
 # --------------------------------------------------------------------------- #
-
-
-def _pp_layer(cfg: LlamaConfig, x, p, positions, tensor_axis=None,
-              collectives=None, stat_axes=()):
-    """One decoder layer on *local* shards inside a manual shard_map.
-    Returns ``(x, stats)``, ``stats`` as :func:`_mlp_half` gives them
-    (``stat_axes``: the mesh axes that split the batch, for a routed
-    layer's load fractions).
-
-    Head/mlp counts come from the shard shapes (Megatron-style manual TP:
-    q/k/v/gate/up column-parallel — no comm; wo/down row-parallel — psum
-    over ``tensor_axis``). Norm weights are full-width (replicated).
-    ``collectives``: optional ``(f, g)`` pair from :func:`tp_psum_pair`,
-    required when the caller differentiates INSIDE the shard_map body
-    (train/spmd.py); the pipeline path differentiates outside shard_map
-    and leaves it None for the raw psum."""
-    from ray_tpu.ops.flash_attention import flash_attention
-
-    fi, gp = collectives if collectives is not None else (None, None)
-    col_in = fi if fi is not None else (lambda h: h)
-    if not tensor_axis:
-        row_out = lambda y: y
-    elif gp is not None:
-        row_out = gp
-    else:
-        row_out = lambda y: jax.lax.psum(y, tensor_axis)
-    cd = cfg.dtype
-    B, T, d = x.shape
-    hd = cfg.head_dim
-    nq = p["wq"].shape[-1] // hd
-    nkv = p["wk"].shape[-1] // hd
-    h = col_in(rms_norm(x, p["attn_norm"], cfg.norm_eps).astype(cd))
-    q, kk, vv = _qkv(cfg, p, h, nq, nkv, positions)
-    attn = flash_attention(q, kk, vv, causal=True)
-    o = attn.reshape(B, T, nq * hd) @ p["wo"].astype(cd)
-    o = row_out(o)
-    x = x + o.astype(x.dtype)
-    h = col_in(rms_norm(x, p["mlp_norm"], cfg.norm_eps).astype(cd))
-    y, stats = _mlp_half(cfg, p, h, stat_axes)
-    y = row_out(y)
-    return x + y.astype(x.dtype), stats
 
 
 def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
@@ -1111,7 +1050,10 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
 
     if "pipe" not in mesh.axis_names:
         raise ValueError("mesh has no 'pipe' axis")
-    _dense_only(cfg, "make_pipeline_train_step")
+    _dense_only(
+        cfg, "make_pipeline_train_step",
+        "its stages pass the residual stream alone, so a router's losses "
+        "have no way out, and its layer specs name the dense leaves only")
     n_stages = mesh.shape["pipe"]
     if cfg.n_layers % n_stages:
         raise ValueError(f"{cfg.n_layers} layers not divisible by "
@@ -1120,6 +1062,8 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
                                          weight_decay=0.1)
     ta = "tensor" if ("tensor" in mesh.axis_names
                       and mesh.shape["tensor"] > 1) else None
+    # differentiated OUTSIDE the shard_map: the raw psum (tp_psum_pair)
+    row_out = (lambda y: jax.lax.psum(y, ta)) if ta else None
     batch_axes = tuple(a for a in ("slice", "data", "fsdp")
                        if a in mesh.axis_names)
     bspec = batch_axes if batch_axes else None
@@ -1178,7 +1122,8 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
 
         def stage_fn(sp, act):
             def one_layer(carry, lp):
-                return _pp_layer(cfg, carry, lp, act["pos"], ta)[0], None
+                return decoder_block(cfg, carry, lp, act["pos"], flash_causal,
+                                     row_out=row_out)[0], None
 
             body = one_layer
             if cfg.remat:
@@ -1200,17 +1145,14 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
 
     def loss(params, tokens):
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
-        B, T = inputs.shape
         # pipeline shards the table by its own specs P(ta, None): sharded
         # iff the tensor axis is live — DEFAULT_RULES inference would
         # misread a dp/fsdp batch axis as embed sharding
         x = embed_tokens(cfg, params, inputs, mesh,
                          table_sharded=ta is not None)
-        positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
-        x = pipe_fn(params["layers"], x, positions)
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = (x.astype(cfg.dtype)
-                  @ _head(cfg, params).astype(cfg.dtype)).astype(jnp.float32)
+        x = pipe_fn(params["layers"], x, positions_of(*inputs.shape))
+        logits = head_logits(cfg, x, params["final_norm"],
+                             _head(cfg, params))
         logp = jax.nn.log_softmax(logits, axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         return nll.mean()

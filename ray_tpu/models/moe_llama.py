@@ -19,7 +19,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import LlamaConfig, _attention, embed_tokens
+from ray_tpu.models.llama import (LlamaConfig, _attention, _head,
+                                  embed_tokens, head_logits, positions_of)
 from ray_tpu.ops.layers import rms_norm, rotary_embedding
 from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.parallel.sharding import DEFAULT_RULES, logical_sharding
@@ -130,10 +131,9 @@ def _layer(cfg: MoEConfig, mesh, x, p, positions):
 
 
 def forward_with_aux(cfg: MoEConfig, params, tokens, mesh=None):
-    """tokens [B,T] -> (logits [B,T,V], total aux loss)."""
-    B, T = tokens.shape
+    """tokens [B,T] -> (logits [B,T,V] float32, total aux loss)."""
     x = embed_tokens(cfg, params, tokens, mesh)
-    positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
+    positions = positions_of(*tokens.shape)
 
     layer_fn = partial(_layer, cfg, mesh)
     if cfg.remat:
@@ -146,17 +146,14 @@ def forward_with_aux(cfg: MoEConfig, params, tokens, mesh=None):
 
     (x, aux), _ = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embedding"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    logits = x.astype(cfg.dtype) @ head.astype(cfg.dtype)
+    logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
     return logits, aux / cfg.n_layers
 
 
 def loss_fn(cfg: MoEConfig, params, tokens, mesh=None):
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     logits, aux = forward_with_aux(cfg, params, inputs, mesh)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     return nll.mean() + cfg.aux_loss_coef * aux
 

@@ -174,15 +174,15 @@ def _llama_stage_fwd(cfg, sparams, x):
     int32 tokens there, the residual stream everywhere else), then this
     stage's decoder blocks via lax.scan over the sliced layer stack."""
     import jax
-    import jax.numpy as jnp
 
-    from ray_tpu.models.llama import _dense_only, _layer
+    from ray_tpu.models.llama import _dense_only, _layer, positions_of
 
-    _dense_only(cfg, "the MPMD pipeline")
+    _dense_only(cfg, "the MPMD pipeline",
+                "its stages pass the residual stream alone, so a router's "
+                "losses have no way out, and no test runs QK-norm through it")
     if "embedding" in sparams:
         x = sparams["embedding"].astype(cfg.dtype)[x]
-    B, T = x.shape[0], x.shape[1]
-    positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
+    positions = positions_of(*x.shape[:2])
 
     def body(carry, lp):
         return _layer(cfg, None, carry, lp, positions)[0], None
@@ -198,12 +198,10 @@ def _llama_stage_loss(cfg, sparams, a, tokens):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import rms_norm
+    from ray_tpu.models.llama import head_logits
 
     x = _llama_stage_fwd(cfg, sparams, a)
-    x = rms_norm(x, sparams["final_norm"], cfg.norm_eps)
-    logits = (x.astype(cfg.dtype)
-              @ sparams["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    logits = head_logits(cfg, x, sparams["final_norm"], sparams["lm_head"])
     targets = tokens[:, 1:]
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]

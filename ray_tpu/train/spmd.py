@@ -100,7 +100,6 @@ __all__ = [
     "llama_partition_rules",
     "spmd_param_specs",
     "make_spmd_train_step",
-    "make_collective_probes",
     "spmd_train_loop",
     "tree_paths",
 ]
@@ -273,8 +272,7 @@ def _is_spec(x):
 def spmd_param_specs(cfg, mesh, rules=None):
     """(abstract param tree, PartitionSpec tree) for ``cfg`` on ``mesh``
     — the rule table matched and restricted to the mesh's live axes.
-    Shared by the train step, the collective probes, and bench's
-    analytic residency accounting."""
+    What the train step shards its state by."""
     import jax
 
     from ray_tpu.models.llama import init_params
@@ -312,14 +310,14 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
       Folds to ``"upfront"`` when the mesh has no live fsdp axis.
 
     A live ``tensor`` axis shards heads/mlp/vocab THROUGH compute
-    (Megatron manual TP via ``_pp_layer`` + ``tp_psum_pair`` — exact
+    (Megatron manual TP: ``decoder_block`` + ``tp_psum_pair`` — exact
     grads under value_and_grad inside shard_map), with vocab-parallel
     embedding and cross-entropy; tensor-sharded dims are never
     gathered. ``seq``/``pipe``/``expert`` still route to the GSPMD /
     pipeline steps.
 
     A config with experts (``cfg.num_experts``) runs its routed MLP half
-    in the same layer (``_pp_layer`` -> ``ops/moe.routed_mlp``), every
+    in the same layer (``decoder_block`` -> ``ops/moe.routed_mlp``), every
     expert on every device; the router's losses leave the layer stack as
     the scan's stacked outputs under both gather schedules and are added
     to the loss (``add_router_losses``). Its step returns a third value,
@@ -357,10 +355,12 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     from ray_tpu.collective import pmean_tree
     from ray_tpu.models.llama import (
         _plain_chunk_nll,
-        _pp_layer,
         add_router_losses,
         chunked_nll_mean,
+        decoder_block,
+        flash_causal,
         init_params,
+        positions_of,
         tp_psum_pair,
         vp_chunk_nll,
         vp_embed,
@@ -476,14 +476,14 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     lspecs = param_specs["layers"]
     # one layer (scan dim sliced off) -> spec dims shift left by one
     lspecs1 = jax.tree.map(lambda sp: P(*sp[1:]), lspecs, is_leaf=_is_spec)
-    collectives = tp_psum_pair(tensor) if tensor is not None else None
-    fi, gp = collectives if collectives is not None else (None, None)
+    # value_and_grad runs INSIDE the shard_map: the pair, not a raw psum
+    fi, gp = tp_psum_pair(tensor) if tensor is not None else (None, None)
 
     def layer_fn(x, lp):
-        B, T, _ = x.shape
-        positions = jnp.arange(T, dtype=jnp.int32)[None, :].repeat(B, axis=0)
-        return _pp_layer(cfg, x, lp, positions, tensor_axis=tensor,
-                         collectives=collectives, stat_axes=batch_axes)
+        x, stats, _ = decoder_block(
+            cfg, x, lp, positions_of(*x.shape[:2]), flash_causal,
+            col_in=fi, row_out=gp, stat_axes=batch_axes)
+        return x, stats
 
     def gather_layer(shards):
         return jax.tree.map(gather_leaf, shards, lspecs1)
@@ -600,75 +600,6 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         donate_argnums=(0,) if donate else (),
     ), "spmd.train_step")
     return init_jit, train_step, data_sharding, state_shardings
-
-
-def make_collective_probes(cfg, mesh, rules=None):
-    """Jitted probe programs that price the step's collective seams
-    OUTSIDE the fused step (an op inside a jit cannot be timed):
-    ``gather_probe(params)`` all-gathers every fsdp-sharded leaf — the
-    upfront schedule's full-tree gather — and ``scatter_probe(params)``
-    reduce-scatters a same-shaped full tree — the backward's
-    psum_scatter. Each returns a scalar that depends on every
-    collective's output so nothing constant-folds or DCEs away.
-    ``bench.py measure_sharded`` times them; the train loop does not
-    (a one-shot gather of the whole tree is a program the streamed step
-    never runs: what the step's collectives cost is in the device
-    trace)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from ray_tpu.util.jax_compat import shard_map
-
-    _, specs = spmd_param_specs(cfg, mesh, rules)
-    fsdp = "fsdp" if "fsdp" in mesh.axis_names else None
-
-    def fsdp_dim(spec):
-        for dim, ax in enumerate(spec):
-            if fsdp is not None and fsdp in (
-                    ax if isinstance(ax, tuple) else (ax,)):
-                return dim
-        return None
-
-    def gather_body(shards):
-        acc = [jnp.zeros((), jnp.float32)]
-
-        def one(leaf, spec):
-            d = fsdp_dim(spec)
-            if d is not None:
-                full = jax.lax.all_gather(leaf, fsdp, axis=d, tiled=True)
-                acc.append(full.reshape(-1)[0].astype(jnp.float32))
-            return leaf
-
-        jax.tree.map(one, shards, specs)
-        return sum(acc)
-
-    def scatter_body(shards):
-        acc = [jnp.zeros((), jnp.float32)]
-
-        def one(leaf, spec):
-            d = fsdp_dim(spec)
-            if d is not None:
-                shape = list(leaf.shape)
-                shape[d] = shape[d] * mesh.shape[fsdp]
-                # seed from the input so the full buffer can't fold to
-                # a constant before the collective
-                seed = leaf.reshape(-1)[0]
-                full = jnp.ones(shape, leaf.dtype) * seed
-                sh = jax.lax.psum_scatter(full, fsdp, scatter_dimension=d,
-                                          tiled=True)
-                acc.append(sh.reshape(-1)[0].astype(jnp.float32))
-            return leaf
-
-        jax.tree.map(one, shards, specs)
-        return sum(acc)
-
-    def build(body):
-        return jax.jit(shard_map(body, mesh=mesh, in_specs=(specs,),
-                                 out_specs=P(), check=False))
-
-    return build(gather_body), build(scatter_body)
-
 
 
 # --------------------------------------------------------------------------- #

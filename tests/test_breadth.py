@@ -121,6 +121,8 @@ class TestServeMultiplex:
                 model = await self.get_model(mid)
                 return {"model": model, "loads": list(self.loads)}
 
+        # not the default port 8000: another xdist worker may hold it
+        serve.start(serve.HTTPOptions(port=0))
         handle = serve.run(Multi.bind(), route_prefix="/multi")
         h1 = handle.options(multiplexed_model_id="a")
         out1 = h1.remote({"x": 1}).result(timeout=60)

@@ -167,6 +167,8 @@ def test_cluster_events_end_to_end():
         def hello(x):
             return "hi"
 
+        # not the default port 8000: another xdist worker may hold it
+        serve.start(serve.HTTPOptions(port=0))
         serve.run(hello.bind(), route_prefix=None)
         assert _wait_for(lambda: state.list_cluster_events(source="SERVE"))
 

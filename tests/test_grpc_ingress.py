@@ -13,7 +13,10 @@ grpc = pytest.importorskip("grpc")
 def grpc_serve(ray_start_regular):
     from ray_tpu import serve
 
-    serve.start(grpc_options=serve.gRPCOptions(port=0))
+    # the HTTP proxy starts too: on a port of its own, not the default
+    # 8000 that another xdist worker's serve test may hold
+    serve.start(serve.HTTPOptions(port=0),
+                grpc_options=serve.gRPCOptions(port=0))
     yield serve
     serve.shutdown()
 

@@ -140,6 +140,18 @@ class TestRecordGC:
 
             _gc.collect()
             head.gc_task_records(ttl_s=0)
+        # the last batch's refs release through the __del__ reaper thread:
+        # wait on that release with a deadline, as the stream test above
+        # does (lost once to the reaper under six xdist workers)
+        from ray_tpu.core.object_ref import flush_pending_drops
+
+        deadline = time.monotonic() + 10
+        while head.tasks and time.monotonic() < deadline:
+            _gc.collect()
+            flush_pending_drops(timeout=2.0)
+            head.gc_task_records(ttl_s=0)
+            if head.tasks:
+                time.sleep(0.05)
         assert len(head.tasks) == 0
 
 

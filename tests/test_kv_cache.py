@@ -373,9 +373,55 @@ class TestLlamaEngine:
         want = self._forward_logits(engine, toks)[5:]
         assert scattered.shape == want.shape == (5, engine.cfg.vocab_size)
         scale = float(np.max(np.abs(want)))
-        # bf16: forward() keeps its logits in bf16, the engine's are the
-        # same products accumulated to float32
+        # bf16: forward() attends with the flash path over the whole
+        # sequence, the engine with float32 scores over cached pages
         assert float(np.max(np.abs(scattered - want))) / scale < 0.05
+
+    def test_qk_norm_through_the_serving_programs_matches_forward(self):
+        """The serving programs run the trainer's block, so they compute
+        QK-norm where the config has it: a dense QK-norm config through
+        ``prefill_with_cache`` and one ``decode_step_with_cache``, called
+        directly (the engine refuses the config until its logits are held
+        to the reference), against ``forward`` on the same tokens; and the
+        norms are read, not skipped."""
+        import dataclasses
+        from functools import partial
+
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import llama
+
+        cfg = dataclasses.replace(llama.LlamaConfig.debug(), qk_norm=True)
+        params = llama.init_params(cfg, jax.random.PRNGKey(3))
+        rng = np.random.RandomState(5)
+        for name in ("q_norm", "k_norm"):  # away from one: a skipped norm shows
+            leaf = params["layers"][name]
+            params["layers"][name] = leaf * jnp.asarray(
+                rng.uniform(0.5, 1.5, leaf.shape), jnp.float32)
+        ps, toks = 4, rng.randint(0, cfg.vocab_size, size=7).astype(np.int32)
+        pages = np.asarray([5, 2], np.int32)
+
+        def serve(params):
+            store = jnp.zeros((cfg.n_layers, 8, ps, cfg.n_kv_heads,
+                               cfg.head_dim), jnp.float32)
+            padded = np.zeros((1, 2 * ps), np.int32)
+            padded[0, :6] = toks[:6]
+            k, v, pre = jax.jit(partial(llama.prefill_with_cache, cfg))(
+                params, store, store, padded, pages, np.asarray(5, np.int32))
+            _, _, dec = jax.jit(partial(llama.decode_step_with_cache, cfg))(
+                params, k, v, toks[6:7], np.asarray(6, np.int32), pages)
+            return np.stack([np.asarray(pre), np.asarray(dec)])
+
+        got = serve(params)
+        want = np.asarray(jax.jit(partial(llama.forward, cfg))(
+            params, jnp.asarray(toks[None])))[0, 5:]
+        scale = float(np.max(np.abs(want)))
+        assert float(np.max(np.abs(got - want))) / scale < 0.05
+        plain = dict(params, layers=dict(
+            params["layers"],
+            q_norm=jnp.ones_like(params["layers"]["q_norm"])))
+        assert float(np.max(np.abs(serve(plain) - got))) / scale > 0.05
 
     def test_alternating_sequences_do_not_touch_each_other(self, engine):
         """Two sequences decoded in turn: each one's logits are what it

@@ -15,6 +15,10 @@ def test_every_node_proxies(ray_start_cluster):
 
     serve.start(http_options=serve.HTTPOptions(
         host="127.0.0.1", port=0, proxy_location="EveryNode"))
+    # serve.run() below starts the driver's own proxy where there is none,
+    # on the default port 8000, which another xdist worker's serve test
+    # may hold: give it a port of its own first
+    serve.start(http_options=serve.HTTPOptions(host="127.0.0.1", port=0))
 
     @serve.deployment(num_replicas=1)
     class Hello:

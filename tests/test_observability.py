@@ -461,6 +461,8 @@ def test_dashboard_serve_and_pubsub_endpoints():
         def hello(x):
             return "hi"
 
+        # not the default port 8000: another xdist worker may hold it
+        serve.start(serve.HTTPOptions(port=0))
         serve.run(hello.bind(), route_prefix=None)
         with urllib.request.urlopen(base + "/api/serve", timeout=30) as r:
             summary = json.loads(r.read())

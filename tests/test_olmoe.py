@@ -5,7 +5,8 @@ widths on the CPU: the routed MLP half and its gradients, the case where
 every token picks the same experts, QK-norm attention, the loss with its
 router losses APART, the controls a tolerance has to refuse, the trainer's
 step on one device against ``fsdp=4`` under both gather schedules, and the
-dense program, which must be what it was."""
+lowered text of every program that runs the block, which must be what it
+was."""
 
 import dataclasses
 import hashlib
@@ -319,8 +320,11 @@ def test_tensor_axis_is_refused():
 
 
 def test_paths_with_their_own_block_refuse_the_config():
-    with pytest.raises(NotImplementedError, match="dense block"):
+    with pytest.raises(NotImplementedError,
+                       match="no test compares its logits with the "
+                             "reference") as refused:
         llama.LlamaDecodeEngine(program_cfg())
+    assert "dense block" not in str(refused.value)
 
 
 def test_loop_reports_the_router_and_records_its_gauges():
@@ -356,21 +360,11 @@ def test_loop_reports_the_router_and_records_its_gauges():
     assert "moe.max_load_ratio" in fr.format_attribution(rep)
 
 
-# --- (f) the dense program is what it was ----------------------------------- #
+# --- (f) the programs are what they were ------------------------------------ #
 
-# sha256 of LlamaConfig.debug()'s parameters (PRNGKey(7)) and of the lowered
-# text of its train step, taken on the commit before the routed half went
-# into the block (bea6b96). A PR that means to change the dense program
-# replaces them; one that does not has changed it by accident.
+# sha256 of LlamaConfig.debug()'s parameters (PRNGKey(7)), taken on the commit
+# before the routed half went into the block (bea6b96).
 DENSE_PARAMS = "9f1ff577332224ef3f7ea22a3b79964c1124efa2069a1b139b8b8193af1eed1f"
-DENSE_STEP = {
-    ("", 1, "streamed"):
-        "90f52bb7182988719693b307dea1b7af700e18813e501c295d3f917e99fe4f41",
-    ("fsdp=4", 4, "streamed"):
-        "999a6a502c954414038f8f83723808c52872d6a452a507db4bdbe24232dee8db",
-    ("fsdp=4", 4, "upfront"):
-        "3383c9e196be230a9964da69289bc26aae093a4df436c9cfbae7037c1882d336",
-}
 
 
 def test_dense_parameter_tree_is_what_it_was():
@@ -395,14 +389,104 @@ def test_dense_parameter_tree_is_what_it_was():
     assert published.num_params() == 625_616_896
 
 
-@pytest.mark.parametrize("spec,n,gather", list(DENSE_STEP))
-def test_dense_step_lowers_to_the_same_text(spec, n, gather):
+def _step_text(init, step):
+    state = jax.eval_shape(init._fn, jax.random.PRNGKey(0))
+    return step._fn.lower(
+        state, jax.ShapeDtypeStruct((4, 33), jnp.int32)).as_text()
+
+
+def _spmd_text(cfg, spec, n, gather="streamed"):
     from ray_tpu.train.spmd import make_spmd_train_step
 
-    cfg = LlamaConfig.debug()
-    init, step, _, _ = make_spmd_train_step(cfg, _mesh(spec, n), gather=gather)
-    state = jax.eval_shape(init._fn, jax.random.PRNGKey(0))
-    text = step._fn.lower(
-        state, jax.ShapeDtypeStruct((4, 33), jnp.int32)).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == \
-        DENSE_STEP[(spec, n, gather)]
+    return _step_text(*make_spmd_train_step(cfg, _mesh(spec, n),
+                                            gather=gather)[:2])
+
+
+def _pipeline_text(**axes):
+    from ray_tpu.parallel.mesh import make_mesh
+
+    return _step_text(*llama.make_pipeline_train_step(
+        LlamaConfig.debug(), make_mesh(axis_sizes=axes), 2)[:2])
+
+
+def _serving_text(program, n):
+    """``program`` of the decode engine at ``n`` pages, lowered as the
+    engine jits it."""
+    from functools import partial
+
+    cfg, ps = LlamaConfig.debug(), 8
+    params = jax.eval_shape(
+        lambda key: llama.serving_params(cfg, llama.init_params(cfg, key)),
+        jax.random.PRNGKey(0))
+    store = jax.ShapeDtypeStruct(
+        (cfg.n_layers, 16, ps, cfg.n_kv_heads, cfg.head_dim), jnp.float32)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = {llama.prefill_with_cache: (i32(1, n * ps), i32(n), i32()),
+            llama.decode_step_with_cache: (i32(1), i32(), i32(n))}[program]
+    return jax.jit(partial(program, cfg), donate_argnums=(1, 2)).lower(
+        params, store, store, *args).as_text()
+
+
+def _gspmd_text():
+    from ray_tpu.parallel.mesh import make_mesh
+
+    return _step_text(*llama.make_train_step(
+        LlamaConfig.debug(), make_mesh(devices=jax.devices()[:1]))[:2])
+
+
+# sha256 of the lowered text of every program that runs the decoder block.
+# The first three: the dense trainer's step, taken on bea6b96 (before the
+# routed half went into the block). The rest were taken on 7bda524, the
+# commit before the five copies of the block became ``decoder_block``: the
+# routed step, the serving programs (page size 8, float32 stores of 16
+# pages, the ``serving_params`` tree), the GSPMD and pipeline steps, and the
+# two steps whose ``col_in`` / ``row_out`` are collectives. A PR that means
+# to change a program replaces its hash; one that does not has changed it
+# by accident.
+PROGRAMS = {
+    "dense spmd, one device": (
+        "90f52bb7182988719693b307dea1b7af700e18813e501c295d3f917e99fe4f41",
+        lambda: _spmd_text(LlamaConfig.debug(), "", 1)),
+    "dense spmd, fsdp=4 streamed": (
+        "999a6a502c954414038f8f83723808c52872d6a452a507db4bdbe24232dee8db",
+        lambda: _spmd_text(LlamaConfig.debug(), "fsdp=4", 4)),
+    "dense spmd, fsdp=4 upfront": (
+        "3383c9e196be230a9964da69289bc26aae093a4df436c9cfbae7037c1882d336",
+        lambda: _spmd_text(LlamaConfig.debug(), "fsdp=4", 4, "upfront")),
+    "routed spmd, one device": (
+        "da0b5b8048e7ac43a9d7a87e06a7d195f34defd5ffc8c45124cc7afeea0fad9e",
+        lambda: _spmd_text(program_cfg(), "", 1)),
+    "routed spmd, fsdp=4 streamed": (
+        "c4277f392db5d65581faa4423e4fe1eea8e33680efbdce16470a96cbabb50d4d",
+        lambda: _spmd_text(program_cfg(), "fsdp=4", 4)),
+    "dense spmd, fsdp=2 tensor=2": (
+        "fe19fe4132d3b6f18554e68fa84f50481a9b1ca17d1e57bc5eefdf1a667bf3fb",
+        lambda: _spmd_text(LlamaConfig.debug(), "fsdp=2,tensor=2", 4)),
+    "prefill, 1 page": (
+        "7a6a8e472df32aac1668379c02df7a0b9cb645c7df262a647bd42f0788f6845f",
+        lambda: _serving_text(llama.prefill_with_cache, 1)),
+    "prefill, 2 pages": (
+        "c91a3edb75f0fc7b96bb84d087caa02c7a8a631584a8169946e53c2ed5754dc1",
+        lambda: _serving_text(llama.prefill_with_cache, 2)),
+    "decode, 2 pages": (
+        "80aafab84a7adbcfd024c81f93ee91a11faf64b023ebb12b6c1db6a18e8702f6",
+        lambda: _serving_text(llama.decode_step_with_cache, 2)),
+    "gspmd, one device": (
+        "7754f0780379b5b1865945af2396c496b788f0a15139a3e003d229cb0ca194de",
+        _gspmd_text),
+    "pipeline, pipe=2": (
+        "14adea293974ad064dfb395d399b9638952654f126b9bfeade82a89eea856de2",
+        lambda: _pipeline_text(pipe=2)),
+    "pipeline, pipe=2 tensor=2": (
+        "44b3a4ca71797b37d385ba93e44dcbae037328c2518c14d023942ea78641f8ef",
+        lambda: _pipeline_text(pipe=2, tensor=2)),
+}
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS))
+def test_program_lowers_to_the_same_text(program):
+    sha, lower = PROGRAMS[program]
+    assert hashlib.sha256(lower().encode()).hexdigest() == sha

@@ -14,6 +14,9 @@ from ray_tpu.serve import InputNode
 @pytest.fixture
 def cluster():
     ray_tpu.init(num_cpus=4)
+    # a port of its own: serve.run() alone would take the default, 8000,
+    # which another xdist worker's serve test may hold
+    serve.start(serve.HTTPOptions(port=0))
     yield
     serve.shutdown()
     ray_tpu.shutdown()

@@ -17,7 +17,9 @@ from ray_tpu import serve
 @pytest.fixture
 def serve_instance():
     ray_tpu.init(num_cpus=4)
-    serve.start()
+    # a port of its own (the OS's choice): the default, 8000, is held by
+    # whichever other xdist worker runs a serve test at the moment
+    serve.start(serve.HTTPOptions(port=0))
     yield
     serve.shutdown()
     ray_tpu.shutdown()

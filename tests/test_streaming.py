@@ -244,6 +244,8 @@ def test_serve_streaming_and_data_split_head_free(ray_start_regular):
             for i in range(n):
                 yield f"chunk{i}"
 
+    # not the default port 8000: another xdist worker may hold it
+    serve.start(serve.HTTPOptions(port=0))
     h = serve.run(Streamer.bind())
     assert list(h.options(stream=True).remote(2)) == ["chunk0", "chunk1"]
     before = len(head.tasks)
