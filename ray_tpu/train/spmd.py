@@ -307,6 +307,9 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
       remat), then ``psum_scatter``s the layer grad straight back to
       shards. At most two fsdp-full layers (current + prefetched) are
       ever live, so peak param residency stays O(tree/L), not O(tree).
+      The carried gather is under ``stop_gradient``: its cotangent is
+      zero by construction, and differentiated it reduce-scattered a
+      second whole layer (of zeros) a layer in the backward.
       Folds to ``"upfront"`` when the mesh has no live fsdp axis.
 
     A live ``tensor`` axis shards heads/mlp/vocab THROUGH compute
@@ -519,13 +522,20 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
 
     streamed_apply = _make_streamed_apply()
 
+    def prefetch_layer(shards):
+        """The gathered layer the scan CARRIES. ``streamed_apply`` gives it
+        a zero cotangent and takes the layer's gradient from its own
+        re-gather, so nothing is differentiated here: a gather left in the
+        backward reduce-scatters a whole layer of zeros a layer a step."""
+        return jax.lax.stop_gradient(gather_layer(shards))
+
     def run_layers(x, layer_shards):
         if gather_mode == "streamed":
-            first = gather_layer(
+            first = prefetch_layer(
                 jax.tree.map(lambda a: a[0], layer_shards))
             # xs pairs each layer's shards with the NEXT layer's (rolled
             # by -1); the wrap-around gather of layer 0 at the last step
-            # feeds a dead carry and DCEs away
+            # feeds a carry nobody reads
             xs = (layer_shards,
                   jax.tree.map(lambda a: jnp.roll(a, -1, axis=0),
                                layer_shards))
@@ -535,7 +545,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
                 cur_sh, nxt_sh = xs_i
                 # issue layer i+1's gather FIRST: XLA schedules the
                 # collective to overlap layer i's matmuls
-                nxt = gather_layer(nxt_sh)
+                nxt = prefetch_layer(nxt_sh)
                 h, stats = streamed_apply(h, cur, cur_sh)
                 return (h, nxt), stats
 
@@ -698,6 +708,10 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
     shard's ``to_jax`` (sharded, double-buffered ingest) reading the
     ``tokens`` column; otherwise a synthetic token stream feeds the
     step through the same per-shard placement path.
+
+    The loop runs one step ahead of its reports: step i + 1 is issued
+    before step i's loss is waited for, and step i is reported when that
+    loss is ready.
     """
     import jax
     import optax
@@ -768,8 +782,60 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
 
     t0 = time.perf_counter()
     tokens_done = 0
-    loss = None
     win_t, win_tokens, win_step = t0, 0, 0  # since last report (gauges)
+    ready_at = 0.0  # when the step before was seen ready (recorder's clock)
+
+    def settle(i, issued_at, loss, router, n_tokens):
+        """Step ``i``'s span and report, once its loss is ready."""
+        nonlocal tokens_done, win_t, win_tokens, win_step, ready_at
+        if issued_at:
+            # recorder on: close the span at data-ready, not dispatch. A
+            # step issued behind the one before starts when that one ends
+            jax.block_until_ready(loss)
+            (_sp_compile if i == 0 else _sp_compute).end(
+                max(issued_at, ready_at))
+            ready_at = _fr.now()
+        tokens_done += n_tokens
+        if (i + 1) % report_every and i != steps - 1:
+            return
+        # the router's scalars come with the loss: one fetch a report
+        lf, moe = jax.device_get((loss, router[0] if router else {}))
+        lf = float(lf)
+        moe = {k: float(v) for k, v in moe.items()}
+        for k, v in moe.items():
+            _ROUTER_GAUGES[k].instant(v)
+        now = time.perf_counter()
+        dt = max(now - t0, 1e-9)
+        win_dt = max(now - win_t, 1e-9)
+        _g_tokens_per_sec.set((tokens_done - win_tokens) / win_dt,
+                              tags={"loop": "spmd"})
+        step_seconds = win_dt / max(i + 1 - win_step, 1)
+        _g_step_seconds.set(step_seconds, tags={"loop": "spmd"})
+        win_t, win_tokens, win_step = now, tokens_done, i + 1
+        report = {
+            "loss": lf,
+            "step": i + 1,
+            "step_seconds": step_seconds,  # mean since the last report
+            "tokens_per_sec": tokens_done / dt,
+            "tokens_per_sec_per_chip": tokens_done / dt / mesh.size,
+            "devices": mesh.size,
+            "mesh": dict(mesh.shape),
+            **ran_on,
+            **{f"moe_{k}": v for k, v in moe.items()},
+        }
+        if i == steps - 1:
+            report.update(_run_evidence(state))
+        session.report(report)
+
+    # One step ahead: step i + 1 is issued BEFORE the loop waits for step
+    # i's loss, so the device goes from one step into the next while the
+    # host fetches, reports and places a batch. Waiting first left the
+    # device idle for exactly as long as the host took, every step, and a
+    # host whose cores are busy elsewhere then sets the rate. A step is
+    # still reported when its loss is ready. The first step (trace and
+    # compile) is waited for alone.
+    loss = None
+    before = None  # the step issued and not yet settled
     for i in range(steps):
         _t = _fr.now()
         toks = next_tokens()
@@ -778,44 +844,15 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
             break
         _t = _fr.now()
         state, loss, *router = step_fn(state, toks)
-        if _t:
-            # recorder on: close the span at data-ready, not dispatch
-            # (the loop syncs on float(loss) at report time anyway)
-            jax.block_until_ready(loss)
+        if before is not None:
+            settle(*before)
+        before = (i, _t, loss, router,
+                  int(toks.shape[0]) * (int(toks.shape[1]) - 1))
         if i == 0:
-            _sp_compile.end(_t)  # first call traces + compiles the step
-        else:
-            _sp_compute.end(_t)
-        tokens_done += int(toks.shape[0]) * (int(toks.shape[1]) - 1)
-        if (i + 1) % report_every == 0 or i == steps - 1:
-            # the router's scalars come with the loss: one fetch a report
-            lf, moe = jax.device_get((loss, router[0] if router else {}))
-            lf = float(lf)
-            moe = {k: float(v) for k, v in moe.items()}
-            for k, v in moe.items():
-                _ROUTER_GAUGES[k].instant(v)
-            now = time.perf_counter()
-            dt = max(now - t0, 1e-9)
-            win_dt = max(now - win_t, 1e-9)
-            _g_tokens_per_sec.set((tokens_done - win_tokens) / win_dt,
-                                  tags={"loop": "spmd"})
-            step_seconds = win_dt / max(i + 1 - win_step, 1)
-            _g_step_seconds.set(step_seconds, tags={"loop": "spmd"})
-            win_t, win_tokens, win_step = now, tokens_done, i + 1
-            report = {
-                "loss": lf,
-                "step": i + 1,
-                "step_seconds": step_seconds,  # mean since the last report
-                "tokens_per_sec": tokens_done / dt,
-                "tokens_per_sec_per_chip": tokens_done / dt / mesh.size,
-                "devices": mesh.size,
-                "mesh": dict(mesh.shape),
-                **ran_on,
-                **{f"moe_{k}": v for k, v in moe.items()},
-            }
-            if i == steps - 1:
-                report.update(_run_evidence(state))
-            session.report(report)
+            settle(*before)
+            before = None
+    if before is not None:
+        settle(*before)
     return float(loss) if loss is not None else None
 
 

@@ -445,13 +445,18 @@ def _gspmd_text():
 # pages, the ``serving_params`` tree), the GSPMD and pipeline steps, and the
 # two steps whose ``col_in`` / ``row_out`` are collectives. A PR that means
 # to change a program replaces its hash; one that does not has changed it
-# by accident.
+# by accident. PR 29 meant to change the three STREAMED steps with a live
+# ``fsdp`` axis and replaced their hashes (``fsdp=4`` streamed, the routed
+# ``fsdp=4`` step, ``fsdp=2 tensor=2``): the carried layer's gather is
+# under ``stop_gradient``. With that call made the identity they lower to
+# the texts they had (``tests/test_spmd_train.py``, which keeps the old
+# ``fsdp=4`` hash); the other nine keep theirs.
 PROGRAMS = {
     "dense spmd, one device": (
         "90f52bb7182988719693b307dea1b7af700e18813e501c295d3f917e99fe4f41",
         lambda: _spmd_text(LlamaConfig.debug(), "", 1)),
     "dense spmd, fsdp=4 streamed": (
-        "999a6a502c954414038f8f83723808c52872d6a452a507db4bdbe24232dee8db",
+        "73715f27e998f7bee29d2090c745365dd66b73368e7a6d092a8bf83fddef6aa6",
         lambda: _spmd_text(LlamaConfig.debug(), "fsdp=4", 4)),
     "dense spmd, fsdp=4 upfront": (
         "3383c9e196be230a9964da69289bc26aae093a4df436c9cfbae7037c1882d336",
@@ -460,10 +465,10 @@ PROGRAMS = {
         "da0b5b8048e7ac43a9d7a87e06a7d195f34defd5ffc8c45124cc7afeea0fad9e",
         lambda: _spmd_text(program_cfg(), "", 1)),
     "routed spmd, fsdp=4 streamed": (
-        "c4277f392db5d65581faa4423e4fe1eea8e33680efbdce16470a96cbabb50d4d",
+        "164c8b4462e1898adc1e0881a906e96176252a21d731c0872dc68fac2d8a2ff4",
         lambda: _spmd_text(program_cfg(), "fsdp=4", 4)),
     "dense spmd, fsdp=2 tensor=2": (
-        "fe19fe4132d3b6f18554e68fa84f50481a9b1ca17d1e57bc5eefdf1a667bf3fb",
+        "344206d8f12065599cf4b0aa7c27095d427915891c1ddf9272e8e4eb170a7fa9",
         lambda: _spmd_text(LlamaConfig.debug(), "fsdp=2,tensor=2", 4)),
     "prefill, 1 page": (
         "7a6a8e472df32aac1668379c02df7a0b9cb645c7df262a647bd42f0788f6845f",
@@ -490,3 +495,31 @@ PROGRAMS = {
 def test_program_lowers_to_the_same_text(program):
     sha, lower = PROGRAMS[program]
     assert hashlib.sha256(lower().encode()).hexdigest() == sha
+
+
+# the three streamed steps as bdeba28 lowered them, before PR 29
+BEFORE_THE_CARRIED_GATHER_LEFT_THE_BACKWARD = {
+    "dense spmd, fsdp=4 streamed":
+        "999a6a502c954414038f8f83723808c52872d6a452a507db4bdbe24232dee8db",
+    "routed spmd, fsdp=4 streamed":
+        "c4277f392db5d65581faa4423e4fe1eea8e33680efbdce16470a96cbabb50d4d",
+    "dense spmd, fsdp=2 tensor=2":
+        "fe19fe4132d3b6f18554e68fa84f50481a9b1ca17d1e57bc5eefdf1a667bf3fb",
+}
+
+
+@pytest.mark.parametrize("program",
+                         list(BEFORE_THE_CARRIED_GATHER_LEFT_THE_BACKWARD))
+def test_the_stop_gradient_is_all_that_changed_the_streamed_steps(
+        program, monkeypatch):
+    """With ``prefetch_layer``'s ``stop_gradient`` made the identity (the
+    one call that is given a dict: a layer's leaves) the step lowers to
+    bdeba28's text: ``tests/test_spmd_train.py`` runs that program against
+    this one, bit for bit."""
+    real = jax.lax.stop_gradient
+    monkeypatch.setattr(
+        jax.lax, "stop_gradient",
+        lambda x: x if isinstance(x, dict) else real(x))
+    text = PROGRAMS[program][1]()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == BEFORE_THE_CARRIED_GATHER_LEFT_THE_BACKWARD[program]

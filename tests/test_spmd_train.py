@@ -320,6 +320,174 @@ def test_param_residency_bytes_streamed_below_upfront(cfg):
 
 
 # --------------------------------------------------------------------------- #
+# what crosses the links: each gradient once, in float32
+# --------------------------------------------------------------------------- #
+
+_MESHES = {"data=2,fsdp=4": MeshConfig(data=2, fsdp=4),
+           "fsdp=4,tensor=2": MeshConfig(fsdp=4, tensor=2)}
+_CASES = [(m, g) for m in _MESHES for g in ("streamed", "upfront")]
+
+
+def _collectives(jaxpr, times=1):
+    """Every ``all_gather`` / ``reduce_scatter`` of ``jaxpr`` and of the
+    jaxprs inside it: ``(primitive, operand dtype, times)``, ``times`` the
+    product of the scan lengths around it."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    out = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("all_gather", "reduce_scatter"):
+            out.append((name, eqn.invars[0].aval.dtype.name, times))
+        inner = times * (eqn.params["length"] if name == "scan" else 1)
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                if isinstance(sub, ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, Jaxpr):
+                    out.extend(_collectives(sub, inner))
+    return out
+
+
+def _census(cfg, mesh_name, gather):
+    """``{primitive: calls a step}`` of the step's jaxpr, the operand
+    dtypes seen, and how many leaves the step gathers: of one layer, and
+    outside the layers (a spec axis other than ``tensor``, whose dims go
+    through compute sharded)."""
+    import jax
+
+    from ray_tpu.train.spmd import spmd_param_specs
+
+    mesh = make_mesh(_MESHES[mesh_name])
+    init, step, _, _ = make_spmd_train_step(cfg, mesh, gather=gather)
+    state = jax.eval_shape(init._fn, jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(step._fn)(
+        state, jax.ShapeDtypeStruct((8, 33), np.int32)).jaxpr
+    calls, dtypes = {}, set()
+    for prim, dtype, times in _collectives(jaxpr):
+        calls[prim] = calls.get(prim, 0) + times
+        dtypes.add(dtype)
+    _, specs = spmd_param_specs(cfg, mesh)
+
+    def gathered(spec):
+        return any(ax not in (None, "tensor") for ax in spec)
+
+    in_layer = sum(gathered(s) for s in specs["layers"].values())
+    outside = sum(gathered(s) for k, s in specs.items() if k != "layers")
+    return calls, dtypes, in_layer, outside
+
+
+def _check_census(cfg, mesh_name, gather, n_in_layer):
+    calls, dtypes, in_layer, outside = _census(cfg, mesh_name, gather)
+    assert (in_layer, outside) == (n_in_layer, 2)  # embedding, lm_head
+    # parameters are gathered as they are held and gradients summed in
+    # float32: nothing narrower crosses the links in the traced step
+    assert dtypes == {"float32"}
+    L = cfg.n_layers
+    if gather == "upfront":  # the stacked leaves, once each way
+        assert calls == {"all_gather": in_layer + outside,
+                         "reduce_scatter": in_layer + outside}
+        return
+    # a layer is gathered 2 L + 1 times a step: layer 0 in front of the
+    # scan, the next layer in each of its L iterations (the last, layer 0
+    # again, for a carry nobody reads), each layer again in the backward.
+    # Its gradient is reduce-scattered ONCE: the carried gather is not
+    # differentiated (before PR 29: 2 L + 1 reduce-scatters a leaf, all
+    # but L of them of zeros)
+    assert calls == {"all_gather": in_layer * (2 * L + 1) + outside,
+                     "reduce_scatter": in_layer * L + outside}
+
+
+@pytest.mark.parametrize("mesh_name,gather", _CASES)
+def test_each_gradient_is_reduce_scattered_once_in_float32(cfg, mesh_name,
+                                                           gather):
+    """Read from the step's jaxpr: one reduce-scatter a gathered leaf a
+    layer (seven matmul weights; the norms are replicated) and one each
+    for ``embedding`` and ``lm_head``, every operand float32."""
+    _check_census(cfg, mesh_name, gather, 7)
+
+
+def test_routed_gradients_are_reduce_scattered_once_in_float32(cfg):
+    """A routed layer gathers its router too: eight leaves a layer."""
+    import dataclasses
+
+    routed = dataclasses.replace(cfg, num_experts=4, experts_per_token=2)
+    _check_census(routed, "data=2,fsdp=4", "streamed", 8)
+
+
+def _keep_the_carried_gather_in_the_backward(monkeypatch):
+    """Make ``prefetch_layer``'s ``stop_gradient`` the identity (it is the
+    one call that is given a dict: a layer's leaves): the step is then
+    bdeba28's, text for text (``test_olmoe.py`` holds the hashes)."""
+    import jax
+
+    real = jax.lax.stop_gradient
+    monkeypatch.setattr(
+        jax.lax, "stop_gradient",
+        lambda x: x if isinstance(x, dict) else real(x))
+
+
+# bdeba28 (the commit before the carried gather left the backward), CPU,
+# LlamaConfig.debug(), key 0, this module's tokens, per mesh (both schedules
+# gave the same): the first two losses, and sha256 over the bytes of every
+# leaf of the state (parameters, both adamw moments, counters; in
+# ``jax.tree.leaves`` order) after one step and after two.
+_PARENT = {
+    "data=2,fsdp=4": (
+        (6.021799087524414, 5.881684303283691),
+        "98659fed367fea3134f5d6bc3f1904c68418e73c77cabd0de6712d81ed768aae",
+        "f18ea157c3d71d3b5d80acee38a24669770ee936814e79d4fb951fee115f1a60"),
+    "fsdp=4,tensor=2": (
+        (6.021988391876221, 5.881901741027832),
+        "33d08c8256871f9e813e2e25c4f607f2e91a1c6e4902589112e1ed4d2301a700",
+        "3343b7d3f5be538979f1a65dcdc8aa76d264b397ad6b2af9d9030c71b0bc6fa4"),
+}
+
+
+@pytest.mark.parametrize("mesh_name,gather", _CASES)
+def test_step_is_the_parents_bit_for_bit(cfg, tokens, mesh_name, gather,
+                                         monkeypatch):
+    """Two steps against bdeba28: the ``stop_gradient`` takes sums of zeros
+    out of the backward, so losses, parameters and both moments are the
+    parent's to the bit. Held twice: against the values taken on bdeba28
+    itself, and against the parent's program run here (the step with the
+    carried gather left in the backward), array for array."""
+    import hashlib
+
+    import jax
+
+    mesh = make_mesh(_MESHES[mesh_name])
+
+    def two_steps():
+        init, step, ds, _ = make_spmd_train_step(cfg, mesh, donate=False,
+                                                 gather=gather)
+        s0 = init(jax.random.PRNGKey(0))
+        toks = jax.device_put(tokens, ds)
+        s1, l1 = step(s0, toks)
+        s2, l2 = step(s1, toks)
+        return (float(l1), float(l2)), jax.device_get((s1, s2))
+
+    def sha(state):
+        h = hashlib.sha256()
+        for leaf in jax.tree.leaves(state):
+            h.update(np.ascontiguousarray(leaf).tobytes())
+        return h.hexdigest()
+
+    losses, states = two_steps()
+    _keep_the_carried_gather_in_the_backward(monkeypatch)
+    want_losses, want_states = two_steps()
+    assert losses == want_losses
+    for got, want in zip(jax.tree.leaves(states),
+                         jax.tree.leaves(want_states)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+    # float32 in, float32 out: parameters and what the optimizer keeps
+    assert {leaf.dtype.name for leaf in jax.tree.leaves(states)} \
+        == {"float32", "int32"}
+    assert (losses, sha(states[0]), sha(states[1])) == _PARENT[mesh_name]
+
+
+# --------------------------------------------------------------------------- #
 # sharded ingest
 # --------------------------------------------------------------------------- #
 
@@ -453,6 +621,58 @@ def test_spmd_train_loop_smoke():
     assert losses[-1] < losses[0], f"no learning: {losses}"
     assert reports[-1].metrics["devices"] == 1
     assert reports[-1].metrics["tokens_per_sec_per_chip"] > 0
+
+
+@pytest.mark.parametrize("report_every", [1, 2])
+def test_loop_issues_the_next_step_before_it_waits(monkeypatch,
+                                                   report_every):
+    """The loop keeps one step ahead of its reports: step i + 1 is issued
+    before step i's loss is waited for (the first step, which compiles,
+    alone), every step is still reported, in order, and the recorder's
+    ``spmd.compute`` spans follow one another without overlap."""
+    from ray_tpu.train import session, spmd
+    from ray_tpu.train.session import TrainContext, set_context
+    from ray_tpu.util import flight_recorder as fr
+
+    events = []
+    real_make = spmd.make_spmd_train_step
+
+    def make(*a, **kw):
+        init, step, ds, rest = real_make(*a, **kw)
+
+        def logged_step(state, toks):
+            events.append("issue")
+            return step(state, toks)
+
+        return init, logged_step, ds, rest
+
+    monkeypatch.setattr(spmd, "make_spmd_train_step", make)
+    real_report = session.report
+    monkeypatch.setattr(
+        session, "report",
+        lambda m, c=None: (events.append(m["step"]), real_report(m, c)))
+    fr.reset_for_tests()
+    fr.configure(enabled=True, min_span_us=0.0)
+    ctx = TrainContext(1, 0, 0, 1, 0)
+    set_context(ctx)
+    try:
+        spmd.spmd_train_loop({"steps": 5, "batch_per_device": 1, "seq": 32,
+                              "mesh": "data=1", "distinct_batches": 1,
+                              "report_every": report_every})
+        reports = [r.metrics for r in ctx._drain()]
+    finally:
+        set_context(None)
+    want = {1: ["issue", 1, "issue", "issue", 2, "issue", 3, "issue", 4, 5],
+            2: ["issue", "issue", "issue", 2, "issue", "issue", 4, 5]}
+    assert events == want[report_every]
+    assert [r["step"] for r in reports] == [e for e in events if e != "issue"]
+    assert "device_report" in reports[-1]  # the last report's evidence
+    spans = sorted((t0, dur) for _, sid, kind, t0, dur, _ in
+                   fr.snapshot_payload()["events"]
+                   if int(sid) == spmd._sp_compute.sid and kind == 0)
+    assert len(spans) == 4
+    for (a, da), (b, _) in zip(spans, spans[1:]):
+        assert a + da <= b
 
 
 def test_jax_trainer_default_loop_spmd():
