@@ -5,7 +5,10 @@ per-leaf logical axes consumed by ray_tpu.parallel.sharding rules, so one
 model definition runs dp/fsdp/tp/sp via GSPMD. Design choices for the MXU:
 
 - layers stacked and scanned (``lax.scan``) — one compiled layer body,
-  constant compile time in depth;
+  constant compile time in depth; or, where the config gives a
+  ``layer_pattern``, a stack of Mamba-2, routed and attention layers (each
+  ONE half of the block), weights stacked per kind and walked in the
+  pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
 - bf16 matmuls with fp32 accumulation (``preferred_element_type``), params
   stored fp32, gradients/optimizer fp32;
 - ``jax.checkpoint`` per layer (remat) to trade FLOPs for HBM;
@@ -77,6 +80,11 @@ _sp_decode_readback = _fr.register_span("engine.decode_readback",
                                         tag_keys=("pages",))
 
 
+# a patterned stack's layer kinds: the character -> the name of the kind's
+# stacked weights under params["layers"]
+LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+
+
 @dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 128256
@@ -104,10 +112,63 @@ class LlamaConfig:
     z_loss_coef: float = 0.001
     # RMSNorm over the WHOLE q / k projection, before heads and RoPE
     qk_norm: bool = False
+    # a head's width where it is not dim // n_heads (0 = that)
+    attn_head_dim: int = 0
+    rope: bool = True  # False: attention without rotation
+    # The stack as data: one character a layer, of which the first n_layers
+    # are built. "M" a Mamba-2 mixer (ops/ssm.py), "E" a routed
+    # feed-forward, "*" attention; each layer is ONE of them,
+    # x + f(RMSNorm(x)). Empty: every layer is the block (attention THEN
+    # MLP), as every dense and every all-routed configuration has it.
+    layer_pattern: str = ""
+    ssm_heads: int = 0      # H; d_inner = ssm_heads * ssm_head_dim
+    ssm_head_dim: int = 0   # P
+    ssm_groups: int = 1     # G: heads that share one B and C
+    ssm_state: int = 0      # N
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    # the routed layer beyond softmax top-k of SwiGLU experts, all here:
+    # num_experts is what THIS device holds, router_experts the router's
+    # width (0 = num_experts: all here), first_expert the first one held
+    router_experts: int = 0
+    first_expert: int = 0
+    router_scoring: str = "softmax"  # or "sigmoid"; a "sigmoid" router has
+    #   a bias added for the choice alone, and no router loss is trained
+    routed_scale: float = 1.0        # multiplies the top-k weights
+    mlp_act: str = "swiglu"          # experts: or "relu2", two matrices
+    shared_mlp_dim: int = 0          # a shared expert's width (0 = none)
+
+    def __post_init__(self):
+        bad = set(self.layer_pattern) - set(LAYER_KINDS)
+        if bad or 0 < len(self.layer_pattern) < self.n_layers:
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r}: one of "
+                f"{sorted(LAYER_KINDS)} a layer, at least n_layers="
+                f"{self.n_layers} of them (or empty)")
+        if (self.router_scoring not in ("softmax", "sigmoid")
+                or self.mlp_act not in ("swiglu", "relu2")):
+            raise ValueError(f"router_scoring={self.router_scoring!r}, "
+                             f"mlp_act={self.mlp_act!r}")
+        if not self.layer_pattern and (
+                self.router_experts or self.shared_mlp_dim
+                or self.router_scoring != "softmax"
+                or self.mlp_act != "swiglu" or self.routed_scale != 1.0):
+            raise ValueError(
+                "a held range, a shared expert, sigmoid scores, a weight "
+                "scale and relu2 experts belong to a patterned stack's 'E' "
+                "layer: the block's MLP half has none of them")
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.attn_head_dim or self.dim // self.n_heads
+
+    @property
+    def kinds(self) -> str:
+        """The built layers' kinds, in order ('' for a stack of blocks)."""
+        return self.layer_pattern[:self.n_layers]
 
     @staticmethod
     def small(vocab_size: int = 32000) -> "LlamaConfig":
@@ -132,15 +193,29 @@ class LlamaConfig:
 
     def num_params(self) -> int:
         d, v, l = self.dim, self.vocab_size, self.n_layers
-        kv = self.n_kv_heads * self.head_dim
-        attn = d * d + 2 * d * kv + d * d
+        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+        emb = v * d * (1 if self.tie_embeddings else 2)
+        if self.layer_pattern:
+            di, gn = self.ssm_heads * self.ssm_head_dim, \
+                self.ssm_groups * self.ssm_state
+            per_kind = {
+                "M": (d * (2 * di + 2 * gn + self.ssm_heads) + di * d
+                      + (self.ssm_conv + 1) * (di + 2 * gn)
+                      + 3 * self.ssm_heads + di + d),
+                "E": ((self.router_experts or self.num_experts) * (d + 1)
+                      + 2 * d * self.shared_mlp_dim + self.num_experts
+                      * (2 if self.mlp_act == "relu2" else 3)
+                      * d * self.mlp_dim + d),
+                "*": 2 * d * q + 2 * d * kv + d,
+            }
+            return emb + sum(per_kind[k] for k in self.kinds) + d
+        attn = d * q + 2 * d * kv + q * d
         if self.qk_norm:
-            attn += d + kv
+            attn += q + kv
         mlp = 3 * d * self.mlp_dim
         if self.num_experts:
             mlp = self.num_experts * mlp + d * self.num_experts
         per_layer = attn + mlp + 2 * d
-        emb = v * d * (1 if self.tie_embeddings else 2)
         return emb + l * per_layer + d
 
 
@@ -151,6 +226,48 @@ class LlamaConfig:
 
 def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     """Pytree of per-leaf logical axis names (leading 'layers' = scan axis)."""
+    if cfg.layer_pattern:
+        kinds = {
+            "mamba": {
+                "norm": ("layers", None),
+                "w_in": ("layers", "embed", None),
+                "conv_w": ("layers", None, None),
+                "conv_b": ("layers", None),
+                "dt_bias": ("layers", None),
+                "A_log": ("layers", None),
+                "D": ("layers", None),
+                "gate_norm": ("layers", None),
+                "w_out": ("layers", None, "embed"),
+            },
+            "moe": {
+                "norm": ("layers", None),
+                "router": ("layers", "embed", None),
+                "router_bias": ("layers", None),
+                "w_up": ("layers", None, "embed", "mlp"),
+                "w_down": ("layers", None, "mlp", "embed"),
+            },
+            "attn": {
+                "norm": ("layers", None),
+                "wq": ("layers", "embed", "heads"),
+                "wk": ("layers", "embed", "kv_heads"),
+                "wv": ("layers", "embed", "kv_heads"),
+                "wo": ("layers", "heads", "embed"),
+            },
+        }
+        if cfg.mlp_act == "swiglu":
+            kinds["moe"]["w_gate"] = ("layers", None, "embed", "mlp")
+        if cfg.shared_mlp_dim:
+            kinds["moe"]["shared_up"] = ("layers", "embed", "mlp")
+            kinds["moe"]["shared_down"] = ("layers", "mlp", "embed")
+        out = {
+            "embedding": ("vocab", "embed"),
+            "layers": {LAYER_KINDS[k]: kinds[LAYER_KINDS[k]]
+                       for k in LAYER_KINDS if k in cfg.kinds},
+            "final_norm": (None,),
+        }
+        if not cfg.tie_embeddings:
+            out["lm_head"] = ("embed", "vocab")
+        return out
     layer = {
         "wq": ("layers", "embed", "heads"),
         "wk": ("layers", "embed", "kv_heads"),
@@ -182,14 +299,72 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     return out
 
 
+def _dense_init(rng, shape, fan_in):
+    return (jax.random.normal(rng, shape, jnp.float32)
+            * (1.0 / math.sqrt(fan_in)))
+
+
+def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
+    """A patterned stack's weights, stacked per KIND in the order the
+    pattern meets them: ``{"mamba": {leaf: [n_M, ...]}, "moe": {...},
+    "attn": {...}}``, a kind the built layers lack left out. The router's
+    choice bias starts at zero, as published."""
+    from ray_tpu.ops.ssm import init_mamba2
+
+    d, hd, f = cfg.dim, cfg.head_dim, cfg.mlp_dim
+    nq, nkv = cfg.n_heads, cfg.n_kv_heads
+    held, wide = cfg.num_experts, cfg.router_experts or cfg.num_experts
+    n = {kind: cfg.kinds.count(kind) for kind in LAYER_KINDS}
+    k = iter(jax.random.split(key, 12))
+    dense = _dense_init
+    out = {}
+    if n["M"]:
+        out["mamba"] = init_mamba2(
+            next(k), n["M"], d, heads=cfg.ssm_heads,
+            head_dim=cfg.ssm_head_dim, groups=cfg.ssm_groups,
+            state=cfg.ssm_state, conv=cfg.ssm_conv, dt_min=cfg.ssm_dt_min,
+            dt_max=cfg.ssm_dt_max, dt_floor=cfg.ssm_dt_floor)
+    if n["E"]:
+        L = n["E"]
+        moe = {
+            "norm": jnp.ones((L, d), jnp.float32),
+            "router": dense(next(k), (L, d, wide), d),
+            "router_bias": jnp.zeros((L, wide), jnp.float32),
+            "w_up": dense(next(k), (L, held, d, f), d),
+            "w_down": dense(next(k), (L, held, f, d), f),
+        }
+        if cfg.mlp_act == "swiglu":
+            moe["w_gate"] = dense(next(k), (L, held, d, f), d)
+        if cfg.shared_mlp_dim:
+            fs = cfg.shared_mlp_dim
+            moe["shared_up"] = dense(next(k), (L, d, fs), d)
+            moe["shared_down"] = dense(next(k), (L, fs, d), fs)
+        out["moe"] = moe
+    if n["*"]:
+        L = n["*"]
+        out["attn"] = {
+            "norm": jnp.ones((L, d), jnp.float32),
+            "wq": dense(next(k), (L, d, nq * hd), d),
+            "wk": dense(next(k), (L, d, nkv * hd), d),
+            "wv": dense(next(k), (L, d, nkv * hd), d),
+            "wo": dense(next(k), (L, nq * hd, d), nq * hd),
+        }
+    return out
+
+
 def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
     d, hd = cfg.dim, cfg.head_dim
     nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
+    dense = _dense_init
+    if cfg.layer_pattern:
+        k_emb, k_head, k_layers = jax.random.split(key, 3)
+        params = {"embedding": dense(k_emb, (cfg.vocab_size, d), d),
+                  "layers": _init_pattern_layers(cfg, k_layers),
+                  "final_norm": jnp.ones((d,), jnp.float32)}
+        if not cfg.tie_embeddings:
+            params["lm_head"] = dense(k_head, (d, cfg.vocab_size), d)
+        return params
     k = iter(jax.random.split(key, 16))
-
-    def dense(rng, shape, fan_in):
-        return (jax.random.normal(rng, shape, jnp.float32)
-                * (1.0 / math.sqrt(fan_in)))
 
     # a routed config's MLP weights carry an expert dimension after the
     # scan's; they are drawn from the keys the dense ones are drawn from
@@ -272,7 +447,8 @@ def _qkv(cfg: LlamaConfig, p, h, n_q: int, n_kv: int, positions):
     """The attention half's inputs: ``h`` [B, T, dim] (normed, cfg.dtype)
     through wq / wk / wv, QK-norm where the config has it (RMSNorm over the
     whole projected vector, before the split into heads), heads split,
-    RoPE on q and k. ``n_q`` / ``n_kv``: the heads THESE weights hold."""
+    RoPE on q and k (unless the config attends without rotation). ``n_q`` /
+    ``n_kv``: the heads THESE weights hold."""
     cd, hd = cfg.dtype, cfg.head_dim
     B, T, _ = h.shape
 
@@ -285,7 +461,8 @@ def _qkv(cfg: LlamaConfig, p, h, n_q: int, n_kv: int, positions):
     q = project("wq", n_q, "q_norm" if cfg.qk_norm else None)
     kk = project("wk", n_kv, "k_norm" if cfg.qk_norm else None)
     vv = project("wv", n_kv)
-    q, kk = rotary_embedding(q, kk, positions, cfg.rope_theta)
+    if cfg.rope:
+        q, kk = rotary_embedding(q, kk, positions, cfg.rope_theta)
     return q, kk, vv
 
 
@@ -300,10 +477,16 @@ def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=()):
     if cfg.num_experts:
         from ray_tpu.ops.moe import routed_mlp
 
+        wide = cfg.router_experts or cfg.num_experts
         y, stats = routed_mlp(
-            h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+            h, p["router"], p.get("w_gate"), p["w_up"], p["w_down"],
             top_k=cfg.experts_per_token, norm_topk_prob=cfg.norm_topk_prob,
-            stat_axes=stat_axes)
+            stat_axes=stat_axes, scoring=cfg.router_scoring,
+            choice_bias=p.get("router_bias"), scale=cfg.routed_scale,
+            held=((cfg.first_expert, cfg.num_experts)
+                  if wide != cfg.num_experts else None),
+            shared=((p["shared_up"], p["shared_down"])
+                    if cfg.shared_mlp_dim else None))
         return y.astype(cd), stats
     g = jax.nn.silu(h @ p["w_gate"].astype(cd))
     u = h @ p["w_up"].astype(cd)
@@ -317,10 +500,16 @@ def add_router_losses(cfg: LlamaConfig, nll, stats):
     step reports (``{}`` for a dense model, whose total is ``nll``)."""
     if not stats:
         return nll, {}
-    report = {"lb_loss": stats["lb_loss"].mean(),
-              "z_loss": stats["z_loss"].mean(),
-              "max_load_ratio": stats["max_load_ratio"].max(),
-              "dropped": stats["dropped"].sum()}
+    report = {}
+    if "lb_loss" in stats:  # a sigmoid router has no router loss
+        report = {"lb_loss": stats["lb_loss"].mean(),
+                  "z_loss": stats["z_loss"].mean()}
+    report.update(max_load_ratio=stats["max_load_ratio"].max(),
+                  dropped=stats["dropped"].sum())
+    if "held_share" in stats:  # the layers hold a range of the experts
+        report["held_share"] = stats["held_share"].mean()
+    if "lb_loss" not in stats:
+        return nll, report
     total = (nll + cfg.lb_loss_coef * report["lb_loss"]
              + cfg.z_loss_coef * report["z_loss"])
     return total, report
@@ -352,16 +541,70 @@ def decoder_block(cfg: LlamaConfig, x, p, positions, attend, *,
     keeps and a train step drops."""
     same = lambda a: a  # noqa: E731
     col_in, row_out = col_in or same, row_out or same
-    cd, hd = cfg.dtype, cfg.head_dim
-    B, T, _ = x.shape
-    nq, nkv = p["wq"].shape[-1] // hd, p["wk"].shape[-1] // hd
+    cd = cfg.dtype
     h = col_in(rms_norm(x, p["attn_norm"], cfg.norm_eps).astype(cd))
-    q, k, v = _qkv(cfg, p, h, nq, nkv, positions)
-    attn = attend(q, k, v).reshape(B, T, nq * hd)
-    x = x + row_out(attn @ p["wo"].astype(cd)).astype(x.dtype)
+    y, k, v = _attn_half(cfg, p, h, positions, attend)
+    x = x + row_out(y).astype(x.dtype)
     h = col_in(rms_norm(x, p["mlp_norm"], cfg.norm_eps).astype(cd))
     y, stats = _mlp_half(cfg, p, h, stat_axes)
     return x + row_out(y).astype(x.dtype), stats, (k, v)
+
+
+def _attn_half(cfg: LlamaConfig, p, h, positions, attend):
+    """The attention half on ``h`` [B, T, dim] (normed, cfg.dtype), before
+    the residual add: q / k / v (:func:`_qkv`), ``attend``, ``wo``. The
+    head counts are read off ``wq`` / ``wk``. Returns ``(y, k, v)``."""
+    hd = cfg.head_dim
+    B, T, _ = h.shape
+    nq, nkv = p["wq"].shape[-1] // hd, p["wk"].shape[-1] // hd
+    q, k, v = _qkv(cfg, p, h, nq, nkv, positions)
+    attn = attend(q, k, v).reshape(B, T, nq * hd)
+    return attn @ p["wo"].astype(cfg.dtype), k, v
+
+
+def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
+    """One layer of a patterned stack (``cfg.layer_pattern``), each kind a
+    half of the block: ``x + f(RMSNorm(x))`` with ``f`` the Mamba-2 mixer
+    (``"M"``, :func:`ray_tpu.ops.ssm.mamba2_mixer`), the routed
+    feed-forward (``"E"``, :func:`_mlp_half`) or attention (``"*"``,
+    :func:`_attn_half` over ``attend``). ``p``: this layer's weights, of its
+    kind. Returns ``(x, stats)``, ``stats`` ``{}`` but for ``"E"``."""
+    h = rms_norm(x, p["norm"], cfg.norm_eps).astype(cfg.dtype)
+    stats = {}
+    if kind == "M":
+        from ray_tpu.ops.ssm import mamba2_mixer
+
+        y = mamba2_mixer(h, p, heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+                         groups=cfg.ssm_groups, state=cfg.ssm_state,
+                         chunk=cfg.ssm_chunk, eps=cfg.norm_eps)
+    elif kind == "E":
+        y, stats = _mlp_half(cfg, p, h, stat_axes)
+    else:
+        y, _, _ = _attn_half(cfg, p, h, positions_of(*x.shape[:2]), attend)
+    return x + y.astype(x.dtype), stats
+
+
+def pattern_stack(cfg: LlamaConfig, x, layers, attend, stat_axes=()):
+    """The residual stream through a patterned stack in the pattern's
+    order: layer ``i`` of its kind reads row ``i`` of that kind's stacked
+    weights ``layers[kind name]``; every layer is rematerialised where
+    ``cfg.remat``. Returns ``(x, stats)``, the routed layers' stats stacked
+    ``[n_E]`` a leaf (``{}`` with no routed layer)."""
+    met = dict.fromkeys(LAYER_KINDS, 0)
+    stats = []
+    for kind in cfg.kinds:
+        fn = partial(pattern_layer, cfg, kind, attend, stat_axes=stat_axes)
+        if cfg.remat:
+            fn = jax.checkpoint(fn)
+        row = met[kind]
+        met[kind] += 1
+        x, st = fn(x, jax.tree.map(lambda a: a[row],
+                                   layers[LAYER_KINDS[kind]]))
+        if st:
+            stats.append(st)
+    if not stats:
+        return x, {}
+    return x, jax.tree.map(lambda *leaves: jnp.stack(leaves), *stats)
 
 
 def flash_causal(q, k, v):
@@ -433,6 +676,11 @@ def _backbone(cfg: LlamaConfig, params, tokens, mesh=None):
     """tokens [B, T] int32 -> ``(final-normed hidden states [B, T, dim],
     the layers' stats stacked [L])``."""
     x = embed_tokens(cfg, params, tokens, mesh)
+    if cfg.layer_pattern:
+        x, stats = pattern_stack(
+            cfg, x, params["layers"],
+            lambda q, k, v: _attention(cfg, q, k, v, mesh))
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), stats
     positions = positions_of(*tokens.shape)
 
     layer_fn = partial(_layer, cfg, mesh)
@@ -530,10 +778,11 @@ def _dense_only(cfg: LlamaConfig, who: str, why: str) -> None:
     """Routed experts and QK-norm are the block's (:func:`decoder_block`),
     so every path COMPUTES them; a path that cannot yet answer for the
     result says what it lacks instead of running."""
-    if cfg.num_experts or cfg.qk_norm:
+    if cfg.num_experts or cfg.qk_norm or cfg.layer_pattern:
         raise NotImplementedError(
             f"{who} takes no config with num_experts={cfg.num_experts}, "
-            f"qk_norm={cfg.qk_norm} yet: {why}")
+            f"qk_norm={cfg.qk_norm}, layer_pattern={cfg.layer_pattern!r} "
+            f"yet: {why}")
 
 
 # --------------------------------------------------------------------------- #

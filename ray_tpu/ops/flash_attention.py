@@ -39,6 +39,7 @@ logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 _LANE = 128
+_SCOPED_VMEM_DEFAULT = 16 * 2 ** 20  # Mosaic's limit for a kernel that asks none
 
 # the three things flash_attention can run; which one a call got is
 # recorded (paths_taken) and logged, never left to be guessed
@@ -292,10 +293,27 @@ def _bwd(q, k, v, o, lse, do, *, causal, blk, interpret):
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
+    # What the dK/dV call's block specs keep in VMEM, each buffer twice (the
+    # pipeline's double buffering): a head's whole q and do, its lse and
+    # delta ([T, 1] float32 columns, padded to 128 lanes), a block each of
+    # k and v, a float32 block each of dk and dv. 3 KiB a position at
+    # D = 128: 7.5 MiB at T = 2048, 13.5 at 4096, 25.5 at 8192. A call that
+    # fits the compiler's default scoped limit is compiled under it, as it
+    # always was; one that does not asks for its buffers and as much again
+    # as the default for the kernel's own tiles (a v5e core has 128 MiB).
+    buffers = 2 * (T * (2 * D * q.dtype.itemsize + 2 * _LANE * 4)
+                   + 2 * blk * D * (k.dtype.itemsize + 4))
+    params = {}
+    if buffers > _SCOPED_VMEM_DEFAULT and not interpret:
+        from jax.experimental.pallas import tpu as pltpu
+
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=buffers + _SCOPED_VMEM_DEFAULT)
     dk_exp, dv_exp = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, blk=blk, causal=causal,
                           n_q_blocks=n_blocks),
         grid=(B, Hq, n_blocks),
+        **params,
         in_specs=[
             pl.BlockSpec((1, 1, T, D), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, blk, D), lambda b, h, j: (b, h // rep, j, 0)),

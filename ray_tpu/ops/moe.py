@@ -7,7 +7,16 @@
   (``jax.lax.ragged_dot``), rows go back to token order. Memory grows with
   ``tokens x k``; every choice is computed whatever the imbalance; every
   shape is static; every index map is a gather in both directions (no
-  scatter, forward or backward).
+  scatter, forward or backward). A layer that HOLDS A RANGE of the router's
+  experts (``held=``: one chip's share of an expert-parallel job, the cell
+  ``train-nemotron3nano-1chip``) routes over all of them, sorts the same
+  way with the assignments to experts held elsewhere behind the held ones,
+  and makes rows, the same grouped products and the sum onto tokens for
+  the first places alone (:func:`_held_rows`: there the sum is a
+  scatter-add of few rows): nothing is made for an assignment that falls
+  elsewhere. The router's variants (sigmoid scores, a choice-only bias, a
+  weight scale, two-matrix ``relu2`` experts, a shared expert) are
+  arguments, each by itself.
 - :func:`moe_ffn` / :func:`top_k_routing` are the older GShard/Switch
   *dense dispatch*: one-hot ``[G, S, E, C]`` dispatch/combine tensors with a
   static capacity that DROPS tokens and always renormalises the gates. It
@@ -20,7 +29,8 @@
 from __future__ import annotations
 
 import math
-from typing import Dict, Sequence, Tuple
+from functools import partial
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -219,25 +229,129 @@ def _combine_bwd(res, ct):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _expert_ffn(xs, w_gate, w_up, w_down, counts):
+    """The experts' products on rows sorted by expert (``counts`` rows an
+    expert): SwiGLU where there is a ``w_gate``, else the two-matrix
+    ``relu(x W_up) ** 2 W_down``. Compute type in, compute type out.
+
+    A width over 512 that is no multiple of it is filled up with zero
+    columns (and zero rows of ``w_down``), which add nothing: on the TPU
+    the grouped product of 6,144 rows in 8 groups takes 6.2 ms forward and
+    16.6 with its backward at width 1856, 6.0 / 17.1 at 1920 and 3.5 / 9.0
+    at 2048 (PERF.md, PR 31)."""
+    cd = xs.dtype
+    f = w_up.shape[-1]
+    if f > 512 and f % 512:
+        cols, rows = ((0, 0), (0, 0), (0, -f % 512)), ((0, 0), (0, -f % 512),
+                                                       (0, 0))
+        w_up, w_down = jnp.pad(w_up, cols), jnp.pad(w_down, rows)
+        w_gate = None if w_gate is None else jnp.pad(w_gate, cols)
+    if w_gate is None:
+        u = _grouped_dot(xs, w_up, counts)
+        a = jnp.square(jax.nn.relu(u)).astype(cd)
+    else:
+        g = _grouped_dot(xs, w_gate, counts).astype(cd)
+        u = _grouped_dot(xs, w_up, counts).astype(cd)
+        a = (jax.nn.silu(g.astype(jnp.float32)) * u).astype(cd)
+    return _grouped_dot(a, w_down, counts).astype(cd)
+
+
+def _held_rows(hf, top_w, order, starts, end, weights, blocks):
+    """The routed sum ``[N, d]`` float32 of a layer that holds some of the
+    router's experts. ``order`` [A] are the assignments sorted by held
+    expert (``starts`` [count]: each one's first place), from place ``end``
+    on those that chose an expert held elsewhere: they get no row, no
+    product and no part in the sum.
+
+    The sorted places are walked in ``blocks`` blocks of ``A / blocks``.
+    The first block's rows are always made: gathered, through
+    :func:`_expert_ffn` with the held experts' groups, weighted and added
+    to their tokens. A further block's only in a step whose router sends
+    places into it (recomputed in the backward: it is the rare step), so
+    no assignment is ever left out whatever the routing, and a common step
+    makes ``A / blocks`` rows. The index maps here are a gather of rows and
+    a scatter-add onto tokens (transposed: the same two): ``_dispatch`` /
+    ``_combine`` gather a row for EVERY assignment, which at 16,384 tokens
+    x 6 of width 2688 is 10 ms a map against 0.3 and 3.6 ms for a quarter
+    of the places (PERF.md, PR 31). On the TPU the grouped product leaves
+    the rows of no group UNWRITTEN (whatever the buffer held): places from
+    ``end`` on are set to zero going in and coming out, and so are,
+    transposed, their cotangents."""
+    (N, d), A = hf.shape, order.shape[0]
+    if A % blocks:
+        blocks = 1
+    n = A // blocks
+    edges = jnp.append(starts, end).astype(jnp.int32)
+    flat_w = top_w.reshape(A)
+
+    def block(i):
+        lo = i * n
+        live = lo + jnp.arange(n, dtype=jnp.int32) < end
+        mine = jax.lax.dynamic_slice_in_dim(order, lo, n)
+        token = mine // (A // N)
+        with jax.named_scope("moe.dispatch"):
+            xs = jnp.where(live[:, None], hf[token], 0)
+        with jax.named_scope("moe.experts"):
+            ys = _expert_ffn(xs, *weights,
+                             jnp.diff(jnp.clip(edges - lo, 0, n)))
+        with jax.named_scope("moe.combine"):
+            ys = jnp.where(live, flat_w[mine], 0.0)[:, None] * jnp.where(
+                live[:, None], ys, 0).astype(jnp.float32)
+            return jnp.zeros((N, d), jnp.float32).at[token].add(ys)
+
+    y = block(0)
+    for i in range(1, blocks):  # behind the first: only if places fall in it
+        y = y + jax.lax.cond(i * n < end, jax.checkpoint(partial(block, i)),
+                             lambda: jnp.zeros((N, d), jnp.float32))
+    return y
+
+
 def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
                norm_topk_prob: bool = False,
-               stat_axes: Sequence[str] = ()
+               stat_axes: Sequence[str] = (),
+               scoring: str = "softmax", choice_bias=None,
+               scale: float = 1.0, held: Optional[Tuple[int, int]] = None,
+               shared=None
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Dropless top-k routed SwiGLU. ``h`` [..., d] in the compute type.
+    """Dropless top-k routed experts. ``h`` [..., d] in the compute type.
 
     ``router_w`` [d, E]; ``w_gate`` / ``w_up`` [E, d, f]; ``w_down``
-    [E, f, d], as stored. Router product and softmax in float32; the
-    ``top_k`` weights stay as the softmax gave them unless
+    [E, f, d], as stored. Router product and scores in float32; the
+    ``top_k`` weights stay as the scores gave them unless
     ``norm_topk_prob``; the expert products in ``h``'s type with float32
-    accumulation. Returns ``(y [..., d] float32, stats)``; ``stats`` are
-    float32 scalars:
+    accumulation. What a configuration may switch on, each by itself:
+
+    - ``scoring="sigmoid"``: ``sigmoid(logits)`` an expert in place of the
+      softmax over experts. The router losses below are the softmax
+      router's; a sigmoid router reports none.
+    - ``choice_bias`` [E]: added to the scores for the CHOICE of the
+      ``top_k`` alone; the weights are the unbiased scores of the chosen,
+      and no gradient reaches the bias.
+    - ``scale``: multiplies the weights, after ``norm_topk_prob``'s
+      renormalisation (``w_j = s_j / (sum_j s_j + 1e-20) * scale``).
+    - ``w_gate=None``: two-matrix experts, ``relu(x W_up) ** 2 W_down``.
+    - ``shared = (w_up [d, fs], w_down [fs, d])``: one expert of that form
+      (no gate) every token runs, added to the routed sum once.
+    - ``held = (first, count)``: this device holds the ``count`` experts
+      from ``first`` on of the router's ``E`` (``w_up`` [count, d, f]):
+      router and ``top_k`` run over all ``E``; assignments to experts held
+      elsewhere sort behind the held experts' and get no row, no product
+      and no part in the sum (:func:`_held_rows`); ``y`` is the partial
+      sum of the held experts (plus the shared one). With ``held=None``
+      every expert is here.
+
+    Returns ``(y [..., d] float32, stats)``; ``stats`` are float32 scalars:
 
     - ``lb_loss``: ``E * sum_e fraction_e * mean_prob_e``, ``fraction_e``
       the assignments expert ``e`` got over the TOKENS (the fractions sum
-      to ``top_k``), no gradient through it;
-    - ``z_loss``: ``mean(logsumexp(logits) ** 2)``;
-    - ``max_load_ratio``: the heaviest expert's assignments over the mean;
-    - ``dropped``: assignments that no group holds (0 by construction).
+      to ``top_k``), no gradient through it (softmax scoring only);
+    - ``z_loss``: ``mean(logsumexp(logits) ** 2)`` (softmax scoring only);
+    - ``max_load_ratio``: the heaviest (held) expert's assignments over the
+      mean of all ``E``;
+    - ``dropped``: assignments to experts here that no group holds (0 by
+      construction: every assignment has a place);
+    - ``held_share`` (with ``held`` only): the share of all assignments
+      that fell on held experts.
 
     ``stat_axes``: inside a ``shard_map`` whose axes split the tokens, the
     axis names to average ``fraction_e`` over, so that ``lb_loss``, averaged
@@ -253,33 +367,65 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
     with jax.named_scope("moe.route"):
         logits = jnp.dot(hf.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_e = jax.lax.top_k(probs, K)
+        if scoring == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        else:
+            probs = jax.nn.sigmoid(logits)
+        if choice_bias is None:
+            top_w, top_e = jax.lax.top_k(probs, K)
+        else:
+            _, top_e = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(choice_bias), K)
+            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
         if norm_topk_prob:
-            top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+            top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        if scale != 1.0:
+            top_w = top_w * scale
+    first, count = (0, E) if held is None else held
     with jax.named_scope("moe.dispatch"):
         ids = jnp.arange(A, dtype=jnp.int32)
-        sorted_e, order = jax.lax.sort_key_val(
-            top_e.reshape(A).astype(jnp.int32), ids)  # stable
-        _, inverse = jax.lax.sort_key_val(order, ids)
-        starts = jnp.searchsorted(sorted_e, jnp.arange(E, dtype=jnp.int32))
-        counts = jnp.diff(starts.astype(jnp.int32), append=jnp.int32(A))
-        xs = _dispatch(hf, order, inverse)
-    with jax.named_scope("moe.experts"):
-        g = _grouped_dot(xs, w_gate, counts).astype(cd)
-        u = _grouped_dot(xs, w_up, counts).astype(cd)
-        a = (jax.nn.silu(g.astype(jnp.float32)) * u).astype(cd)
-        ys = _grouped_dot(a, w_down, counts).astype(cd)
-    with jax.named_scope("moe.combine"):
-        y = _combine(ys, top_w, order, inverse)
+        group = top_e.reshape(A).astype(jnp.int32)
+        if held is not None:  # experts held elsewhere sort behind these
+            here = (group >= first) & (group < first + count)
+            group = jnp.where(here, group - first, count)
+        sorted_e, order = jax.lax.sort_key_val(group, ids)  # stable
+        if held is None:
+            _, inverse = jax.lax.sort_key_val(order, ids)
+        starts = jnp.searchsorted(sorted_e,
+                                  jnp.arange(count, dtype=jnp.int32))
+        end = jnp.int32(A) if held is None else jnp.searchsorted(
+            sorted_e, jnp.int32(count)).astype(jnp.int32)
+        counts = jnp.diff(starts.astype(jnp.int32), append=end)
+        if held is None:
+            xs = _dispatch(hf, order, inverse)
+    if held is None:
+        with jax.named_scope("moe.experts"):
+            ys = _expert_ffn(xs, w_gate, w_up, w_down, counts)
+        with jax.named_scope("moe.combine"):
+            y = _combine(ys, top_w, order, inverse)
+    else:  # a block of places is four even shares of the assignments
+        y = _held_rows(hf, top_w, order, starts, end,
+                       (w_gate, w_up, w_down), max(1, E // (4 * count)))
+    if shared is not None:
+        with jax.named_scope("moe.shared"):
+            a = jnp.square(jax.nn.relu(hf @ shared[0].astype(cd)))
+            y = y + (a @ shared[1].astype(cd)).astype(jnp.float32)
     with jax.named_scope("moe.route"):
         fraction = jax.lax.stop_gradient(counts.astype(jnp.float32) / N)
         for ax in stat_axes:
             fraction = jax.lax.pmean(fraction, ax)
-        stats = {
-            "lb_loss": E * jnp.sum(fraction * jnp.mean(probs, axis=0)),
-            "z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
-            "max_load_ratio": jnp.max(fraction) * (E / K),
-            "dropped": (A - jnp.sum(counts)).astype(jnp.float32),
-        }
+        stats = {}
+        if scoring == "softmax":
+            mean_prob = jnp.mean(probs, axis=0)
+            if held is not None:  # this device's terms of the sum over E
+                mean_prob = mean_prob[first:first + count]
+            stats = {
+                "lb_loss": E * jnp.sum(fraction * mean_prob),
+                "z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
+            }
+        stats["max_load_ratio"] = jnp.max(fraction) * (E / K)
+        stats["dropped"] = ((A if held is None else jnp.sum(here))
+                            - jnp.sum(counts)).astype(jnp.float32)
+        if held is not None:
+            stats["held_share"] = jnp.sum(fraction) / K
     return y.reshape(*lead, d), stats

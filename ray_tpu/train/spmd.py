@@ -81,6 +81,9 @@ _ROUTER_GAUGES = {
     "max_load_ratio": _fr.register_span("moe.max_load_ratio",
                                         tag_keys=("value",)),
     "dropped": _fr.register_span("moe.dropped", tag_keys=("value",)),
+    # where the layers hold a range of the router's experts: the share of
+    # all assignments that fell on the held ones
+    "held_share": _fr.register_span("moe.held_share", tag_keys=("value",)),
 }
 
 # Throughput/step-time gauges feeding the head's metrics-history rings
@@ -93,6 +96,12 @@ _g_tokens_per_sec = Gauge("ray_tpu_train_tokens_per_sec",
 _g_step_seconds = Gauge("ray_tpu_train_step_seconds",
                         "Recent mean train step wall time (s)",
                         tag_keys=("loop",))
+# the stack the step was built for, set once when it is built: layers by
+# kind (block; of a patterned stack mamba / moe / attn), and the experts a
+# routed layer holds of the router's width
+_g_stack = Gauge("ray_tpu_train_stack",
+                 "The model a train step was built for: layers by kind, "
+                 "experts held, the router's width", tag_keys=("part",))
 
 __all__ = [
     "match_partition_rules",
@@ -152,10 +161,13 @@ def match_partition_rules(rules, params, sep: str = "/"):
     return jax.tree.map(spec_for, names, params)
 
 
-def llama_partition_rules(routed: bool = False):
+def llama_partition_rules(routed: bool = False, pattern: bool = False):
     """Partition rules for the llama param tree (models/llama.py);
     ``routed``: for a config with experts, whose w_gate / w_up / w_down
-    carry an expert dimension after the scan's.
+    carry an expert dimension after the scan's; ``pattern``: for a
+    patterned stack, whose weights are stacked per kind
+    (``layers/<kind>/<leaf>``) and which ``make_spmd_train_step`` runs on
+    batch axes only: one rule, replicated.
 
     Mirrors ``parallel/sharding.DEFAULT_RULES``'s logical-axis mapping
     (embed→fsdp, heads/kv_heads/mlp/vocab→tensor) but keyed by name, so
@@ -172,6 +184,8 @@ def llama_partition_rules(routed: bool = False):
         (r"layers/w_(gate|up)$", P(None, None, "fsdp", "tensor")),
         (r"layers/w_down$", P(None, None, "tensor", "fsdp")),
     ) if routed else ()
+    if pattern:  # batch axes only: every leaf whole on every device
+        return ((r".", P()),)
     return experts + (
         # embedding: (vocab, embed)
         (r"(^|/)embedding$", P("tensor", "fsdp")),
@@ -281,7 +295,8 @@ def spmd_param_specs(cfg, mesh, rules=None):
     specs = jax.tree.map(
         lambda s: _restrict_spec(s, mesh),
         match_partition_rules(
-            rules or llama_partition_rules(routed=bool(cfg.num_experts)),
+            rules or llama_partition_rules(routed=bool(cfg.num_experts),
+                                           pattern=bool(cfg.layer_pattern)),
             sample),
         is_leaf=_is_spec)
     return sample, specs
@@ -328,6 +343,13 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     ``max_load_ratio``, ``dropped``); a dense config's step and program
     are what they were.
 
+    A patterned stack (``cfg.layer_pattern``: Mamba-2, routed and attention
+    layers, each one half of the block, weights stacked per kind) runs on
+    batch axes only, its layers walked in the pattern's order
+    (``models.llama.pattern_stack``); a live ``fsdp`` or ``tensor`` axis is
+    refused. Its routed layers may hold a range of the router's experts:
+    the step's router scalars then carry ``held_share``.
+
     A caller-supplied ``optimizer`` runs INSIDE shard_map on the
     fsdp/tensor shards, so per-leaf elementwise transforms (adam/adamw
     moments, per-leaf clipping, weight decay) are exact, but transforms
@@ -363,6 +385,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         decoder_block,
         flash_causal,
         init_params,
+        pattern_stack,
         positions_of,
         tp_psum_pair,
         vp_chunk_nll,
@@ -384,6 +407,14 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
 
     tensor = ("tensor" if "tensor" in mesh.axis_names
               and mesh.shape["tensor"] > 1 else None)
+    if cfg.layer_pattern and ("fsdp" in mesh.axis_names or tensor is not None):
+        raise ValueError(
+            "a patterned stack (layer_pattern) runs on batch axes only: the "
+            "streamed fsdp gather scans ONE stacked tree of equal layers "
+            "and has no per-kind form, and the Mamba-2 mixer, the held "
+            "experts and the shared expert have no tensor-parallel form "
+            "(tp_psum_pair placement, a split of heads and groups) yet; "
+            f"mesh {dict(mesh.shape)} has a live fsdp or tensor axis")
     if tensor is not None:
         t = mesh.shape["tensor"]
         for what, n in (("n_heads", cfg.n_heads),
@@ -406,6 +437,15 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
 
     batch_axes = data_axes(mesh)  # the canonical ("slice","data","fsdp")
     fsdp = "fsdp" if "fsdp" in mesh.axis_names else None
+    kinds = cfg.kinds or "b" * cfg.n_layers
+    for part, n in (("block_layers", kinds.count("b")),
+                    ("mamba_layers", kinds.count("M")),
+                    ("moe_layers", kinds.count("E")),
+                    ("attn_layers", kinds.count("*")),
+                    ("experts_held", cfg.num_experts),
+                    ("router_experts",
+                     cfg.router_experts or cfg.num_experts)):
+        _g_stack.set(float(n), tags={"part": part})
     # no fsdp axis → nothing to stream; fold so the scan stays simple
     gather_mode = gather if fsdp is not None else "upfront"
     dp_axes = tuple(a for a in batch_axes if a != "fsdp")
@@ -530,6 +570,9 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         return jax.lax.stop_gradient(gather_layer(shards))
 
     def run_layers(x, layer_shards):
+        if cfg.layer_pattern:  # batch axes only: nothing to gather
+            return pattern_stack(cfg, x, layer_shards, flash_causal,
+                                 stat_axes=batch_axes)
         if gather_mode == "streamed":
             first = prefetch_layer(
                 jax.tree.map(lambda a: a[0], layer_shards))
@@ -596,7 +639,8 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         return new_state, loss, pmean_tree(router, batch_axes)
 
     # after the state: the loss, and a routed config's router scalars
-    scalars = 2 if cfg.num_experts else 1
+    routed = cfg.num_experts and (not cfg.layer_pattern or "E" in cfg.kinds)
+    scalars = 2 if routed else 1
     sharded_step = shard_map(
         sm_step, mesh=mesh,
         in_specs=(state_specs, data_spec),

@@ -1,0 +1,79 @@
+"""Kernels of the measured path compiled at real widths for a v5e chip that
+is described and not attached (the TPU's compiler is installed here): what
+interpret mode cannot refuse, at no chip time. Nothing runs, so nothing here
+is a result or a time. All such compiles live in THIS file: the process that
+describes the topology holds the TPU library until it exits."""
+
+import pytest
+
+jax = pytest.importorskip("jax")
+jnp = jax.numpy
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no compiler for it here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """``flash_attention`` asks the default backend which path to take, and
+    here that is the CPU: steer it to the kernel. The persistent compile
+    cache cannot read back what was compiled for an absent chip: off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (batch, sequence, query heads, key/value heads, the dK/dV call asks for a
+# VMEM limit): the cells' attention, and the longest sequence whose buffers
+# (15.0 MiB) still fit the default limit, with the kernel's own tiles
+SHAPES = {
+    "train-nemotron3nano-1chip": (2, 8192, 32, 2, True),
+    "train-olmoe-1chip": (4, 4096, 16, 16, False),
+    "train-mistral7b-1chip": (8, 2048, 32, 8, False),
+    "4608 positions": (1, 4608, 8, 8, False),
+    "5120 positions": (1, 5120, 8, 8, True),
+}
+
+
+@pytest.mark.parametrize("cell", list(SHAPES))
+def test_flash_kernels_compile_at_the_cells_shapes(cell, one_chip,
+                                                   as_on_the_chip):
+    """Forward, dQ and dK/dV at head width 128. At 8,192 positions the
+    dK/dV call's buffers are 25.5 MiB of VMEM, over the compiler's default
+    16 MiB: it asks for its limit. The calls that fit the default (every
+    cell the benchmark had before) ask for nothing, as they always did."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    b, t, hq, hkv, asks = SHAPES[cell]
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, t, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(hq), arg(hkv), arg(hkv))
+    # a limit that is asked for is the call's scoped_memory_configs
+    assert ("scoped_memory_configs" in lowered.as_text()) == asks
+    compiled = lowered.compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        >= 3
