@@ -11,7 +11,8 @@ model definition runs dp/fsdp/tp/sp via GSPMD. Design choices for the MXU:
   pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
 - bf16 matmuls with fp32 accumulation (``preferred_element_type``), params
   stored fp32, gradients/optimizer fp32;
-- ``jax.checkpoint`` per layer (remat) to trade FLOPs for HBM;
+- ``jax.checkpoint`` per layer (remat) to trade FLOPs for HBM, its products
+  named (``KEEP_GROUPS``) so that a step with room can keep some of them;
 - attention: GQA + RoPE; ring attention over the ``seq`` mesh axis for long
   context, plain (XLA-fused, or Pallas flash) otherwise;
 - static shapes everywhere; causal masking is position arithmetic, no
@@ -31,6 +32,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.layers import rms_norm, rotary_embedding
 from ray_tpu.parallel.ring_attention import plain_attention, ring_attention_local
@@ -80,6 +82,27 @@ _sp_decode_readback = _fr.register_span("engine.decode_readback",
                                         tag_keys=("pages",))
 
 
+# What a train step may KEEP of its forward pass where it would otherwise
+# recompute it in the backward (``jax.checkpoint`` around a layer and around
+# a chunk of the loss): every kept value carries its group's name
+# (``checkpoint_name``). ``attn``: q / k / v as they are handed to ``attend``,
+# the flash kernel's output and log-sum-exp (ops/flash_attention.py), the
+# ``wo`` product; ``mlp``: the dense SwiGLU's gate and up products; ``head``:
+# the logits product in the compute type. All are results in cfg.dtype with
+# the model's width contracted away, so a group's bytes say what it saves. A
+# name is the identity outside a checkpoint (the serving programs) and under
+# one whose policy does not list it. In the order a tie is broken in.
+KEEP_GROUPS = ("attn", "mlp", "head")
+
+
+def keep_policy(keep):
+    """``jax.checkpoint``'s ``policy`` that keeps the groups ``keep`` (of
+    :data:`KEEP_GROUPS`); None, which keeps nothing, for none."""
+    if not keep:
+        return None
+    return jax.checkpoint_policies.save_only_these_names(*keep)
+
+
 # a patterned stack's layer kinds: the character -> the name of the kind's
 # stacked weights under params["layers"]
 LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
@@ -101,6 +124,14 @@ class LlamaConfig:
     # weights and convert at each product; LlamaDecodeEngine holds what it
     # multiplies in this dtype
     dtype: Any = jnp.bfloat16
+    # jax.checkpoint around every layer: a layer's backward makes the layer
+    # again from its input. False: no checkpoint at all, every intermediate
+    # saved. True is not all-or-nothing in the SPMD trainer's step: of the
+    # recomputed products it keeps the named ones (KEEP_GROUPS) that fit the
+    # device beside the step, and decides that itself from the compiler's
+    # memory account (train/spmd.py); the GSPMD and pipeline steps keep
+    # nothing. The loss's chunks are under a checkpoint of their own
+    # whatever this says (chunked_nll_mean)
     remat: bool = True
     loss_chunk: int = 256  # seq-chunk for the xent head; 0 = unchunked
     # routed experts in place of the dense MLP (0 = dense): mlp_dim is then
@@ -488,8 +519,9 @@ def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=()):
             shared=((p["shared_up"], p["shared_down"])
                     if cfg.shared_mlp_dim else None))
         return y.astype(cd), stats
-    g = jax.nn.silu(h @ p["w_gate"].astype(cd))
-    u = h @ p["w_up"].astype(cd)
+    # the two products may be kept (KEEP_GROUPS); silu is made again
+    g = jax.nn.silu(checkpoint_name(h @ p["w_gate"].astype(cd), "mlp"))
+    u = checkpoint_name(h @ p["w_up"].astype(cd), "mlp")
     return (g * u) @ p["w_down"].astype(cd), {}
 
 
@@ -557,9 +589,10 @@ def _attn_half(cfg: LlamaConfig, p, h, positions, attend):
     hd = cfg.head_dim
     B, T, _ = h.shape
     nq, nkv = p["wq"].shape[-1] // hd, p["wk"].shape[-1] // hd
-    q, k, v = _qkv(cfg, p, h, nq, nkv, positions)
+    q, k, v = (checkpoint_name(a, "attn")
+               for a in _qkv(cfg, p, h, nq, nkv, positions))
     attn = attend(q, k, v).reshape(B, T, nq * hd)
-    return attn @ p["wo"].astype(cfg.dtype), k, v
+    return checkpoint_name(attn @ p["wo"].astype(cfg.dtype), "attn"), k, v
 
 
 def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
@@ -584,18 +617,20 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     return x + y.astype(x.dtype), stats
 
 
-def pattern_stack(cfg: LlamaConfig, x, layers, attend, stat_axes=()):
+def pattern_stack(cfg: LlamaConfig, x, layers, attend, stat_axes=(),
+                  policy=None):
     """The residual stream through a patterned stack in the pattern's
     order: layer ``i`` of its kind reads row ``i`` of that kind's stacked
     weights ``layers[kind name]``; every layer is rematerialised where
-    ``cfg.remat``. Returns ``(x, stats)``, the routed layers' stats stacked
-    ``[n_E]`` a leaf (``{}`` with no routed layer)."""
+    ``cfg.remat``, but for what ``policy`` keeps (:func:`keep_policy`; only
+    an attention layer has names). Returns ``(x, stats)``, the routed
+    layers' stats stacked ``[n_E]`` a leaf (``{}`` with no routed layer)."""
     met = dict.fromkeys(LAYER_KINDS, 0)
     stats = []
     for kind in cfg.kinds:
         fn = partial(pattern_layer, cfg, kind, attend, stat_axes=stat_axes)
         if cfg.remat:
-            fn = jax.checkpoint(fn)
+            fn = jax.checkpoint(fn, policy=policy)
         row = met[kind]
         met[kind] += 1
         x, st = fn(x, jax.tree.map(lambda a: a[row],
@@ -702,7 +737,8 @@ def _head(cfg: LlamaConfig, params):
 def _logits(cfg: LlamaConfig, x, head):
     """Final-normed hidden states through ``head`` [dim, vocab] (whole or a
     vocabulary shard): the product in cfg.dtype, the logits float32."""
-    return (x.astype(cfg.dtype) @ head.astype(cfg.dtype)).astype(jnp.float32)
+    return checkpoint_name(x.astype(cfg.dtype) @ head.astype(cfg.dtype),
+                           "head").astype(jnp.float32)
 
 
 def head_logits(cfg: LlamaConfig, x, final_norm, head):
@@ -727,12 +763,17 @@ def _plain_chunk_nll(cfg: LlamaConfig, head):
     return chunk_nll
 
 
-def chunked_nll_mean(cfg: LlamaConfig, x, targets, chunk_nll):
+def chunked_nll_mean(cfg: LlamaConfig, x, targets, chunk_nll, policy=None):
     """Mean NLL with the lm_head matmul + softmax CHUNKED over the
     sequence under ``jax.checkpoint``: fp32 logits exist only per-chunk
     ([B, C, vocab] instead of [B, T, vocab] — the round-1 OOM at batch
-    32), recomputed in the backward pass. Costs one extra head matmul
-    per chunk; frees GBs. ``chunk_nll(x_c, t_c) -> [B, C]`` supplies
+    32) and are made again in the backward pass. With no ``policy`` a
+    chunk's backward recomputes its head product too (one extra head
+    matmul a chunk; frees GBs); under one that keeps ``head``
+    (:func:`keep_policy`; ``make_spmd_train_step`` gives it where the
+    device has the room) the product is kept in cfg.dtype, [B, T, vocab]
+    at half the float32 logits' size, and only its conversion and the
+    softmax are made again. ``chunk_nll(x_c, t_c) -> [B, C]`` supplies
     the head — full-width (:func:`_plain_chunk_nll`) or vocab-parallel
     (:func:`vp_chunk_nll`)."""
     B, T, d = x.shape
@@ -749,8 +790,8 @@ def chunked_nll_mean(cfg: LlamaConfig, x, targets, chunk_nll):
         x_c, t_c = chunk
         return total + chunk_nll(x_c, t_c).sum(), None
 
-    total, _ = jax.lax.scan(jax.checkpoint(body), jnp.zeros((), jnp.float32),
-                            (xs, ts))
+    total, _ = jax.lax.scan(jax.checkpoint(body, policy=policy),
+                            jnp.zeros((), jnp.float32), (xs, ts))
     if rem:
         total = total + chunk_nll(x[:, n * C:], targets[:, n * C:]).sum()
     return total / (B * T)

@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 logger = logging.getLogger(__name__)
@@ -352,6 +353,16 @@ def _flash_bhtd(q, k, v, causal, blk, interpret):
 
 def _flash_fwd_rule(q, k, v, causal, blk, interpret):
     o, lse = _fwd(q, k, v, causal=causal, blk=blk, interpret=interpret)
+    # the two residuals only the kernel can give, under the name the model
+    # gives its attention products (models/llama.py KEEP_GROUPS): a
+    # jax.checkpoint whose policy keeps "attn" then holds them and does not
+    # run the forward kernel a second time in the backward; else the
+    # identity. The log-sum-exp is named as [B, Hq, T]: as the kernel writes
+    # it, [B, Hq, T, 1], the device's tiling fills its last dimension up to
+    # 128 lanes, and a kept copy holds 128 times its 4 bytes a row (134 MB
+    # a layer at 4 x 16 x 4096, by the compiler's account)
+    o = checkpoint_name(o, "attn")
+    lse = checkpoint_name(lse[..., 0], "attn")[..., None]
     return o, (q, k, v, o, lse)
 
 

@@ -56,15 +56,23 @@ so the same seed gives the same params on every mesh).
 from __future__ import annotations
 
 import difflib
+import itertools
+import logging
 import re
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ray_tpu.util import flight_recorder as _fr
 from ray_tpu.util.metrics import Gauge
-from ray_tpu.util.xla_observatory import observe_compiled
+from ray_tpu.util.xla_observatory import (
+    ObservedFunction,
+    analyses,
+    observe_compiled,
+)
+
+logger = logging.getLogger(__name__)
 
 _sp_ingest = _fr.register_span("spmd.ingest_wait")
 _sp_compute = _fr.register_span("spmd.compute")
@@ -102,6 +110,16 @@ _g_step_seconds = Gauge("ray_tpu_train_step_seconds",
 _g_stack = Gauge("ray_tpu_train_stack",
                  "The model a train step was built for: layers by kind, "
                  "experts held, the router's width", tag_keys=("part",))
+
+# what the step that runs keeps of its forward pass (models/llama.py
+# KEEP_GROUPS), set when that is chosen: bytes a device by group, 0 for a
+# group that is recomputed, and under group="headroom" the bytes the choice
+# had to spend
+_g_kept = Gauge("ray_tpu_train_kept_bytes",
+                "Bytes a device of forward products the train step keeps "
+                "for its backward pass instead of recomputing them, by "
+                "group; group=headroom: what the device had free for them",
+                tag_keys=("group",))
 
 __all__ = [
     "match_partition_rules",
@@ -273,6 +291,217 @@ def make_shard_and_gather_fns(partition_specs, mesh, dtype_specs=None):
 
 
 # --------------------------------------------------------------------------- #
+# What the step keeps of its forward pass
+# --------------------------------------------------------------------------- #
+
+# Of the device's limit, what a program's account must stay under for the
+# step to keep anything beside it. The compiler's account is of ONE program
+# (the process holds batches in flight too, and the allocator cannot hand
+# out every byte in one piece), and the TPU compiler rematerialises ON ITS
+# OWN once a program passes some 92-94% of the limit (PERF.md, PR 32): in
+# that band a product kept here is bought back by a recomputation added
+# there, so a step already in it is left as it is.
+KEEP_MARGIN = 0.08
+
+
+def kept_group_bytes(cfg, batch: int, seq: int, *,
+                     tensor: int = 1) -> Dict[str, int]:
+    """Bytes a device each of ``models.llama.KEEP_GROUPS`` holds once kept,
+    from shapes: ``batch`` x ``seq`` rows a device, each group's widths in
+    cfg.dtype, times the layers that have it. Only the groups a step of
+    this shape HAS: ``attn`` / ``mlp`` where the layers run under
+    ``jax.checkpoint`` (``cfg.remat``), ``mlp`` of dense blocks only (the
+    routed half has no names), ``head`` where the loss is chunked (its
+    whole chunks)."""
+    import jax.numpy as jnp
+
+    item = jnp.dtype(cfg.dtype).itemsize
+    rows = batch * seq
+    groups: Dict[str, int] = {}
+    if cfg.remat:
+        kinds = cfg.kinds or "b" * cfg.n_layers
+        q = cfg.n_heads * cfg.head_dim // tensor
+        kv = cfg.n_kv_heads * cfg.head_dim // tensor
+        # q, k, v, the kernel's output, the wo product; and the kernel's
+        # float32 log-sum-exp, one a query head
+        groups["attn"] = (kinds.count("b") + kinds.count("*")) * rows * (
+            (2 * q + 2 * kv + cfg.dim) * item + 4 * cfg.n_heads // tensor)
+        if not cfg.num_experts:  # gate and up
+            groups["mlp"] = (kinds.count("b") * rows
+                             * 2 * (cfg.mlp_dim // tensor) * item)
+    if cfg.loss_chunk and seq > cfg.loss_chunk:
+        groups["head"] = (batch * (seq - seq % cfg.loss_chunk)
+                          * (cfg.vocab_size // tensor) * item)
+    return {g: b for g, b in groups.items() if b}
+
+
+def loss_phase_bytes(cfg, batch: int, seq: int, *, tensor: int = 1) -> int:
+    """What a device holds beside the step's arguments while the loss runs,
+    from shapes: what the forward pass saved whatever is kept (a layer's
+    input each, the final stream and its norm), a chunk's float32 logits
+    three times over (with their softmax and its gradient) and the head's
+    float32 gradient. The kept ``head`` products live and die HERE, before
+    the first layer's backward: the compiler's account, whose peak lies in
+    the layers' backward, says nothing of the room they have."""
+    import jax.numpy as jnp
+
+    vocab = cfg.vocab_size // tensor
+    stream = batch * seq * cfg.dim * jnp.dtype(cfg.dtype).itemsize
+    chunk = batch * min(cfg.loss_chunk or seq, seq) * vocab * 4
+    return (cfg.n_layers + 2) * stream + 3 * chunk + cfg.dim * vocab * 4
+
+
+def program_bytes(memory: Dict[str, int]) -> int:
+    """What a program needs of a device while it runs, by the compiler's
+    own account (``xla_observatory.analyses``'s ``memory``): the peak of
+    its live buffers where the compiler gives one; else arguments, the
+    outputs that are not donated arguments written in place, temporaries
+    and code, which counts every temporary as if all were live at once."""
+    return memory.get("peak") or (
+        memory["argument"] + memory["output"] - memory.get("alias", 0)
+        + memory["temp"] + memory.get("code", 0))
+
+
+def choose_kept(group_bytes: Dict[str, int], layers_room: int,
+                loss_room: int) -> Tuple[str, ...]:
+    """The groups to keep: the subset of ``group_bytes`` with the most
+    bytes (every kept product contracts the model's width away, so the
+    FLOPs a group saves go with its bytes) whose ``attn`` and ``mlp`` fit
+    ``layers_room``, the room beside the step that keeps nothing, whose
+    peak lies in the layers' backward where they are live; and which fits
+    ``loss_room`` whole, ``head`` and all, the room while the loss runs. Of
+    two subsets with the same bytes the one with ``attn``, which also saves
+    the flash forward kernel. ``()``: nothing fits."""
+    from ray_tpu.models.llama import KEEP_GROUPS
+
+    have = [g for g in KEEP_GROUPS if group_bytes.get(g)]
+    best, best_rank = (), (0, False)
+    for r in range(1, len(have) + 1):
+        for subset in itertools.combinations(have, r):
+            size = sum(group_bytes[g] for g in subset)
+            in_layers = sum(group_bytes[g] for g in subset if g != "head")
+            rank = (size, "attn" in subset)
+            if (in_layers <= layers_room and size <= loss_room
+                    and rank > best_rank):
+                best, best_rank = subset, rank
+    return best
+
+
+def keeps_what_it_should(base: Dict[str, Any], kept: Dict[str, Any],
+                         limit: int) -> str:
+    """Why a step that keeps products must NOT run in place of the one that
+    keeps nothing ('': it may), from the two programs' ``analyses``. Its
+    own account has to stay under the limit less the margin (or under the
+    first program's); and its FLOPs under the first program's: every kept
+    byte is part of a product the backward no longer makes, so a count
+    that did not fall says the compiler, under pressure, recomputes more
+    than was kept."""
+    if "memory" not in kept:
+        return "it has no memory account"
+    need, had = program_bytes(kept["memory"]), program_bytes(base["memory"])
+    if need > max(had, int((1 - KEEP_MARGIN) * limit)):
+        return (f"its account, {need} bytes, is over the first program's "
+                f"{had} and leaves no margin under the limit {limit}")
+    if "flops" in base and not kept.get("flops", 0) < base["flops"]:
+        return (f"it makes {kept.get('flops')} FLOPs, the step that keeps "
+                f"nothing {base['flops']}: the compiler recomputes more "
+                f"than was kept")
+    return ""
+
+
+def _device_limit(device) -> Optional[int]:
+    """The bytes the backend lets a process hold on ``device``, or None
+    where it does not say (the CPU)."""
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
+# the step a build and a batch shape came to on a device with a limit: what
+# it keeps and the executable, so that the same step built again in this
+# process (a second loop) neither chooses nor lowers nor loads anything:
+# {(the build's key, the call's fingerprint): (groups, executable)}
+_BUILT: Dict[tuple, Tuple[Tuple[str, ...], Any]] = {}
+
+
+class _KeepingStep(ObservedFunction):
+    """The train step a caller holds. ``_fn`` is the jitted step that keeps
+    nothing: every layer and every chunk of the loss recomputed whole in
+    the backward. When a batch shape is first seen that program is lowered
+    and compiled ahead of time, whatever ``xla_observatory_enabled`` says,
+    and its memory account decides what the step that RUNS keeps
+    (:func:`choose_kept`). Nothing fits, the account is within
+    ``KEEP_MARGIN`` of the limit, or the backend reports no limit: that
+    executable is the step, one compile as ever. Else the step that keeps
+    the chosen groups is compiled too and runs, unless the compiler refuses
+    it or :func:`keeps_what_it_should` does: then the first executable
+    runs. At most two compiles a shape."""
+
+    def __init__(self, step_keeping: Callable[[Tuple[str, ...]], Any],
+                 sizes: Callable[..., Tuple[Dict[str, int], int]], device,
+                 name: str, build_key=None):
+        super().__init__(step_keeping(()), name)
+        self._step_keeping = step_keeping
+        self._sizes = sizes
+        self._device = device
+        self._build_key = build_key
+
+    def _ahead_of_time(self) -> bool:
+        return True
+
+    def _executable(self, fp, args, kwargs):
+        groups, loss_bytes = self._sizes(*args, **kwargs)
+        key = (self._build_key, fp) if self._build_key is not None else None
+        if key in _BUILT:
+            keep, compiled = _BUILT[key]
+            self._report(groups, keep, None)
+            return compiled
+        base = self._compile(self._fn, args, kwargs)
+        limit = _device_limit(self._device)
+        found = analyses(base.compiled)
+        keep, why, room = (), "", 0
+        if limit and "memory" in found:
+            under = int((1 - KEEP_MARGIN) * limit)
+            room = under - program_bytes(found["memory"])
+            if room > 0:
+                keep = choose_kept(
+                    groups, room,
+                    under - found["memory"]["argument"] - loss_bytes)
+        chosen = base
+        if keep:
+            try:
+                kept = self._compile(self._step_keeping(keep), args, kwargs)
+            except Exception as e:  # noqa: BLE001 - the compiler's refusal
+                kept, why = None, f"the compiler refused it: {e}"[:300]
+            if kept is not None:
+                why = keeps_what_it_should(found, analyses(kept.compiled),
+                                           limit)
+                self._record(fp, kept if why else base)  # the loser first
+                if not why:
+                    chosen = kept
+            if why:
+                keep = ()
+        self._record(fp, chosen)
+        if key is not None and limit:
+            _BUILT[key] = (keep, chosen.compiled)
+        self._report(groups, keep, room)
+        # a second program that fell back cost a compile for nothing (and
+        # one the compiler refuses is not cached: every start pays it)
+        logger.log(
+            logging.WARNING if why else logging.INFO,
+            "%s keeps %s of %s: device limit %s, margin %.0f%%, the step "
+            "that keeps nothing %s%s", self.program_name,
+            list(keep) or "nothing", groups, limit, 100 * KEEP_MARGIN,
+            found.get("memory"), f"; fell back: {why}" if why else "")
+        return chosen.compiled
+
+    @staticmethod
+    def _report(groups, keep, room) -> None:
+        for g, nbytes in groups.items():
+            _g_kept.set(float(nbytes if g in keep else 0), tags={"group": g})
+        if room is not None:
+            _g_kept.set(float(max(room, 0)), tags={"group": "headroom"})
+
+
+# --------------------------------------------------------------------------- #
 # shard_map train step (manual DP + fsdp ZeRO-3 + tensor)
 # --------------------------------------------------------------------------- #
 
@@ -303,7 +532,8 @@ def spmd_param_specs(cfg, mesh, rules=None):
 
 
 def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
-                         donate: bool = True, gather: str = "streamed"):
+                         donate: bool = True, gather: str = "streamed",
+                         keep: Optional[Sequence[str]] = None):
     """Build (init, step, data_sharding, state_shardings) with the SPMD
     program written out in shard_map, matching ``make_train_step``'s
     contract and numerics (rtol 3e-3 vs the GSPMD step, tested).
@@ -350,6 +580,17 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     refused. Its routed layers may hold a range of the router's experts:
     the step's router scalars then carry ``held_share``.
 
+    Where the layers run under ``jax.checkpoint`` (``cfg.remat``) and where
+    the loss is chunked, the step KEEPS the forward products that fit the
+    device beside it and recomputes the rest: ``models.llama.KEEP_GROUPS``,
+    chosen when a batch shape is first seen from the compiler's memory
+    account of the step that keeps nothing (:class:`_KeepingStep`). No
+    setting: on a backend that reports no memory limit (the CPU) nothing is
+    kept. Not the streamed scan with a live ``fsdp`` axis: it recomputes a
+    layer by construction and is left exactly as it was, its loss too.
+    ``keep`` is for tests: a subset to keep whatever the room, with no
+    choice made.
+
     A caller-supplied ``optimizer`` runs INSIDE shard_map on the
     fsdp/tensor shards, so per-leaf elementwise transforms (adam/adamw
     moments, per-leaf clipping, weight decay) are exact, but transforms
@@ -385,6 +626,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         decoder_block,
         flash_causal,
         init_params,
+        keep_policy,
         pattern_stack,
         positions_of,
         tp_psum_pair,
@@ -430,6 +672,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
                 "normalises whole, and the routed MLP half has no "
                 "tensor-parallel form yet: use batch axes and fsdp")
 
+    user_optimizer = optimizer
     optimizer = optimizer or optax.adamw(3e-4, b1=0.9, b2=0.95,
                                          weight_decay=0.1)
 
@@ -569,10 +812,10 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         backward reduce-scatters a whole layer of zeros a layer a step."""
         return jax.lax.stop_gradient(gather_layer(shards))
 
-    def run_layers(x, layer_shards):
+    def run_layers(x, layer_shards, policy):
         if cfg.layer_pattern:  # batch axes only: nothing to gather
             return pattern_stack(cfg, x, layer_shards, flash_causal,
-                                 stat_axes=batch_axes)
+                                 stat_axes=batch_axes, policy=policy)
         if gather_mode == "streamed":
             first = prefetch_layer(
                 jax.tree.map(lambda a: a[0], layer_shards))
@@ -595,10 +838,11 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
             (x, _), stats = jax.lax.scan(body, (x, first), xs)
             return x, stats
         full = jax.tree.map(gather_leaf, layer_shards, lspecs)
-        body = jax.checkpoint(layer_fn) if cfg.remat else layer_fn
+        body = (jax.checkpoint(layer_fn, policy=policy) if cfg.remat
+                else layer_fn)
         return jax.lax.scan(body, x, full)
 
-    def local_loss(shards, tokens):
+    def local_loss(shards, tokens, policy):
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
         emb_local = gather_leaf(shards["embedding"],
                                 param_specs["embedding"])
@@ -606,7 +850,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
             x = vp_embed(cfg, emb_local, inputs, tensor, gp)
         else:
             x = emb_local.astype(cfg.dtype)[inputs]
-        x, stats = run_layers(x, shards["layers"])
+        x, stats = run_layers(x, shards["layers"], policy)
         x = rms_norm(x, shards["final_norm"], cfg.norm_eps)
         if cfg.tie_embeddings:
             head_local = emb_local.T
@@ -616,43 +860,69 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         if tensor is not None:
             nll = chunked_nll_mean(
                 cfg, fi(x), targets,
-                vp_chunk_nll(cfg, head_local, tensor, gp))
+                vp_chunk_nll(cfg, head_local, tensor, gp), policy)
         else:
             nll = chunked_nll_mean(cfg, x, targets,
-                                   _plain_chunk_nll(cfg, head_local))
+                                   _plain_chunk_nll(cfg, head_local), policy)
         return add_router_losses(cfg, nll, stats)
-
-    def sm_step(state, tokens):
-        (loss, router), grads = jax.value_and_grad(
-            lambda p: local_loss(p, tokens), has_aux=True)(state["params"])
-        # params-major maps: the array tree's structure governs, so the
-        # PartitionSpec leaves (tuple subclasses) are passed whole
-        grads = jax.tree.map(reduce_leaf, grads, param_specs)
-        loss = pmean_tree(loss, batch_axes)
-        updates, new_opt = optimizer.update(grads, state["opt_state"],
-                                            state["params"])
-        new_params = optax.apply_updates(state["params"], updates)
-        new_state = {"params": new_params, "opt_state": new_opt,
-                     "step": state["step"] + 1}
-        if not router:
-            return new_state, loss
-        return new_state, loss, pmean_tree(router, batch_axes)
 
     # after the state: the loss, and a routed config's router scalars
     routed = cfg.num_experts and (not cfg.layer_pattern or "E" in cfg.kinds)
     scalars = 2 if routed else 1
-    sharded_step = shard_map(
-        sm_step, mesh=mesh,
-        in_specs=(state_specs, data_spec),
-        out_specs=(state_specs,) + (P(),) * scalars,
-        check=False)
 
-    train_step = observe_compiled(jax.jit(
-        sharded_step,
-        in_shardings=(state_shardings, data_sharding),
-        out_shardings=(state_shardings,) + (repl,) * scalars,
-        donate_argnums=(0,) if donate else (),
-    ), "spmd.train_step")
+    def step_keeping(groups):
+        """The jitted step whose checkpoints keep ``groups`` (of
+        ``KEEP_GROUPS``; none: every layer and chunk recomputed whole)."""
+        policy = keep_policy(groups)
+
+        def sm_step(state, tokens):
+            (loss, router), grads = jax.value_and_grad(
+                lambda p: local_loss(p, tokens, policy),
+                has_aux=True)(state["params"])
+            # params-major maps: the array tree's structure governs, so the
+            # PartitionSpec leaves (tuple subclasses) are passed whole
+            grads = jax.tree.map(reduce_leaf, grads, param_specs)
+            loss = pmean_tree(loss, batch_axes)
+            updates, new_opt = optimizer.update(grads, state["opt_state"],
+                                                state["params"])
+            new_params = optax.apply_updates(state["params"], updates)
+            new_state = {"params": new_params, "opt_state": new_opt,
+                         "step": state["step"] + 1}
+            if not router:
+                return new_state, loss
+            return new_state, loss, pmean_tree(router, batch_axes)
+
+        return jax.jit(
+            shard_map(sm_step, mesh=mesh,
+                      in_specs=(state_specs, data_spec),
+                      out_specs=(state_specs,) + (P(),) * scalars,
+                      check=False),
+            in_shardings=(state_shardings, data_sharding),
+            out_shardings=(state_shardings,) + (repl,) * scalars,
+            donate_argnums=(0,) if donate else ())
+
+    if keep is not None or gather_mode == "streamed":
+        # a test's subset, no choice made; or the streamed fsdp scan, left
+        # exactly as it was: it recomputes a layer by construction, and
+        # what it could keep (its loss's products too) is a later PR's
+        return (init_jit, observe_compiled(step_keeping(tuple(keep or ())),
+                                           "spmd.train_step"),
+                data_sharding, state_shardings)
+
+    n_batch_shards = int(np.prod([mesh.shape[a] for a in batch_axes]))
+    t = mesh.shape[tensor] if tensor else 1
+
+    def sizes(state, tokens):
+        batch, seq = tokens.shape[0] // n_batch_shards, tokens.shape[1] - 1
+        return (kept_group_bytes(cfg, batch, seq, tensor=t),
+                loss_phase_bytes(cfg, batch, seq, tensor=t))
+
+    # a build with the default optimizer and rules is the same step as the
+    # last one with this key, and runs that one's executable
+    build_key = ((cfg, mesh, donate)
+                 if user_optimizer is None and rules is None else None)
+    train_step = _KeepingStep(step_keeping, sizes, mesh.devices.flat[0],
+                              "spmd.train_step", build_key)
     return init_jit, train_step, data_sharding, state_shardings
 
 

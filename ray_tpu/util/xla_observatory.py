@@ -41,7 +41,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from ray_tpu.core.config import global_config
 from ray_tpu.util import flight_recorder as _fr
@@ -49,6 +49,8 @@ from ray_tpu.util.metrics import Counter, Gauge, aggregate_series, registry
 
 __all__ = [
     "observe_compiled",
+    "ObservedFunction",
+    "analyses",
     "snapshot",
     "get_program",
     "program_names",
@@ -192,7 +194,12 @@ def _describe(fp: tuple) -> str:
 # --------------------------------------------------------------------------- #
 
 
-def _analyses(compiled, lowered=None) -> Dict[str, Any]:
+def analyses(compiled, lowered=None) -> Dict[str, Any]:
+    """What a compiled executable says of itself, each part only where the
+    backend gives it: ``flops`` / ``bytes_accessed`` (``cost_analysis()``),
+    ``memory`` (``memory_analysis()``: ``argument`` / ``output`` / ``temp`` /
+    ``code`` / ``alias`` bytes, and ``peak`` where the runtime gives it) and their ``peak_bytes``, the input
+    shardings, and of ``lowered`` the count of donated arguments."""
     info: Dict[str, Any] = {}
     try:
         ca = compiled.cost_analysis()
@@ -218,6 +225,10 @@ def _analyses(compiled, lowered=None) -> Dict[str, Any]:
             v = getattr(ma, attr, None)
             if v is not None:
                 mem[key] = int(v)
+        # the most the live buffers hold at once, arguments among them:
+        # newer runtimes give it; temp counts every temporary as live
+        if getattr(ma, "peak_memory_in_bytes", 0):
+            mem["peak"] = int(ma.peak_memory_in_bytes)
         if mem:
             info["memory"] = mem
             info["peak_bytes"] = (mem.get("argument", 0)
@@ -246,7 +257,7 @@ def _analyses(compiled, lowered=None) -> Dict[str, Any]:
 
 def _record_compiled(name: str, fp: tuple, fp_str: str, compiled,
                      compile_s: float, lowered=None) -> None:
-    info = _analyses(compiled, lowered)
+    info = analyses(compiled, lowered)
     with _LOCK:
         rec = _REGISTRY.get(name)
         if rec is None:
@@ -323,8 +334,37 @@ class ObservedFunction:
             "unobserved through jit from here on",
             what, self.program_name, type(exc).__name__, exc)
 
+    def _ahead_of_time(self) -> bool:
+        """Whether calls run executables this wrapper lowered and compiled
+        itself: while the observatory is on. A subclass that needs a
+        program's analyses whatever the knob says answers True."""
+        return global_config().xla_observatory_enabled
+
+    def _compile(self, fn, args, kwargs) -> "_Build":
+        """``fn`` lowered and compiled for these arguments, timed (and, with
+        the observatory on, under an ``xla.compile`` span)."""
+        t0 = time.monotonic()
+        lowered = fn.lower(*args, **kwargs)
+        compiled = lowered.compile()
+        if global_config().xla_observatory_enabled:
+            _sp_compile.end(t0, self.program_name)
+        return _Build(lowered, compiled, time.monotonic() - t0)
+
+    def _record(self, fp: tuple, build: "_Build") -> None:
+        """One compile into the registry; the last one recorded is what the
+        program's analyses (FLOPs, bytes, memory) say."""
+        if global_config().xla_observatory_enabled:
+            _record_compiled(self.program_name, fp, _describe(fp),
+                             build.compiled, build.seconds, build.lowered)
+
+    def _executable(self, fp: tuple, args, kwargs):
+        """What runs calls with a new fingerprint."""
+        build = self._compile(self._fn, args, kwargs)
+        self._record(fp, build)
+        return build.compiled
+
     def __call__(self, *args, **kwargs):
-        if self._unobserved or not global_config().xla_observatory_enabled:
+        if self._unobserved or not self._ahead_of_time():
             return self._fn(*args, **kwargs)
         try:
             fp = _fingerprint(args, kwargs)
@@ -333,14 +373,7 @@ class ObservedFunction:
             return self._fn(*args, **kwargs)
         compiled = self._cache.get(fp)
         if compiled is None:
-            t0 = time.monotonic()
-            lowered = self._fn.lower(*args, **kwargs)
-            compiled = lowered.compile()
-            dt = time.monotonic() - t0
-            _sp_compile.end(t0, self.program_name)
-            _record_compiled(self.program_name, fp, _describe(fp),
-                             compiled, dt, lowered)
-            self._cache[fp] = compiled
+            compiled = self._cache[fp] = self._executable(fp, args, kwargs)
         try:
             return compiled(*args, **kwargs)
         except (TypeError, ValueError) as e:
@@ -350,6 +383,13 @@ class ObservedFunction:
             self._stop_observing(
                 "the AOT executable rejected the call's arguments", e)
             return self._fn(*args, **kwargs)
+
+
+class _Build(NamedTuple):
+    """One lower + compile: the stages and the wall seconds they took."""
+    lowered: Any
+    compiled: Any
+    seconds: float
 
 
 def observe_compiled(fn_or_lowered, name: str):

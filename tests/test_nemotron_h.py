@@ -578,13 +578,15 @@ def test_loop_reports_held_share_and_sets_the_stack_gauge():
 # --- (f) the programs that were, and the benchmark's files ------------------ #
 
 
-def test_an_empty_pattern_lowers_to_the_pinned_texts():
+def test_an_empty_pattern_lowers_to_the_pinned_texts(monkeypatch):
     """The routed and the dense step of ``tests/test_olmoe.py``, whose
-    hashes that file pins: the new fields at their defaults build them."""
+    hashes that file pins (with the block's names off, as there): the new
+    fields at their defaults build them."""
     import hashlib
 
     import test_olmoe
 
+    test_olmoe.turn_names_off(monkeypatch)
     for name in ("routed spmd, one device", "dense spmd, one device"):
         sha, lower = test_olmoe.PROGRAMS[name]
         assert hashlib.sha256(lower().encode()).hexdigest() == sha

@@ -12,6 +12,7 @@ import dataclasses
 import hashlib
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -450,7 +451,16 @@ def _gspmd_text():
 # ``fsdp=4`` step, ``fsdp=2 tensor=2``): the carried layer's gather is
 # under ``stop_gradient``. With that call made the identity they lower to
 # the texts they had (``tests/test_spmd_train.py``, which keeps the old
-# ``fsdp=4`` hash); the other nine keep theirs.
+# ``fsdp=4`` hash); the other nine keep theirs. PR 32 gave the block's
+# products names (``checkpoint_name``: ``models.llama.KEEP_GROUPS``) and
+# all twelve keep their hashes, taken WITH THE NAMES OFF: a name lowers to
+# no operation, but while it is lowered each distinct (shape, name) takes a
+# number from the module's counter for private functions' names, so the
+# functions lowered after it are numbered on (``@_where_70`` is
+# ``@_where_71``, ``@silu_204`` ``@silu_208``) and the text's hash moves
+# though no operation does. The test below holds a program to its hash
+# with the names off, and with them on to the same text but for those
+# numbers.
 PROGRAMS = {
     "dense spmd, one device": (
         "90f52bb7182988719693b307dea1b7af700e18813e501c295d3f917e99fe4f41",
@@ -491,10 +501,32 @@ PROGRAMS = {
 }
 
 
+def turn_names_off(monkeypatch):
+    """``checkpoint_name`` made the identity where the model calls it."""
+    from ray_tpu.ops import flash_attention
+
+    for module in (llama, flash_attention):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+
+
+@pytest.fixture
+def names_off(monkeypatch):
+    turn_names_off(monkeypatch)
+    return monkeypatch
+
+
+def _unnumbered(text):
+    """``text`` with the counter's numbers off its private functions."""
+    return re.sub(r"(@[A-Za-z_]\w*?)_\d+\b", r"\1", text)
+
+
 @pytest.mark.parametrize("program", list(PROGRAMS))
-def test_program_lowers_to_the_same_text(program):
+def test_program_lowers_to_the_same_text(program, names_off):
     sha, lower = PROGRAMS[program]
-    assert hashlib.sha256(lower().encode()).hexdigest() == sha
+    unnamed = lower()
+    assert hashlib.sha256(unnamed.encode()).hexdigest() == sha
+    names_off.undo()  # as the program runs: the names lower to nothing
+    assert _unnumbered(lower()) == _unnumbered(unnamed)
 
 
 # the three streamed steps as bdeba28 lowered them, before PR 29
@@ -511,7 +543,7 @@ BEFORE_THE_CARRIED_GATHER_LEFT_THE_BACKWARD = {
 @pytest.mark.parametrize("program",
                          list(BEFORE_THE_CARRIED_GATHER_LEFT_THE_BACKWARD))
 def test_the_stop_gradient_is_all_that_changed_the_streamed_steps(
-        program, monkeypatch):
+        program, monkeypatch, names_off):
     """With ``prefetch_layer``'s ``stop_gradient`` made the identity (the
     one call that is given a dict: a layer's leaves) the step lowers to
     bdeba28's text: ``tests/test_spmd_train.py`` runs that program against
