@@ -7,8 +7,9 @@ model definition runs dp/fsdp/tp/sp via GSPMD. Design choices for the MXU:
 - layers stacked and scanned (``lax.scan``) — one compiled layer body,
   constant compile time in depth; or, where the config gives a
   ``layer_pattern``, a stack of Mamba-2, routed and attention layers (each
-  ONE half of the block), weights stacked per kind and walked in the
-  pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
+  ONE half of the block) or of shortcut-connected double layers (latent
+  attention, :func:`shortcut_layer`), weights stacked per kind and walked
+  in the pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
 - bf16 matmuls with fp32 accumulation (``preferred_element_type``), params
   stored fp32, gradients/optimizer fp32;
 - ``jax.checkpoint`` per layer (remat) to trade FLOPs for HBM, its products
@@ -58,6 +59,21 @@ _g_engine_weight_bytes = Gauge(
     "Bytes of model weights the decode engine keeps on the device, by "
     "the dtype they are held in", tag_keys=("dtype",))
 
+# what ONE position leaves in the engine's page store, over all layers: the
+# block's per-head keys and values (kind=kv) or a latent sublayer's row
+# (kind=latent); the other kind reads 0
+_g_engine_page_bytes = Gauge(
+    "ray_tpu_serve_engine_page_bytes",
+    "Bytes one position holds in the decode engine's page store, by the "
+    "layout the layer kind gives it", tag_keys=("kind",))
+# the last prefill's routed assignments, averaged over its layers: on the
+# experts held here, on identity experts, and on experts held elsewhere
+# (left out). Read with the logits; a model without a router sets none
+_g_moe_assignment_share = Gauge(
+    "ray_tpu_serve_moe_assignment_share",
+    "Share of the last prefill's routed assignments that fell on experts "
+    "held here, on identity experts, or elsewhere", tag_keys=("part",))
+
 # LlamaDecodeEngine's calls taken apart (one registration site per name):
 # the device program against what the host does on either side of it. The
 # three prefill spans lie inside the scheduler's serve.prefill; the three
@@ -105,7 +121,7 @@ def keep_policy(keep):
 
 # a patterned stack's layer kinds: the character -> the name of the kind's
 # stacked weights under params["layers"]
-LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe"}
 
 
 @dataclass(frozen=True)
@@ -149,8 +165,11 @@ class LlamaConfig:
     # The stack as data: one character a layer, of which the first n_layers
     # are built. "M" a Mamba-2 mixer (ops/ssm.py), "E" a routed
     # feed-forward, "*" attention; each layer is ONE of them,
-    # x + f(RMSNorm(x)). Empty: every layer is the block (attention THEN
-    # MLP), as every dense and every all-routed configuration has it.
+    # x + f(RMSNorm(x)). "S": the shortcut-connected double layer
+    # (shortcut_layer): two latent attentions and two dense feed-forwards
+    # in line, the routed experts beside the first and added at the end.
+    # Empty: every layer is the block (attention THEN MLP), as every dense
+    # and every all-routed configuration has it.
     layer_pattern: str = ""
     ssm_heads: int = 0      # H; d_inner = ssm_heads * ssm_head_dim
     ssm_head_dim: int = 0   # P
@@ -166,11 +185,26 @@ class LlamaConfig:
     # width (0 = num_experts: all here), first_expert the first one held
     router_experts: int = 0
     first_expert: int = 0
-    router_scoring: str = "softmax"  # or "sigmoid"; a "sigmoid" router has
-    #   a bias added for the choice alone, and no router loss is trained
+    router_scoring: str = "softmax"  # or "sigmoid", which trains no router
+    #   loss; a patterned stack's router has a bias added for the CHOICE
+    #   alone, whatever the scoring
     routed_scale: float = 1.0        # multiplies the top-k weights
     mlp_act: str = "swiglu"          # experts: or "relu2", two matrices
     shared_mlp_dim: int = 0          # a shared expert's width (0 = none)
+    zero_experts: int = 0  # identity experts, the router's LAST outputs: its
+    #   width is (router_experts or num_experts) + zero_experts
+    # the "S" layer: mlp_dim is ONE routed expert's width, dense_mlp_dim its
+    # two dense feed-forwards'; its attention is latent (_latent_half): q
+    # through a rank-q_lora_rank bottleneck, keys and values expanded from
+    # ONE rank-kv_lora_rank row a position; a head scores over
+    # qk_nope_head_dim + qk_rope_head_dim (the last rotated, its key shared
+    # by all heads) and returns v_head_dim
+    dense_mlp_dim: int = 0
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         bad = set(self.layer_pattern) - set(LAYER_KINDS)
@@ -185,16 +219,31 @@ class LlamaConfig:
                              f"mlp_act={self.mlp_act!r}")
         if not self.layer_pattern and (
                 self.router_experts or self.shared_mlp_dim
-                or self.router_scoring != "softmax"
+                or self.router_scoring != "softmax" or self.zero_experts
                 or self.mlp_act != "swiglu" or self.routed_scale != 1.0):
             raise ValueError(
                 "a held range, a shared expert, sigmoid scores, a weight "
-                "scale and relu2 experts belong to a patterned stack's 'E' "
-                "layer: the block's MLP half has none of them")
+                "scale, identity experts and relu2 experts belong to a "
+                "patterned stack's 'E' or 'S' layer: the block's MLP half "
+                "has none of them")
+        if "S" in self.kinds and not (
+                self.q_lora_rank and self.kv_lora_rank and self.v_head_dim
+                and self.qk_nope_head_dim and self.qk_rope_head_dim
+                and self.dense_mlp_dim and self.mlp_act == "swiglu"
+                and not self.shared_mlp_dim):
+            raise ValueError(
+                "an 'S' layer needs its latent ranks, its three head "
+                "widths and dense_mlp_dim, and has swiglu experts and no "
+                "shared one")
 
     @property
     def head_dim(self) -> int:
         return self.attn_head_dim or self.dim // self.n_heads
+
+    @property
+    def latent_row(self) -> int:
+        """What a position leaves behind in one latent sublayer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def kinds(self) -> str:
@@ -239,6 +288,17 @@ class LlamaConfig:
                       * d * self.mlp_dim + d),
                 "*": 2 * d * q + 2 * d * kv + d,
             }
+            if "S" in self.kinds:
+                rq, rkv, h = self.q_lora_rank, self.kv_lora_rank, self.n_heads
+                qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+                mla = (d * rq + rq + rq * h * qk + d * self.latent_row + rkv
+                       + rkv * h * (self.qk_nope_head_dim + self.v_head_dim)
+                       + h * self.v_head_dim * d)
+                wide = ((self.router_experts or self.num_experts)
+                        + self.zero_experts)
+                per_kind["S"] = (
+                    2 * mla + 2 * 3 * d * self.dense_mlp_dim + wide * (d + 1)
+                    + self.num_experts * 3 * d * self.mlp_dim + 4 * d)
             return emb + sum(per_kind[k] for k in self.kinds) + d
         attn = d * q + 2 * d * kv + q * d
         if self.qk_norm:
@@ -284,6 +344,24 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
                 "wv": ("layers", "embed", "kv_heads"),
                 "wo": ("layers", "heads", "embed"),
             },
+        }
+        # a double layer's leaves: the two sublayers' stacked behind the
+        # layer ([L, 2, ...]), router and experts as the "moe" kind's
+        two = ("layers", None)
+        kinds["scmoe"] = {
+            "attn_norm": two + (None,), "mlp_norm": two + (None,),
+            "wq_a": two + ("embed", None), "q_norm": two + (None,),
+            "wq_b": two + (None, "heads"),
+            "wkv_a": two + ("embed", None), "kv_norm": two + (None,),
+            "wkv_b": two + (None, "heads"), "wo": two + ("heads", "embed"),
+            "ffn_gate": two + ("embed", "mlp"),
+            "ffn_up": two + ("embed", "mlp"),
+            "ffn_down": two + ("mlp", "embed"),
+            "router": ("layers", "embed", None),
+            "router_bias": ("layers", None),
+            "w_gate": ("layers", None, "embed", "mlp"),
+            "w_up": ("layers", None, "embed", "mlp"),
+            "w_down": ("layers", None, "mlp", "embed"),
         }
         if cfg.mlp_act == "swiglu":
             kinds["moe"]["w_gate"] = ("layers", None, "embed", "mlp")
@@ -338,13 +416,14 @@ def _dense_init(rng, shape, fan_in):
 def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
     """A patterned stack's weights, stacked per KIND in the order the
     pattern meets them: ``{"mamba": {leaf: [n_M, ...]}, "moe": {...},
-    "attn": {...}}``, a kind the built layers lack left out. The router's
-    choice bias starts at zero, as published."""
+    "attn": {...}, "scmoe": {...}}``, a kind the built layers lack left
+    out. The router's choice bias starts at zero, as published."""
     from ray_tpu.ops.ssm import init_mamba2
 
     d, hd, f = cfg.dim, cfg.head_dim, cfg.mlp_dim
     nq, nkv = cfg.n_heads, cfg.n_kv_heads
-    held, wide = cfg.num_experts, cfg.router_experts or cfg.num_experts
+    held = cfg.num_experts
+    wide = (cfg.router_experts or cfg.num_experts) + cfg.zero_experts
     n = {kind: cfg.kinds.count(kind) for kind in LAYER_KINDS}
     k = iter(jax.random.split(key, 12))
     dense = _dense_init
@@ -379,6 +458,38 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
             "wk": dense(next(k), (L, d, nkv * hd), d),
             "wv": dense(next(k), (L, d, nkv * hd), d),
             "wo": dense(next(k), (L, nq * hd, d), nq * hd),
+        }
+    if n["S"]:
+        # keys of its own: the other kinds draw what they drew before
+        k = iter(jax.random.split(jax.random.fold_in(key, 1), 12))
+        L, fd = n["S"], cfg.dense_mlp_dim
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        kv = cfg.qk_nope_head_dim + cfg.v_head_dim
+        ones = lambda width: jnp.ones((L, 2, width), jnp.float32)  # noqa: E731
+        out["scmoe"] = {
+            "attn_norm": ones(d), "mlp_norm": ones(d),
+            # wq_b / wkv_b over sqrt(dim), not over sqrt(their rank): the
+            # latent half multiplies their inputs by sqrt(dim / rank), the
+            # published correction for matrices that all start at one
+            # variance; with it q, k and v have unit variance. Over sqrt(rank)
+            # scores are 6 wide and a softmax picks one key (4 layers then
+            # turn bfloat16's rounding into half a logit's size on the chip)
+            "wq_a": dense(next(k), (L, 2, d, rq), d), "q_norm": ones(rq),
+            "wq_b": dense(next(k), (L, 2, rq, nq * qk), d),
+            "wkv_a": dense(next(k), (L, 2, d, cfg.latent_row), d),
+            "kv_norm": ones(rkv),
+            "wkv_b": dense(next(k), (L, 2, rkv, nq * kv), d),
+            "wo": dense(next(k), (L, 2, nq * cfg.v_head_dim, d),
+                        nq * cfg.v_head_dim),
+            "ffn_gate": dense(next(k), (L, 2, d, fd), d),
+            "ffn_up": dense(next(k), (L, 2, d, fd), d),
+            "ffn_down": dense(next(k), (L, 2, fd, d), fd),
+            "router": dense(next(k), (L, d, wide), d),
+            "router_bias": jnp.zeros((L, wide), jnp.float32),
+            "w_gate": dense(next(k), (L, held, d, f), d),
+            "w_up": dense(next(k), (L, held, d, f), d),
+            "w_down": dense(next(k), (L, held, f, d), f),
         }
     return out
 
@@ -517,7 +628,8 @@ def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=()):
             held=((cfg.first_expert, cfg.num_experts)
                   if wide != cfg.num_experts else None),
             shared=((p["shared_up"], p["shared_down"])
-                    if cfg.shared_mlp_dim else None))
+                    if cfg.shared_mlp_dim else None),
+            zero_experts=cfg.zero_experts)
         return y.astype(cd), stats
     # the two products may be kept (KEEP_GROUPS); silu is made again
     g = jax.nn.silu(checkpoint_name(h @ p["w_gate"].astype(cd), "mlp"))
@@ -538,8 +650,9 @@ def add_router_losses(cfg: LlamaConfig, nll, stats):
                   "z_loss": stats["z_loss"].mean()}
     report.update(max_load_ratio=stats["max_load_ratio"].max(),
                   dropped=stats["dropped"].sum())
-    if "held_share" in stats:  # the layers hold a range of the experts
-        report["held_share"] = stats["held_share"].mean()
+    for share in ("held_share", "zero_share"):
+        if share in stats:  # the layers hold a range; have identity experts
+            report[share] = stats[share].mean()
     if "lb_loss" not in stats:
         return nll, report
     total = (nll + cfg.lb_loss_coef * report["lb_loss"]
@@ -595,13 +708,171 @@ def _attn_half(cfg: LlamaConfig, p, h, positions, attend):
     return checkpoint_name(attn @ p["wo"].astype(cfg.dtype), "attn"), k, v
 
 
+# the expanding latent attention scores a block of queries against a block of
+# keys at a time: float32 scores of [heads, this, this] (67 MB at 64 heads),
+# where [heads, T, T] would be 17 GB at 8,192 positions
+LATENT_QUERY_BLOCK = 512
+
+
+def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
+    """The latent attention half (``_attn_half``'s counterpart) on ``h``
+    [B, T, dim] (normed, cfg.dtype), before the residual add. ``p``: ONE
+    sublayer's ``wq_a, q_norm, wq_b, wkv_a, kv_norm, wkv_b, wo``.
+
+    ``q = RMSNorm(h wq_a) wq_b`` times ``sqrt(dim / q_lora_rank)``, a head
+    ``[nope | rope]``; ``h wkv_a = [c | kr]``, ``c = RMSNorm(c) *
+    sqrt(dim / kv_lora_rank)`` (both scales always: the one published model
+    of this kind sets both); RoPE over pairs ``(2i, 2i + 1)`` of ``q``'s
+    rope slice and of ``kr``, the ONE rotated key slice all heads share.
+    ``attend(q, latent, wkv_b)`` -> ``[B, T, heads, v_head_dim]`` decides
+    whether ``c wkv_b = [k_nope | v]`` a head is ever made (prefill) or
+    absorbed into the query and the output (decode). Returns ``(y,
+    latent)``: ``latent`` ``[B, T, kv_lora_rank + qk_rope_head_dim]`` is
+    ``[c | kr]``, normed, scaled and rotated: what a cache keeps."""
+    cd, d, eps = cfg.dtype, cfg.dim, cfg.norm_eps
+    B, T, _ = h.shape
+    H, r, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    with jax.named_scope("mla.project"):
+        # a norm's weight times the scale: one rounding, not two
+        qa = rms_norm(h @ p["wq_a"].astype(cd),
+                      p["q_norm"] * math.sqrt(d / cfg.q_lora_rank), eps)
+        q = (qa @ p["wq_b"].astype(cd)).reshape(B, T, H, -1)
+        ckr = h @ p["wkv_a"].astype(cd)
+        c = rms_norm(ckr[..., :r], p["kv_norm"] * math.sqrt(d / r), eps)
+        q_rope, kr = rotary_embedding(
+            q[..., dn:], ckr[:, :, None, r:], positions, cfg.rope_theta,
+            interleaved=True)
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        latent = jnp.concatenate([c, kr[:, :, 0]], axis=-1)
+    with jax.named_scope("mla.attend"):
+        o = attend(q, latent, p["wkv_b"].astype(cd))
+    with jax.named_scope("mla.out"):
+        y = o.reshape(B, T, -1) @ p["wo"].astype(cd)
+    return y, latent
+
+
+def attend_latent_expanded(cfg: LlamaConfig, q, latent, wkv_b):
+    """``_latent_half``'s ``attend`` over the call's own positions, causal
+    (the full forward and prefill): every position's per-head keys and
+    values are made, ``c wkv_b``. Queries go in blocks of
+    ``LATENT_QUERY_BLOCK`` (``lax.map``), each over the key blocks up to
+    its own (a ``lax.scan`` whose later steps are skipped), the softmax
+    carried across key blocks in float32 (running maximum, sum and weighted
+    values), so the scores alive are ``[heads, block, block]``."""
+    cd, f32 = cfg.dtype, jnp.float32
+    B, T, H, D = q.shape
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = (latent[..., :r] @ wkv_b).reshape(B, T, H, -1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        latent[:, :, None, r:], (B, T, H, D - dn))], axis=-1)
+    v = kv[..., dn:]
+    block = math.gcd(T, LATENT_QUERY_BLOCK)
+    at = jnp.arange(block, dtype=jnp.int32)
+    numbers = jnp.arange(T // block, dtype=jnp.int32)
+
+    def cut(a, n):  # block ``n`` of the positions, where it is used
+        return jax.lax.dynamic_slice_in_dim(a, n * block, block, axis=1)
+
+    def query_block(i):
+        q_b = cut(q, i)
+
+        def key_block(carry, j):
+            def seen(carry):
+                k_b, v_b = cut(k, j), cut(v, j)
+                top, total, acc = carry
+                s = jnp.einsum("bqhd,bkhd->bhqk", q_b, k_b,
+                               preferred_element_type=f32) / math.sqrt(D)
+                s = jnp.where((i * block + at)[:, None]
+                              >= (j * block + at)[None, :], s, -1e30)
+                new_top = jnp.maximum(top, s.max(axis=-1))
+                probs = jnp.exp(s - new_top[..., None])
+                old = jnp.exp(top - new_top)
+                acc = acc * old[..., None] + jnp.einsum(
+                    "bhqk,bkhd->bhqd", probs.astype(cd), v_b,
+                    preferred_element_type=f32)
+                return new_top, total * old + probs.sum(axis=-1), acc
+
+            # key blocks behind the query block hold nothing it may see
+            return jax.lax.cond(j <= i, seen, lambda c: c, carry), None
+
+        start = (jnp.full((B, H, block), -1e30, f32),
+                 jnp.zeros((B, H, block), f32),
+                 jnp.zeros((B, H, block, v.shape[-1]), f32))
+        (_, total, acc), _ = jax.lax.scan(key_block, start, numbers)
+        return jnp.moveaxis(acc / total[..., None], 1, 2).astype(cd)
+
+    o = jax.lax.map(query_block, numbers)  # [T / block, B, block, H, dv]
+    return jnp.moveaxis(o, 0, 1).reshape(B, T, H, -1)
+
+
+def shortcut_layer(cfg: LlamaConfig, x, layers, i, positions, attend,
+                   stat_axes=()):
+    """THE shortcut-connected double layer (kind ``"S"``), for the full
+    forward and for the serving programs::
+
+        a0  = x  + MLA_0(N(x))
+        h0  = N(a0);  m = MoE(h0)          # the shortcut: m waits
+        b0  = a0 + FFN_0(h0)
+        a1  = b0 + MLA_1(N(b0))
+        b1  = a1 + FFN_1(N(a1))
+        out = b1 + m                       # attention 1 and FFN 1 never see m
+
+    ``layers``: the kind's stacked weights ``[n_S, ...]``, ``i``: which
+    layer (a number, or traced in a scan over layers). The two sublayers'
+    norms, latent attention (:func:`_latent_half`) and dense SwiGLU are
+    stacked ``[n_S, 2, ...]``, router and experts are :func:`_mlp_half`'s.
+    Every matrix is cut out where it is used, ``[i, j]`` at once: a whole
+    layer cut out first is a copy of 2.5 GB a layer at the published
+    widths (30 of a decode call's 37 ms; my chip run, PR 33).
+    ``attend(j, q, latent, wkv_b)``: what sublayer ``j`` attends over.
+    Returns ``(x, stats, latents)``: ``latents`` ``[2, B, T, latent_row]``,
+    what each sublayer's cache keeps."""
+    cd = cfg.dtype
+
+    def norm(y, name, j):
+        return rms_norm(y, layers[name][i, j], cfg.norm_eps).astype(cd)
+
+    latents = []
+
+    def mla(j, y):
+        sub = {w: layers[w][i, j] for w in ("wq_a", "q_norm", "wq_b", "wkv_a",
+                                            "kv_norm", "wkv_b", "wo")}
+        out, latent = _latent_half(cfg, sub, norm(y, "attn_norm", j),
+                                   positions, partial(attend, j))
+        latents.append(latent)
+        return y + out.astype(y.dtype)
+
+    def ffn(j, h):
+        with jax.named_scope("ffn.dense"):
+            g = jax.nn.silu(h @ layers["ffn_gate"][i, j].astype(cd))
+            return (g * (h @ layers["ffn_up"][i, j].astype(cd))) \
+                @ layers["ffn_down"][i, j].astype(cd)
+
+    a0 = mla(0, x)
+    h0 = norm(a0, "mlp_norm", 0)
+    m, stats = _mlp_half(cfg, {w: layers[w][i] for w in (
+        "router", "router_bias", "w_gate", "w_up", "w_down")}, h0, stat_axes)
+    b0 = a0 + ffn(0, h0).astype(x.dtype)
+    a1 = mla(1, b0)
+    b1 = a1 + ffn(1, norm(a1, "mlp_norm", 1)).astype(x.dtype)
+    return b1 + m.astype(x.dtype), stats, jnp.stack(latents)
+
+
 def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
-    """One layer of a patterned stack (``cfg.layer_pattern``), each kind a
-    half of the block: ``x + f(RMSNorm(x))`` with ``f`` the Mamba-2 mixer
+    """One layer of a patterned stack (``cfg.layer_pattern``). ``"S"`` is
+    the double layer (:func:`shortcut_layer`, its latent attention
+    expanded: :func:`attend_latent_expanded`). Every other kind is a half of
+    the block: ``x + f(RMSNorm(x))`` with ``f`` the Mamba-2 mixer
     (``"M"``, :func:`ray_tpu.ops.ssm.mamba2_mixer`), the routed
     feed-forward (``"E"``, :func:`_mlp_half`) or attention (``"*"``,
     :func:`_attn_half` over ``attend``). ``p``: this layer's weights, of its
-    kind. Returns ``(x, stats)``, ``stats`` ``{}`` but for ``"E"``."""
+    kind. Returns ``(x, stats)``, ``stats`` ``{}`` but for ``"E"`` and
+    ``"S"``."""
+    if kind == "S":  # this layer's weights as a stack of one
+        return shortcut_layer(
+            cfg, x, jax.tree.map(lambda a: a[None], p), 0,
+            positions_of(*x.shape[:2]),
+            lambda j, *a: attend_latent_expanded(cfg, *a), stat_axes)[:2]
     h = rms_norm(x, p["norm"], cfg.norm_eps).astype(cfg.dtype)
     stats = {}
     if kind == "M":
@@ -840,71 +1111,117 @@ def _gqa_repeat(cfg: LlamaConfig, k, v):
 
 
 def _page_slab(pages, page):
-    """The ``[L, 1, page_size, n_kv, head_dim]`` slab of one physical page
-    (``page`` traced) of a store laid out ``[L, n_pages, ...]``."""
-    L, _, ps, nkv, hd = pages.shape
-    return jax.lax.dynamic_slice(pages, (0, page, 0, 0, 0),
-                                 (L, 1, ps, nkv, hd))
+    """The ``[S, 1, page_size, *row]`` slab of one physical page (``page``
+    traced) of a store laid out ``[S, n_pages, page_size, *row]``."""
+    S, _, ps, *row = pages.shape
+    return jax.lax.dynamic_slice(pages, (0, page, 0) + (0,) * len(row),
+                                 (S, 1, ps, *row))
 
 
 def _read_pages(pages, page_ids):
     """The ``n`` physical pages ``page_ids``, in that order, as one
-    ``[L, n * page_size, n_kv, head_dim]`` view. One dynamic slice per
-    page (``n`` is static) and not ``pages[:, page_ids]``: XLA moves the
-    layers' conversion to the compute dtype in front of a gather and
-    then converts the WHOLE store on every call (6.8 of a 29.5 ms decode
-    call at 128 pages; my chip run, PR 25)."""
-    L, _, ps, nkv, hd = pages.shape
+    ``[S, n * page_size, *row]`` view. One dynamic slice per page (``n``
+    is static) and not ``pages[:, page_ids]``: XLA moves the layers'
+    conversion to the compute dtype in front of a gather and then converts
+    the WHOLE store on every call (6.8 of a 29.5 ms decode call at 128
+    pages; my chip run, PR 25)."""
+    S, _, ps, *row = pages.shape
     slabs = [_page_slab(pages, page_ids[i])
              for i in range(page_ids.shape[0])]
-    return jnp.concatenate(slabs, axis=1).reshape(L, -1, nkv, hd)
+    return jnp.concatenate(slabs, axis=1).reshape(S, -1, *row)
 
 
-def _write_pages(pages, kv, page_ids):
-    """Write ``kv`` [L, n * page_size, n_kv, head_dim] into the ``n``
-    physical pages ``page_ids`` of ``pages``. One dynamic-update-slice per
-    page (``n`` is static): with the store donated each is in place."""
-    L, _, ps, nkv, hd = pages.shape
-    kv = kv.astype(pages.dtype).reshape(L, -1, ps, nkv, hd)
-    for i in range(kv.shape[1]):
+def _write_pages(pages, rows, page_ids):
+    """Write ``rows`` [S, n * page_size, *row] into the ``n`` physical
+    pages ``page_ids`` of ``pages``. One dynamic-update-slice per page
+    (``n`` is static): with the store donated each is in place."""
+    S, _, ps, *row = pages.shape
+    rows = rows.astype(pages.dtype).reshape(S, -1, ps, *row)
+    for i in range(rows.shape[1]):
         pages = jax.lax.dynamic_update_slice(
-            pages, kv[:, i:i + 1], (0, page_ids[i], 0, 0, 0))
+            pages, rows[:, i:i + 1], (0, page_ids[i], 0) + (0,) * len(row))
     return pages
 
 
-def prefill_with_cache(cfg: LlamaConfig, params, k_pages, v_pages, tokens,
-                       page_ids, last):
+def page_rows(cfg: LlamaConfig):
+    """What the engine's page stores keep, by the layer kind: ``(kind,
+    [(S, row), ...])``, a store ``[S, n_pages, page_size, *row]`` each. The
+    block (``"kv"``) keeps per-head keys and values, a store each over its
+    layers; the double layer (``"latent"``) keeps ONE store of latent rows
+    over its ``2 * n_layers`` attention sublayers, from which decode never
+    expands keys or values."""
+    if cfg.layer_pattern:
+        return "latent", [(2 * cfg.n_layers, (cfg.latent_row,))]
+    return "kv", [(cfg.n_layers, (cfg.n_kv_heads, cfg.head_dim))] * 2
+
+
+def _latent_layers(cfg: LlamaConfig, x, layers, positions, attend, cache):
+    """The served double layers, scanned (every layer is ``"S"``, so one
+    compiled body; the scan carries the layer's number, not its weights:
+    see :func:`shortcut_layer`): layer ``l``'s sublayer ``j`` attends
+    through ``attend(cache[l], j, q, latent, wkv_b)``; ``cache`` ``[L, 2,
+    ...]`` (or None: nothing cached). Returns ``(x, latent rows
+    [2 L, B, T, latent_row], the routed assignments' shares, averaged over
+    layers)``."""
+
+    def body(carry, xs):
+        i, cached = xs
+        h, stats, latents = shortcut_layer(cfg, carry, layers["scmoe"], i,
+                                           positions, partial(attend, cached))
+        return h, (latents, stats)
+
+    x, (rows, stats) = jax.lax.scan(
+        body, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), cache))
+    shares = {k: stats[k].mean() for k in ("held_share", "zero_share")
+              if k in stats}
+    return x, rows.reshape(-1, *rows.shape[2:]), shares
+
+
+def prefill_with_cache(cfg: LlamaConfig, params, *args):
     """Prefill one sequence into its pages, inside the program.
 
-    ``k_pages`` / ``v_pages``: the engine's page stores ``[L, n_pages,
-    page_size, n_kv, head_dim]`` (donated by the engine, updated in place);
-    ``tokens`` [1, n * page_size] int32, right-padded (causal masking
-    keeps pad garbage out of real positions); ``page_ids`` [n] int32;
-    ``last`` int32 scalar, the last real position. Returns ``(k_pages,
-    v_pages, logits [vocab] fp32)``: the post-RoPE keys and values of ALL
-    ``n * page_size`` positions are written, the pad positions of the last
-    page included (they hold the pad token's keys: finite, and masked by
-    every decode until the sequence itself overwrites them), and the head
-    is applied to position ``last`` alone."""
+    ``args``: ``*stores, tokens, page_ids, last``. ``stores``: the engine's
+    page stores (:func:`page_rows`: ``k_pages, v_pages`` ``[L, n_pages,
+    page_size, n_kv, head_dim]`` for the block, ``latent_pages`` alone for
+    the double layer; donated by the engine, updated in place); ``tokens``
+    [1, n * page_size] int32, right-padded (causal masking keeps pad
+    garbage out of real positions); ``page_ids`` [n] int32; ``last`` int32
+    scalar, the last real position. Returns ``(*stores, logits [vocab]
+    fp32, shares)``: the post-RoPE keys and
+    values (the latent rows) of ALL ``n * page_size`` positions are
+    written, the pad positions of the last page included (they hold the pad
+    token's keys: finite, and masked by every decode until the sequence
+    itself overwrites them), and the head is applied to position ``last``
+    alone. ``shares``: a routed model's assignment shares (``{}`` for a
+    dense one)."""
+    *stores, tokens, page_ids, last = args
     x = embed_tokens(cfg, params, tokens, None)
     positions = positions_of(*tokens.shape)
+    shares = {}
+    if cfg.layer_pattern:
+        # keys and values expanded, attended in tiles
+        x, rows, shares = _latent_layers(
+            cfg, x, params["layers"], positions,
+            lambda cached, j, *a: attend_latent_expanded(cfg, *a), None)
+        new = (rows,)
+    else:
+        def attend(q, k, v):
+            # plain attention, float32 scores: decode numerics never depend
+            # on prefill matching a fused kernel, only on the cached bytes
+            return plain_attention(q, *_gqa_repeat(cfg, k, v), causal=True)
 
-    def attend(q, k, v):
-        # plain attention, float32 scores: decode numerics never depend on
-        # prefill matching a fused kernel, only on the cached bytes
-        return plain_attention(q, *_gqa_repeat(cfg, k, v), causal=True)
+        def body(carry, layer_params):
+            h, _, kv = decoder_block(cfg, carry, layer_params, positions,
+                                     attend)
+            return h, kv
 
-    def body(carry, layer_params):
-        h, _, kv = decoder_block(cfg, carry, layer_params, positions, attend)
-        return h, kv
-
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    k_pages = _write_pages(k_pages, ks[:, 0], page_ids)
-    v_pages = _write_pages(v_pages, vs[:, 0], page_ids)
+        x, new = jax.lax.scan(body, x, params["layers"])
+    stores = [_write_pages(pages, rows[:, 0], page_ids)
+              for pages, rows in zip(stores, new)]
     # final_norm and the head are per position: one row, not T
     x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
     logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
-    return k_pages, v_pages, logits[0, 0]
+    return (*stores, logits[0, 0], shares)
 
 
 def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv):
@@ -928,78 +1245,119 @@ def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv):
                       V.astype(jnp.float32)).astype(cd)
 
 
-def decode_step_with_cache(cfg: LlamaConfig, params, k_pages, v_pages,
-                           token, pos, page_ids):
+def _attend_latent_cached(cfg: LlamaConfig, cache, length, q, latent, wkv_b):
+    """One new token (``_latent_half``'s ``attend`` arguments) against ONE
+    sublayer's gathered, page-padded latent rows ``[Tpad, latent_row]``,
+    masked as :func:`_attend_cached` masks. No key or value is expanded:
+    ``wkv_b``'s key half is absorbed into the query (``q_abs[h] = q_nope[h]
+    Wk[h]^T``, as wide as a latent row's ``c``), the scores are taken
+    against the rows themselves, and its value half is applied to the
+    ``heads`` weighted sums of rows. Float32 scores."""
+    cd, f32 = cfg.dtype, jnp.float32
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    Tpad = cache.shape[0]
+    w = wkv_b.reshape(r, cfg.n_heads, -1)  # a head's [Wk | Wv]
+    rows = jnp.concatenate([cache.astype(cd), latent[0]], axis=0).astype(f32)
+    q_abs = jnp.einsum("bqhd,rhd->bqhr", q[..., :dn], w[..., :dn],
+                       preferred_element_type=f32)
+    q_row = jnp.concatenate([q_abs, q[..., dn:].astype(f32)], axis=-1)
+    s = jnp.einsum("bqhc,kc->bhqk", q_row, rows) / math.sqrt(q.shape[-1])
+    idx = jnp.arange(Tpad + 1)
+    valid = (idx < length) | (idx == Tpad)  # history + the token itself
+    probs = jax.nn.softmax(jnp.where(valid, s, -1e30), axis=-1)
+    o = jnp.einsum("bhqk,kr->bqhr", probs, rows[:, :r])
+    return jnp.einsum("bqhr,rhd->bqhd", o.astype(cd), w[..., dn:],
+                      preferred_element_type=f32).astype(cd)
+
+
+def decode_step_with_cache(cfg: LlamaConfig, params, *args):
     """One decode step of one sequence against the page stores.
 
-    ``token`` [1] int32; ``pos`` int32 scalar (the KV write position =
-    tokens so far); ``page_ids`` [n] int32, the sequence's page table in
-    order. The table's pages are gathered on the device into the
-    ``[L, n * page_size, n_kv, head_dim]`` view (positions >= ``pos`` are
-    masked), and the new position's keys and values are written at
-    ``(page_ids[pos // page_size], pos % page_size)``. Returns
-    ``(k_pages, v_pages, logits [vocab] fp32)``. ``pos`` and the page ids
-    are traced, so one compilation covers every step at a given page
-    count."""
-    ps = k_pages.shape[2]
-    k_cache = _read_pages(k_pages, page_ids)
-    v_cache = _read_pages(v_pages, page_ids)
+    ``args``: ``*stores, token, pos, page_ids``. ``token`` [1] int32; ``pos`` int32 scalar (the write position = tokens
+    so far); ``page_ids`` [n] int32, the sequence's page table in order.
+    The table's pages are gathered on the device into the ``[S, n *
+    page_size, *row]`` view of each store (positions >= ``pos`` are
+    masked), and the new position's keys and values (its latent rows) are
+    written at ``(page_ids[pos // page_size], pos % page_size)``. Returns
+    ``(*stores, logits [vocab] fp32)``. ``pos`` and the page ids are
+    traced, so one compilation covers every step at a given page count."""
+    *stores, token, pos, page_ids = args
+    ps = stores[0].shape[2]
+    cached = [_read_pages(pages, page_ids) for pages in stores]
     x = embed_tokens(cfg, params, token[None, :], None)
     positions = jnp.full((1, 1), pos, dtype=jnp.int32)
+    if cfg.layer_pattern:
+        x, rows, _ = _latent_layers(
+            cfg, x, params["layers"], positions,
+            lambda cached, j, *a: _attend_latent_cached(
+                cfg, cached[j], pos, *a),
+            cached[0].reshape(cfg.n_layers, 2, *cached[0].shape[1:]))
+        new = (rows[:, 0],)
+    else:
+        def body(carry, xs):
+            p, kc, vc = xs
+            h, _, (kn, vn) = decoder_block(
+                cfg, carry, p, positions,
+                partial(_attend_cached, cfg, kc, vc, pos))
+            return h, (kn[:, 0], vn[:, 0])
 
-    def body(carry, xs):
-        p, kc, vc = xs
-        h, _, (kn, vn) = decoder_block(
-            cfg, carry, p, positions, partial(_attend_cached, cfg, kc, vc, pos))
-        return h, (kn[:, 0], vn[:, 0])
-
-    x, (kns, vns) = jax.lax.scan(body, x,
-                                 (params["layers"], k_cache, v_cache))
+        x, new = jax.lax.scan(body, x, (params["layers"], *cached))
     logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
-    at = (0, page_ids[pos // ps], pos % ps, 0, 0)
-    k_pages = jax.lax.dynamic_update_slice(
-        k_pages, kns[:, :, None].astype(k_pages.dtype), at)
-    v_pages = jax.lax.dynamic_update_slice(
-        v_pages, vns[:, :, None].astype(v_pages.dtype), at)
-    return k_pages, v_pages, logits[0, 0]
+    at = (0, page_ids[pos // ps], pos % ps)
+    stores = [jax.lax.dynamic_update_slice(
+        pages, rows[:, :, None].astype(pages.dtype),
+        at + (0,) * (pages.ndim - 3)) for pages, rows in zip(stores, new)]
+    return (*stores, logits[0, 0])
 
 
-def copy_page_in_stores(k_pages, v_pages, src, dst):
-    """Physical page ``src`` duplicated into ``dst`` (both traced) in both
-    stores."""
-    at = (0, dst, 0, 0, 0)
-    return (jax.lax.dynamic_update_slice(k_pages, _page_slab(k_pages, src),
-                                         at),
-            jax.lax.dynamic_update_slice(v_pages, _page_slab(v_pages, src),
-                                         at))
+def copy_page_in_stores(*args):
+    """Physical page ``src`` duplicated into ``dst`` (both traced) in every
+    store: ``args`` is ``*stores, src, dst``."""
+    *stores, src, dst = args
+    return tuple(jax.lax.dynamic_update_slice(
+        pages, _page_slab(pages, src), (0, dst) + (0,) * (pages.ndim - 2))
+        for pages in stores)
 
 
 # the leaves the serving programs multiply (each stands under an
-# ``.astype(cfg.dtype)`` in embed_tokens / the head and in the block's
-# _qkv / wo / dense _mlp_half). By name, not by rank: the stacked norms are
-# two-dimensional too, and rms_norm uses them in float32.
+# ``.astype(cfg.dtype)`` in embed_tokens / the head, in the block's _qkv /
+# wo / dense _mlp_half, in the double layer's _latent_half and dense
+# feed-forwards, and the experts' in routed_mlp). By name, not by rank: the
+# stacked norms are two-dimensional too, and rms_norm and the router use
+# theirs in float32.
 _MATMUL_TOP = ("embedding", "lm_head")
-_MATMUL_LAYER = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+_MATMUL_LAYER = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+                 "wq_a", "wq_b", "wkv_a", "wkv_b",
+                 "ffn_gate", "ffn_up", "ffn_down")
 
 
 def serving_params(cfg: LlamaConfig, params) -> Dict[str, Any]:
     """``params`` with every leaf the serving programs multiply held in
     ``cfg.dtype``: the round-to-nearest conversion the programs apply in
     front of each product, made once, in one jitted call over the leaves
-    that need it. Every other leaf (the norms; a weight already in
-    ``cfg.dtype``) is the array it was, and a tree with nothing to convert
-    is returned as it is. Traced inside the program that builds the
-    weights, the float32 ones are that program's temporaries."""
+    that need it. Every other leaf (the norms, a router and its bias; a
+    weight already in ``cfg.dtype``) is the array it was, and a tree with
+    nothing to convert is returned as it is. Traced inside the program that
+    builds the weights, the float32 ones are that program's temporaries.
+    A patterned stack's leaves lie a level down, under their kind."""
     cd = jnp.dtype(cfg.dtype)
-    top = {k: params[k] for k in _MATMUL_TOP
-           if k in params and params[k].dtype != cd}
-    layers = {k: params["layers"][k] for k in _MATMUL_LAYER
-              if params["layers"][k].dtype != cd}
-    if not (top or layers):
+
+    def matmuls(tree, names):
+        return {k: tree[k] for k in names
+                if k in tree and tree[k].dtype != cd}
+
+    top = matmuls(params, _MATMUL_TOP)
+    kinds = (params["layers"] if cfg.layer_pattern
+             else {"": params["layers"]})
+    layers = {kind: matmuls(tree, _MATMUL_LAYER)
+              for kind, tree in kinds.items()}
+    if not (top or any(layers.values())):
         return params
     top, layers = jax.jit(lambda tree: jax.tree.map(
         lambda w: w.astype(cd), tree))((top, layers))
-    return {**params, **top, "layers": {**params["layers"], **layers}}
+    layers = {kind: {**kinds[kind], **layers[kind]} for kind in kinds}
+    return {**params, **top,
+            "layers": layers if cfg.layer_pattern else layers[""]}
 
 
 class LlamaDecodeEngine:
@@ -1007,25 +1365,37 @@ class LlamaDecodeEngine:
     engine protocol :class:`ray_tpu.serve.decode.DecodeScheduler` drives
     (prefill/decode/copy_page + pool/prefix_cache/page_size).
 
+    It serves a stack of dense blocks and a stack of shortcut-connected
+    double layers (``layer_pattern`` all ``"S"``: latent attention, routed
+    and identity experts); a Mamba layer's state has no page kind yet, and
+    no test holds QK-norm or the routed block to a reference here, so those
+    are refused.
+
     ``params`` is the tree the programs run on: ``init_params``' layout
-    with the matmul weights (``embedding``, ``lm_head``, ``wq`` .. ``w_down``)
-    in ``cfg.dtype``, converted ONCE here and not inside every call, and
-    the norms in float32. A float32 tree passed in (a trainer's) is
+    with the matmul weights (``embedding``, ``lm_head``, ``wq`` .. ``w_down``,
+    the latent projections, dense feed-forwards and experts of a double
+    layer) in ``cfg.dtype``, converted ONCE here and not inside every call,
+    and the norms, a router and its bias in float32. A float32 tree passed in (a trainer's) is
     converted and not kept; leaves already in ``cfg.dtype`` are kept as
     they are. At bfloat16 that is two bytes a parameter on the device
     (3.8 GB at 1.89B parameters), which
     ``ray_tpu_serve_engine_weight_bytes{dtype}`` reports.
 
-    Physical pages live ON THE DEVICE, in two float32 arrays indexed by
-    pool page id: ``k_pages`` / ``v_pages`` ``[L, n_pages, page_size,
-    n_kv, head_dim]`` (``2 * n_pages * page_size * L * n_kv * head_dim *
-    4`` bytes beside the weights). They are read and written only inside
-    three jitted programs that take them donated and return them: prefill
-    writes the scan's k/v into the pages it is given, decode gathers the
-    sequence's page table into a page-padded view (positions beyond the
-    true length are masked, so a compilation per page count serves every
-    sequence and step) and writes the new position, copy_page duplicates
-    one page. A call moves token ids and page ids in and one ``[vocab]``
+    Physical pages live ON THE DEVICE, in float32 arrays indexed by pool
+    page id, ``stores``: what a position keeps there is the layer kind's
+    (:func:`page_rows`). The block: two stores, per-head keys and values,
+    ``[L, n_pages, page_size, n_kv, head_dim]`` each (``2 * n_pages *
+    page_size * L * n_kv * head_dim * 4`` bytes beside the weights). The
+    double layer: ONE store of latent rows ``[2 L, n_pages, page_size,
+    kv_lora_rank + qk_rope_head_dim]``, a row an attention sublayer, from
+    which prefill expands keys and values and decode never does
+    (``ray_tpu_serve_engine_page_bytes{kind}``: a position's bytes). They
+    are read and written only inside three jitted programs that take them
+    donated and return them: prefill writes the scan's rows into the pages
+    it is given, decode gathers the sequence's page table into a
+    page-padded view (positions beyond the true length are masked, so a
+    compilation per page count serves every sequence and step) and writes
+    the new position, copy_page duplicates one page. A call moves token ids and page ids in and one ``[vocab]``
     row of float32 logits out.
 
     One caller at a time (the scheduler's lock covers a whole iteration):
@@ -1038,11 +1408,13 @@ class LlamaDecodeEngine:
         from ray_tpu.serve.kv_cache import PagePool, PrefixCache
 
         self.cfg = cfg or LlamaConfig.debug()
-        _dense_only(
-            self.cfg, "LlamaDecodeEngine",
-            "no test compares its logits with the reference for such a "
-            "config, and serving_params and the page store's sizing know "
-            "the dense weights only")
+        if set(self.cfg.kinds) != {"S"}:
+            _dense_only(
+                self.cfg, "LlamaDecodeEngine",
+                "of the patterned kinds it serves 'S' alone (a Mamba "
+                "layer's state has no page kind), and for QK-norm or a "
+                "routed block no test compares its logits with the "
+                "reference")
         if params is None:
             # one jitted program, not a dozen eager ones: at 664.6M
             # parameters the eager form spends 67 s on a v5e, nearly all of
@@ -1066,20 +1438,27 @@ class LlamaDecodeEngine:
         self.pool = PagePool(n_pages, page_size)
         self.prefix_cache = PrefixCache(self.pool)
         self._np = np
-        c = self.cfg
-        shape = (c.n_layers, n_pages, page_size, c.n_kv_heads, c.head_dim)
-        self.k_pages = jnp.zeros(shape, jnp.float32)
-        self.v_pages = jnp.zeros(shape, jnp.float32)
+        kind, rows = page_rows(self.cfg)
+        self.stores = tuple(
+            jnp.zeros((S, n_pages, page_size, *row), jnp.float32)
+            for S, row in rows)
+        # both tags always, as above
+        page_bytes = {"kv": 0, "latent": 0,
+                      kind: sum(4 * S * math.prod(row) for S, row in rows)}
+        for tag, nbytes in page_bytes.items():
+            _g_engine_page_bytes.set(float(nbytes), tags={"kind": tag})
+        donated = tuple(range(1, 1 + len(rows)))  # the stores, every call
         self._prefill_fn = observe_compiled(
             jax.jit(partial(prefill_with_cache, self.cfg),
-                    donate_argnums=(1, 2)),
+                    donate_argnums=donated),
             "llama.prefill")
         self._decode_fn = observe_compiled(
             jax.jit(partial(decode_step_with_cache, self.cfg),
-                    donate_argnums=(1, 2)),
+                    donate_argnums=donated),
             "llama.decode")
         self._copy_fn = observe_compiled(
-            jax.jit(copy_page_in_stores, donate_argnums=(0, 1)),
+            jax.jit(copy_page_in_stores,
+                    donate_argnums=tuple(range(len(rows)))),
             "llama.copy_page")
         self.prefill_calls = 0
         self.decode_calls = 0
@@ -1115,18 +1494,25 @@ class LlamaDecodeEngine:
         toks[0, :T] = tokens
         # the read below would wait for the results anyway: waiting here
         # puts the device's time in its own span
-        k, v, logits = jax.block_until_ready(self._prefill_fn(
-            self.params, self.k_pages, self.v_pages, toks,
+        *stores, logits, shares = jax.block_until_ready(self._prefill_fn(
+            self.params, *self.stores, toks,
             np.asarray(pages, np.int32), np.asarray(T - 1, np.int32)))
         _sp_prefill_program.end(_t, n_pages)
         _t = _fr.now()
         # the stores were donated: only a call that returned hands back
         # live ones, and only those replace the engine's
-        self.k_pages, self.v_pages = k, v
+        self.stores = tuple(stores)
         _sp_prefill_kv.end(_t, n_pages)
         _t = _fr.now()
-        last = np.asarray(logits, np.float32)
+        # a routed model's shares come with the logits: one read
+        last, shares = jax.device_get((logits, shares))
         _sp_prefill_logits.end(_t, n_pages)
+        if shares:
+            held = float(shares.get("held_share", 1.0))
+            zero = float(shares.get("zero_share", 0.0))
+            for part, share in (("held", held), ("zero", zero),
+                                ("elsewhere", 1.0 - held - zero)):
+                _g_moe_assignment_share.set(share, tags={"part": part})
         return last
 
     def decode(self, pos, token, pages):
@@ -1146,11 +1532,11 @@ class LlamaDecodeEngine:
              np.asarray(pages, np.int32))))
         _sp_decode_upload.end(_t, n_pages)
         _t = _fr.now()
-        k, v, logits = jax.block_until_ready(self._decode_fn(
-            self.params, self.k_pages, self.v_pages, *inputs))
+        *stores, logits = jax.block_until_ready(self._decode_fn(
+            self.params, *self.stores, *inputs))
         _sp_decode_program.end(_t, n_pages)
         _t = _fr.now()
-        self.k_pages, self.v_pages = k, v  # as in prefill
+        self.stores = tuple(stores)  # as in prefill
         out = np.asarray(logits, np.float32)
         _sp_decode_readback.end(_t, n_pages)
         _sp_decode.end(_t_call, n_pages)
@@ -1158,8 +1544,8 @@ class LlamaDecodeEngine:
 
     def copy_page(self, src: int, dst: int) -> None:
         np = self._np
-        self.k_pages, self.v_pages = self._copy_fn(
-            self.k_pages, self.v_pages, np.asarray(src, np.int32),
+        self.stores = self._copy_fn(
+            *self.stores, np.asarray(src, np.int32),
             np.asarray(dst, np.int32))
 
 

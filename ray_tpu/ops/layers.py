@@ -19,10 +19,14 @@ def rms_norm(x, weight, eps: float = 1e-5):
     return ((x32 * rms) * weight.astype(jnp.float32)).astype(dtype)
 
 
-def rotary_embedding(q, k, positions, theta: float = 500000.0):
+def rotary_embedding(q, k, positions, theta: float = 500000.0,
+                     interleaved: bool = False):
     """Apply RoPE to q,k of shape [B, T, H, D]; positions [B, T] or [T].
 
-    theta=500000 is the Llama-3 base frequency.
+    theta=500000 is the Llama-3 base frequency. Frequency ``i`` turns the
+    pair ``(i, i + D/2)`` (the half-split "rotate_half" convention), or,
+    ``interleaved``, the pair ``(2i, 2i + 1)``; the result keeps the
+    layout it was given.
     """
     dtype = q.dtype
     D = q.shape[-1]
@@ -35,6 +39,10 @@ def rotary_embedding(q, k, positions, theta: float = 500000.0):
 
     def rot(x):
         x32 = x.astype(jnp.float32)
+        if interleaved:
+            x1, x2 = x32[..., 0::2], x32[..., 1::2]
+            return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                             axis=-1).reshape(x.shape).astype(dtype)
         x1, x2 = jnp.split(x32, 2, axis=-1)
         return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                                axis=-1).astype(dtype)

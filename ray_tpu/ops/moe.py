@@ -15,8 +15,8 @@
   the first places alone (:func:`_held_rows`: there the sum is a
   scatter-add of few rows): nothing is made for an assignment that falls
   elsewhere. The router's variants (sigmoid scores, a choice-only bias, a
-  weight scale, two-matrix ``relu2`` experts, a shared expert) are
-  arguments, each by itself.
+  weight scale, two-matrix ``relu2`` experts, a shared expert, identity
+  experts) are arguments, each by itself.
 - :func:`moe_ffn` / :func:`top_k_routing` are the older GShard/Switch
   *dense dispatch*: one-hot ``[G, S, E, C]`` dispatch/combine tensors with a
   static capacity that DROPS tokens and always renormalises the gates. It
@@ -311,7 +311,7 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
                stat_axes: Sequence[str] = (),
                scoring: str = "softmax", choice_bias=None,
                scale: float = 1.0, held: Optional[Tuple[int, int]] = None,
-               shared=None
+               shared=None, zero_experts: int = 0
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Dropless top-k routed experts. ``h`` [..., d] in the compute type.
 
@@ -339,6 +339,12 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
       and no part in the sum (:func:`_held_rows`); ``y`` is the partial
       sum of the held experts (plus the shared one). With ``held=None``
       every expert is here.
+    - ``zero_experts = n``: the LAST ``n`` of the router's ``E`` outputs
+      are identity experts, which have no weights and run where the token
+      is: an assignment to one adds ``w_j h`` and gets no row, no place
+      among the experts' groups and no product (it sorts behind the held
+      experts', as one held elsewhere does). ``w_gate`` / ``w_up`` /
+      ``w_down`` hold the ``E - n`` real experts, or ``held``'s range.
 
     Returns ``(y [..., d] float32, stats)``; ``stats`` are float32 scalars:
 
@@ -351,7 +357,9 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
     - ``dropped``: assignments to experts here that no group holds (0 by
       construction: every assignment has a place);
     - ``held_share`` (with ``held`` only): the share of all assignments
-      that fell on held experts.
+      that fell on held experts;
+    - ``zero_share`` (with ``zero_experts`` only): the share that fell on
+      identity experts. What neither share counts fell elsewhere.
 
     ``stat_axes``: inside a ``shard_map`` whose axes split the tokens, the
     axis names to average ``fraction_e`` over, so that ``lb_loss``, averaged
@@ -381,6 +389,8 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
             top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
         if scale != 1.0:
             top_w = top_w * scale
+    if zero_experts and held is None:  # the real experts, all here
+        held = (0, E - zero_experts)
     first, count = (0, E) if held is None else held
     with jax.named_scope("moe.dispatch"):
         ids = jnp.arange(A, dtype=jnp.int32)
@@ -403,9 +413,15 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
             ys = _expert_ffn(xs, w_gate, w_up, w_down, counts)
         with jax.named_scope("moe.combine"):
             y = _combine(ys, top_w, order, inverse)
-    else:  # a block of places is four even shares of the assignments
-        y = _held_rows(hf, top_w, order, starts, end,
-                       (w_gate, w_up, w_down), max(1, E // (4 * count)))
+    else:  # a block of places is four even shares of the assignments, and
+        # at least 128 places (a decode call's few are one block)
+        y = _held_rows(hf, top_w, order, starts, end, (w_gate, w_up, w_down),
+                       max(1, min(E // (4 * count), A // 128)))
+    if zero_experts:
+        with jax.named_scope("moe.zero"):
+            to_zero = top_e >= E - zero_experts
+            y = y + (jnp.sum(jnp.where(to_zero, top_w, 0.0), axis=-1,
+                             keepdims=True) * hf.astype(jnp.float32))
     if shared is not None:
         with jax.named_scope("moe.shared"):
             a = jnp.square(jax.nn.relu(hf @ shared[0].astype(cd)))
@@ -428,4 +444,6 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
                             - jnp.sum(counts)).astype(jnp.float32)
         if held is not None:
             stats["held_share"] = jnp.sum(fraction) / K
+        if zero_experts:
+            stats["zero_share"] = jnp.mean(to_zero.astype(jnp.float32))
     return y.reshape(*lead, d), stats

@@ -326,8 +326,7 @@ class TestLlamaEngine:
     @staticmethod
     def _store(engine):
         """Both page stores as numpy, ``[2, L, n_pages, page_size, ...]``."""
-        return np.stack([np.asarray(engine.k_pages),
-                         np.asarray(engine.v_pages)])
+        return np.stack([np.asarray(pages) for pages in engine.stores])
 
     @staticmethod
     def _forward_logits(engine, toks):
@@ -407,7 +406,7 @@ class TestLlamaEngine:
                                cfg.head_dim), jnp.float32)
             padded = np.zeros((1, 2 * ps), np.int32)
             padded[0, :6] = toks[:6]
-            k, v, pre = jax.jit(partial(llama.prefill_with_cache, cfg))(
+            k, v, pre, _ = jax.jit(partial(llama.prefill_with_cache, cfg))(
                 params, store, store, padded, pages, np.asarray(5, np.int32))
             _, _, dec = jax.jit(partial(llama.decode_step_with_cache, cfg))(
                 params, k, v, toks[6:7], np.asarray(6, np.int32), pages)
@@ -535,8 +534,7 @@ class TestLlamaEngine:
                 engine.decode(5, toks[5], [3, 1])
         finally:
             engine._prefill_fn, engine._decode_fn = jitted
-        assert not engine.k_pages.is_deleted()
-        assert not engine.v_pages.is_deleted()
+        assert not any(pages.is_deleted() for pages in engine.stores)
         np.testing.assert_array_equal(
             self._teacher_forced(engine, toks, 5, [6, 7]), want)
 
@@ -623,7 +621,7 @@ class TestLlamaEngine:
             0, engine.cfg.vocab_size, size=(1, 8)).astype(np.int32)
         # the decode reads what a prefill wrote, not pages of zeros
         engine.prefill([int(t) for t in toks[0, :7]], [9, 4])
-        stores = (jnp.array(engine.k_pages), jnp.array(engine.v_pages))
+        stores = tuple(jnp.array(pages) for pages in engine.stores)
         return {
             "prefill": (jax.jit(partial(prefill_with_cache, engine.cfg)),
                         stores + (toks, np.asarray([6, 2], np.int32),
@@ -641,8 +639,12 @@ class TestLlamaEngine:
         product, inside the call) and the engine's tree (converted once)
         give the same logits and the same page stores, bit for bit."""
         fn, args = self._programs(engine)[kind]
-        want = fn(self._float32_tree(engine.cfg), *args)
-        got = fn(engine.params, *args)
+        import jax
+
+        # (k_pages, v_pages), the logits (and a dense prefill's no shares)
+        want = jax.tree.leaves(fn(self._float32_tree(engine.cfg), *args))
+        got = jax.tree.leaves(fn(engine.params, *args))
+        assert len(got) == len(want) == 3
         for g, w in zip(got, want):
             assert g.dtype == w.dtype == np.float32
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
