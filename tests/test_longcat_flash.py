@@ -15,6 +15,7 @@ import pytest
 
 from benchmarks.lib import spec
 from benchmarks.reference import longcat_flash_decoder as ref
+from jitted import forward, init_params, reference
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.layers import rotary_embedding
@@ -50,7 +51,7 @@ def program_cfg(dtype=jnp.float32, **file_keys):
 def params():
     """Seeded weights with norms and the choice bias off their defaults, so
     that a norm or a bias left out shows."""
-    p = llama.init_params(program_cfg(), jax.random.PRNGKey(7))
+    p = init_params(program_cfg(), jax.random.PRNGKey(7))
     k = iter(jax.random.split(jax.random.PRNGKey(8), 8))
     layers = dict(p["layers"]["scmoe"])
     for name in ("attn_norm", "mlp_norm", "q_norm", "kv_norm"):
@@ -70,8 +71,17 @@ def close(got, want, rtol=2e-5):
                                rtol=0, atol=rtol * scale)
 
 
-def expanded(cfg):
-    return lambda j, *a: llama.attend_latent_expanded(cfg, *a)
+def double_layer(cfg, x, p, i):
+    """The program's layer ``i`` with expanded attention: one program (the
+    reference's side is ``jitted.reference``)."""
+    return jax.jit(lambda x, p: llama.shortcut_layer(
+        cfg, x, p, i, llama.positions_of(1, x.shape[1]),
+        lambda j, *a: llama.attend_latent_expanded(cfg, *a)))(x, p)
+
+
+def logits_one(params, toks):
+    return reference(lambda p, t: ref.logits_one(FILE, p, t), params,
+                     jnp.asarray(toks))
 
 
 # --- (a) the double layer ---------------------------------------------------- #
@@ -84,11 +94,9 @@ def one_layer(p, i):
 @pytest.mark.parametrize("i", [0, 1])
 def test_double_layer_is_the_references(params, i):
     cfg, x = program_cfg(), hidden(1)
-    got, stats, latents = llama.shortcut_layer(
-        cfg, x, params["layers"]["scmoe"], i, llama.positions_of(1, 24),
-        expanded(cfg))
-    with jax.default_matmul_precision("highest"):
-        want = ref.layer(FILE, x[0], params["layers"]["scmoe"], i)
+    got, stats, latents = double_layer(cfg, x, params["layers"]["scmoe"], i)
+    want = reference(lambda x, p: ref.layer(FILE, x, p, i), x[0],
+                     params["layers"]["scmoe"])
     close(got[0], want)
     assert latents.shape == (2, 1, 24, 16 + 8)
     assert 0.0 < float(stats["zero_share"]) < 1.0
@@ -112,10 +120,9 @@ def test_the_routed_sum_waits_for_the_end_of_the_layer(params):
         return a1 + ref.ffn(ref._rms_norm(a1, p["mlp_norm"][0, 1], eps),
                             p["ffn_gate"], p["ffn_up"], p["ffn_down"], (0, 1))
 
-    got = llama.shortcut_layer(cfg, x, p, 0, llama.positions_of(1, 24),
-                               expanded(cfg))[0][0]
-    with jax.default_matmul_precision("highest"):
-        want, wrong = ref.layer(FILE, x[0], p, 0), early(x[0])
+    got = double_layer(cfg, x, p, 0)[0][0]
+    want, wrong = reference(lambda x: (ref.layer(FILE, x, p, 0), early(x)),
+                            x[0])
     close(got, want)
     off = float(jnp.max(jnp.abs(wrong - want)) / jnp.max(jnp.abs(want)))
     assert off > 0.01, off
@@ -124,10 +131,9 @@ def test_the_routed_sum_waits_for_the_end_of_the_layer(params):
 def test_forward_is_the_references_logits(params):
     cfg = program_cfg()
     toks = np.random.RandomState(3).randint(0, 128, size=(2, 20))
-    got = llama.forward(cfg, params, toks)
+    got = forward(cfg, params, toks)
     for row in range(2):
-        with jax.default_matmul_precision("highest"):
-            close(got[row], ref.logits_one(FILE, params, toks[row]), 5e-5)
+        close(got[row], logits_one(params, toks[row]), 5e-5)
 
 
 def test_num_params_counts_the_tree_and_the_file():
@@ -170,14 +176,20 @@ def sublayer(params, i, j):
         "wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo")}
 
 
+def latent_half(cfg, p, h, positions, attend=llama.attend_latent_expanded):
+    """``llama._latent_half`` as one program; ``attend`` takes ``cfg``
+    first."""
+    return jax.jit(lambda p, h, positions: llama._latent_half(
+        cfg, p, h, positions, lambda *a: attend(cfg, *a)))(p, h, positions)
+
+
 @pytest.mark.parametrize("at", [(0, 0), (1, 1)])
 def test_latent_half_is_the_references_mla(params, at):
     cfg, h = program_cfg(), hidden(4)
-    got, latent = llama._latent_half(
-        cfg, sublayer(params, *at), h, llama.positions_of(1, 24),
-        lambda *a: llama.attend_latent_expanded(cfg, *a))
-    with jax.default_matmul_precision("highest"):
-        close(got[0], ref.mla(FILE, h[0], params["layers"]["scmoe"], at))
+    got, latent = latent_half(cfg, sublayer(params, *at), h,
+                              llama.positions_of(1, 24))
+    close(got[0], reference(lambda h, p: ref.mla(FILE, h, p, at), h[0],
+                            params["layers"]["scmoe"]))
     assert latent.shape == (1, 24, 24)
 
 
@@ -187,9 +199,8 @@ def test_queries_in_blocks_change_nothing(params, block, monkeypatch):
     outs = []
     for size in (block, 512):
         monkeypatch.setattr(llama, "LATENT_QUERY_BLOCK", size)
-        outs.append(llama._latent_half(
-            cfg, sublayer(params, 0, 0), h, llama.positions_of(1, 24),
-            lambda *a: llama.attend_latent_expanded(cfg, *a))[0])
+        outs.append(latent_half(cfg, sublayer(params, 0, 0), h,
+                                llama.positions_of(1, 24))[0])
     close(outs[0], outs[1], 1e-6)
 
 
@@ -199,13 +210,11 @@ def test_absorbed_attention_over_latent_rows_is_the_expanded(params):
     the full pass's last position, pad rows masked."""
     cfg, h = program_cfg(), hidden(6, seq=13)
     p = sublayer(params, 1, 0)
-    full, latent = llama._latent_half(
-        cfg, p, h, llama.positions_of(1, 13),
-        lambda *a: llama.attend_latent_expanded(cfg, *a))
+    full, latent = latent_half(cfg, p, h, llama.positions_of(1, 13))
     cache = jnp.concatenate([latent[0, :12], jnp.full((4, 24), 1e3)])
-    got, row = llama._latent_half(
+    got, row = latent_half(
         cfg, p, h[:, 12:], jnp.full((1, 1), 12, jnp.int32),
-        lambda *a: llama._attend_latent_cached(cfg, cache, 12, *a))
+        lambda cfg, *a: llama._attend_latent_cached(cfg, cache, 12, *a))
     close(got[0, 0], full[0, 12])
     close(row[0, 0], latent[0, 12], 1e-6)
 
@@ -213,12 +222,15 @@ def test_absorbed_attention_over_latent_rows_is_the_expanded(params):
 def test_rotation_takes_the_pairing_it_is_asked_for():
     x = jax.random.normal(jax.random.PRNGKey(9), (1, 7, 3, 8))
     pos = llama.positions_of(1, 7)
-    got, _ = rotary_embedding(x, x, pos, 100.0, interleaved=True)
-    close(got[0], ref._rope(x[0], 100.0), 1e-6)
-    half, _ = rotary_embedding(x, x, pos, 100.0)
+    rotate = jax.jit(lambda x, **kw: rotary_embedding(x, x, pos, 100.0,
+                                                      **kw)[0],
+                     static_argnames="interleaved")
+    close(rotate(x, interleaved=True)[0],
+          reference(lambda x: ref._rope(x, 100.0), x[0]), 1e-6)
     # the half-split pairing is the interleaved one on permuted channels
     perm = jnp.array([0, 4, 1, 5, 2, 6, 3, 7])
-    close(half[0][..., perm], ref._rope(x[0][..., perm], 100.0), 1e-6)
+    close(rotate(x)[0][..., perm],
+          reference(lambda x: ref._rope(x, 100.0), x[0][..., perm]), 1e-6)
 
 
 # --- (c) the router: identity experts, choice bias, shares ------------------- #
@@ -226,18 +238,19 @@ def test_rotation_takes_the_pairing_it_is_asked_for():
 
 def routed(cfg, h, p, **kw):
     args = dict(top_k=cfg.experts_per_token, scale=cfg.routed_scale,
-                choice_bias=p["router_bias"], zero_experts=cfg.zero_experts,
+                zero_experts=cfg.zero_experts,
                 held=(cfg.first_expert, cfg.num_experts))
     args.update(kw)
-    return routed_mlp(h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-                      **args)
+    return jax.jit(lambda h, p: routed_mlp(
+        h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+        choice_bias=p["router_bias"], **args))(h, p)
 
 
 def test_routed_branch_is_the_references(params):
     cfg, h = program_cfg(), hidden(10, seq=64)
     got, stats = routed(cfg, h, one_layer(params, 0))
-    with jax.default_matmul_precision("highest"):
-        close(got[0], ref.moe(FILE, h[0], params["layers"]["scmoe"], 0))
+    close(got[0], reference(lambda h, p: ref.moe(FILE, h, p, 0), h[0],
+                            params["layers"]["scmoe"]))
     shares = float(stats["held_share"]) + float(stats["zero_share"])
     assert 0.0 < shares < 1.0 and float(stats["dropped"]) == 0.0
 
@@ -258,6 +271,7 @@ def test_all_identity_router_returns_scaled_input(params):
     close(routed(cfg, h, zeroed)[0], ref_identity_part(h, zeroed))
 
 
+@jax.jit
 def ref_identity_part(h, p):
     probs = jax.nn.softmax(jnp.dot(h, p["router"], precision="highest"))
     _, chosen = jax.lax.top_k(probs + p["router_bias"], 3)
@@ -270,16 +284,15 @@ def test_softmax_choice_bias_picks_biased_and_weighs_unbiased(params):
     p = dict(one_layer(params, 0))
     p["router_bias"] = jnp.zeros(12).at[1].set(5.0)  # expert 1: always chosen
     got, stats = routed(cfg, h, p)
-    with jax.default_matmul_precision("highest"):
-        close(got[0], ref.moe(FILE, h[0], jax.tree.map(
-            lambda a: a[None], p), 0))
+    close(got[0], reference(lambda h, p: ref.moe(FILE, h, p, 0), h[0],
+                            jax.tree.map(lambda a: a[None], p)))
     unbiased, _ = routed(cfg, h, one_layer(params, 0))
     assert float(jnp.max(jnp.abs(got - unbiased))) > 1e-3
     # weighed by the UNBIASED score: expert 1 alone, chosen by every token
     only = dict(p, w_down=p["w_down"].at[jnp.array([0, 2, 3])].set(0.0))
     probs = jax.nn.softmax(jnp.dot(h[0], p["router"], precision="highest"))
-    with jax.default_matmul_precision("highest"):
-        one = ref.ffn(h[0], p["w_gate"], p["w_up"], p["w_down"], (1,))
+    one = reference(lambda h: ref.ffn(h, p["w_gate"], p["w_up"], p["w_down"],
+                                      (1,)), h[0])
     close(routed(cfg, h, only)[0][0] - ref_identity_part(h[0], p),
           6.0 * probs[:, 1:2] * one, 1e-4)
 
@@ -335,9 +348,7 @@ def test_prefill_then_decode_through_pages_is_the_references(engine, params, n):
     decode attends over the latent rows."""
     toks = np.random.RandomState(n).randint(0, 128, size=n + 4)
     got = served(engine, toks, n, [9, 2, 6])
-    with jax.default_matmul_precision("highest"):
-        want = ref.logits_one(FILE, params, toks)
-    close(got, want[n - 1:], 5e-5)
+    close(got, logits_one(params, toks)[n - 1:], 5e-5)
 
 
 def test_the_store_is_one_array_of_latent_rows(engine):
@@ -393,8 +404,7 @@ def test_bfloat16_engine_stays_near_the_reference(params):
                                      n_pages=8, page_size=8)
     toks = np.random.RandomState(21).randint(0, 128, size=17)
     got = served(engine, toks, 14, [5, 1, 3])
-    with jax.default_matmul_precision("highest"):
-        want = ref.logits_one(FILE, engine.params, toks)[13:]
+    want = logits_one(engine.params, toks)[13:]
     # at 64 wide a rounded router input that moves one of three choices
     # moves a logit by several percent; a wrong page or an un-rotated key
     # moves it by its own size
@@ -416,8 +426,8 @@ def test_scheduler_drives_the_latent_engine(engine):
     assert warm["cached_prefix"] is True
     assert warm["tokens"] == cold["tokens"] and len(cold["tokens"]) == 6
     toks = req["prompt"] + cold["tokens"]
-    logits = np.asarray(llama.forward(engine.cfg, engine.params,
-                                      np.asarray([toks])))[0]
+    logits = np.asarray(forward(engine.cfg, engine.params,
+                                np.asarray([toks])))[0]
     assert [int(t) for t in logits[10:16].argmax(-1)] == cold["tokens"]
 
 

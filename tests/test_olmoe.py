@@ -22,6 +22,8 @@ from benchmarks.reference import olmoe_decoder as ref
 jax = pytest.importorskip("jax")
 jnp = jax.numpy
 
+from jitted import (init_params, loss_fn, reference,  # noqa: E402
+                    value_and_grad)
 from ray_tpu.models import llama  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig  # noqa: E402
 from ray_tpu.ops.moe import routed_mlp  # noqa: E402
@@ -62,7 +64,7 @@ def tokens():
 
 @pytest.fixture(scope="module")
 def params():
-    p = llama.init_params(program_cfg(), jax.random.PRNGKey(11))
+    p = init_params(program_cfg(), jax.random.PRNGKey(11))
     # norm scales away from one, so that a misplaced norm shows
     rng = np.random.RandomState(5)
     for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm"):
@@ -104,7 +106,7 @@ def test_routed_mlp_matches_reference_outputs_and_gradients(params,
         return jnp.sum(y * target) + stats["lb_loss"] + stats["z_loss"], \
             (y, stats)
 
-    def reference(h, *w):
+    def plain(h, *w):
         with jax.default_matmul_precision("highest"):
             y, lb, z = ref.experts(file, h.reshape(-1, h.shape[-1]),
                                    dict(zip(names, w)))
@@ -112,10 +114,10 @@ def test_routed_mlp_matches_reference_outputs_and_gradients(params,
         return jnp.sum(y * target) + lb + z, (y, {"lb_loss": lb, "z_loss": z})
 
     args = (h,) + tuple(p[n] for n in names)
-    (_, (y, stats)), grads = jax.value_and_grad(
-        program, argnums=range(5), has_aux=True)(*args)
-    (_, (y_ref, stats_ref)), grads_ref = jax.value_and_grad(
-        reference, argnums=range(5), has_aux=True)(*args)
+    (_, (y, stats)), grads = value_and_grad(
+        program, *args, argnums=range(5), has_aux=True)
+    (_, (y_ref, stats_ref)), grads_ref = value_and_grad(
+        plain, *args, argnums=range(5), has_aux=True)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-6)
     for k in ("lb_loss", "z_loss"):
         np.testing.assert_allclose(stats[k], stats_ref[k], rtol=1e-5)
@@ -140,10 +142,10 @@ def test_total_imbalance_is_still_dropless(params):
     for rank, e in enumerate(favourites):
         router[:, e] += 1.0 - 0.1 * rank
     p["router"] = jnp.asarray(router)
-    y, stats = routed_mlp(h, p["router"], p["w_gate"], p["w_up"], p["w_down"],
-                          top_k=K)
-    with jax.default_matmul_precision("highest"):
-        y_ref, lb, _ = ref.experts(FILE, h.reshape(-1, h.shape[-1]), p)
+    y, stats = jax.jit(lambda h, p: routed_mlp(
+        h, p["router"], p["w_gate"], p["w_up"], p["w_down"], top_k=K))(h, p)
+    y_ref, lb, _ = reference(lambda h, p: ref.experts(FILE, h, p),
+                             h.reshape(-1, h.shape[-1]), p)
     np.testing.assert_allclose(y, y_ref.reshape(h.shape), rtol=1e-5,
                                atol=1e-5)
     assert float(stats["dropped"]) == 0.0
@@ -164,19 +166,23 @@ def test_qk_norm_attention_matches_reference(params):
     p["w_down"] = jax.random.normal(ks[2], (f, d)) / np.sqrt(f)
     x = mlp_inputs(seed=6)
     positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None].repeat(2, 0)
-    got, stats = llama._layer(cfg, None, x, p, positions)
+    layer = jax.jit(lambda x, p: llama._layer(cfg, None, x, p, positions))
+    got, stats = layer(x, p)
     assert stats == {}
-    with jax.default_matmul_precision("highest"):
-        for b in range(2):
-            x1 = x[b] + ref.attention(FILE, x[b], p)
-            h = ref._rms_norm(x1, p["mlp_norm"], FILE["rms_norm_eps"])
-            want = x1 + (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) \
-                @ p["w_down"]
-            np.testing.assert_allclose(got[b], want, rtol=2e-5, atol=2e-5)
+
+    def plain(x):  # one sequence: attention, then a dense SwiGLU half
+        x1 = x + ref.attention(FILE, x, p)
+        h = ref._rms_norm(x1, p["mlp_norm"], FILE["rms_norm_eps"])
+        return x1 + (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) \
+            @ p["w_down"]
+
+    for b in range(2):
+        np.testing.assert_allclose(got[b], reference(plain, x[b]), rtol=2e-5,
+                                   atol=2e-5)
     # the norm is over the WHOLE projected vector: one per head is another
     # model, and the reference tells them apart
     per_head = dict(p, q_norm=p["q_norm"].at[:16].mul(3.0))
-    other, _ = llama._layer(cfg, None, x, per_head, positions)
+    other, _ = layer(x, per_head)
     assert float(jnp.abs(other - got).max()) > 1e-3
 
 
@@ -204,9 +210,9 @@ def test_loss_parts_match_reference_float32(params, tokens):
         assert got[k] == pytest.approx(want[k], rel=2e-5), k
     assert float(got["report"]["dropped"]) == 0.0
     assert got["total"] == pytest.approx(
-        float(llama.loss_fn(program_cfg(), params, tokens)), rel=1e-6)
-    assert want["total"] == pytest.approx(
-        float(ref.loss(FILE, params, tokens)), rel=1e-6)
+        float(loss_fn(program_cfg(), params, tokens)), rel=1e-6)
+    assert want["total"] == pytest.approx(float(reference(
+        lambda p, t: ref.loss(FILE, p, t), params, tokens)), rel=1e-6)
     # the router losses weigh in: left out, the total is another number
     assert abs(want["total"] - want["cross_entropy"]) > 1e-2
 

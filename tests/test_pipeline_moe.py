@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jitted import loss_fn
 from ray_tpu.parallel.mesh import make_mesh
 from ray_tpu.parallel.pipeline import (merge_microbatches, pipelined_apply,
                                        split_microbatches)
@@ -47,7 +48,7 @@ class TestPipelineSchedule:
             region, mesh=mesh,
             in_specs=((P("pipe"), P("pipe")), P(None)),
             out_specs=P(None), check=False)
-        got = fn((ws, bs), x)
+        got = jax.jit(fn)((ws, bs), x)
 
         want = x
         for i in range(P_st):
@@ -84,8 +85,8 @@ class TestPipelineSchedule:
                 h = jnp.tanh(h @ w[i])
             return jnp.sum(h ** 2)
 
-        gp = jax.grad(loss_pipe)(ws)
-        gs = jax.grad(loss_serial)(ws)
+        gp = jax.jit(jax.grad(loss_pipe))(ws)
+        gs = jax.jit(jax.grad(loss_serial))(ws)
         np.testing.assert_allclose(np.asarray(gp), np.asarray(gs),
                                    rtol=1e-4, atol=1e-5)
 
@@ -115,7 +116,7 @@ class TestLlamaPipeline:
         }
         tokens_np = np.asarray(jax.device_get(tokens))
         _, loss_pp = train_step(state, tokens)
-        loss_ref = llama.loss_fn(cfg, flat, tokens_np)
+        loss_ref = loss_fn(cfg, flat, tokens_np)
         # rtol: the pipelined program orders the fp32 reductions across
         # the pipe axis differently from the serial reference
         np.testing.assert_allclose(float(loss_pp), float(loss_ref),
@@ -168,7 +169,7 @@ class TestMoERouting:
         B, S, d, E, f = 2, 8, 16, 4, 32
         key = iter(jax.random.split(jax.random.PRNGKey(0), 8))
         x = jax.random.normal(next(key), (B, S, d))
-        y, aux = moe_ffn(
+        y, aux = jax.jit(moe_ffn, static_argnames="compute_dtype")(
             x, jax.random.normal(next(key), (d, E)) * 0.1,
             jax.random.normal(next(key), (E, d, f)) * 0.1,
             jax.random.normal(next(key), (E, d, f)) * 0.1,
