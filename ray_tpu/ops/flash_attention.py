@@ -184,6 +184,7 @@ def _fwd(q, k, v, *, causal, blk, interpret):
             jax.ShapeDtypeStruct((B, Hq, T, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -292,6 +293,7 @@ def _bwd(q, k, v, o, lse, do, *, causal, blk, interpret):
         out_specs=pl.BlockSpec((1, 1, blk, D), lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, T, D), q.dtype),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse, delta)
 
     # What the dK/dV call's block specs keep in VMEM, each buffer twice (the
@@ -332,6 +334,7 @@ def _bwd(q, k, v, o, lse, do, *, causal, blk, interpret):
             jax.ShapeDtypeStruct((B, Hq, T, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
 
     # GQA group-sum: q heads [g*rep, (g+1)*rep) all attend kv head g

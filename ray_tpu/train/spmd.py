@@ -74,24 +74,45 @@ from ray_tpu.util.xla_observatory import (
 
 logger = logging.getLogger(__name__)
 
-_sp_ingest = _fr.register_span("spmd.ingest_wait")
-_sp_compute = _fr.register_span("spmd.compute")
+# Every spmd.* span and every moe.* instant carries ``step``, the loop's step
+# number as the report spells it (i + 1): the key that joins a step's spans
+# and its router scalars, as ``corr`` joins a request's.
+_sp_ingest = _fr.register_span("spmd.ingest_wait", tag_keys=("step",))
+_sp_compute = _fr.register_span("spmd.compute", tag_keys=("step",))
 # the first step pays trace + XLA compile; recording it under its own
 # name keeps the badput ledger's compile column honest instead of
 # folding a multi-second outlier into spmd.compute
-_sp_compile = _fr.register_span("spmd.compile")
+_sp_compile = _fr.register_span("spmd.compile", tag_keys=("step",))
+# The host's phases of a step, each where the work happens: one record a
+# step, so floor_exempt (a median is over every step, not the slow ones).
+# dispatch: the call into the jitted step (fingerprint, argument check, the
+# executable's call; it blocks where the runtime's queue is full), every
+# step but the first, whose call lowers and compiles under spmd.compile.
+# ready_wait: the host asleep on the step's loss; near zero means the host
+# came late and sets the pace. fetch: loss and router scalars to the host;
+# report: gauges and ``session.report``, what a user's report costs the
+# loop's thread (both on reported steps only).
+_sp_dispatch = _fr.register_span("spmd.dispatch", tag_keys=("step",),
+                                 floor_exempt=True)
+_sp_ready = _fr.register_span("spmd.ready_wait", tag_keys=("step",),
+                              floor_exempt=True)
+_sp_fetch = _fr.register_span("spmd.fetch", tag_keys=("step",),
+                              floor_exempt=True)
+_sp_report = _fr.register_span("spmd.report", tag_keys=("step",),
+                               floor_exempt=True)
 # a routed model's router, one instant a report (``timeline --attribute``
 # prints them): the two router losses, the heaviest expert's load over the
 # mean, and the assignments no expert computed (0: the routing is dropless)
 _ROUTER_GAUGES = {
-    "lb_loss": _fr.register_span("moe.lb_loss", tag_keys=("value",)),
-    "z_loss": _fr.register_span("moe.z_loss", tag_keys=("value",)),
+    "lb_loss": _fr.register_span("moe.lb_loss", tag_keys=("value", "step")),
+    "z_loss": _fr.register_span("moe.z_loss", tag_keys=("value", "step")),
     "max_load_ratio": _fr.register_span("moe.max_load_ratio",
-                                        tag_keys=("value",)),
-    "dropped": _fr.register_span("moe.dropped", tag_keys=("value",)),
+                                        tag_keys=("value", "step")),
+    "dropped": _fr.register_span("moe.dropped", tag_keys=("value", "step")),
     # where the layers hold a range of the router's experts: the share of
     # all assignments that fell on the held ones
-    "held_share": _fr.register_span("moe.held_share", tag_keys=("value",)),
+    "held_share": _fr.register_span("moe.held_share",
+                                    tag_keys=("value", "step")),
 }
 
 # Throughput/step-time gauges feeding the head's metrics-history rings
@@ -1025,7 +1046,9 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
 
     The loop runs one step ahead of its reports: step i + 1 is issued
     before step i's loss is waited for, and step i is reported when that
-    loss is ready.
+    loss is ready. With the flight recorder on, the loop thread's phases of
+    a step are spans joined by ``step`` (``spmd.ingest_wait``, ``.dispatch``,
+    ``.ready_wait``, ``.fetch``, ``.report``; ``timeline --attribute``).
     """
     import jax
     import optax
@@ -1100,24 +1123,30 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
     ready_at = 0.0  # when the step before was seen ready (recorder's clock)
 
     def settle(i, issued_at, loss, router, n_tokens):
-        """Step ``i``'s span and report, once its loss is ready."""
+        """Step ``i``'s spans and report, once its loss is ready."""
         nonlocal tokens_done, win_t, win_tokens, win_step, ready_at
         if issued_at:
             # recorder on: close the span at data-ready, not dispatch. A
             # step issued behind the one before starts when that one ends
+            _t = _fr.now()
             jax.block_until_ready(loss)
+            if i:
+                _sp_ready.end(_t, i + 1)
             (_sp_compile if i == 0 else _sp_compute).end(
-                max(issued_at, ready_at))
+                max(issued_at, ready_at), i + 1)
             ready_at = _fr.now()
         tokens_done += n_tokens
         if (i + 1) % report_every and i != steps - 1:
             return
         # the router's scalars come with the loss: one fetch a report
+        _t = _fr.now()
         lf, moe = jax.device_get((loss, router[0] if router else {}))
         lf = float(lf)
         moe = {k: float(v) for k, v in moe.items()}
+        _sp_fetch.end(_t, i + 1)
+        _t = _fr.now()
         for k, v in moe.items():
-            _ROUTER_GAUGES[k].instant(v)
+            _ROUTER_GAUGES[k].instant(v, i + 1)
         now = time.perf_counter()
         dt = max(now - t0, 1e-9)
         win_dt = max(now - win_t, 1e-9)
@@ -1140,6 +1169,7 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
         if i == steps - 1:
             report.update(_run_evidence(state))
         session.report(report)
+        _sp_report.end(_t, i + 1)
 
     # One step ahead: step i + 1 is issued BEFORE the loop waits for step
     # i's loss, so the device goes from one step into the next while the
@@ -1153,11 +1183,13 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
     for i in range(steps):
         _t = _fr.now()
         toks = next_tokens()
-        _sp_ingest.end(_t)
+        _sp_ingest.end(_t, i + 1)
         if toks is None:
             break
         _t = _fr.now()
         state, loss, *router = step_fn(state, toks)
+        if i:
+            _sp_dispatch.end(_t, i + 1)
         if before is not None:
             settle(*before)
         before = (i, _t, loss, router,

@@ -36,10 +36,10 @@ knob; spans shorter than ``flight_recorder_min_span_us`` (default
 500 us) stop at the duration compare so microsecond-rate dispatch pays
 only the clock reads — the on/off overhead is bench-gated in
 BENCH_TRACE.json (``bench_core.py --trace-bench``). Spans registered
-``floor_exempt`` (one per REQUEST or per engine call, not per dispatch:
-a request's queue waits, what is left of the decode engine's copies) are
-recorded however short, so their median is over every request and not
-over the ones that waited.
+``floor_exempt`` (one per REQUEST, per engine call or per train STEP, not
+per dispatch: a request's queue waits, what is left of the decode engine's
+copies, the host's phases of a step) are recorded however short, so their
+median is over every request or step and not over the ones that waited.
 """
 
 from __future__ import annotations
@@ -581,6 +581,65 @@ def _serving_attribution(by_name: Dict[str, List[dict]]
     return out
 
 
+# the host's phases of an SPMD train step, in the loop's order:
+# (report key, span, human label)
+_SPMD_PHASES = (
+    ("ingest_wait", "spmd.ingest_wait", "batch wait"),
+    ("dispatch", "spmd.dispatch", "dispatch"),
+    ("ready_wait", "spmd.ready_wait", "device wait"),
+    ("fetch", "spmd.fetch", "fetch"),
+    ("report", "spmd.report", "report"),
+)
+
+
+def _slowest_spmd_step(by_name: Dict[str, List[dict]],
+                       instants: List[dict]) -> Optional[Dict[str, Any]]:
+    """The SPMD loop's slowest step by wall, joined on ``step``: its
+    number, its phases, its ``moe.*`` values. The phases are one thread's
+    work and disjoint, so every millisecond of the loop belongs to one
+    step's phases, and a step's wall is their sum: about a step's time
+    while the host keeps ahead (``ready_wait`` is what the rest leaves of
+    it). Long ``ready_wait`` with the rest normal is the device's, a long
+    ``dispatch`` a retrace, a compile or a full queue, a long ``report``
+    the runtime's; the step AFTER a stalled one was ready before the host
+    came to wait for it, and shows a ``ready_wait`` near zero. A process
+    may run the loop again and repeat its step numbers: a step number that
+    falls back starts another run."""
+    marks = [(key, ev) for key, name, _label in _SPMD_PHASES
+             for ev in by_name.get(name, ())]
+    marks += [(None, ev) for ev in instants]
+    runs: Dict[Any, List[int]] = {}   # source -> [run, highest step in it]
+    found: Dict[tuple, dict] = {}
+    for key, ev in sorted(marks, key=lambda m: m[1]["ts"]):
+        args = ev.get("args") or {}
+        step, source = args.get("step"), args.get("source")
+        if step is None:
+            continue  # a trace from before the spans carried ``step``
+        run = runs.setdefault(source, [0, step])
+        if step < run[1] - 1 or step == 1 < run[1]:
+            run[:] = [run[0] + 1, step]
+        run[1] = max(run[1], step)
+        rec = found.setdefault((source, run[0], step),
+                               {"phases": {}, "router": {}})
+        if key is None:
+            rec["router"][ev["name"]] = float(args.get("value", 0.0))
+        else:
+            rec["phases"][key] = (rec["phases"].get(key, 0.0)
+                                  + ev.get("dur", 0.0) / 1e3)
+    if not found:
+        return None
+    (source, _run, step), rec = max(
+        found.items(), key=lambda kv: sum(kv[1]["phases"].values()))
+    out = {"step": int(step),
+           "wall_ms": round(sum(rec["phases"].values()), 3),
+           "phases_ms": {k: round(v, 3) for k, v in rec["phases"].items()}}
+    if source is not None:
+        out["source"] = source
+    if rec["router"]:
+        out["router"] = rec["router"]
+    return out
+
+
 def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Fold a merged trace into a per-step budget: where did the step
     time go. Pipeline busy/bubble mirrors ``pipeline_stats()`` exactly
@@ -630,6 +689,9 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         rec = xla_compile.setdefault(prog, {"compiles": 0, "compile_s": 0.0})
         rec["compiles"] += 1
         rec["compile_s"] += ev.get("dur", 0.0) / 1e6
+    # all but the wait for a batch, which has its line: ingest_wait_s
+    spmd_phases = {key: total_s((name,))
+                   for key, name, _label in _SPMD_PHASES[1:]}
     denom = wall_s or (spmd_compute_s + ingest_s) or None
     report: Dict[str, Any] = {
         "step_wall_s": round(wall_s, 6),
@@ -643,6 +705,7 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         "ring_stall_s": round(ring_stall_s, 6),
         "ingest_wait_s": round(ingest_s, 6),
         "spmd_compute_s": round(spmd_compute_s, 6),
+        **{f"spmd_{key}_s": round(v, 6) for key, v in spmd_phases.items()},
         "dag_exec_s": round(exec_s, 6),
         "serve_batch_s": round(serve_s, 6),
         "compile_s": round(compile_s, 6),
@@ -662,11 +725,15 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     if serving:
         report["serving"] = serving
     # a routed model's router (train/spmd.py: one instant a report)
+    instants = [ev for ev in events if ev.get("ph") == "i"
+                and str(ev.get("name", "")).startswith("moe.")]
     router: Dict[str, List[float]] = {}
-    for ev in events:
-        if ev.get("ph") == "i" and str(ev.get("name", "")).startswith("moe."):
-            router.setdefault(ev["name"], []).append(
-                float((ev.get("args") or {}).get("value", 0.0)))
+    for ev in instants:
+        router.setdefault(ev["name"], []).append(
+            float((ev.get("args") or {}).get("value", 0.0)))
+    slowest = _slowest_spmd_step(by_name, instants)
+    if slowest:
+        report["slowest_step"] = slowest
     if router:
         report["router"] = {
             name: {"n": len(v), "last": v[-1], "max": max(v)}
@@ -695,6 +762,23 @@ def format_attribution(report: Dict[str, Any]) -> str:
     lines.append(f"ring stall         : {report['ring_stall_s']:.4f}s")
     if report.get("ingest_wait_s"):
         lines.append(f"ingest wait        : {report['ingest_wait_s']:.4f}s")
+    if report.get("spmd_compute_s"):
+        lines.append(f"spmd compute       : {report['spmd_compute_s']:.4f}s")
+    for key, _name, label in _SPMD_PHASES[1:]:
+        if report.get(f"spmd_{key}_s"):
+            lines.append(f"  {label:<17}: {report[f'spmd_{key}_s']:.4f}s")
+    slow = report.get("slowest_step")
+    if slow:
+        lines.append(
+            f"slowest step       : {slow['step']}, {slow['wall_ms']:.3f} ms "
+            f"wall" + (f" on {slow['source']}" if slow.get("source") else ""))
+        lines.append("  " + ", ".join(
+            f"{label} {slow['phases_ms'][key]:.3f} ms"
+            for key, _name, label in _SPMD_PHASES
+            if key in slow["phases_ms"]))
+        if slow.get("router"):
+            lines.append("  " + ", ".join(
+                f"{name} {v:.6g}" for name, v in slow["router"].items()))
     if report.get("compile_s"):
         lines.append(f"compile (1st step) : {report['compile_s']:.4f}s")
     for prog, rec in (report.get("xla_compile_s") or {}).items():

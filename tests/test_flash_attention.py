@@ -114,3 +114,26 @@ def test_flash_attention_reports_its_path():
             fa.attention_path(q_shape, k_shape)
     finally:
         fa.jax.default_backend = real
+
+
+def test_the_three_kernels_carry_their_names():
+    """A trace or a reader tells the kernels apart by ``name=``: the forward
+    and backward traced hold one ``pallas_call`` each of ``flash_fwd``,
+    ``flash_dq`` and ``flash_dkv``."""
+    q = jnp.zeros((1, 128, 2, 64), jnp.float32)
+    kv = jnp.zeros((1, 128, 1, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True).sum()
+
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv).jaxpr)
+    assert sorted(names) == ["flash_dkv", "flash_dq", "flash_fwd"]

@@ -366,6 +366,131 @@ def test_attribute_trace_serving_section():
         [ev("spmd.compute", 10.0)])
 
 
+def _spmd_steps(slow_step, how, source="worker:1", at=0.0):
+    """Six 100 ms steps as the SPMD loop records them, one step ahead of
+    its reports (times in ms; the first step is the compile, settled
+    alone), one of them slow: ``how`` = "device" (its program took 400 ms
+    longer) or "host" (the report two steps before took 400 ms). A routed
+    model: each step reports its ``moe.held_share``, 0.01 a step number."""
+    events = []
+
+    def ev(name, ts, dur, step, **args):
+        events.append({"ph": "X", "cat": "span", "name": name,
+                       "ts": (at + ts) * 1e3, "dur": dur * 1e3, "pid": "n",
+                       "tid": name, "args": dict(args, source=source,
+                                                 step=step)})
+
+    host = 0.0   # the loop's thread
+    issued, ready = {}, {0: 0.0}  # ready: when the HOST saw a step ready
+
+    def settle(k, done):
+        nonlocal host
+        waited_from, host = host, max(host, done)
+        lo = max(issued[k], ready[k - 1])
+        if k == 1:
+            ev("spmd.compile", lo, host - lo, k)
+        else:
+            ev("spmd.ready_wait", waited_from, host - waited_from, k)
+            ev("spmd.compute", lo, host - lo, k)
+        ready[k] = host
+        ev("spmd.fetch", host, 0.1, k)
+        events.append({"ph": "i", "s": "t", "cat": "span",
+                       "name": "moe.held_share", "pid": "n", "tid": "moe",
+                       "ts": (at + host + 0.1) * 1e3,
+                       "args": {"value": 0.01 * k, "step": k,
+                                "source": source}})
+        rep = 400.0 if how == "host" and k == slow_step - 2 else 1.0
+        ev("spmd.report", host + 0.1, rep, k)
+        host += 0.1 + rep
+
+    device, before = 0.0, None   # when the device comes free
+    for step in range(1, 7):
+        ev("spmd.ingest_wait", host, 0.2, step)
+        issued[step] = host = host + 0.2
+        if step > 1:
+            ev("spmd.dispatch", host, 0.5, step)
+            host += 0.5
+        device = max(issued[step], device) + 100.0 + (
+            400.0 if how == "device" and step == slow_step else 0.0)
+        if before is not None:
+            settle(*before)
+        before = (step, device)
+        if step == 1:
+            settle(*before)
+            before = None
+    settle(*before)
+    return events
+
+
+def test_attribute_trace_spmd_phases_and_slowest_step():
+    """The SPMD loop's phases fold into seconds beside ``spmd_compute_s``,
+    and the slowest step by wall comes with what is tagged with its step: a
+    step the device took long over shows in its wait for the device, a
+    report that stalled the loop in the step that made it."""
+    rep = fr.attribute_trace(_spmd_steps(4, "device"))
+    assert rep["spmd_dispatch_s"] == pytest.approx(5 * 0.5e-3)
+    assert rep["spmd_fetch_s"] == pytest.approx(6 * 0.1e-3)
+    assert rep["spmd_report_s"] == pytest.approx(6 * 1e-3)
+    assert rep["spmd_ready_wait_s"] > 0.5
+    slow = rep["slowest_step"]
+    assert slow["step"] == 4 and slow["source"] == "worker:1"
+    assert slow["phases_ms"]["ready_wait"] > 400.0
+    assert slow["phases_ms"]["dispatch"] == pytest.approx(0.5)
+    assert slow["phases_ms"]["report"] == pytest.approx(1.0)
+    assert slow["wall_ms"] == pytest.approx(sum(slow["phases_ms"].values()))
+    assert 495.0 < slow["wall_ms"] < 505.0
+    assert slow["router"] == {"moe.held_share": pytest.approx(0.04)}
+    text = fr.format_attribution(rep)
+    assert f"slowest step       : 4, {slow['wall_ms']:.3f} ms wall" in text
+    assert "device wait 4" in text and "moe.held_share 0.04" in text
+    assert "  dispatch         : 0.0025s" in text
+    # the same loop run twice in one process repeats its step numbers: the
+    # slow step of the second run keeps its own spans and scalars
+    twice = _spmd_steps(0, "device") + _spmd_steps(4, "device", at=5000.0)
+    assert fr.attribute_trace(twice)["slowest_step"] == slow
+
+    # a report of 400 ms in step 3: the step after it was ready before the
+    # host came to wait for it
+    late = fr.attribute_trace(_spmd_steps(5, "host"))["slowest_step"]
+    assert late["step"] == 3
+    assert late["phases_ms"]["report"] == pytest.approx(400.0)
+    assert late["phases_ms"]["ready_wait"] < 100.0
+    # a trace from before the spans carried ``step`` has no such line
+    assert "slowest_step" not in fr.attribute_trace(
+        [{"ph": "X", "cat": "span", "name": "spmd.dispatch", "ts": 0.0,
+          "dur": 1e5, "args": {}}])
+
+
+@pytest.mark.parametrize("metric,span", [
+    ("train.dispatch_ms", "spmd.dispatch"),
+    ("train.fetch_ms", "spmd.fetch"),
+    ("train.report_ms", "spmd.report"),
+])
+def test_train_loop_metric_files_read_their_spans(metric, span):
+    """Each per-layer metric of the train loop's phases, through the
+    benchmark's own loader and reader: the median of its span in ms, and
+    nothing (not an error) from a program that records no such span."""
+    import json
+    import os
+
+    from benchmarks.lib import reducers, spec
+
+    file = spec.load_layer_metric(metric)
+    assert file["args"] == {"span": span} and file["layer"] == "train loop"
+    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
+        entry, = [m for m in json.load(f)["per_layer"]
+                  if m["name"] == metric]
+    assert (entry["unit"], entry["source"], entry["moves"]) == (
+        file["unit"], file["source"], file["moves"])
+    assert entry["workloads"] == [
+        "train-mistral7b-1chip", "train-mistral7b-4chip",
+        "train-olmoe-1chip", "train-nemotron3nano-1chip"]
+    spans = {span: [[0.0, 0.001], [1.0, 0.003], [2.0, 2.5]]}
+    assert reducers.read_metric(file, {"spans": spans}) == pytest.approx(3.0)
+    assert reducers.wanted_spans([file]) == {span}
+    assert reducers.read_metric(file, {"spans": {}}) is None
+
+
 # --------------------------------------------------------------------------- #
 # Cluster plumbing: 2 separate-process daemons -> one merged trace
 # --------------------------------------------------------------------------- #

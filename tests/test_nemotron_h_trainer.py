@@ -114,6 +114,12 @@ def test_loop_reports_held_share_and_sets_the_stack_gauge():
                                   "moe.held_share"}
     assert rep["router"]["moe.held_share"]["last"] == pytest.approx(
         reports[-1]["moe_held_share"])
+    # the slowest step's line holds the held share OF THAT STEP
+    slow = rep["slowest_step"]
+    assert slow["router"]["moe.held_share"] == pytest.approx(
+        reports[slow["step"] - 1]["moe_held_share"])
+    assert "moe.held_share" in fr.format_attribution(rep).split(
+        "slowest step")[1].split("compile")[0]
     gauge = registry().local_values("ray_tpu_train_stack")
     assert {k[0][1]: v for k, v in gauge.items()} == {
         "block_layers": 0.0, "mamba_layers": 4.0, "moe_layers": 4.0,

@@ -339,6 +339,7 @@ def test_loop_reports_the_router_and_records_its_gauges():
     from ray_tpu.train.spmd import spmd_train_loop
     from ray_tpu.util import flight_recorder as fr
 
+    fr.reset_for_tests()  # this test reads its own loop's records alone
     fr.configure(enabled=True)
     ctx = TrainContext(1, 0, 0, 1, 0)
     set_context(ctx)
@@ -365,6 +366,19 @@ def test_loop_reports_the_router_and_records_its_gauges():
     assert rep["router"]["moe.lb_loss"]["last"] == pytest.approx(
         reports[-1]["moe_lb_loss"])
     assert "moe.max_load_ratio" in fr.format_attribution(rep)
+    # every instant carries its step beside its value: one a name a step
+    names = {int(sid): d for sid, d in payload["names"].items()}
+    tagged = [(names[int(sid)], tags[1])
+              for _, sid, kind, _, _, tags in payload["events"] if kind == 1]
+    assert all(d["tag_keys"] == ["value", "step"] for d, _ in tagged)
+    assert [step for _, step in tagged] == [
+        step for step in (1, 2, 3, 4) for _ in range(4)]
+    assert {d["name"] for d, _ in tagged[-4:]} == set(rep["router"])
+    # and the slowest step comes with the router's scalars of THAT step
+    slow = rep["slowest_step"]
+    assert set(slow["router"]) == set(rep["router"])
+    assert slow["router"]["moe.lb_loss"] == pytest.approx(
+        reports[slow["step"] - 1]["moe_lb_loss"])
 
 
 # --- (f) the programs are what they were ------------------------------------ #

@@ -623,13 +623,10 @@ def test_spmd_train_loop_smoke():
     assert reports[-1].metrics["tokens_per_sec_per_chip"] > 0
 
 
-@pytest.mark.parametrize("report_every", [1, 2])
-def test_loop_issues_the_next_step_before_it_waits(monkeypatch,
-                                                   report_every):
-    """The loop keeps one step ahead of its reports: step i + 1 is issued
-    before step i's loss is waited for (the first step, which compiles,
-    alone), every step is still reported, in order, and the recorder's
-    ``spmd.compute`` spans follow one another without overlap."""
+def _logged_loop(monkeypatch, report_every, recorder):
+    """Five steps of the default loop with every issue of a step and every
+    report logged in order: (events, reports, the recorder's records by
+    span name as sorted (t0, dur, tags))."""
     from ray_tpu.train import session, spmd
     from ray_tpu.train.session import TrainContext, set_context
     from ray_tpu.util import flight_recorder as fr
@@ -652,7 +649,7 @@ def test_loop_issues_the_next_step_before_it_waits(monkeypatch,
         session, "report",
         lambda m, c=None: (events.append(m["step"]), real_report(m, c)))
     fr.reset_for_tests()
-    fr.configure(enabled=True, min_span_us=0.0)
+    fr.configure(enabled=recorder, min_span_us=0.0)
     ctx = TrainContext(1, 0, 0, 1, 0)
     set_context(ctx)
     try:
@@ -660,19 +657,91 @@ def test_loop_issues_the_next_step_before_it_waits(monkeypatch,
                               "mesh": "data=1", "distinct_batches": 1,
                               "report_every": report_every})
         reports = [r.metrics for r in ctx._drain()]
+        payload = fr.snapshot_payload()
     finally:
         set_context(None)
-    want = {1: ["issue", 1, "issue", "issue", 2, "issue", 3, "issue", 4, 5],
-            2: ["issue", "issue", "issue", 2, "issue", "issue", 4, 5]}
-    assert events == want[report_every]
+        fr.configure(enabled=True)
+    names = {int(sid): d["name"] for sid, d in payload["names"].items()}
+    spans = {}
+    for _, sid, kind, t0, dur, tags in payload["events"]:
+        assert kind == 0  # a dense model: no router instant
+        spans.setdefault(names[int(sid)], []).append((t0, dur, tuple(tags)))
+    return events, reports, {n: sorted(v) for n, v in spans.items()}
+
+
+# the loop's order of issues and reports, by ``report_every``
+_LOOP_ORDER = {1: ["issue", 1, "issue", "issue", 2, "issue", 3, "issue", 4, 5],
+               2: ["issue", "issue", "issue", 2, "issue", "issue", 4, 5]}
+
+
+@pytest.mark.parametrize("report_every", [1, 2])
+def test_loop_issues_the_next_step_before_it_waits(monkeypatch,
+                                                   report_every):
+    """The loop keeps one step ahead of its reports: step i + 1 is issued
+    before step i's loss is waited for (the first step, which compiles,
+    alone), every step is still reported, in order, and the recorder's
+    ``spmd.compute`` spans follow one another without overlap."""
+    from ray_tpu.train import spmd
+
+    events, reports, by_name = _logged_loop(monkeypatch, report_every, True)
+    assert events == _LOOP_ORDER[report_every]
     assert [r["step"] for r in reports] == [e for e in events if e != "issue"]
     assert "device_report" in reports[-1]  # the last report's evidence
-    spans = sorted((t0, dur) for _, sid, kind, t0, dur, _ in
-                   fr.snapshot_payload()["events"]
-                   if int(sid) == spmd._sp_compute.sid and kind == 0)
+    spans = [(t0, dur) for t0, dur, _ in by_name[spmd._sp_compute.name]]
     assert len(spans) == 4
     for (a, da), (b, _) in zip(spans, spans[1:]):
         assert a + da <= b
+
+
+@pytest.mark.parametrize("report_every", [1, 2])
+def test_loop_records_the_hosts_phases_of_every_step(monkeypatch,
+                                                     report_every):
+    """The host's part of a step as spans joined by ``step``: a dispatch and
+    a wait for the device for every step after the first (whose call is the
+    compile), a fetch and a report for every reported step; one thread's
+    work, so pairwise disjoint; and the one-step-ahead order in the spans
+    themselves: step i + 1's dispatch ends before step i's wait begins."""
+    events, reports, by_name = _logged_loop(monkeypatch, report_every, True)
+    reported = [e for e in events if e != "issue"]
+
+    def steps_of(name):
+        return [tags[0] for _, _, tags in by_name.get(name, [])]
+
+    assert all(len(tags) == 1 for spans in by_name.values()
+               for _, _, tags in spans)  # every span: the one tag, ``step``
+    assert steps_of("spmd.compile") == [1]
+    assert steps_of("spmd.compute") == [2, 3, 4, 5]
+    assert steps_of("spmd.ingest_wait") == [1, 2, 3, 4, 5]
+    assert steps_of("spmd.dispatch") == [2, 3, 4, 5]
+    assert steps_of("spmd.ready_wait") == [2, 3, 4, 5]
+    assert steps_of("spmd.fetch") == reported
+    assert steps_of("spmd.report") == reported
+    phases = sorted((t0, t0 + dur, name, tags[0])
+                    for name in ("spmd.ingest_wait", "spmd.dispatch",
+                                 "spmd.ready_wait", "spmd.fetch",
+                                 "spmd.report")
+                    for t0, dur, tags in by_name[name])
+    for (_, end, *_a), (start, *_b) in zip(phases, phases[1:]):
+        assert end <= start
+    dispatched = {tags[0]: t0 + dur
+                  for t0, dur, tags in by_name["spmd.dispatch"]}
+    for t0, _, tags in by_name["spmd.ready_wait"]:
+        if tags[0] + 1 in dispatched:
+            assert dispatched[tags[0] + 1] <= t0
+    # each step's wait for the device ends inside its own compute span
+    computed = {tags[0]: (t0, t0 + dur)
+                for t0, dur, tags in by_name["spmd.compute"]}
+    for t0, dur, tags in by_name["spmd.ready_wait"]:
+        lo, hi = computed[tags[0]]
+        assert lo <= t0 + dur <= hi
+
+
+def test_loop_with_the_recorder_off_records_nothing(monkeypatch):
+    """Recorder off: no record, the same order of issues and reports."""
+    events, reports, by_name = _logged_loop(monkeypatch, 1, False)
+    assert by_name == {}
+    assert events == _LOOP_ORDER[1]
+    assert [r["step"] for r in reports] == [1, 2, 3, 4, 5]
 
 
 def test_jax_trainer_default_loop_spmd():
