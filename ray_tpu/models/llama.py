@@ -66,6 +66,14 @@ _g_engine_page_bytes = Gauge(
     "ray_tpu_serve_engine_page_bytes",
     "Bytes one position holds in the decode engine's page store, by the "
     "layout the layer kind gives it", tag_keys=("kind",))
+# set where the engine builds its programs: the groups their grouped expert
+# products run over (part=program: a kind's whole stack, read where it
+# lies) and the experts one layer holds (part=layer). Equal, the programs
+# cut each layer's experts out of the stack; a dense engine reads 0 and 0
+_g_engine_expert_groups = Gauge(
+    "ray_tpu_serve_engine_expert_groups",
+    "Groups the decode engine's grouped expert products run over, and the "
+    "experts one layer holds", tag_keys=("part",))
 # the last prefill's routed assignments, averaged over its layers: on the
 # experts held here, on identity experts, and on experts held elsewhere
 # (left out). Read with the logits; a model without a router sets none
@@ -608,13 +616,14 @@ def _qkv(cfg: LlamaConfig, p, h, n_q: int, n_kv: int, positions):
     return q, kk, vv
 
 
-def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=()):
+def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=(), layer=None):
     """The MLP half on ``h`` [B, T, dim] (normed, cfg.dtype), before the
     residual add (and before a row-parallel caller's psum): the dense
     SwiGLU, or, where the config has experts, the dropless routed one
     (:func:`ray_tpu.ops.moe.routed_mlp`). Returns ``(y, stats)``; ``stats``
     is ``{}`` for a dense layer and the router's scalars for a routed one.
-    ``stat_axes``: see ``routed_mlp``."""
+    ``stat_axes``, and ``layer`` for experts that come as their kind's
+    whole stack: see ``routed_mlp``."""
     cd = cfg.dtype
     if cfg.num_experts:
         from ray_tpu.ops.moe import routed_mlp
@@ -629,7 +638,7 @@ def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=()):
                   if wide != cfg.num_experts else None),
             shared=((p["shared_up"], p["shared_down"])
                     if cfg.shared_mlp_dim else None),
-            zero_experts=cfg.zero_experts)
+            zero_experts=cfg.zero_experts, layer=layer)
         return y.astype(cd), stats
     # the two products may be kept (KEEP_GROUPS); silu is made again
     g = jax.nn.silu(checkpoint_name(h @ p["w_gate"].astype(cd), "mlp"))
@@ -823,7 +832,13 @@ def shortcut_layer(cfg: LlamaConfig, x, layers, i, positions, attend,
     stacked ``[n_S, 2, ...]``, router and experts are :func:`_mlp_half`'s.
     Every matrix is cut out where it is used, ``[i, j]`` at once: a whole
     layer cut out first is a copy of 2.5 GB a layer at the published
-    widths (30 of a decode call's 37 ms; my chip run, PR 33).
+    widths (30 of a decode call's 37 ms; my chip run, PR 33). The router
+    and its bias are small and are cut out ``[i]``. The EXPERTS are not cut
+    out at all: their grouped product is a kernel and no fusion, so
+    ``[i]`` before it is three copies of 403 MB a layer at every prefill
+    and decode call (14.7 ms a call, 32% of the LongCat cell's device
+    time; ledger, PR 35). They go down whole with ``layer=i`` and the
+    product places the layer's groups in the stack (``routed_mlp``).
     ``attend(j, q, latent, wkv_b)``: what sublayer ``j`` attends over.
     Returns ``(x, stats, latents)``: ``latents`` ``[2, B, T, latent_row]``,
     what each sublayer's cache keeps."""
@@ -850,8 +865,11 @@ def shortcut_layer(cfg: LlamaConfig, x, layers, i, positions, attend,
 
     a0 = mla(0, x)
     h0 = norm(a0, "mlp_norm", 0)
-    m, stats = _mlp_half(cfg, {w: layers[w][i] for w in (
-        "router", "router_bias", "w_gate", "w_up", "w_down")}, h0, stat_axes)
+    m, stats = _mlp_half(
+        cfg, {"router": layers["router"][i],
+              "router_bias": layers["router_bias"][i],
+              **{w: layers[w] for w in ("w_gate", "w_up", "w_down")}},
+        h0, stat_axes, layer=i)
     b0 = a0 + ffn(0, h0).astype(x.dtype)
     a1 = mla(1, b0)
     b1 = a1 + ffn(1, norm(a1, "mlp_norm", 1)).astype(x.dtype)
@@ -1447,6 +1465,15 @@ class LlamaDecodeEngine:
                       kind: sum(4 * S * math.prod(row) for S, row in rows)}
         for tag, nbytes in page_bytes.items():
             _g_engine_page_bytes.set(float(nbytes), tags={"kind": tag})
+        groups = {"program": 0, "layer": 0}
+        if self.cfg.num_experts:  # of the routed kinds it serves "S" alone
+            from ray_tpu.ops.moe import expert_groups
+
+            w_up = self.params["layers"]["scmoe"]["w_up"]
+            groups = {"program": expert_groups(w_up, self.cfg.dtype),
+                      "layer": w_up.shape[1]}
+        for part, n in groups.items():
+            _g_engine_expert_groups.set(float(n), tags={"part": part})
         donated = tuple(range(1, 1 + len(rows)))  # the stores, every call
         self._prefill_fn = observe_compiled(
             jax.jit(partial(prefill_with_cache, self.cfg),

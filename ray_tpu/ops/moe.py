@@ -229,23 +229,65 @@ def _combine_bwd(res, ct):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _expert_ffn(xs, w_gate, w_up, w_down, counts):
+def expert_groups(w_up, dtype) -> int:
+    """The groups the grouped products run over for a kind's stacked
+    ``w_up`` [L, count, d, f] and rows of ``dtype``: the stack's ``L *
+    count`` where it is read where it lies, one layer's ``count`` where the
+    layer is cut out of it (:func:`_expert_ffn` says when)."""
+    L, count, _, f = w_up.shape
+    cut = w_up.dtype != dtype or (f > 512 and f % 512)
+    return count if cut else L * count
+
+
+def _expert_ffn(xs, w_gate, w_up, w_down, counts, layer=None):
     """The experts' products on rows sorted by expert (``counts`` rows an
     expert): SwiGLU where there is a ``w_gate``, else the two-matrix
     ``relu(x W_up) ** 2 W_down``. Compute type in, compute type out.
+
+    With ``layer`` the weights are a kind's whole stacked leaves ``[L,
+    count, ...]``, read where they lie: as ``L * count`` groups (the same
+    bytes) of which this layer's, from ``layer * count`` on, get ``counts``
+    and every other one no row. The grouped product reads the tiles of the
+    groups that have rows; ``w[layer]`` under a traced ``layer`` is a copy
+    of the whole layer's experts a matrix, because the product is a kernel
+    that wants a whole operand (14.7 ms of every call of the LongCat
+    engine, 32% of its cell's device time; ledger, PR 35). A stack in
+    another type than the rows', or of a width that is filled up below,
+    would be copied WHOLE: there the layer is cut out.
 
     A width over 512 that is no multiple of it is filled up with zero
     columns (and zero rows of ``w_down``), which add nothing: on the TPU
     the grouped product of 6,144 rows in 8 groups takes 6.2 ms forward and
     16.6 with its backward at width 1856, 6.0 / 17.1 at 1920 and 3.5 / 9.0
-    at 2048 (PERF.md, PR 31)."""
+    at 2048 (PERF.md, PR 31). A row count that is no multiple of 8 (a
+    decode call's ``top_k`` = 12) is filled up with rows of no group: the
+    TPU compiler makes the grouped kernel only of whole sublanes, and of
+    anything else a dense product over EVERY group under a mask, which
+    reads every expert's weights (compiled for a described v5e at 1, 9, 12
+    and 20 rows against 8, 16 and 24; PR 37)."""
     cd = xs.dtype
     f = w_up.shape[-1]
+    if layer is not None:
+        stack = (w_gate, w_up, w_down)
+        (L, count), groups = w_up.shape[:2], expert_groups(w_up, cd)
+        if groups == L * count:
+            w_gate, w_up, w_down = (
+                None if w is None else w.reshape(groups, *w.shape[2:])
+                for w in stack)
+            counts = jax.lax.dynamic_update_slice(
+                jnp.zeros(groups, counts.dtype), counts,
+                (jnp.asarray(layer, jnp.int32) * count,))
+        else:
+            w_gate, w_up, w_down = (None if w is None else w[layer]
+                                    for w in stack)
     if f > 512 and f % 512:
         cols, rows = ((0, 0), (0, 0), (0, -f % 512)), ((0, 0), (0, -f % 512),
                                                        (0, 0))
         w_up, w_down = jnp.pad(w_up, cols), jnp.pad(w_down, rows)
         w_gate = None if w_gate is None else jnp.pad(w_gate, cols)
+    n = xs.shape[0]
+    if n % 8:
+        xs = jnp.pad(xs, ((0, -n % 8), (0, 0)))
     if w_gate is None:
         u = _grouped_dot(xs, w_up, counts)
         a = jnp.square(jax.nn.relu(u)).astype(cd)
@@ -253,15 +295,18 @@ def _expert_ffn(xs, w_gate, w_up, w_down, counts):
         g = _grouped_dot(xs, w_gate, counts).astype(cd)
         u = _grouped_dot(xs, w_up, counts).astype(cd)
         a = (jax.nn.silu(g.astype(jnp.float32)) * u).astype(cd)
-    return _grouped_dot(a, w_down, counts).astype(cd)
+    ys = _grouped_dot(a, w_down, counts).astype(cd)
+    return ys[:n] if n % 8 else ys
 
 
-def _held_rows(hf, top_w, order, starts, end, weights, blocks):
+def _held_rows(hf, top_w, order, starts, end, weights, blocks,
+               layer=None):
     """The routed sum ``[N, d]`` float32 of a layer that holds some of the
     router's experts. ``order`` [A] are the assignments sorted by held
     expert (``starts`` [count]: each one's first place), from place ``end``
     on those that chose an expert held elsewhere: they get no row, no
-    product and no part in the sum.
+    product and no part in the sum. ``weights`` / ``layer``: as
+    :func:`_expert_ffn` takes them.
 
     The sorted places are walked in ``blocks`` blocks of ``A / blocks``.
     The first block's rows are always made: gathered, through
@@ -293,7 +338,7 @@ def _held_rows(hf, top_w, order, starts, end, weights, blocks):
             xs = jnp.where(live[:, None], hf[token], 0)
         with jax.named_scope("moe.experts"):
             ys = _expert_ffn(xs, *weights,
-                             jnp.diff(jnp.clip(edges - lo, 0, n)))
+                             jnp.diff(jnp.clip(edges - lo, 0, n)), layer)
         with jax.named_scope("moe.combine"):
             ys = jnp.where(live, flat_w[mine], 0.0)[:, None] * jnp.where(
                 live[:, None], ys, 0).astype(jnp.float32)
@@ -311,7 +356,7 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
                stat_axes: Sequence[str] = (),
                scoring: str = "softmax", choice_bias=None,
                scale: float = 1.0, held: Optional[Tuple[int, int]] = None,
-               shared=None, zero_experts: int = 0
+               shared=None, zero_experts: int = 0, layer=None
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Dropless top-k routed experts. ``h`` [..., d] in the compute type.
 
@@ -366,6 +411,11 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
     over those axes by the caller, is the loss of the whole batch and its
     gradient the whole batch's (the fractions carry no gradient, so no
     collective is differentiated)."""
+    if (w_up.ndim == 4) != (layer is not None):
+        raise ValueError(
+            f"expert weights {w_up.shape} with layer={layer!r}: stacked "
+            "leaves [L, count, d, f] come with their layer's number, one "
+            "layer's [count, d, f] without")
     cd = h.dtype
     lead, d = h.shape[:-1], h.shape[-1]
     E, K = router_w.shape[-1], top_k
@@ -410,13 +460,13 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
             xs = _dispatch(hf, order, inverse)
     if held is None:
         with jax.named_scope("moe.experts"):
-            ys = _expert_ffn(xs, w_gate, w_up, w_down, counts)
+            ys = _expert_ffn(xs, w_gate, w_up, w_down, counts, layer)
         with jax.named_scope("moe.combine"):
             y = _combine(ys, top_w, order, inverse)
     else:  # a block of places is four even shares of the assignments, and
         # at least 128 places (a decode call's few are one block)
         y = _held_rows(hf, top_w, order, starts, end, (w_gate, w_up, w_down),
-                       max(1, min(E // (4 * count), A // 128)))
+                       max(1, min(E // (4 * count), A // 128)), layer)
     if zero_experts:
         with jax.named_scope("moe.zero"):
             to_zero = top_e >= E - zero_experts

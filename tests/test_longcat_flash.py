@@ -323,6 +323,111 @@ def test_the_shares_partial_sums_add_up_to_the_uncut_layer(params, seq):
     assert held_share + float(stats["zero_share"]) == pytest.approx(1.0)
 
 
+# --- (c') experts that come as their kind's whole stack ---------------------- #
+
+# LongCat's form (a share of 4 of 24 real experts, 8 identity experts, a
+# choice bias) and the plain one (16 experts, all here), both top-12 as the
+# cell is; ``rows``: a prefill's tokens, or SIXTEEN decode calls of one
+# token's dozen assignments
+FORMS = {"longcat": dict(wide=32, count=4, held=(4, 4), zero_experts=8),
+         "plain": dict(wide=16, count=16)}
+
+
+def stack_case(form, rows, depth):
+    wide, count = FORMS[form]["wide"], FORMS[form]["count"]
+    k = jax.random.split(jax.random.PRNGKey(21), 6)
+    weights = (jax.random.normal(k[0], (depth, count, 64, 32)) / 8,
+               jax.random.normal(k[1], (depth, count, 64, 32)) / 8,
+               jax.random.normal(k[2], (depth, count, 32, 64)) / 6)
+    bias = (0.003 * jax.random.normal(k[3], (wide,))
+            if form == "longcat" else None)
+    h = jax.random.normal(k[5], (16, 1, 64) if rows == "decode" else (48, 64))
+    return h, jax.random.normal(k[4], (64, wide)) / 8, bias, weights
+
+
+def routed_sum(form, h, router, bias, weights, layer):
+    """``sum(y * y)`` and the stats of the routed branch: one call on all
+    of ``h``'s rows, or a call a row (a decode call each)."""
+    kw = {k: v for k, v in FORMS[form].items() if k not in ("wide", "count")}
+
+    def call(rows):
+        return routed_mlp(rows, router, *weights, top_k=12, scale=6.0,
+                          choice_bias=bias, layer=layer, **kw)
+
+    y, stats = call(h) if h.ndim == 2 else jax.lax.map(call, h)
+    return jnp.sum(y * y), (y, stats)
+
+
+@pytest.mark.parametrize("depth,i", [(3, 0), (3, 1), (3, 2), (1, 0)])
+@pytest.mark.parametrize("rows", ["prefill", "decode"])
+@pytest.mark.parametrize("form", ["longcat", "plain"])
+def test_a_stack_with_its_layers_number_is_the_layer_cut_out(form, rows,
+                                                             depth, i):
+    """``routed_mlp`` given the kind's stacked experts and ``layer=i``
+    (traced, as the engine's scan has it) against given ``stack[i]``: the
+    same values and stats for every layer of a stack of three, and for the
+    full forward's stack of one the same gradients too."""
+    from jitted import value_and_grad
+
+    h, router, bias, stack = stack_case(form, rows, depth)
+
+    def stacked(h, router, stack, i):
+        return routed_sum(form, h, router, bias, stack, i)
+
+    def cut_out(h, router, stack, i):
+        return routed_sum(form, h, router, bias, [w[i] for w in stack], None)
+
+    at = jnp.int32(i)
+    (_, (got, stats)), d_got = value_and_grad(
+        stacked, h, router, stack, at, has_aux=True, argnums=(0, 1, 2))
+    (_, (want, st)), d_want = value_and_grad(
+        cut_out, h, router, stack, at, has_aux=True, argnums=(0, 1, 2))
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+    close(got, want, 1e-6)
+    for name in st:
+        np.testing.assert_allclose(stats[name], st[name], rtol=1e-6)
+    if form == "longcat" and rows == "decode":
+        # calls with NO assignment on a held expert, and calls with some
+        assert float(st["held_share"].min()) == 0.0 < float(
+            st["held_share"].max())
+    if depth == 1:
+        for g, w in zip(jax.tree.leaves(d_got), jax.tree.leaves(d_want)):
+            close(g, w, 1e-5)
+
+
+@pytest.mark.parametrize("why", ["another type", "a width filled up"])
+def test_a_stack_that_would_be_copied_whole_has_its_layer_cut_out(why):
+    """Rows in bfloat16 over a float32 stack, or experts 576 wide (filled
+    up to 1024): the grouped product would read a converted or filled COPY
+    of the whole stack, so the layer is cut out first, as without
+    ``layer``; the values are the same either way."""
+    from ray_tpu.ops.moe import expert_groups
+
+    k = jax.random.split(jax.random.PRNGKey(22), 5)
+    f = 576 if why == "a width filled up" else 32
+    cd = jnp.bfloat16 if why == "another type" else jnp.float32
+    stack = (jax.random.normal(k[0], (2, 4, 64, f)) / 8,
+             jax.random.normal(k[1], (2, 4, 64, f)) / 8,
+             jax.random.normal(k[2], (2, 4, f, 64)) / 24)
+    assert expert_groups(stack[1], cd) == 4
+    assert expert_groups(stack[1][:, :, :, :32], jnp.float32) == 8
+    h = jax.random.normal(k[3], (24, 64)).astype(cd)
+    router = jax.random.normal(k[4], (64, 4)) / 8
+    got, _ = jax.jit(lambda h, stack, i: routed_mlp(
+        h, router, *stack, top_k=2, layer=i))(h, stack, jnp.int32(1))
+    want, _ = jax.jit(lambda h, stack: routed_mlp(
+        h, router, *(w[1] for w in stack), top_k=2))(h, stack)
+    close(got, want, 1e-6)
+
+
+def test_routed_mlp_takes_a_stack_with_its_layer_and_a_layer_without():
+    h, router, _, stack = stack_case("plain", "prefill", 2)
+    with pytest.raises(ValueError, match="layer=None"):
+        routed_mlp(h, router, *stack, top_k=2)
+    with pytest.raises(ValueError, match="layer=1"):
+        routed_mlp(h, router, *(w[1] for w in stack), top_k=2, layer=1)
+
+
 # --- (d) the engine: a latent page store, prefill and decode ---------------- #
 
 
@@ -357,14 +462,50 @@ def test_the_store_is_one_array_of_latent_rows(engine):
     assert llama.page_rows(engine.cfg) == ("latent", [(4, (24,))])
     (store,) = engine.stores
     assert store.shape == (4, 12, 8, 24) and store.dtype == jnp.float32
-    got = {k[0][1]: v for k, v in registry().local_values(
-        "ray_tpu_serve_engine_page_bytes").items()}
-    assert got == {"kv": 0.0, "latent": 4 * 24 * 4.0}
+
+    def gauge(name):
+        return {k[0][1]: v for k, v in registry().local_values(name).items()}
+
+    assert gauge("ray_tpu_serve_engine_page_bytes") == {
+        "kv": 0.0, "latent": 4 * 24 * 4.0}
+    # the grouped products run over the stack's 2 x 4 groups
+    assert gauge("ray_tpu_serve_engine_expert_groups") == {
+        "program": 8.0, "layer": 4.0}
     dense = llama.LlamaDecodeEngine(n_pages=4, page_size=4)
     assert [s.shape for s in dense.stores] == [(2, 4, 4, 2, 16)] * 2
-    got = {k[0][1]: v for k, v in registry().local_values(
-        "ray_tpu_serve_engine_page_bytes").items()}
-    assert got == {"kv": 2 * 2 * 2 * 16 * 4.0, "latent": 0.0}
+    assert gauge("ray_tpu_serve_engine_page_bytes") == {
+        "kv": 2 * 2 * 2 * 16 * 4.0, "latent": 0.0}
+    assert gauge("ray_tpu_serve_engine_expert_groups") == {
+        "program": 0.0, "layer": 0.0}
+
+
+def test_no_program_cuts_a_layers_experts_out_of_the_stack(engine):
+    """The engine's prefill and decode programs as LOWERED (before any
+    compiler): nothing is sliced or gathered to the shape of one layer's
+    experts, ``[4, 64, 32]`` or ``[4, 32, 64]`` here (a copy of 403 MB a
+    matrix at the published widths, at every call). Over how many groups
+    the grouped products run instead, the engine's gauge says (above)."""
+    import re
+
+    pages = np.asarray([1, 2], np.int32)
+    calls = {
+        "_prefill_fn": (np.zeros((1, 16), np.int32), pages,
+                        np.asarray(15, np.int32)),
+        "_decode_fn": (np.asarray([1], np.int32), np.asarray(15, np.int32),
+                       pages)}
+    cut = re.compile(r"stablehlo\.(?:dynamic_slice|slice|gather|"
+                     r"dynamic_gather)\b.*->\s*tensor<((?:\d+x)+)f32>")
+    for name, args in calls.items():
+        text = getattr(engine, name)._fn.lower(
+            engine.params, *engine.stores, *args).as_text()
+        assert "tensor<8x64x32xf32>" in text  # the stack as 2 x 4 groups
+        shapes = {tuple(int(n) for n in m.group(1).split("x") if n)
+                  for m in map(cut.search, text.splitlines()) if m}
+        assert shapes  # the pattern still reads this lowering
+        found = {s for s in shapes
+                 if tuple(n for n in s if n != 1) in {(4, 64, 32),
+                                                      (4, 32, 64)}}
+        assert not found, (name, found)
 
 
 def test_prefill_reports_where_the_assignments_fell(engine):

@@ -77,3 +77,47 @@ def test_flash_kernels_compile_at_the_cells_shapes(cell, one_chip,
     compiled = lowered.compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
         >= 3
+
+
+@pytest.mark.parametrize("tokens", [1, 2048])
+def test_grouped_products_over_the_stack_read_it_where_it_lies(
+        tokens, one_chip, as_on_the_chip):
+    """``routed_mlp`` at LongCat's share (4 layers of 16 held experts, 6144
+    x 2048, a 768-wide router, top-12) under a scan that traces the layer's
+    number, at a decode call's one token and at a short prefill's 2,048:
+    every expert product is XLA's grouped kernel (of a decode call's 12
+    rows, not filled up to whole sublanes, the compiler makes a dense
+    product over every group), and nothing of a layer's experts' shape is
+    made (a copy of 403 MB a matrix and layer)."""
+    import re
+
+    from ray_tpu.ops.moe import routed_mlp
+
+    L, count, d, f, wide = 4, 16, 6144, 2048, 768
+
+    def layers(h, router, bias, w_gate, w_up, w_down):
+        def body(x, i):
+            y, _ = routed_mlp(x, router[i], w_gate, w_up, w_down, top_k=12,
+                              scale=6.0, choice_bias=bias[i],
+                              held=(0, count), zero_experts=256, layer=i)
+            return x + y.astype(x.dtype), None
+
+        return jax.lax.scan(body, h, jnp.arange(L, dtype=jnp.int32))[0]
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(layers).lower(
+        arg(tokens, d), arg(L, d, wide, dtype=jnp.float32),
+        arg(L, wide, dtype=jnp.float32), arg(L, count, d, f),
+        arg(L, count, d, f), arg(L, count, f, d)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 3
+    assert not re.findall(r"= f32\[(?:16|64),\d+,\d+\]\S* convolution\(",
+                          text)
+    made = re.findall(r"%(\S+) = bf16\[16,(?:6144,2048|2048,6144)\]\S* "
+                      r"(?!bitcast|parameter|get-tuple-element)(\w[\w-]*)\(",
+                      text)
+    assert not made, made
+    if tokens == 1:  # one such copy is 403 MB
+        assert compiled.memory_analysis().temp_size_in_bytes < 100e6
