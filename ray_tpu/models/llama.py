@@ -7,8 +7,10 @@ model definition runs dp/fsdp/tp/sp via GSPMD. Design choices for the MXU:
 - layers stacked and scanned (``lax.scan``) — one compiled layer body,
   constant compile time in depth; or, where the config gives a
   ``layer_pattern``, a stack of Mamba-2, routed and attention layers (each
-  ONE half of the block) or of shortcut-connected double layers (latent
-  attention, :func:`shortcut_layer`), weights stacked per kind and walked
+  ONE half of the block), of shortcut-connected double layers (latent
+  attention, :func:`shortcut_layer`) or of whole routed blocks whose
+  attention is full and unrotated in some layers and windowed and rotated
+  in the others (:func:`window_block`), weights stacked per kind and walked
   in the pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
 - bf16 matmuls with fp32 accumulation (``preferred_element_type``), params
   stored fp32, gradients/optimizer fp32;
@@ -61,7 +63,9 @@ _g_engine_weight_bytes = Gauge(
 
 # what ONE position leaves in the engine's page store, over all layers: the
 # block's per-head keys and values (kind=kv) or a latent sublayer's row
-# (kind=latent); the other kind reads 0
+# (kind=latent); of a stack of full and window layers, a position's keys and
+# values in the full layers' stores (kind=full) and a SLOT position's in the
+# window layers' (kind=window); the kinds an engine has not read 0
 _g_engine_page_bytes = Gauge(
     "ray_tpu_serve_engine_page_bytes",
     "Bytes one position holds in the decode engine's page store, by the "
@@ -74,6 +78,12 @@ _g_engine_expert_groups = Gauge(
     "ray_tpu_serve_engine_expert_groups",
     "Groups the decode engine's grouped expert products run over, and the "
     "experts one layer holds", tag_keys=("part",))
+# the window layers' page slabs (LlamaDecodeEngine: a slot a page that holds
+# window rows): how many the stores have, and how many are assigned
+_g_engine_window_slots = Gauge(
+    "ray_tpu_serve_engine_window_slots",
+    "Page slabs of the decode engine's window stores: all of them, and "
+    "those assigned to a pool page", tag_keys=("state",))
 # the last prefill's routed assignments, averaged over its layers: on the
 # experts held here, on identity experts, and on experts held elsewhere
 # (left out). Read with the logits; a model without a router sets none
@@ -104,6 +114,12 @@ _sp_decode_program = _fr.register_span("engine.decode_program",
                                        tag_keys=("pages",))
 _sp_decode_readback = _fr.register_span("engine.decode_readback",
                                         tag_keys=("pages",))
+# a stack with window layers: the engine's slot bookkeeping in a prefill or
+# decode call (looking the pages' slots up, assigning the missing ones,
+# building the table that is uploaded); inside engine.prefill_program's or
+# engine.decode_upload's stretch of the call, microseconds
+_sp_window_slots = _fr.register_span("engine.window_slots",
+                                     tag_keys=("pages",), floor_exempt=True)
 
 
 # What a train step may KEEP of its forward pass where it would otherwise
@@ -129,7 +145,34 @@ def keep_policy(keep):
 
 # a patterned stack's layer kinds: the character -> the name of the kind's
 # stacked weights under params["layers"]
-LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe"}
+LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
+               "F": "block", "W": "block"}
+# the kinds that are a WHOLE block (attention THEN the routed MLP, whose
+# router reads the attention's normed input; window_block): "F" attends over
+# every earlier position without rotation, "W" over the last cfg.window with
+# rotation. They have the same leaves and share ONE stack, in layer order.
+BLOCK_KINDS = "FW"
+# Where their matrices start off the square root of their fan-in (seeded random
+# weights; a checkpoint brings its own): ``wo`` 12 times as wide, the router
+# 6 times, so that BOTH halves carry the logits and a comparison of logits
+# sees a fault in either. At fan-in scaling it sees neither. Attention over
+# thousands of keys whose scores are one unit wide is nearly an average, 0.03
+# of the stream a layer against the experts' 0.3: a window, a rotation or a
+# slot does not show. And a top-k choice is a hard one: bfloat16 products
+# move the LAST of a token's six choices in some 3% of its layers, a router
+# one unit wide weighs the six 0.27 .. 0.09, and one moved choice is then a
+# third of a layer's routed sum, 6-16% of a logit (4 of 16 readings on the
+# chip): more than SwiGLU for ReGLU moves it. A router 6 units wide weighs
+# the six 0.90, 0.08, 0.013 .. 0.001: the choice that rounding can move
+# weighs next to nothing but for the rare token whose six lie close
+# together (wider than 6 the tail grows again: each layer's sharper choice
+# multiplies what the layers before it left). ``wo`` sets the halves'
+# shares: at 12 the routed sum is some 12% of a logit, enough that the
+# router's input and the experts' activation show, little enough that the
+# worst moved choice stays under the limit (PERF.md, PR 38, has the
+# readings at 3, 4, 6, 8, 12 and 16). WHICH experts are chosen, and so their
+# load and every product's shape and time, is the same at any width.
+BLOCK_INIT = {"wo": 12.0, "router": 6.0}
 
 
 @dataclass(frozen=True)
@@ -176,9 +219,12 @@ class LlamaConfig:
     # x + f(RMSNorm(x)). "S": the shortcut-connected double layer
     # (shortcut_layer): two latent attentions and two dense feed-forwards
     # in line, the routed experts beside the first and added at the end.
-    # Empty: every layer is the block (attention THEN MLP), as every dense
-    # and every all-routed configuration has it.
+    # "F" / "W": a whole block (window_block), full unrotated or windowed
+    # rotated attention, then the routed MLP whose router reads the
+    # attention's input. Empty: every layer is the block (attention THEN
+    # MLP), as every dense and every all-routed configuration has it.
     layer_pattern: str = ""
+    window: int = 0  # a "W" layer's position i sees j <= i with j > i - window
     ssm_heads: int = 0      # H; d_inner = ssm_heads * ssm_head_dim
     ssm_head_dim: int = 0   # P
     ssm_groups: int = 1     # G: heads that share one B and C
@@ -197,7 +243,8 @@ class LlamaConfig:
     #   loss; a patterned stack's router has a bias added for the CHOICE
     #   alone, whatever the scoring
     routed_scale: float = 1.0        # multiplies the top-k weights
-    mlp_act: str = "swiglu"          # experts: or "relu2", two matrices
+    mlp_act: str = "swiglu"          # experts: or "reglu" (relu for silu),
+    #   or "relu2", two matrices
     shared_mlp_dim: int = 0          # a shared expert's width (0 = none)
     zero_experts: int = 0  # identity experts, the router's LAST outputs: its
     #   width is (router_experts or num_experts) + zero_experts
@@ -222,7 +269,7 @@ class LlamaConfig:
                 f"{sorted(LAYER_KINDS)} a layer, at least n_layers="
                 f"{self.n_layers} of them (or empty)")
         if (self.router_scoring not in ("softmax", "sigmoid")
-                or self.mlp_act not in ("swiglu", "relu2")):
+                or self.mlp_act not in ("swiglu", "relu2", "reglu")):
             raise ValueError(f"router_scoring={self.router_scoring!r}, "
                              f"mlp_act={self.mlp_act!r}")
         if not self.layer_pattern and (
@@ -243,6 +290,23 @@ class LlamaConfig:
                 "an 'S' layer needs its latent ranks, its three head "
                 "widths and dense_mlp_dim, and has swiglu experts and no "
                 "shared one")
+        blocks = set(self.kinds) & set(BLOCK_KINDS)
+        if ("W" in self.kinds) != bool(self.window) or self.window < 0:
+            raise ValueError(
+                f"window={self.window} with kinds {self.kinds!r}: a 'W' "
+                "layer needs a window, and only a 'W' layer has one")
+        if blocks and (
+                not (self.num_experts and self.experts_per_token)
+                or self.mlp_act == "relu2" or not self.rope or self.qk_norm
+                or self.router_experts or self.zero_experts
+                or self.shared_mlp_dim or self.router_scoring != "softmax"
+                or self.routed_scale != 1.0):
+            raise ValueError(
+                "an 'F' / 'W' layer is attention then a softmax-routed "
+                "gated MLP with every expert here (num_experts, "
+                "experts_per_token; swiglu or reglu): it rotates its 'W' "
+                "layers by itself (rope stays True) and has no QK-norm, "
+                "held range, identity or shared expert, or weight scale")
 
     @property
     def head_dim(self) -> int:
@@ -257,6 +321,24 @@ class LlamaConfig:
     def kinds(self) -> str:
         """The built layers' kinds, in order ('' for a stack of blocks)."""
         return self.layer_pattern[:self.n_layers]
+
+    @property
+    def window_layout(self):
+        """The whole pattern as a published config lists it: 1 a layer
+        whose attention has the window, else 0."""
+        return [int(kind == "W") for kind in self.layer_pattern]
+
+    @property
+    def rope_layout(self):
+        """1 a layer of the whole pattern whose attention rotates."""
+        return [int(kind in "WS" or (kind == "*" and self.rope))
+                for kind in self.layer_pattern]
+
+    def window_pages(self, page_size: int) -> int:
+        """The pages at a sequence's end that can hold a position some later
+        position's window reaches: a stretch of ``window`` positions touches
+        at most ``ceil(window / page_size) + 1`` pages."""
+        return -(-self.window // page_size) + 1
 
     @staticmethod
     def small(vocab_size: int = 32000) -> "LlamaConfig":
@@ -296,6 +378,9 @@ class LlamaConfig:
                       * d * self.mlp_dim + d),
                 "*": 2 * d * q + 2 * d * kv + d,
             }
+            per_kind["F"] = per_kind["W"] = (
+                2 * d * q + 2 * d * kv + d * self.num_experts
+                + self.num_experts * 3 * d * self.mlp_dim + 2 * d)
             if "S" in self.kinds:
                 rq, rkv, h = self.q_lora_rank, self.kv_lora_rank, self.n_heads
                 qk = self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -371,7 +456,20 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             "w_up": ("layers", None, "embed", "mlp"),
             "w_down": ("layers", None, "mlp", "embed"),
         }
-        if cfg.mlp_act == "swiglu":
+        # a whole block's leaves: the dense block's with a router and
+        # four-dimensional experts
+        kinds["block"] = {
+            "attn_norm": ("layers", None), "mlp_norm": ("layers", None),
+            "wq": ("layers", "embed", "heads"),
+            "wk": ("layers", "embed", "kv_heads"),
+            "wv": ("layers", "embed", "kv_heads"),
+            "wo": ("layers", "heads", "embed"),
+            "router": ("layers", "embed", None),
+            "w_gate": ("layers", None, "embed", "mlp"),
+            "w_up": ("layers", None, "embed", "mlp"),
+            "w_down": ("layers", None, "mlp", "embed"),
+        }
+        if cfg.mlp_act != "relu2":
             kinds["moe"]["w_gate"] = ("layers", None, "embed", "mlp")
         if cfg.shared_mlp_dim:
             kinds["moe"]["shared_up"] = ("layers", "embed", "mlp")
@@ -424,8 +522,10 @@ def _dense_init(rng, shape, fan_in):
 def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
     """A patterned stack's weights, stacked per KIND in the order the
     pattern meets them: ``{"mamba": {leaf: [n_M, ...]}, "moe": {...},
-    "attn": {...}, "scmoe": {...}}``, a kind the built layers lack left
-    out. The router's choice bias starts at zero, as published."""
+    "attn": {...}, "scmoe": {...}, "block": {...}}``, a kind the built
+    layers lack left out ("F" and "W" layers share the one ``block`` stack,
+    in layer order). The router's choice bias starts at zero, as
+    published."""
     from ray_tpu.ops.ssm import init_mamba2
 
     d, hd, f = cfg.dim, cfg.head_dim, cfg.mlp_dim
@@ -451,7 +551,7 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
             "w_up": dense(next(k), (L, held, d, f), d),
             "w_down": dense(next(k), (L, held, f, d), f),
         }
-        if cfg.mlp_act == "swiglu":
+        if cfg.mlp_act != "relu2":
             moe["w_gate"] = dense(next(k), (L, held, d, f), d)
         if cfg.shared_mlp_dim:
             fs = cfg.shared_mlp_dim
@@ -495,6 +595,21 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
             "ffn_down": dense(next(k), (L, 2, fd, d), fd),
             "router": dense(next(k), (L, d, wide), d),
             "router_bias": jnp.zeros((L, wide), jnp.float32),
+            "w_gate": dense(next(k), (L, held, d, f), d),
+            "w_up": dense(next(k), (L, held, d, f), d),
+            "w_down": dense(next(k), (L, held, f, d), f),
+        }
+    L = n["F"] + n["W"]
+    if L:  # keys of its own, as above
+        k = iter(jax.random.split(jax.random.fold_in(key, 2), 8))
+        out["block"] = {
+            "attn_norm": jnp.ones((L, d), jnp.float32),
+            "mlp_norm": jnp.ones((L, d), jnp.float32),
+            "wq": dense(next(k), (L, d, nq * hd), d),
+            "wk": dense(next(k), (L, d, nkv * hd), d),
+            "wv": dense(next(k), (L, d, nkv * hd), d),
+            "wo": BLOCK_INIT["wo"] * dense(next(k), (L, nq * hd, d), nq * hd),
+            "router": BLOCK_INIT["router"] * dense(next(k), (L, d, wide), d),
             "w_gate": dense(next(k), (L, held, d, f), d),
             "w_up": dense(next(k), (L, held, d, f), d),
             "w_down": dense(next(k), (L, held, f, d), f),
@@ -593,11 +708,13 @@ def _attention(cfg: LlamaConfig, q, k, v, mesh):
     return flash_attention(q, k, v, causal=True)
 
 
-def _qkv(cfg: LlamaConfig, p, h, n_q: int, n_kv: int, positions):
+def _qkv(cfg: LlamaConfig, p, h, n_q: int, n_kv: int, positions,
+         rope: Optional[bool] = None):
     """The attention half's inputs: ``h`` [B, T, dim] (normed, cfg.dtype)
     through wq / wk / wv, QK-norm where the config has it (RMSNorm over the
     whole projected vector, before the split into heads), heads split,
-    RoPE on q and k (unless the config attends without rotation). ``n_q`` /
+    RoPE on q and k (unless the config attends without rotation; ``rope``:
+    a layer kind's own answer in place of the config's). ``n_q`` /
     ``n_kv``: the heads THESE weights hold."""
     cd, hd = cfg.dtype, cfg.head_dim
     B, T, _ = h.shape
@@ -611,19 +728,21 @@ def _qkv(cfg: LlamaConfig, p, h, n_q: int, n_kv: int, positions):
     q = project("wq", n_q, "q_norm" if cfg.qk_norm else None)
     kk = project("wk", n_kv, "k_norm" if cfg.qk_norm else None)
     vv = project("wv", n_kv)
-    if cfg.rope:
+    if cfg.rope if rope is None else rope:
         q, kk = rotary_embedding(q, kk, positions, cfg.rope_theta)
     return q, kk, vv
 
 
-def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=(), layer=None):
+def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=(), layer=None,
+              router_in=None):
     """The MLP half on ``h`` [B, T, dim] (normed, cfg.dtype), before the
     residual add (and before a row-parallel caller's psum): the dense
     SwiGLU, or, where the config has experts, the dropless routed one
     (:func:`ray_tpu.ops.moe.routed_mlp`). Returns ``(y, stats)``; ``stats``
     is ``{}`` for a dense layer and the router's scalars for a routed one.
-    ``stat_axes``, and ``layer`` for experts that come as their kind's
-    whole stack: see ``routed_mlp``."""
+    ``stat_axes``, ``layer`` for experts that come as their kind's whole
+    stack, and ``router_in`` for a router that reads something else than its
+    experts do: see ``routed_mlp``."""
     cd = cfg.dtype
     if cfg.num_experts:
         from ray_tpu.ops.moe import routed_mlp
@@ -638,7 +757,8 @@ def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=(), layer=None):
                   if wide != cfg.num_experts else None),
             shared=((p["shared_up"], p["shared_down"])
                     if cfg.shared_mlp_dim else None),
-            zero_experts=cfg.zero_experts, layer=layer)
+            zero_experts=cfg.zero_experts, layer=layer,
+            router_input=router_in, act=cfg.mlp_act)
         return y.astype(cd), stats
     # the two products may be kept (KEEP_GROUPS); silu is made again
     g = jax.nn.silu(checkpoint_name(h @ p["w_gate"].astype(cd), "mlp"))
@@ -704,15 +824,16 @@ def decoder_block(cfg: LlamaConfig, x, p, positions, attend, *,
     return x + row_out(y).astype(x.dtype), stats, (k, v)
 
 
-def _attn_half(cfg: LlamaConfig, p, h, positions, attend):
+def _attn_half(cfg: LlamaConfig, p, h, positions, attend, rope=None):
     """The attention half on ``h`` [B, T, dim] (normed, cfg.dtype), before
-    the residual add: q / k / v (:func:`_qkv`), ``attend``, ``wo``. The
-    head counts are read off ``wq`` / ``wk``. Returns ``(y, k, v)``."""
+    the residual add: q / k / v (:func:`_qkv`, which takes ``rope``),
+    ``attend``, ``wo``. The head counts are read off ``wq`` / ``wk``.
+    Returns ``(y, k, v)``."""
     hd = cfg.head_dim
     B, T, _ = h.shape
     nq, nkv = p["wq"].shape[-1] // hd, p["wk"].shape[-1] // hd
     q, k, v = (checkpoint_name(a, "attn")
-               for a in _qkv(cfg, p, h, nq, nkv, positions))
+               for a in _qkv(cfg, p, h, nq, nkv, positions, rope))
     attn = attend(q, k, v).reshape(B, T, nq * hd)
     return checkpoint_name(attn @ p["wo"].astype(cfg.dtype), "attn"), k, v
 
@@ -763,18 +884,32 @@ def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
 def attend_latent_expanded(cfg: LlamaConfig, q, latent, wkv_b):
     """``_latent_half``'s ``attend`` over the call's own positions, causal
     (the full forward and prefill): every position's per-head keys and
-    values are made, ``c wkv_b``. Queries go in blocks of
-    ``LATENT_QUERY_BLOCK`` (``lax.map``), each over the key blocks up to
-    its own (a ``lax.scan`` whose later steps are skipped), the softmax
-    carried across key blocks in float32 (running maximum, sum and weighted
-    values), so the scores alive are ``[heads, block, block]``."""
-    cd, f32 = cfg.dtype, jnp.float32
+    values are made, ``c wkv_b``, and attended in tiles
+    (:func:`attend_tiles`)."""
     B, T, H, D = q.shape
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     kv = (latent[..., :r] @ wkv_b).reshape(B, T, H, -1)
     k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
         latent[:, :, None, r:], (B, T, H, D - dn))], axis=-1)
-    v = kv[..., dn:]
+    return attend_tiles(q, k, kv[..., dn:], cfg.dtype)
+
+
+def attend_tiles(q, k, v, cd, window: int = 0):
+    """Causal attention over the call's own positions without the ``[heads,
+    T, T]`` scores: ``q`` [B, T, H, D], ``k`` / ``v`` [B, T, Hkv, D / Dv]
+    (``Hkv`` divides ``H``: a tile's keys and values are repeated for GQA),
+    products in ``cd``, scores and softmax float32. Queries go in blocks of
+    ``LATENT_QUERY_BLOCK`` (``lax.map``), each over the key blocks it may
+    see (a ``lax.scan`` whose other steps are skipped), the softmax carried
+    across key blocks in float32 (running maximum, sum and weighted
+    values), so the scores alive are ``[heads, block, block]``. With a
+    ``window`` position ``i`` sees ``j <= i`` with ``j > i - window``: the
+    key blocks wholly behind the band are skipped as those ahead of the
+    query block are, so a window layer's work follows the band's area and
+    not the triangle's."""
+    f32 = jnp.float32
+    B, T, H, D = q.shape
+    rep = H // k.shape[2]
     block = math.gcd(T, LATENT_QUERY_BLOCK)
     at = jnp.arange(block, dtype=jnp.int32)
     numbers = jnp.arange(T // block, dtype=jnp.int32)
@@ -788,11 +923,18 @@ def attend_latent_expanded(cfg: LlamaConfig, q, latent, wkv_b):
         def key_block(carry, j):
             def seen(carry):
                 k_b, v_b = cut(k, j), cut(v, j)
+                if rep > 1:
+                    k_b, v_b = (jnp.repeat(a, rep, axis=2)
+                                for a in (k_b, v_b))
                 top, total, acc = carry
                 s = jnp.einsum("bqhd,bkhd->bhqk", q_b, k_b,
                                preferred_element_type=f32) / math.sqrt(D)
-                s = jnp.where((i * block + at)[:, None]
-                              >= (j * block + at)[None, :], s, -1e30)
+                at_q, at_k = (i * block + at)[:, None], \
+                    (j * block + at)[None, :]
+                visible = at_q >= at_k
+                if window:
+                    visible &= at_q - at_k < window
+                s = jnp.where(visible, s, -1e30)
                 new_top = jnp.maximum(top, s.max(axis=-1))
                 probs = jnp.exp(s - new_top[..., None])
                 old = jnp.exp(top - new_top)
@@ -801,8 +943,14 @@ def attend_latent_expanded(cfg: LlamaConfig, q, latent, wkv_b):
                     preferred_element_type=f32)
                 return new_top, total * old + probs.sum(axis=-1), acc
 
-            # key blocks behind the query block hold nothing it may see
-            return jax.lax.cond(j <= i, seen, lambda c: c, carry), None
+            # key blocks ahead of the query block hold nothing it may
+            # see, nor do those wholly behind its first query's window (a
+            # row whose keys in a visited block are all masked carries
+            # exp(0) sums until its first real score rescales them by 0)
+            near = j <= i
+            if window:
+                near &= j >= (i * block - window + 1) // block
+            return jax.lax.cond(near, seen, lambda c: c, carry), None
 
         start = (jnp.full((B, H, block), -1e30, f32),
                  jnp.zeros((B, H, block), f32),
@@ -876,10 +1024,67 @@ def shortcut_layer(cfg: LlamaConfig, x, layers, i, positions, attend,
     return b1 + m.astype(x.dtype), stats, jnp.stack(latents)
 
 
+def window_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
+                 attend, stat_axes=()):
+    """THE whole block of the kinds ``"F"`` and ``"W"``, for the full
+    forward and for the serving programs::
+
+        a   = N(x)
+        h   = x + Attention(a)        # "W": rotated, over the last
+                                      # cfg.window positions; "F": not
+                                      # rotated, over every earlier one
+        out = h + MoE(N(h); router reads a)
+
+    The ROUTER reads ``a``, the attention's input, and its experts
+    ``N(h)`` (``routed_mlp``'s ``router_input``). ``layers``: the ``block``
+    stack ``[L, ...]`` both kinds share, ``i``: which layer (a number, or
+    traced in a scan). The attention's matrices, the norms and the router
+    are cut out ``[i]`` where they are used; the experts go down whole with
+    ``layer=i`` (:func:`shortcut_layer` says why). The stream keeps the
+    type it comes in (the serving programs carry it in float32: a top-k
+    choice is a hard one, and a stream rounded to ``cfg.dtype`` at every
+    layer moves the last of a token's choices often enough to show in the
+    logits; my chip runs, PR 38), every product runs in ``cfg.dtype``.
+    ``attend(q, k, v)`` as :func:`decoder_block` takes it: what this layer
+    attends over, under the device scope ``attn.window`` / ``attn.full``.
+    Returns ``(x, stats, (k, v))``: ``k`` rotated for ``"W"`` and as
+    projected for ``"F"``."""
+    cd = cfg.dtype
+    scope = "attn.window" if kind == "W" else "attn.full"
+
+    def scoped(q, k, v):
+        with jax.named_scope(scope):
+            return attend(q, k, v)
+
+    # the router reads the norm's output as the stream's type gives it (the
+    # engine carries the stream in float32), the products its rounding
+    a = rms_norm(x, layers["attn_norm"][i], cfg.norm_eps)
+    y, k, v = _attn_half(
+        cfg, {w: layers[w][i] for w in ("wq", "wk", "wv", "wo")},
+        a.astype(cd), positions, scoped, rope=kind == "W")
+    h = x + y.astype(x.dtype)
+    m = rms_norm(h, layers["mlp_norm"][i], cfg.norm_eps).astype(cd)
+    y, stats = _mlp_half(
+        cfg, {"router": layers["router"][i],
+              **{w: layers[w] for w in ("w_gate", "w_up", "w_down")}},
+        m, stat_axes, layer=i, router_in=a)
+    return h + y.astype(x.dtype), stats, (k, v)
+
+
+def attend_window_tiles(cfg: LlamaConfig, kind: str, q, k, v):
+    """:func:`window_block`'s ``attend`` over the call's own positions (the
+    full forward and prefill): the tile loop, with the band for ``"W"``."""
+    return attend_tiles(q, k, v, cfg.dtype,
+                        window=cfg.window if kind == "W" else 0)
+
+
 def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     """One layer of a patterned stack (``cfg.layer_pattern``). ``"S"`` is
     the double layer (:func:`shortcut_layer`, its latent attention
-    expanded: :func:`attend_latent_expanded`). Every other kind is a half of
+    expanded: :func:`attend_latent_expanded`), ``"F"`` / ``"W"`` the whole
+    block with full or windowed attention (:func:`window_block`, in XLA
+    tiles whatever ``attend`` is: the flash kernel has no window). Every
+    other kind is a half of
     the block: ``x + f(RMSNorm(x))`` with ``f`` the Mamba-2 mixer
     (``"M"``, :func:`ray_tpu.ops.ssm.mamba2_mixer`), the routed
     feed-forward (``"E"``, :func:`_mlp_half`) or attention (``"*"``,
@@ -891,6 +1096,11 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
             cfg, x, jax.tree.map(lambda a: a[None], p), 0,
             positions_of(*x.shape[:2]),
             lambda j, *a: attend_latent_expanded(cfg, *a), stat_axes)[:2]
+    if kind in BLOCK_KINDS:
+        return window_block(
+            cfg, kind, x, jax.tree.map(lambda a: a[None], p), 0,
+            positions_of(*x.shape[:2]),
+            partial(attend_window_tiles, cfg, kind), stat_axes)[:2]
     h = rms_norm(x, p["norm"], cfg.norm_eps).astype(cfg.dtype)
     stats = {}
     if kind == "M":
@@ -910,18 +1120,19 @@ def pattern_stack(cfg: LlamaConfig, x, layers, attend, stat_axes=(),
                   policy=None):
     """The residual stream through a patterned stack in the pattern's
     order: layer ``i`` of its kind reads row ``i`` of that kind's stacked
-    weights ``layers[kind name]``; every layer is rematerialised where
+    weights ``layers[kind name]`` (kinds that share a stack count
+    together); every layer is rematerialised where
     ``cfg.remat``, but for what ``policy`` keeps (:func:`keep_policy`; only
     an attention layer has names). Returns ``(x, stats)``, the routed
     layers' stats stacked ``[n_E]`` a leaf (``{}`` with no routed layer)."""
-    met = dict.fromkeys(LAYER_KINDS, 0)
+    met = dict.fromkeys(LAYER_KINDS.values(), 0)
     stats = []
     for kind in cfg.kinds:
         fn = partial(pattern_layer, cfg, kind, attend, stat_axes=stat_axes)
         if cfg.remat:
             fn = jax.checkpoint(fn, policy=policy)
-        row = met[kind]
-        met[kind] += 1
+        row = met[LAYER_KINDS[kind]]
+        met[LAYER_KINDS[kind]] += 1
         x, st = fn(x, jax.tree.map(lambda a: a[row],
                                    layers[LAYER_KINDS[kind]]))
         if st:
@@ -1115,6 +1326,15 @@ def _dense_only(cfg: LlamaConfig, who: str, why: str) -> None:
             f"yet: {why}")
 
 
+def _no_window_kinds(cfg: LlamaConfig, who: str, why: str) -> None:
+    """A path whose attention cannot band refuses the ``"F"`` / ``"W"``
+    kinds by name, as :func:`_dense_only` refuses what it lacks."""
+    if set(cfg.kinds) & set(BLOCK_KINDS):
+        raise NotImplementedError(
+            f"{who} takes no 'F' / 'W' layer (layer_pattern="
+            f"{cfg.layer_pattern!r}, window={cfg.window}) yet: {why}")
+
+
 # --------------------------------------------------------------------------- #
 # Generative decode (paged KV cache — serve/kv_cache.py owns the pages)
 # --------------------------------------------------------------------------- #
@@ -1167,7 +1387,16 @@ def page_rows(cfg: LlamaConfig):
     block (``"kv"``) keeps per-head keys and values, a store each over its
     layers; the double layer (``"latent"``) keeps ONE store of latent rows
     over its ``2 * n_layers`` attention sublayers, from which decode never
-    expands keys or values."""
+    expands keys or values. A stack of full and window layers
+    (``"window"``) keeps per-head keys and values in TWO pairs of stores:
+    the full layers' pair, indexed by pool page id as the others are, and
+    the window layers' pair, whose second dimension counts SLOTS and not
+    pages (the engine says how many and maps a page to its slot): a window
+    layer needs a position for ``cfg.window`` positions and no longer."""
+    if set(cfg.kinds) & set(BLOCK_KINDS):
+        row = (cfg.n_kv_heads, cfg.head_dim)
+        return "window", [(cfg.kinds.count("F"), row)] * 2 \
+            + [(cfg.kinds.count("W"), row)] * 2
     if cfg.layer_pattern:
         return "latent", [(2 * cfg.n_layers, (cfg.latent_row,))]
     return "kv", [(cfg.n_layers, (cfg.n_kv_heads, cfg.head_dim))] * 2
@@ -1195,13 +1424,84 @@ def _latent_layers(cfg: LlamaConfig, x, layers, positions, attend, cache):
     return x, rows.reshape(-1, *rows.shape[2:]), shares
 
 
+def _period(kinds: str) -> Tuple[str, int]:
+    """``(unit, times)``: the shortest ``unit`` whose repetition ``kinds``
+    is a prefix of, and how many whole units ``kinds`` holds."""
+    for n in range(1, len(kinds) + 1):
+        if all(kind == kinds[i % n] for i, kind in enumerate(kinds)):
+            return kinds[:n], len(kinds) // n
+    return "", 0
+
+
+def _block_layers(cfg: LlamaConfig, x, layers, positions, attend, cache,
+                  keep: Dict[str, int]):
+    """The served ``"F"`` / ``"W"`` layers (:func:`window_block`): the
+    pattern's whole periods scanned (one compiled body of a period's
+    layers; the scan carries the period's number, not its weights), the
+    layers of a period cut short, or of a stack of one period, in line.
+    Layer ``l`` of kind ``c`` attends through ``attend(c, cached, q, k,
+    v)``; ``cache``: ``{c: (keys, values)}`` with a leading dimension over
+    the layers of kind ``c`` (or None: nothing cached, and ``cached`` is
+    None). Returns ``(x, {c: (keys, values)})``: each layer's new keys and
+    values ``[n_c, B, keep[c], n_kv, head_dim]``, the LAST ``keep[c]``
+    positions of the call."""
+    kinds = cfg.kinds
+    unit, times = _period(kinds)
+    if times < 2:
+        unit, times = kinds, 0
+    per = {c: unit.count(c) for c in BLOCK_KINDS if c in kinds}
+    block = layers["block"]
+
+    def run(x, some, first, cached):
+        """Layers ``first ..`` of kinds ``some`` in line; ``cached``:
+        ``{c: (keys, values)}`` over THESE layers of kind ``c``."""
+        met, rows = dict.fromkeys(per, 0), {c: [] for c in per}
+        for j, c in enumerate(some):
+            mine = None if cached is None else tuple(
+                a[met[c]] for a in cached[c])
+            met[c] += 1
+            x, _, kv = window_block(cfg, c, x, block, first + j, positions,
+                                    partial(attend, c, mine))
+            rows[c].append(tuple(a[:, -keep[c]:] for a in kv))
+        return x, {c: tuple(jnp.stack(a) for a in zip(*rows[c]))
+                   for c in per if rows[c]}
+
+    done = {c: times * per[c] for c in per}
+    new = {c: [] for c in per}
+    if times:
+        def body(x, xs):
+            number, cached = xs
+            return run(x, unit, number * len(unit), cached)
+
+        scanned = None if cache is None else {
+            c: tuple(a[:done[c]].reshape(times, per[c], *a.shape[1:])
+                     for a in cache[c]) for c in per}
+        x, rows = jax.lax.scan(
+            body, x, (jnp.arange(times, dtype=jnp.int32), scanned))
+        for c in per:
+            new[c].append(tuple(a.reshape(-1, *a.shape[2:])
+                                for a in rows[c]))
+    rest = kinds[times * len(unit):]
+    if rest:
+        x, rows = run(x, rest, times * len(unit), None if cache is None else {
+            c: tuple(a[done[c]:] for a in cache[c]) for c in per})
+        for c in rows:
+            new[c].append(rows[c])
+    return x, {c: tuple(jnp.concatenate(a) if len(a) > 1 else a[0]
+                        for a in zip(*new[c])) for c in per}
+
+
 def prefill_with_cache(cfg: LlamaConfig, params, *args):
     """Prefill one sequence into its pages, inside the program.
 
-    ``args``: ``*stores, tokens, page_ids, last``. ``stores``: the engine's
+    ``args``: ``*stores, tokens, page_ids, last`` and, for a stack with
+    window layers, ``slot_ids`` [min(n, k)] int32 behind them: the slots of
+    the LAST pages, the only ones whose window rows are written (``k``:
+    ``cfg.window_pages``). ``stores``: the engine's
     page stores (:func:`page_rows`: ``k_pages, v_pages`` ``[L, n_pages,
     page_size, n_kv, head_dim]`` for the block, ``latent_pages`` alone for
-    the double layer; donated by the engine, updated in place); ``tokens``
+    the double layer, the full layers' pair and the window layers' pair
+    for a stack of both; donated by the engine, updated in place); ``tokens``
     [1, n * page_size] int32, right-padded (causal masking keeps pad
     garbage out of real positions); ``page_ids`` [n] int32; ``last`` int32
     scalar, the last real position. Returns ``(*stores, logits [vocab]
@@ -1212,11 +1512,22 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
     itself overwrites them), and the head is applied to position ``last``
     alone. ``shares``: a routed model's assignment shares (``{}`` for a
     dense one)."""
+    kind, _ = page_rows(cfg)
+    if kind == "window":
+        *args, slot_ids = args
     *stores, tokens, page_ids, last = args
     x = embed_tokens(cfg, params, tokens, None)
     positions = positions_of(*tokens.shape)
     shares = {}
-    if cfg.layer_pattern:
+    if kind == "window":
+        ps = stores[0].shape[2]
+        x, rows = _block_layers(
+            cfg, x.astype(jnp.float32), params["layers"], positions,
+            lambda c, cached, *a: attend_window_tiles(cfg, c, *a), None,
+            {"F": tokens.shape[1], "W": slot_ids.shape[0] * ps})
+        new, page_ids = rows["F"] + rows["W"], (page_ids,) * 2 \
+            + (slot_ids,) * 2
+    elif cfg.layer_pattern:
         # keys and values expanded, attended in tiles
         x, rows, shares = _latent_layers(
             cfg, x, params["layers"], positions,
@@ -1234,19 +1545,24 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
             return h, kv
 
         x, new = jax.lax.scan(body, x, params["layers"])
-    stores = [_write_pages(pages, rows[:, 0], page_ids)
-              for pages, rows in zip(stores, new)]
+    if kind != "window":
+        page_ids = (page_ids,) * len(stores)
+    stores = [_write_pages(pages, rows[:, 0], ids)
+              for pages, rows, ids in zip(stores, new, page_ids)]
     # final_norm and the head are per position: one row, not T
     x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
     logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
     return (*stores, logits[0, 0], shares)
 
 
-def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv):
+def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv,
+                   lowest=None):
     """One new token (``q``, ``kk``, ``vv``: the block's ``attend``
     arguments) against ONE layer's gathered, page-padded keys and values
     ``[Tpad, n_kv, head_dim]``: positions >= ``length`` are pad garbage and
-    masked; the token attends to the history and itself. Float32 scores."""
+    masked, and so are, for a window layer, those below ``lowest`` (both
+    count the view's rows); the token attends to the history and itself.
+    Float32 scores."""
     cd = cfg.dtype
     Tpad = k_cache.shape[0]
     K = jnp.concatenate([k_cache.astype(cd)[None], kk], axis=1)
@@ -1257,6 +1573,8 @@ def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv):
                    K.astype(jnp.float32)) * scale
     idx = jnp.arange(Tpad + 1)
     valid = (idx < length) | (idx == Tpad)  # history + the token itself
+    if lowest is not None:
+        valid &= idx >= lowest
     s = jnp.where(valid[None, None, None, :], s, -1e30)
     probs = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs,
@@ -1291,20 +1609,45 @@ def _attend_latent_cached(cfg: LlamaConfig, cache, length, q, latent, wkv_b):
 def decode_step_with_cache(cfg: LlamaConfig, params, *args):
     """One decode step of one sequence against the page stores.
 
-    ``args``: ``*stores, token, pos, page_ids``. ``token`` [1] int32; ``pos`` int32 scalar (the write position = tokens
-    so far); ``page_ids`` [n] int32, the sequence's page table in order.
+    ``args``: ``*stores, token, pos, page_ids`` and, for a stack with
+    window layers, ``slot_ids, first`` behind them. ``token`` [1] int32;
+    ``pos`` int32 scalar (the write position = tokens so far);
+    ``page_ids`` [n] int32, the sequence's page table in order;
+    ``slot_ids`` [min(n, k)] int32: the slots of the table's pages ``first
+    ..`` (``first`` int32 scalar), which hold every position ``pos``'s
+    window reaches and ``pos`` itself: a window layer gathers those alone,
+    masks by true position (``pos - window < j <= pos``) and writes the new
+    position's keys and values into its page's slot.
     The table's pages are gathered on the device into the ``[S, n *
     page_size, *row]`` view of each store (positions >= ``pos`` are
     masked), and the new position's keys and values (its latent rows) are
     written at ``(page_ids[pos // page_size], pos % page_size)``. Returns
     ``(*stores, logits [vocab] fp32)``. ``pos`` and the page ids are
     traced, so one compilation covers every step at a given page count."""
+    kind, _ = page_rows(cfg)
+    if kind == "window":
+        *args, slot_ids, first = args
     *stores, token, pos, page_ids = args
     ps = stores[0].shape[2]
-    cached = [_read_pages(pages, page_ids) for pages in stores]
+    tables = ((page_ids,) * 2 + (slot_ids,) * 2 if kind == "window"
+              else (page_ids,) * len(stores))
+    cached = [_read_pages(pages, ids) for pages, ids in zip(stores, tables)]
     x = embed_tokens(cfg, params, token[None, :], None)
     positions = jnp.full((1, 1), pos, dtype=jnp.int32)
-    if cfg.layer_pattern:
+    if kind == "window":
+        base = first * ps  # the position of the window view's first row
+
+        def attend(c, mine, *a):
+            if c == "F":
+                return _attend_cached(cfg, *mine, pos, *a)
+            return _attend_cached(cfg, *mine, pos - base, *a,
+                                  lowest=pos - cfg.window + 1 - base)
+
+        x, rows = _block_layers(
+            cfg, x.astype(jnp.float32), params["layers"], positions, attend,
+            {"F": cached[:2], "W": cached[2:]}, {"F": 1, "W": 1})
+        new = [a[:, 0] for a in rows["F"] + rows["W"]]
+    elif cfg.layer_pattern:
         x, rows, _ = _latent_layers(
             cfg, x, params["layers"], positions,
             lambda cached, j, *a: _attend_latent_cached(
@@ -1321,10 +1664,13 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
 
         x, new = jax.lax.scan(body, x, (params["layers"], *cached))
     logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
-    at = (0, page_ids[pos // ps], pos % ps)
+    at = [(0, page_ids[pos // ps], pos % ps)] * len(stores)
+    if kind == "window":
+        at[2:] = [(0, slot_ids[pos // ps - first], pos % ps)] * 2
     stores = [jax.lax.dynamic_update_slice(
         pages, rows[:, :, None].astype(pages.dtype),
-        at + (0,) * (pages.ndim - 3)) for pages, rows in zip(stores, new)]
+        where + (0,) * (pages.ndim - 3))
+        for pages, rows, where in zip(stores, new, at)]
     return (*stores, logits[0, 0])
 
 
@@ -1383,11 +1729,13 @@ class LlamaDecodeEngine:
     engine protocol :class:`ray_tpu.serve.decode.DecodeScheduler` drives
     (prefill/decode/copy_page + pool/prefix_cache/page_size).
 
-    It serves a stack of dense blocks and a stack of shortcut-connected
+    It serves a stack of dense blocks, a stack of shortcut-connected
     double layers (``layer_pattern`` all ``"S"``: latent attention, routed
-    and identity experts); a Mamba layer's state has no page kind yet, and
-    no test holds QK-norm or the routed block to a reference here, so those
-    are refused.
+    and identity experts) and a stack of whole routed blocks with full and
+    window attention (``"F"`` and ``"W"``, both present: window_block); a
+    Mamba layer's state has no page kind yet, and no test holds QK-norm,
+    the ``"E"`` / ``"*"`` halves or the UNPATTERNED routed block to a
+    reference here, so those are refused.
 
     ``params`` is the tree the programs run on: ``init_params``' layout
     with the matmul weights (``embedding``, ``lm_head``, ``wq`` .. ``w_down``,
@@ -1407,7 +1755,11 @@ class LlamaDecodeEngine:
     double layer: ONE store of latent rows ``[2 L, n_pages, page_size,
     kv_lora_rank + qk_rope_head_dim]``, a row an attention sublayer, from
     which prefill expands keys and values and decode never does
-    (``ray_tpu_serve_engine_page_bytes{kind}``: a position's bytes). They
+    (``ray_tpu_serve_engine_page_bytes{kind}``: a position's bytes). A
+    stack of full and window layers: the full layers' keys and values
+    ``[L_full, n_pages, page_size, n_kv, head_dim]`` by page id, and the
+    window layers' ``[L_window, n_slots, page_size, n_kv, head_dim]`` by
+    SLOT (below). They
     are read and written only inside three jitted programs that take them
     donated and return them: prefill writes the scan's rows into the pages
     it is given, decode gathers the sequence's page table into a
@@ -1415,6 +1767,38 @@ class LlamaDecodeEngine:
     compilation per page count serves every sequence and step) and writes
     the new position, copy_page duplicates one page. A call moves token ids and page ids in and one ``[vocab]``
     row of float32 logits out.
+
+    WINDOW SLOTS. A window layer needs a position's keys and values for
+    ``cfg.window`` positions and no longer, so its stores have ``n_slots``
+    page slabs, fewer than the pool has pages, and the engine owns the map
+    pool page -> slot. With ``k = cfg.window_pages(page_size)`` (the pages a
+    window can touch) and ``P = ceil(max_seq_len / page_size)`` (the longest
+    sequence's pages)::
+
+        n_slots = min(n_pages, ceil(n_pages / P) * (k + 2))
+
+    each of the longest sequences the pool can hold keeps ``k`` pages of
+    window, one copied tail page and one page opened by decode. That sizes
+    the stores for LONG sequences; short ones need a slot for every page
+    (16 of five pages fill 80 pages and would need 80 slots), so slots and
+    not pages may bound how many run: ``DecodeScheduler`` admits a sequence
+    only while ``window_slots_needed`` of every running one and its own fit
+    in ``n_slots``, and a decode call that opens a page always finds its
+    slot. A page gets
+    its slot when a window layer first touches it: prefill of ``n`` pages
+    writes window rows for the LAST ``min(n, k)`` pages alone (earlier
+    pages of a long prompt never get a slot), decode gathers the slots of
+    the ``min(n, k)`` pages that end at the position's own (a page no
+    prefill wrote gets its slot then), ``copy_page`` gives the copy a slot
+    where the source has one. A slot is freed when the pool frees its page
+    (``PagePool.release_hooks``). Short of slots the engine evicts idle
+    prefixes as the pool does under page pressure; a call that still finds
+    none raises ``WindowSlotsOOM`` (a ``CacheOOM``) with nothing assigned,
+    and ``DecodeScheduler`` keeps the prefill queued. A whole-prompt prefix
+    hit stays valid: the pages its window layers need are the last ``k``,
+    which hold their slots as long as the entry holds its pages. Slots are
+    NOT freed behind a long decode as it advances.
+    ``ray_tpu_serve_engine_window_slots{state}`` reports total and used.
 
     One caller at a time (the scheduler's lock covers a whole iteration):
     a call hands the stores to its program and takes the returned ones."""
@@ -1426,13 +1810,15 @@ class LlamaDecodeEngine:
         from ray_tpu.serve.kv_cache import PagePool, PrefixCache
 
         self.cfg = cfg or LlamaConfig.debug()
-        if set(self.cfg.kinds) != {"S"}:
+        if set(self.cfg.kinds) not in ({"S"}, set(BLOCK_KINDS)):
             _dense_only(
                 self.cfg, "LlamaDecodeEngine",
-                "of the patterned kinds it serves 'S' alone (a Mamba "
-                "layer's state has no page kind), and for QK-norm or a "
-                "routed block no test compares its logits with the "
-                "reference")
+                "of the patterned kinds it serves all-'S' and 'F' with 'W' "
+                "(a Mamba layer's state has no page kind, and its stores "
+                "are the double layer's or the two pairs of a stack that "
+                "has both full and window layers), and for QK-norm, the "
+                "'E' / '*' halves or an unpatterned routed block no test "
+                "compares its logits with the reference")
         if params is None:
             # one jitted program, not a dozen eager ones: at 664.6M
             # parameters the eager form spends 67 s on a v5e, nearly all of
@@ -1457,19 +1843,41 @@ class LlamaDecodeEngine:
         self.prefix_cache = PrefixCache(self.pool)
         self._np = np
         kind, rows = page_rows(self.cfg)
+        # a window store's slabs (the class docstring's rule); the slots'
+        # map is one caller's at a time, as the stores are
+        self.window_pages = self.n_slots = 0
+        if kind == "window":
+            self.window_pages = self.cfg.window_pages(page_size)
+            longest = -(-self.cfg.max_seq_len // page_size)
+            self.n_slots = min(n_pages, -(-n_pages // longest)
+                               * (self.window_pages + 2))
+        self._slot_of: Dict[int, int] = {}
+        self._free_slots = list(range(self.n_slots - 1, -1, -1))
+        if self.n_slots:
+            self.pool.release_hooks.append(self._free_slots_of)
+        slabs = [n_pages] * len(rows)
+        if kind == "window":
+            slabs[2:] = [self.n_slots] * 2
         self.stores = tuple(
-            jnp.zeros((S, n_pages, page_size, *row), jnp.float32)
-            for S, row in rows)
-        # both tags always, as above
-        page_bytes = {"kv": 0, "latent": 0,
-                      kind: sum(4 * S * math.prod(row) for S, row in rows)}
+            jnp.zeros((S, n, page_size, *row), jnp.float32)
+            for (S, row), n in zip(rows, slabs))
+        # every tag always, as above
+        page_bytes = {"kv": 0, "latent": 0, "full": 0, "window": 0}
+        per_store = [4 * S * math.prod(row) for S, row in rows]
+        if kind == "window":
+            page_bytes.update(full=sum(per_store[:2]),
+                              window=sum(per_store[2:]))
+        else:
+            page_bytes[kind] = sum(per_store)
         for tag, nbytes in page_bytes.items():
             _g_engine_page_bytes.set(float(nbytes), tags={"kind": tag})
+        self._note_slots()
         groups = {"program": 0, "layer": 0}
-        if self.cfg.num_experts:  # of the routed kinds it serves "S" alone
+        if self.cfg.num_experts:  # the routed kinds it serves
             from ray_tpu.ops.moe import expert_groups
 
-            w_up = self.params["layers"]["scmoe"]["w_up"]
+            w_up = self.params["layers"][
+                "block" if kind == "window" else "scmoe"]["w_up"]
             groups = {"program": expert_groups(w_up, self.cfg.dtype),
                       "layer": w_up.shape[1]}
         for part, n in groups.items():
@@ -1484,8 +1892,8 @@ class LlamaDecodeEngine:
                     donate_argnums=donated),
             "llama.decode")
         self._copy_fn = observe_compiled(
-            jax.jit(copy_page_in_stores,
-                    donate_argnums=tuple(range(len(rows)))),
+            jax.jit(copy_page_in_stores,  # a window stack's: a pair a call
+                    donate_argnums=tuple(range(min(len(rows), 2)))),
             "llama.copy_page")
         self.prefill_calls = 0
         self.decode_calls = 0
@@ -1493,6 +1901,61 @@ class LlamaDecodeEngine:
         # compiled here: a server warms prefill and decode by running them,
         # but may never copy a page before its first prefix hit
         self.copy_page(0, 0)
+        if self.n_slots:  # and a window slab's copy, a shape of its own
+            self.stores = self.stores[:2] + self._copy_fn(
+                *self.stores[2:], np.asarray(0, np.int32),
+                np.asarray(0, np.int32))
+
+    # ---- window slots (a stack with window layers; else n_slots is 0)
+
+    def window_slots_needed(self, n_prompt: int, max_tokens: int) -> int:
+        """The most slots one sequence can hold at once: its prompt's last
+        ``k`` pages (counted again for every sequence that shares a prefix
+        entry's), a copied tail page, and every page its ``max_tokens``
+        open, since none is freed behind a decode. What
+        ``DecodeScheduler`` counts at admission; 0 without window layers."""
+        if not self.n_slots:
+            return 0
+        ps = self.page_size
+        prompt = -(-n_prompt // ps)
+        return (min(prompt, self.window_pages) + (1 if n_prompt % ps else 0)
+                + -(-(n_prompt + max_tokens) // ps) - prompt)
+
+    def _note_slots(self) -> None:
+        for state, n in (("total", self.n_slots),
+                         ("used", len(self._slot_of))):
+            _g_engine_window_slots.set(float(n), tags={"state": state})
+
+    def _free_slots_of(self, pages) -> None:
+        """``PagePool.release``'s hook: the freed pages' slots are free."""
+        for page in pages:
+            slot = self._slot_of.pop(page, None)
+            if slot is not None:
+                self._free_slots.append(slot)
+        self._note_slots()
+
+    def _slots_for(self, pages):
+        """The slots of ``pages``, in order, as the ``int32`` table a
+        program takes; a page without one is assigned one now. All or
+        nothing: short of slots, idle prefixes are evicted, and if that
+        does not free enough ``WindowSlotsOOM`` is raised with no slot
+        assigned."""
+        from ray_tpu.serve.kv_cache import WindowSlotsOOM
+
+        new = [p for p in pages if p not in self._slot_of]
+        if len(new) > len(self._free_slots):
+            self.prefix_cache.evict_lru(
+                enough=lambda: len(self._free_slots) >= len(new))
+            if len(new) > len(self._free_slots):
+                raise WindowSlotsOOM(
+                    f"{len(new)} pages need a window slot and "
+                    f"{len(self._free_slots)} of {self.n_slots} are free")
+        for page in new:
+            self._slot_of[page] = self._free_slots.pop()
+        if new:
+            self._note_slots()
+        return self._np.asarray([self._slot_of[p] for p in pages],
+                                self._np.int32)
 
     def _note_bucket(self, kind: str, tpad: int) -> None:
         buckets = self._buckets[kind]
@@ -1519,11 +1982,17 @@ class LlamaDecodeEngine:
         _t = _fr.now()
         toks = np.zeros((1, tpad), np.int32)
         toks[0, :T] = tokens
+        window = ()
+        if self.n_slots:  # the last pages' slots: the only window rows kept
+            _t_slots = _fr.now()
+            window = (self._slots_for(pages[-self.window_pages:]),)
+            _sp_window_slots.end(_t_slots, n_pages)
         # the read below would wait for the results anyway: waiting here
         # puts the device's time in its own span
         *stores, logits, shares = jax.block_until_ready(self._prefill_fn(
             self.params, *self.stores, toks,
-            np.asarray(pages, np.int32), np.asarray(T - 1, np.int32)))
+            np.asarray(pages, np.int32), np.asarray(T - 1, np.int32),
+            *window))
         _sp_prefill_program.end(_t, n_pages)
         _t = _fr.now()
         # the stores were donated: only a call that returned hands back
@@ -1534,7 +2003,7 @@ class LlamaDecodeEngine:
         # a routed model's shares come with the logits: one read
         last, shares = jax.device_get((logits, shares))
         _sp_prefill_logits.end(_t, n_pages)
-        if shares:
+        if shares or self.n_slots:  # every expert here: held reads 1.0
             held = float(shares.get("held_share", 1.0))
             zero = float(shares.get("zero_share", 0.0))
             for part, share in (("held", held), ("zero", zero),
@@ -1552,11 +2021,19 @@ class LlamaDecodeEngine:
                              f"of {self.page_size}")
         self._note_bucket("decode", tpad)
         _t_call = _t = _fr.now()
+        window = ()
+        if self.n_slots:  # the pages the position's window can reach
+            _t_slots = _fr.now()
+            reach = min(n_pages, self.window_pages)
+            first = max(0, pos // self.page_size - reach + 1)
+            window = (self._slots_for(pages[first:first + reach]),
+                      np.asarray(first, np.int32))
+            _sp_window_slots.end(_t_slots, n_pages)
         # the program cannot start before its inputs are on the device,
         # and the read waits for its results: the two waits add none
         inputs = jax.block_until_ready(jax.device_put(
             (np.asarray([token], np.int32), np.asarray(pos, np.int32),
-             np.asarray(pages, np.int32))))
+             np.asarray(pages, np.int32), *window)))
         _sp_decode_upload.end(_t, n_pages)
         _t = _fr.now()
         *stores, logits = jax.block_until_ready(self._decode_fn(
@@ -1571,9 +2048,23 @@ class LlamaDecodeEngine:
 
     def copy_page(self, src: int, dst: int) -> None:
         np = self._np
+        if not self.n_slots:
+            self.stores = self._copy_fn(
+                *self.stores, np.asarray(src, np.int32),
+                np.asarray(dst, np.int32))
+            return
+        # the full layers' rows by page id; the window layers' by slot,
+        # where the source has one (the copy's is assigned first: short of
+        # slots nothing is copied)
+        slots = ()
+        if src in self._slot_of:
+            slots = tuple(np.asarray(s, np.int32)
+                          for s in self._slots_for([src, dst]))
         self.stores = self._copy_fn(
-            *self.stores, np.asarray(src, np.int32),
-            np.asarray(dst, np.int32))
+            *self.stores[:2], np.asarray(src, np.int32),
+            np.asarray(dst, np.int32)) + (
+                self._copy_fn(*self.stores[2:], *slots) if slots
+                else self.stores[2:])
 
 
 # --------------------------------------------------------------------------- #
@@ -1753,6 +2244,10 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
 
     if "pipe" not in mesh.axis_names:
         raise ValueError("mesh has no 'pipe' axis")
+    _no_window_kinds(
+        cfg, "make_pipeline_train_step",
+        "its stages run the dense block over the flash kernel, which has "
+        "no window, and pass no router's losses on")
     _dense_only(
         cfg, "make_pipeline_train_step",
         "its stages pass the residual stream alone, so a router's losses "

@@ -15,8 +15,9 @@
   the first places alone (:func:`_held_rows`: there the sum is a
   scatter-add of few rows): nothing is made for an assignment that falls
   elsewhere. The router's variants (sigmoid scores, a choice-only bias, a
-  weight scale, two-matrix ``relu2`` experts, a shared expert, identity
-  experts) are arguments, each by itself.
+  weight scale, two-matrix ``relu2`` or gated ``reglu`` experts, a shared
+  expert, identity experts, a router input of its own) are arguments, each
+  by itself.
 - :func:`moe_ffn` / :func:`top_k_routing` are the older GShard/Switch
   *dense dispatch*: one-hot ``[G, S, E, C]`` dispatch/combine tensors with a
   static capacity that DROPS tokens and always renormalises the gates. It
@@ -229,20 +230,30 @@ def _combine_bwd(res, ct):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _filled(f: int) -> bool:
+    """Whether an expert width is filled up to a multiple of 512
+    (:func:`_expert_ffn` says why): over 512 and no multiple of it, but
+    for 768, the one such width measured faster as it is."""
+    return f > 512 and f % 512 != 0 and f != 768
+
+
 def expert_groups(w_up, dtype) -> int:
     """The groups the grouped products run over for a kind's stacked
     ``w_up`` [L, count, d, f] and rows of ``dtype``: the stack's ``L *
     count`` where it is read where it lies, one layer's ``count`` where the
     layer is cut out of it (:func:`_expert_ffn` says when)."""
     L, count, _, f = w_up.shape
-    cut = w_up.dtype != dtype or (f > 512 and f % 512)
+    cut = w_up.dtype != dtype or _filled(f)
     return count if cut else L * count
 
 
-def _expert_ffn(xs, w_gate, w_up, w_down, counts, layer=None):
+def _expert_ffn(xs, w_gate, w_up, w_down, counts, layer=None,
+                act: str = "swiglu"):
     """The experts' products on rows sorted by expert (``counts`` rows an
-    expert): SwiGLU where there is a ``w_gate``, else the two-matrix
-    ``relu(x W_up) ** 2 W_down``. Compute type in, compute type out.
+    expert): where there is a ``w_gate`` the gated ``act(x W_gate) * (x
+    W_up) W_down`` (``act`` ``"swiglu"``: silu; ``"reglu"``: relu), else the
+    two-matrix ``relu(x W_up) ** 2 W_down``. Compute type in, compute type
+    out.
 
     With ``layer`` the weights are a kind's whole stacked leaves ``[L,
     count, ...]``, read where they lie: as ``L * count`` groups (the same
@@ -259,7 +270,11 @@ def _expert_ffn(xs, w_gate, w_up, w_down, counts, layer=None):
     columns (and zero rows of ``w_down``), which add nothing: on the TPU
     the grouped product of 6,144 rows in 8 groups takes 6.2 ms forward and
     16.6 with its backward at width 1856, 6.0 / 17.1 at 1920 and 3.5 / 9.0
-    at 2048 (PERF.md, PR 31). A row count that is no multiple of 8 (a
+    at 2048 (PERF.md, PR 31). 768 alone is left as it is: the product of
+    98,304 rows by 64 experts of a stack of 8 x 64 takes 4.5 ms read where
+    it lies against 6.3 cut out and filled to 1024, and a decode call's 16
+    rows 0.12 ms against 1.8 (PERF.md, PR 38); no other multiple of 256 was
+    measured, so none is exempt. A row count that is no multiple of 8 (a
     decode call's ``top_k`` = 12) is filled up with rows of no group: the
     TPU compiler makes the grouped kernel only of whole sublanes, and of
     anything else a dense product over EVERY group under a mask, which
@@ -280,7 +295,7 @@ def _expert_ffn(xs, w_gate, w_up, w_down, counts, layer=None):
         else:
             w_gate, w_up, w_down = (None if w is None else w[layer]
                                     for w in stack)
-    if f > 512 and f % 512:
+    if _filled(f):
         cols, rows = ((0, 0), (0, 0), (0, -f % 512)), ((0, 0), (0, -f % 512),
                                                        (0, 0))
         w_up, w_down = jnp.pad(w_up, cols), jnp.pad(w_down, rows)
@@ -294,13 +309,14 @@ def _expert_ffn(xs, w_gate, w_up, w_down, counts, layer=None):
     else:
         g = _grouped_dot(xs, w_gate, counts).astype(cd)
         u = _grouped_dot(xs, w_up, counts).astype(cd)
-        a = (jax.nn.silu(g.astype(jnp.float32)) * u).astype(cd)
+        gate = jax.nn.relu if act == "reglu" else jax.nn.silu
+        a = (gate(g.astype(jnp.float32)) * u).astype(cd)
     ys = _grouped_dot(a, w_down, counts).astype(cd)
     return ys[:n] if n % 8 else ys
 
 
 def _held_rows(hf, top_w, order, starts, end, weights, blocks,
-               layer=None):
+               layer=None, act: str = "swiglu"):
     """The routed sum ``[N, d]`` float32 of a layer that holds some of the
     router's experts. ``order`` [A] are the assignments sorted by held
     expert (``starts`` [count]: each one's first place), from place ``end``
@@ -338,7 +354,8 @@ def _held_rows(hf, top_w, order, starts, end, weights, blocks,
             xs = jnp.where(live[:, None], hf[token], 0)
         with jax.named_scope("moe.experts"):
             ys = _expert_ffn(xs, *weights,
-                             jnp.diff(jnp.clip(edges - lo, 0, n)), layer)
+                             jnp.diff(jnp.clip(edges - lo, 0, n)), layer,
+                             act)
         with jax.named_scope("moe.combine"):
             ys = jnp.where(live, flat_w[mine], 0.0)[:, None] * jnp.where(
                 live[:, None], ys, 0).astype(jnp.float32)
@@ -356,7 +373,8 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
                stat_axes: Sequence[str] = (),
                scoring: str = "softmax", choice_bias=None,
                scale: float = 1.0, held: Optional[Tuple[int, int]] = None,
-               shared=None, zero_experts: int = 0, layer=None
+               shared=None, zero_experts: int = 0, layer=None,
+               router_input=None, act: str = "swiglu"
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Dropless top-k routed experts. ``h`` [..., d] in the compute type.
 
@@ -375,6 +393,12 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
     - ``scale``: multiplies the weights, after ``norm_topk_prob``'s
       renormalisation (``w_j = s_j / (sum_j s_j + 1e-20) * scale``).
     - ``w_gate=None``: two-matrix experts, ``relu(x W_up) ** 2 W_down``.
+    - ``act="reglu"``: gated experts whose gate goes through relu,
+      ``relu(x W_gate) * (x W_up) W_down``, in place of SwiGLU's silu.
+    - ``router_input`` [..., d]: what the ROUTER reads where that is not
+      what its experts read (a block whose router sits before its
+      attention): logits, scores and the choice come from it, the experts'
+      rows from ``h``.
     - ``shared = (w_up [d, fs], w_down [fs, d])``: one expert of that form
       (no gate) every token runs, added to the routed sum once.
     - ``held = (first, count)``: this device holds the ``count`` experts
@@ -422,8 +446,9 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
     hf = h.reshape(-1, d)
     N = hf.shape[0]
     A = N * K
+    rf = hf if router_input is None else router_input.reshape(-1, d)
     with jax.named_scope("moe.route"):
-        logits = jnp.dot(hf.astype(jnp.float32), router_w.astype(jnp.float32),
+        logits = jnp.dot(rf.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         if scoring == "softmax":
             probs = jax.nn.softmax(logits, axis=-1)
@@ -460,13 +485,13 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
             xs = _dispatch(hf, order, inverse)
     if held is None:
         with jax.named_scope("moe.experts"):
-            ys = _expert_ffn(xs, w_gate, w_up, w_down, counts, layer)
+            ys = _expert_ffn(xs, w_gate, w_up, w_down, counts, layer, act)
         with jax.named_scope("moe.combine"):
             y = _combine(ys, top_w, order, inverse)
     else:  # a block of places is four even shares of the assignments, and
         # at least 128 places (a decode call's few are one block)
         y = _held_rows(hf, top_w, order, starts, end, (w_gate, w_up, w_down),
-                       max(1, min(E // (4 * count), A // 128)), layer)
+                       max(1, min(E // (4 * count), A // 128)), layer, act)
     if zero_experts:
         with jax.named_scope("moe.zero"):
             to_zero = top_e >= E - zero_experts

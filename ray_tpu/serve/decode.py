@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ray_tpu.serve.kv_cache import (
+    CacheOOM,
     PagePool,
     PrefixCache,
     SequenceKV,
@@ -67,10 +68,11 @@ _GAUGE_INTERVAL_S = 0.25
 
 class _Seq:
     __slots__ = ("corr", "prompt", "max_tokens", "eos", "kv", "pos",
-                 "generated", "eager", "cached_prefix", "last_chunk_ts")
+                 "generated", "eager", "cached_prefix", "last_chunk_ts",
+                 "slots")
 
     def __init__(self, corr, prompt, max_tokens, eos, kv, pos, eager,
-                 cached_prefix):
+                 cached_prefix, slots=0):
         self.corr = corr
         self.prompt = prompt
         self.max_tokens = max_tokens
@@ -81,6 +83,7 @@ class _Seq:
         self.eager = eager
         self.cached_prefix = cached_prefix
         self.last_chunk_ts: Optional[float] = None  # ITL anchor
+        self.slots = slots            # engine's window slots, whole life
 
 
 def parse_decode_request(value) -> dict:
@@ -133,6 +136,12 @@ class DecodeScheduler:
         self.retired: List[Tuple[object, int]] = []
         self.steps = 0
         self.admitted = 0
+        # an engine whose window layers keep their rows in fewer slabs than
+        # the pool has pages (LlamaDecodeEngine): how many, and what one
+        # sequence can hold of them
+        self._slots_total: int = getattr(engine, "n_slots", 0)
+        self._slots_needed = getattr(engine, "window_slots_needed",
+                                     lambda n_prompt, max_tokens: 0)
 
     # ------------------------------------------------------------ intake
 
@@ -224,7 +233,12 @@ class DecodeScheduler:
                       admitted: List[tuple]) -> None:
         """Admit waiting prefills into the RUNNING batch, prefix-cache
         first. A prefill that cannot get pages (even after evicting idle
-        prefixes) stays queued — admission stops for this iteration so
+        prefixes), or for whose WHOLE life the engine's own room beside the
+        pages is not there (a window layer's slots, counted here over the
+        running sequences so that no decode call can find none;
+        ``CacheOOM`` out of ``engine.prefill`` or ``engine.copy_page``
+        where something else holds them), stays queued — admission stops
+        for this iteration so
         arrival order is preserved under memory pressure. ``admitted``
         collects ``(corr, t)`` of each sequence admitted, ``t`` the
         moment its first token existed."""
@@ -233,6 +247,21 @@ class DecodeScheduler:
             prompt = req["prompt"]
             key = tuple(prompt)
             n_prompt = len(prompt)
+            max_tokens = min(req["max_tokens"], self.max_tokens_cap)
+            # an engine with room of its own beside the pages says how much
+            # of it the sequence can hold over its whole life, and the
+            # running ones' is counted HERE: a decode call never finds none
+            slots = self._slots_needed(n_prompt, max_tokens)
+            if slots > self._slots_total:
+                self.waiting.popleft()
+                replies.append((corr, "error", ValueError(
+                    f"prompt of {n_prompt} tokens and {max_tokens} more can "
+                    f"never fit: needs {slots} window slots, the engine "
+                    f"has {self._slots_total}")))
+                continue
+            if slots + sum(s.slots for s in self.running.values()) \
+                    > self._slots_total:
+                break  # slot pressure: retry once a sequence has retired
             _t0 = _fr.now()
             entry = self.prefix_cache.lookup(key)
             was_hit = entry is not None
@@ -254,6 +283,9 @@ class DecodeScheduler:
                     break  # pool pressure: retry next iteration
                 try:
                     logits = self.engine.prefill(prompt, pages)
+                except CacheOOM:  # the engine's own room: retry later
+                    self.pool.release(pages)
+                    break
                 except Exception as e:  # noqa: BLE001 — fail one request
                     self.pool.release(pages)
                     self.waiting.popleft()
@@ -267,10 +299,8 @@ class DecodeScheduler:
                 break
             self.waiting.popleft()
             first = int(np.argmax(logits))
-            seq = _Seq(corr, prompt,
-                       min(req["max_tokens"], self.max_tokens_cap),
-                       req["eos"], kv, n_prompt, eager,
-                       cached_prefix=was_hit)
+            seq = _Seq(corr, prompt, max_tokens, req["eos"], kv, n_prompt,
+                       eager, cached_prefix=was_hit, slots=slots)
             seq.generated.append(first)
             self.running[corr] = seq
             self.admitted += 1
@@ -302,7 +332,11 @@ class DecodeScheduler:
             tail = self.prefix_cache.alloc_with_evict(1)
             if tail is None:
                 return None
-            self.engine.copy_page(entry.pages[n_full], tail[0])
+            try:
+                self.engine.copy_page(entry.pages[n_full], tail[0])
+            except CacheOOM:  # no room of the engine's own for the copy
+                self.pool.release(tail)
+                return None
             kv.owned.append(tail[0])
         return kv
 

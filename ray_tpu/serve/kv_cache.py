@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from ray_tpu.util.metrics import Gauge as _Gauge
 
@@ -47,9 +47,19 @@ class CacheOOM(Exception):
     every refcount-0 prefix entry."""
 
 
+class WindowSlotsOOM(CacheOOM):
+    """An engine whose window layers keep their rows in fewer slabs than
+    the pool has pages found no free slab for a page, even after evicting
+    every refcount-0 prefix entry: the call holds what it held before, and
+    the caller may retry once a sequence has retired."""
+
+
 class PagePool:
     """Fixed-size page-id allocator. Thread-safe (the replica's compiled
-    exec loop and the eager plane both allocate)."""
+    exec loop and the eager plane both allocate). An engine that keeps
+    something BESIDE a page (a window layer's slot) frees it in a hook that
+    :meth:`release` calls: every caller frees pages here, the scheduler, a
+    prefix eviction and a harness alike."""
 
     def __init__(self, n_pages: int, page_size: int):
         if n_pages < 1 or page_size < 1:
@@ -62,6 +72,7 @@ class PagePool:
         self._lock = threading.Lock()
         self.alloc_total = 0
         self.free_total = 0
+        self.release_hooks: List[Callable[[List[int]], None]] = []
 
     @property
     def used(self) -> int:
@@ -94,6 +105,8 @@ class PagePool:
                     raise ValueError(f"double free of page {p}")
                 self._free.append(p)
             self.free_total += len(pages)
+        for hook in self.release_hooks:  # outside the lock: a hook may ask
+            hook(pages)                  # the pool how much is free
 
 
 def pages_for(length: int, page_size: int) -> int:
@@ -177,17 +190,22 @@ class PrefixCache:
         with self._lock:
             entry.refs = max(0, entry.refs - 1)
 
-    def evict_lru(self, need_pages: int) -> int:
+    def evict_lru(self, need_pages: int = 0,
+                  enough: Optional[Callable[[], bool]] = None) -> int:
         """Free refcount-0 entries, LRU first, until ``need_pages`` pool
-        pages are free (or no evictable entry remains). NEVER touches a
-        referenced entry — that is the running-sequence safety rule.
-        Returns the number of entries evicted."""
+        pages are free, or until ``enough()`` where the caller is short of
+        something else that a page's release frees (or no evictable entry
+        remains). NEVER touches a referenced entry — that is the
+        running-sequence safety rule. Returns the number of entries
+        evicted."""
+        if enough is None:
+            enough = lambda: self.pool.free_count >= need_pages  # noqa: E731
         evicted = 0
         with self._lock:
             idle = sorted((e for e in self._entries.values() if e.refs == 0),
                           key=lambda e: e.stamp)
             for e in idle:
-                if self.pool.free_count >= need_pages:
+                if enough():
                     break
                 del self._entries[e.key]
                 self.pool.release(e.pages)

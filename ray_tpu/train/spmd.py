@@ -641,6 +641,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
 
     from ray_tpu.collective import pmean_tree
     from ray_tpu.models.llama import (
+        _no_window_kinds,
         _plain_chunk_nll,
         add_router_losses,
         chunked_nll_mean,
@@ -667,6 +668,12 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     if gather not in ("streamed", "upfront"):
         raise ValueError(
             f"gather must be 'streamed' or 'upfront', got {gather!r}")
+    _no_window_kinds(
+        cfg, "make_spmd_train_step",
+        "its layers attend through the flash kernel, which masks the "
+        "causal triangle and has no window (forward and backward), and "
+        "under fsdp / tensor a patterned stack has no per-kind gather; "
+        "models.llama.loss_fn runs these kinds in XLA tiles")
 
     tensor = ("tensor" if "tensor" in mesh.axis_names
               and mesh.shape["tensor"] > 1 else None)

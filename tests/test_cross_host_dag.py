@@ -46,6 +46,10 @@ class OnD1:
     def inc(self, x):
         return x + 1
 
+    def inc_late(self, x):
+        time.sleep(1.0)  # a kill sent right after execute() lands mid-flight
+        return x + 1
+
     def pid(self):
         return os.getpid()
 
@@ -155,7 +159,7 @@ def test_executor_death_cross_daemon_fails_attributed(two_daemons):
     a = OnD1.remote()
     pid = ray_tpu.get(a.pid.remote(), timeout=60)
     with InputNode() as inp:
-        out = a.inc.bind(inp)
+        out = a.inc_late.bind(inp)  # else a reply can get out before the kill
     dag = out.experimental_compile(max_inflight=2)
     assert dag.execute(1).get(timeout=60) == 2
     ref = dag.execute(2)
