@@ -29,6 +29,7 @@ here it is the in-framework model that Train, Serve and the benchmark
 from __future__ import annotations
 
 import math
+import threading
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -84,6 +85,17 @@ _g_engine_window_slots = Gauge(
     "ray_tpu_serve_engine_window_slots",
     "Page slabs of the decode engine's window stores: all of them, and "
     "those assigned to a pool page", tag_keys=("state",))
+# which way a prefill's attention went, counted where a program is traced
+# (attend_tiles): the Pallas kernel (ops/flash_prefill.py) or the XLA tile
+# loop, by the layer kind that asked. Both paths of a kind are always set,
+# the one not taken at what it has counted so far (0 in an engine's process
+# on the chip, where every compiled prefill program reads path=kernel);
+# prefill_attend_paths() keeps the reason beside the count
+_g_engine_prefill_attend = Gauge(
+    "ray_tpu_serve_engine_prefill_attend",
+    "Prefill attentions traced into the decode engine's programs (and the "
+    "full forward), by layer kind and by the path they took: the flash "
+    "kernel or XLA tiles", tag_keys=("kind", "path"))
 # the last prefill's routed assignments, averaged over its layers: on the
 # experts held here, on identity experts, and on experts held elsewhere
 # (left out). Read with the logits; a model without a router sets none
@@ -838,9 +850,12 @@ def _attn_half(cfg: LlamaConfig, p, h, positions, attend, rope=None):
     return checkpoint_name(attn @ p["wo"].astype(cfg.dtype), "attn"), k, v
 
 
-# the expanding latent attention scores a block of queries against a block of
-# keys at a time: float32 scores of [heads, this, this] (67 MB at 64 heads),
-# where [heads, T, T] would be 17 GB at 8,192 positions
+# prefill's attention goes a block of queries against a block of keys at a
+# time. In the XLA tile loop (every backend but the TPU) the float32 scores
+# alive are [heads, this, this] (67 MB at 64 heads), where [heads, T, T] would
+# be 17 GB at 8,192 positions; on the TPU one head's [this, this] stay in
+# VMEM inside the kernel (ops/flash_prefill.py picks its own block, this one
+# where it divides T)
 LATENT_QUERY_BLOCK = 512
 
 
@@ -883,32 +898,142 @@ def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
 
 def attend_latent_expanded(cfg: LlamaConfig, q, latent, wkv_b):
     """``_latent_half``'s ``attend`` over the call's own positions, causal
-    (the full forward and prefill): every position's per-head keys and
-    values are made, ``c wkv_b``, and attended in tiles
-    (:func:`attend_tiles`)."""
-    B, T, H, D = q.shape
+    (the full forward and prefill): every position's per-head ``[k_nope |
+    v]`` is made, ``c wkv_b``, and attended with :func:`attend_tiles`. The
+    ONE rotated key slice all heads share goes down as it is (``shared``):
+    the flash kernel scores it as a second operand, and only the tile loop
+    repeats it for every head."""
+    B, T, H, _ = q.shape
     r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     kv = (latent[..., :r] @ wkv_b).reshape(B, T, H, -1)
-    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-        latent[:, :, None, r:], (B, T, H, D - dn))], axis=-1)
-    return attend_tiles(q, k, kv[..., dn:], cfg.dtype)
+    return attend_tiles(q, kv[..., :dn], kv[..., dn:], cfg.dtype,
+                        shared=latent[..., r:])
 
 
-def attend_tiles(q, k, v, cd, window: int = 0):
+# every distinct prefill attention traced in this process and the way it
+# went: (kind, shapes, window, path) -> {.., "reason", "calls"}; programs are
+# traced on whatever thread first calls them
+_prefill_attend_lock = threading.Lock()
+_prefill_attend_taken: Dict[tuple, dict] = {}
+
+
+def prefill_attend_path(q, k, v, cd, shared=None) -> Tuple[str, str]:
+    """``(path, reason)`` :func:`attend_tiles` takes for these operands in
+    this process: ``"kernel"`` on a TPU backend for what
+    ``ops/flash_prefill.py`` takes (positions a multiple of one of its
+    blocks, operands all in ``cd``, key and value widths whole lanes),
+    ``"tiles"`` with what stands in the way otherwise. Read from the
+    backend and the shapes alone."""
+    platform = jax.default_backend()
+    if platform != "tpu":
+        return "tiles", f"backend is {platform!r}, not tpu"
+    from ray_tpu.ops.flash_prefill import pick_blocks
+
+    if pick_blocks(q.shape[1]) is None:
+        return "tiles", (f"{q.shape[1]} positions are no multiple of a "
+                         "block of the kernel's")
+    types = {a.dtype.name for a in (q, k, v, shared) if a is not None}
+    if types != {jnp.dtype(cd).name}:
+        return "tiles", (f"operands in {sorted(types)}, products in "
+                         f"{jnp.dtype(cd).name}")
+    if k.shape[-1] % 128 or v.shape[-1] % 128:
+        return "tiles", (f"key width {k.shape[-1]} or value width "
+                         f"{v.shape[-1]} is no multiple of 128 lanes")
+    return "kernel", "tpu backend"
+
+
+def prefill_attend_paths() -> list:
+    """Every distinct prefill attention (kind, shapes, window, path) traced
+    in this process, with its reason and how often: how a run proves which
+    attention its prefill programs hold (the trainer's kernels:
+    ``ops.flash_attention.paths_taken``)."""
+    with _prefill_attend_lock:
+        return [dict(rec) for rec in _prefill_attend_taken.values()]
+
+
+def _note_prefill_attend(kind, q, k, window, path, reason) -> None:
+    key = (kind, q.shape, k.shape, window, path)
+    with _prefill_attend_lock:
+        rec = _prefill_attend_taken.setdefault(key, {
+            "kind": kind, "q_shape": list(q.shape), "k_shape": list(k.shape),
+            "window": window, "path": path, "reason": reason, "calls": 0})
+        rec["calls"] += 1
+        counts = {way: sum(r["calls"] for r in _prefill_attend_taken.values()
+                           if (r["kind"], r["path"]) == (kind, way))
+                  for way in ("kernel", "tiles")}
+    for way, n in counts.items():
+        _g_engine_prefill_attend.set(float(n),
+                                     tags={"kind": kind, "path": way})
+
+
+def attend_tiles(q, k, v, cd, window: int = 0, shared=None):
     """Causal attention over the call's own positions without the ``[heads,
-    T, T]`` scores: ``q`` [B, T, H, D], ``k`` / ``v`` [B, T, Hkv, D / Dv]
-    (``Hkv`` divides ``H``: a tile's keys and values are repeated for GQA),
-    products in ``cd``, scores and softmax float32. Queries go in blocks of
+    T, T]`` scores: ``q`` [B, T, H, D], ``k`` / ``v`` [B, T, Hkv, Dk / Dv]
+    (``Hkv`` divides ``H``), ``shared`` [B, T, D - Dk] or None: a slice of
+    every head's key that all heads share, scored against ``q[..., Dk:]``
+    (latent attention's rotated slice). Products in ``cd``, scores and
+    softmax float32, the scale ``1 / sqrt(D)``. With a ``window`` position
+    ``i`` sees ``j <= i`` with ``j > i - window``.
+
+    Two paths, ONE arithmetic (:func:`prefill_attend_path` says which and
+    why; ``ray_tpu_serve_engine_prefill_attend{kind, path}`` and
+    :func:`prefill_attend_paths` count them where a program is traced). On
+    a TPU backend one Pallas call, forward only
+    (``ops/flash_prefill.py``, imported here and nowhere else): a head's
+    scores ``[block, block]`` and its running maximum, sum and weighted
+    values never leave VMEM, GQA and the shared slice are index maps and
+    not copies, and a window layer's loop over key blocks starts at its
+    band. Its transpose is the tile loop's (``jax.grad`` works, at the tile
+    loop's cost). On every other backend, and for what the kernel does not
+    take, the XLA tile loop (:func:`_tile_loop`): float32 scores ``[heads,
+    block, block]`` and accumulators through memory at every tile, the keys
+    and values of a tile repeated for GQA and ``shared`` repeated for every
+    head first. Both skip the key blocks wholly behind the band as those
+    ahead of the query block, so a window layer's work follows the band's
+    area and not the triangle's."""
+    kind = ("latent" if shared is not None
+            else "window" if window else "full")
+    path, reason = prefill_attend_path(q, k, v, cd, shared)
+    _note_prefill_attend(kind, q, k, window, path, reason)
+    if path == "kernel":
+        return _attend_kernel(q, k, v, shared, window, cd)
+    return _tile_loop(q, k, v, shared, window, cd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _attend_kernel(q, k, v, shared, window, cd):
+    from ray_tpu.ops.flash_prefill import flash_prefill
+
+    # interpreted where a test has steered a CPU process onto this path
+    return flash_prefill(q, k, v, shared=shared, window=window,
+                         interpret=jax.default_backend() != "tpu")
+
+
+def _attend_kernel_fwd(q, k, v, shared, window, cd):
+    return _attend_kernel(q, k, v, shared, window, cd), (q, k, v, shared)
+
+
+def _attend_kernel_bwd(window, cd, operands, g):
+    # forward only: the kernel keeps no log-sum-exp, so its transpose is the
+    # tile loop's, run again from the operands
+    return jax.vjp(lambda *a: _tile_loop(*a, window, cd), *operands)[1](g)
+
+
+_attend_kernel.defvjp(_attend_kernel_fwd, _attend_kernel_bwd)
+
+
+def _tile_loop(q, k, v, shared, window: int, cd):
+    """:func:`attend_tiles` in XLA. Queries go in blocks of
     ``LATENT_QUERY_BLOCK`` (``lax.map``), each over the key blocks it may
     see (a ``lax.scan`` whose other steps are skipped), the softmax carried
     across key blocks in float32 (running maximum, sum and weighted
-    values), so the scores alive are ``[heads, block, block]``. With a
-    ``window`` position ``i`` sees ``j <= i`` with ``j > i - window``: the
-    key blocks wholly behind the band are skipped as those ahead of the
-    query block are, so a window layer's work follows the band's area and
-    not the triangle's."""
+    values), so the scores alive are ``[heads, block, block]``."""
     f32 = jnp.float32
     B, T, H, D = q.shape
+    if shared is not None:
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            shared[:, :, None], (B, T, k.shape[2], D - k.shape[-1]))],
+            axis=-1)
     rep = H // k.shape[2]
     block = math.gcd(T, LATENT_QUERY_BLOCK)
     at = jnp.arange(block, dtype=jnp.int32)
@@ -1073,7 +1198,8 @@ def window_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
 
 def attend_window_tiles(cfg: LlamaConfig, kind: str, q, k, v):
     """:func:`window_block`'s ``attend`` over the call's own positions (the
-    full forward and prefill): the tile loop, with the band for ``"W"``."""
+    full forward and prefill): :func:`attend_tiles` (the flash kernel on a
+    TPU backend, XLA tiles elsewhere), with the band for ``"W"``."""
     return attend_tiles(q, k, v, cfg.dtype,
                         window=cfg.window if kind == "W" else 0)
 
@@ -1082,11 +1208,12 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     """One layer of a patterned stack (``cfg.layer_pattern``). ``"S"`` is
     the double layer (:func:`shortcut_layer`, its latent attention
     expanded: :func:`attend_latent_expanded`), ``"F"`` / ``"W"`` the whole
-    block with full or windowed attention (:func:`window_block`, in XLA
-    tiles whatever ``attend`` is: the flash kernel has no window). Every
-    other kind is a half of
-    the block: ``x + f(RMSNorm(x))`` with ``f`` the Mamba-2 mixer
-    (``"M"``, :func:`ray_tpu.ops.ssm.mamba2_mixer`), the routed
+    block with full or windowed attention (:func:`window_block`, through
+    :func:`attend_window_tiles` whatever ``attend`` is: the trainer's flash
+    kernel has no window; prefill's forward-only one on a TPU backend, XLA
+    tiles elsewhere). Every other kind is a half of the block: ``x +
+    f(RMSNorm(x))`` with ``f`` the Mamba-2 mixer (``"M"``,
+    :func:`ray_tpu.ops.ssm.mamba2_mixer`), the routed
     feed-forward (``"E"``, :func:`_mlp_half`) or attention (``"*"``,
     :func:`_attn_half` over ``attend``). ``p``: this layer's weights, of its
     kind. Returns ``(x, stats)``, ``stats`` ``{}`` but for ``"E"`` and
@@ -1528,7 +1655,7 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
         new, page_ids = rows["F"] + rows["W"], (page_ids,) * 2 \
             + (slot_ids,) * 2
     elif cfg.layer_pattern:
-        # keys and values expanded, attended in tiles
+        # keys and values expanded, attended by attend_tiles
         x, rows, shares = _latent_layers(
             cfg, x, params["layers"], positions,
             lambda cached, j, *a: attend_latent_expanded(cfg, *a), None)
@@ -1799,6 +1926,15 @@ class LlamaDecodeEngine:
     which hold their slots as long as the entry holds its pages. Slots are
     NOT freed behind a long decode as it advances.
     ``ray_tpu_serve_engine_window_slots{state}`` reports total and used.
+
+    PREFILL'S ATTENTION of the latent, the full and the window layers is
+    :func:`attend_tiles`: on a TPU backend one forward-only Pallas call
+    (``ops/flash_prefill.py``: a split score for the latent layers, GQA by
+    index map, a window layer's key blocks from its band on), on every other
+    backend the XLA tile loop; ``ray_tpu_serve_engine_prefill_attend{kind,
+    path}`` counts which, where a program is traced
+    (:func:`prefill_attend_paths` has the reason). The dense block's prefill
+    is ``plain_attention`` over its few short pages.
 
     One caller at a time (the scheduler's lock covers a whole iteration):
     a call hands the stores to its program and takes the returned ones."""

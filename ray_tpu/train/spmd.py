@@ -673,7 +673,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         "its layers attend through the flash kernel, which masks the "
         "causal triangle and has no window (forward and backward), and "
         "under fsdp / tensor a patterned stack has no per-kind gather; "
-        "models.llama.loss_fn runs these kinds in XLA tiles")
+        "models.llama.loss_fn runs these kinds through attend_tiles")
 
     tensor = ("tensor" if "tensor" in mesh.axis_names
               and mesh.shape["tensor"] > 1 else None)

@@ -520,6 +520,24 @@ def test_prefill_reports_where_the_assignments_fell(engine):
     assert all(0.0 < v < 1.0 for v in got.values())
 
 
+def test_prefill_says_which_way_its_attention_went(engine):
+    """On the CPU every prefill program attends in XLA tiles, and says so
+    where it is traced: the gauge by kind and path, the record with why."""
+    from ray_tpu.util.metrics import registry
+
+    engine.prefill(list(range(30)), [4, 5, 6, 7])  # four pages: a new program
+    got = {tuple(v for _, v in sorted(tags)): n for tags, n in
+           registry().local_values(
+               "ray_tpu_serve_engine_prefill_attend").items()}
+    assert got[("latent", "tiles")] >= 2.0  # a program's two sublayers
+    assert got[("latent", "kernel")] == 0.0
+    mine = [r for r in llama.prefill_attend_paths()
+            if r["q_shape"] == [1, 32, 4, 16]]
+    assert [(r["kind"], r["path"], r["reason"], r["k_shape"])
+            for r in mine] == [("latent", "tiles",
+                                "backend is 'cpu', not tpu", [1, 32, 4, 8])]
+
+
 def test_copy_page_copies_latent_rows(engine):
     engine.prefill(list(range(8)), [3])
     engine.copy_page(3, 10)

@@ -324,6 +324,26 @@ def gauge(name):
     return {k[0][1]: v for k, v in registry().local_values(name).items()}
 
 
+def test_prefill_says_which_way_each_kind_attended(engine):
+    """On the CPU a prefill program's full and window layers both attend in
+    XLA tiles, and say so where the program is traced."""
+    from ray_tpu.util.metrics import registry
+
+    pages = engine.pool.alloc(7)  # seven pages: a program of its own
+    engine.prefill(list(range(33)), pages)
+    engine.pool.release(pages)
+    got = {tuple(v for _, v in sorted(tags)): n for tags, n in
+           registry().local_values(
+               "ray_tpu_serve_engine_prefill_attend").items()}
+    for kind in ("full", "window"):
+        assert got[(kind, "tiles")] >= 1.0 and got[(kind, "kernel")] == 0.0
+    mine = {(r["kind"], r["window"]): (r["path"], r["reason"])
+            for r in llama.prefill_attend_paths()
+            if r["q_shape"] == [1, 35, 4, 16]}
+    why = ("tiles", "backend is 'cpu', not tpu")
+    assert mine == {("full", 0): why, ("window", WINDOW): why}
+
+
 @pytest.mark.parametrize("n,more", [(23, 14), (7, 17), (15, 3)])
 def test_prefill_then_decode_through_both_stores_is_the_references(
         engine, params, n, more):

@@ -121,3 +121,40 @@ def test_grouped_products_over_the_stack_read_it_where_it_lies(
     assert not made, made
     if tokens == 1:  # one such copy is 403 MB
         assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+# (positions, query heads, key/value heads, the score's width, a head's own
+# part of it, window): the serving cells' prefill attentions at their
+# longest, and at a page count whose keys are filled up to whole steps
+PREFILL = {
+    "longcat 16 pages": (8192, 64, 64, 192, 128, 0),
+    "longcat 5 pages": (2560, 64, 64, 192, 128, 0),
+    "smallthinker full 16 pages": (16384, 28, 4, 128, 128, 0),
+    "smallthinker window 16 pages": (16384, 28, 4, 128, 128, 4096),
+    "smallthinker window 5 pages": (5120, 28, 4, 128, 128, 4096),
+}
+
+
+@pytest.mark.parametrize("what", list(PREFILL))
+def test_prefill_kernel_compiles_at_the_cells_shapes(what, one_chip,
+                                                     as_on_the_chip):
+    """``attend_tiles`` on the kernel's path (``ops/flash_prefill.py``) at
+    published widths: a 128 + 64 wide score with ONE shared slice and a
+    128-wide value, 28 heads on 4, the band; a head's whole keys and values
+    in VMEM (16 MB at 16,384 positions with their second buffers), so the
+    call asks for its limit. One Mosaic call, nothing of the tile loop."""
+    from ray_tpu.models import llama
+
+    t, hq, hkv, d, dk, window = PREFILL[what]
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    shared = arg(1, t, d - dk) if d > dk else None
+    lowered = jax.jit(lambda q, k, v, shared: llama.attend_tiles(
+        q, k, v, jnp.bfloat16, window=window, shared=shared)).lower(
+            arg(1, t, hq, d), arg(1, t, hkv, dk), arg(1, t, hkv, 128), shared)
+    assert "scoped_memory_configs" in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%flash_prefill" in text and "while(" not in text
