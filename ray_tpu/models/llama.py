@@ -10,8 +10,9 @@ model definition runs dp/fsdp/tp/sp via GSPMD. Design choices for the MXU:
   ONE half of the block), of shortcut-connected double layers (latent
   attention, :func:`shortcut_layer`) or of whole routed blocks whose
   attention is full and unrotated in some layers and windowed and rotated
-  in the others (:func:`window_block`), weights stacked per kind and walked
-  in the pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
+  in the others (:func:`window_block`) or reads the keys a learned indexer
+  picks (:func:`index_block`), weights stacked per kind and walked in the
+  pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
 - bf16 matmuls with fp32 accumulation (``preferred_element_type``), params
   stored fp32, gradients/optimizer fp32;
 - ``jax.checkpoint`` per layer (remat) to trade FLOPs for HBM, its products
@@ -66,7 +67,9 @@ _g_engine_weight_bytes = Gauge(
 # block's per-head keys and values (kind=kv) or a latent sublayer's row
 # (kind=latent); of a stack of full and window layers, a position's keys and
 # values in the full layers' stores (kind=full) and a SLOT position's in the
-# window layers' (kind=window); the kinds an engine has not read 0
+# window layers' (kind=window); of a stack of indexed blocks, its keys and
+# values (kind=kv) and its index keys (kind=index); the kinds an engine has
+# not read 0
 _g_engine_page_bytes = Gauge(
     "ray_tpu_serve_engine_page_bytes",
     "Bytes one position holds in the decode engine's page store, by the "
@@ -103,6 +106,14 @@ _g_moe_assignment_share = Gauge(
     "ray_tpu_serve_moe_assignment_share",
     "Share of the last prefill's routed assignments that fell on experts "
     "held here, on identity experts, or elsewhere", tag_keys=("part",))
+
+# an indexed block attends over the keys its indexer picks: what the last
+# call attended of what it could see (1.0 while nothing is longer than
+# index_topk), counted on the host from the call's own length
+_g_engine_selected_share = Gauge(
+    "ray_tpu_serve_engine_selected_share",
+    "Keys the decode engine's indexed blocks attended over the keys "
+    "visible, last call, by program", tag_keys=("program",))
 
 # LlamaDecodeEngine's calls taken apart (one registration site per name):
 # the device program against what the host does on either side of it. The
@@ -158,7 +169,7 @@ def keep_policy(keep):
 # a patterned stack's layer kinds: the character -> the name of the kind's
 # stacked weights under params["layers"]
 LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
-               "F": "block", "W": "block"}
+               "F": "block", "W": "block", "I": "index"}
 # the kinds that are a WHOLE block (attention THEN the routed MLP, whose
 # router reads the attention's normed input; window_block): "F" attends over
 # every earlier position without rotation, "W" over the last cfg.window with
@@ -185,6 +196,33 @@ BLOCK_KINDS = "FW"
 # readings at 3, 4, 6, 8, 12 and 16). WHICH experts are chosen, and so their
 # load and every product's shape and time, is the same at any width.
 BLOCK_INIT = {"wo": 12.0, "router": 6.0}
+# The indexed block's ("I", index_block) starting scales off the square root
+# of the fan-in, for the same reason: ``wo`` starts 16 times as wide and
+# ``q_norm`` (the per-head QK-norm's gain on the query) at 0.7, not 1.
+# ``wo`` sets the halves' shares. The routed half here is the HELD experts'
+# eighth of the routed sum, and what makes a sound engine's rare large
+# difference is in it: the router's 8th and 9th logits of 128 lie 0.06
+# apart, a stream that differs by rounding moves that choice in one
+# token-layer of ten, and where a held expert is the one moved that is a
+# whole layer's routed part. At ``wo`` 1 that read 6.3% of a logit in one of
+# four sound positions, at 2 (router 4 times as wide, which weighs the moved
+# choice at 0.01) still 0.97-5.50% over 12 seeds against 8.4% for the score
+# without its ReLU; at 16 the attention is nearly all of a layer's output
+# and the sound engine reads 0.52-1.92% (19 seeds), every fault of the
+# attention or the indexer 8.3% or more. The price: leaving the router's
+# renormalisation out moves a logit by 1.5-1.9%, under any limit that
+# passes the sound engine (tests/test_keye_vl2.py holds it in float32).
+# The gain: QK-norm makes a score one unit wide times the gain whatever the
+# weights. At 1.4 some 290 of a query's 2,048 chosen keys carry its weight
+# (2,048 / exp(gain^2), for normal scores) and one key that bfloat16 index
+# scores move across the last place (3.5 of 2,048 do, a query) can be a
+# head's best; at 0.7 some 1,250 carry it, and leaving the norm out (scores
+# then one unit wide) still reads 14-15%; at 1.0 with ``wo`` 8 that read
+# 6.2-6.8%. The indexer's and the router's own weights start at the fan-in
+# (both read the NORMED stream): how sharply either chooses, the experts'
+# load and every product's shape and time depend on neither scale (my chip
+# runs, PR 40; the configuration file's ``correct`` has every reading).
+INDEX_INIT = {"wo": 16.0, "q_norm": 0.7}
 
 
 @dataclass(frozen=True)
@@ -233,8 +271,12 @@ class LlamaConfig:
     # in line, the routed experts beside the first and added at the end.
     # "F" / "W": a whole block (window_block), full unrotated or windowed
     # rotated attention, then the routed MLP whose router reads the
-    # attention's input. Empty: every layer is the block (attention THEN
-    # MLP), as every dense and every all-routed configuration has it.
+    # attention's input. "I": a whole block too (index_block): rotated
+    # attention with per-head QK-norm over the index_topk keys a learned
+    # indexer picks for each query, then the routed MLP (its router reads
+    # the MLP's own input; a held range of experts may be set). Empty:
+    # every layer is the block (attention THEN MLP), as every dense and
+    # every all-routed configuration has it.
     layer_pattern: str = ""
     window: int = 0  # a "W" layer's position i sees j <= i with j > i - window
     ssm_heads: int = 0      # H; d_inner = ssm_heads * ssm_head_dim
@@ -272,8 +314,23 @@ class LlamaConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # the "I" layer's indexer: index_heads query heads of index_head_dim on
+    # ONE key head, a weight a head; a query attends over the index_topk
+    # visible positions of largest index score (all of them while there are
+    # no more). index_chunk: the published tiling of the score, here the
+    # XLA path's query block; it changes no selection
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_chunk: int = 512
+    # the rotation's sections (temporal, height, width), in frequencies:
+    # frequency i takes its angle from the component whose section it falls
+    # in, a head with fewer frequencies than their sum has the first of them
+    # only. Empty: one position a token. Only the "I" layer reads it
+    mrope_section: Tuple[int, ...] = ()
 
     def __post_init__(self):
+        object.__setattr__(self, "mrope_section", tuple(self.mrope_section))
         bad = set(self.layer_pattern) - set(LAYER_KINDS)
         if bad or 0 < len(self.layer_pattern) < self.n_layers:
             raise ValueError(
@@ -302,6 +359,26 @@ class LlamaConfig:
                 "an 'S' layer needs its latent ranks, its three head "
                 "widths and dense_mlp_dim, and has swiglu experts and no "
                 "shared one")
+        if "I" in self.kinds and not (
+                set(self.kinds) == {"I"} and self.index_heads
+                and self.index_head_dim and self.index_head_dim % 4 == 0
+                and self.index_topk and self.num_experts
+                and self.experts_per_token and self.mlp_act != "relu2"
+                and self.rope and not self.qk_norm and not self.zero_experts
+                and not self.shared_mlp_dim
+                and self.router_scoring == "softmax"
+                and self.routed_scale == 1.0
+                and len(self.mrope_section) in (0, 3)):
+            raise ValueError(
+                "an 'I' layer is rotated attention with its own per-head "
+                "QK-norm over the keys an indexer picks (index_heads, "
+                "index_head_dim a multiple of 4, index_topk; mrope_section "
+                "empty or three sections), then a softmax-routed gated MLP "
+                "(num_experts, experts_per_token; a held range may be set): "
+                "every built layer is 'I', and it has no whole-projection "
+                "qk_norm, identity or shared expert, or weight scale")
+        if self.mrope_section and "I" not in self.kinds:
+            raise ValueError("only an 'I' layer reads mrope_section")
         blocks = set(self.kinds) & set(BLOCK_KINDS)
         if ("W" in self.kinds) != bool(self.window) or self.window < 0:
             raise ValueError(
@@ -343,8 +420,27 @@ class LlamaConfig:
     @property
     def rope_layout(self):
         """1 a layer of the whole pattern whose attention rotates."""
-        return [int(kind in "WS" or (kind == "*" and self.rope))
+        return [int(kind in "WSI" or (kind == "*" and self.rope))
                 for kind in self.layer_pattern]
+
+    @property
+    def sa_config(self):
+        """The indexer as a published config nests it (None without)."""
+        if not self.index_heads:
+            return None
+        return {"indexer_head_dim": self.index_head_dim,
+                "indexer_num_heads": self.index_heads,
+                "indexer_num_kv_heads": 1,
+                "kv_chunk_size": self.index_chunk,
+                "q_chunk_size": self.index_chunk, "topk": self.index_topk}
+
+    @property
+    def rope_scaling(self):
+        """The rotation's sections as a published config nests them."""
+        if not self.mrope_section:
+            return None
+        return {"mrope_section": list(self.mrope_section),
+                "rope_type": "default", "type": "default"}
 
     def window_pages(self, page_size: int) -> int:
         """The pages at a sequence's end that can hold a position some later
@@ -392,6 +488,12 @@ class LlamaConfig:
             }
             per_kind["F"] = per_kind["W"] = (
                 2 * d * q + 2 * d * kv + d * self.num_experts
+                + self.num_experts * 3 * d * self.mlp_dim + 2 * d)
+            hi, di = self.index_heads, self.index_head_dim
+            per_kind["I"] = (
+                2 * d * q + 2 * d * kv + 2 * self.head_dim
+                + d * hi * di + d * di + d * hi + 2 * di
+                + d * (self.router_experts or self.num_experts)
                 + self.num_experts * 3 * d * self.mlp_dim + 2 * d)
             if "S" in self.kinds:
                 rq, rkv, h = self.q_lora_rank, self.kv_lora_rank, self.n_heads
@@ -481,6 +583,13 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             "w_up": ("layers", None, "embed", "mlp"),
             "w_down": ("layers", None, "mlp", "embed"),
         }
+        # an indexed block's: the whole block's, the per-head QK-norm's two
+        # gains and the indexer's three projections and LayerNorm
+        kinds["index"] = dict(
+            kinds["block"], q_norm=("layers", None), k_norm=("layers", None),
+            wqi=("layers", "embed", None), wki=("layers", "embed", None),
+            ww=("layers", "embed", None), ki_norm=("layers", None),
+            ki_bias=("layers", None))
         if cfg.mlp_act != "relu2":
             kinds["moe"]["w_gate"] = ("layers", None, "embed", "mlp")
         if cfg.shared_mlp_dim:
@@ -622,6 +731,29 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
             "wv": dense(next(k), (L, d, nkv * hd), d),
             "wo": BLOCK_INIT["wo"] * dense(next(k), (L, nq * hd, d), nq * hd),
             "router": BLOCK_INIT["router"] * dense(next(k), (L, d, wide), d),
+            "w_gate": dense(next(k), (L, held, d, f), d),
+            "w_up": dense(next(k), (L, held, d, f), d),
+            "w_down": dense(next(k), (L, held, f, d), f),
+        }
+    L = n["I"]
+    if L:  # keys of its own, as above
+        k = iter(jax.random.split(jax.random.fold_in(key, 3), 12))
+        hi, di = cfg.index_heads, cfg.index_head_dim
+        out["index"] = {
+            "attn_norm": jnp.ones((L, d), jnp.float32),
+            "mlp_norm": jnp.ones((L, d), jnp.float32),
+            "wq": dense(next(k), (L, d, nq * hd), d),
+            "wk": dense(next(k), (L, d, nkv * hd), d),
+            "wv": dense(next(k), (L, d, nkv * hd), d),
+            "wo": INDEX_INIT["wo"] * dense(next(k), (L, nq * hd, d), nq * hd),
+            "q_norm": jnp.full((L, hd), INDEX_INIT["q_norm"], jnp.float32),
+            "k_norm": jnp.ones((L, hd), jnp.float32),
+            "wqi": dense(next(k), (L, d, hi * di), d),
+            "wki": dense(next(k), (L, d, di), d),
+            "ww": dense(next(k), (L, d, hi), d),
+            "ki_norm": jnp.ones((L, di), jnp.float32),
+            "ki_bias": jnp.zeros((L, di), jnp.float32),
+            "router": dense(next(k), (L, d, wide), d),
             "w_gate": dense(next(k), (L, held, d, f), d),
             "w_up": dense(next(k), (L, held, d, f), d),
             "w_down": dense(next(k), (L, held, f, d), f),
@@ -1204,6 +1336,248 @@ def attend_window_tiles(cfg: LlamaConfig, kind: str, q, k, v):
                         window=cfg.window if kind == "W" else 0)
 
 
+# --------------------------------------------------------------------------- #
+# The indexed block (kind "I"): attention over the keys an indexer picks
+# --------------------------------------------------------------------------- #
+
+
+def mrope_rotate(x, positions, theta: float, sections=()):
+    """Rotate ``x`` [B, T, H, D] by ``positions`` [3, B, T] (temporal,
+    height, width; or [B, T]: the three equal, which is the 1-D rotation),
+    half-split pairs ``(i, i + D/2)``. Frequency ``i`` of ``D/2`` takes its
+    angle from the component whose section of ``sections`` it falls in
+    (boundaries at their running sums; beyond the last, the last): a head
+    with fewer frequencies than the sections' sum has the first of them
+    only. Without sections every frequency reads component 0."""
+    import numpy as np
+
+    D = x.shape[-1]
+    if positions.ndim == 2:
+        positions = jnp.broadcast_to(positions, (3, *positions.shape))
+    at = np.arange(D // 2)
+    part = (np.minimum(np.searchsorted(np.cumsum(sections), at, "right"),
+                       len(sections) - 1) if len(sections) else 0 * at)
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    # [D/2, B, T] -> [B, T, 1, D/2]
+    angles = jnp.moveaxis(positions[part].astype(jnp.float32), 0, -1) \
+        * inv_freq
+    cos, sin = jnp.cos(angles)[:, :, None], jnp.sin(angles)[:, :, None]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           axis=-1).astype(x.dtype)
+
+
+def _order_key(score):
+    """float32 -> int32 that orders as the floats do (-0.0 under +0.0)."""
+    bits = jax.lax.bitcast_convert_type(score, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+_INT_MIN = -2 ** 31
+
+
+def select_top(score, visible, k: int):
+    """The mask of the ``k`` ``visible`` entries of largest ``score``
+    (float32) along the last axis, all of them where there are no more than
+    ``k``; of entries that tie at the last place the LOWER indices. No sort
+    and no top-k: the ``k``-th largest value a row is found by bisection
+    over the floats' ordered bit patterns (32 counts of the row), the last
+    tie's index by bisection over indices, and the mask is two comparisons.
+    ``jax.lax.top_k`` for 2,048 of 32,768 a row is a sort of every row."""
+    n = score.shape[-1]
+    if k >= n:
+        return visible
+    i32 = jnp.int32
+    key = jnp.where(visible, _order_key(score), i32(_INT_MIN))
+
+    def count(m):
+        return jnp.sum(m, axis=-1, dtype=i32, keepdims=True)
+
+    def value_bit(b, tau):  # the largest tau that k entries reach
+        cand = tau | jnp.left_shift(i32(1), 30 - b)
+        return jnp.where(count(key >= cand) >= k, cand, tau)
+
+    tau = jax.lax.fori_loop(
+        0, 31, value_bit,
+        jnp.where(count(key >= 0) >= k, i32(0), i32(_INT_MIN)))
+    above = key > tau
+    tie = visible & (key == tau)
+    need = k - count(above)
+    at = jnp.arange(n, dtype=i32)
+    bits = (n - 1).bit_length()
+
+    def index_bit(b, cut):  # the largest cut with fewer than need ties under it
+        cand = cut | jnp.left_shift(i32(1), bits - 1 - b)
+        return jnp.where(count(tie & (at < cand)) < need, cand, cut)
+
+    cut = jax.lax.fori_loop(0, bits, index_bit, jnp.zeros_like(tau))
+    return above | (tie & (at <= cut))
+
+
+def index_scores(qi, ki, w):
+    """The indexer's score of every (query, key) pair: ``qi`` [B, Tq, Hi,
+    Di] and ``ki`` [B, Tk, Di] (ONE key head; both rotated, compute type),
+    ``w`` [B, Tq, Hi] float32, a query head's weight with the two constant
+    scales in it. ``sum_j w_j relu(qi_j . ki)``, float32, [B, Tq, Tk]."""
+    s = jnp.einsum("bqhd,bkd->bhqk", qi, ki,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(s) * jnp.moveaxis(w, -1, 1)[..., None],
+                   axis=1)
+
+
+def _indexer(cfg: LlamaConfig, p, a, positions):
+    """The indexer's three projections of ``a`` [B, T, dim] (normed, the
+    stream's type): ``qi`` [B, T, Hi, Di] and ``ki`` [B, T, Di] =
+    LayerNorm(a wki) (scale and bias), their FIRST HALF rotated as a rotary
+    of its own (``Di / 4`` frequencies, half-split, by the temporal
+    position), both in the compute type; ``w`` [B, T, Hi] float32 = ``a ww
+    / sqrt(Hi Di)``. ``ki`` is what a cache keeps (as float32)."""
+    cd = cfg.dtype
+    B, T, _ = a.shape
+    hi, di = cfg.index_heads, cfg.index_head_dim
+    h = a.astype(cd)
+    qi = (h @ p["wqi"].astype(cd)).reshape(B, T, hi, di)
+    ki = (h @ p["wki"].astype(cd)).astype(jnp.float32)
+    mean = jnp.mean(ki, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
+    ki = (ki - mean) * jax.lax.rsqrt(var + cfg.norm_eps) * p["ki_norm"] \
+        + p["ki_bias"]
+    when = positions[0] if positions.ndim == 3 else positions
+
+    def rotated(x):  # [B, T, H, Di]: the first half turns
+        turned = mrope_rotate(x[..., :di // 2], when, cfg.rope_theta)
+        return jnp.concatenate([turned, x[..., di // 2:]], axis=-1)
+
+    w = jnp.dot(a.astype(jnp.float32), p["ww"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST) / math.sqrt(hi * di)
+    return rotated(qi), rotated(ki[:, :, None])[:, :, 0].astype(cd), w
+
+
+def index_block(cfg: LlamaConfig, x, layers, i, positions, attend,
+                stat_axes=()):
+    """THE indexed block (kind ``"I"``), for the full forward and for the
+    serving programs::
+
+        a       = N(x)
+        q, k, v = a Wq, a Wk, a Wv; q, k: RMSNorm over each head's width
+                  (one gain for q, one for k), then rotated (sections)
+        qI, kI, w = the indexer's (:func:`_indexer`)
+        I(t, s) = sum_j w_j(t) relu(qI_j(t) . kI(s))           float32
+        S(t)    = the index_topk positions s <= t of largest I(t, s)
+        h       = x + Wo softmax_{s in S(t)}(q(t) . k(s) / sqrt(D)) v(s)
+        out     = h + MoE(N(h))        # the router reads N(h), in float32
+
+    ``layers``: the ``index`` stack ``[L, ...]``, ``i``: which layer (a
+    number, or traced in a scan); small leaves are cut out ``[i]`` where
+    they are used, the experts go down whole with ``layer=i``
+    (:func:`shortcut_layer` says why). The stream keeps the type it comes
+    in (the serving programs carry it in float32, as :func:`window_block`'s
+    do). ``positions`` [B, T] or [3, B, T]. ``attend(q, k, v, qi, ki, w)``
+    -> ``[B, T, heads, head_dim]``: what this layer scores, selects and
+    attends over, under the device scopes ``dsa.index``, ``dsa.select`` and
+    ``dsa.attend``. Returns ``(x, stats, (k, v, ki))``: what a cache keeps
+    (``k`` normed and rotated, ``ki`` normed and rotated)."""
+    cd, hd, eps = cfg.dtype, cfg.head_dim, cfg.norm_eps
+    B, T, _ = x.shape
+    p = {w: layers[w][i] for w in (
+        "wq", "wk", "wv", "wo", "q_norm", "k_norm", "wqi", "wki", "ww",
+        "ki_norm", "ki_bias")}
+    a = rms_norm(x, layers["attn_norm"][i], eps)
+    h = a.astype(cd)
+    q = rms_norm((h @ p["wq"].astype(cd)).reshape(B, T, -1, hd),
+                 p["q_norm"], eps)
+    k = rms_norm((h @ p["wk"].astype(cd)).reshape(B, T, -1, hd),
+                 p["k_norm"], eps)
+    v = (h @ p["wv"].astype(cd)).reshape(B, T, -1, hd)
+    q, k = (mrope_rotate(y, positions, cfg.rope_theta, cfg.mrope_section)
+            for y in (q, k))
+    with jax.named_scope("dsa.index"):
+        qi, ki, w = _indexer(cfg, p, a, positions)
+    o = attend(q, k, v, qi, ki, w).reshape(B, T, -1)
+    h = x + (o @ p["wo"].astype(cd)).astype(x.dtype)
+    m = rms_norm(h, layers["mlp_norm"][i], eps)
+    y, stats = _mlp_half(
+        cfg, {"router": layers["router"][i],
+              **{w: layers[w] for w in ("w_gate", "w_up", "w_down")}},
+        m.astype(cd), stat_axes, layer=i, router_in=m)
+    return h + y.astype(x.dtype), stats, (k, v, ki)
+
+
+def selected_attend_path(q, cd) -> Tuple[str, str]:
+    """``(path, reason)`` :func:`attend_selected` takes: ``"kernel"`` on a
+    TPU backend for what ``ops/sparse_prefill.py`` takes, ``"tiles"`` with
+    what stands in the way otherwise."""
+    platform = jax.default_backend()
+    if platform != "tpu":
+        return "tiles", f"backend is {platform!r}, not tpu"
+    if q.shape[1] % 128 or q.shape[-1] % 128:
+        return "tiles", (f"{q.shape[1]} positions or a head of "
+                         f"{q.shape[-1]} are no multiple of 128")
+    if q.dtype != jnp.dtype(cd):
+        return "tiles", f"operands in {q.dtype.name}"
+    return "kernel", "tpu backend"
+
+
+def attend_selected(cfg: LlamaConfig, q, k, v, qi, ki, w):
+    """:func:`index_block`'s ``attend`` over the call's own positions (the
+    full forward and prefill): index scores of every visible pair, the
+    ``index_topk`` best a query (:func:`select_top`'s rule), softmax
+    attention under that selection. Two paths, ONE arithmetic
+    (:func:`selected_attend_path`; counted as kind ``selected`` where
+    :func:`attend_tiles`' kinds are). On a TPU backend two Pallas calls
+    (``ops/sparse_prefill.py``, imported here and nowhere else): one scores
+    a block of queries against every visible key, finds each row's
+    threshold by bisection inside VMEM and writes the selection as a mask;
+    one is flash attention under that mask, a KV group's query heads
+    stacked into one product. On every other backend, and as the oracle,
+    :func:`_selected_tiles` in XLA."""
+    path, reason = selected_attend_path(q, cfg.dtype)
+    _note_prefill_attend("selected", q, k, 0, path, reason)
+    if path == "kernel":
+        from ray_tpu.ops.sparse_prefill import index_select, masked_flash
+
+        with jax.named_scope("dsa.select"):  # scores and selection, fused
+            chosen = index_select(qi, ki, w, cfg.index_topk)
+        with jax.named_scope("dsa.attend"):
+            return masked_flash(q, k, v, chosen)
+    return _selected_tiles(q, k, v, qi, ki, w, cfg.index_topk, cfg.dtype,
+                           cfg.index_chunk)
+
+
+def _selected_tiles(q, k, v, qi, ki, w, topk: int, cd, chunk: int):
+    """:func:`attend_selected` in XLA: queries in blocks of ``chunk``
+    (``lax.map``), each against ALL keys: index scores ``[block, T]``, the
+    selection, float32 attention scores ``[heads, block, T]`` under it."""
+    f32 = jnp.float32
+    B, T, H, D = q.shape
+    G = k.shape[2]
+    block = math.gcd(T, chunk)
+    at = jnp.arange(block, dtype=jnp.int32)
+    keys = jnp.arange(T, dtype=jnp.int32)
+
+    def query_block(n):
+        def cut(a):
+            return jax.lax.dynamic_slice_in_dim(a, n * block, block, axis=1)
+
+        visible = keys[None, :] <= (n * block + at)[:, None]
+        with jax.named_scope("dsa.index"):
+            score = index_scores(cut(qi), ki, cut(w))
+        with jax.named_scope("dsa.select"):
+            chosen = select_top(score, visible[None], topk)
+        with jax.named_scope("dsa.attend"):
+            s = jnp.einsum("bqgrd,bkgd->bgrqk",
+                           cut(q).reshape(B, block, G, H // G, D), k,
+                           preferred_element_type=f32) / math.sqrt(D)
+            s = jnp.where(chosen[:, None, None], s, -1e30)
+            probs = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(cd), v,
+                           preferred_element_type=f32)
+        return o.reshape(B, block, H, -1).astype(cd)
+
+    o = jax.lax.map(query_block, jnp.arange(T // block, dtype=jnp.int32))
+    return jnp.moveaxis(o, 0, 1).reshape(B, T, H, -1)
+
+
 def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     """One layer of a patterned stack (``cfg.layer_pattern``). ``"S"`` is
     the double layer (:func:`shortcut_layer`, its latent attention
@@ -1228,6 +1602,11 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
             cfg, kind, x, jax.tree.map(lambda a: a[None], p), 0,
             positions_of(*x.shape[:2]),
             partial(attend_window_tiles, cfg, kind), stat_axes)[:2]
+    if kind == "I":  # whatever ``attend`` is: the trainer's kernel selects
+        return index_block(  # nothing
+            cfg, x, jax.tree.map(lambda a: a[None], p), 0,
+            positions_of(*x.shape[:2]), partial(attend_selected, cfg),
+            stat_axes)[:2]
     h = rms_norm(x, p["norm"], cfg.norm_eps).astype(cfg.dtype)
     stats = {}
     if kind == "M":
@@ -1519,7 +1898,15 @@ def page_rows(cfg: LlamaConfig):
     the full layers' pair, indexed by pool page id as the others are, and
     the window layers' pair, whose second dimension counts SLOTS and not
     pages (the engine says how many and maps a page to its slot): a window
-    layer needs a position for ``cfg.window`` positions and no longer."""
+    layer needs a position for ``cfg.window`` positions and no longer. A
+    stack of indexed blocks (``"index"``) keeps THREE stores by page id:
+    per-head keys, values, and the indexer's key, ONE head of
+    ``index_head_dim`` a layer, which every later decode call reads over ALL
+    of a sequence's pages to pick the rows of the other two it gathers."""
+    if "I" in cfg.kinds:
+        row = (cfg.n_kv_heads, cfg.head_dim)
+        return "index", [(cfg.n_layers, row)] * 2 \
+            + [(cfg.n_layers, (cfg.index_head_dim,))]
     if set(cfg.kinds) & set(BLOCK_KINDS):
         row = (cfg.n_kv_heads, cfg.head_dim)
         return "window", [(cfg.kinds.count("F"), row)] * 2 \
@@ -1618,6 +2005,85 @@ def _block_layers(cfg: LlamaConfig, x, layers, positions, attend, cache,
                         for a in zip(*new[c])) for c in per}
 
 
+def _index_layers(cfg: LlamaConfig, x, layers, positions, attend, cache):
+    """The served indexed blocks, scanned (one compiled body; the scan
+    carries the layer's number, not its weights): layer ``l`` attends
+    through ``attend(l, cache[l], q, k, v, qi, ki, w)``; ``cache``: a tree
+    with a leading dimension over layers (or None: nothing cached).
+    Returns ``(x, (keys, values, index keys) [L, B, T, ...] each, the
+    routed assignments' shares, averaged over layers)``."""
+
+    def body(carry, xs):
+        i, cached = xs
+        h, stats, rows = index_block(cfg, carry, layers["index"], i,
+                                     positions, partial(attend, i, cached))
+        return h, (rows, stats)
+
+    x, (rows, stats) = jax.lax.scan(
+        body, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), cache))
+    shares = {k: stats[k].mean() for k in ("held_share",) if k in stats}
+    return x, rows, shares
+
+
+def _attend_selected_cached(cfg: LlamaConfig, k_pages, v_pages, page_ids,
+                            pos, layer, ki_cache, q, kk, vv, qi, ki, w):
+    """One new token at position ``pos`` (:func:`index_block`'s ``attend``
+    arguments) against layer ``layer`` of the page stores: index scores
+    over ALL the sequence's index keys ``ki_cache`` ``[Tpad, Di]`` (the
+    gathered pages' view; rows from ``pos`` on are masked, the token's own
+    key stands in at ``pos``), the ``index_topk`` best
+    (:func:`select_top`), then attention over those ROWS of the key and
+    value stores, gathered one by one: ``index_topk`` rows a store and not
+    the sequence's whole pages. The token's own key and value are not in
+    the stores yet: they come as ``kk`` / ``vv`` and count only if ``pos``
+    is among the chosen. Float32 scores.
+
+    The gathered rows are used as the float32 they are kept in (they are
+    the compute type's values: a program of this engine wrote them), in
+    products at ``HIGHEST`` precision, so that NO conversion stands behind
+    the gather, the one a default-precision product makes of its float32
+    operands included. With one, XLA moves the conversion in front of the
+    gather and out of the layers' loop and converts both WHOLE stores on
+    every call (12 of a 14 ms decode call at 80 pages; my chip run, PR 40;
+    :func:`_read_pages` met the same in PR 25), and an
+    ``optimization_barrier`` between the two did not hold it back. The
+    products are 32 query heads by 2,049 rows: six passes cost nothing."""
+    cd, f32, i32 = cfg.dtype, jnp.float32, jnp.int32
+    Tpad, K = ki_cache.shape[0], cfg.index_topk
+    ps = k_pages.shape[2]
+    G, rep = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    at = jnp.arange(Tpad, dtype=i32)
+    with jax.named_scope("dsa.index"):
+        keys = jax.lax.dynamic_update_slice(
+            ki_cache.astype(cd), ki[0], (pos, 0))
+        score = index_scores(qi, keys[None], w)[0, 0]
+    with jax.named_scope("dsa.select"):
+        chosen = select_top(score, at <= pos, K)
+        own = chosen[pos]
+        earlier = chosen & (at != pos)
+        # the chosen positions in order: place j holds the position under
+        # which j chosen ones lie
+        under = jnp.cumsum(earlier, dtype=i32)
+        place = jnp.arange(K, dtype=i32)
+        where = jnp.sum(under[None, :] <= place[:, None], axis=1, dtype=i32)
+        real = place < under[-1]
+        where = jnp.where(real, where, 0)
+    with jax.named_scope("dsa.attend"):
+        page, row = page_ids[where // ps], where % ps
+        K_all = jnp.concatenate([k_pages[layer, page, row],
+                                 kk[0].astype(f32)])
+        V_all = jnp.concatenate([v_pages[layer, page, row],
+                                 vv[0].astype(f32)])
+        seen = jnp.append(real, own)
+        exact = jax.lax.Precision.HIGHEST
+        s = jnp.einsum("grd,kgd->grk",
+                       q[0, 0].astype(f32).reshape(G, rep, -1), K_all,
+                       precision=exact) / math.sqrt(cfg.head_dim)
+        probs = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+        o = jnp.einsum("grk,kgd->grd", probs, V_all, precision=exact)
+    return o.reshape(1, 1, G * rep, -1).astype(cd)
+
+
 def prefill_with_cache(cfg: LlamaConfig, params, *args):
     """Prefill one sequence into its pages, inside the program.
 
@@ -1628,7 +2094,8 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
     page stores (:func:`page_rows`: ``k_pages, v_pages`` ``[L, n_pages,
     page_size, n_kv, head_dim]`` for the block, ``latent_pages`` alone for
     the double layer, the full layers' pair and the window layers' pair
-    for a stack of both; donated by the engine, updated in place); ``tokens``
+    for a stack of both, keys, values and index keys for a stack of indexed
+    blocks; donated by the engine, updated in place); ``tokens``
     [1, n * page_size] int32, right-padded (causal masking keeps pad
     garbage out of real positions); ``page_ids`` [n] int32; ``last`` int32
     scalar, the last real position. Returns ``(*stores, logits [vocab]
@@ -1654,6 +2121,10 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
             {"F": tokens.shape[1], "W": slot_ids.shape[0] * ps})
         new, page_ids = rows["F"] + rows["W"], (page_ids,) * 2 \
             + (slot_ids,) * 2
+    elif kind == "index":
+        x, new, shares = _index_layers(
+            cfg, x.astype(jnp.float32), params["layers"], positions,
+            lambda l, cached, *a: attend_selected(cfg, *a), None)
     elif cfg.layer_pattern:
         # keys and values expanded, attended by attend_tiles
         x, rows, shares = _latent_layers(
@@ -1748,7 +2219,11 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
     The table's pages are gathered on the device into the ``[S, n *
     page_size, *row]`` view of each store (positions >= ``pos`` are
     masked), and the new position's keys and values (its latent rows) are
-    written at ``(page_ids[pos // page_size], pos % page_size)``. Returns
+    written at ``(page_ids[pos // page_size], pos % page_size)``. A stack
+    of indexed blocks whose table holds more than ``index_topk`` positions
+    gathers the index keys' pages alone and, a layer, the ``index_topk``
+    chosen ROWS of keys and values (:func:`_attend_selected_cached`).
+    Returns
     ``(*stores, logits [vocab] fp32)``. ``pos`` and the page ids are
     traced, so one compilation covers every step at a given page count."""
     kind, _ = page_rows(cfg)
@@ -1758,10 +2233,27 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
     ps = stores[0].shape[2]
     tables = ((page_ids,) * 2 + (slot_ids,) * 2 if kind == "window"
               else (page_ids,) * len(stores))
-    cached = [_read_pages(pages, ids) for pages, ids in zip(stores, tables)]
+    # an indexed block past index_topk reads its index keys' pages alone:
+    # the other two stores' rows are gathered where a layer has chosen them
+    pick = kind == "index" and page_ids.shape[0] * ps > cfg.index_topk
+    read = stores[2:] if pick else stores
+    cached = [_read_pages(pages, ids)
+              for pages, ids in zip(read, tables[-len(read):])]
     x = embed_tokens(cfg, params, token[None, :], None)
     positions = jnp.full((1, 1), pos, dtype=jnp.int32)
-    if kind == "window":
+    if kind == "index":
+        if pick:
+            attend = partial(_attend_selected_cached, cfg, *stores[:2],
+                             page_ids, pos)
+        else:  # every visible position is attended: the pages' views
+            def attend(l, mine, q, kk, vv, qi, ki, w):
+                return _attend_cached(cfg, *mine[:2], pos, q, kk, vv)
+
+        x, rows, _ = _index_layers(
+            cfg, x.astype(jnp.float32), params["layers"], positions, attend,
+            cached[0] if pick else tuple(cached))
+        new = [a[:, 0] for a in rows]
+    elif kind == "window":
         base = first * ps  # the position of the window view's first row
 
         def attend(c, mine, *a):
@@ -1819,7 +2311,10 @@ def copy_page_in_stores(*args):
 _MATMUL_TOP = ("embedding", "lm_head")
 _MATMUL_LAYER = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                  "wq_a", "wq_b", "wkv_a", "wkv_b",
-                 "ffn_gate", "ffn_up", "ffn_down")
+                 "ffn_gate", "ffn_up", "ffn_down",
+                 # the indexer's query and key projections; the head
+                 # weights' (ww) is used in float32, as a router is
+                 "wqi", "wki")
 
 
 def serving_params(cfg: LlamaConfig, params) -> Dict[str, Any]:
@@ -1858,11 +2353,13 @@ class LlamaDecodeEngine:
 
     It serves a stack of dense blocks, a stack of shortcut-connected
     double layers (``layer_pattern`` all ``"S"``: latent attention, routed
-    and identity experts) and a stack of whole routed blocks with full and
-    window attention (``"F"`` and ``"W"``, both present: window_block); a
-    Mamba layer's state has no page kind yet, and no test holds QK-norm,
-    the ``"E"`` / ``"*"`` halves or the UNPATTERNED routed block to a
-    reference here, so those are refused.
+    and identity experts), a stack of whole routed blocks with full and
+    window attention (``"F"`` and ``"W"``, both present: window_block) and
+    a stack of indexed blocks (all ``"I"``: index_block, per-head QK-norm,
+    a held range of experts, attention over the keys an indexer picks); a
+    Mamba layer's state has no page kind yet, and no test holds
+    whole-projection QK-norm, the ``"E"`` / ``"*"`` halves or the
+    UNPATTERNED routed block to a reference here, so those are refused.
 
     ``params`` is the tree the programs run on: ``init_params``' layout
     with the matmul weights (``embedding``, ``lm_head``, ``wq`` .. ``w_down``,
@@ -1886,7 +2383,14 @@ class LlamaDecodeEngine:
     stack of full and window layers: the full layers' keys and values
     ``[L_full, n_pages, page_size, n_kv, head_dim]`` by page id, and the
     window layers' ``[L_window, n_slots, page_size, n_kv, head_dim]`` by
-    SLOT (below). They
+    SLOT (below). A stack of indexed blocks: keys and values as the block's
+    and a THIRD store ``[L, n_pages, page_size, index_head_dim]``, the
+    indexer's ONE key head a layer, all three by page id; prefill writes
+    all three, and a decode call whose table holds more than ``index_topk``
+    positions reads the index keys' pages whole, picks, and gathers
+    ``index_topk`` ROWS of the other two a layer in place of their pages
+    (``ray_tpu_serve_engine_selected_share{program}``: what the last call
+    attended of what it saw). They
     are read and written only inside three jitted programs that take them
     donated and return them: prefill writes the scan's rows into the pages
     it is given, decode gathers the sequence's page table into a
@@ -1933,7 +2437,11 @@ class LlamaDecodeEngine:
     index map, a window layer's key blocks from its band on), on every other
     backend the XLA tile loop; ``ray_tpu_serve_engine_prefill_attend{kind,
     path}`` counts which, where a program is traced
-    (:func:`prefill_attend_paths` has the reason). The dense block's prefill
+    (:func:`prefill_attend_paths` has the reason). An indexed block's is
+    :func:`attend_selected`, counted as kind ``selected``: two Pallas calls
+    on a TPU backend (``ops/sparse_prefill.py``: index scores and the
+    selection as a mask, then flash attention under it), XLA tiles
+    elsewhere. The dense block's prefill
     is ``plain_attention`` over its few short pages.
 
     One caller at a time (the scheduler's lock covers a whole iteration):
@@ -1946,15 +2454,16 @@ class LlamaDecodeEngine:
         from ray_tpu.serve.kv_cache import PagePool, PrefixCache
 
         self.cfg = cfg or LlamaConfig.debug()
-        if set(self.cfg.kinds) not in ({"S"}, set(BLOCK_KINDS)):
+        if set(self.cfg.kinds) not in ({"S"}, set(BLOCK_KINDS), {"I"}):
             _dense_only(
                 self.cfg, "LlamaDecodeEngine",
-                "of the patterned kinds it serves all-'S' and 'F' with 'W' "
-                "(a Mamba layer's state has no page kind, and its stores "
-                "are the double layer's or the two pairs of a stack that "
-                "has both full and window layers), and for QK-norm, the "
-                "'E' / '*' halves or an unpatterned routed block no test "
-                "compares its logits with the reference")
+                "of the patterned kinds it serves all-'S', 'F' with 'W' and "
+                "all-'I' (a Mamba layer's state has no page kind, and its "
+                "stores are the double layer's, the two pairs of a stack "
+                "that has both full and window layers, or the three of a "
+                "stack of indexed blocks), and for whole-projection "
+                "QK-norm, the 'E' / '*' halves or an unpatterned routed "
+                "block no test compares its logits with the reference")
         if params is None:
             # one jitted program, not a dozen eager ones: at 664.6M
             # parameters the eager form spends 67 s on a v5e, nearly all of
@@ -1998,11 +2507,14 @@ class LlamaDecodeEngine:
             jnp.zeros((S, n, page_size, *row), jnp.float32)
             for (S, row), n in zip(rows, slabs))
         # every tag always, as above
-        page_bytes = {"kv": 0, "latent": 0, "full": 0, "window": 0}
+        page_bytes = {"kv": 0, "latent": 0, "full": 0, "window": 0,
+                      "index": 0}
         per_store = [4 * S * math.prod(row) for S, row in rows]
         if kind == "window":
             page_bytes.update(full=sum(per_store[:2]),
                               window=sum(per_store[2:]))
+        elif kind == "index":
+            page_bytes.update(kv=sum(per_store[:2]), index=per_store[2])
         else:
             page_bytes[kind] = sum(per_store)
         for tag, nbytes in page_bytes.items():
@@ -2013,7 +2525,8 @@ class LlamaDecodeEngine:
             from ray_tpu.ops.moe import expert_groups
 
             w_up = self.params["layers"][
-                "block" if kind == "window" else "scmoe"]["w_up"]
+                {"window": "block", "index": "index"}.get(kind, "scmoe")
+            ]["w_up"]
             groups = {"program": expert_groups(w_up, self.cfg.dtype),
                       "layer": w_up.shape[1]}
         for part, n in groups.items():
@@ -2029,7 +2542,8 @@ class LlamaDecodeEngine:
             "llama.decode")
         self._copy_fn = observe_compiled(
             jax.jit(copy_page_in_stores,  # a window stack's: a pair a call
-                    donate_argnums=tuple(range(min(len(rows), 2)))),
+                    donate_argnums=tuple(range(
+                        2 if kind == "window" else len(rows)))),
             "llama.copy_page")
         self.prefill_calls = 0
         self.decode_calls = 0
@@ -2139,6 +2653,9 @@ class LlamaDecodeEngine:
         # a routed model's shares come with the logits: one read
         last, shares = jax.device_get((logits, shares))
         _sp_prefill_logits.end(_t, n_pages)
+        few = min(T, self.cfg.index_topk)  # rows that see no more than topk
+        self._note_selected("prefill", T * (T + 1) // 2,
+                            few * (few + 1) // 2 + (T - few) * few)
         if shares or self.n_slots:  # every expert here: held reads 1.0
             held = float(shares.get("held_share", 1.0))
             zero = float(shares.get("zero_share", 0.0))
@@ -2180,7 +2697,16 @@ class LlamaDecodeEngine:
         out = np.asarray(logits, np.float32)
         _sp_decode_readback.end(_t, n_pages)
         _sp_decode.end(_t_call, n_pages)
+        self._note_selected("decode", pos + 1,
+                            min(pos + 1, self.cfg.index_topk))
         return out
+
+    def _note_selected(self, program: str, visible: int, attended: int):
+        """A stack of indexed blocks: the share of the visible keys the
+        call's queries attended, from the call's own length."""
+        if self.cfg.index_topk:
+            _g_engine_selected_share.set(attended / visible,
+                                         tags={"program": program})
 
     def copy_page(self, src: int, dst: int) -> None:
         np = self._np

@@ -395,7 +395,7 @@ def test_the_stores_are_two_pairs_and_the_window_pair_counts_slots(params):
         + [(4, 12, 5, 2, 16)] * 2
     assert gauge("ray_tpu_serve_engine_page_bytes") == {
         "kv": 0.0, "latent": 0.0, "full": 2 * 2 * 2 * 16 * 4.0,
-        "window": 2 * 4 * 2 * 16 * 4.0}
+        "window": 2 * 4 * 2 * 16 * 4.0, "index": 0.0}
     assert gauge("ray_tpu_serve_engine_expert_groups") == {
         "program": 6 * 8.0, "layer": 8.0}
     assert gauge("ray_tpu_serve_engine_window_slots")["total"] == 12.0
@@ -587,7 +587,7 @@ def test_benchmark_files_fit_together_with_the_new_cell():
 
     test_yardstick.test_benchmark_files_fit_together()
     bench = spec.load_benchmark()
-    assert len(bench["workloads"]) == 7
+    assert len(bench["workloads"]) == 8
     assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
     b = spec.cell_bundle(CELL)
     assert (b["cell"]["chips"], b["cell"]["traffic"]) == (
@@ -624,19 +624,11 @@ def test_the_cells_rehearsal_runs_end_to_end():
     """``--rehearsal`` of the new cell on the CPU: a pattern cut inside a
     period, a window longer than any context, more window pages than pool
     pages, through ``serve.run``, the scheduler and the harness's check."""
-    import subprocess
-    import sys
+    import rehearse
 
     tiny = spec.cell_bundle(CELL, rehearsal=True)
     cfg = spec.program_config(tiny["config"])
     assert (cfg.kinds, cfg.window, cfg.mlp_dim) == ("FW", 4096, 768)
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    run = subprocess.run(
-        [sys.executable, "benchmarks/run.py", "--workload", CELL,
-         "--rehearsal", "--seed", "3800000038", "--seconds", "3",
-         "--trace", "0"], cwd=spec.ROOT, env=env, capture_output=True,
-        text=True, timeout=160)
-    assert run.returncode == 0, run.stderr[-2000:]
-    line = json.loads(run.stdout.strip().splitlines()[-1])
+    line = rehearse.run_cell(CELL, 3800000038)
     assert line["correct"] is True, line
     assert line["attempted"] > 0 and line["failed"] == 0

@@ -158,3 +158,31 @@ def test_prefill_kernel_compiles_at_the_cells_shapes(what, one_chip,
     text = lowered.compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "%flash_prefill" in text and "while(" not in text
+
+
+@pytest.mark.parametrize("pages", [4, 16])
+def test_selection_kernels_compile_at_the_keye_cells_shapes(pages, one_chip,
+                                                            as_on_the_chip):
+    """``ops/sparse_prefill.py`` at the indexed block's published widths
+    (16 index heads of 64 on one key head, 32 query heads on 4 of 128,
+    ``topk`` 2,048), at the cell's shortest and longest page tables: the
+    selection call keeps a 128-query block's whole row of order keys in
+    VMEM (16 MB at 32,768 keys) and asks for its limit, and the attention
+    under the mask keeps a group's whole keys and values there."""
+    from ray_tpu.ops import sparse_prefill as sp
+
+    t = pages * 2048
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    select = jax.jit(lambda qi, ki, w: sp.index_select(qi, ki, w, 2048)).lower(
+        arg(1, t, 16, 64), arg(1, t, 64), arg(1, t, 16, dtype=jnp.float32))
+    assert "scoped_memory_configs" in select.as_text()
+    assert select.compile().as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    mask = arg(1, t // 128, t // 512, 128, 512, dtype=jnp.int8)
+    attend = jax.jit(sp.masked_flash).lower(
+        arg(1, t, 32, 128), arg(1, t, 4, 128), arg(1, t, 4, 128), mask)
+    assert attend.compile().as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
