@@ -1,30 +1,26 @@
 """Mixture-of-Experts feed-forward layers, TPU-native. Two paths:
 
-- :func:`routed_mlp` is the MEASURED one (``models/llama.py _mlp_half``,
-  the trainer's step, the cell ``train-olmoe-1chip``): dropless top-k. The
-  ``tokens x k`` assignments are sorted by expert, their rows gathered, the
-  three expert products run as grouped products over the sorted rows
-  (``jax.lax.ragged_dot``), rows go back to token order. Memory grows with
-  ``tokens x k``; every choice is computed whatever the imbalance; every
-  shape is static; every index map is a gather in both directions (no
-  scatter, forward or backward). A layer that HOLDS A RANGE of the router's
-  experts (``held=``: one chip's share of an expert-parallel job, the cell
-  ``train-nemotron3nano-1chip``) routes over all of them, sorts the same
-  way with the assignments to experts held elsewhere behind the held ones,
-  and makes rows, the same grouped products and the sum onto tokens for
-  the first places alone (:func:`_held_rows`: there the sum is a
-  scatter-add of few rows): nothing is made for an assignment that falls
-  elsewhere. The router's variants (sigmoid scores, a choice-only bias, a
-  weight scale, two-matrix ``relu2`` or gated ``reglu`` experts, a shared
-  expert, identity experts, a router input of its own) are arguments, each
-  by itself.
+- :func:`routed_mlp` is the MEASURED one (``models/llama.py _mlp_half``:
+  the trainer's step, the decode engine): dropless top-k. The ``tokens x k``
+  assignments are sorted by expert, their rows gathered, the expert
+  products run as grouped products over the sorted rows
+  (``jax.lax.ragged_dot``), a token's rows summed from one gather in the
+  compute type (:func:`_sum_rows`): memory grows with ``tokens x k``, every
+  choice is computed whatever the imbalance, every shape is static, rows
+  move by gathers both ways (no scatter). A layer that HOLDS A RANGE of the
+  router's experts (``held=``: one chip's share of an expert-parallel job)
+  routes over all of them, sorts the assignments to experts held elsewhere
+  behind the held ones, and makes rows, products and the sum onto tokens (a
+  scatter-add of few rows) for the first places alone (:func:`_held_rows`).
+  The router's variants (scores, a choice-only bias, a weight scale, the
+  experts' form, a shared expert, identity experts, a router input of its
+  own) are arguments, each by itself.
 - :func:`moe_ffn` / :func:`top_k_routing` are the older GShard/Switch
   *dense dispatch*: one-hot ``[G, S, E, C]`` dispatch/combine tensors with a
-  static capacity that DROPS tokens and always renormalises the gates. It
-  is the only path with a live ``expert`` mesh axis (GSPMD all-to-all,
-  ``models/moe_llama.py``) and runs in no benchmark cell; it goes when the
-  four-chip expert-parallel cell is built on ``routed_mlp`` (ROADMAP
-  Design 1 and 2).
+  static capacity that DROPS tokens and always renormalises the gates: the
+  only path with a live ``expert`` mesh axis (GSPMD all-to-all,
+  ``models/moe_llama.py``), in no benchmark cell; it goes when the four-chip
+  expert-parallel cell is built on ``routed_mlp`` (ROADMAP Design 1 and 2).
 """
 
 from __future__ import annotations
@@ -183,51 +179,56 @@ def _grouped_dot_bwd(res, ct):
 _grouped_dot.defvjp(_grouped_dot_fwd, _grouped_dot_bwd)
 
 
+def _sum_rows(rows, inverse, n, w=None):
+    """Each token's ``k`` rows of ``rows`` [A, d] (its ``j``-th at place
+    ``inverse[t * k + j]``), times ``w[t, j]`` where ``w`` [n, k] is given,
+    summed in float32 [n, d]: ONE gather in ``rows``' type, slot by slot ([k
+    * n, d] seen as [k, n, d]: ``n``, not ``k``, beside the lanes), and one
+    fused pass, in which the barrier keeps the widening (PERF.md, PR 41)."""
+    g = rows[inverse.reshape(n, -1).T.reshape(-1)]
+    g = jax.lax.optimization_barrier(g.reshape(-1, n, rows.shape[-1]))
+    return (g if w is None else g * w.T[:, :, None]).sum(0, dtype=jnp.float32)
+
+
 @jax.custom_vjp
 def _dispatch(h, order, inverse):
-    """``h`` [N, d] -> the assignments' rows in sorted order [A, d]:
-    ``order[i]`` is the assignment (token ``order[i] // k``) in sorted
-    place ``i``, ``inverse[a]`` the sorted place of assignment ``a``. Both
-    directions are gathers."""
+    """``h`` [N, d] -> the assignments' rows in sorted order [A, d], a gather
+    as its transpose is: ``order[i]`` is the assignment (token ``order[i] //
+    k``) in sorted place ``i``, ``inverse[a]`` assignment ``a``'s place."""
     return h[order // (order.shape[0] // h.shape[0])]
-
-
-def _dispatch_fwd(h, order, inverse):
-    return _dispatch(h, order, inverse), (h.shape[0], order, inverse)
 
 
 def _dispatch_bwd(res, ct):
     n, order, inverse = res
     with jax.named_scope("moe.dispatch"):
-        dh = ct[inverse.reshape(n, -1)].astype(jnp.float32).sum(1)
+        dh = _sum_rows(ct, inverse, n)
     return dh.astype(ct.dtype), _float0(order), _float0(inverse)
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_dispatch.defvjp(lambda h, *ix: (_dispatch(h, *ix), (h.shape[0], *ix)),
+                 _dispatch_bwd)
 
 
 @jax.custom_vjp
 def _combine(ys, w, order, inverse):
     """``y[n] = sum_j w[n, j] * ys[inverse[n * k + j]]`` in float32."""
-    return jnp.einsum("nk,nkd->nd", w, ys[inverse.reshape(w.shape)],
-                      preferred_element_type=jnp.float32)
-
-
-def _combine_fwd(ys, w, order, inverse):
-    return _combine(ys, w, order, inverse), (ys, w, order, inverse)
+    return _sum_rows(ys, inverse, w.shape[0], w=w)
 
 
 def _combine_bwd(res, ct):
     ys, w, order, inverse = res
     with jax.named_scope("moe.combine"):
-        d_ys = (w.reshape(-1)[order][:, None]
-                * ct[order // w.shape[1]]).astype(ys.dtype)
-        d_w = jnp.einsum("nkd,nd->nk", ys[inverse.reshape(w.shape)], ct,
-                         preferred_element_type=jnp.float32)
-    return d_ys, d_w.astype(w.dtype), _float0(order), _float0(inverse)
+        # ONE gather (of ``ct``) for both; NUMBERS change order by a sort
+        _, w_sorted = jax.lax.sort_key_val(inverse, w.reshape(-1))
+        ct = ct[order // w.shape[1]]
+        d_ys = (w_sorted[:, None] * ct).astype(ys.dtype)
+        _, d_w = jax.lax.sort_key_val(
+            order, (ys.astype(jnp.float32) * ct).sum(-1))
+    return (d_ys, d_w.reshape(w.shape).astype(w.dtype), _float0(order),
+            _float0(inverse))
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+_combine.defvjp(lambda *args: (_combine(*args), args), _combine_bwd)
 
 
 def _filled(f: int) -> bool:
@@ -333,11 +334,11 @@ def _held_rows(hf, top_w, order, starts, end, weights, blocks,
     makes ``A / blocks`` rows. The index maps here are a gather of rows and
     a scatter-add onto tokens (transposed: the same two): ``_dispatch`` /
     ``_combine`` gather a row for EVERY assignment, which at 16,384 tokens
-    x 6 of width 2688 is 10 ms a map against 0.3 and 3.6 ms for a quarter
-    of the places (PERF.md, PR 31). On the TPU the grouped product leaves
-    the rows of no group UNWRITTEN (whatever the buffer held): places from
-    ``end`` on are set to zero going in and coming out, and so are,
-    transposed, their cotangents."""
+    x 6 of width 2688 is 5.3 ms a map (9.5 before PR 41) against 0.3 and
+    3.6 ms for a quarter of the places (PERF.md, PRs 31 and 41). On the TPU
+    the grouped product leaves the rows of no group UNWRITTEN (whatever the
+    buffer held): places from ``end`` on are set to zero going in and coming
+    out, and so are, transposed, their cotangents."""
     (N, d), A = hf.shape, order.shape[0]
     if A % blocks:
         blocks = 1
@@ -474,14 +475,13 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
             here = (group >= first) & (group < first + count)
             group = jnp.where(here, group - first, count)
         sorted_e, order = jax.lax.sort_key_val(group, ids)  # stable
-        if held is None:
-            _, inverse = jax.lax.sort_key_val(order, ids)
         starts = jnp.searchsorted(sorted_e,
                                   jnp.arange(count, dtype=jnp.int32))
         end = jnp.int32(A) if held is None else jnp.searchsorted(
             sorted_e, jnp.int32(count)).astype(jnp.int32)
         counts = jnp.diff(starts.astype(jnp.int32), append=end)
         if held is None:
+            _, inverse = jax.lax.sort_key_val(order, ids)
             xs = _dispatch(hf, order, inverse)
     if held is None:
         with jax.named_scope("moe.experts"):

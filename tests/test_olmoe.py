@@ -480,7 +480,10 @@ def _gspmd_text():
 # ``@_where_71``, ``@silu_204`` ``@silu_208``) and the text's hash moves
 # though no operation does. The test below holds a program to its hash
 # with the names off, and with them on to the same text but for those
-# numbers.
+# numbers. PR 41 meant to change the two ROUTED steps and replaced their
+# hashes (and the routed one of the three below): ``ops/moe.py``'s row maps
+# (``_sum_rows``; the weights' cotangent made where the rows lie); the ten
+# programs that run no ``routed_mlp`` keep theirs.
 PROGRAMS = {
     "dense spmd, one device": (
         "90f52bb7182988719693b307dea1b7af700e18813e501c295d3f917e99fe4f41",
@@ -492,10 +495,10 @@ PROGRAMS = {
         "3383c9e196be230a9964da69289bc26aae093a4df436c9cfbae7037c1882d336",
         lambda: _spmd_text(LlamaConfig.debug(), "fsdp=4", 4, "upfront")),
     "routed spmd, one device": (
-        "da0b5b8048e7ac43a9d7a87e06a7d195f34defd5ffc8c45124cc7afeea0fad9e",
+        "d166dd80654a151161aa5d6a26814ea2b769b795a94fc31acd9ef2d7ef7be138",
         lambda: _spmd_text(program_cfg(), "", 1)),
     "routed spmd, fsdp=4 streamed": (
-        "164c8b4462e1898adc1e0881a906e96176252a21d731c0872dc68fac2d8a2ff4",
+        "7b4f54dcac5110c848eb3b17b28ada71a4d3cb98cbdd9888cf7546d9a0341b5d",
         lambda: _spmd_text(program_cfg(), "fsdp=4", 4)),
     "dense spmd, fsdp=2 tensor=2": (
         "344206d8f12065599cf4b0aa7c27095d427915891c1ddf9272e8e4eb170a7fa9",
@@ -549,12 +552,13 @@ def test_program_lowers_to_the_same_text(program, names_off):
     assert _unnumbered(lower()) == _unnumbered(unnamed)
 
 
-# the three streamed steps as bdeba28 lowered them, before PR 29
+# the three streamed steps as bdeba28 lowered them, before PR 29 (the routed
+# one with PR 41's row maps, which both sides of that comparison now run)
 BEFORE_THE_CARRIED_GATHER_LEFT_THE_BACKWARD = {
     "dense spmd, fsdp=4 streamed":
         "999a6a502c954414038f8f83723808c52872d6a452a507db4bdbe24232dee8db",
     "routed spmd, fsdp=4 streamed":
-        "c4277f392db5d65581faa4423e4fe1eea8e33680efbdce16470a96cbabb50d4d",
+        "7824e9a08c2bee0064826cdcbad6dd8b6731c65a7352f1b21b8b7752aa716d5f",
     "dense spmd, fsdp=2 tensor=2":
         "fe19fe4132d3b6f18554e68fa84f50481a9b1ca17d1e57bc5eefdf1a667bf3fb",
 }
