@@ -74,10 +74,12 @@ _g_engine_page_bytes = Gauge(
     "ray_tpu_serve_engine_page_bytes",
     "Bytes one position holds in the decode engine's page store, by the "
     "layout the layer kind gives it", tag_keys=("kind",))
-# set where the engine builds its programs: the groups their grouped expert
+# set where the engine builds its programs: the groups XLA's grouped expert
 # products run over (part=program: a kind's whole stack, read where it
 # lies) and the experts one layer holds (part=layer). Equal, the programs
-# cut each layer's experts out of the stack; a dense engine reads 0 and 0
+# cut each layer's experts out of the stack; a dense engine reads 0 and 0.
+# A prefill's products that take the Pallas kernel read the layer's experts
+# by index map (ray_tpu_serve_engine_expert_products{path}, below)
 _g_engine_expert_groups = Gauge(
     "ray_tpu_serve_engine_expert_groups",
     "Groups the decode engine's grouped expert products run over, and the "
@@ -99,6 +101,16 @@ _g_engine_prefill_attend = Gauge(
     "Prefill attentions traced into the decode engine's programs (and the "
     "full forward), by layer kind and by the path they took: the flash "
     "kernel or XLA tiles", tag_keys=("kind", "path"))
+# which way the experts' products on a kind's STACKED leaves went, counted
+# where a program is traced (ops/moe.py _expert_ffn tells an engine's
+# process through watch_stacked_calls): in an engine's process on the chip
+# the prefill programs read path=kernel and the decode programs (a few rows
+# a call) path=xla; expert_product_paths() keeps the reason beside the count
+_g_engine_expert_products = Gauge(
+    "ray_tpu_serve_engine_expert_products",
+    "Calls of the experts' products on stacked leaves traced in a decode "
+    "engine's process, by the path they took: the Pallas grouped kernel or "
+    "XLA's grouped products", tag_keys=("path",))
 # the last prefill's routed assignments, averaged over its layers: on the
 # experts held here, on identity experts, and on experts held elsewhere
 # (left out). Read with the logits; a model without a router sets none
@@ -1044,8 +1056,9 @@ def attend_latent_expanded(cfg: LlamaConfig, q, latent, wkv_b):
 
 # every distinct prefill attention traced in this process and the way it
 # went: (kind, shapes, window, path) -> {.., "reason", "calls"}; programs are
-# traced on whatever thread first calls them
-_prefill_attend_lock = threading.Lock()
+# traced on whatever thread first calls them (one lock for this record and
+# the experts' products' below)
+_paths_lock = threading.Lock()
 _prefill_attend_taken: Dict[tuple, dict] = {}
 
 
@@ -1079,13 +1092,13 @@ def prefill_attend_paths() -> list:
     in this process, with its reason and how often: how a run proves which
     attention its prefill programs hold (the trainer's kernels:
     ``ops.flash_attention.paths_taken``)."""
-    with _prefill_attend_lock:
+    with _paths_lock:
         return [dict(rec) for rec in _prefill_attend_taken.values()]
 
 
 def _note_prefill_attend(kind, q, k, window, path, reason) -> None:
     key = (kind, q.shape, k.shape, window, path)
-    with _prefill_attend_lock:
+    with _paths_lock:
         rec = _prefill_attend_taken.setdefault(key, {
             "kind": kind, "q_shape": list(q.shape), "k_shape": list(k.shape),
             "window": window, "path": path, "reason": reason, "calls": 0})
@@ -1096,6 +1109,35 @@ def _note_prefill_attend(kind, q, k, window, path, reason) -> None:
     for way, n in counts.items():
         _g_engine_prefill_attend.set(float(n),
                                      tags={"kind": kind, "path": way})
+
+
+# the same for the experts' products on stacked leaves: (rows, stack, type,
+# path, reason) -> {.., "calls"}
+_expert_products_taken: Dict[tuple, dict] = {}
+
+
+def expert_product_paths() -> list:
+    """Every distinct call of the experts' products on stacked leaves (rows,
+    stack, type, path) traced in this process since its first routed engine
+    was built, with its reason (``ops.moe.expert_product_path``'s) and how
+    often: how a run proves which products its programs hold."""
+    with _paths_lock:
+        return [dict(rec) for rec in _expert_products_taken.values()]
+
+
+def _note_expert_products(xs, w_up, path, reason) -> None:
+    key = (xs.shape, w_up.shape, xs.dtype.name, path, reason)
+    with _paths_lock:
+        rec = _expert_products_taken.setdefault(key, {
+            "rows": list(xs.shape), "stack": list(w_up.shape),
+            "dtype": xs.dtype.name, "path": path, "reason": reason,
+            "calls": 0})
+        rec["calls"] += 1
+        counts = {way: sum(r["calls"]
+                           for r in _expert_products_taken.values()
+                           if r["path"] == way) for way in ("kernel", "xla")}
+    for way, n in counts.items():
+        _g_engine_expert_products.set(float(n), tags={"path": way})
 
 
 def attend_tiles(q, k, v, cd, window: int = 0, shared=None):
@@ -2444,6 +2486,17 @@ class LlamaDecodeEngine:
     elsewhere. The dense block's prefill
     is ``plain_attention`` over its few short pages.
 
+    THE EXPERTS' PRODUCTS of the routed kinds come from the kind's stacked
+    leaves by the layer's number (``ops/moe.py _expert_ffn``): a prefill's
+    rows on a TPU backend in one forward-only Pallas call
+    (``ops/grouped_ffn.py``: weights by index map, gate, up and the
+    activation in one pass, results in ``cfg.dtype``), a decode call's few
+    rows and every other backend in XLA's grouped products over the groups
+    ``ray_tpu_serve_engine_expert_groups{part}`` reports;
+    ``ray_tpu_serve_engine_expert_products{path}`` (``kernel`` / ``xla``)
+    counts which, where a program is traced
+    (:func:`expert_product_paths` has the reason).
+
     One caller at a time (the scheduler's lock covers a whole iteration):
     a call hands the stores to its program and takes the returned ones."""
 
@@ -2522,8 +2575,9 @@ class LlamaDecodeEngine:
         self._note_slots()
         groups = {"program": 0, "layer": 0}
         if self.cfg.num_experts:  # the routed kinds it serves
-            from ray_tpu.ops.moe import expert_groups
+            from ray_tpu.ops.moe import expert_groups, watch_stacked_calls
 
+            watch_stacked_calls(_note_expert_products)
             w_up = self.params["layers"][
                 {"window": "block", "index": "index"}.get(kind, "scmoe")
             ]["w_up"]

@@ -4,7 +4,8 @@
   the trainer's step, the decode engine): dropless top-k. The ``tokens x k``
   assignments are sorted by expert, their rows gathered, the expert
   products run as grouped products over the sorted rows
-  (``jax.lax.ragged_dot``), a token's rows summed from one gather in the
+  (``jax.lax.ragged_dot``; a served kind's prefill on a TPU in one Pallas
+  call, :func:`_expert_ffn`), a token's rows summed from one gather in the
   compute type (:func:`_sum_rows`): memory grows with ``tokens x k``, every
   choice is computed whatever the imbalance, every shape is static, rows
   move by gathers both ways (no scatter). A layer that HOLDS A RANGE of the
@@ -239,13 +240,60 @@ def _filled(f: int) -> bool:
 
 
 def expert_groups(w_up, dtype) -> int:
-    """The groups the grouped products run over for a kind's stacked
+    """The groups XLA's grouped products run over for a kind's stacked
     ``w_up`` [L, count, d, f] and rows of ``dtype``: the stack's ``L *
     count`` where it is read where it lies, one layer's ``count`` where the
-    layer is cut out of it (:func:`_expert_ffn` says when)."""
+    layer is cut out of it (:func:`_expert_ffn` says when). What a decode
+    call's products run over in every engine; a prefill's that take the
+    kernel (:func:`expert_product_path`) read ``w[layer, g]`` by index map
+    and know one layer's ``count`` groups."""
     L, count, _, f = w_up.shape
     cut = w_up.dtype != dtype or _filled(f)
     return count if cut else L * count
+
+
+# told ``(xs, w_up, path, reason)`` of every call of the experts' products on
+# a kind's STACKED leaves, where a program is traced: the decode engine
+# registers its count here (models/llama.py expert_product_paths and the
+# gauge beside it); this module knows the rule and no metric. Calls with one
+# layer's weights (the trainer's step) have one path and tell nobody
+_stacked_call_watchers: list = []
+
+
+def watch_stacked_calls(tell) -> None:
+    """Register ``tell(xs, w_up, path, reason)`` (once, however often it is
+    asked) for every call of :func:`_expert_ffn` on stacked leaves."""
+    if tell not in _stacked_call_watchers:
+        _stacked_call_watchers.append(tell)
+
+
+def expert_product_path(xs, w_gate, w_up, layer=None) -> Tuple[str, str]:
+    """``(path, reason)`` :func:`_expert_ffn` takes for these operands in
+    this process: ``"kernel"`` (``ops/grouped_ffn.py``) on a TPU backend for
+    gated experts that come as a kind's stack with their layer's number, in
+    the rows' type, of widths that are whole lanes and are not filled up, on
+    at least one row tile of rows; ``"xla"`` with what stands in the way
+    otherwise. Read from the backend and the call's own shapes alone."""
+    platform = jax.default_backend()
+    if platform != "tpu":
+        return "xla", f"backend is {platform!r}, not tpu"
+    if layer is None:
+        return "xla", ("one layer's experts [count, d, f], not a kind's "
+                       "stack with its layer's number")
+    if w_gate is None:
+        return "xla", "two-matrix experts (no gate)"
+    L, count, d, f = w_up.shape
+    if expert_groups(w_up, xs.dtype) != L * count:
+        return "xla", (f"a stack in {w_up.dtype.name} of width {f} under "
+                       f"rows in {xs.dtype.name}: the layer is cut out of it")
+    from ray_tpu.ops.grouped_ffn import ROW_TILE, pick_columns
+
+    if pick_columns(d, f, xs.dtype.itemsize) is None:
+        return "xla", f"widths {d} and {f} are not whole lanes of 128"
+    if xs.shape[0] < ROW_TILE:
+        return "xla", (f"{xs.shape[0]} rows are under one row tile of "
+                       f"{ROW_TILE}")
+    return "kernel", "tpu backend"
 
 
 def _expert_ffn(xs, w_gate, w_up, w_down, counts, layer=None,
@@ -254,18 +302,77 @@ def _expert_ffn(xs, w_gate, w_up, w_down, counts, layer=None,
     expert): where there is a ``w_gate`` the gated ``act(x W_gate) * (x
     W_up) W_down`` (``act`` ``"swiglu"``: silu; ``"reglu"``: relu), else the
     two-matrix ``relu(x W_up) ** 2 W_down``. Compute type in, compute type
-    out.
+    out. Gate and up are rounded to the compute type, the gate function runs
+    in float32, the product is rounded once, and so is the down product:
+    on BOTH paths.
 
     With ``layer`` the weights are a kind's whole stacked leaves ``[L,
-    count, ...]``, read where they lie: as ``L * count`` groups (the same
-    bytes) of which this layer's, from ``layer * count`` on, get ``counts``
-    and every other one no row. The grouped product reads the tiles of the
-    groups that have rows; ``w[layer]`` under a traced ``layer`` is a copy
-    of the whole layer's experts a matrix, because the product is a kernel
-    that wants a whole operand (14.7 ms of every call of the LongCat
-    engine, 32% of its cell's device time; ledger, PR 35). A stack in
-    another type than the rows', or of a width that is filled up below,
-    would be copied WHOLE: there the layer is cut out.
+    count, ...]``, read where they lie (``w[layer]`` under a traced
+    ``layer`` is a copy of the whole layer's experts a matrix: 14.7 ms of
+    every call of the LongCat engine; ledger, PR 35). Which way, from the
+    call's own shapes (:func:`expert_product_path`; the decode engine
+    counts the stacked calls where a program is traced, through
+    :func:`watch_stacked_calls`:
+    ``ray_tpu_serve_engine_expert_products{path}`` and
+    ``models.llama.expert_product_paths()``):
+
+    - ``kernel``: a prefill's rows (at least a row tile) on a TPU backend,
+      gated experts in the rows' type at widths of whole lanes: ONE
+      forward-only Pallas call (``ops/grouped_ffn.py``, imported here and
+      nowhere else) that reads tile ``w[layer, g]`` by index map, makes
+      gate, up and the activation in one pass over a row tile and writes
+      every result in the compute type. Under ``jax.grad`` it gives the
+      other path's transposes (:func:`_expert_kernel`).
+    - ``xla``: everything else, three ``jax.lax.ragged_dot`` products with
+      float32 results (:func:`_expert_xla`): a decode call's 6-16 rows (0.12
+      ms; PR 37), two-matrix experts, another backend, and every call with
+      ``layer=None`` (the trainer's step, whose scan hands each layer its
+      own slice), which also keeps a train process from ever importing
+      the kernel's module."""
+    if layer is not None:
+        path, reason = expert_product_path(xs, w_gate, w_up, layer)
+        for tell in _stacked_call_watchers:
+            tell(xs, w_up, path, reason)
+        if path == "kernel":
+            return _expert_kernel(xs, w_gate, w_up, w_down, counts,
+                                  jnp.asarray(layer, jnp.int32), act)
+    return _expert_xla(xs, w_gate, w_up, w_down, counts, layer, act)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _expert_kernel(xs, w_gate, w_up, w_down, counts, layer, act):
+    from ray_tpu.ops.grouped_ffn import grouped_ffn
+
+    return grouped_ffn(xs, w_gate, w_up, w_down, counts, layer, act=act)
+
+
+def _expert_kernel_fwd(xs, w_gate, w_up, w_down, counts, layer, act):
+    return (_expert_kernel(xs, w_gate, w_up, w_down, counts, layer, act),
+            (xs, w_gate, w_up, w_down, counts, layer))
+
+
+def _expert_kernel_bwd(act, res, ct):
+    # forward only: the transposes are the XLA path's, made again from the
+    # operands (its products are not kept)
+    *operands, counts, layer = res
+    _, transpose = jax.vjp(
+        lambda *a: _expert_xla(*a, counts, layer, act), *operands)
+    return (*transpose(ct), _float0(counts), _float0(layer))
+
+
+_expert_kernel.defvjp(_expert_kernel_fwd, _expert_kernel_bwd)
+
+
+def _expert_xla(xs, w_gate, w_up, w_down, counts, layer, act):
+    """:func:`_expert_ffn` as XLA's grouped products
+    (:func:`_grouped_dot`).
+
+    A stack (``layer``) in the rows' type is handed over as ``L * count``
+    groups (the same bytes) of which this layer's, from ``layer * count``
+    on, get ``counts`` and every other one no row: the grouped product
+    reads the tiles of the groups that have rows. A stack in another type
+    than the rows', or of a width that is filled up below, would be copied
+    WHOLE: there the layer is cut out.
 
     A width over 512 that is no multiple of it is filled up with zero
     columns (and zero rows of ``w_down``), which add nothing: on the TPU

@@ -79,16 +79,86 @@ def test_flash_kernels_compile_at_the_cells_shapes(cell, one_chip,
         >= 3
 
 
+def _scanned_experts(L, act):
+    """``_expert_ffn`` of every layer of a stack, under a scan that traces
+    the layer's number as the engine's does."""
+    from ray_tpu.ops.moe import _expert_ffn
+
+    def layers(xs, counts, w_gate, w_up, w_down):
+        def body(x, i):
+            return _expert_ffn(x, w_gate, w_up, w_down, counts[i], i,
+                               act), None
+
+        return jax.lax.scan(body, xs, jnp.arange(L, dtype=jnp.int32))[0]
+
+    return layers
+
+
+# (rows a call, layers, experts a layer, d, f, gate function): the routed
+# serving cells' prefill calls at their longest and shortest, and a block of
+# the held path's places (Keye: 131,072 of which some 32,768 have a group)
+EXPERTS = {
+    "smallthinker 16 pages": (98304, 8, 64, 2560, 768, "reglu"),
+    "smallthinker 5 pages": (30720, 8, 64, 2560, 768, "reglu"),
+    "keye 16 pages, a block": (131072, 8, 16, 2048, 768, "swiglu"),
+}
+
+
+@pytest.mark.parametrize("what", list(EXPERTS))
+def test_grouped_ffn_compiles_at_the_cells_shapes(what, one_chip,
+                                                  as_on_the_chip):
+    """``_expert_ffn`` on the kernel's path (``ops/grouped_ffn.py``) at
+    published widths, the stack read by the scan's traced layer number: ONE
+    Mosaic call that asks for its VMEM (an expert's three matrices twice
+    buffered), none of XLA's grouped products, and no float32 array of the
+    rows' length (the parent's three products and their conversions)."""
+    import re
+
+    rows, L, count, d, f, act = EXPERTS[what]
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(_scanned_experts(L, act)).lower(
+        arg(rows, d), arg(L, count, dtype=jnp.int32), arg(L, count, d, f),
+        arg(L, count, d, f), arg(L, count, f, d))
+    assert "scoped_memory_configs" in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%moe_ffn" in text and "ragged-dot" not in text
+    assert not re.findall(rf"f32\[{rows},\d+\]", text)
+
+
+@pytest.mark.parametrize("rows", [6, 16, 200])
+def test_a_decode_calls_rows_stay_on_xlas_grouped_products(rows, one_chip,
+                                                          as_on_the_chip):
+    """Under a row tile of rows (a decode call's six, a batch's few) the
+    same stack goes through ``ragged_dot`` as it did (whose metadata the
+    compiler makes in a Mosaic call of its own): not through ``moe_ffn``."""
+    _, L, count, d, f, act = EXPERTS["smallthinker 16 pages"]
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(_scanned_experts(L, act)).lower(
+        arg(rows, d), arg(L, count, dtype=jnp.int32), arg(L, count, d, f),
+        arg(L, count, d, f), arg(L, count, f, d)).compile().as_text()
+    assert "moe_ffn" not in text
+    assert text.count("ragged-dot") >= 3
+
+
 @pytest.mark.parametrize("tokens", [1, 2048])
 def test_grouped_products_over_the_stack_read_it_where_it_lies(
         tokens, one_chip, as_on_the_chip):
     """``routed_mlp`` at LongCat's share (4 layers of 16 held experts, 6144
     x 2048, a 768-wide router, top-12) under a scan that traces the layer's
     number, at a decode call's one token and at a short prefill's 2,048:
-    every expert product is XLA's grouped kernel (of a decode call's 12
+    a decode call's expert products are XLA's grouped kernel (of its 12
     rows, not filled up to whole sublanes, the compiler makes a dense
-    product over every group), and nothing of a layer's experts' shape is
-    made (a copy of 403 MB a matrix and layer)."""
+    product over every group), a prefill's blocks of 2,048 places the
+    Pallas call (an expert's matrices, 75 MB, go by in column blocks), and
+    nothing of a layer's experts' shape is made (a copy of 403 MB a matrix
+    and layer)."""
     import re
 
     from ray_tpu.ops.moe import routed_mlp
@@ -112,7 +182,11 @@ def test_grouped_products_over_the_stack_read_it_where_it_lies(
         arg(L, wide, dtype=jnp.float32), arg(L, count, d, f),
         arg(L, count, d, f), arg(L, count, f, d)).compile()
     text = compiled.as_text()
-    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) >= 3
+    products = len(re.findall(r"%ragged-dot-none[.\d]* = ", text))
+    if tokens == 1:
+        assert products >= 3 and "moe_ffn" not in text
+    else:
+        assert products == 0 and "%moe_ffn" in text
     assert not re.findall(r"= f32\[(?:16|64),\d+,\d+\]\S* convolution\(",
                           text)
     made = re.findall(r"%(\S+) = bf16\[16,(?:6144,2048|2048,6144)\]\S* "
@@ -123,6 +197,92 @@ def test_grouped_products_over_the_stack_read_it_where_it_lies(
         assert compiled.memory_analysis().temp_size_in_bytes < 100e6
 
 
+def _lowered_for(one_chip, fn, *args):
+    """``fn`` lowered for the described chip at ``args``' shapes."""
+    return fn.lower(*jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)).as_text()
+
+
+def _train_step_text(one_chip, cfg):
+    from ray_tpu.train.spmd import build_train_mesh, make_spmd_train_step
+
+    init, step, *_ = make_spmd_train_step(
+        cfg, build_train_mesh("", list(one_chip.device_set)))
+    state = jax.eval_shape(init._fn, jax.random.PRNGKey(0))
+    return step._fn.lower(
+        state, jax.ShapeDtypeStruct((4, 33), jnp.int32)).as_text()
+
+
+def _engine_text(one_chip, cfg, program, n_pages):
+    """``program`` (``"prefill"`` / ``"decode"``) of a decode engine at
+    ``n_pages`` pages of 5, lowered as the engine jits it."""
+    import numpy as np
+
+    from ray_tpu.models import llama
+
+    shapes = jax.eval_shape(lambda key: llama.serving_params(
+        cfg, llama.init_params(cfg, key)), jax.random.PRNGKey(0))
+    engine = llama.LlamaDecodeEngine(
+        cfg, jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), shapes),
+        n_pages=24, page_size=5)
+    reach = min(n_pages, engine.window_pages)
+    window = [np.zeros(reach, np.int32)] if engine.n_slots else []
+    if program == "prefill":
+        fn, args = engine._prefill_fn, [
+            np.zeros((1, 5 * n_pages), np.int32),
+            np.zeros(n_pages, np.int32), np.int32(0), *window]
+    else:
+        fn, args = engine._decode_fn, [
+            np.zeros(1, np.int32), np.int32(0), np.zeros(n_pages, np.int32),
+            *window, *([np.int32(0)] if window else [])]
+    return _lowered_for(one_chip, getattr(fn, "_fn", fn), engine.params,
+                        *engine.stores, *args)
+
+
+def _whole_lanes_smallthinker():
+    import test_smallthinker as st
+
+    return st.program_cfg(hidden_size=128, moe_ffn_hidden_size=128,
+                          head_dim=32, num_hidden_layers=4,
+                          max_position_embeddings=120)
+
+
+# the programs the grouped kernel must NOT reach, each lowered for the chip
+# with every path rule asked as on a TPU: the routed and the patterned train
+# step (one layer's experts, out of the scan), a dense engine's prefill, a
+# routed engine's decode program at widths the kernel would take
+UNTOUCHED = {
+    "olmoe train step": lambda chip: _train_step_text(
+        chip, __import__("test_olmoe").program_cfg()),
+    "nemotron train step": lambda chip: _train_step_text(
+        chip, __import__("nemotron_h_small").program_cfg()),
+    "dense prefill": lambda chip: _engine_text(
+        chip, __import__("ray_tpu.models.llama", fromlist=["x"])
+        .LlamaConfig.debug(), "prefill", 4),
+    "routed decode": lambda chip: _engine_text(
+        chip, _whole_lanes_smallthinker(), "decode", 20),
+}
+
+
+@pytest.mark.parametrize("program", list(UNTOUCHED))
+def test_programs_that_must_not_change_hold_no_kernel_of_the_experts(
+        program, one_chip, as_on_the_chip):
+    """No Mosaic call that the parent did not have (at these sizes it had
+    none: sequences under the flash kernels' blocks), and the routed ones
+    still hold XLA's grouped products. PERF.md (PR 43) has the hashes of the
+    cells' own programs on both trees."""
+    text = UNTOUCHED[program](one_chip)
+    assert "tpu_custom_call" not in text and "moe_ffn" not in text
+    assert ("ragged_dot" in text) == (program != "dense prefill")
+
+
+def test_a_routed_prefill_of_the_same_engine_holds_the_kernel(
+        one_chip, as_on_the_chip):
+    """What the check above can see: 20 pages x 5 positions x 3 choices are
+    300 rows, over a row tile, and every layer's experts are ``moe_ffn``."""
+    text = _engine_text(one_chip, _whole_lanes_smallthinker(), "prefill", 20)
+    assert 'kernel_name = "moe_ffn"' in text and "ragged_dot" not in text
 # (positions, query heads, key/value heads, the score's width, a head's own
 # part of it, window): the serving cells' prefill attentions at their
 # longest, and at a page count whose keys are filled up to whole steps
