@@ -579,3 +579,78 @@ def test_the_stop_gradient_is_all_that_changed_the_streamed_steps(
     text = PROGRAMS[program][1]()
     assert hashlib.sha256(text.encode()).hexdigest() \
         == BEFORE_THE_CARRIED_GATHER_LEFT_THE_BACKWARD[program]
+
+
+# --- (g) the patterned families' serving programs are what they were -------- #
+
+
+def _family_text(family, program, n, n_pages, page, **file_keys):
+    """``program`` (``"prefill"`` / ``"decode"``) at ``n`` pages of the
+    engine ``tests/test_<family>.py`` builds at its tiny config (seeded
+    weights), lowered as the engine jits it."""
+    import importlib
+
+    cfg = importlib.import_module(f"test_{family}").program_cfg(**file_keys)
+    engine = llama.LlamaDecodeEngine(cfg, n_pages=n_pages, page_size=page)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    slots = min(n, engine.window_pages)
+    if program == "prefill":
+        args = (i32(1, n * page), i32(n), i32()) + (
+            (i32(slots),) if engine.n_slots else ())
+        fn = engine._prefill_fn
+    else:
+        args = (i32(1), i32(), i32(n)) + (
+            (i32(slots), i32()) if engine.n_slots else ())
+        fn = engine._decode_fn
+    return fn._fn.lower(engine.params, *engine.stores, *args).as_text()
+
+
+# sha256 of the lowered text (names off) of the patterned families' prefill
+# and decode, taken on 37a7761, the commit before the four serving layer
+# loops became ONE walker over a table of served kinds (PR 44): all-"S"
+# (LongCat; scanned even at two layers), "F" with "W" at 6 layers (less than
+# two periods: in line) and at 10 (two periods scanned, a rest of two in
+# line; 5 pages, so the window rows kept are the last 4 pages'), all-"I"
+# (Keye: a decode call whose pages hold no more than ``index_topk`` = 8
+# positions attends them all, one that holds more picks and gathers rows).
+# As ``PROGRAMS``: a PR that means to change a program replaces its hash.
+SERVED_PROGRAMS = {
+    "S prefill, 2 pages": (
+        "47c781408cf89d9ba82926eb02c646b4310eb462249b5181722237379b42881e",
+        lambda: _family_text("longcat_flash", "prefill", 2, 12, 8)),
+    "S decode, 2 pages": (
+        "84c78555f2a06494ce220c31c1161bbbf02602f78cdda188c3ec85b814a4a022",
+        lambda: _family_text("longcat_flash", "decode", 2, 12, 8)),
+    "FW in line, prefill, 5 pages": (
+        "de55a5c509d7f729080ee8d4bf304a5b8775858e71ddfe645719fd3f4b371776",
+        lambda: _family_text("smallthinker", "prefill", 5, 24, 5)),
+    "FW in line, decode, 5 pages": (
+        "8a1e7d4c312707e51e00f6c2fe91a40087139c2071780f917a8e330dbf3b1d27",
+        lambda: _family_text("smallthinker", "decode", 5, 24, 5)),
+    "FW scanned, prefill, 5 pages": (
+        "3a07dcddccc72f9c56274e6274cbcd3f38dd323ee81eba9bff7977e4c2a887ed",
+        lambda: _family_text("smallthinker", "prefill", 5, 24, 5,
+                             num_hidden_layers=10)),
+    "FW scanned, decode, 5 pages": (
+        "69570fbbadfe0478869d107eeff4537ccb3f3effc04700e156f3dfa397e3dd11",
+        lambda: _family_text("smallthinker", "decode", 5, 24, 5,
+                             num_hidden_layers=10)),
+    "I prefill, 3 pages": (
+        "041f739d4bb2810f0b92dae5efea191fcbf644a9b649d23897b1aaf94c8d4a88",
+        lambda: _family_text("keye_vl2", "prefill", 3, 24, 4)),
+    "I decode under topk, 2 pages": (
+        "f72ebbf11a1570926648e15a1a16331202b917df8048cba890203db59f8ac66c",
+        lambda: _family_text("keye_vl2", "decode", 2, 24, 4)),
+    "I decode past topk, 3 pages": (
+        "c7e009f784b970ab5b7b622f460a7f01bacdea6c8f33df65a565f5482c53371f",
+        lambda: _family_text("keye_vl2", "decode", 3, 24, 4)),
+}
+
+
+@pytest.mark.parametrize("program", list(SERVED_PROGRAMS))
+def test_served_program_lowers_to_the_same_text(program, names_off):
+    sha, lower = SERVED_PROGRAMS[program]
+    assert hashlib.sha256(lower().encode()).hexdigest() == sha
