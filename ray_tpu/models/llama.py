@@ -33,7 +33,8 @@ import math
 import threading
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from types import SimpleNamespace
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -63,13 +64,10 @@ _g_engine_weight_bytes = Gauge(
     "Bytes of model weights the decode engine keeps on the device, by "
     "the dtype they are held in", tag_keys=("dtype",))
 
-# what ONE position leaves in the engine's page store, over all layers: the
-# block's per-head keys and values (kind=kv) or a latent sublayer's row
-# (kind=latent); of a stack of full and window layers, a position's keys and
-# values in the full layers' stores (kind=full) and a SLOT position's in the
-# window layers' (kind=window); of a stack of indexed blocks, its keys and
-# values (kind=kv) and its index keys (kind=index); the kinds an engine has
-# not read 0
+# what ONE position (in a store by slot: one SLOT position) leaves in the
+# engine's page stores, over all layers, summed under the tag each store has
+# in the table of served kinds (SERVED's rows: kv, latent, full, window,
+# index); the tags an engine has not read 0
 _g_engine_page_bytes = Gauge(
     "ray_tpu_serve_engine_page_bytes",
     "Bytes one position holds in the decode engine's page store, by the "
@@ -1621,33 +1619,22 @@ def _selected_tiles(q, k, v, qi, ki, w, topk: int, cd, chunk: int):
 
 
 def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
-    """One layer of a patterned stack (``cfg.layer_pattern``). ``"S"`` is
-    the double layer (:func:`shortcut_layer`, its latent attention
-    expanded: :func:`attend_latent_expanded`), ``"F"`` / ``"W"`` the whole
-    block with full or windowed attention (:func:`window_block`, through
-    :func:`attend_window_tiles` whatever ``attend`` is: the trainer's flash
-    kernel has no window; prefill's forward-only one on a TPU backend, XLA
-    tiles elsewhere). Every other kind is a half of the block: ``x +
-    f(RMSNorm(x))`` with ``f`` the Mamba-2 mixer (``"M"``,
+    """One layer of a patterned stack (``cfg.layer_pattern``). A kind the
+    decode engine serves (a row of :data:`SERVED`: ``"S"``, ``"F"`` /
+    ``"W"``, ``"I"``) is that row's block over that row's attention of the
+    call's own positions, whatever ``attend`` is: the trainer's flash kernel
+    has no window and selects nothing (prefill's forward-only kernels on a
+    TPU backend, XLA tiles elsewhere). Every other kind is a half of the
+    block: ``x + f(RMSNorm(x))`` with ``f`` the Mamba-2 mixer (``"M"``,
     :func:`ray_tpu.ops.ssm.mamba2_mixer`), the routed
     feed-forward (``"E"``, :func:`_mlp_half`) or attention (``"*"``,
     :func:`_attn_half` over ``attend``). ``p``: this layer's weights, of its
-    kind. Returns ``(x, stats)``, ``stats`` ``{}`` but for ``"E"`` and
-    ``"S"``."""
-    if kind == "S":  # this layer's weights as a stack of one
-        return shortcut_layer(
+    kind. Returns ``(x, stats)``, ``stats`` ``{}`` but for ``"E"`` and the
+    served kinds."""
+    if kind in SERVED:  # this layer's weights as a stack of one
+        return SERVED[kind].block(
             cfg, x, jax.tree.map(lambda a: a[None], p), 0,
-            positions_of(*x.shape[:2]),
-            lambda j, *a: attend_latent_expanded(cfg, *a), stat_axes)[:2]
-    if kind in BLOCK_KINDS:
-        return window_block(
-            cfg, kind, x, jax.tree.map(lambda a: a[None], p), 0,
-            positions_of(*x.shape[:2]),
-            partial(attend_window_tiles, cfg, kind), stat_axes)[:2]
-    if kind == "I":  # whatever ``attend`` is: the trainer's kernel selects
-        return index_block(  # nothing
-            cfg, x, jax.tree.map(lambda a: a[None], p), 0,
-            positions_of(*x.shape[:2]), partial(attend_selected, cfg),
+            positions_of(*x.shape[:2]), partial(SERVED[kind].prefill, cfg),
             stat_axes)[:2]
     h = rms_norm(x, p["norm"], cfg.norm_eps).astype(cfg.dtype)
     stats = {}
@@ -1929,144 +1916,6 @@ def _write_pages(pages, rows, page_ids):
     return pages
 
 
-def page_rows(cfg: LlamaConfig):
-    """What the engine's page stores keep, by the layer kind: ``(kind,
-    [(S, row), ...])``, a store ``[S, n_pages, page_size, *row]`` each. The
-    block (``"kv"``) keeps per-head keys and values, a store each over its
-    layers; the double layer (``"latent"``) keeps ONE store of latent rows
-    over its ``2 * n_layers`` attention sublayers, from which decode never
-    expands keys or values. A stack of full and window layers
-    (``"window"``) keeps per-head keys and values in TWO pairs of stores:
-    the full layers' pair, indexed by pool page id as the others are, and
-    the window layers' pair, whose second dimension counts SLOTS and not
-    pages (the engine says how many and maps a page to its slot): a window
-    layer needs a position for ``cfg.window`` positions and no longer. A
-    stack of indexed blocks (``"index"``) keeps THREE stores by page id:
-    per-head keys, values, and the indexer's key, ONE head of
-    ``index_head_dim`` a layer, which every later decode call reads over ALL
-    of a sequence's pages to pick the rows of the other two it gathers."""
-    if "I" in cfg.kinds:
-        row = (cfg.n_kv_heads, cfg.head_dim)
-        return "index", [(cfg.n_layers, row)] * 2 \
-            + [(cfg.n_layers, (cfg.index_head_dim,))]
-    if set(cfg.kinds) & set(BLOCK_KINDS):
-        row = (cfg.n_kv_heads, cfg.head_dim)
-        return "window", [(cfg.kinds.count("F"), row)] * 2 \
-            + [(cfg.kinds.count("W"), row)] * 2
-    if cfg.layer_pattern:
-        return "latent", [(2 * cfg.n_layers, (cfg.latent_row,))]
-    return "kv", [(cfg.n_layers, (cfg.n_kv_heads, cfg.head_dim))] * 2
-
-
-def _latent_layers(cfg: LlamaConfig, x, layers, positions, attend, cache):
-    """The served double layers, scanned (every layer is ``"S"``, so one
-    compiled body; the scan carries the layer's number, not its weights:
-    see :func:`shortcut_layer`): layer ``l``'s sublayer ``j`` attends
-    through ``attend(cache[l], j, q, latent, wkv_b)``; ``cache`` ``[L, 2,
-    ...]`` (or None: nothing cached). Returns ``(x, latent rows
-    [2 L, B, T, latent_row], the routed assignments' shares, averaged over
-    layers)``."""
-
-    def body(carry, xs):
-        i, cached = xs
-        h, stats, latents = shortcut_layer(cfg, carry, layers["scmoe"], i,
-                                           positions, partial(attend, cached))
-        return h, (latents, stats)
-
-    x, (rows, stats) = jax.lax.scan(
-        body, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), cache))
-    shares = {k: stats[k].mean() for k in ("held_share", "zero_share")
-              if k in stats}
-    return x, rows.reshape(-1, *rows.shape[2:]), shares
-
-
-def _period(kinds: str) -> Tuple[str, int]:
-    """``(unit, times)``: the shortest ``unit`` whose repetition ``kinds``
-    is a prefix of, and how many whole units ``kinds`` holds."""
-    for n in range(1, len(kinds) + 1):
-        if all(kind == kinds[i % n] for i, kind in enumerate(kinds)):
-            return kinds[:n], len(kinds) // n
-    return "", 0
-
-
-def _block_layers(cfg: LlamaConfig, x, layers, positions, attend, cache,
-                  keep: Dict[str, int]):
-    """The served ``"F"`` / ``"W"`` layers (:func:`window_block`): the
-    pattern's whole periods scanned (one compiled body of a period's
-    layers; the scan carries the period's number, not its weights), the
-    layers of a period cut short, or of a stack of one period, in line.
-    Layer ``l`` of kind ``c`` attends through ``attend(c, cached, q, k,
-    v)``; ``cache``: ``{c: (keys, values)}`` with a leading dimension over
-    the layers of kind ``c`` (or None: nothing cached, and ``cached`` is
-    None). Returns ``(x, {c: (keys, values)})``: each layer's new keys and
-    values ``[n_c, B, keep[c], n_kv, head_dim]``, the LAST ``keep[c]``
-    positions of the call."""
-    kinds = cfg.kinds
-    unit, times = _period(kinds)
-    if times < 2:
-        unit, times = kinds, 0
-    per = {c: unit.count(c) for c in BLOCK_KINDS if c in kinds}
-    block = layers["block"]
-
-    def run(x, some, first, cached):
-        """Layers ``first ..`` of kinds ``some`` in line; ``cached``:
-        ``{c: (keys, values)}`` over THESE layers of kind ``c``."""
-        met, rows = dict.fromkeys(per, 0), {c: [] for c in per}
-        for j, c in enumerate(some):
-            mine = None if cached is None else tuple(
-                a[met[c]] for a in cached[c])
-            met[c] += 1
-            x, _, kv = window_block(cfg, c, x, block, first + j, positions,
-                                    partial(attend, c, mine))
-            rows[c].append(tuple(a[:, -keep[c]:] for a in kv))
-        return x, {c: tuple(jnp.stack(a) for a in zip(*rows[c]))
-                   for c in per if rows[c]}
-
-    done = {c: times * per[c] for c in per}
-    new = {c: [] for c in per}
-    if times:
-        def body(x, xs):
-            number, cached = xs
-            return run(x, unit, number * len(unit), cached)
-
-        scanned = None if cache is None else {
-            c: tuple(a[:done[c]].reshape(times, per[c], *a.shape[1:])
-                     for a in cache[c]) for c in per}
-        x, rows = jax.lax.scan(
-            body, x, (jnp.arange(times, dtype=jnp.int32), scanned))
-        for c in per:
-            new[c].append(tuple(a.reshape(-1, *a.shape[2:])
-                                for a in rows[c]))
-    rest = kinds[times * len(unit):]
-    if rest:
-        x, rows = run(x, rest, times * len(unit), None if cache is None else {
-            c: tuple(a[done[c]:] for a in cache[c]) for c in per})
-        for c in rows:
-            new[c].append(rows[c])
-    return x, {c: tuple(jnp.concatenate(a) if len(a) > 1 else a[0]
-                        for a in zip(*new[c])) for c in per}
-
-
-def _index_layers(cfg: LlamaConfig, x, layers, positions, attend, cache):
-    """The served indexed blocks, scanned (one compiled body; the scan
-    carries the layer's number, not its weights): layer ``l`` attends
-    through ``attend(l, cache[l], q, k, v, qi, ki, w)``; ``cache``: a tree
-    with a leading dimension over layers (or None: nothing cached).
-    Returns ``(x, (keys, values, index keys) [L, B, T, ...] each, the
-    routed assignments' shares, averaged over layers)``."""
-
-    def body(carry, xs):
-        i, cached = xs
-        h, stats, rows = index_block(cfg, carry, layers["index"], i,
-                                     positions, partial(attend, i, cached))
-        return h, (rows, stats)
-
-    x, (rows, stats) = jax.lax.scan(
-        body, x, (jnp.arange(cfg.n_layers, dtype=jnp.int32), cache))
-    shares = {k: stats[k].mean() for k in ("held_share",) if k in stats}
-    return x, rows, shares
-
-
 def _attend_selected_cached(cfg: LlamaConfig, k_pages, v_pages, page_ids,
                             pos, layer, ki_cache, q, kk, vv, qi, ki, w):
     """One new token at position ``pos`` (:func:`index_block`'s ``attend``
@@ -2126,75 +1975,6 @@ def _attend_selected_cached(cfg: LlamaConfig, k_pages, v_pages, page_ids,
     return o.reshape(1, 1, G * rep, -1).astype(cd)
 
 
-def prefill_with_cache(cfg: LlamaConfig, params, *args):
-    """Prefill one sequence into its pages, inside the program.
-
-    ``args``: ``*stores, tokens, page_ids, last`` and, for a stack with
-    window layers, ``slot_ids`` [min(n, k)] int32 behind them: the slots of
-    the LAST pages, the only ones whose window rows are written (``k``:
-    ``cfg.window_pages``). ``stores``: the engine's
-    page stores (:func:`page_rows`: ``k_pages, v_pages`` ``[L, n_pages,
-    page_size, n_kv, head_dim]`` for the block, ``latent_pages`` alone for
-    the double layer, the full layers' pair and the window layers' pair
-    for a stack of both, keys, values and index keys for a stack of indexed
-    blocks; donated by the engine, updated in place); ``tokens``
-    [1, n * page_size] int32, right-padded (causal masking keeps pad
-    garbage out of real positions); ``page_ids`` [n] int32; ``last`` int32
-    scalar, the last real position. Returns ``(*stores, logits [vocab]
-    fp32, shares)``: the post-RoPE keys and
-    values (the latent rows) of ALL ``n * page_size`` positions are
-    written, the pad positions of the last page included (they hold the pad
-    token's keys: finite, and masked by every decode until the sequence
-    itself overwrites them), and the head is applied to position ``last``
-    alone. ``shares``: a routed model's assignment shares (``{}`` for a
-    dense one)."""
-    kind, _ = page_rows(cfg)
-    if kind == "window":
-        *args, slot_ids = args
-    *stores, tokens, page_ids, last = args
-    x = embed_tokens(cfg, params, tokens, None)
-    positions = positions_of(*tokens.shape)
-    shares = {}
-    if kind == "window":
-        ps = stores[0].shape[2]
-        x, rows = _block_layers(
-            cfg, x.astype(jnp.float32), params["layers"], positions,
-            lambda c, cached, *a: attend_window_tiles(cfg, c, *a), None,
-            {"F": tokens.shape[1], "W": slot_ids.shape[0] * ps})
-        new, page_ids = rows["F"] + rows["W"], (page_ids,) * 2 \
-            + (slot_ids,) * 2
-    elif kind == "index":
-        x, new, shares = _index_layers(
-            cfg, x.astype(jnp.float32), params["layers"], positions,
-            lambda l, cached, *a: attend_selected(cfg, *a), None)
-    elif cfg.layer_pattern:
-        # keys and values expanded, attended by attend_tiles
-        x, rows, shares = _latent_layers(
-            cfg, x, params["layers"], positions,
-            lambda cached, j, *a: attend_latent_expanded(cfg, *a), None)
-        new = (rows,)
-    else:
-        def attend(q, k, v):
-            # plain attention, float32 scores: decode numerics never depend
-            # on prefill matching a fused kernel, only on the cached bytes
-            return plain_attention(q, *_gqa_repeat(cfg, k, v), causal=True)
-
-        def body(carry, layer_params):
-            h, _, kv = decoder_block(cfg, carry, layer_params, positions,
-                                     attend)
-            return h, kv
-
-        x, new = jax.lax.scan(body, x, params["layers"])
-    if kind != "window":
-        page_ids = (page_ids,) * len(stores)
-    stores = [_write_pages(pages, rows[:, 0], ids)
-              for pages, rows, ids in zip(stores, new, page_ids)]
-    # final_norm and the head are per position: one row, not T
-    x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-    logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
-    return (*stores, logits[0, 0], shares)
-
-
 def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv,
                    lowest=None):
     """One new token (``q``, ``kk``, ``vv``: the block's ``attend``
@@ -2246,99 +2026,404 @@ def _attend_latent_cached(cfg: LlamaConfig, cache, length, q, latent, wkv_b):
                       preferred_element_type=f32).astype(cd)
 
 
-def decode_step_with_cache(cfg: LlamaConfig, params, *args):
-    """One decode step of one sequence against the page stores.
+# --- the served kinds: ONE row a layer letter ------------------------------ #
 
-    ``args``: ``*stores, token, pos, page_ids`` and, for a stack with
-    window layers, ``slot_ids, first`` behind them. ``token`` [1] int32;
-    ``pos`` int32 scalar (the write position = tokens so far);
-    ``page_ids`` [n] int32, the sequence's page table in order;
+
+class Served(NamedTuple):
+    """One layer kind the decode engine serves: all that :func:`page_rows`,
+    the walker (:func:`_serve_layers`), the two programs and
+    :class:`LlamaDecodeEngine` know of it. Serving a new kind is a block
+    function, its attends and ONE row of :data:`SERVED`.
+
+    ``family``: :func:`page_rows`' name of the stores; the kinds of one
+    family are served TOGETHER and no others with them. ``stack``: the name
+    of its weights under ``params["layers"]`` (None: the dense tree; the
+    table says how either reaches the block). ``block(cfg, x, stack, which,
+    positions, attend)`` -> ``(x, stats, rows)``: the layer, ``which`` its
+    number in the stack (the dense block: its own weights), ``rows`` what it
+    keeps, an array a store (or the one array). ``f32``: the serving
+    stream is float32 and not ``cfg.dtype``. ``rows(cfg)``: ``[(tag,
+    sublayers, row shape, table), ...]``, what a layer keeps a position, a
+    store each: by the pool's page ids (``table`` ``"page"``) or by the
+    engine's map of a page to its SLOT (``"slot"``), counted by
+    ``ray_tpu_serve_engine_page_bytes{kind}`` under ``tag``.
+    ``prefill(cfg, *the block's attend arguments)``: attention over the
+    call's own positions. ``decode(cfg, call, l, mine, *the block's attend
+    arguments)``: one new token of the kind's ``l``-th layer against
+    ``mine``, that layer's page-padded views, one a row of ``rows``
+    (``[Tpad, *row]``; ``[sublayers, Tpad, *row]`` for several); ``call``
+    has ``pos`` (the write position), ``page_ids``, ``base`` (the position
+    of a SLOT view's first row) and ``stores`` (``{kind: its stores,
+    whole}``). ``attended(cfg, program, n)`` -> ``(visible, attended)``
+    keys of a prefill of ``n`` tokens or the decode of the ``n``-th, for a
+    kind that attends fewer than it sees
+    (``ray_tpu_serve_engine_selected_share{program}``)."""
+    family: str
+    stack: Optional[str]
+    block: Callable
+    f32: bool
+    rows: Callable
+    prefill: Callable
+    decode: Callable
+    attended: Optional[Callable] = None
+
+
+def _kv_rows(cfg: LlamaConfig, tag: str, table: str = "page"):
+    """Per-head keys and values, a store each over the kind's layers."""
+    return [(tag, 1, (cfg.n_kv_heads, cfg.head_dim), table)] * 2
+
+
+def _attend_pages(cfg: LlamaConfig, call, l, mine, *a):
+    """A decode call's token against its layer's keys' and values' pages,
+    every visible position."""
+    return _attend_cached(cfg, *mine, call.pos, *a)
+
+
+def _attend_slots(cfg: LlamaConfig, call, l, mine, *a):
+    """A window layer's views are the SLOTS of the pages its window reaches
+    (``call.base``: the position of their first row), masked by true
+    position: ``pos - window < j <= pos``."""
+    return _attend_cached(cfg, *mine, call.pos - call.base, *a,
+                          lowest=call.pos - cfg.window + 1 - call.base)
+
+
+def _attend_picked(cfg: LlamaConfig, call, l, mine, q, kk, vv, qi, ki, w):
+    """While an indexed block's table holds no more than ``index_topk``
+    positions every visible one is attended, from the keys' and values'
+    views; past that the index keys' view ALONE is read, and
+    ``index_topk`` ROWS of the other two stores are gathered where the
+    layer has picked them (:func:`_attend_selected_cached`). The views no
+    ``attend`` reads are never made: lowering drops what is dead (the
+    pinned texts of ``tests/test_olmoe.py SERVED_PROGRAMS`` hold that)."""
+    if mine[2].shape[0] <= cfg.index_topk:
+        return _attend_cached(cfg, *mine[:2], call.pos, q, kk, vv)
+    return _attend_selected_cached(
+        cfg, *call.stores["I"][:2], call.page_ids, call.pos, l, mine[2],
+        q, kk, vv, qi, ki, w)
+
+
+def _index_attended(cfg: LlamaConfig, program: str, n: int):
+    """A row that sees no more than ``index_topk`` keys attends them all."""
+    few = min(n, cfg.index_topk)
+    if program == "decode":
+        return n, few
+    return n * (n + 1) // 2, few * (few + 1) // 2 + (n - few) * few
+
+
+# The table, a comment a row. HOW A LAYER'S WEIGHTS REACH ITS BLOCK: a
+# patterned kind's stay stacked under ``params["layers"][stack]`` and every
+# matrix is cut out by the layer's number where it is used, the experts never
+# (:func:`shortcut_layer` has the readings: a whole layer cut out first is a
+# copy of 2.5 GB a layer, the experts' three of 403 MB a call). The dense
+# block's are the layer scan's ``xs``, and a decode call's new row leaves that
+# scan's body without its position where the others drop the batch behind
+# the scan: no measurement prefers either, each is what its programs' pinned
+# texts hold (``tests/test_olmoe.py``; the dense ones were taken before there
+# was a pattern).
+SERVED: Dict[str, Served] = {
+    # the dense block (a dense model is the pattern "b" * n_layers): per-head
+    # keys and values; prefill is plain attention over its few short pages
+    "b": Served(
+        "kv", None,
+        lambda cfg, x, stack, p, positions, attend: decoder_block(
+            cfg, x, p, positions, attend),
+        False, partial(_kv_rows, tag="kv"),
+        # float32 scores: decode numerics never depend on prefill matching a
+        # fused kernel, only on the cached bytes
+        lambda cfg, q, k, v: plain_attention(q, *_gqa_repeat(cfg, k, v),
+                                             causal=True), _attend_pages),
+    # the shortcut-connected double layer (latent attention, routed and
+    # identity experts): ONE store of latent rows, [kv_lora_rank +
+    # qk_rope_head_dim] an attention sublayer, from which prefill expands keys
+    # and values and decode never does (_attend_latent_cached)
+    "S": Served(
+        "latent", "scmoe", shortcut_layer, False,
+        lambda cfg: [("latent", 2, (cfg.latent_row,), "page")],
+        lambda cfg, j, *a: attend_latent_expanded(cfg, *a),
+        lambda cfg, call, l, mine, j, *a: _attend_latent_cached(
+            cfg, mine[0][j], call.pos, *a)),
+    # the whole routed block with full and with window attention, ONE stack
+    # in layer order: the full layers' keys and values by page id, the window
+    # layers' by SLOT (a window layer needs a position for cfg.window
+    # positions and no longer: the engine's WINDOW SLOTS). The stream is
+    # float32: a top-k choice is a hard one (window_block)
+    "F": Served(
+        "window", "block", lambda cfg, *a: window_block(cfg, "F", *a), True,
+        partial(_kv_rows, tag="full"),
+        lambda cfg, *a: attend_window_tiles(cfg, "F", *a), _attend_pages),
+    "W": Served(
+        "window", "block", lambda cfg, *a: window_block(cfg, "W", *a), True,
+        partial(_kv_rows, tag="window", table="slot"),
+        lambda cfg, *a: attend_window_tiles(cfg, "W", *a), _attend_slots),
+    # the indexed block (per-head QK-norm, a held range of experts): keys and
+    # values as the block's and a THIRD store, the indexer's ONE key head a
+    # layer, all three by page id; prefill attends under the selection
+    "I": Served(
+        "index", "index", index_block, True,
+        lambda cfg: _kv_rows(cfg, "kv")
+        + [("index", 1, (cfg.index_head_dim,), "page")],
+        attend_selected, _attend_picked, _index_attended),
+}
+
+
+class Store(NamedTuple):
+    """One page store of a served stack, ``[layers, n_pages or n_slots,
+    page_size, *row]``: ``kind``'s (``layers``: its layers times ``sub``,
+    the rows a layer keeps there), by ``table``, counted under ``tag``."""
+    kind: str
+    tag: str
+    sub: int
+    layers: int
+    row: tuple
+    table: str
+
+
+def served_kinds(cfg: LlamaConfig) -> str:
+    """The stack's layers as letters of :data:`SERVED`."""
+    return cfg.kinds or "b" * cfg.n_layers
+
+
+def served_stores(cfg: LlamaConfig) -> list:
+    """The engine's page stores, in THE order every program takes and
+    returns them: the table's kinds that the stack has, in the table's
+    order, each kind's ``rows`` in theirs."""
+    kinds = served_kinds(cfg)
+    return [Store(c, tag, sub, kinds.count(c) * sub, row, table)
+            for c, kind in SERVED.items() if c in kinds
+            for tag, sub, row, table in kind.rows(cfg)]
+
+
+def _by_kind(layout, values):
+    """``values``, one a store of ``layout``: ``{kind: [its own]}``."""
+    return {c: [v for v, s in zip(values, layout) if s.kind == c]
+            for c in dict.fromkeys(s.kind for s in layout)}
+
+
+def page_rows(cfg: LlamaConfig):
+    """What the engine's page stores keep: ``(family, [(S, row), ...])``, a
+    store ``[S, n_pages, page_size, *row]`` each (a store by slot counts
+    SLOTS there; the engine says how many): :func:`served_stores`, folded."""
+    stores = served_stores(cfg)
+    return ("+".join(dict.fromkeys(SERVED[s.kind].family for s in stores)),
+            [(s.layers, s.row) for s in stores])
+
+
+def _period(kinds: str) -> Tuple[str, int]:
+    """``(unit, times)``: the shortest ``unit`` whose repetition ``kinds``
+    is a prefix of, and how many whole units ``kinds`` holds."""
+    for n in range(1, len(kinds) + 1):
+        if all(kind == kinds[i % n] for i, kind in enumerate(kinds)):
+            return kinds[:n], len(kinds) // n
+    return "", 0
+
+
+def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
+                  keep):
+    """THE served layers, whatever their kinds: the pattern's whole periods
+    scanned (one compiled body of a period's layers; the scan carries the
+    period's number and not a patterned kind's weights), what a last period
+    cut short leaves in line. A period of ONE layer is scanned whatever the
+    depth; a longer one from two repetitions on, and in line below that.
+
+    The ``l``-th layer of kind ``c`` runs ``SERVED[c].block`` and attends
+    through ``attends[c](l, mine, *the block's arguments)``. ``cache`` and
+    ``keep`` have an entry a store (:func:`served_stores`): the store's
+    view ``[layers, Tpad, *row]``, of which ``mine`` are the layer's own,
+    or ``cache`` None (a prefill: nothing cached, ``mine`` None); and how
+    many of the call's LAST positions a layer's rows leave. Returns ``(x,
+    rows, shares)``: ``rows`` a store, ``[layers, B, n, *row]`` for a
+    prefill and ``[layers, 1, *row]`` for a decode call's one new position;
+    ``shares``: the routed assignments' shares, each averaged over the
+    layers that report it."""
+    kinds, layout = served_kinds(cfg), served_stores(cfg)
+    subs = _by_kind(layout, [s.sub for s in layout])
+    keep = _by_kind(layout, keep)
+    table = {c: SERVED[c] for c in subs}
+    if any(kind.f32 for kind in table.values()):
+        x = x.astype(jnp.float32)
+    if cache is not None:  # a layer's rows together: [n_c, sub, Tpad, *row]
+        cache = {c: [a if sub == 1 else a.reshape(-1, sub, *a.shape[1:])
+                     for a, sub in zip(views, subs[c])]
+                 for c, views in _by_kind(layout, cache).items()}
+    unit, times = _period(kinds)
+    if times < 2 and len(unit) > 1:
+        times = 0
+    # a period of one layer IS that layer: the scan's own stacking is the
+    # layers', with no stack of one in the body and no reshape around it
+    one = len(unit) == 1
+    per = {c: unit.count(c) for c in table}
+    done = {c: times * per[c] for c in table}
+
+    def layer(x, c, which, l, mine):
+        kind = table[c]
+        x, stats, rows = kind.block(
+            cfg, x, layers if kind.stack is None else layers[kind.stack],
+            which, positions, partial(attends[c], l, mine))
+        rows = rows if isinstance(rows, tuple) else (rows,)
+        if cache is not None and kind.stack is None:  # the table's comment
+            rows = tuple(a[:, 0] for a in rows)
+        else:  # the positions' axis stands behind [sublayers,] B
+            rows = tuple(a[:, :, -n:] if sub > 1 else a[:, -n:]
+                         for a, sub, n in zip(rows, subs[c], keep[c]))
+        return x, (rows, {k: stats[k] for k in ("held_share", "zero_share")
+                          if k in stats})
+
+    def run(x, some, first, start, cached):
+        """Layers of kinds ``some`` in line; the first of them is layer
+        ``first`` of the stack (a served family keeps ONE: a layer's
+        number in the pattern is its number there) and ``start[c]`` among
+        ``c``'s; ``cached``: ``{c: views}`` over THESE layers. Returns ``(x,
+        {c: (rows, shares)})``, a leading dimension over ``c``'s layers."""
+        out = {c: [] for c in table}
+        for j, c in enumerate(some):
+            n = len(out[c])
+            mine = None if cached is None else [a[n] for a in cached[c]]
+            x, new = layer(x, c, first + j, start[c] + n, mine)
+            out[c].append(new)
+        return x, {c: jax.tree.map(lambda *a: jnp.stack(a), *out[c])
+                   for c in table if out[c]}
+
+    def views(lo, hi, *lead):
+        """The cached views of each kind's layers ``lo[c] .. hi[c]`` (that
+        dimension as ``[*lead, the rest]``)."""
+        return None if cache is None else {
+            c: [a[lo[c]:hi[c]].reshape(*lead, -1, *a.shape[1:])
+                for a in cache[c]] for c in table}
+
+    out = {c: [] for c in table}
+    if times:
+        def period(x, xs):
+            number, own, cached = xs
+            if one:
+                x, new = layer(x, unit, number if own is None else own,
+                               number,
+                               None if cached is None else cached[unit])
+                return x, {unit: new}
+            return run(x, unit, number * len(unit),
+                       {c: number * per[c] for c in table}, cached)
+
+        cached = views(dict.fromkeys(table, 0), done,
+                       *(() if one else (times,)))
+        x, new = jax.lax.scan(period, x, (
+            jnp.arange(times, dtype=jnp.int32),
+            layers if one and table[unit].stack is None else None, cached))
+        for c in table:
+            out[c].append(new[c] if one else jax.tree.map(
+                lambda a: a.reshape(-1, *a.shape[2:]), new[c]))
+    rest = kinds[times * len(unit):]
+    if rest:
+        x, new = run(x, rest, times * len(unit), done,
+                     views(done, dict.fromkeys(table)))
+        for c in new:
+            out[c].append(new[c])
+    out = {c: jax.tree.map(lambda *a: jnp.concatenate(a), *out[c])
+           for c in table}
+    shares: Dict[str, list] = {}
+    for _, share in out.values():
+        for k, a in share.items():
+            shares.setdefault(k, []).append(a.reshape(-1))
+    shares = {k: jnp.concatenate(a).mean() for k, a in shares.items()}
+    rows = [a if sub == 1 else a.reshape(-1, *a.shape[2:])
+            for c, (mine, _) in out.items() for a, sub in zip(mine, subs[c])]
+    if cache is not None:  # the one new row: the batch's 1 for its position
+        rows = [a if SERVED[s.kind].stack is None else a[:, 0]
+                for a, s in zip(rows, layout)]
+    return x, rows, shares
+
+
+def prefill_with_cache(cfg: LlamaConfig, params, *args):
+    """Prefill one sequence into its pages, inside the program: embed, the
+    layers (:func:`_serve_layers`), each store written through ITS table,
+    the head.
+
+    ``args``: ``*stores, tokens, page_ids, last`` and, for a stack with a
+    store by slot (window layers), ``slot_ids`` [min(n, k)] int32 behind
+    them: the slots of the LAST pages, the only ones whose window rows are
+    written (``k``: ``cfg.window_pages``). ``stores``: the engine's page
+    stores (:func:`served_stores`; donated by the engine, updated in place);
+    ``tokens`` [1, n * page_size] int32, right-padded (causal masking keeps
+    pad garbage out of real positions); ``page_ids`` [n] int32; ``last``
+    int32 scalar, the last real position. Returns ``(*stores, logits
+    [vocab] fp32, shares)``: what every layer keeps (rotated keys and
+    values, latent rows, index keys) of ALL ``n * page_size`` positions is
+    written, the pad positions of the last page included (they hold the pad
+    token's keys: finite, and masked by every decode until the sequence
+    itself overwrites them), and the head is applied to position ``last``
+    alone. ``shares``: a routed model's assignment shares (``{}`` for a
+    dense one)."""
+    layout, slot_ids = served_stores(cfg), None
+    if any(s.table == "slot" for s in layout):
+        *args, slot_ids = args
+    *stores, tokens, page_ids, last = args
+    ids = {"page": page_ids, "slot": slot_ids}
+    x = embed_tokens(cfg, params, tokens, None)
+    positions = positions_of(*tokens.shape)
+    keep = {"page": tokens.shape[1], "slot": 0 if slot_ids is None
+            else slot_ids.shape[0] * stores[0].shape[2]}
+    x, rows, shares = _serve_layers(
+        cfg, x, params["layers"], positions,
+        {c: lambda l, mine, *a, c=c: SERVED[c].prefill(cfg, *a)
+         for c in {s.kind for s in layout}},
+        None, [keep[s.table] for s in layout])
+    stores = [_write_pages(pages, new[:, 0], ids[s.table])
+              for pages, new, s in zip(stores, rows, layout)]
+    # final_norm and the head are per position: one row, not T
+    x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+    logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
+    return (*stores, logits[0, 0], shares)
+
+
+def decode_step_with_cache(cfg: LlamaConfig, params, *args):
+    """One decode step of one sequence against the page stores: each
+    store gathered through ITS table where its kind's row says so
+    (``SERVED[c].decode``), embed, the layers (:func:`_serve_layers`), the
+    head, the new position's rows written.
+
+    ``args``: ``*stores, token, pos, page_ids`` and, for a stack with a
+    store by slot (window layers), ``slot_ids, first`` behind them.
+    ``token`` [1] int32; ``pos`` int32 scalar (the write position = tokens
+    so far); ``page_ids`` [n] int32, the sequence's page table in order;
     ``slot_ids`` [min(n, k)] int32: the slots of the table's pages ``first
     ..`` (``first`` int32 scalar), which hold every position ``pos``'s
-    window reaches and ``pos`` itself: a window layer gathers those alone,
-    masks by true position (``pos - window < j <= pos``) and writes the new
-    position's keys and values into its page's slot.
+    window reaches and ``pos`` itself: a store by slot gathers those alone
+    and writes the new position's rows into its page's slot.
     The table's pages are gathered on the device into the ``[S, n *
     page_size, *row]`` view of each store (positions >= ``pos`` are
-    masked), and the new position's keys and values (its latent rows) are
-    written at ``(page_ids[pos // page_size], pos % page_size)``. A stack
-    of indexed blocks whose table holds more than ``index_topk`` positions
-    gathers the index keys' pages alone and, a layer, the ``index_topk``
-    chosen ROWS of keys and values (:func:`_attend_selected_cached`).
-    Returns
-    ``(*stores, logits [vocab] fp32)``. ``pos`` and the page ids are
-    traced, so one compilation covers every step at a given page count."""
-    kind, _ = page_rows(cfg)
-    if kind == "window":
+    masked), and what the new position keeps is written at
+    ``(page_ids[pos // page_size], pos % page_size)``. Returns ``(*stores,
+    logits [vocab] fp32)``. ``pos`` and the page ids are traced, so one
+    compilation covers every step at a given page count."""
+    layout, slot_ids = served_stores(cfg), None
+    if any(s.table == "slot" for s in layout):
         *args, slot_ids, first = args
     *stores, token, pos, page_ids = args
+    ids = {"page": page_ids, "slot": slot_ids}
     ps = stores[0].shape[2]
-    tables = ((page_ids,) * 2 + (slot_ids,) * 2 if kind == "window"
-              else (page_ids,) * len(stores))
-    # an indexed block past index_topk reads its index keys' pages alone:
-    # the other two stores' rows are gathered where a layer has chosen them
-    pick = kind == "index" and page_ids.shape[0] * ps > cfg.index_topk
-    read = stores[2:] if pick else stores
-    cached = [_read_pages(pages, ids)
-              for pages, ids in zip(read, tables[-len(read):])]
+    cached = [_read_pages(pages, ids[s.table])
+              for pages, s in zip(stores, layout)]
     x = embed_tokens(cfg, params, token[None, :], None)
     positions = jnp.full((1, 1), pos, dtype=jnp.int32)
-    if kind == "index":
-        if pick:
-            attend = partial(_attend_selected_cached, cfg, *stores[:2],
-                             page_ids, pos)
-        else:  # every visible position is attended: the pages' views
-            def attend(l, mine, q, kk, vv, qi, ki, w):
-                return _attend_cached(cfg, *mine[:2], pos, q, kk, vv)
-
-        x, rows, _ = _index_layers(
-            cfg, x.astype(jnp.float32), params["layers"], positions, attend,
-            cached[0] if pick else tuple(cached))
-        new = [a[:, 0] for a in rows]
-    elif kind == "window":
-        base = first * ps  # the position of the window view's first row
-
-        def attend(c, mine, *a):
-            if c == "F":
-                return _attend_cached(cfg, *mine, pos, *a)
-            return _attend_cached(cfg, *mine, pos - base, *a,
-                                  lowest=pos - cfg.window + 1 - base)
-
-        x, rows = _block_layers(
-            cfg, x.astype(jnp.float32), params["layers"], positions, attend,
-            {"F": cached[:2], "W": cached[2:]}, {"F": 1, "W": 1})
-        new = [a[:, 0] for a in rows["F"] + rows["W"]]
-    elif cfg.layer_pattern:
-        x, rows, _ = _latent_layers(
-            cfg, x, params["layers"], positions,
-            lambda cached, j, *a: _attend_latent_cached(
-                cfg, cached[j], pos, *a),
-            cached[0].reshape(cfg.n_layers, 2, *cached[0].shape[1:]))
-        new = (rows[:, 0],)
-    else:
-        def body(carry, xs):
-            p, kc, vc = xs
-            h, _, (kn, vn) = decoder_block(
-                cfg, carry, p, positions,
-                partial(_attend_cached, cfg, kc, vc, pos))
-            return h, (kn[:, 0], vn[:, 0])
-
-        x, new = jax.lax.scan(body, x, (params["layers"], *cached))
+    call = SimpleNamespace(
+        pos=pos, page_ids=page_ids, stores=_by_kind(layout, stores),
+        base=None if slot_ids is None else first * ps)
+    x, rows, _ = _serve_layers(
+        cfg, x, params["layers"], positions,
+        {c: partial(SERVED[c].decode, cfg, call) for c in call.stores},
+        cached, [1] * len(layout))
     logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
-    at = [(0, page_ids[pos // ps], pos % ps)] * len(stores)
-    if kind == "window":
-        at[2:] = [(0, slot_ids[pos // ps - first], pos % ps)] * 2
+    at = {"page": (0, page_ids[pos // ps], pos % ps)}
+    if slot_ids is not None:
+        at["slot"] = (0, slot_ids[pos // ps - first], pos % ps)
     stores = [jax.lax.dynamic_update_slice(
-        pages, rows[:, :, None].astype(pages.dtype),
-        where + (0,) * (pages.ndim - 3))
-        for pages, rows, where in zip(stores, new, at)]
+        pages, new[:, :, None].astype(pages.dtype),
+        at[s.table] + (0,) * (pages.ndim - 3))
+        for pages, new, s in zip(stores, rows, layout)]
     return (*stores, logits[0, 0])
 
 
-def copy_page_in_stores(*args):
-    """Physical page ``src`` duplicated into ``dst`` (both traced) in every
-    store: ``args`` is ``*stores, src, dst``."""
-    *stores, src, dst = args
+def copy_page_in_stores(stores, src, dst):
+    """Slab ``src`` duplicated into ``dst`` (both traced: page ids, or
+    slots) in every one of ``stores``, the stores of ONE table."""
     return tuple(jax.lax.dynamic_update_slice(
         pages, _page_slab(pages, src), (0, dst) + (0,) * (pages.ndim - 2))
         for pages in stores)
@@ -2393,58 +2478,40 @@ class LlamaDecodeEngine:
     engine protocol :class:`ray_tpu.serve.decode.DecodeScheduler` drives
     (prefill/decode/copy_page + pool/prefix_cache/page_size).
 
-    It serves a stack of dense blocks, a stack of shortcut-connected
-    double layers (``layer_pattern`` all ``"S"``: latent attention, routed
-    and identity experts), a stack of whole routed blocks with full and
-    window attention (``"F"`` and ``"W"``, both present: window_block) and
-    a stack of indexed blocks (all ``"I"``: index_block, per-head QK-norm,
-    a held range of experts, attention over the keys an indexer picks); a
-    Mamba layer's state has no page kind yet, and no test holds
-    whole-projection QK-norm, the ``"E"`` / ``"*"`` halves or the
-    UNPATTERNED routed block to a reference here, so those are refused.
+    A KIND IS A ROW OF :data:`SERVED`: what a layer letter keeps a position,
+    by which table, through which block, attended how in prefill and in
+    decode is said there and nowhere here. The engine serves a stack whose
+    kinds are ONE family's, all of them (dense blocks; all ``"S"``; ``"F"``
+    with ``"W"``; all ``"I"``). A kind without a row (a Mamba layer's state
+    has no page kind yet; the ``"E"`` / ``"*"`` halves), a part or a mix of
+    families, whole-projection QK-norm and the UNPATTERNED routed block are
+    refused: no test holds their logits to a reference here.
 
-    ``params`` is the tree the programs run on: ``init_params``' layout
-    with the matmul weights (``embedding``, ``lm_head``, ``wq`` .. ``w_down``,
-    the latent projections, dense feed-forwards and experts of a double
-    layer) in ``cfg.dtype``, converted ONCE here and not inside every call,
-    and the norms, a router and its bias in float32. A float32 tree passed in (a trainer's) is
-    converted and not kept; leaves already in ``cfg.dtype`` are kept as
-    they are. At bfloat16 that is two bytes a parameter on the device
-    (3.8 GB at 1.89B parameters), which
+    ``params`` is the tree the programs run on, :func:`serving_params`': the
+    matmul weights in ``cfg.dtype``, converted ONCE here and not inside
+    every call, the norms, a router and its bias in float32. A float32 tree
+    passed in (a trainer's) is converted and not kept. At bfloat16 that is
+    two bytes a parameter on the device (3.8 GB at 1.89B parameters), which
     ``ray_tpu_serve_engine_weight_bytes{dtype}`` reports.
 
-    Physical pages live ON THE DEVICE, in float32 arrays indexed by pool
-    page id, ``stores``: what a position keeps there is the layer kind's
-    (:func:`page_rows`). The block: two stores, per-head keys and values,
-    ``[L, n_pages, page_size, n_kv, head_dim]`` each (``2 * n_pages *
-    page_size * L * n_kv * head_dim * 4`` bytes beside the weights). The
-    double layer: ONE store of latent rows ``[2 L, n_pages, page_size,
-    kv_lora_rank + qk_rope_head_dim]``, a row an attention sublayer, from
-    which prefill expands keys and values and decode never does
-    (``ray_tpu_serve_engine_page_bytes{kind}``: a position's bytes). A
-    stack of full and window layers: the full layers' keys and values
-    ``[L_full, n_pages, page_size, n_kv, head_dim]`` by page id, and the
-    window layers' ``[L_window, n_slots, page_size, n_kv, head_dim]`` by
-    SLOT (below). A stack of indexed blocks: keys and values as the block's
-    and a THIRD store ``[L, n_pages, page_size, index_head_dim]``, the
-    indexer's ONE key head a layer, all three by page id; prefill writes
-    all three, and a decode call whose table holds more than ``index_topk``
-    positions reads the index keys' pages whole, picks, and gathers
-    ``index_topk`` ROWS of the other two a layer in place of their pages
-    (``ray_tpu_serve_engine_selected_share{program}``: what the last call
-    attended of what it saw). They
-    are read and written only inside three jitted programs that take them
-    donated and return them: prefill writes the scan's rows into the pages
-    it is given, decode gathers the sequence's page table into a
-    page-padded view (positions beyond the true length are masked, so a
-    compilation per page count serves every sequence and step) and writes
-    the new position, copy_page duplicates one page. A call moves token ids and page ids in and one ``[vocab]``
-    row of float32 logits out.
+    THE STORES. Physical pages live ON THE DEVICE, in float32 arrays,
+    ``stores`` (:func:`served_stores`: one a row of every kind's ``rows``):
+    ``[layers * sublayers, n_pages, page_size, *row]`` by pool page id for
+    a row whose table is ``"page"``, ``[.., n_slots, ..]`` by SLOT (below)
+    for ``"slot"``. They are read and written only inside three jitted
+    programs that take them donated and return them: prefill writes the
+    layers' rows into the pages (slots) it is given, decode gathers the
+    sequence's page table into a page-padded view (positions beyond the
+    true length are masked, so a compilation per page count serves every
+    sequence and step) and writes the new position, copy_page duplicates
+    one page, the stores of one table a call. A call moves token ids and
+    page ids in and one ``[vocab]`` row of float32 logits out.
 
     WINDOW SLOTS. A window layer needs a position's keys and values for
     ``cfg.window`` positions and no longer, so its stores have ``n_slots``
     page slabs, fewer than the pool has pages, and the engine owns the map
-    pool page -> slot. With ``k = cfg.window_pages(page_size)`` (the pages a
+    pool page -> slot (``n_slots`` is non-zero exactly when a store goes by
+    slot). With ``k = cfg.window_pages(page_size)`` (the pages a
     window can touch) and ``P = ceil(max_seq_len / page_size)`` (the longest
     sequence's pages)::
 
@@ -2473,30 +2540,6 @@ class LlamaDecodeEngine:
     NOT freed behind a long decode as it advances.
     ``ray_tpu_serve_engine_window_slots{state}`` reports total and used.
 
-    PREFILL'S ATTENTION of the latent, the full and the window layers is
-    :func:`attend_tiles`: on a TPU backend one forward-only Pallas call
-    (``ops/flash_prefill.py``: a split score for the latent layers, GQA by
-    index map, a window layer's key blocks from its band on), on every other
-    backend the XLA tile loop; ``ray_tpu_serve_engine_prefill_attend{kind,
-    path}`` counts which, where a program is traced
-    (:func:`prefill_attend_paths` has the reason). An indexed block's is
-    :func:`attend_selected`, counted as kind ``selected``: two Pallas calls
-    on a TPU backend (``ops/sparse_prefill.py``: index scores and the
-    selection as a mask, then flash attention under it), XLA tiles
-    elsewhere. The dense block's prefill
-    is ``plain_attention`` over its few short pages.
-
-    THE EXPERTS' PRODUCTS of the routed kinds come from the kind's stacked
-    leaves by the layer's number (``ops/moe.py _expert_ffn``): a prefill's
-    rows on a TPU backend in one forward-only Pallas call
-    (``ops/grouped_ffn.py``: weights by index map, gate, up and the
-    activation in one pass, results in ``cfg.dtype``), a decode call's few
-    rows and every other backend in XLA's grouped products over the groups
-    ``ray_tpu_serve_engine_expert_groups{part}`` reports;
-    ``ray_tpu_serve_engine_expert_products{path}`` (``kernel`` / ``xla``)
-    counts which, where a program is traced
-    (:func:`expert_product_paths` has the reason).
-
     One caller at a time (the scheduler's lock covers a whole iteration):
     a call hands the stores to its program and takes the returned ones."""
 
@@ -2507,16 +2550,23 @@ class LlamaDecodeEngine:
         from ray_tpu.serve.kv_cache import PagePool, PrefixCache
 
         self.cfg = cfg or LlamaConfig.debug()
-        if set(self.cfg.kinds) not in ({"S"}, set(BLOCK_KINDS), {"I"}):
-            _dense_only(
+        families: Dict[str, str] = {}  # the table's kinds, by family
+        for c, kind in SERVED.items():
+            families[kind.family] = families.get(kind.family, "") + c
+        kinds = set(served_kinds(self.cfg))
+        if not (self.cfg.layer_pattern
+                and kinds in map(set, families.values())):
+            _dense_only(  # a dense stack's own refusals, or the pattern's
                 self.cfg, "LlamaDecodeEngine",
-                "of the patterned kinds it serves all-'S', 'F' with 'W' and "
-                "all-'I' (a Mamba layer's state has no page kind, and its "
-                "stores are the double layer's, the two pairs of a stack "
-                "that has both full and window layers, or the three of a "
-                "stack of indexed blocks), and for whole-projection "
-                "QK-norm, the 'E' / '*' halves or an unpatterned routed "
-                "block no test compares its logits with the reference")
+                f"it serves the kinds of ONE family of its table (SERVED: "
+                f"{' | '.join(families.values())}), all of them (both full "
+                f"and window layers), and this stack has "
+                f"{' '.join(sorted(kinds))}, of which the table lacks "
+                f"{' '.join(sorted(kinds - set(SERVED))) or 'none'} (a "
+                f"Mamba layer's state has no page kind); for the 'E' / '*' "
+                f"halves, a part or a mix of families, whole-projection "
+                f"QK-norm or an unpatterned routed block no test compares "
+                f"its logits with the reference")
         if params is None:
             # one jitted program, not a dozen eager ones: at 664.6M
             # parameters the eager form spends 67 s on a v5e, nearly all of
@@ -2540,11 +2590,15 @@ class LlamaDecodeEngine:
         self.pool = PagePool(n_pages, page_size)
         self.prefix_cache = PrefixCache(self.pool)
         self._np = np
-        kind, rows = page_rows(self.cfg)
-        # a window store's slabs (the class docstring's rule); the slots'
-        # map is one caller's at a time, as the stores are
+        layout = served_stores(self.cfg)
+        # the stores' places by their table: a program's and a copy's
+        self._by_table = {table: [i for i, s in enumerate(layout)
+                                  if s.table == table]
+                          for table in ("page", "slot")}
+        # a store by slot has n_slots slabs (the class docstring's rule);
+        # the slots' map is one caller's at a time, as the stores are
         self.window_pages = self.n_slots = 0
-        if kind == "window":
+        if self._by_table["slot"]:
             self.window_pages = self.cfg.window_pages(page_size)
             longest = -(-self.cfg.max_seq_len // page_size)
             self.n_slots = min(n_pages, -(-n_pages // longest)
@@ -2553,23 +2607,15 @@ class LlamaDecodeEngine:
         self._free_slots = list(range(self.n_slots - 1, -1, -1))
         if self.n_slots:
             self.pool.release_hooks.append(self._free_slots_of)
-        slabs = [n_pages] * len(rows)
-        if kind == "window":
-            slabs[2:] = [self.n_slots] * 2
         self.stores = tuple(
-            jnp.zeros((S, n, page_size, *row), jnp.float32)
-            for (S, row), n in zip(rows, slabs))
-        # every tag always, as above
-        page_bytes = {"kv": 0, "latent": 0, "full": 0, "window": 0,
-                      "index": 0}
-        per_store = [4 * S * math.prod(row) for S, row in rows]
-        if kind == "window":
-            page_bytes.update(full=sum(per_store[:2]),
-                              window=sum(per_store[2:]))
-        elif kind == "index":
-            page_bytes.update(kv=sum(per_store[:2]), index=per_store[2])
-        else:
-            page_bytes[kind] = sum(per_store)
+            jnp.zeros((s.layers, self.n_slots if s.table == "slot"
+                       else n_pages, page_size, *s.row), jnp.float32)
+            for s in layout)
+        # every tag of the table always, as above
+        page_bytes = {tag: 0 for kind in SERVED.values()
+                      for tag, *_ in kind.rows(self.cfg)}
+        for s in layout:
+            page_bytes[s.tag] += 4 * s.layers * math.prod(s.row)
         for tag, nbytes in page_bytes.items():
             _g_engine_page_bytes.set(float(nbytes), tags={"kind": tag})
         self._note_slots()
@@ -2578,14 +2624,15 @@ class LlamaDecodeEngine:
             from ray_tpu.ops.moe import expert_groups, watch_stacked_calls
 
             watch_stacked_calls(_note_expert_products)
-            w_up = self.params["layers"][
-                {"window": "block", "index": "index"}.get(kind, "scmoe")
-            ]["w_up"]
+            w_up = self.params["layers"][SERVED[layout[0].kind].stack]["w_up"]
             groups = {"program": expert_groups(w_up, self.cfg.dtype),
                       "layer": w_up.shape[1]}
         for part, n in groups.items():
             _g_engine_expert_groups.set(float(n), tags={"part": part})
-        donated = tuple(range(1, 1 + len(rows)))  # the stores, every call
+        # the kinds that attend fewer keys than they see say how many
+        self._attended = [SERVED[c].attended for c in sorted(kinds)
+                          if SERVED[c].attended]
+        donated = tuple(range(1, 1 + len(layout)))  # the stores, every call
         self._prefill_fn = observe_compiled(
             jax.jit(partial(prefill_with_cache, self.cfg),
                     donate_argnums=donated),
@@ -2595,20 +2642,16 @@ class LlamaDecodeEngine:
                     donate_argnums=donated),
             "llama.decode")
         self._copy_fn = observe_compiled(
-            jax.jit(copy_page_in_stores,  # a window stack's: a pair a call
-                    donate_argnums=tuple(range(
-                        2 if kind == "window" else len(rows)))),
+            jax.jit(copy_page_in_stores, donate_argnums=0),
             "llama.copy_page")
         self.prefill_calls = 0
         self.decode_calls = 0
         self._buckets: Dict[str, set] = {"prefill": set(), "decode": set()}
-        # compiled here: a server warms prefill and decode by running them,
-        # but may never copy a page before its first prefix hit
-        self.copy_page(0, 0)
-        if self.n_slots:  # and a window slab's copy, a shape of its own
-            self.stores = self.stores[:2] + self._copy_fn(
-                *self.stores[2:], np.asarray(0, np.int32),
-                np.asarray(0, np.int32))
+        # compiled here, a shape of its own a table: a server warms prefill
+        # and decode by running them, but may never copy a page before its
+        # first prefix hit
+        for table in self._by_table:
+            self._copy(table, 0, 0)
 
     # ---- window slots (a stack with window layers; else n_slots is 0)
 
@@ -2707,10 +2750,8 @@ class LlamaDecodeEngine:
         # a routed model's shares come with the logits: one read
         last, shares = jax.device_get((logits, shares))
         _sp_prefill_logits.end(_t, n_pages)
-        few = min(T, self.cfg.index_topk)  # rows that see no more than topk
-        self._note_selected("prefill", T * (T + 1) // 2,
-                            few * (few + 1) // 2 + (T - few) * few)
-        if shares or self.n_slots:  # every expert here: held reads 1.0
+        self._note_selected("prefill", T)
+        if shares or self.cfg.num_experts:  # every expert here: held is 1.0
             held = float(shares.get("held_share", 1.0))
             zero = float(shares.get("zero_share", 0.0))
             for part, share in (("held", held), ("zero", zero),
@@ -2751,36 +2792,39 @@ class LlamaDecodeEngine:
         out = np.asarray(logits, np.float32)
         _sp_decode_readback.end(_t, n_pages)
         _sp_decode.end(_t_call, n_pages)
-        self._note_selected("decode", pos + 1,
-                            min(pos + 1, self.cfg.index_topk))
+        self._note_selected("decode", pos + 1)
         return out
 
-    def _note_selected(self, program: str, visible: int, attended: int):
-        """A stack of indexed blocks: the share of the visible keys the
-        call's queries attended, from the call's own length."""
-        if self.cfg.index_topk:
-            _g_engine_selected_share.set(attended / visible,
+    def _note_selected(self, program: str, n: int) -> None:
+        """The share of the visible keys the call's queries attended, from
+        the call's own length ``n``, as the kinds that attend fewer say."""
+        for attended in self._attended:
+            visible, kept = attended(self.cfg, program, n)
+            _g_engine_selected_share.set(kept / visible,
                                          tags={"program": program})
 
-    def copy_page(self, src: int, dst: int) -> None:
-        np = self._np
-        if not self.n_slots:
-            self.stores = self._copy_fn(
-                *self.stores, np.asarray(src, np.int32),
-                np.asarray(dst, np.int32))
+    def _copy(self, table: str, src: int, dst: int) -> None:
+        """Slab ``src`` of the stores by ``table`` copied into ``dst``."""
+        np, which = self._np, self._by_table[table]
+        if not which:
             return
-        # the full layers' rows by page id; the window layers' by slot,
-        # where the source has one (the copy's is assigned first: short of
+        stores = list(self.stores)
+        copied = self._copy_fn(tuple(stores[i] for i in which),
+                               np.asarray(src, np.int32),
+                               np.asarray(dst, np.int32))
+        for i, pages in zip(which, copied):
+            stores[i] = pages
+        self.stores = tuple(stores)
+
+    def copy_page(self, src: int, dst: int) -> None:
+        # the stores by page id in one call; those by slot in one, where
+        # the source has a slot (the copy's is assigned first: short of
         # slots nothing is copied)
-        slots = ()
+        moves = {"page": (src, dst)}
         if src in self._slot_of:
-            slots = tuple(np.asarray(s, np.int32)
-                          for s in self._slots_for([src, dst]))
-        self.stores = self._copy_fn(
-            *self.stores[:2], np.asarray(src, np.int32),
-            np.asarray(dst, np.int32)) + (
-                self._copy_fn(*self.stores[2:], *slots) if slots
-                else self.stores[2:])
+            moves["slot"] = self._slots_for([src, dst])
+        for table, (a, b) in moves.items():
+            self._copy(table, a, b)
 
 
 # --------------------------------------------------------------------------- #

@@ -654,3 +654,75 @@ SERVED_PROGRAMS = {
 def test_served_program_lowers_to_the_same_text(program, names_off):
     sha, lower = SERVED_PROGRAMS[program]
     assert hashlib.sha256(lower().encode()).hexdigest() == sha
+
+
+# --- (h) the table of served kinds is what the engine is built from -------- #
+
+
+def _gauge(name):
+    from ray_tpu.util.metrics import registry
+
+    return {k[0][1]: v for k, v in registry().local_values(name).items()}
+
+
+@pytest.mark.parametrize("family,n_pages,page,held", [
+    ("dense", 16, 4, None),          # no routed kind: the share is not set
+    ("longcat_flash", 12, 8, 0.0),   # from the program's shares
+    ("smallthinker", 24, 5, 1.0),    # every expert here
+    ("keye_vl2", 24, 4, 0.0)])
+def test_the_engine_is_what_the_table_folds(family, n_pages, page, held):
+    """Stores, slots and the ``page_bytes`` tags are ``served_stores``'
+    (``SERVED`` folded over the stack), for every served family at its
+    tests' tiny config; the assignment shares are set for a routed stack
+    and for no other."""
+    import importlib
+    import math
+
+    cfg = (LlamaConfig.debug() if family == "dense" else
+           importlib.import_module(f"test_{family}").program_cfg())
+    engine = llama.LlamaDecodeEngine(cfg, n_pages=n_pages, page_size=page)
+    layout = llama.served_stores(cfg)
+    assert llama.page_rows(cfg)[1] == [(s.layers, s.row) for s in layout]
+    assert {llama.SERVED[s.kind].family for s in layout} \
+        == {llama.page_rows(cfg)[0]}
+    assert bool(engine.n_slots) == any(s.table == "slot" for s in layout)
+    assert [s.shape for s in engine.stores] == [
+        (s.layers, engine.n_slots if s.table == "slot" else n_pages, page,
+         *s.row) for s in layout]
+    want = {tag: 0.0 for kind in llama.SERVED.values()
+            for tag, *_ in kind.rows(cfg)}
+    for s in layout:
+        want[s.tag] += 4.0 * s.layers * math.prod(s.row)
+    assert _gauge("ray_tpu_serve_engine_page_bytes") == want
+    assert set(want) == {"kv", "latent", "full", "window", "index"}
+    for part in ("held", "zero", "elsewhere"):
+        llama._g_moe_assignment_share.set(-1.0, tags={"part": part})
+    pages = engine.pool.alloc(2)
+    engine.prefill(list(range(page + 1)), pages)
+    engine.copy_page(pages[1], pages[0])  # a call a table, where it has rows
+    shares = _gauge("ray_tpu_serve_moe_assignment_share")
+    if held is None:
+        assert set(shares.values()) == {-1.0}
+    else:
+        assert held <= shares["held"] <= 1.0
+        assert sum(shares.values()) == pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("keys,lacks", [
+    (dict(layer_pattern="M*", n_layers=2, ssm_heads=2, ssm_head_dim=8,
+          ssm_state=8), r"lacks \* M \("),
+    (dict(layer_pattern="E*", n_layers=2, num_experts=4,
+          experts_per_token=2), r"lacks \* E \("),
+    ("a mix", r"has F S W, of which the table lacks none \(")])
+def test_the_refusal_names_the_kinds_the_table_lacks(keys, lacks):
+    if keys == "a mix":  # one family's kinds with another's
+        import test_smallthinker
+
+        cfg = dataclasses.replace(
+            test_smallthinker.program_cfg(), layer_pattern="FWSFWS",
+            mlp_act="swiglu", q_lora_rank=8, kv_lora_rank=8, v_head_dim=8,
+            qk_nope_head_dim=8, qk_rope_head_dim=8, dense_mlp_dim=32)
+    else:
+        cfg = dataclasses.replace(LlamaConfig.debug(), **keys)
+    with pytest.raises(NotImplementedError, match=lacks):
+        llama.LlamaDecodeEngine(cfg)
