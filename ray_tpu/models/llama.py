@@ -11,8 +11,11 @@ model definition runs dp/fsdp/tp/sp via GSPMD. Design choices for the MXU:
   attention, :func:`shortcut_layer`) or of whole routed blocks whose
   attention is full and unrotated in some layers and windowed and rotated
   in the others (:func:`window_block`) or reads the keys a learned indexer
-  picks (:func:`index_block`), weights stacked per kind and walked in the
-  pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
+  picks (:func:`index_block`), or whose mixer is the gated delta rule in
+  some layers (:func:`delta_block`: a STATE a sequence, no row a position)
+  and gated full attention in the others (:func:`gated_block`), weights
+  stacked per kind and walked in the pattern's order (:func:`pattern_layer`,
+  :func:`pattern_stack`);
 - bf16 matmuls with fp32 accumulation (``preferred_element_type``), params
   stored fp32, gradients/optimizer fp32;
 - ``jax.checkpoint`` per layer (remat) to trade FLOPs for HBM, its products
@@ -72,6 +75,13 @@ _g_engine_page_bytes = Gauge(
     "ray_tpu_serve_engine_page_bytes",
     "Bytes one position holds in the decode engine's page store, by the "
     "layout the layer kind gives it", tag_keys=("kind",))
+# what ONE SEQUENCE leaves in the stores whose table is "state" (TABLES),
+# whatever its length, over all layers: a recurrent layer's state and its
+# convolution's tail. Not bytes a position: page_bytes does not count them
+_g_engine_state_bytes = Gauge(
+    "ray_tpu_serve_engine_state_bytes",
+    "Bytes one sequence holds in the decode engine's state stores whatever "
+    "its length, by part", tag_keys=("part",))
 # set where the engine builds its programs: the groups XLA's grouped expert
 # products run over (part=program: a kind's whole stack, read where it
 # lies) and the experts one layer holds (part=layer). Equal, the programs
@@ -179,12 +189,17 @@ def keep_policy(keep):
 # a patterned stack's layer kinds: the character -> the name of the kind's
 # stacked weights under params["layers"]
 LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
-               "F": "block", "W": "block", "I": "index"}
+               "F": "block", "W": "block", "I": "index", "D": "delta",
+               "A": "gated"}
 # the kinds that are a WHOLE block (attention THEN the routed MLP, whose
 # router reads the attention's normed input; window_block): "F" attends over
 # every earlier position without rotation, "W" over the last cfg.window with
 # rotation. They have the same leaves and share ONE stack, in layer order.
 BLOCK_KINDS = "FW"
+# the kinds of the delta-rule family: whole blocks too, each with a routed MLP
+# of its own; "D" mixes through the gated delta rule, "A" through gated full
+# attention. A stack EACH (their mixers' leaves differ)
+DELTA_KINDS = "DA"
 # Where their matrices start off the square root of their fan-in (seeded random
 # weights; a checkpoint brings its own): ``wo`` 12 times as wide, the router
 # 6 times, so that BOTH halves carry the logits and a comparison of logits
@@ -233,6 +248,30 @@ BLOCK_INIT = {"wo": 12.0, "router": 6.0}
 # load and every product's shape and time depend on neither scale (my chip
 # runs, PR 40; the configuration file's ``correct`` has every reading).
 INDEX_INIT = {"wo": 16.0, "q_norm": 0.7}
+# The delta-rule ("D", delta_block) and gated-attention ("A", gated_block)
+# layers' starting values, for the same reason. ``dt``: the delta layers'
+# ``dt_bias`` starts at the inverse softplus of a log-uniform step in this
+# range (Mamba-2's start, ops/ssm.py) and NOT at the published ones(): with
+# untrained projections a bias of 1 decays a state by exp(-A 1.3), A up to
+# 16, and all but one head in thirty forgets everything within a token: the
+# state, the page it lies in and the position it was taken at then change
+# no logit, and a comparison of logits sees no fault in any of them.
+# ``q_norm``: the gated attention's zero-centred QK-norm gain on the query,
+# ``1 + w``, starts at this value: scores that many units wide over some
+# 8,000 keys (at 1 the softmax is nearly an average and the layer adds 0.03
+# of the stream). ``wo``: its output product starts that many times as wide,
+# so that the attention layers carry the logits as the delta layers do
+# (whose gated norm makes their output one unit wide whatever the state's
+# size). ``router``: the routers start that many times as wide as their
+# fan-in, as BLOCK_INIT's does and for its reason: a router one unit wide
+# weighs a token's ten of 512 nearly alike (0.1 each), rounding moves the
+# tenth choice in a fifth of the token-layers, and a moved choice is then a
+# fifth of a layer's MLP output: the sound engine read 3.9-10.7% of a logit
+# over ten seeds at 1 and 2.3-4.3% at 4 (4.6 at 6), where the tenth weighs
+# a hundredth of the first. Readings: the configuration file's ``correct``
+# (my chip runs, PR 45). WHICH experts are chosen, their load and every
+# product's shape and time are the same at any width.
+DELTA_INIT = {"dt": (0.001, 0.1), "q_norm": 2.0, "wo": 8.0, "router": 4.0}
 
 
 @dataclass(frozen=True)
@@ -338,6 +377,23 @@ class LlamaConfig:
     # in, a head with fewer frequencies than their sum has the first of them
     # only. Empty: one position a token. Only the "I" layer reads it
     mrope_section: Tuple[int, ...] = ()
+    # the "D" layer (delta_block): lin_key_heads query / key heads of
+    # lin_key_dim and lin_value_heads value heads of lin_value_dim (a value
+    # head reads key head i // (value heads / key heads)), a causal depthwise
+    # convolution of lin_conv over [q | k | v], the gated delta rule
+    # (ops/gdn.py) in chunks of lin_chunk. The "A" layer (gated_block): the
+    # block's heads (n_heads on n_kv_heads of head_dim), ``wq`` twice as wide
+    # (a head's query and its output gate), the first partial_rotary_factor
+    # of a head's width rotated. Both: shared_mlp_dim is the width of the
+    # routed MLP's ONE shared SwiGLU expert behind a per-token sigmoid gate,
+    # and every RMSNorm but the delta rule's output norm is zero-centred
+    lin_key_heads: int = 0
+    lin_value_heads: int = 0
+    lin_key_dim: int = 0
+    lin_value_dim: int = 0
+    lin_conv: int = 4
+    lin_chunk: int = 64
+    partial_rotary_factor: float = 1.0
 
     def __post_init__(self):
         object.__setattr__(self, "mrope_section", tuple(self.mrope_section))
@@ -351,6 +407,34 @@ class LlamaConfig:
                 or self.mlp_act not in ("swiglu", "relu2", "reglu")):
             raise ValueError(f"router_scoring={self.router_scoring!r}, "
                              f"mlp_act={self.mlp_act!r}")
+        delta = set(self.kinds) & set(DELTA_KINDS)
+        if delta and not (
+                set(self.kinds) <= set(DELTA_KINDS)
+                and ("D" not in delta or (
+                    self.lin_key_heads and self.lin_key_dim
+                    and self.lin_value_dim and self.lin_conv > 1
+                    and self.lin_value_heads % self.lin_key_heads == 0))
+                and self.num_experts and self.experts_per_token
+                and self.mlp_act == "swiglu" and self.rope
+                and not self.qk_norm and not self.zero_experts
+                and self.router_scoring == "softmax"
+                and self.routed_scale == 1.0
+                and 0 < self.partial_rotary_factor <= 1
+                and round(self.head_dim * self.partial_rotary_factor) % 2
+                == 0):
+            raise ValueError(
+                "a 'D' / 'A' layer is the gated delta rule (lin_key_heads, "
+                "lin_key_dim, lin_value_heads a multiple of them, "
+                "lin_value_dim, lin_conv) or gated full attention with its "
+                "own per-head QK-norm and a partial rotation, then a "
+                "softmax-routed SwiGLU MLP (num_experts, experts_per_token; "
+                "a held range and a gated shared expert, shared_mlp_dim, may "
+                "be set): every built layer is one of the two, and it has no "
+                "whole-projection qk_norm, identity expert or weight scale")
+        if (self.partial_rotary_factor != 1.0 or self.lin_key_heads) \
+                and not delta:
+            raise ValueError("only 'D' / 'A' layers read lin_* and "
+                             "partial_rotary_factor")
         if not self.layer_pattern and (
                 self.router_experts or self.shared_mlp_dim
                 or self.router_scoring != "softmax" or self.zero_experts
@@ -430,8 +514,23 @@ class LlamaConfig:
     @property
     def rope_layout(self):
         """1 a layer of the whole pattern whose attention rotates."""
-        return [int(kind in "WSI" or (kind == "*" and self.rope))
+        return [int(kind in "WSIA" or (kind == "*" and self.rope))
                 for kind in self.layer_pattern]
+
+    @property
+    def full_attention_interval(self):
+        """``n`` where the whole pattern is ``n - 1`` delta-rule layers and
+        ONE gated attention layer, over and over, as a published config
+        counts it (None for any other pattern)."""
+        n = self.layer_pattern.find("A") + 1
+        fits = n and all(kind == ("A" if (i + 1) % n == 0 else "D")
+                         for i, kind in enumerate(self.layer_pattern))
+        return n if fits else None
+
+    @property
+    def zero_centered(self) -> bool:
+        """Every RMSNorm gain is ``1 + w`` (the "D" / "A" family's)."""
+        return bool(set(self.kinds) & set(DELTA_KINDS))
 
     @property
     def sa_config(self):
@@ -505,6 +604,20 @@ class LlamaConfig:
                 + d * hi * di + d * di + d * hi + 2 * di
                 + d * (self.router_experts or self.num_experts)
                 + self.num_experts * 3 * d * self.mlp_dim + 2 * d)
+            # the "D" / "A" blocks' routed MLP: router, the held experts, a
+            # gated shared expert; then the two mixers
+            routed = (d * (self.router_experts or self.num_experts)
+                      + self.num_experts * 3 * d * self.mlp_dim
+                      + 3 * d * self.shared_mlp_dim
+                      + (d if self.shared_mlp_dim else 0) + 2 * d)
+            kd = self.lin_key_heads * self.lin_key_dim
+            vd = self.lin_value_heads * self.lin_value_dim
+            per_kind["D"] = (
+                d * (2 * kd + 2 * vd) + d * 2 * self.lin_value_heads
+                + self.lin_conv * (2 * kd + vd) + 2 * self.lin_value_heads
+                + self.lin_value_dim + vd * d + routed)
+            per_kind["A"] = (2 * d * q + 2 * d * kv + q * d
+                             + 2 * self.head_dim + routed)
             if "S" in self.kinds:
                 rq, rkv, h = self.q_lora_rank, self.kv_lora_rank, self.n_heads
                 qk = self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -600,6 +713,31 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             wqi=("layers", "embed", None), wki=("layers", "embed", None),
             ww=("layers", "embed", None), ki_norm=("layers", None),
             ki_bias=("layers", None))
+        # the delta-rule family's: a routed MLP with a gated shared expert
+        # under either mixer
+        routed = {
+            "attn_norm": ("layers", None), "mlp_norm": ("layers", None),
+            "router": ("layers", "embed", None),
+            "w_gate": ("layers", None, "embed", "mlp"),
+            "w_up": ("layers", None, "embed", "mlp"),
+            "w_down": ("layers", None, "mlp", "embed"),
+        }
+        if cfg.shared_mlp_dim:
+            routed.update(w_sg=("layers", None),
+                          ws_gate=("layers", "embed", "mlp"),
+                          ws_up=("layers", "embed", "mlp"),
+                          ws_down=("layers", "mlp", "embed"))
+        kinds["delta"] = dict(
+            routed, w_qkvz=("layers", "embed", None),
+            w_ba=("layers", "embed", None), conv_w=("layers", None, None),
+            dt_bias=("layers", None), A_log=("layers", None),
+            gate_norm=("layers", None), w_out=("layers", None, "embed"))
+        kinds["gated"] = dict(
+            routed, wq=("layers", "embed", "heads"),
+            wk=("layers", "embed", "kv_heads"),
+            wv=("layers", "embed", "kv_heads"),
+            wo=("layers", "heads", "embed"), q_norm=("layers", None),
+            k_norm=("layers", None))
         if cfg.mlp_act != "relu2":
             kinds["moe"]["w_gate"] = ("layers", None, "embed", "mlp")
         if cfg.shared_mlp_dim:
@@ -768,6 +906,61 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
             "w_up": dense(next(k), (L, held, d, f), d),
             "w_down": dense(next(k), (L, held, f, d), f),
         }
+    for c in DELTA_KINDS:
+        if n[c]:
+            out[LAYER_KINDS[c]] = _init_delta_family(cfg, c, n[c], key)
+    return out
+
+
+def _init_delta_family(cfg: LlamaConfig, kind: str, L: int, key):
+    """The ``L`` stacked layers of kind ``"D"`` or ``"A"``, keys of their
+    own a kind. Every norm gain is ZERO-CENTRED, ``1 + w``, and starts at
+    ``w = 0`` (but the gated attention's query gain, :data:`DELTA_INIT`);
+    the delta rule's output norm is plain and starts at one. ``A_log``
+    ``log U(0.001, 16)`` (published: from 0); ``dt_bias``:
+    :data:`DELTA_INIT`."""
+    d, hd, f, fs = cfg.dim, cfg.head_dim, cfg.mlp_dim, cfg.shared_mlp_dim
+    k = iter(jax.random.split(
+        jax.random.fold_in(key, 4 + DELTA_KINDS.index(kind)), 16))
+    dense = _dense_init
+    out = {
+        "attn_norm": jnp.zeros((L, d), jnp.float32),
+        "mlp_norm": jnp.zeros((L, d), jnp.float32),
+        "router": DELTA_INIT["router"] * dense(
+            next(k), (L, d, cfg.router_experts or cfg.num_experts), d),
+        "w_gate": dense(next(k), (L, cfg.num_experts, d, f), d),
+        "w_up": dense(next(k), (L, cfg.num_experts, d, f), d),
+        "w_down": dense(next(k), (L, cfg.num_experts, f, d), f),
+    }
+    if fs:
+        out.update(w_sg=dense(next(k), (L, d), d),
+                   ws_gate=dense(next(k), (L, d, fs), d),
+                   ws_up=dense(next(k), (L, d, fs), d),
+                   ws_down=dense(next(k), (L, fs, d), fs))
+    if kind == "A":
+        nq, nkv = cfg.n_heads, cfg.n_kv_heads
+        out.update(
+            wq=dense(next(k), (L, d, 2 * nq * hd), d),
+            wk=dense(next(k), (L, d, nkv * hd), d),
+            wv=dense(next(k), (L, d, nkv * hd), d),
+            wo=DELTA_INIT["wo"] * dense(next(k), (L, nq * hd, d), nq * hd),
+            q_norm=jnp.full((L, hd), DELTA_INIT["q_norm"] - 1.0, jnp.float32),
+            k_norm=jnp.zeros((L, hd), jnp.float32))
+        return out
+    hv, kd = cfg.lin_value_heads, cfg.lin_key_heads * cfg.lin_key_dim
+    vd = hv * cfg.lin_value_dim
+    lo, hi = DELTA_INIT["dt"]
+    dt = jnp.exp(jax.random.uniform(next(k), (L, hv), jnp.float32)
+                 * (math.log(hi) - math.log(lo)) + math.log(lo))
+    out.update(
+        w_qkvz=dense(next(k), (L, d, 2 * kd + 2 * vd), d),
+        w_ba=dense(next(k), (L, d, 2 * hv), d),
+        conv_w=dense(next(k), (L, cfg.lin_conv, 2 * kd + vd), cfg.lin_conv),
+        dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+        A_log=jnp.log(jax.random.uniform(next(k), (L, hv), jnp.float32,
+                                         1e-3, 16.0)),
+        gate_norm=jnp.ones((L, cfg.lin_value_dim), jnp.float32),
+        w_out=dense(next(k), (L, vd, d), vd))
     return out
 
 
@@ -779,7 +972,8 @@ def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
         k_emb, k_head, k_layers = jax.random.split(key, 3)
         params = {"embedding": dense(k_emb, (cfg.vocab_size, d), d),
                   "layers": _init_pattern_layers(cfg, k_layers),
-                  "final_norm": jnp.ones((d,), jnp.float32)}
+                  "final_norm": (jnp.zeros if cfg.zero_centered
+                                 else jnp.ones)((d,), jnp.float32)}
         if not cfg.tie_embeddings:
             params["lm_head"] = dense(k_head, (d, cfg.vocab_size), d)
         return params
@@ -1138,14 +1332,15 @@ def _note_expert_products(xs, w_up, path, reason) -> None:
         _g_engine_expert_products.set(float(n), tags={"path": way})
 
 
-def attend_tiles(q, k, v, cd, window: int = 0, shared=None):
+def attend_tiles(q, k, v, cd, window: int = 0, shared=None, kind=None):
     """Causal attention over the call's own positions without the ``[heads,
     T, T]`` scores: ``q`` [B, T, H, D], ``k`` / ``v`` [B, T, Hkv, Dk / Dv]
     (``Hkv`` divides ``H``), ``shared`` [B, T, D - Dk] or None: a slice of
     every head's key that all heads share, scored against ``q[..., Dk:]``
     (latent attention's rotated slice). Products in ``cd``, scores and
     softmax float32, the scale ``1 / sqrt(D)``. With a ``window`` position
-    ``i`` sees ``j <= i`` with ``j > i - window``.
+    ``i`` sees ``j <= i`` with ``j > i - window``. ``kind``: the name the
+    call is counted under where the shapes do not say it.
 
     Two paths, ONE arithmetic (:func:`prefill_attend_path` says which and
     why; ``ray_tpu_serve_engine_prefill_attend{kind, path}`` and
@@ -1163,8 +1358,8 @@ def attend_tiles(q, k, v, cd, window: int = 0, shared=None):
     head first. Both skip the key blocks wholly behind the band as those
     ahead of the query block, so a window layer's work follows the band's
     area and not the triangle's."""
-    kind = ("latent" if shared is not None
-            else "window" if window else "full")
+    kind = kind or ("latent" if shared is not None
+                    else "window" if window else "full")
     path, reason = prefill_attend_path(q, k, v, cd, shared)
     _note_prefill_attend(kind, q, k, window, path, reason)
     if path == "kernel":
@@ -1618,6 +1813,229 @@ def _selected_tiles(q, k, v, qi, ki, w, topk: int, cd, chunk: int):
     return jnp.moveaxis(o, 0, 1).reshape(B, T, H, -1)
 
 
+# --------------------------------------------------------------------------- #
+# The delta-rule family (kinds "D" and "A"): a state a sequence, beside pages
+# --------------------------------------------------------------------------- #
+
+
+def _routed_half(cfg: LlamaConfig, h, layers, i, stat_axes):
+    """The "D" / "A" blocks' second half on the stream ``h`` (its type
+    kept): the zero-centred norm, then the routed MLP whose router reads its
+    own input in float32, the held experts and the ONE shared SwiGLU expert
+    behind its per-token sigmoid gate (``routed_mlp``'s ``shared_gated``).
+    Small leaves are cut out ``[i]``, the experts go down whole with
+    ``layer=i`` (:func:`shortcut_layer` says why)."""
+    from ray_tpu.ops.moe import routed_mlp
+
+    m = rms_norm(h, 1.0 + layers["mlp_norm"][i], cfg.norm_eps)
+    wide = cfg.router_experts or cfg.num_experts
+    # the float32 sum goes onto the float32 stream as it is (_mlp_half would
+    # round it to cfg.dtype first)
+    y, stats = routed_mlp(
+        m.astype(cfg.dtype), layers["router"][i], layers["w_gate"],
+        layers["w_up"], layers["w_down"], top_k=cfg.experts_per_token,
+        norm_topk_prob=cfg.norm_topk_prob, stat_axes=stat_axes,
+        held=((cfg.first_expert, cfg.num_experts)
+              if wide != cfg.num_experts else None),
+        layer=i, router_input=m,
+        shared_gated=(tuple(layers[w][i] for w in (
+            "w_sg", "ws_gate", "ws_up", "ws_down"))
+            if cfg.shared_mlp_dim else None))
+    return h + y.astype(h.dtype), stats
+
+
+def delta_block(cfg: LlamaConfig, x, layers, i, positions, attend,
+                stat_axes=()):
+    """THE delta-rule block (kind ``"D"``), for the full forward and for the
+    serving programs (``N``: the zero-centred RMSNorm, ``x / rms(x) (1 +
+    w)``)::
+
+        a   = N(x)
+        [q | k | v | z] = a W_qkvz                [b | a'] = a W_ba
+        beta = sigmoid(b)       g = -exp(A_log) softplus(a' + dt_bias)
+        o, state, tail = attend([q | k | v], g, beta, conv_w)
+        y   = gate_norm * o / rms(o) * silu(z)    a value head; the norm
+                                                  BEFORE the gate, plain
+        h   = x + y W_out
+        out = h + MoE(N(h))                       (:func:`_routed_half`)
+
+    ``attend`` is all that knows where the call stands in its sequence: the
+    causal convolution over ``[q | k | v]`` and ``silu``, the heads' split
+    and l2-norm (:func:`_delta_heads`) and the gated delta rule
+    (``ops/gdn.py``), over the call's own positions from an empty state
+    (:func:`attend_delta`) or one token on from a kept state and tail
+    (:func:`_attend_state`). It returns ``o`` [B, T, value heads, value dim]
+    float32 and what a cache keeps A SEQUENCE: the state after the last real
+    position ``[B, 1, value heads, key dim, value dim]`` float32 and the
+    convolution's tail, the last ``lin_conv - 1`` rows of ``[q | k | v]``
+    ``[B, 1, lin_conv - 1, width]``. Device scopes ``gdn.in_proj``,
+    ``gdn.conv``, ``gdn.scan`` / ``gdn.step``, ``gdn.gate_norm``,
+    ``gdn.out_proj``. Returns ``(x, stats, (state, tail))``."""
+    cd, f32, eps = cfg.dtype, jnp.float32, cfg.norm_eps
+    B, T, _ = x.shape
+    hv, dv = cfg.lin_value_heads, cfg.lin_value_dim
+    a = rms_norm(x, 1.0 + layers["attn_norm"][i], eps).astype(cd)
+    with jax.named_scope("gdn.in_proj"):
+        # one stored matrix, two products: a split of ONE product's columns
+        # is two copies of it (1.3 GB at 32,768 positions)
+        w = layers["w_qkvz"][i].astype(cd)
+        qkv, z = a @ w[:, :-hv * dv], a @ w[:, -hv * dv:]
+        ba = jnp.dot(a, layers["w_ba"][i].astype(cd),
+                     preferred_element_type=f32)
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(layers["A_log"][i]) * jax.nn.softplus(
+            ba[..., hv:] + layers["dt_bias"][i])
+    o, state, tail = attend(qkv, g, beta, layers["conv_w"][i])
+    with jax.named_scope("gdn.gate_norm"):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+        y = o * layers["gate_norm"][i] * jax.nn.silu(
+            z.astype(f32)).reshape(B, T, hv, dv)
+    with jax.named_scope("gdn.out_proj"):
+        y = y.reshape(B, T, hv * dv).astype(cd) @ layers["w_out"][i].astype(
+            cd)
+    out, stats = _routed_half(cfg, x + y.astype(x.dtype), layers, i,
+                              stat_axes)
+    return out, stats, (state, tail)
+
+
+def _delta_heads(cfg: LlamaConfig, mixed):
+    """``mixed`` [B, T, width] float32 (``[q | k | v]`` behind the
+    convolution and ``silu``) as the delta rule's operands in the compute
+    type: ``q`` / ``k`` [B, T, value heads, key dim], each l2-normalised
+    (eps 1e-6), ``q`` times ``key dim ** -0.5``, a key head repeated for the
+    value heads that read it; ``v`` [B, T, value heads, value dim]."""
+    B, T, _ = mixed.shape
+    hk, dk, hv = cfg.lin_key_heads, cfg.lin_key_dim, cfg.lin_value_heads
+    q, k, v = jnp.split(mixed, [hk * dk, 2 * hk * dk], axis=-1)
+
+    def unit(y):
+        y = y.reshape(B, T, hk, dk)
+        return y * jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True)
+                                 + 1e-6)
+
+    q, k = (jnp.repeat(y, hv // hk, axis=2).astype(cfg.dtype)
+            for y in (unit(q) * dk ** -0.5, unit(k)))
+    return q, k, v.reshape(B, T, hv, -1).astype(cfg.dtype)
+
+
+# a long prompt's delta-rule layers go this many positions at a time (where
+# it divides them): the convolution's float32 rows, the heads and what the
+# chunked form prepares for all of a stretch's chunks at once are a
+# segment's, 1.6 GB at the published widths where 32,768 positions' are 5 GB
+DELTA_SEGMENT = 8192
+
+
+def attend_delta(cfg: LlamaConfig, last, qkv, g, beta, conv_w):
+    """:func:`delta_block`'s ``attend`` over the call's own positions from an
+    empty state (the full forward and prefill): the causal convolution
+    (``ops/ssm.py causal_conv``, no bias), then the recurrence in chunks
+    (``ops/gdn.py gated_delta_chunked``), ``DELTA_SEGMENT`` positions at a
+    time: a ``lax.scan`` that carries what a decode call would find, the
+    state and the convolution's tail. ``last`` (a number, traced or not;
+    None: the last position): the last REAL position. The engine right-pads
+    a prompt to whole pages, and a causal mask keeps nothing out of a
+    recurrence: the positions after ``last`` are identity updates, so the
+    state is the state after ``last``, and the tail is taken at ``last``
+    (rows before the sequence's first are zeros, as the convolution pads)."""
+    from ray_tpu.ops.gdn import gated_delta_chunked
+    from ray_tpu.ops.ssm import causal_conv
+
+    f32 = jnp.float32
+    (B, T, _), K = qkv.shape, conv_w.shape[0]
+    seg = DELTA_SEGMENT if T % DELTA_SEGMENT == 0 else T
+
+    def segment(carry, xs):
+        state, before = carry
+        qkv_s, g_s, beta_s, start = xs
+        with jax.named_scope("gdn.conv"):
+            rows = jnp.concatenate([before, qkv_s], axis=1).astype(f32)
+            mixed = jax.nn.silu(causal_conv(rows, conv_w, 0.0)[:, K - 1:])
+            q, k, v = _delta_heads(cfg, mixed)
+        with jax.named_scope("gdn.scan"):
+            o, state = gated_delta_chunked(
+                q, k, v, g_s, beta_s, cfg.lin_chunk, state,
+                None if last is None else last - start)
+        return (state, qkv_s[:, seg - (K - 1):]), o
+
+    def segments(a):  # [B, T, ...] -> [T / seg, B, seg, ...]
+        return jnp.moveaxis(a.reshape(B, -1, seg, *a.shape[2:]), 1, 0)
+
+    hv = cfg.lin_value_heads
+    start = (jnp.zeros((B, hv, cfg.lin_key_dim, cfg.lin_value_dim), f32),
+             jnp.zeros((B, K - 1, qkv.shape[-1]), qkv.dtype))
+    (state, _), o = jax.lax.scan(
+        segment, start, (segments(qkv), segments(g), segments(beta),
+                         jnp.arange(0, T, seg, dtype=jnp.int32)))
+    with jax.named_scope("gdn.conv"):
+        at = (T - 1 if last is None else last) - (K - 2) + jnp.arange(K - 1)
+        tail = jnp.where((at >= 0)[None, :, None],
+                         qkv[:, jnp.maximum(at, 0)], 0)
+    o = jnp.moveaxis(o, 0, 1).reshape(B, T, hv, -1)
+    return o, state[:, None], tail[:, None]
+
+
+def _attend_state(cfg: LlamaConfig, call, l, mine, qkv, g, beta, conv_w):
+    """A decode call's token through its layer's kept state and tail
+    (``mine``: the views ``[1, *row]`` of the page that holds position
+    ``pos - 1``, the batch's one sequence): the convolution's one new row,
+    one step of the recurrence (``ops/gdn.py gated_delta_step``), and what
+    the page that holds ``pos`` keeps. A sequence's first position starts
+    from zeros whatever its page held."""
+    from ray_tpu.ops.gdn import gated_delta_step
+
+    f32 = jnp.float32
+    state, tail = (jnp.where(call.pos > 0, a, 0) for a in mine)
+    with jax.named_scope("gdn.conv"):
+        rows = jnp.concatenate([tail, qkv.astype(f32)], axis=1)
+        mixed = jax.nn.silu(jnp.sum(rows * conv_w, axis=1, keepdims=True))
+        q, k, v = _delta_heads(cfg, mixed)
+    with jax.named_scope("gdn.step"):
+        o, state = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                    beta[:, 0], state)
+    return o[:, None], state[:, None], rows[:, None, 1:]
+
+
+def gated_block(cfg: LlamaConfig, x, layers, i, positions, attend,
+                stat_axes=()):
+    """THE gated-attention block (kind ``"A"``), for the full forward and
+    for the serving programs (``N`` zero-centred)::
+
+        a   = N(x)
+        [q | gate] = a Wq  a head;   k, v = a Wk, a Wv
+        q, k = N over each head's width (one gain for q, one for k), the
+               first partial_rotary_factor of it rotated (half-split pairs)
+        o   = softmax(q k^T / sqrt(head_dim), causal) v        GQA
+        h   = x + (o * sigmoid(gate)) Wo
+        out = h + MoE(N(h))                       (:func:`_routed_half`)
+
+    ``attend(q, k, v)`` as :func:`decoder_block` takes it, under the device
+    scope ``attn.gated``. Returns ``(x, stats, (k, v))``: ``k`` normed and
+    rotated, what a cache keeps."""
+    cd, hd, eps = cfg.dtype, cfg.head_dim, cfg.norm_eps
+    B, T, _ = x.shape
+    p = {w: layers[w][i] for w in ("wq", "wk", "wv", "wo", "q_norm",
+                                   "k_norm")}
+    a = rms_norm(x, 1.0 + layers["attn_norm"][i], eps).astype(cd)
+    q, gate = jnp.split((a @ p["wq"].astype(cd)).reshape(B, T, -1, 2 * hd),
+                        2, axis=-1)
+    q = rms_norm(q, 1.0 + p["q_norm"], eps)
+    k = rms_norm((a @ p["wk"].astype(cd)).reshape(B, T, -1, hd),
+                 1.0 + p["k_norm"], eps)
+    v = (a @ p["wv"].astype(cd)).reshape(B, T, -1, hd)
+    rot = round(hd * cfg.partial_rotary_factor)
+    q, k = (jnp.concatenate([mrope_rotate(y[..., :rot], positions,
+                                          cfg.rope_theta), y[..., rot:]],
+                            axis=-1) for y in (q, k))
+    with jax.named_scope("attn.gated"):
+        o = attend(q, k, v)
+    o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
+         ).astype(cd).reshape(B, T, -1)
+    out, stats = _routed_half(
+        cfg, x + (o @ p["wo"].astype(cd)).astype(x.dtype), layers, i,
+        stat_axes)
+    return out, stats, (k, v)
+
+
 def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     """One layer of a patterned stack (``cfg.layer_pattern``). A kind the
     decode engine serves (a row of :data:`SERVED`: ``"S"``, ``"F"`` /
@@ -1634,8 +2052,8 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     if kind in SERVED:  # this layer's weights as a stack of one
         return SERVED[kind].block(
             cfg, x, jax.tree.map(lambda a: a[None], p), 0,
-            positions_of(*x.shape[:2]), partial(SERVED[kind].prefill, cfg),
-            stat_axes)[:2]
+            positions_of(*x.shape[:2]),
+            partial(SERVED[kind].prefill, cfg, None), stat_axes)[:2]
     h = rms_norm(x, p["norm"], cfg.norm_eps).astype(cfg.dtype)
     stats = {}
     if kind == "M":
@@ -1750,7 +2168,8 @@ def _backbone(cfg: LlamaConfig, params, tokens, mesh=None):
         x, stats = pattern_stack(
             cfg, x, params["layers"],
             lambda q, k, v: _attention(cfg, q, k, v, mesh))
-        return rms_norm(x, params["final_norm"], cfg.norm_eps), stats
+        return rms_norm(x, _final_gain(cfg, params["final_norm"]),
+                        cfg.norm_eps), stats
     positions = positions_of(*tokens.shape)
 
     layer_fn = partial(_layer, cfg, mesh)
@@ -1776,9 +2195,14 @@ def _logits(cfg: LlamaConfig, x, head):
                            "head").astype(jnp.float32)
 
 
+def _final_gain(cfg: LlamaConfig, final_norm):
+    return 1.0 + final_norm if cfg.zero_centered else final_norm
+
+
 def head_logits(cfg: LlamaConfig, x, final_norm, head):
     """The model's end: final norm -> head -> float32 logits."""
-    return _logits(cfg, rms_norm(x, final_norm, cfg.norm_eps), head)
+    return _logits(cfg, rms_norm(x, _final_gain(cfg, final_norm),
+                                 cfg.norm_eps), head)
 
 
 def forward(cfg: LlamaConfig, params, tokens, mesh=None):
@@ -1868,6 +2292,15 @@ def _no_window_kinds(cfg: LlamaConfig, who: str, why: str) -> None:
         raise NotImplementedError(
             f"{who} takes no 'F' / 'W' layer (layer_pattern="
             f"{cfg.layer_pattern!r}, window={cfg.window}) yet: {why}")
+
+
+def _no_delta_kinds(cfg: LlamaConfig, who: str, why: str) -> None:
+    """A path no reference holds the ``"D"`` / ``"A"`` kinds' gradients on
+    refuses them by name, as :func:`_no_window_kinds` refuses its kinds."""
+    if set(cfg.kinds) & set(DELTA_KINDS):
+        raise NotImplementedError(
+            f"{who} takes no 'D' / 'A' layer (layer_pattern="
+            f"{cfg.layer_pattern!r}) yet: {why}")
 
 
 # --------------------------------------------------------------------------- #
@@ -2043,12 +2476,16 @@ class Served(NamedTuple):
     number in the stack (the dense block: its own weights), ``rows`` what it
     keeps, an array a store (or the one array). ``f32``: the serving
     stream is float32 and not ``cfg.dtype``. ``rows(cfg)``: ``[(tag,
-    sublayers, row shape, table), ...]``, what a layer keeps a position, a
-    store each: by the pool's page ids (``table`` ``"page"``) or by the
-    engine's map of a page to its SLOT (``"slot"``), counted by
-    ``ray_tpu_serve_engine_page_bytes{kind}`` under ``tag``.
-    ``prefill(cfg, *the block's attend arguments)``: attention over the
-    call's own positions. ``decode(cfg, call, l, mine, *the block's attend
+    sublayers, row shape, table), ...]``, what a layer keeps, a store each,
+    found by the rule ``table`` names (:data:`TABLES`): a row a POSITION by
+    the pool's page ids (``"page"``) or by the engine's map of a page to its
+    SLOT (``"slot"``), counted by ``ray_tpu_serve_engine_page_bytes{kind}``
+    under ``tag``; or ONE row a SEQUENCE (``"state"``: a recurrent layer's
+    state), counted by ``ray_tpu_serve_engine_state_bytes{part}``.
+    ``prefill(cfg, last, *the block's attend arguments)``: the layer's
+    mixing over the call's own positions, of which ``last`` is the last
+    real one (None: all are; attention's causal mask needs no telling, a
+    recurrence does). ``decode(cfg, call, l, mine, *the block's attend
     arguments)``: one new token of the kind's ``l``-th layer against
     ``mine``, that layer's page-padded views, one a row of ``rows``
     (``[Tpad, *row]``; ``[sublayers, Tpad, *row]`` for several); ``call``
@@ -2066,6 +2503,32 @@ class Served(NamedTuple):
     prefill: Callable
     decode: Callable
     attended: Optional[Callable] = None
+
+
+class Table(NamedTuple):
+    """One rule by which a store's slabs are found and filled. ``ids``: whose
+    numbers find a slab, the pool's page ids (``"page"``) or the engine's
+    slots (``"slot"``, fewer than pages). ``rows``: the rows a slab holds,
+    None for a page's positions (``page_size``: a row a POSITION, written
+    where the position lies) or 1: ONE row, what a SEQUENCE keeps whatever
+    its length (a recurrent state), which each call reads and REWRITES."""
+    ids: str
+    rows: Optional[int]
+
+
+# The rules ``Served.rows`` may name. ``"state"``: the slab of page ``p``
+# holds the state after the NEWEST position written in ``p``. Prefill
+# writes the slab of the page that holds its last real position; a decode
+# call at ``pos`` reads the slab of the page that holds ``pos - 1`` and
+# writes that of the page that holds ``pos``; ``copy_page`` copies it with
+# the page. So the state is found from the page table alone (a call carries
+# no sequence), it follows a sequence across a page boundary, a page shared
+# read-only (a prefix entry's) is never written again after its prefill,
+# whether the prompt fills its last page or not, and every full page keeps
+# the state at its end. A slab never written is zeros, a valid state.
+TABLES: Dict[str, Table] = {"page": Table("page", None),
+                            "slot": Table("slot", None),
+                            "state": Table("page", 1)}
 
 
 def _kv_rows(cfg: LlamaConfig, tag: str, table: str = "page"):
@@ -2130,8 +2593,8 @@ SERVED: Dict[str, Served] = {
         False, partial(_kv_rows, tag="kv"),
         # float32 scores: decode numerics never depend on prefill matching a
         # fused kernel, only on the cached bytes
-        lambda cfg, q, k, v: plain_attention(q, *_gqa_repeat(cfg, k, v),
-                                             causal=True), _attend_pages),
+        lambda cfg, last, q, k, v: plain_attention(
+            q, *_gqa_repeat(cfg, k, v), causal=True), _attend_pages),
     # the shortcut-connected double layer (latent attention, routed and
     # identity experts): ONE store of latent rows, [kv_lora_rank +
     # qk_rope_head_dim] an attention sublayer, from which prefill expands keys
@@ -2139,7 +2602,7 @@ SERVED: Dict[str, Served] = {
     "S": Served(
         "latent", "scmoe", shortcut_layer, False,
         lambda cfg: [("latent", 2, (cfg.latent_row,), "page")],
-        lambda cfg, j, *a: attend_latent_expanded(cfg, *a),
+        lambda cfg, last, j, *a: attend_latent_expanded(cfg, *a),
         lambda cfg, call, l, mine, j, *a: _attend_latent_cached(
             cfg, mine[0][j], call.pos, *a)),
     # the whole routed block with full and with window attention, ONE stack
@@ -2150,11 +2613,13 @@ SERVED: Dict[str, Served] = {
     "F": Served(
         "window", "block", lambda cfg, *a: window_block(cfg, "F", *a), True,
         partial(_kv_rows, tag="full"),
-        lambda cfg, *a: attend_window_tiles(cfg, "F", *a), _attend_pages),
+        lambda cfg, last, *a: attend_window_tiles(cfg, "F", *a),
+        _attend_pages),
     "W": Served(
         "window", "block", lambda cfg, *a: window_block(cfg, "W", *a), True,
         partial(_kv_rows, tag="window", table="slot"),
-        lambda cfg, *a: attend_window_tiles(cfg, "W", *a), _attend_slots),
+        lambda cfg, last, *a: attend_window_tiles(cfg, "W", *a),
+        _attend_slots),
     # the indexed block (per-head QK-norm, a held range of experts): keys and
     # values as the block's and a THIRD store, the indexer's ONE key head a
     # layer, all three by page id; prefill attends under the selection
@@ -2162,20 +2627,45 @@ SERVED: Dict[str, Served] = {
         "index", "index", index_block, True,
         lambda cfg: _kv_rows(cfg, "kv")
         + [("index", 1, (cfg.index_head_dim,), "page")],
-        attend_selected, _attend_picked, _index_attended),
+        lambda cfg, last, *a: attend_selected(cfg, *a), _attend_picked,
+        _index_attended),
+    # the delta-rule family, a stack a kind. "D" (the gated delta rule)
+    # keeps NO row a position: its state and its convolution's tail, a
+    # SEQUENCE, by the table rule "state". "A" (gated full attention) keeps
+    # normed, rotated keys and values by page id as the dense block does
+    "D": Served(
+        "delta", "delta", delta_block, True,
+        lambda cfg: [
+            ("state", 1, (cfg.lin_value_heads, cfg.lin_key_dim,
+                          cfg.lin_value_dim), "state"),
+            ("conv", 1, (cfg.lin_conv - 1,
+                         2 * cfg.lin_key_heads * cfg.lin_key_dim
+                         + cfg.lin_value_heads * cfg.lin_value_dim),
+             "state")],
+        attend_delta, _attend_state),
+    "A": Served(
+        "delta", "gated", gated_block, True, partial(_kv_rows, tag="gated"),
+        lambda cfg, last, *a: attend_tiles(*a, cfg.dtype, kind="gated"),
+        _attend_pages),
 }
 
 
 class Store(NamedTuple):
-    """One page store of a served stack, ``[layers, n_pages or n_slots,
-    page_size, *row]``: ``kind``'s (``layers``: its layers times ``sub``,
-    the rows a layer keeps there), by ``table``, counted under ``tag``."""
+    """One store of a served stack, ``[layers, n_pages or n_slots, page_size
+    or 1, *row]`` as its ``table``'s rule says (:data:`TABLES`): ``kind``'s
+    (``layers``: its layers times ``sub``, the rows a layer keeps there),
+    counted under ``tag``."""
     kind: str
     tag: str
     sub: int
     layers: int
     row: tuple
     table: str
+
+    def shape(self, n_pages: int, n_slots: int, page_size: int) -> tuple:
+        rule = TABLES[self.table]
+        return (self.layers, n_slots if rule.ids == "slot" else n_pages,
+                rule.rows or page_size, *self.row)
 
 
 def served_kinds(cfg: LlamaConfig) -> str:
@@ -2253,6 +2743,11 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
     one = len(unit) == 1
     per = {c: unit.count(c) for c in table}
     done = {c: times * per[c] for c in table}
+    # a layer's number in its STACK: its number among the layers of the
+    # kinds that share the stack (one stack a family, or one a kind)
+    stacks = {c: table[c].stack for c in table}
+    per_stack = {k: sum(per[c] for c in table if stacks[c] == k)
+                 for k in stacks.values()}
 
     def layer(x, c, which, l, mine):
         kind = table[c]
@@ -2269,16 +2764,19 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
                           if k in stats})
 
     def run(x, some, first, start, cached):
-        """Layers of kinds ``some`` in line; the first of them is layer
-        ``first`` of the stack (a served family keeps ONE: a layer's
-        number in the pattern is its number there) and ``start[c]`` among
-        ``c``'s; ``cached``: ``{c: views}`` over THESE layers. Returns ``(x,
-        {c: (rows, shares)})``, a leading dimension over ``c``'s layers."""
+        """Layers of kinds ``some`` in line; the first of them in stack
+        ``k`` is layer ``first[k]`` there, the first of kind ``c`` is
+        ``start[c]`` among ``c``'s; ``cached``: ``{c: views}`` over THESE
+        layers. Returns ``(x, {c: (rows, shares)})``, a leading dimension
+        over ``c``'s layers."""
         out = {c: [] for c in table}
-        for j, c in enumerate(some):
+        met = dict.fromkeys(first, 0)
+        for c in some:
             n = len(out[c])
             mine = None if cached is None else [a[n] for a in cached[c]]
-            x, new = layer(x, c, first + j, start[c] + n, mine)
+            x, new = layer(x, c, first[stacks[c]] + met[stacks[c]],
+                           start[c] + n, mine)
+            met[stacks[c]] += 1
             out[c].append(new)
         return x, {c: jax.tree.map(lambda *a: jnp.stack(a), *out[c])
                    for c in table if out[c]}
@@ -2299,7 +2797,8 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
                                number,
                                None if cached is None else cached[unit])
                 return x, {unit: new}
-            return run(x, unit, number * len(unit),
+            return run(x, unit,
+                       {k: number * n for k, n in per_stack.items()},
                        {c: number * per[c] for c in table}, cached)
 
         cached = views(dict.fromkeys(table, 0), done,
@@ -2312,8 +2811,8 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
                 lambda a: a.reshape(-1, *a.shape[2:]), new[c]))
     rest = kinds[times * len(unit):]
     if rest:
-        x, new = run(x, rest, times * len(unit), done,
-                     views(done, dict.fromkeys(table)))
+        x, new = run(x, rest, {k: times * n for k, n in per_stack.items()},
+                     done, views(done, dict.fromkeys(table)))
         for c in new:
             out[c].append(new[c])
     out = {c: jax.tree.map(lambda *a: jnp.concatenate(a), *out[c])
@@ -2342,8 +2841,11 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
     written (``k``: ``cfg.window_pages``). ``stores``: the engine's page
     stores (:func:`served_stores`; donated by the engine, updated in place);
     ``tokens`` [1, n * page_size] int32, right-padded (causal masking keeps
-    pad garbage out of real positions); ``page_ids`` [n] int32; ``last``
-    int32 scalar, the last real position. Returns ``(*stores, logits
+    pad garbage out of real positions, and a kind that keeps a state is told
+    ``last``); ``page_ids`` [n] int32; ``last`` int32 scalar, the last real
+    position. A store whose table is ``"state"`` gets its ONE row, the
+    state after ``last``, in the page that holds ``last``. Returns
+    ``(*stores, logits
     [vocab] fp32, shares)``: what every layer keeps (rotated keys and
     values, latent rows, index keys) of ALL ``n * page_size`` positions is
     written, the pad positions of the last page included (they hold the pad
@@ -2355,14 +2857,18 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
     if any(s.table == "slot" for s in layout):
         *args, slot_ids = args
     *stores, tokens, page_ids, last = args
+    ps = _page_size(stores, layout)
     ids = {"page": page_ids, "slot": slot_ids}
     x = embed_tokens(cfg, params, tokens, None)
     positions = positions_of(*tokens.shape)
     keep = {"page": tokens.shape[1], "slot": 0 if slot_ids is None
-            else slot_ids.shape[0] * stores[0].shape[2]}
+            else slot_ids.shape[0] * ps}
+    if any(s.table == "state" for s in layout):
+        # ONE row a sequence: into the page of the last real position
+        ids["state"], keep["state"] = page_ids[last // ps][None], 1
     x, rows, shares = _serve_layers(
         cfg, x, params["layers"], positions,
-        {c: lambda l, mine, *a, c=c: SERVED[c].prefill(cfg, *a)
+        {c: lambda l, mine, *a, c=c: SERVED[c].prefill(cfg, last, *a)
          for c in {s.kind for s in layout}},
         None, [keep[s.table] for s in layout])
     stores = [_write_pages(pages, new[:, 0], ids[s.table])
@@ -2390,7 +2896,10 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
     The table's pages are gathered on the device into the ``[S, n *
     page_size, *row]`` view of each store (positions >= ``pos`` are
     masked), and what the new position keeps is written at
-    ``(page_ids[pos // page_size], pos % page_size)``. Returns ``(*stores,
+    ``(page_ids[pos // page_size], pos % page_size)``. A store whose table
+    is ``"state"`` is READ AND REWRITTEN: its view is the ONE row of the page
+    that holds ``pos - 1`` (:data:`TABLES`), and the layer's new state goes
+    into the page that holds ``pos``. Returns ``(*stores,
     logits [vocab] fp32)``. ``pos`` and the page ids are traced, so one
     compilation covers every step at a given page count."""
     layout, slot_ids = served_stores(cfg), None
@@ -2398,7 +2907,10 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
         *args, slot_ids, first = args
     *stores, token, pos, page_ids = args
     ids = {"page": page_ids, "slot": slot_ids}
-    ps = stores[0].shape[2]
+    ps = _page_size(stores, layout)
+    if any(s.table == "state" for s in layout):
+        # the state BEFORE this position: the page of the one before it
+        ids["state"] = page_ids[jnp.maximum(pos - 1, 0) // ps][None]
     cached = [_read_pages(pages, ids[s.table])
               for pages, s in zip(stores, layout)]
     x = embed_tokens(cfg, params, token[None, :], None)
@@ -2411,7 +2923,8 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
         {c: partial(SERVED[c].decode, cfg, call) for c in call.stores},
         cached, [1] * len(layout))
     logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
-    at = {"page": (0, page_ids[pos // ps], pos % ps)}
+    page = page_ids[pos // ps]
+    at = {"page": (0, page, pos % ps), "state": (0, page, 0)}
     if slot_ids is not None:
         at["slot"] = (0, slot_ids[pos // ps - first], pos % ps)
     stores = [jax.lax.dynamic_update_slice(
@@ -2421,9 +2934,17 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
     return (*stores, logits[0, 0])
 
 
+def _page_size(stores, layout) -> int:
+    """A page's positions, read off a store that keeps a row a position."""
+    return next(pages.shape[2] for pages, s in zip(stores, layout)
+                if TABLES[s.table].rows is None)
+
+
 def copy_page_in_stores(stores, src, dst):
     """Slab ``src`` duplicated into ``dst`` (both traced: page ids, or
-    slots) in every one of ``stores``, the stores of ONE table."""
+    slots) in every one of ``stores``, the stores that go by ONE kind of id
+    (a page's positions and, with them, a sequence's state that lies in the
+    page)."""
     return tuple(jax.lax.dynamic_update_slice(
         pages, _page_slab(pages, src), (0, dst) + (0,) * (pages.ndim - 2))
         for pages in stores)
@@ -2441,7 +2962,11 @@ _MATMUL_LAYER = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                  "ffn_gate", "ffn_up", "ffn_down",
                  # the indexer's query and key projections; the head
                  # weights' (ww) is used in float32, as a router is
-                 "wqi", "wki")
+                 "wqi", "wki",
+                 # the delta rule's projections and the gated shared expert
+                 # (its gate's vector w_sg, A_log, dt_bias and the
+                 # convolution are used in float32)
+                 "w_qkvz", "w_ba", "w_out", "ws_gate", "ws_up", "ws_down")
 
 
 def serving_params(cfg: LlamaConfig, params) -> Dict[str, Any]:
@@ -2482,10 +3007,11 @@ class LlamaDecodeEngine:
     by which table, through which block, attended how in prefill and in
     decode is said there and nowhere here. The engine serves a stack whose
     kinds are ONE family's, all of them (dense blocks; all ``"S"``; ``"F"``
-    with ``"W"``; all ``"I"``). A kind without a row (a Mamba layer's state
-    has no page kind yet; the ``"E"`` / ``"*"`` halves), a part or a mix of
-    families, whole-projection QK-norm and the UNPATTERNED routed block are
-    refused: no test holds their logits to a reference here.
+    with ``"W"``; all ``"I"``; ``"D"`` with ``"A"``). A kind without a row
+    (the ``"M"`` mixer, whose state would go by the ``"state"`` rule; the
+    ``"E"`` / ``"*"`` halves), a part or a mix of families, whole-projection
+    QK-norm and the UNPATTERNED routed block are refused: no test holds
+    their logits to a reference here.
 
     ``params`` is the tree the programs run on, :func:`serving_params`': the
     matmul weights in ``cfg.dtype``, converted ONCE here and not inside
@@ -2495,10 +3021,16 @@ class LlamaDecodeEngine:
     ``ray_tpu_serve_engine_weight_bytes{dtype}`` reports.
 
     THE STORES. Physical pages live ON THE DEVICE, in float32 arrays,
-    ``stores`` (:func:`served_stores`: one a row of every kind's ``rows``):
+    ``stores`` (:func:`served_stores`: one a row of every kind's ``rows``),
+    each laid out as its table's rule says (:data:`TABLES`):
     ``[layers * sublayers, n_pages, page_size, *row]`` by pool page id for
     a row whose table is ``"page"``, ``[.., n_slots, ..]`` by SLOT (below)
-    for ``"slot"``. They are read and written only inside three jitted
+    for ``"slot"``, ``[.., n_pages, 1, *row]`` for ``"state"``: ONE row a
+    page, the state a recurrent layer keeps A SEQUENCE (after the newest
+    position written in that page), which a decode call reads and rewrites
+    where every other row is appended, and which ``copy_page`` copies with
+    its page, so that a shared page's state is never written. They are
+    read and written only inside three jitted
     programs that take them donated and return them: prefill writes the
     layers' rows into the pages (slots) it is given, decode gathers the
     sequence's page table into a page-padded view (positions beyond the
@@ -2562,8 +3094,9 @@ class LlamaDecodeEngine:
                 f"{' | '.join(families.values())}), all of them (both full "
                 f"and window layers), and this stack has "
                 f"{' '.join(sorted(kinds))}, of which the table lacks "
-                f"{' '.join(sorted(kinds - set(SERVED))) or 'none'} (a "
-                f"Mamba layer's state has no page kind); for the 'E' / '*' "
+                f"{' '.join(sorted(kinds - set(SERVED))) or 'none'} (the "
+                f"'M' mixer has no row: its state would go by the 'state' "
+                f"rule the 'D' layers use); for the 'E' / '*' "
                 f"halves, a part or a mix of families, whole-projection "
                 f"QK-norm or an unpatterned routed block no test compares "
                 f"its logits with the reference")
@@ -2591,14 +3124,14 @@ class LlamaDecodeEngine:
         self.prefix_cache = PrefixCache(self.pool)
         self._np = np
         layout = served_stores(self.cfg)
-        # the stores' places by their table: a program's and a copy's
-        self._by_table = {table: [i for i, s in enumerate(layout)
-                                  if s.table == table]
-                          for table in ("page", "slot")}
+        # the stores' places by whose ids find their slabs: a copy's
+        self._by_ids = {ids: [i for i, s in enumerate(layout)
+                              if TABLES[s.table].ids == ids]
+                        for ids in ("page", "slot")}
         # a store by slot has n_slots slabs (the class docstring's rule);
         # the slots' map is one caller's at a time, as the stores are
         self.window_pages = self.n_slots = 0
-        if self._by_table["slot"]:
+        if self._by_ids["slot"]:
             self.window_pages = self.cfg.window_pages(page_size)
             longest = -(-self.cfg.max_seq_len // page_size)
             self.n_slots = min(n_pages, -(-n_pages // longest)
@@ -2608,16 +3141,21 @@ class LlamaDecodeEngine:
         if self.n_slots:
             self.pool.release_hooks.append(self._free_slots_of)
         self.stores = tuple(
-            jnp.zeros((s.layers, self.n_slots if s.table == "slot"
-                       else n_pages, page_size, *s.row), jnp.float32)
+            jnp.zeros(s.shape(n_pages, self.n_slots, page_size), jnp.float32)
             for s in layout)
-        # every tag of the table always, as above
-        page_bytes = {tag: 0 for kind in SERVED.values()
-                      for tag, *_ in kind.rows(self.cfg)}
+        # every tag of the table always, as above: a row a position under
+        # page_bytes, a row a sequence under state_bytes
+        held = {(tag, TABLES[table].rows is None): 0
+                for kind in SERVED.values()
+                for tag, _, _, table in kind.rows(self.cfg)}
         for s in layout:
-            page_bytes[s.tag] += 4 * s.layers * math.prod(s.row)
-        for tag, nbytes in page_bytes.items():
-            _g_engine_page_bytes.set(float(nbytes), tags={"kind": tag})
+            held[s.tag, TABLES[s.table].rows is None] += (
+                4 * s.layers * math.prod(s.row))
+        for (tag, a_position), nbytes in held.items():
+            if a_position:
+                _g_engine_page_bytes.set(float(nbytes), tags={"kind": tag})
+            else:
+                _g_engine_state_bytes.set(float(nbytes), tags={"part": tag})
         self._note_slots()
         groups = {"program": 0, "layer": 0}
         if self.cfg.num_experts:  # the routed kinds it serves
@@ -2650,8 +3188,8 @@ class LlamaDecodeEngine:
         # compiled here, a shape of its own a table: a server warms prefill
         # and decode by running them, but may never copy a page before its
         # first prefix hit
-        for table in self._by_table:
-            self._copy(table, 0, 0)
+        for ids in self._by_ids:
+            self._copy(ids, 0, 0)
 
     # ---- window slots (a stack with window layers; else n_slots is 0)
 
@@ -2803,9 +3341,10 @@ class LlamaDecodeEngine:
             _g_engine_selected_share.set(kept / visible,
                                          tags={"program": program})
 
-    def _copy(self, table: str, src: int, dst: int) -> None:
-        """Slab ``src`` of the stores by ``table`` copied into ``dst``."""
-        np, which = self._np, self._by_table[table]
+    def _copy(self, ids: str, src: int, dst: int) -> None:
+        """Slab ``src`` of the stores that go by ``ids`` copied into
+        ``dst``."""
+        np, which = self._np, self._by_ids[ids]
         if not which:
             return
         stores = list(self.stores)
@@ -2817,14 +3356,15 @@ class LlamaDecodeEngine:
         self.stores = tuple(stores)
 
     def copy_page(self, src: int, dst: int) -> None:
-        # the stores by page id in one call; those by slot in one, where
-        # the source has a slot (the copy's is assigned first: short of
-        # slots nothing is copied)
+        # the stores by page id in one call (a page's positions and the
+        # state that lies with it); those by slot in one, where the source
+        # has a slot (the copy's is assigned first: short of slots nothing
+        # is copied)
         moves = {"page": (src, dst)}
         if src in self._slot_of:
             moves["slot"] = self._slots_for([src, dst])
-        for table, (a, b) in moves.items():
-            self._copy(table, a, b)
+        for ids, (a, b) in moves.items():
+            self._copy(ids, a, b)
 
 
 # --------------------------------------------------------------------------- #

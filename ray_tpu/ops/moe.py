@@ -482,7 +482,7 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
                scoring: str = "softmax", choice_bias=None,
                scale: float = 1.0, held: Optional[Tuple[int, int]] = None,
                shared=None, zero_experts: int = 0, layer=None,
-               router_input=None, act: str = "swiglu"
+               router_input=None, act: str = "swiglu", shared_gated=None
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Dropless top-k routed experts. ``h`` [..., d] in the compute type.
 
@@ -509,6 +509,9 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
       rows from ``h``.
     - ``shared = (w_up [d, fs], w_down [fs, d])``: one expert of that form
       (no gate) every token runs, added to the routed sum once.
+    - ``shared_gated = (w_sg [d], w_gate [d, fs], w_up [d, fs], w_down
+      [fs, d])``: one SwiGLU expert every token runs, behind the token's own
+      gate ``sigmoid(h . w_sg)`` (float32), added to the routed sum once.
     - ``held = (first, count)``: this device holds the ``count`` experts
       from ``first`` on of the router's ``E`` (``w_up`` [count, d, f]):
       router and ``top_k`` run over all ``E``; assignments to experts held
@@ -595,10 +598,13 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
             ys = _expert_ffn(xs, w_gate, w_up, w_down, counts, layer, act)
         with jax.named_scope("moe.combine"):
             y = _combine(ys, top_w, order, inverse)
-    else:  # a block of places is four even shares of the assignments, and
-        # at least 128 places (a decode call's few are one block)
+    else:  # a block of places is four even shares of the assignments (two
+        # where four would be all of them: a quarter of the experts held),
+        # and at least 128 places (a decode call's few are one block)
         y = _held_rows(hf, top_w, order, starts, end, (w_gate, w_up, w_down),
-                       max(1, min(E // (4 * count), A // 128)), layer, act)
+                       max(1, min(max(E // (4 * count),
+                                      min(2, E // (2 * count))), A // 128)),
+                       layer, act)
     if zero_experts:
         with jax.named_scope("moe.zero"):
             to_zero = top_e >= E - zero_experts
@@ -608,6 +614,16 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
         with jax.named_scope("moe.shared"):
             a = jnp.square(jax.nn.relu(hf @ shared[0].astype(cd)))
             y = y + (a @ shared[1].astype(cd)).astype(jnp.float32)
+    if shared_gated is not None:
+        with jax.named_scope("moe.shared"):
+            w_sg, s_gate, s_up, s_down = shared_gated
+            a = (jax.nn.silu((hf @ s_gate.astype(cd)).astype(jnp.float32))
+                 * (hf @ s_up.astype(cd))).astype(cd)
+            open_ = jax.nn.sigmoid(jnp.dot(
+                hf.astype(jnp.float32), w_sg.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            y = y + open_[:, None] * (a @ s_down.astype(cd)).astype(
+                jnp.float32)
     with jax.named_scope("moe.route"):
         fraction = jax.lax.stop_gradient(counts.astype(jnp.float32) / N)
         for ax in stat_axes:

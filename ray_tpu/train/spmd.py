@@ -641,6 +641,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
 
     from ray_tpu.collective import pmean_tree
     from ray_tpu.models.llama import (
+        _no_delta_kinds,
         _no_window_kinds,
         _plain_chunk_nll,
         add_router_losses,
@@ -674,6 +675,11 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         "causal triangle and has no window (forward and backward), and "
         "under fsdp / tensor a patterned stack has no per-kind gather; "
         "models.llama.loss_fn runs these kinds through attend_tiles")
+    _no_delta_kinds(
+        cfg, "make_spmd_train_step",
+        "no train step is held to a reference for the gated delta rule's "
+        "backward (autodiff through ops/gdn.py's chunked form) or the gated "
+        "attention's; models.llama.loss_fn runs the forward of both")
 
     tensor = ("tensor" if "tensor" in mesh.axis_names
               and mesh.shape["tensor"] > 1 else None)

@@ -690,11 +690,14 @@ def test_the_engine_is_what_the_table_folds(family, n_pages, page, held):
         (s.layers, engine.n_slots if s.table == "slot" else n_pages, page,
          *s.row) for s in layout]
     want = {tag: 0.0 for kind in llama.SERVED.values()
-            for tag, *_ in kind.rows(cfg)}
+            for tag, _, _, table in kind.rows(cfg)
+            if llama.TABLES[table].rows is None}  # a row a POSITION
     for s in layout:
         want[s.tag] += 4.0 * s.layers * math.prod(s.row)
     assert _gauge("ray_tpu_serve_engine_page_bytes") == want
-    assert set(want) == {"kv", "latent", "full", "window", "index"}
+    assert set(want) == {"kv", "latent", "full", "window", "index", "gated"}
+    # these families keep nothing a SEQUENCE (tests/test_qwen3_next.py)
+    assert set(_gauge("ray_tpu_serve_engine_state_bytes").values()) == {0.0}
     for part in ("held", "zero", "elsewhere"):
         llama._g_moe_assignment_share.set(-1.0, tags={"part": part})
     pages = engine.pool.alloc(2)

@@ -4,6 +4,8 @@ interpret mode cannot refuse, at no chip time. Nothing runs, so nothing here
 is a result or a time. All such compiles live in THIS file: the process that
 describes the topology holds the TPU library until it exits."""
 
+import math
+
 import pytest
 
 jax = pytest.importorskip("jax")
@@ -292,6 +294,10 @@ PREFILL = {
     "smallthinker full 16 pages": (16384, 28, 4, 128, 128, 0),
     "smallthinker window 16 pages": (16384, 28, 4, 128, 128, 4096),
     "smallthinker window 5 pages": (5120, 28, 4, 128, 128, 4096),
+    # gated attention at head width 256, values as wide: a head's whole keys
+    # and values are 16 MB each at 32,768 positions, twice
+    "qwen3next gated 3 pages": (6144, 16, 2, 256, 256, 0, 256),
+    "qwen3next gated 16 pages": (32768, 16, 2, 256, 256, 0, 256),
 }
 
 
@@ -305,7 +311,8 @@ def test_prefill_kernel_compiles_at_the_cells_shapes(what, one_chip,
     call asks for its limit. One Mosaic call, nothing of the tile loop."""
     from ray_tpu.models import llama
 
-    t, hq, hkv, d, dk, window = PREFILL[what]
+    t, hq, hkv, d, dk, window, *dv = PREFILL[what]
+    dv = dv[0] if dv else 128
 
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -313,7 +320,7 @@ def test_prefill_kernel_compiles_at_the_cells_shapes(what, one_chip,
     shared = arg(1, t, d - dk) if d > dk else None
     lowered = jax.jit(lambda q, k, v, shared: llama.attend_tiles(
         q, k, v, jnp.bfloat16, window=window, shared=shared)).lower(
-            arg(1, t, hq, d), arg(1, t, hkv, dk), arg(1, t, hkv, 128), shared)
+            arg(1, t, hq, d), arg(1, t, hkv, dk), arg(1, t, hkv, dv), shared)
     assert "scoped_memory_configs" in lowered.as_text()
     text = lowered.compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
@@ -346,3 +353,55 @@ def test_selection_kernels_compile_at_the_keye_cells_shapes(pages, one_chip,
         arg(1, t, 32, 128), arg(1, t, 4, 128), arg(1, t, 4, 128), mask)
     assert attend.compile().as_text().count(
         'custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("program,pages", [
+    ("prefill", 3), ("prefill", 16), ("decode", 4), ("decode", 16)])
+def test_qwen3next_cells_programs_compile_and_fit_the_chip(
+        program, pages, one_chip, as_on_the_chip):
+    """The delta-rule family's WHOLE prefill and decode programs at the
+    cell's published widths, its stores and its shortest and longest page
+    tables, from shapes alone: the chunked delta rule's temporaries beside
+    9.7 GB of weights and stores must leave the program inside the chip
+    (16 pages: 4.5 GB of temporaries; the in-projection as ONE product, the
+    convolution over the whole prompt or a routed block of every assignment
+    each put it past 16 GB), prefill holds the flash kernel at head width
+    256 and the experts' grouped kernel, decode neither."""
+    from functools import partial
+
+    from benchmarks.lib import spec
+    from ray_tpu.models import llama
+
+    file = spec.cell_bundle("serve-qwen3next-prefill-open")["config"]
+    cfg, dep = spec.program_config(file), file["deployment"]
+    ps, n_pages = dep["page_size"], dep["n_pages"]
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: llama.serving_params(cfg, llama.init_params(cfg, key)),
+        jax.random.PRNGKey(0)))
+    layout = llama.served_stores(cfg)
+    stores = [jax.ShapeDtypeStruct(s.shape(n_pages, 0, ps), jnp.float32,
+                                   sharding=one_chip) for s in layout]
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    if program == "prefill":
+        fn, args = llama.prefill_with_cache, (i32(1, pages * ps), i32(pages),
+                                              i32())
+    else:
+        fn, args = llama.decode_step_with_cache, (i32(1), i32(), i32(pages))
+    compiled = jax.jit(partial(fn, cfg), donate_argnums=tuple(
+        range(1, 1 + len(layout)))).lower(params, *stores, *args).compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes
+    assert 9.7e9 < held < 9.8e9
+    assert memory.alias_size_in_bytes >= sum(
+        4 * math.prod(a.shape) for a in stores)  # every store in place
+    assert held + memory.temp_size_in_bytes < 15.0e9
+    text = compiled.as_text()
+    assert ("%flash_prefill" in text) == (program == "prefill")
+    assert ("moe_ffn" in text) == (program == "prefill")
