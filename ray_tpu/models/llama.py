@@ -99,16 +99,16 @@ _g_engine_window_slots = Gauge(
     "Page slabs of the decode engine's window stores: all of them, and "
     "those assigned to a pool page", tag_keys=("state",))
 # which way a prefill's attention went, counted where a program is traced
-# (attend_tiles): the Pallas kernel (ops/flash_prefill.py) or the XLA tile
-# loop, by the layer kind that asked. Both paths of a kind are always set,
-# the one not taken at what it has counted so far (0 in an engine's process
-# on the chip, where every compiled prefill program reads path=kernel);
-# prefill_attend_paths() keeps the reason beside the count
+# (attend_tiles; attend_delta): a Pallas kernel (ops/flash_prefill.py; kind
+# delta: ops/gdn_prefill.py) or XLA (path=tiles; kind delta: path=chunks),
+# by the layer kind that asked. Both paths of a kind are always set, the one
+# not taken at what it has counted so far (0 in an engine's process on the
+# chip); prefill_attend_paths() keeps the reason beside the count
 _g_engine_prefill_attend = Gauge(
     "ray_tpu_serve_engine_prefill_attend",
     "Prefill attentions traced into the decode engine's programs (and the "
-    "full forward), by layer kind and by the path they took: the flash "
-    "kernel or XLA tiles", tag_keys=("kind", "path"))
+    "full forward), by layer kind and by the path they took: a Pallas "
+    "kernel, or XLA tiles / chunks", tag_keys=("kind", "path"))
 # which way the experts' products on a kind's STACKED leaves went, counted
 # where a program is traced (ops/moe.py _expert_ffn tells an engine's
 # process through watch_stacked_calls): in an engine's process on the chip
@@ -1288,7 +1288,7 @@ def prefill_attend_paths() -> list:
         return [dict(rec) for rec in _prefill_attend_taken.values()]
 
 
-def _note_prefill_attend(kind, q, k, window, path, reason) -> None:
+def _note_prefill_attend(kind, q, k, window, path, reason, xla="tiles"):
     key = (kind, q.shape, k.shape, window, path)
     with _paths_lock:
         rec = _prefill_attend_taken.setdefault(key, {
@@ -1297,7 +1297,7 @@ def _note_prefill_attend(kind, q, k, window, path, reason) -> None:
         rec["calls"] += 1
         counts = {way: sum(r["calls"] for r in _prefill_attend_taken.values()
                            if (r["kind"], r["path"]) == (kind, way))
-                  for way in ("kernel", "tiles")}
+                  for way in ("kernel", xla)}
     for way, n in counts.items():
         _g_engine_prefill_attend.set(float(n),
                                      tags={"kind": kind, "path": way})
@@ -1863,7 +1863,9 @@ def delta_block(cfg: LlamaConfig, x, layers, i, positions, attend,
     causal convolution over ``[q | k | v]`` and ``silu``, the heads' split
     and l2-norm (:func:`_delta_heads`) and the gated delta rule
     (``ops/gdn.py``), over the call's own positions from an empty state
-    (:func:`attend_delta`) or one token on from a kept state and tail
+    (:func:`attend_delta`: on a TPU all of it is ONE Pallas call,
+    ``ops/gdn_prefill.py``, which reads ``[q | k | v]`` where the product
+    below wrote it) or one token on from a kept state and tail
     (:func:`_attend_state`). It returns ``o`` [B, T, value heads, value dim]
     float32 and what a cache keeps A SEQUENCE: the state after the last real
     position ``[B, 1, value heads, key dim, value dim]`` float32 and the
@@ -1918,59 +1920,57 @@ def _delta_heads(cfg: LlamaConfig, mixed):
     return q, k, v.reshape(B, T, hv, -1).astype(cfg.dtype)
 
 
-# a long prompt's delta-rule layers go this many positions at a time (where
-# it divides them): the convolution's float32 rows, the heads and what the
-# chunked form prepares for all of a stretch's chunks at once are a
-# segment's, 1.6 GB at the published widths where 32,768 positions' are 5 GB
+# the XLA path of a long prompt's delta-rule layers goes this many positions
+# at a time (where it divides them): the convolution's float32 rows, the heads
+# and what the chunked form prepares for all of a stretch's chunks at once are
+# a segment's, 1.6 GB at the published widths where 32,768 positions' are
+# 5 GB. The kernel's path knows no segment: its state stays in VMEM
 DELTA_SEGMENT = 8192
 
 
 def attend_delta(cfg: LlamaConfig, last, qkv, g, beta, conv_w):
     """:func:`delta_block`'s ``attend`` over the call's own positions from an
-    empty state (the full forward and prefill): the causal convolution
-    (``ops/ssm.py causal_conv``, no bias), then the recurrence in chunks
-    (``ops/gdn.py gated_delta_chunked``), ``DELTA_SEGMENT`` positions at a
-    time: a ``lax.scan`` that carries what a decode call would find, the
-    state and the convolution's tail. ``last`` (a number, traced or not;
-    None: the last position): the last REAL position. The engine right-pads
-    a prompt to whole pages, and a causal mask keeps nothing out of a
-    recurrence: the positions after ``last`` are identity updates, so the
-    state is the state after ``last``, and the tail is taken at ``last``
-    (rows before the sequence's first are zeros, as the convolution pads)."""
-    from ray_tpu.ops.gdn import gated_delta_chunked
-    from ray_tpu.ops.ssm import causal_conv
+    empty state (the full forward and prefill): the causal convolution, the
+    heads' l2-norm and the recurrence in chunks. ``last`` (a number, traced
+    or not; None: the last position): the last REAL position. The engine
+    right-pads a prompt to whole pages, and a causal mask keeps nothing out
+    of a recurrence: the positions after ``last`` are identity updates
+    (``beta = 0``, ``g = 0``), so the state is the state after ``last``, and
+    the tail is taken at ``last`` (rows before the sequence's first are
+    zeros, as the convolution pads).
 
-    f32 = jnp.float32
+    Two paths, ONE arithmetic (:func:`delta_prefill_path` says which and
+    why; counted as kind ``delta``, path ``kernel`` or ``chunks``, where
+    :func:`attend_tiles`' kinds are). On a TPU backend one Pallas call,
+    forward only (``ops/gdn_prefill.py``, imported here and nowhere else;
+    its transpose is the XLA path's): the rows are read as the in-projection
+    wrote them, a head's state and the convolution's last rows stay in VMEM,
+    and the prompt is ONE piece whatever its pages. On every other backend,
+    for what the kernel does not take and as its oracle,
+    :func:`_delta_chunks` in XLA (``ops/ssm.py causal_conv``, ``ops/gdn.py
+    gated_delta_chunked``, ``DELTA_SEGMENT`` positions at a time). Device
+    scopes ``gdn.conv`` / ``gdn.scan``; the kernel runs under ``gdn.scan``,
+    and what it is handed has the positions behind ``last`` made identity
+    updates HERE (two ``where``s over ``[B, T, value heads]``), which the
+    XLA form does for itself a segment.
+
+    The tail is XLA's gather of ``lin_conv - 1`` rows of ``qkv`` at
+    ``last`` on both paths (:func:`_delta_tail`).
+
+    Both paths, the tail and the rule stand at this file's END: lines put in
+    here move the frames that every other program's Mosaic kernels record."""
     (B, T, _), K = qkv.shape, conv_w.shape[0]
-    seg = DELTA_SEGMENT if T % DELTA_SEGMENT == 0 else T
-
-    def segment(carry, xs):
-        state, before = carry
-        qkv_s, g_s, beta_s, start = xs
-        with jax.named_scope("gdn.conv"):
-            rows = jnp.concatenate([before, qkv_s], axis=1).astype(f32)
-            mixed = jax.nn.silu(causal_conv(rows, conv_w, 0.0)[:, K - 1:])
-            q, k, v = _delta_heads(cfg, mixed)
+    path, reason = delta_prefill_path(cfg, qkv, g, beta)
+    _note_prefill_attend("delta", qkv, g, 0, path, reason, "chunks")
+    if path == "kernel":
+        if last is not None:
+            live = (jnp.arange(T) <= last)[None, :, None]
+            g, beta = jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0)
         with jax.named_scope("gdn.scan"):
-            o, state = gated_delta_chunked(
-                q, k, v, g_s, beta_s, cfg.lin_chunk, state,
-                None if last is None else last - start)
-        return (state, qkv_s[:, seg - (K - 1):]), o
-
-    def segments(a):  # [B, T, ...] -> [T / seg, B, seg, ...]
-        return jnp.moveaxis(a.reshape(B, -1, seg, *a.shape[2:]), 1, 0)
-
-    hv = cfg.lin_value_heads
-    start = (jnp.zeros((B, hv, cfg.lin_key_dim, cfg.lin_value_dim), f32),
-             jnp.zeros((B, K - 1, qkv.shape[-1]), qkv.dtype))
-    (state, _), o = jax.lax.scan(
-        segment, start, (segments(qkv), segments(g), segments(beta),
-                         jnp.arange(0, T, seg, dtype=jnp.int32)))
-    with jax.named_scope("gdn.conv"):
-        at = (T - 1 if last is None else last) - (K - 2) + jnp.arange(K - 1)
-        tail = jnp.where((at >= 0)[None, :, None],
-                         qkv[:, jnp.maximum(at, 0)], 0)
-    o = jnp.moveaxis(o, 0, 1).reshape(B, T, hv, -1)
+            o, state = _delta_kernel(qkv, g, beta, conv_w, cfg)
+        tail = _delta_tail(last, qkv, K)
+    else:
+        o, state, tail = _delta_chunks(cfg, last, qkv, g, beta, conv_w)
     return o, state[:, None], tail[:, None]
 
 
@@ -3672,3 +3672,125 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
         ),
         "llama.pipe_train_step")
     return init_jit, train_step, data_sharding, state_shardings
+
+
+# --- the delta rule's prefill: its rule and its two paths (attend_delta) --- #
+# Here, behind every frame a compiled program records, and not beside
+# attend_delta: a Mosaic kernel's bytecode holds the call stack it was traced
+# under, so lines put in above move every other program out of the compile
+# cache (ROADMAP Speed, setup_s).
+
+
+def delta_prefill_path(cfg: LlamaConfig, qkv, g, beta) -> Tuple[str, str]:
+    """``(path, reason)`` :func:`attend_delta` takes for these operands in
+    this process: ``"kernel"`` on a TPU backend for what
+    ``ops/gdn_prefill.py`` takes (``qkv`` in ``cfg.dtype``, ``g`` and
+    ``beta`` float32, key and value widths whole lanes, a key head's value
+    heads side by side in whole column blocks, positions a multiple of one
+    of its row tiles), ``"chunks"`` with what stands in the way otherwise.
+    Read from the backend and the shapes alone."""
+    platform = jax.default_backend()
+    if platform != "tpu":
+        return "chunks", f"backend is {platform!r}, not tpu"
+    from ray_tpu.ops.gdn_prefill import pick_rows
+
+    hk, dk = cfg.lin_key_heads, cfg.lin_key_dim
+    hv, dv = cfg.lin_value_heads, cfg.lin_value_dim
+    types = [a.dtype.name for a in (qkv, g, beta)]
+    if types != [jnp.dtype(cfg.dtype).name, "float32", "float32"]:
+        return "chunks", (f"[q | k | v], g, beta in {types}: not "
+                          f"{jnp.dtype(cfg.dtype).name} and float32 twice")
+    if dk % 128 or dv % 128:
+        return "chunks", (f"key width {dk} or value width {dv} is no "
+                          "multiple of 128 lanes")
+    if hv % hk or hv // hk > 2 or (2 * hk * dk) % (hv // hk * dv):
+        return "chunks", (f"{hv} value heads of {dv} on {hk} key heads of "
+                          f"{dk}: a key head's value heads (at most 2, a "
+                          "chunk of each side by side in 128 lanes) are no "
+                          "whole column block")
+    if pick_rows(qkv.shape[1]) is None:
+        return "chunks", (f"{qkv.shape[1]} positions are no multiple of a "
+                          "row tile of the kernel's")
+    return "kernel", "tpu backend"
+
+
+def _delta_chunks(cfg: LlamaConfig, last, qkv, g, beta, conv_w):
+    """:func:`attend_delta` in XLA: the causal convolution (``ops/ssm.py
+    causal_conv``, no bias), then the recurrence in chunks (``ops/gdn.py
+    gated_delta_chunked``), ``DELTA_SEGMENT`` positions at a time where that
+    divides them and in one piece where not: a ``lax.scan`` that carries
+    what a decode call would find, the state and the convolution's tail.
+    Returns ``(o [B, T, value heads, value dim] float32, state, tail)``."""
+    from ray_tpu.ops.gdn import gated_delta_chunked
+    from ray_tpu.ops.ssm import causal_conv
+
+    f32 = jnp.float32
+    (B, T, _), K = qkv.shape, conv_w.shape[0]
+    seg = DELTA_SEGMENT if T % DELTA_SEGMENT == 0 else T
+
+    def segment(carry, xs):
+        state, before = carry
+        qkv_s, g_s, beta_s, start = xs
+        with jax.named_scope("gdn.conv"):
+            rows = jnp.concatenate([before, qkv_s], axis=1).astype(f32)
+            mixed = jax.nn.silu(causal_conv(rows, conv_w, 0.0)[:, K - 1:])
+            q, k, v = _delta_heads(cfg, mixed)
+        with jax.named_scope("gdn.scan"):
+            o, state = gated_delta_chunked(
+                q, k, v, g_s, beta_s, cfg.lin_chunk, state,
+                None if last is None else last - start)
+        return (state, qkv_s[:, seg - (K - 1):]), o
+
+    def segments(a):  # [B, T, ...] -> [T / seg, B, seg, ...]
+        return jnp.moveaxis(a.reshape(B, -1, seg, *a.shape[2:]), 1, 0)
+
+    hv = cfg.lin_value_heads
+    start = (jnp.zeros((B, hv, cfg.lin_key_dim, cfg.lin_value_dim), f32),
+             jnp.zeros((B, K - 1, qkv.shape[-1]), qkv.dtype))
+    (state, _), o = jax.lax.scan(
+        segment, start, (segments(qkv), segments(g), segments(beta),
+                         jnp.arange(0, T, seg, dtype=jnp.int32)))
+    tail = _delta_tail(last, qkv, K)
+    return jnp.moveaxis(o, 0, 1).reshape(B, T, hv, -1), state, tail
+
+
+def _delta_tail(last, qkv, K: int):
+    """The convolution's tail at ``last`` (None: the last position): the
+    ``K - 1`` rows of ``qkv`` [B, T, width] that end there, zeros before the
+    sequence's first."""
+    T = qkv.shape[1]
+    with jax.named_scope("gdn.conv"):
+        at = (T - 1 if last is None else last) - (K - 2) + jnp.arange(K - 1)
+        return jnp.where((at >= 0)[None, :, None],
+                         qkv[:, jnp.maximum(at, 0)], 0)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _delta_kernel(qkv, g, beta, conv_w, cfg):
+    """``g`` and ``beta`` with the positions behind the last real one
+    already 0: from an empty state and tail, as :func:`_delta_chunks`."""
+    from ray_tpu.ops.gdn_prefill import gdn_prefill
+
+    B, K = qkv.shape[0], conv_w.shape[0]
+    # interpreted where a test has steered a CPU process onto this path
+    return gdn_prefill(
+        qkv, conv_w, g, beta,
+        jnp.zeros((B, cfg.lin_value_heads, cfg.lin_key_dim,
+                   cfg.lin_value_dim), jnp.float32),
+        jnp.zeros((B, K - 1, qkv.shape[-1]), qkv.dtype),
+        key_heads=cfg.lin_key_heads, key_dim=cfg.lin_key_dim,
+        interpret=jax.default_backend() != "tpu")
+
+
+def _delta_kernel_fwd(qkv, g, beta, conv_w, cfg):
+    return _delta_kernel(qkv, g, beta, conv_w, cfg), (qkv, g, beta, conv_w)
+
+
+def _delta_kernel_bwd(cfg, operands, cotangent):
+    # forward only: the kernel keeps nothing for a backward pass, so its
+    # transpose is the XLA path's, run again from the operands
+    return jax.vjp(lambda *a: _delta_chunks(cfg, None, *a)[:2],
+                   *operands)[1](cotangent)
+
+
+_delta_kernel.defvjp(_delta_kernel_fwd, _delta_kernel_bwd)

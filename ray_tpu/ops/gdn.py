@@ -33,9 +33,15 @@ read ``S_0`` is computed for all chunks at once; a ``lax.scan`` over chunks
 carries ``[H, K, V]`` and does the four products that do.
 
 Products run in the compute type with float32 accumulation; ``g``, the
-cumulative decays, the solve and the carried state are float32. No kernel: a
-Pallas scan waits for kernels with names, as ``ops/ssm.py``'s does (its
-docstring says why; ROADMAP Reach B3).
+cumulative decays, the solve and the carried state are float32.
+
+This module is the XLA form: what the full forward, every backend but a TPU
+and ``jax.grad`` run, and the oracle of the serving kernel. On a TPU backend a
+prefill's rows go through ``ops/gdn_prefill.py`` instead (PR 46): ONE
+forward-only Pallas call with this algebra and these rounding points, the
+convolution and the heads' l2-norm in front of it, the state in VMEM. The
+delta rule runs in no train step, so the kernel did not have to wait for
+**kernels by name** as ``ops/ssm.py``'s scan does (its docstring says why).
 """
 
 from __future__ import annotations

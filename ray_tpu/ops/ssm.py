@@ -16,9 +16,15 @@ inside a chunk the decay-masked ``C B^T`` scores times ``dt x`` (two
 products, as attention over the chunk), across chunks a ``lax.scan`` that
 carries the ``[H, P, N]`` states. The backward pass is autodiff through it.
 Products run in the compute type with float32 accumulation; ``dt``, the
-decays, the carried state and the norm are float32. No kernel: the
-benchmark's ``flash_roofline`` tells Pallas kernels apart by result type
-alone, so a Pallas scan waits for kernels with names (ROADMAP Reach B3).
+decays, the carried state and the norm are float32. No kernel HERE: this
+scan is ``train-nemotron3nano-1chip``'s step, and the benchmark's
+``flash_roofline`` (the train cells' alone) tells Pallas kernels apart by
+result type, so one more ``tpu_custom_call`` in a train step would be counted
+as ``flash_dq``: a Pallas scan waits for **kernels by name** (ROADMAP Reach
+B1(a)). The serving half does not wait: no serving cell reports
+``flash_roofline``, and the delta rule's prefill, this scan's sibling, is a
+kernel since PR 46 (``ops/gdn_prefill.py``). :func:`causal_conv` stays the
+delta rule's XLA path's too (``models/llama.py _delta_chunks``).
 """
 
 from __future__ import annotations

@@ -276,6 +276,7 @@ def test_programs_that_must_not_change_hold_no_kernel_of_the_experts(
     cells' own programs on both trees."""
     text = UNTOUCHED[program](one_chip)
     assert "tpu_custom_call" not in text and "moe_ffn" not in text
+    assert "gdn_prefill" not in text
     assert ("ragged_dot" in text) == (program != "dense prefill")
 
 
@@ -355,18 +356,49 @@ def test_selection_kernels_compile_at_the_keye_cells_shapes(pages, one_chip,
         'custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.mark.parametrize("pages", [3, 9, 14, 15, 16])
+def test_delta_rule_kernel_compiles_at_the_qwen3next_cells_shapes(
+        pages, one_chip, as_on_the_chip):
+    """``ops/gdn_prefill.py`` at the published widths (16 key heads and 32
+    value heads of 128 in rows of 8,192 columns, 4 taps) at the cell's
+    shortest prompt, at 9 pages and at its longest three, 14 and 15 among
+    them: page counts that 8,192 positions do not divide, which the XLA
+    path took in ONE piece. One Mosaic call whatever the pages, under the
+    VMEM limit it asks for (its blocks and scratch, some 15 MB)."""
+    from ray_tpu.ops.gdn_prefill import gdn_prefill
+
+    t, hk, hv, d, taps = pages * 2048, 16, 32, 128, 4
+    width = 2 * hk * d + hv * d
+
+    def arg(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(lambda *a: gdn_prefill(
+        *a, key_heads=hk, key_dim=d)).lower(
+            arg(1, t, width, dtype=jnp.bfloat16), arg(taps, width),
+            arg(1, t, hv), arg(1, t, hv), arg(1, hv, d, d),
+            arg(1, taps - 1, width, dtype=jnp.bfloat16))
+    assert "scoped_memory_configs" in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%gdn_prefill" in text and "while(" not in text
+
+
 @pytest.mark.parametrize("program,pages", [
-    ("prefill", 3), ("prefill", 16), ("decode", 4), ("decode", 16)])
+    ("prefill", 3), ("prefill", 15), ("prefill", 16), ("decode", 4),
+    ("decode", 16)])
 def test_qwen3next_cells_programs_compile_and_fit_the_chip(
         program, pages, one_chip, as_on_the_chip):
     """The delta-rule family's WHOLE prefill and decode programs at the
     cell's published widths, its stores and its shortest and longest page
-    tables, from shapes alone: the chunked delta rule's temporaries beside
-    9.7 GB of weights and stores must leave the program inside the chip
-    (16 pages: 4.5 GB of temporaries; the in-projection as ONE product, the
-    convolution over the whole prompt or a routed block of every assignment
-    each put it past 16 GB), prefill holds the flash kernel at head width
-    256 and the experts' grouped kernel, decode neither."""
+    tables, from shapes alone: beside 9.7 GB of weights and stores the
+    program must stay inside the chip. Prefill holds the delta rule's
+    kernel (one a ``D`` layer of the scanned period: no triangular solve,
+    no ``[.., 64, 64]`` float32 arrays of a whole prompt), the flash kernel
+    at head width 256 and the experts' grouped kernel; its temporaries are
+    1.11 / 3.95 / 4.19 GB at 3 / 15 / 16 pages (with the chunked form in
+    XLA 1.60 / 6.64 / 4.53: 15 pages went in ONE piece; PERF.md, PR 46).
+    Decode holds none of the three."""
     from functools import partial
 
     from benchmarks.lib import spec
@@ -403,5 +435,13 @@ def test_qwen3next_cells_programs_compile_and_fit_the_chip(
         4 * math.prod(a.shape) for a in stores)  # every store in place
     assert held + memory.temp_size_in_bytes < 15.0e9
     text = compiled.as_text()
-    assert ("%flash_prefill" in text) == (program == "prefill")
-    assert ("moe_ffn" in text) == (program == "prefill")
+    prefill = program == "prefill"
+    assert ("%flash_prefill" in text) == prefill
+    assert ("moe_ffn" in text) == prefill
+    assert ("%gdn_prefill" in text) == prefill
+    if prefill:
+        # the period DDDA is one scanned body: three calls of the kernel
+        assert text.count("%gdn_prefill") % 3 == 0
+        assert "triangular" not in text.lower()
+        assert memory.temp_size_in_bytes < {3: 1.2e9, 15: 4.0e9,
+                                            16: 4.3e9}[pages]
