@@ -3290,11 +3290,11 @@ class LlamaDecodeEngine:
         _sp_prefill_logits.end(_t, n_pages)
         self._note_selected("prefill", T)
         if shares or self.cfg.num_experts:  # every expert here: held is 1.0
-            held = float(shares.get("held_share", 1.0))
-            zero = float(shares.get("zero_share", 0.0))
-            for part, share in (("held", held), ("zero", zero),
-                                ("elsewhere", 1.0 - held - zero)):
-                _g_moe_assignment_share.set(share, tags={"part": part})
+            # where the assignments fell and, of a held range, the places a
+            # row was made for (at this file's end; this block keeps its six
+            # lines: one more would move the frames the kernels below record)
+            _note_assignments(shares, tpad * self.cfg.experts_per_token,
+                              self.cfg)
         return last
 
     def decode(self, pos, token, pages):
@@ -3794,3 +3794,36 @@ def _delta_kernel_bwd(cfg, operands, cotangent):
 
 
 _delta_kernel.defvjp(_delta_kernel_fwd, _delta_kernel_bwd)
+
+
+# what the held path made of the last prefill's assignments: the places with
+# a held expert (``live``: held_share of tokens x top_k, a routed layer's
+# mean) and the places a row was gathered, multiplied and scatter-added for
+# (``made``: ops/moe.py held_places_made, whole chunks up to ``live``). Set by
+# an engine whose layers hold a range of the router's experts, by no other
+_g_moe_places = Gauge(
+    "ray_tpu_serve_moe_places",
+    "Sorted places of the last prefill's routed layers (a layer's mean) "
+    "that fell on experts held here, and the places a row was made for",
+    tag_keys=("state",))
+
+
+def _note_assignments(shares, places: int, cfg) -> None:
+    """Set ``ray_tpu_serve_moe_assignment_share{part}`` from a prefill's
+    ``shares`` (a routed layer's mean of ``routed_mlp``'s ``held_share`` /
+    ``zero_share``; neither: every expert is here) and, where a range is
+    held, ``ray_tpu_serve_moe_places{state}`` of its ``places`` (tokens x
+    ``top_k``, pads and all: the program routes them too)."""
+    from ray_tpu.ops.moe import held_places_made
+
+    held = float(shares.get("held_share", 1.0))
+    zero = float(shares.get("zero_share", 0.0))
+    for part, share in (("held", held), ("zero", zero),
+                        ("elsewhere", 1.0 - held - zero)):
+        _g_moe_assignment_share.set(share, tags={"part": part})
+    if "held_share" in shares:
+        live = round(held * places)
+        wide = (cfg.router_experts or cfg.num_experts) + cfg.zero_experts
+        made = held_places_made(places, live, cfg.num_experts, wide)
+        for state, n in (("live", live), ("made", made)):
+            _g_moe_places.set(float(n), tags={"state": state})

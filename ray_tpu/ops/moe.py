@@ -11,8 +11,8 @@
   move by gathers both ways (no scatter). A layer that HOLDS A RANGE of the
   router's experts (``held=``: one chip's share of an expert-parallel job)
   routes over all of them, sorts the assignments to experts held elsewhere
-  behind the held ones, and makes rows, products and the sum onto tokens (a
-  scatter-add of few rows) for the first places alone (:func:`_held_rows`).
+  behind the held ones, and makes rows, products and the float32 scatter-add
+  onto tokens for the LIVE places (:func:`_held_rows`: a loop up to ``end``).
   The router's variants (scores, a choice-only bias, a weight scale, the
   experts' form, a shared expert, identity experts, a router input of its
   own) are arguments, each by itself.
@@ -423,57 +423,57 @@ def _expert_xla(xs, w_gate, w_up, w_down, counts, layer, act):
     return ys[:n] if n % 8 else ys
 
 
-def _held_rows(hf, top_w, order, starts, end, weights, blocks,
+# what a row tile of the grouped kernel holds: a chunk of the held path's
+# forward loop is whole tiles (held_chunk), so that a chunk edge cuts a group
+# where a tile edge would
+HELD_TILE = 256
+
+
+def _held_rows(hf, top_w, order, starts, end, weights, experts,
                layer=None, act: str = "swiglu"):
     """The routed sum ``[N, d]`` float32 of a layer that holds some of the
-    router's experts. ``order`` [A] are the assignments sorted by held
-    expert (``starts`` [count]: each one's first place), from place ``end``
-    on those that chose an expert held elsewhere: they get no row, no
-    product and no part in the sum. ``weights`` / ``layer``: as
+    router's ``experts`` experts. ``order`` [A] are the assignments sorted
+    by held expert (``starts`` [count]: each one's first place), from place
+    ``end`` on those that chose an expert held elsewhere: they get no row,
+    no product and no part in the sum. ``weights`` / ``layer``: as
     :func:`_expert_ffn` takes them.
 
-    The sorted places are walked in ``blocks`` blocks of ``A / blocks``.
-    The first block's rows are always made: gathered, through
-    :func:`_expert_ffn` with the held experts' groups, weighted and added
-    to their tokens. A further block's only in a step whose router sends
-    places into it (recomputed in the backward: it is the rare step), so
-    no assignment is ever left out whatever the routing, and a common step
-    makes ``A / blocks`` rows. The index maps here are a gather of rows and
-    a scatter-add onto tokens (transposed: the same two): ``_dispatch`` /
-    ``_combine`` gather a row for EVERY assignment, which at 16,384 tokens
-    x 6 of width 2688 is 5.3 ms a map (9.5 before PR 41) against 0.3 and
-    3.6 ms for a quarter of the places (PERF.md, PRs 31 and 41). On the TPU
-    the grouped product leaves the rows of no group UNWRITTEN (whatever the
-    buffer held): places from ``end`` on are set to zero going in and coming
-    out, and so are, transposed, their cotangents."""
-    (N, d), A = hf.shape, order.shape[0]
-    if A % blocks:
-        blocks = 1
-    n = A // blocks
-    edges = jnp.append(starts, end).astype(jnp.int32)
-    flat_w = top_w.reshape(A)
+    The index maps here are a gather of rows and a float32 scatter-add onto
+    tokens (``_dispatch`` / ``_combine`` gather a row for EVERY assignment).
+    On this chip the gather is linear in the places a row is made for (from
+    HBM 24-30 rows a microsecond); a scatter-add CALL is 1-2 ms and then
+    0.07-0.1 us a row (PERF.md, PRs 41 and 47). So the places follow the
+    router's own count ``end``, in few large chunks, by the call's own shape
+    and by whether it is differentiated (:func:`held_places_made`):
 
-    def block(i):
-        lo = i * n
-        live = lo + jnp.arange(n, dtype=jnp.int32) < end
-        mine = jax.lax.dynamic_slice_in_dim(order, lo, n)
-        token = mine // (A // N)
-        with jax.named_scope("moe.dispatch"):
-            xs = jnp.where(live[:, None], hf[token], 0)
-        with jax.named_scope("moe.experts"):
-            ys = _expert_ffn(xs, *weights,
-                             jnp.diff(jnp.clip(edges - lo, 0, n)), layer,
-                             act)
-        with jax.named_scope("moe.combine"):
-            ys = jnp.where(live, flat_w[mine], 0.0)[:, None] * jnp.where(
-                live[:, None], ys, 0).astype(jnp.float32)
-            return jnp.zeros((N, d), jnp.float32).at[token].add(ys)
+    - ``A <= held_chunk(..)`` (every decode call: 8-12 places): one straight
+      block, a row for every place (:func:`_held_blocks`);
+    - a larger FORWARD call (a prefill program, ``forward`` / ``loss_fn``):
+      :func:`_held_chunks`' ONE loop of ``ceil(end / chunk)`` chunks of the
+      EVEN share of the places and a margin: a balanced router's load is
+      one chunk with a short dead tail, a heavier one more chunks, none can
+      outrun it, and a program holds one expert kernel a routed block;
+    - a larger DIFFERENTIATED call (the trainer's step): fixed blocks, the
+      first always made, a further one under a ``cond``
+      (:func:`_held_chunks_jvp`): a loop of traced length has no transpose.
 
-    y = block(0)
-    for i in range(1, blocks):  # behind the first: only if places fall in it
-        y = y + jax.lax.cond(i * n < end, jax.checkpoint(partial(block, i)),
-                             lambda: jnp.zeros((N, d), jnp.float32))
-    return y
+    Whichever runs: float32 rows times float32 weights added in float32
+    onto ``[N, d]``, every live assignment's row whatever the routing, and
+    nothing from a dead place. On the TPU the grouped product leaves the
+    rows of no group UNWRITTEN (whatever the buffer held): places from
+    ``end`` on are set to zero going in and coming out, and so are,
+    transposed, their cotangents (:func:`_place_rows`).
+
+    These functions stand at the END of this file but for this one, which
+    keeps the lines it had: a Mosaic kernel's recorded frames are part of
+    its program's text, and the all-here path's (``routed_mlp`` ->
+    :func:`_expert_ffn`) must not move for a change of this path."""
+    if order.shape[0] <= held_chunk(order.shape[0], starts.shape[0], experts):
+        return _held_blocks(hf, top_w, order, starts, end, weights, 1,
+                            layer, act)
+    return _held_chunks(act, experts, hf, top_w, order, starts, end, weights,
+                        None if layer is None else jnp.asarray(layer,
+                                                               jnp.int32))
 
 
 def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
@@ -516,9 +516,9 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
       from ``first`` on of the router's ``E`` (``w_up`` [count, d, f]):
       router and ``top_k`` run over all ``E``; assignments to experts held
       elsewhere sort behind the held experts' and get no row, no product
-      and no part in the sum (:func:`_held_rows`); ``y`` is the partial
-      sum of the held experts (plus the shared one). With ``held=None``
-      every expert is here.
+      and no part in the sum (:func:`_held_rows`: rows up to the held
+      experts' last place); ``y`` is the partial sum of the held experts
+      (plus the shared one). With ``held=None`` every expert is here.
     - ``zero_experts = n``: the LAST ``n`` of the router's ``E`` outputs
       are identity experts, which have no weights and run where the token
       is: an assignment to one adds ``w_j h`` and gets no row, no place
@@ -598,13 +598,9 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
             ys = _expert_ffn(xs, w_gate, w_up, w_down, counts, layer, act)
         with jax.named_scope("moe.combine"):
             y = _combine(ys, top_w, order, inverse)
-    else:  # a block of places is four even shares of the assignments (two
-        # where four would be all of them: a quarter of the experts held),
-        # and at least 128 places (a decode call's few are one block)
+    else:
         y = _held_rows(hf, top_w, order, starts, end, (w_gate, w_up, w_down),
-                       max(1, min(max(E // (4 * count),
-                                      min(2, E // (2 * count))), A // 128)),
-                       layer, act)
+                       E, layer, act)
     if zero_experts:
         with jax.named_scope("moe.zero"):
             to_zero = top_e >= E - zero_experts
@@ -645,3 +641,121 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
         if zero_experts:
             stats["zero_share"] = jnp.mean(to_zero.astype(jnp.float32))
     return y.reshape(*lead, d), stats
+
+
+# --------------------------------------------------------------------------- #
+# The held path's forms (:func:`_held_rows` says which runs when)
+# --------------------------------------------------------------------------- #
+
+
+def held_chunk(places: int, count: int, experts: int) -> int:
+    """The sorted places a chunk of :func:`_held_chunks`' loop (and the most
+    a straight block): the EVEN share of the call's ``places`` that ``count``
+    held experts of the router's ``experts`` get, and one part in
+    ``sqrt(count)`` more (what the sum of ``count`` experts' uneven loads
+    strays by: a quarter over 16 experts, a twelfth over 128), in whole row
+    tiles. A function of the call's shapes and no knob: a chunk pays a
+    scatter-add call (1-2 ms on a v5e, some 15,000 dead places' worth), so
+    the common load should be ONE chunk and its dead tail the margin."""
+    even = places * count / experts
+    return math.ceil(even * (1 + count ** -0.5) / HELD_TILE) * HELD_TILE
+
+
+def held_places_made(places: int, live, count: int, experts: int) -> int:
+    """The places :func:`_held_rows` makes a row for (gathers, multiplies
+    and adds onto its token) in a FORWARD call over ``places`` sorted places
+    of which the first ``live`` fell on the ``count`` held of ``experts``
+    experts: all of them where the call is one straight block, else whole
+    chunks up to ``live``."""
+    c = held_chunk(places, count, experts)
+    return places if places <= c else -(-int(live) // c) * c
+
+
+def _place_rows(hf, flat_w, order, edges, end, weights, layer, act, lo, n):
+    """``(token, rows)`` of the ``n`` sorted places from ``lo`` on: each
+    place's token, and its expert's output times its weight, float32
+    ``[n, d]``, zero from place ``end`` on. ``edges`` [count + 1]: each held
+    expert's first place, then ``end``; a group the range cuts is handed
+    over with the rows it has inside."""
+    live = lo + jnp.arange(n, dtype=jnp.int32) < end
+    mine = jax.lax.dynamic_slice_in_dim(order, lo, n)
+    token = mine // (flat_w.shape[0] // hf.shape[0])
+    with jax.named_scope("moe.dispatch"):
+        xs = jnp.where(live[:, None], hf[token], 0)
+    with jax.named_scope("moe.experts"):
+        ys = _expert_ffn(xs, *weights,
+                         jnp.diff(jnp.clip(edges - lo, 0, n)), layer, act)
+    with jax.named_scope("moe.combine"):
+        return token, jnp.where(live, flat_w[mine], 0.0)[:, None] * jnp.where(
+            live[:, None], ys, 0).astype(jnp.float32)
+
+
+def _held_blocks(hf, top_w, order, starts, end, weights, blocks, layer, act):
+    """:func:`_held_rows` over ``blocks`` fixed blocks of ``A / blocks``
+    places, in operations that have a transpose. The first block's rows are
+    always made; a further block's only in a step whose router sends places
+    into it (recomputed in the backward: it is the rare step), so no
+    assignment is ever left out whatever the routing, and a common step
+    makes ``A / blocks`` rows, live or dead."""
+    (N, d), A = hf.shape, order.shape[0]
+    if A % blocks:
+        blocks = 1
+    n = A // blocks
+    edges = jnp.append(starts, end).astype(jnp.int32)
+    flat_w = top_w.reshape(A)
+
+    def block(i):
+        token, ys = _place_rows(hf, flat_w, order, edges, end, weights,
+                                layer, act, i * n, n)
+        with jax.named_scope("moe.combine"):
+            return jnp.zeros((N, d), jnp.float32).at[token].add(ys)
+
+    y = block(0)
+    for i in range(1, blocks):  # behind the first: only if places fall in it
+        y = y + jax.lax.cond(i * n < end, jax.checkpoint(partial(block, i)),
+                             lambda: jnp.zeros((N, d), jnp.float32))
+    return y
+
+
+@partial(jax.custom_jvp, nondiff_argnums=(0, 1))
+def _held_chunks(act, experts, hf, top_w, order, starts, end, weights, layer):
+    """:func:`_held_rows` as ONE loop over chunks of :func:`held_chunk`
+    sorted places whose trip count is ``ceil(end / chunk)``: a chunk's
+    rows gathered, through :func:`_expert_ffn` with the groups' counts
+    clipped to the chunk (a group a chunk's edge cuts is one more cut
+    tile), weighted and scatter-added in float32 INTO THE CARRIED sum. Only
+    the last chunk has dead places."""
+    (N, d), A = hf.shape, order.shape[0]
+    c = held_chunk(A, starts.shape[0], experts)
+    edges = jnp.append(starts, end).astype(jnp.int32)
+    flat_w = top_w.reshape(A)
+    order = jnp.pad(order, (0, -A % c))  # a last chunk's places behind ``A``
+
+    def chunk(i, y):
+        token, ys = _place_rows(hf, flat_w, order, edges, end, weights,
+                                layer, act, i * c, c)
+        with jax.named_scope("moe.combine"):
+            return y.at[token].add(ys)
+
+    with jax.named_scope("moe.combine"):
+        y = jnp.zeros((N, d), jnp.float32)
+    return jax.lax.fori_loop(0, (end + (c - 1)) // c, chunk, y)
+
+
+@_held_chunks.defjvp
+def _held_chunks_jvp(act, experts, primals, tangents):
+    """A differentiated :func:`_held_chunks` is :func:`_held_blocks`, traced
+    where the call stands, and the loop is in no such program at all (a
+    ``custom_jvp`` and not a ``custom_vjp``: a block's transposes then add
+    to the cotangents one by one in the order they always did, and the
+    trainer's step lowers to the text it had). A block is four even shares
+    of the ``experts`` experts' assignments (two where four would be all of
+    them: a quarter of the experts held) and at least 128 places."""
+    hf, top_w, order, starts, end, weights, layer = primals
+    A, count = order.shape[0], starts.shape[0]
+    blocks = max(1, min(max(experts // (4 * count),
+                            min(2, experts // (2 * count))), A // 128))
+    return jax.jvp(
+        lambda hf, top_w, weights: _held_blocks(
+            hf, top_w, order, starts, end, weights, blocks, layer, act),
+        (hf, top_w, weights), (tangents[0], tangents[1], tangents[5]))

@@ -384,27 +384,17 @@ def test_delta_rule_kernel_compiles_at_the_qwen3next_cells_shapes(
     assert "%gdn_prefill" in text and "while(" not in text
 
 
-@pytest.mark.parametrize("program,pages", [
-    ("prefill", 3), ("prefill", 15), ("prefill", 16), ("decode", 4),
-    ("decode", 16)])
-def test_qwen3next_cells_programs_compile_and_fit_the_chip(
-        program, pages, one_chip, as_on_the_chip):
-    """The delta-rule family's WHOLE prefill and decode programs at the
-    cell's published widths, its stores and its shortest and longest page
-    tables, from shapes alone: beside 9.7 GB of weights and stores the
-    program must stay inside the chip. Prefill holds the delta rule's
-    kernel (one a ``D`` layer of the scanned period: no triangular solve,
-    no ``[.., 64, 64]`` float32 arrays of a whole prompt), the flash kernel
-    at head width 256 and the experts' grouped kernel; its temporaries are
-    1.11 / 3.95 / 4.19 GB at 3 / 15 / 16 pages (with the chunked form in
-    XLA 1.60 / 6.64 / 4.53: 15 pages went in ONE piece; PERF.md, PR 46).
-    Decode holds none of the three."""
+def _cell_program(cell, program, pages, one_chip):
+    """``(lowered, stores)``: a serving cell's ``program`` (``"prefill"`` /
+    ``"decode"``) at ``pages`` pages, at the cell's published widths, its
+    deployment's stores and page size, from shapes alone and lowered for
+    the described chip as the engine jits it."""
     from functools import partial
 
     from benchmarks.lib import spec
     from ray_tpu.models import llama
 
-    file = spec.cell_bundle("serve-qwen3next-prefill-open")["config"]
+    file = spec.cell_bundle(cell)["config"]
     cfg, dep = spec.program_config(file), file["deployment"]
     ps, n_pages = dep["page_size"], dep["n_pages"]
 
@@ -426,8 +416,33 @@ def test_qwen3next_cells_programs_compile_and_fit_the_chip(
                                               i32())
     else:
         fn, args = llama.decode_step_with_cache, (i32(1), i32(), i32(pages))
-    compiled = jax.jit(partial(fn, cfg), donate_argnums=tuple(
-        range(1, 1 + len(layout)))).lower(params, *stores, *args).compile()
+    return jax.jit(partial(fn, cfg), donate_argnums=tuple(
+        range(1, 1 + len(layout)))).lower(params, *stores, *args), stores
+
+
+@pytest.mark.parametrize("program,pages", [
+    ("prefill", 3), ("prefill", 15), ("prefill", 16), ("decode", 4),
+    ("decode", 16)])
+def test_qwen3next_cells_programs_compile_and_fit_the_chip(
+        program, pages, one_chip, as_on_the_chip):
+    """The delta-rule family's WHOLE prefill and decode programs at the
+    cell's published widths, its stores and its shortest and longest page
+    tables, from shapes alone: beside 9.7 GB of weights and stores the
+    program must stay inside the chip. Prefill holds the delta rule's
+    kernel (one a ``D`` layer of the scanned period: no triangular solve,
+    no ``[.., 64, 64]`` float32 arrays of a whole prompt), the flash kernel
+    at head width 256 and the experts' grouped kernel, ONE a routed block
+    inside the held path's loop over chunks (a period's four; the block
+    form held two a routed block and the rows of a whole block of places in
+    and out); its temporaries are 0.89 / 2.96 / 3.12 GB at 3 / 15 / 16 pages
+    (with the block form 1.11 / 3.95 / 4.19; PERF.md, PR 47). Decode holds
+    none of the three and no loop over places: a call's ten places are one
+    straight block."""
+    import re
+
+    lowered, stores = _cell_program("serve-qwen3next-prefill-open", program,
+                                    pages, one_chip)
+    compiled = lowered.compile()
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes
     assert 9.7e9 < held < 9.8e9
@@ -443,5 +458,26 @@ def test_qwen3next_cells_programs_compile_and_fit_the_chip(
         # the period DDDA is one scanned body: three calls of the kernel
         assert text.count("%gdn_prefill") % 3 == 0
         assert "triangular" not in text.lower()
-        assert memory.temp_size_in_bytes < {3: 1.2e9, 15: 4.0e9,
-                                            16: 4.3e9}[pages]
+        assert len(re.findall(r"%moe_ffn[.\d]* = ", text)) == 4
+        assert memory.temp_size_in_bytes < {3: 1.0e9, 15: 3.1e9,
+                                            16: 3.3e9}[pages]
+    else:
+        assert "moe.combine/while" not in text
+
+
+def test_longcats_prefill_holds_one_grouped_kernel_a_routed_block(
+        one_chip, as_on_the_chip):
+    """LongCat's 16-page prefill (98,304 places a routed block, some 2,048
+    of them live), lowered for the chip: the held path is one loop with ONE
+    call of the grouped kernel in its body, where the block form held twelve
+    a routed block, eleven of them under a ``cond`` (what its ``compile_s``
+    grew by; PERF.md, PRs 43 and 47). All-``S``: the stack is one scanned
+    body, so one call in the whole text."""
+    import re
+
+    lowered, _ = _cell_program("serve-longcatflash-prefill-open", "prefill",
+                               16, one_chip)
+    text = lowered.as_text()
+    assert len(re.findall(r"call @grouped_ffn", text)) == 1
+    assert 'kernel_name = "moe_ffn"' in text
+    assert "stablehlo.case" not in text
