@@ -13,9 +13,11 @@ model definition runs dp/fsdp/tp/sp via GSPMD. Design choices for the MXU:
   in the others (:func:`window_block`) or reads the keys a learned indexer
   picks (:func:`index_block`), or whose mixer is the gated delta rule in
   some layers (:func:`delta_block`: a STATE a sequence, no row a position)
-  and gated full attention in the others (:func:`gated_block`), weights
-  stacked per kind and walked in the pattern's order (:func:`pattern_layer`,
-  :func:`pattern_stack`);
+  and gated full attention in the others (:func:`gated_block`), or of
+  latent-attention blocks outside the double layer, TRAINED through the
+  flash kernel (:func:`latent_block`; a prediction module behind them:
+  :func:`add_mtp_loss`), weights stacked per kind and walked in the
+  pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
 - bf16 matmuls with fp32 accumulation (``preferred_element_type``), params
   stored fp32, gradients/optimizer fp32;
 - ``jax.checkpoint`` per layer (remat) to trade FLOPs for HBM, its products
@@ -187,10 +189,15 @@ def keep_policy(keep):
 
 
 # a patterned stack's layer kinds: the character -> the name of the kind's
-# stacked weights under params["layers"]
+# stacked weights under params["layers"]. "L" / "G" (LATENT_KINDS): latent
+# attention THEN the routed MLP ("L") or a dense SwiGLU ("G"). They are
+# TRAINED kinds: the train steps attend them with the flash kernel over
+# per-head keys and values at ONE width (forward, dQ and dK/dV are Pallas
+# calls), NOT with prefill's kernel, which is forward only and whose
+# transpose is the XLA tile loop; the decode engine refuses them by name
 LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
                "F": "block", "W": "block", "I": "index", "D": "delta",
-               "A": "gated"}
+               "A": "gated", "L": "latent", "G": "latent_dense"}
 # the kinds that are a WHOLE block (attention THEN the routed MLP, whose
 # router reads the attention's normed input; window_block): "F" attends over
 # every earlier position without rotation, "W" over the last cfg.window with
@@ -200,6 +207,9 @@ BLOCK_KINDS = "FW"
 # of its own; "D" mixes through the gated delta rule, "A" through gated full
 # attention. A stack EACH (their mixers' leaves differ)
 DELTA_KINDS = "DA"
+# the latent-attention blocks outside "S" (latent_block, at this file's end):
+# whole blocks, a stack each (their MLPs' leaves differ)
+LATENT_KINDS = "LG"
 # Where their matrices start off the square root of their fan-in (seeded random
 # weights; a checkpoint brings its own): ``wo`` 12 times as wide, the router
 # 6 times, so that BOTH halves carry the logits and a comparison of logits
@@ -323,7 +333,12 @@ class LlamaConfig:
     # attention's input. "I": a whole block too (index_block): rotated
     # attention with per-head QK-norm over the index_topk keys a learned
     # indexer picks for each query, then the routed MLP (its router reads
-    # the MLP's own input; a held range of experts may be set). Empty:
+    # the MLP's own input; a held range of experts may be set). "G" / "L":
+    # a whole block of latent attention (the "S" layer's, each sqrt(dim /
+    # rank) scale only where mla_scale_* sets it) then a dense SwiGLU of
+    # dense_mlp_dim ("G": a stack's leading layers) or the routed MLP with
+    # an ungated shared SwiGLU expert ("L"); TRAINED through the flash
+    # kernel (latent_block), not served. Empty:
     # every layer is the block (attention THEN MLP), as every dense and
     # every all-routed configuration has it.
     layer_pattern: str = ""
@@ -394,6 +409,25 @@ class LlamaConfig:
     lin_conv: int = 4
     lin_chunk: int = 64
     partial_rotary_factor: float = 1.0
+    # latent attention's two published corrections for matrices that all
+    # start at one variance: the query's normed bottleneck times sqrt(dim /
+    # q_lora_rank), the normed latent row times sqrt(dim / kv_lora_rank). A
+    # published config of this kind says each by a key of its own; the
+    # default is the first such model's, which sets both
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
+    # the "L" / "G" layers (latent_block): latent attention as the "S"
+    # layer's (the ranks and head widths above; the score's width, nope +
+    # rope, has to be the value's: the trainer's flash kernel attends q, k
+    # and v of ONE width), then the routed MLP with ONE shared SwiGLU expert
+    # of width shared_mlp_dim and no gate in front of it ("L") or a dense
+    # SwiGLU of width dense_mlp_dim ("G"). mtp_layers: multi-token-prediction
+    # modules behind the stack (0 or 1; add_mtp_loss): one more "L" block
+    # that reads [RMSNorm(Emb(t_{i+1})) ; RMSNorm(h_i)] W_eh and predicts
+    # t_{i+2} through the model's own embedding and head; its loss is added
+    # mtp_loss_weight times
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
 
     def __post_init__(self):
         object.__setattr__(self, "mrope_section", tuple(self.mrope_section))
@@ -473,6 +507,33 @@ class LlamaConfig:
                 "qk_norm, identity or shared expert, or weight scale")
         if self.mrope_section and "I" not in self.kinds:
             raise ValueError("only an 'I' layer reads mrope_section")
+        latent = set(self.kinds) & set(LATENT_KINDS)
+        if latent and not (
+                set(self.kinds) <= set(LATENT_KINDS)
+                and self.q_lora_rank and self.kv_lora_rank
+                and self.qk_rope_head_dim % 2 == 0
+                and self.qk_nope_head_dim + self.qk_rope_head_dim
+                == self.v_head_dim > 0
+                and ("G" not in latent or self.dense_mlp_dim)
+                and ("L" not in latent or (
+                    self.num_experts and self.experts_per_token))
+                and self.mlp_act == "swiglu" and self.rope
+                and not self.qk_norm and not self.zero_experts):
+            raise ValueError(
+                "an 'L' / 'G' layer is latent attention (q_lora_rank, "
+                "kv_lora_rank, qk_nope_head_dim + qk_rope_head_dim = "
+                "v_head_dim: the flash kernel attends q, k and v of one "
+                "width) then a routed SwiGLU MLP ('L': num_experts, "
+                "experts_per_token; an ungated shared expert, "
+                "shared_mlp_dim, and a held range may be set) or a dense "
+                "one ('G': dense_mlp_dim): every built layer is one of the "
+                "two, and it has no QK-norm and no identity expert")
+        if self.mtp_layers not in (0, 1) or (
+                self.mtp_layers and "L" not in latent):
+            raise ValueError(
+                f"mtp_layers={self.mtp_layers}: one multi-token-prediction "
+                "module or none, and its block is an 'L' layer's: the stack "
+                "has to have one")
         blocks = set(self.kinds) & set(BLOCK_KINDS)
         if ("W" in self.kinds) != bool(self.window) or self.window < 0:
             raise ValueError(
@@ -514,7 +575,7 @@ class LlamaConfig:
     @property
     def rope_layout(self):
         """1 a layer of the whole pattern whose attention rotates."""
-        return [int(kind in "WSIA" or (kind == "*" and self.rope))
+        return [int(kind in "WSIALG" or (kind == "*" and self.rope))
                 for kind in self.layer_pattern]
 
     @property
@@ -618,7 +679,7 @@ class LlamaConfig:
                 + self.lin_value_dim + vd * d + routed)
             per_kind["A"] = (2 * d * q + 2 * d * kv + q * d
                              + 2 * self.head_dim + routed)
-            if "S" in self.kinds:
+            if set(self.kinds) & set("S" + LATENT_KINDS):
                 rq, rkv, h = self.q_lora_rank, self.kv_lora_rank, self.n_heads
                 qk = self.qk_nope_head_dim + self.qk_rope_head_dim
                 mla = (d * rq + rq + rq * h * qk + d * self.latent_row + rkv
@@ -629,6 +690,12 @@ class LlamaConfig:
                 per_kind["S"] = (
                     2 * mla + 2 * 3 * d * self.dense_mlp_dim + wide * (d + 1)
                     + self.num_experts * 3 * d * self.mlp_dim + 4 * d)
+                per_kind["G"] = mla + 3 * d * self.dense_mlp_dim + 2 * d
+                per_kind["L"] = (
+                    mla + wide * (d + 1) + 3 * d * self.shared_mlp_dim
+                    + self.num_experts * 3 * d * self.mlp_dim + 2 * d)
+                # a prediction module: its block, W_eh and three norms
+                emb += self.mtp_layers * (per_kind["L"] + 2 * d * d + 3 * d)
             return emb + sum(per_kind[k] for k in self.kinds) + d
         attn = d * q + 2 * d * kv + q * d
         if self.qk_norm:
@@ -743,6 +810,22 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         if cfg.shared_mlp_dim:
             kinds["moe"]["shared_up"] = ("layers", "embed", "mlp")
             kinds["moe"]["shared_down"] = ("layers", "mlp", "embed")
+        # the latent blocks': ONE latent attention's leaves (a sublayer of
+        # the double layer's, unstacked), then the dense block's MLP or the
+        # routed one's with its choice bias and an ungated shared expert
+        mla = {w: ("layers",) + ax[2:] for w, ax in kinds["scmoe"].items()
+               if w in ("attn_norm", "mlp_norm", "wq_a", "q_norm", "wq_b",
+                        "wkv_a", "kv_norm", "wkv_b", "wo")}
+        kinds["latent_dense"] = dict(
+            mla, w_gate=("layers", "embed", "mlp"),
+            w_up=("layers", "embed", "mlp"), w_down=("layers", "mlp", "embed"))
+        kinds["latent"] = dict(
+            mla, **{w: kinds["scmoe"][w] for w in (
+                "router", "router_bias", "w_gate", "w_up", "w_down")})
+        if cfg.shared_mlp_dim:
+            kinds["latent"].update(shared_gate=("layers", "embed", "mlp"),
+                                   shared_up=("layers", "embed", "mlp"),
+                                   shared_down=("layers", "mlp", "embed"))
         out = {
             "embedding": ("vocab", "embed"),
             "layers": {LAYER_KINDS[k]: kinds[LAYER_KINDS[k]]
@@ -751,6 +834,10 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         }
         if not cfg.tie_embeddings:
             out["lm_head"] = ("embed", "vocab")
+        if cfg.mtp_layers:  # embedding and head are the model's own
+            out["mtp"] = {"enorm": (None,), "hnorm": (None,),
+                          "eh_proj": (None, "embed"), "final_norm": (None,),
+                          "layers": {"latent": kinds["latent"]}}
         return out
     layer = {
         "wq": ("layers", "embed", "heads"),
@@ -909,6 +996,9 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
     for c in DELTA_KINDS:
         if n[c]:
             out[LAYER_KINDS[c]] = _init_delta_family(cfg, c, n[c], key)
+    for c in LATENT_KINDS:
+        if n[c]:
+            out[LAYER_KINDS[c]] = _init_latent_kind(cfg, c, n[c], key)
     return out
 
 
@@ -976,6 +1066,8 @@ def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
                                  else jnp.ones)((d,), jnp.float32)}
         if not cfg.tie_embeddings:
             params["lm_head"] = dense(k_head, (d, cfg.vocab_size), d)
+        if cfg.mtp_layers:
+            params["mtp"] = _init_mtp(cfg, key)
         return params
     k = iter(jax.random.split(key, 16))
 
@@ -1103,15 +1195,26 @@ def _mlp_half(cfg: LlamaConfig, p, h, stat_axes=(), layer=None,
             choice_bias=p.get("router_bias"), scale=cfg.routed_scale,
             held=((cfg.first_expert, cfg.num_experts)
                   if wide != cfg.num_experts else None),
+            # the shared expert has its experts' form: two matrices, or a
+            # SwiGLU's three with no gate in front of it
             shared=((p["shared_up"], p["shared_down"])
-                    if cfg.shared_mlp_dim else None),
+                    if cfg.shared_mlp_dim and "shared_gate" not in p
+                    else None),
+            shared_gated=((None, p["shared_gate"], p["shared_up"],
+                           p["shared_down"]) if "shared_gate" in p else None),
             zero_experts=cfg.zero_experts, layer=layer,
             router_input=router_in, act=cfg.mlp_act)
         return y.astype(cd), stats
+    return _dense_mlp(cfg, p, h), {}
+
+
+def _dense_mlp(cfg: LlamaConfig, p, h):
+    """The dense SwiGLU on ``h`` [B, T, dim] (normed, cfg.dtype)."""
+    cd = cfg.dtype
     # the two products may be kept (KEEP_GROUPS); silu is made again
     g = jax.nn.silu(checkpoint_name(h @ p["w_gate"].astype(cd), "mlp"))
     u = checkpoint_name(h @ p["w_up"].astype(cd), "mlp")
-    return (g * u) @ p["w_down"].astype(cd), {}
+    return (g * u) @ p["w_down"].astype(cd)
 
 
 def add_router_losses(cfg: LlamaConfig, nll, stats):
@@ -1202,9 +1305,11 @@ def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
 
     ``q = RMSNorm(h wq_a) wq_b`` times ``sqrt(dim / q_lora_rank)``, a head
     ``[nope | rope]``; ``h wkv_a = [c | kr]``, ``c = RMSNorm(c) *
-    sqrt(dim / kv_lora_rank)`` (both scales always: the one published model
-    of this kind sets both); RoPE over pairs ``(2i, 2i + 1)`` of ``q``'s
-    rope slice and of ``kr``, the ONE rotated key slice all heads share.
+    sqrt(dim / kv_lora_rank)`` (each scale where the config sets it,
+    ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: the first published model
+    of this kind sets both, the second neither); RoPE over pairs ``(2i,
+    2i + 1)`` of ``q``'s rope slice and of ``kr``, the ONE rotated key slice
+    all heads share.
     ``attend(q, latent, wkv_b)`` -> ``[B, T, heads, v_head_dim]`` decides
     whether ``c wkv_b = [k_nope | v]`` a head is ever made (prefill) or
     absorbed into the query and the output (decode). Returns ``(y,
@@ -1214,12 +1319,16 @@ def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
     B, T, _ = h.shape
     H, r, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
     with jax.named_scope("mla.project"):
-        # a norm's weight times the scale: one rounding, not two
-        qa = rms_norm(h @ p["wq_a"].astype(cd),
-                      p["q_norm"] * math.sqrt(d / cfg.q_lora_rank), eps)
+        def gain(w, scaled, rank):
+            # a norm's weight times the scale: one rounding, not two
+            return w * math.sqrt(d / rank) if scaled else w
+
+        qa = rms_norm(h @ p["wq_a"].astype(cd), gain(
+            p["q_norm"], cfg.mla_scale_q_lora, cfg.q_lora_rank), eps)
         q = (qa @ p["wq_b"].astype(cd)).reshape(B, T, H, -1)
         ckr = h @ p["wkv_a"].astype(cd)
-        c = rms_norm(ckr[..., :r], p["kv_norm"] * math.sqrt(d / r), eps)
+        c = rms_norm(ckr[..., :r], gain(
+            p["kv_norm"], cfg.mla_scale_kv_lora, r), eps)
         q_rope, kr = rotary_embedding(
             q[..., dn:], ckr[:, :, None, r:], positions, cfg.rope_theta,
             interleaved=True)
@@ -2042,7 +2151,11 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     ``"W"``, ``"I"``) is that row's block over that row's attention of the
     call's own positions, whatever ``attend`` is: the trainer's flash kernel
     has no window and selects nothing (prefill's forward-only kernels on a
-    TPU backend, XLA tiles elsewhere). Every other kind is a half of the
+    TPU backend, XLA tiles elsewhere). A latent block (``"L"`` / ``"G"``,
+    :func:`latent_block`) is a whole block over the CALLER's ``attend``: a
+    train step's flash kernel, so that its backward is the kernels' own and
+    not the tile loop's transpose that prefill's forward-only kernel would
+    leave it. Every other kind is a half of the
     block: ``x + f(RMSNorm(x))`` with ``f`` the Mamba-2 mixer (``"M"``,
     :func:`ray_tpu.ops.ssm.mamba2_mixer`), the routed
     feed-forward (``"E"``, :func:`_mlp_half`) or attention (``"*"``,
@@ -2054,6 +2167,8 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
             cfg, x, jax.tree.map(lambda a: a[None], p), 0,
             positions_of(*x.shape[:2]),
             partial(SERVED[kind].prefill, cfg, None), stat_axes)[:2]
+    if kind in LATENT_KINDS:  # a whole block too, over the CALLER's attend
+        return latent_block(cfg, kind, attend, x, p, stat_axes)
     h = rms_norm(x, p["norm"], cfg.norm_eps).astype(cfg.dtype)
     stats = {}
     if kind == "M":
@@ -2070,17 +2185,19 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
 
 
 def pattern_stack(cfg: LlamaConfig, x, layers, attend, stat_axes=(),
-                  policy=None):
+                  policy=None, kinds=None):
     """The residual stream through a patterned stack in the pattern's
     order: layer ``i`` of its kind reads row ``i`` of that kind's stacked
     weights ``layers[kind name]`` (kinds that share a stack count
     together); every layer is rematerialised where
     ``cfg.remat``, but for what ``policy`` keeps (:func:`keep_policy`; only
-    an attention layer has names). Returns ``(x, stats)``, the routed
-    layers' stats stacked ``[n_E]`` a leaf (``{}`` with no routed layer)."""
+    an attention layer and a latent block have names). ``kinds``: a stack
+    that is not the config's own (a prediction module's blocks). Returns
+    ``(x, stats)``, the routed layers' stats stacked ``[n_E]`` a leaf
+    (``{}`` with no routed layer)."""
     met = dict.fromkeys(LAYER_KINDS.values(), 0)
     stats = []
-    for kind in cfg.kinds:
+    for kind in cfg.kinds if kinds is None else kinds:
         fn = partial(pattern_layer, cfg, kind, attend, stat_axes=stat_axes)
         if cfg.remat:
             fn = jax.checkpoint(fn, policy=policy)
@@ -2222,7 +2339,8 @@ def _plain_chunk_nll(cfg: LlamaConfig, head):
     return chunk_nll
 
 
-def chunked_nll_mean(cfg: LlamaConfig, x, targets, chunk_nll, policy=None):
+def chunked_nll_mean(cfg: LlamaConfig, x, targets, chunk_nll, policy=None,
+                     live=None):
     """Mean NLL with the lm_head matmul + softmax CHUNKED over the
     sequence under ``jax.checkpoint``: fp32 logits exist only per-chunk
     ([B, C, vocab] instead of [B, T, vocab] — the round-1 OOM at batch
@@ -2234,16 +2352,29 @@ def chunked_nll_mean(cfg: LlamaConfig, x, targets, chunk_nll, policy=None):
     at half the float32 logits' size, and only its conversion and the
     softmax are made again. ``chunk_nll(x_c, t_c) -> [B, C]`` supplies
     the head — full-width (:func:`_plain_chunk_nll`) or vocab-parallel
-    (:func:`vp_chunk_nll`)."""
+    (:func:`vp_chunk_nll`). ``live``: only the first ``live`` positions of
+    a row count; the others add nothing and are left out of the mean (a
+    prediction module's last position has no target). None: all count."""
     B, T, d = x.shape
     C = cfg.loss_chunk
+    count = B * T
+    if live is not None:  # a position's target with whether it counts
+        head_nll, count = chunk_nll, B * live
+        targets = jnp.stack([targets, jnp.broadcast_to(
+            jnp.arange(T) < live, (B, T)).astype(targets.dtype)], axis=-1)
+
+        def chunk_nll(x_c, t_c):
+            return head_nll(x_c, t_c[..., 0]) * t_c[..., 1]
 
     if not C or T <= C:
-        return chunk_nll(x, targets).mean()
+        if live is None:
+            return chunk_nll(x, targets).mean()
+        return chunk_nll(x, targets).sum() / count
 
     n, rem = divmod(T, C)
     xs = jnp.swapaxes(x[:, :n * C].reshape(B, n, C, d), 0, 1)     # [n,B,C,d]
-    ts = jnp.swapaxes(targets[:, :n * C].reshape(B, n, C), 0, 1)  # [n,B,C]
+    ts = jnp.swapaxes(targets[:, :n * C].reshape(
+        B, n, C, *targets.shape[2:]), 0, 1)                       # [n,B,C]
 
     def body(total, chunk):
         x_c, t_c = chunk
@@ -2253,18 +2384,28 @@ def chunked_nll_mean(cfg: LlamaConfig, x, targets, chunk_nll, policy=None):
                             jnp.zeros((), jnp.float32), (xs, ts))
     if rem:
         total = total + chunk_nll(x[:, n * C:], targets[:, n * C:]).sum()
-    return total / (B * T)
+    return total / count
 
 
 def loss_parts(cfg: LlamaConfig, params, tokens, mesh=None):
     """``(total, report)``: what is trained on, and for a routed model the
     router's scalars apart (:func:`add_router_losses`; the cross-entropy
-    is ``total`` less the weighted two). tokens [B, T+1]."""
+    is ``total`` less the weighted two); with a prediction module
+    (``cfg.mtp_layers``) the cross-entropy is the main one plus the
+    module's weighted, and the report has both apart (:func:`add_mtp_loss`,
+    the ONE place that builds it, for ``make_spmd_train_step`` too).
+    tokens [B, T+1]."""
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     x, stats = _backbone(cfg, params, inputs, mesh)
-    nll = chunked_nll_mean(cfg, x, targets,
-                           _plain_chunk_nll(cfg, _head(cfg, params)))
-    return add_router_losses(cfg, nll, stats)
+    chunk_nll = _plain_chunk_nll(cfg, _head(cfg, params))
+    nll = chunked_nll_mean(cfg, x, targets, chunk_nll)
+    nll, stats, report = add_mtp_loss(
+        cfg, params.get("mtp"), x, tokens, nll, stats,
+        embed=lambda t: embed_tokens(cfg, params, t, mesh),
+        attend=lambda q, k, v: _attention(cfg, q, k, v, mesh),
+        chunk_nll=chunk_nll)
+    total, router = add_router_losses(cfg, nll, stats)
+    return total, {**router, **report}
 
 
 def loss_fn(cfg: LlamaConfig, params, tokens, mesh=None):
@@ -3082,6 +3223,11 @@ class LlamaDecodeEngine:
         from ray_tpu.serve.kv_cache import PagePool, PrefixCache
 
         self.cfg = cfg or LlamaConfig.debug()
+        _no_latent_kinds(
+            self.cfg, "LlamaDecodeEngine",
+            "serving them is not built: a latent layer outside 'S' has no "
+            "row in SERVED, and a prediction module's self-drafted decode "
+            "steps need a scheduler that takes more than one token a call")
         families: Dict[str, str] = {}  # the table's kinds, by family
         for c, kind in SERVED.items():
             families[kind.family] = families.get(kind.family, "") + c
@@ -3544,6 +3690,11 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
 
     if "pipe" not in mesh.axis_names:
         raise ValueError("mesh has no 'pipe' axis")
+    _no_latent_kinds(
+        cfg, "make_pipeline_train_step",
+        "its stages run the dense block alone, and the prediction module's "
+        "second loss needs the last stage's stream AND the first stage's "
+        "embedding")
     _no_window_kinds(
         cfg, "make_pipeline_train_step",
         "its stages run the dense block over the flash kernel, which has "
@@ -3827,3 +3978,178 @@ def _note_assignments(shares, places: int, cfg) -> None:
         made = held_places_made(places, live, cfg.num_experts, wide)
         for state, n in (("live", live), ("made", made)):
             _g_moe_places.set(float(n), tags={"state": state})
+
+
+# --------------------------------------------------------------------------- #
+# Latent blocks outside "S" (kinds "L" and "G") and the prediction module:
+# TRAINED kinds. At this file's end for attend_delta's reason
+# --------------------------------------------------------------------------- #
+
+
+def _no_latent_kinds(cfg: LlamaConfig, who: str, why: str) -> None:
+    """A path that does not run the ``"L"`` / ``"G"`` kinds and the
+    prediction module refuses them by name, as :func:`_no_delta_kinds`
+    refuses its kinds."""
+    if set(cfg.kinds) & set(LATENT_KINDS) or cfg.mtp_layers:
+        raise NotImplementedError(
+            f"{who} takes no 'L' / 'G' layer and no prediction module "
+            f"(layer_pattern={cfg.layer_pattern!r}, mtp_layers="
+            f"{cfg.mtp_layers}) yet: {why}")
+
+
+def _init_latent_kind(cfg: LlamaConfig, kind: str, L: int, key):
+    """The ``L`` stacked layers of kind ``"L"`` or ``"G"``, keys of their
+    own a kind. Every matrix over the square root of its fan-in (``wq_b`` /
+    ``wkv_b`` over ``sqrt(dim)`` where the config multiplies their inputs by
+    ``sqrt(dim / rank)``, as the ``"S"`` layer's): q, k and v then have unit
+    variance and a score is one unit wide. The choice bias starts at zero."""
+    d, f, H = cfg.dim, cfg.mlp_dim, cfg.n_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    kv = cfg.qk_nope_head_dim + cfg.v_head_dim
+    k = iter(jax.random.split(
+        jax.random.fold_in(key, 6 + LATENT_KINDS.index(kind)), 16))
+    dense = _dense_init
+    ones = lambda width: jnp.ones((L, width), jnp.float32)  # noqa: E731
+    out = {
+        "attn_norm": ones(d), "mlp_norm": ones(d),
+        "wq_a": dense(next(k), (L, d, rq), d), "q_norm": ones(rq),
+        "wq_b": dense(next(k), (L, rq, H * qk),
+                      d if cfg.mla_scale_q_lora else rq),
+        "wkv_a": dense(next(k), (L, d, cfg.latent_row), d),
+        "kv_norm": ones(rkv),
+        "wkv_b": dense(next(k), (L, rkv, H * kv),
+                       d if cfg.mla_scale_kv_lora else rkv),
+        "wo": dense(next(k), (L, H * cfg.v_head_dim, d), H * cfg.v_head_dim),
+    }
+    if kind == "G":
+        fd = cfg.dense_mlp_dim
+        out.update(w_gate=dense(next(k), (L, d, fd), d),
+                   w_up=dense(next(k), (L, d, fd), d),
+                   w_down=dense(next(k), (L, fd, d), fd))
+        return out
+    held, wide = cfg.num_experts, cfg.router_experts or cfg.num_experts
+    out.update(router=dense(next(k), (L, d, wide), d),
+               router_bias=jnp.zeros((L, wide), jnp.float32),
+               w_gate=dense(next(k), (L, held, d, f), d),
+               w_up=dense(next(k), (L, held, d, f), d),
+               w_down=dense(next(k), (L, held, f, d), f))
+    if cfg.shared_mlp_dim:
+        fs = cfg.shared_mlp_dim
+        out.update(shared_gate=dense(next(k), (L, d, fs), d),
+                   shared_up=dense(next(k), (L, d, fs), d),
+                   shared_down=dense(next(k), (L, fs, d), fs))
+    return out
+
+
+def _init_mtp(cfg: LlamaConfig, key):
+    """The prediction module's OWN leaves: the two norms in front of
+    ``eh_proj`` ([2 dim, dim]: the embedding's half first), its ``"L"``
+    blocks and its final norm. Embedding and head are the model's."""
+    d = cfg.dim
+    k_eh, k_block = jax.random.split(jax.random.fold_in(key, 8))
+    return {"enorm": jnp.ones((d,), jnp.float32),
+            "hnorm": jnp.ones((d,), jnp.float32),
+            "eh_proj": _dense_init(k_eh, (2 * d, d), 2 * d),
+            "layers": {"latent": _init_latent_kind(
+                cfg, "L", cfg.mtp_layers, k_block)},
+            "final_norm": jnp.ones((d,), jnp.float32)}
+
+
+def attend_latent_heads(cfg: LlamaConfig, attend, q, latent, wkv_b):
+    """``_latent_half``'s ``attend`` for a TRAIN step: every position's
+    per-head ``[k_nope | v]`` is made, ``c wkv_b``, the ONE rotated key
+    slice is repeated for every head, and ``attend(q, k, v)`` (the caller's:
+    the flash kernel, forward AND backward) sees plain attention of
+    ``n_heads`` on ``n_heads`` at the score's width, which is the value's.
+    Not :func:`attend_latent_expanded`: prefill's kernel takes the shared
+    slice as an operand of its own, but it is forward only, and its
+    transpose is the XLA tile loop. The repeat costs ``qk_rope_head_dim`` of
+    ``head width`` more key bytes, and buys the kernels' backward."""
+    B, T, H, _ = q.shape
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = (latent[..., :r] @ wkv_b).reshape(B, T, H, -1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        latent[:, :, None, r:], (B, T, H, cfg.qk_rope_head_dim))], axis=-1)
+    q, k, v = (checkpoint_name(a, "attn") for a in (q, k, kv[..., dn:]))
+    return attend(q, k, v)
+
+
+def latent_block(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
+    """THE latent block (kinds ``"L"`` and ``"G"``), for the train steps and
+    the full forward::
+
+        a   = x + MLA(N(x))          (:func:`_latent_half`, no sqrt(dim / rank)
+                                      factor unless the config sets it)
+        out = a + MLP(N(a))          "G": the dense SwiGLU (dense_mlp_dim,
+                                     scope ``ffn.dense``); "L": the routed MLP
+                                     (:func:`_mlp_half`: sigmoid or softmax
+                                     scores, the choice bias, a held range)
+                                     with its ungated shared SwiGLU expert
+
+    ``attend(q, k, v)`` as :func:`decoder_block` takes it: the block makes
+    per-head keys and values itself (:func:`attend_latent_heads`), so the
+    trainer's flash kernel runs forward, dQ and dK/dV on them. ``p``: this
+    layer's weights. Returns ``(x, stats)``."""
+    cd, eps = cfg.dtype, cfg.norm_eps
+    h = rms_norm(x, p["attn_norm"], eps).astype(cd)
+    y, _ = _latent_half(cfg, p, h, positions_of(*x.shape[:2]),
+                        partial(attend_latent_heads, cfg, attend))
+    x = x + checkpoint_name(y, "attn").astype(x.dtype)
+    h = rms_norm(x, p["mlp_norm"], eps).astype(cd)
+    if kind == "G":
+        with jax.named_scope("ffn.dense"):
+            y, stats = _dense_mlp(cfg, p, h), {}
+    else:
+        y, stats = _mlp_half(cfg, p, h, stat_axes)
+    return x + y.astype(x.dtype), stats
+
+
+def add_mtp_loss(cfg: LlamaConfig, mtp, x, tokens, nll, stats, *, embed,
+                 attend, chunk_nll, stat_axes=(), policy=None):
+    """The multi-token-prediction module's loss beside the main one, for
+    :func:`loss_parts` and ``make_spmd_train_step`` alike. ``x`` [B, T, dim]:
+    the main model's FINAL-NORMED stream over ``tokens[:, :-1]`` (``tokens``
+    [B, T + 1] = t_0 .. t_T); ``nll`` / ``stats``: the main cross-entropy and
+    the stack's router stats. The caller lends what the module SHARES with
+    the model: ``embed(ids)`` (the embedding table), ``chunk_nll`` (the
+    head), and its ``attend``::
+
+        h'_i = [N_e(Emb(t_{i+1})) ; N_h(x_i)] W_eh      scope ``mtp.merge``
+        y    = the module's "L" block(s) over all T positions  ``mtp.block``
+        L_mtp = mean_{i < T - 1} -log p(t_{i+2} | N(y_i) head)  ``mtp.head``
+
+    Position ``T - 1`` has no target and is left out of the mean
+    (``chunked_nll_mean``'s ``live``). Returns ``(nll + mtp_loss_weight *
+    L_mtp, stats with the module's block's behind the stack's, {"main_loss":
+    nll, "mtp_loss": L_mtp})``; without a module what it was given and
+    ``{}``."""
+    if not cfg.mtp_layers:
+        return nll, stats, {}
+    cd, eps = cfg.dtype, cfg.norm_eps
+    B, T, _ = x.shape
+    # t_{i+2}; the last position's is any id: it does not count
+    targets = jnp.concatenate(
+        [tokens[:, 2:], jnp.zeros((B, 1), tokens.dtype)], axis=1)
+
+    def merge(x, ids, enorm, hnorm, eh_proj):
+        both = jnp.concatenate(
+            [rms_norm(embed(ids), enorm, eps).astype(cd),
+             rms_norm(x, hnorm, eps).astype(cd)], axis=-1)
+        return (both @ eh_proj.astype(cd)).astype(x.dtype)
+
+    with jax.named_scope("mtp.merge"):
+        y = (jax.checkpoint(merge) if cfg.remat else merge)(
+            x, tokens[:, 1:], mtp["enorm"], mtp["hnorm"], mtp["eh_proj"])
+    with jax.named_scope("mtp.block"):
+        y, block_stats = pattern_stack(
+            cfg, y, mtp["layers"], attend, stat_axes, policy,
+            kinds="L" * cfg.mtp_layers)
+    with jax.named_scope("mtp.head"):
+        mtp_nll = chunked_nll_mean(
+            cfg, rms_norm(y, mtp["final_norm"], eps), targets, chunk_nll,
+            policy, live=T - 1)
+    stats = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), stats,
+                         block_stats)
+    return (nll + cfg.mtp_loss_weight * mtp_nll, stats,
+            {"main_loss": nll, "mtp_loss": mtp_nll})

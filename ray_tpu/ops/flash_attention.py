@@ -5,7 +5,10 @@ score materialization (the round-1 MFU bottleneck). Design:
 
 - forward: grid over (batch, q_head, q_block); K/V for the head group live
   in VMEM once (Pallas skips the re-DMA when the block index is unchanged
-  across consecutive grid steps); inner ``fori_loop`` over K/V blocks with
+  across consecutive grid steps; a call whose resident blocks pass the
+  compiler's default scoped VMEM limit, at 8,192 positions the dK/dV call at
+  head width 128 and all three at 256, asks for its limit); inner
+  ``fori_loop`` over K/V blocks with
   online-softmax (max/sum) carries, so HBM traffic is O(T) not O(T^2).
   Causal skips future blocks entirely via a dynamic loop bound.
 - backward: two kernels — dQ (grid over q blocks, loop over past K/V
@@ -157,6 +160,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, blk, causal,
     lse_ref[0, 0, :, 0] = m + jnp.log(l)
 
 
+def _vmem_params(buffers: int, interpret: bool) -> dict:
+    """``pallas_call``'s keywords for a call whose block specs keep
+    ``buffers`` bytes in VMEM (each block twice: the pipeline's double
+    buffering; a [.., 1] float32 column is padded to 128 lanes). A call that
+    fits the compiler's default scoped limit is compiled under it, as it
+    always was; one that does not asks for its buffers and as much again as
+    the default for the kernel's own tiles (a v5e core has 128 MiB). The
+    buffers follow the sequence AND the head's width: a head group's whole
+    K and V are 8.5 MiB at 8,192 positions and width 128 and 17.5 at 256
+    (forward; dQ one block more), a head's whole q, do, lse and delta 25.5
+    and 35 (dK/dV)."""
+    if buffers <= _SCOPED_VMEM_DEFAULT or interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=buffers + _SCOPED_VMEM_DEFAULT)}
+
+
 def _fwd(q, k, v, *, causal, blk, interpret):
     """q [B,Hq,T,D], k/v [B,Hkv,T,D] -> (o [B,Hq,T,D], lse [B,Hq,T])."""
     B, Hq, T, D = q.shape
@@ -167,9 +189,13 @@ def _fwd(q, k, v, *, causal, blk, interpret):
     kernel = functools.partial(
         _fwd_kernel, scale=scale, blk=blk, causal=causal,
         n_kv_blocks=T // blk)
+    # whole k and v, a block each of q and o, a block of lse
+    buffers = 2 * (2 * T * D * k.dtype.itemsize
+                   + 2 * blk * D * q.dtype.itemsize + blk * _LANE * 4)
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
+        **_vmem_params(buffers, interpret),
         in_specs=[
             pl.BlockSpec((1, 1, blk, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h // rep, 0, 0)),
@@ -278,10 +304,14 @@ def _bwd(q, k, v, o, lse, do, *, causal, blk, interpret):
                     axis=-1, keepdims=True)  # [B,Hq,T,1]
     n_blocks = T // blk
 
+    # whole k and v, a block each of q, do and dq, of lse and delta
+    buffers = 2 * (2 * T * D * k.dtype.itemsize
+                   + 3 * blk * D * q.dtype.itemsize + 2 * blk * _LANE * 4)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, blk=blk, causal=causal,
                           n_kv_blocks=n_blocks),
         grid=(B, Hq, n_blocks),
+        **_vmem_params(buffers, interpret),
         in_specs=[
             pl.BlockSpec((1, 1, blk, D), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, T, D), lambda b, h, i: (b, h // rep, 0, 0)),
@@ -296,27 +326,17 @@ def _bwd(q, k, v, o, lse, do, *, causal, blk, interpret):
         name="flash_dq",
     )(q, k, v, do, lse, delta)
 
-    # What the dK/dV call's block specs keep in VMEM, each buffer twice (the
-    # pipeline's double buffering): a head's whole q and do, its lse and
-    # delta ([T, 1] float32 columns, padded to 128 lanes), a block each of
-    # k and v, a float32 block each of dk and dv. 3 KiB a position at
-    # D = 128: 7.5 MiB at T = 2048, 13.5 at 4096, 25.5 at 8192. A call that
-    # fits the compiler's default scoped limit is compiled under it, as it
-    # always was; one that does not asks for its buffers and as much again
-    # as the default for the kernel's own tiles (a v5e core has 128 MiB).
+    # What the dK/dV call's block specs keep in VMEM (_vmem_params): a
+    # head's whole q and do, its lse and delta, a block each of k and v, a
+    # float32 block each of dk and dv. 3 KiB a position at D = 128: 7.5 MiB
+    # at T = 2048, 13.5 at 4096, 25.5 at 8192
     buffers = 2 * (T * (2 * D * q.dtype.itemsize + 2 * _LANE * 4)
                    + 2 * blk * D * (k.dtype.itemsize + 4))
-    params = {}
-    if buffers > _SCOPED_VMEM_DEFAULT and not interpret:
-        from jax.experimental.pallas import tpu as pltpu
-
-        params["compiler_params"] = pltpu.CompilerParams(
-            vmem_limit_bytes=buffers + _SCOPED_VMEM_DEFAULT)
     dk_exp, dv_exp = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, blk=blk, causal=causal,
                           n_q_blocks=n_blocks),
         grid=(B, Hq, n_blocks),
-        **params,
+        **_vmem_params(buffers, interpret),
         in_specs=[
             pl.BlockSpec((1, 1, T, D), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, blk, D), lambda b, h, j: (b, h // rep, j, 0)),

@@ -512,6 +512,7 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
     - ``shared_gated = (w_sg [d], w_gate [d, fs], w_up [d, fs], w_down
       [fs, d])``: one SwiGLU expert every token runs, behind the token's own
       gate ``sigmoid(h . w_sg)`` (float32), added to the routed sum once.
+      ``w_sg=None``: no gate in front of it, the expert's output as it is.
     - ``held = (first, count)``: this device holds the ``count`` experts
       from ``first`` on of the router's ``E`` (``w_up`` [count, d, f]):
       router and ``top_k`` run over all ``E``; assignments to experts held
@@ -615,11 +616,11 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
             w_sg, s_gate, s_up, s_down = shared_gated
             a = (jax.nn.silu((hf @ s_gate.astype(cd)).astype(jnp.float32))
                  * (hf @ s_up.astype(cd))).astype(cd)
-            open_ = jax.nn.sigmoid(jnp.dot(
+            open_ = None if w_sg is None else jax.nn.sigmoid(jnp.dot(
                 hf.astype(jnp.float32), w_sg.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
-            y = y + open_[:, None] * (a @ s_down.astype(cd)).astype(
-                jnp.float32)
+                precision=jax.lax.Precision.HIGHEST))[:, None]
+            out = (a @ s_down.astype(cd)).astype(jnp.float32)
+            y = y + (out if open_ is None else open_ * out)
     with jax.named_scope("moe.route"):
         fraction = jax.lax.stop_gradient(counts.astype(jnp.float32) / N)
         for ax in stat_axes:

@@ -113,7 +113,15 @@ _ROUTER_GAUGES = {
     # all assignments that fell on the held ones
     "held_share": _fr.register_span("moe.held_share",
                                     tag_keys=("value", "step")),
+    # a model with a prediction module (LlamaConfig.mtp_layers): its
+    # cross-entropy and the main one apart, as they stand in the loss before
+    # the module's weight (the report's ``mtp_loss`` / ``main_loss``)
+    "mtp_loss": _fr.register_span("mtp.loss", tag_keys=("value", "step")),
+    "main_loss": _fr.register_span("mtp.main_loss",
+                                   tag_keys=("value", "step")),
 }
+# the step scalars a report spells as they are; the router's: ``moe_<name>``
+_REPORTED_AS_IS = ("mtp_loss", "main_loss")
 
 # Throughput/step-time gauges feeding the head's metrics-history rings
 # (session.report only buffers to the driver's result log) — the series
@@ -126,8 +134,9 @@ _g_step_seconds = Gauge("ray_tpu_train_step_seconds",
                         "Recent mean train step wall time (s)",
                         tag_keys=("loop",))
 # the stack the step was built for, set once when it is built: layers by
-# kind (block; of a patterned stack mamba / moe / attn), and the experts a
-# routed layer holds of the router's width
+# kind (block; of a patterned stack mamba / moe / attn, latent / latent_dense
+# for the "L" / "G" blocks, mtp for a prediction module's blocks), and the
+# experts a routed layer holds of the router's width
 _g_stack = Gauge("ray_tpu_train_stack",
                  "The model a train step was built for: layers by kind, "
                  "experts held, the router's width", tag_keys=("part",))
@@ -347,11 +356,22 @@ def kept_group_bytes(cfg, batch: int, seq: int, *,
         # float32 log-sum-exp, one a query head
         groups["attn"] = (kinds.count("b") + kinds.count("*")) * rows * (
             (2 * q + 2 * kv + cfg.dim) * item + 4 * cfg.n_heads // tensor)
+        # a latent block's (a prediction module's too): keys and values are
+        # made a head, at the value's width, which is the score's
+        wide = cfg.n_heads * cfg.v_head_dim
+        groups["attn"] += (
+            kinds.count("L") + kinds.count("G") + cfg.mtp_layers) * rows * (
+                (4 * wide + cfg.dim) * item + 4 * cfg.n_heads)
         if not cfg.num_experts:  # gate and up
             groups["mlp"] = (kinds.count("b") * rows
                              * 2 * (cfg.mlp_dim // tensor) * item)
+        if "G" in kinds:
+            groups["mlp"] = kinds.count("G") * rows * 2 * cfg.dense_mlp_dim \
+                * item
     if cfg.loss_chunk and seq > cfg.loss_chunk:
-        groups["head"] = (batch * (seq - seq % cfg.loss_chunk)
+        # a prediction module runs the head a second time
+        groups["head"] = ((1 + cfg.mtp_layers) * batch
+                          * (seq - seq % cfg.loss_chunk)
                           * (cfg.vocab_size // tensor) * item)
     return {g: b for g, b in groups.items() if b}
 
@@ -369,7 +389,11 @@ def loss_phase_bytes(cfg, batch: int, seq: int, *, tensor: int = 1) -> int:
     vocab = cfg.vocab_size // tensor
     stream = batch * seq * cfg.dim * jnp.dtype(cfg.dtype).itemsize
     chunk = batch * min(cfg.loss_chunk or seq, seq) * vocab * 4
-    return (cfg.n_layers + 2) * stream + 3 * chunk + cfg.dim * vocab * 4
+    # a prediction module: its merged input, a block's input each and its
+    # own final stream, saved until the second loss's backward has run
+    mtp = cfg.mtp_layers + 2 if cfg.mtp_layers else 0
+    return ((cfg.n_layers + 2 + mtp) * stream + 3 * chunk
+            + cfg.dim * vocab * 4)
 
 
 def program_bytes(memory: Dict[str, int]) -> int:
@@ -599,7 +623,16 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     batch axes only, its layers walked in the pattern's order
     (``models.llama.pattern_stack``); a live ``fsdp`` or ``tensor`` axis is
     refused. Its routed layers may hold a range of the router's experts:
-    the step's router scalars then carry ``held_share``.
+    the step's router scalars then carry ``held_share``. Latent-attention
+    blocks (``"L"`` / ``"G"``, ``models.llama.latent_block``) are attended
+    with the flash kernel over per-head keys and values of ONE width (the
+    score's, which is the value's), forward, dQ and dK/dV, and NOT with
+    prefill's kernel, which has no backward of its own. With a prediction
+    module (``cfg.mtp_layers``) the loss is the main cross-entropy plus
+    ``mtp_loss_weight`` times the module's, built where ``loss_parts``
+    builds it (``models.llama.add_mtp_loss``: the step lends its embedding
+    table, its head and its kernel), and the step's scalars carry
+    ``main_loss`` and ``mtp_loss`` apart.
 
     Where the layers run under ``jax.checkpoint`` (``cfg.remat``) and where
     the loss is chunked, the step KEEPS the forward products that fit the
@@ -644,6 +677,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         _no_delta_kinds,
         _no_window_kinds,
         _plain_chunk_nll,
+        add_mtp_loss,
         add_router_losses,
         chunked_nll_mean,
         decoder_block,
@@ -719,6 +753,9 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
                     ("mamba_layers", kinds.count("M")),
                     ("moe_layers", kinds.count("E")),
                     ("attn_layers", kinds.count("*")),
+                    ("latent_layers", kinds.count("L")),
+                    ("latent_dense_layers", kinds.count("G")),
+                    ("mtp_layers", cfg.mtp_layers),
                     ("experts_held", cfg.num_experts),
                     ("router_experts",
                      cfg.router_experts or cfg.num_experts)):
@@ -895,13 +932,22 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
             nll = chunked_nll_mean(
                 cfg, fi(x), targets,
                 vp_chunk_nll(cfg, head_local, tensor, gp), policy)
-        else:
-            nll = chunked_nll_mean(cfg, x, targets,
-                                   _plain_chunk_nll(cfg, head_local), policy)
-        return add_router_losses(cfg, nll, stats)
+            return add_router_losses(cfg, nll, stats)
+        chunk_nll = _plain_chunk_nll(cfg, head_local)
+        nll = chunked_nll_mean(cfg, x, targets, chunk_nll, policy)
+        # a prediction module shares this step's table, head and kernel
+        nll, stats, report = add_mtp_loss(
+            cfg, shards.get("mtp"), x, tokens, nll, stats,
+            embed=lambda ids: emb_local.astype(cfg.dtype)[ids],
+            attend=flash_causal, chunk_nll=chunk_nll, stat_axes=batch_axes,
+            policy=policy)
+        total, router = add_router_losses(cfg, nll, stats)
+        return total, {**router, **report}
 
-    # after the state: the loss, and a routed config's router scalars
-    routed = cfg.num_experts and (not cfg.layer_pattern or "E" in cfg.kinds)
+    # after the state: the loss, and a routed config's router scalars (with
+    # a prediction module's two losses among them)
+    routed = cfg.num_experts and (not cfg.layer_pattern
+                                  or set(cfg.kinds) & set("EL"))
     scalars = 2 if routed else 1
 
     def step_keeping(groups):
@@ -1160,6 +1206,7 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
         _t = _fr.now()
         for k, v in moe.items():
             _ROUTER_GAUGES[k].instant(v, i + 1)
+        as_is = {k: moe.pop(k) for k in _REPORTED_AS_IS if k in moe}
         now = time.perf_counter()
         dt = max(now - t0, 1e-9)
         win_dt = max(now - win_t, 1e-9)
@@ -1177,7 +1224,7 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
             "devices": mesh.size,
             "mesh": dict(mesh.shape),
             **ran_on,
-            **{f"moe_{k}": v for k, v in moe.items()},
+            **{f"moe_{k}": v for k, v in moe.items()}, **as_is,
         }
         if i == steps - 1:
             report.update(_run_evidence(state))

@@ -726,7 +726,7 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         report["serving"] = serving
     # a routed model's router (train/spmd.py: one instant a report)
     instants = [ev for ev in events if ev.get("ph") == "i"
-                and str(ev.get("name", "")).startswith("moe.")]
+                and str(ev.get("name", "")).startswith(("moe.", "mtp."))]
     router: Dict[str, List[float]] = {}
     for ev in instants:
         router.setdefault(ev["name"], []).append(

@@ -137,3 +137,41 @@ def test_the_three_kernels_carry_their_names():
 
     walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv).jaxpr)
     assert sorted(names) == ["flash_dkv", "flash_dq", "flash_fwd"]
+
+
+# --- head width 256 on 20 / 20 heads: a latent block's attention ------------ #
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """q, k, v [1, 128, 20, 256] (two blocks of 64) and a cotangent."""
+    rng = np.random.RandomState(2)
+    return tuple(jnp.asarray(rng.randn(1, 128, 20, 256), jnp.float32)
+                 for _ in range(4))
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+def test_width_256_on_20_heads_forward(wide, dtype, tol):
+    """The kernel's scale is ``1 / sqrt(256)`` and nothing is padded."""
+    q, k, v, _ = (a.astype(dtype) for a in wide)
+    out = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, block=64, interpret=True))(q, k, v)
+    want = _ref(*(a.astype(jnp.float32) for a in (q, k, v)))
+    assert out.dtype == jnp.dtype(dtype) and out.shape == (1, 128, 20, 256)
+    err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - want))
+                / jnp.max(jnp.abs(want)))
+    assert err < tol, err
+
+
+@pytest.mark.parametrize("wrt", ["dq", "dk", "dv"])
+def test_width_256_on_20_heads_gradients(wide, wrt):
+    """dQ from its kernel, dK and dV from theirs (20 KV heads: the group sum
+    is over ONE head), against plain attention's."""
+    q, k, v, ct = wide
+    arg = "dq dk dv".split().index(wrt)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(flash_attention(
+        *a, block=64, interpret=True) * ct), argnums=arg))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(_ref(*a) * ct),
+                            argnums=arg))(q, k, v)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    assert err < 5e-5, err

@@ -484,7 +484,8 @@ def test_train_loop_metric_files_read_their_spans(metric, span):
         file["unit"], file["source"], file["moves"])
     assert entry["workloads"] == [
         "train-mistral7b-1chip", "train-mistral7b-4chip",
-        "train-olmoe-1chip", "train-nemotron3nano-1chip"]
+        "train-olmoe-1chip", "train-nemotron3nano-1chip",
+        "train-glm47flash-1chip"]
     spans = {span: [[0.0, 0.001], [1.0, 0.003], [2.0, 2.5]]}
     assert reducers.read_metric(file, {"spans": spans}) == pytest.approx(3.0)
     assert reducers.wanted_spans([file]) == {span}

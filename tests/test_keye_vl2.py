@@ -538,7 +538,7 @@ def test_benchmark_files_fit_together_with_the_new_cell():
 
     test_yardstick.test_benchmark_files_fit_together()
     bench = spec.load_benchmark()
-    assert len(bench["workloads"]) == 9
+    assert len(bench["workloads"]) == 10
     assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
     b = spec.cell_bundle(CELL)
     assert (b["cell"]["chips"], b["cell"]["traffic"], b["cell"]["config"]) \
@@ -566,7 +566,9 @@ def test_benchmark_files_fit_together_with_the_new_cell():
     assert dep["n_pages"] >= dep["decode_max_batch"] * (shapes["decode"][-1]
                                                         + 1)
     assert spec.resolve(b["config"]["reference"] + ":logits_one")
-    assert sorted(bench["configs"][-1]["reduced"]) == [
+    entry, = [c for c in bench["configs"]
+              if c["name"] == b["cell"]["config"]]
+    assert sorted(entry["reduced"]) == [
         "num_experts", "num_hidden_layers", "vocab_size"]
 
 

@@ -123,4 +123,5 @@ def test_loop_reports_held_share_and_sets_the_stack_gauge():
     gauge = registry().local_values("ray_tpu_train_stack")
     assert {k[0][1]: v for k, v in gauge.items()} == {
         "block_layers": 0.0, "mamba_layers": 4.0, "moe_layers": 4.0,
-        "attn_layers": 1.0, "experts_held": 4.0, "router_experts": 32.0}
+        "attn_layers": 1.0, "latent_layers": 0.0, "latent_dense_layers": 0.0,
+        "mtp_layers": 0.0, "experts_held": 4.0, "router_experts": 32.0}

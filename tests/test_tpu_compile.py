@@ -5,6 +5,7 @@ is a result or a time. All such compiles live in THIS file: the process that
 describes the topology holds the TPU library until it exits."""
 
 import math
+import re
 
 import pytest
 
@@ -79,6 +80,59 @@ def test_flash_kernels_compile_at_the_cells_shapes(cell, one_chip,
     compiled = lowered.compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
         >= 3
+
+
+def test_flash_kernels_compile_at_head_width_256(one_chip, as_on_the_chip):
+    """``train-glm47flash-1chip``'s attention, [2, 8192, 20, 256] on 20 KV
+    heads: a head group's whole K and V are 17.5 MiB of VMEM in the forward
+    call (18.5 in dQ; a head's whole q, do, lse and delta 35 in dK/dV), so
+    ALL THREE ask for their limit, where at width 128 only dK/dV does."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    arg = jax.ShapeDtypeStruct((2, 8192, 20, 256), jnp.bfloat16,
+                               sharding=one_chip)
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(arg, arg, arg)
+    assert lowered.as_text().count("scoped_memory_configs") == 3
+    assert lowered.compile().as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3
+
+
+def test_the_glm47flash_cells_whole_step_fits_the_chip(one_chip,
+                                                       as_on_the_chip):
+    """The cell's WHOLE train step (706.5M parameters, batch 2 x 8192, every
+    KEEP_GROUPS group recomputed) compiled for a described v5e: its memory
+    account stays under the chip's 16 GB (15.75 GiB usable), the latent
+    blocks' and the module's attention are the flash kernels forward and
+    backward (six latent attentions: a forward, its remat and dQ / dK/dV
+    each), and no tile loop of prefill's is in it."""
+    from benchmarks.lib import spec
+    from ray_tpu.train.spmd import (build_train_mesh, make_spmd_train_step,
+                                    program_bytes)
+
+    cfg = spec.program_config(
+        spec.cell_bundle("train-glm47flash-1chip")["config"])
+    init, step, *_ = make_spmd_train_step(
+        cfg, build_train_mesh("", list(one_chip.device_set)), keep=())
+    state = jax.eval_shape(init._fn, jax.random.PRNGKey(0))
+    compiled = step._fn.lower(
+        state, jax.ShapeDtypeStruct((2, 8193), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    need = program_bytes({
+        "peak": getattr(m, "peak_memory_in_bytes", 0),
+        "argument": m.argument_size_in_bytes,
+        "output": m.output_size_in_bytes, "alias": m.alias_size_in_bytes,
+        "temp": m.temp_size_in_bytes,
+        "code": m.generated_code_size_in_bytes})
+    assert 12 * 2 ** 30 < need < 15.75 * 2 ** 30, need
+    text = compiled.as_text()
+    assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 12
+    assert len(re.findall(r"%flash_dq[.\d]* = ", text)) == 6
+    assert len(re.findall(r"%flash_dkv[.\d]* = ", text)) == 6
+    assert "flash_prefill" not in text
 
 
 def _scanned_experts(L, act):
