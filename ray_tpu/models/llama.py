@@ -3305,9 +3305,9 @@ class LlamaDecodeEngine:
         self._note_slots()
         groups = {"program": 0, "layer": 0}
         if self.cfg.num_experts:  # the routed kinds it serves
-            from ray_tpu.ops.moe import expert_groups, watch_stacked_calls
+            from ray_tpu.ops.moe import expert_groups
 
-            watch_stacked_calls(_note_expert_products)
+            _watch_routed_calls()
             w_up = self.params["layers"][SERVED[layout[0].kind].stack]["w_up"]
             groups = {"program": expert_groups(w_up, self.cfg.dtype),
                       "layer": w_up.shape[1]}
@@ -3949,7 +3949,7 @@ _delta_kernel.defvjp(_delta_kernel_fwd, _delta_kernel_bwd)
 
 # what the held path made of the last prefill's assignments: the places with
 # a held expert (``live``: held_share of tokens x top_k, a routed layer's
-# mean) and the places a row was gathered, multiplied and scatter-added for
+# mean) and the places a row was gathered, multiplied and summed for
 # (``made``: ops/moe.py held_places_made, whole chunks up to ``live``). Set by
 # an engine whose layers hold a range of the router's experts, by no other
 _g_moe_places = Gauge(
@@ -4153,3 +4153,54 @@ def add_mtp_loss(cfg: LlamaConfig, mtp, x, tokens, nll, stats, *, embed,
                          block_stats)
     return (nll + cfg.mtp_loss_weight * mtp_nll, stats,
             {"main_loss": nll, "mtp_loss": mtp_nll})
+
+
+# --------------------------------------------------------------------------- #
+# Which way the held experts' sums went (ops/moe.py held_sum_path). At this
+# file's end for attend_delta's reason
+# --------------------------------------------------------------------------- #
+
+# counted where a program is traced (ops/moe.py _held_chunks / _held_blocks
+# tell an engine's process through watch_held_sums): in an engine's process on
+# the chip a prefill program over more than one chunk of places reads
+# path=kernel (ops/row_sum.py) and a decode program (one straight block)
+# path=xla; held_sum_paths() keeps the reason beside the count
+_g_engine_held_sums = Gauge(
+    "ray_tpu_serve_engine_held_sums",
+    "Sums of the held experts' rows onto tokens traced in a decode engine's "
+    "process, by the path they took: the Pallas gather-sum or XLA's "
+    "scatter-add", tag_keys=("path",))
+
+# (rows, tokens, type, path, reason) -> {.., "calls"}
+_held_sums_taken: Dict[tuple, dict] = {}
+
+
+def held_sum_paths() -> list:
+    """Every distinct sum of the held path on stacked leaves (rows, tokens,
+    type, path) traced in this process since its first routed engine was
+    built, with its reason (``ops.moe.held_sum_path``'s) and how often."""
+    with _paths_lock:
+        return [dict(rec) for rec in _held_sums_taken.values()]
+
+
+def _note_held_sums(ys, n_tokens, path, reason) -> None:
+    dtype = jnp.dtype(ys.dtype).name
+    key = (ys.shape, n_tokens, dtype, path, reason)
+    with _paths_lock:
+        rec = _held_sums_taken.setdefault(key, {
+            "rows": list(ys.shape), "tokens": n_tokens, "dtype": dtype,
+            "path": path, "reason": reason, "calls": 0})
+        rec["calls"] += 1
+        counts = {way: sum(r["calls"] for r in _held_sums_taken.values()
+                           if r["path"] == way) for way in ("kernel", "xla")}
+    for way, n in counts.items():
+        _g_engine_held_sums.set(float(n), tags={"path": way})
+
+
+def _watch_routed_calls() -> None:
+    """What a routed engine registers with ``ops/moe.py`` where it is built:
+    the counts of its experts' products and of its held sums by path."""
+    from ray_tpu.ops.moe import watch_held_sums, watch_stacked_calls
+
+    watch_stacked_calls(_note_expert_products)
+    watch_held_sums(_note_held_sums)

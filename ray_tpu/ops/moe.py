@@ -438,21 +438,21 @@ def _held_rows(hf, top_w, order, starts, end, weights, experts,
     no product and no part in the sum. ``weights`` / ``layer``: as
     :func:`_expert_ffn` takes them.
 
-    The index maps here are a gather of rows and a float32 scatter-add onto
-    tokens (``_dispatch`` / ``_combine`` gather a row for EVERY assignment).
-    On this chip the gather is linear in the places a row is made for (from
-    HBM 24-30 rows a microsecond); a scatter-add CALL is 1-2 ms and then
-    0.07-0.1 us a row (PERF.md, PRs 41 and 47). So the places follow the
-    router's own count ``end``, in few large chunks, by the call's own shape
-    and by whether it is differentiated (:func:`held_places_made`):
+    The index maps here are a gather of rows and a float32 sum onto tokens
+    (``_dispatch`` / ``_combine`` gather a row for EVERY assignment), both
+    linear in the places a row is made for (a gather from HBM 24-30 rows a
+    microsecond; the sum by :func:`held_sum_path`: a kernel in a prefill's
+    loop, XLA's scatter-add elsewhere; PERF.md, PRs 41, 47 and 49). So the
+    places follow the router's own count ``end``, in few large chunks, by the
+    call's shape and whether it is differentiated (:func:`held_places_made`):
 
     - ``A <= held_chunk(..)`` (every decode call: 8-12 places): one straight
       block, a row for every place (:func:`_held_blocks`);
     - a larger FORWARD call (a prefill program, ``forward`` / ``loss_fn``):
       :func:`_held_chunks`' ONE loop of ``ceil(end / chunk)`` chunks of the
-      EVEN share of the places and a margin: a balanced router's load is
-      one chunk with a short dead tail, a heavier one more chunks, none can
-      outrun it, and a program holds one expert kernel a routed block;
+      EVEN share of the places and a margin: a balanced router's load is one
+      chunk with a short dead tail, a heavier one more chunks, none can outrun
+      it, and a program holds one expert (and sum) kernel a routed block;
     - a larger DIFFERENTIATED call (the trainer's step): fixed blocks, the
       first always made, a further one under a ``cond``
       (:func:`_held_chunks_jvp`): a loop of traced length has no transpose.
@@ -461,8 +461,8 @@ def _held_rows(hf, top_w, order, starts, end, weights, experts,
     onto ``[N, d]``, every live assignment's row whatever the routing, and
     nothing from a dead place. On the TPU the grouped product leaves the
     rows of no group UNWRITTEN (whatever the buffer held): places from
-    ``end`` on are set to zero going in and coming out, and so are,
-    transposed, their cotangents (:func:`_place_rows`).
+    ``end`` on are set to zero going in and coming out (the kernel never
+    adds them), and so are, transposed, their cotangents (:func:`_place_rows`).
 
     These functions stand at the END of this file but for this one, which
     keeps the lines it had: a Mosaic kernel's recorded frames are part of
@@ -655,9 +655,9 @@ def held_chunk(places: int, count: int, experts: int) -> int:
     held experts of the router's ``experts`` get, and one part in
     ``sqrt(count)`` more (what the sum of ``count`` experts' uneven loads
     strays by: a quarter over 16 experts, a twelfth over 128), in whole row
-    tiles. A function of the call's shapes and no knob: a chunk pays a
-    scatter-add call (1-2 ms on a v5e, some 15,000 dead places' worth), so
-    the common load should be ONE chunk and its dead tail the margin."""
+    tiles. A function of the call's shapes and no knob, chosen (PERF.md, PR
+    47) so that the common load is ONE chunk and its dead tail the margin;
+    whether a smaller chunk pays under the sum's kernel is not measured."""
     even = places * count / experts
     return math.ceil(even * (1 + count ** -0.5) / HELD_TILE) * HELD_TILE
 
@@ -672,20 +672,29 @@ def held_places_made(places: int, live, count: int, experts: int) -> int:
     return places if places <= c else -(-int(live) // c) * c
 
 
-def _place_rows(hf, flat_w, order, edges, end, weights, layer, act, lo, n):
-    """``(token, rows)`` of the ``n`` sorted places from ``lo`` on: each
-    place's token, and its expert's output times its weight, float32
-    ``[n, d]``, zero from place ``end`` on. ``edges`` [count + 1]: each held
-    expert's first place, then ``end``; a group the range cuts is handed
-    over with the rows it has inside."""
+def _place_outputs(hf, flat_w, order, edges, end, weights, layer, act, lo, n):
+    """``(live, mine, token, ys, inside)`` of the ``n`` sorted places from
+    ``lo`` on: whether a place lies before ``end``, its assignment, its
+    token, its expert's output ``[n, d]`` in the compute type (UNWRITTEN from
+    place ``end`` on) and the experts' edges inside the range. ``edges``
+    [count + 1]: each held expert's first place, then ``end``; a group the
+    range cuts is handed over with the rows it has inside."""
     live = lo + jnp.arange(n, dtype=jnp.int32) < end
     mine = jax.lax.dynamic_slice_in_dim(order, lo, n)
     token = mine // (flat_w.shape[0] // hf.shape[0])
     with jax.named_scope("moe.dispatch"):
         xs = jnp.where(live[:, None], hf[token], 0)
     with jax.named_scope("moe.experts"):
-        ys = _expert_ffn(xs, *weights,
-                         jnp.diff(jnp.clip(edges - lo, 0, n)), layer, act)
+        inside = jnp.clip(edges - lo, 0, n)
+        ys = _expert_ffn(xs, *weights, jnp.diff(inside), layer, act)
+    return live, mine, token, ys, inside
+
+
+def _place_rows(hf, flat_w, order, *place):
+    """``(token, rows)`` of :func:`_place_outputs`' places: each place's
+    token, and its expert's output times its weight, float32 ``[n, d]``,
+    zero from place ``end`` on."""
+    live, mine, token, ys, _ = _place_outputs(hf, flat_w, order, *place)
     with jax.named_scope("moe.combine"):
         return token, jnp.where(live, flat_w[mine], 0.0)[:, None] * jnp.where(
             live[:, None], ys, 0).astype(jnp.float32)
@@ -702,6 +711,7 @@ def _held_blocks(hf, top_w, order, starts, end, weights, blocks, layer, act):
     if A % blocks:
         blocks = 1
     n = A // blocks
+    _tell_held_sum(jax.ShapeDtypeStruct((n, d), hf.dtype), N, layer, False)
     edges = jnp.append(starts, end).astype(jnp.int32)
     flat_w = top_w.reshape(A)
 
@@ -724,17 +734,29 @@ def _held_chunks(act, experts, hf, top_w, order, starts, end, weights, layer):
     sorted places whose trip count is ``ceil(end / chunk)``: a chunk's
     rows gathered, through :func:`_expert_ffn` with the groups' counts
     clipped to the chunk (a group a chunk's edge cuts is one more cut
-    tile), weighted and scatter-added in float32 INTO THE CARRIED sum. Only
-    the last chunk has dead places."""
+    tile), weighted and added in float32 INTO THE CARRIED sum: by ONE
+    Pallas call a chunk that fetches a token tile's rows in the compute type
+    and adds them in VMEM (``ops/row_sum.py``, imported here and nowhere
+    else), or by a float32 product ``[chunk, d]`` and XLA's scatter-add
+    (:func:`held_sum_path` says which). Only the last chunk has dead
+    places."""
     (N, d), A = hf.shape, order.shape[0]
     c = held_chunk(A, starts.shape[0], experts)
+    path = _tell_held_sum(jax.ShapeDtypeStruct((c, d), hf.dtype), N, layer)
     edges = jnp.append(starts, end).astype(jnp.int32)
     flat_w = top_w.reshape(A)
     order = jnp.pad(order, (0, -A % c))  # a last chunk's places behind ``A``
 
     def chunk(i, y):
-        token, ys = _place_rows(hf, flat_w, order, edges, end, weights,
-                                layer, act, i * c, c)
+        place = (edges, end, weights, layer, act, i * c, c)
+        if path == "kernel":
+            from ray_tpu.ops.row_sum import held_sum
+
+            _, mine, token, ys, inside = _place_outputs(hf, flat_w, order,
+                                                        *place)
+            with jax.named_scope("moe.combine"):
+                return held_sum(y, ys, token, flat_w[mine], inside)
+        token, ys = _place_rows(hf, flat_w, order, *place)
         with jax.named_scope("moe.combine"):
             return y.at[token].add(ys)
 
@@ -760,3 +782,66 @@ def _held_chunks_jvp(act, experts, primals, tangents):
         lambda hf, top_w, weights: _held_blocks(
             hf, top_w, order, starts, end, weights, blocks, layer, act),
         (hf, top_w, weights), (tangents[0], tangents[1], tangents[5]))
+
+
+# told ``(ys, n_tokens, path, reason)`` of every held sum on a kind's STACKED
+# leaves, where a program is traced, as ``_stacked_call_watchers`` are of the
+# products (models/llama.py held_sum_paths and the gauge beside it)
+_held_sum_watchers: list = []
+
+
+def watch_held_sums(tell) -> None:
+    """Register ``tell(ys, n_tokens, path, reason)`` (once, however often it
+    is asked) for every sum of the held path on stacked leaves."""
+    if tell not in _held_sum_watchers:
+        _held_sum_watchers.append(tell)
+
+
+def _tell_held_sum(ys, n_tokens: int, layer, loop: bool = True) -> str:
+    """The path of this call's sum; a served kind's calls tell the
+    watchers."""
+    path, reason = held_sum_path(ys, n_tokens, layer, loop)
+    if layer is not None:
+        for tell in _held_sum_watchers:
+            tell(ys, n_tokens, path, reason)
+    return path
+
+
+def held_sum_path(ys, n_tokens: int, layer=None, loop: bool = True
+                  ) -> Tuple[str, str]:
+    """``(path, reason)`` by which a call of the held path adds ``ys`` [c, d]
+    (a chunk's or a block's rows) onto ``n_tokens`` tokens in this process:
+    ``"kernel"`` (``ops/row_sum.py``) on a TPU backend, in
+    :func:`_held_chunks`' forward ``loop``, for a served kind's stacked
+    leaves (a train process never imports the kernel's module), rows of
+    whole lanes in a 2- or 4-byte float type, in whole slabs, tokens that
+    split into tiles and a chunk whose tokens and weights fit the scalar
+    memory; ``"xla"`` (the weighted float32 rows and a
+    scatter-add) with what stands in the way otherwise: every decode call
+    and every differentiated call (:func:`_held_blocks`). Read from the
+    backend and the call's own shapes alone."""
+    platform = jax.default_backend()
+    if platform != "tpu":
+        return "xla", f"backend is {platform!r}, not tpu"
+    if layer is None:
+        return "xla", ("one layer's experts [count, d, f], not a kind's "
+                       "stack with its layer's number")
+    c, d = ys.shape
+    if not loop:
+        return "xla", (f"one straight block of {c} places (a decode call, or "
+                       "a differentiated call's fixed blocks)")
+    dtype = jnp.dtype(ys.dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize not in (2, 4):
+        return "xla", f"rows in {dtype.name}, not a 2- or 4-byte float type"
+    if d % 128:
+        return "xla", f"rows of {d} are not whole lanes of 128"
+    from ray_tpu.ops.row_sum import GROUP, MAX_PLACES, pick_tile
+
+    if pick_tile(n_tokens, d) is None:
+        return "xla", f"{n_tokens} tokens do not split into tiles of 8 or more"
+    if c % GROUP:
+        return "xla", f"{c} places are not whole slabs of {GROUP} rows"
+    if c > MAX_PLACES:
+        return "xla", (f"a chunk of {c} places: their tokens and weights pass "
+                       f"the scalar memory ({MAX_PLACES})")
+    return "kernel", "tpu backend"

@@ -330,7 +330,7 @@ def test_programs_that_must_not_change_hold_no_kernel_of_the_experts(
     cells' own programs on both trees."""
     text = UNTOUCHED[program](one_chip)
     assert "tpu_custom_call" not in text and "moe_ffn" not in text
-    assert "gdn_prefill" not in text
+    assert "gdn_prefill" not in text and "held_sum" not in text
     assert ("ragged_dot" in text) == (program != "dense prefill")
 
 
@@ -508,11 +508,16 @@ def test_qwen3next_cells_programs_compile_and_fit_the_chip(
     assert ("%flash_prefill" in text) == prefill
     assert ("moe_ffn" in text) == prefill
     assert ("%gdn_prefill" in text) == prefill
+    # the held rows' sum (PR 49): one kernel beside each grouped kernel in
+    # the loop's body, and no float32 [chunk, d] product or scatter-add
+    assert ("%held_sum" in text) == prefill
     if prefill:
         # the period DDDA is one scanned body: three calls of the kernel
         assert text.count("%gdn_prefill") % 3 == 0
         assert "triangular" not in text.lower()
         assert len(re.findall(r"%moe_ffn[.\d]* = ", text)) == 4
+        assert len(re.findall(r"%held_sum[.\d]* = ", text)) == 4
+        assert "moe.combine/scatter" not in text
         assert memory.temp_size_in_bytes < {3: 1.0e9, 15: 3.1e9,
                                             16: 3.3e9}[pages]
     else:
@@ -535,3 +540,49 @@ def test_longcats_prefill_holds_one_grouped_kernel_a_routed_block(
     assert len(re.findall(r"call @grouped_ffn", text)) == 1
     assert 'kernel_name = "moe_ffn"' in text
     assert "stablehlo.case" not in text
+    # and one sum of the held rows beside it (PR 49), no scatter
+    assert len(re.findall(r"call @held_sum", text)) == 1
+    assert 'kernel_name = "held_sum"' in text
+    assert "stablehlo.scatter" not in text
+
+
+# (tokens, d, places of a chunk, held experts): the three held families'
+# 16-page prefills and Qwen3-Next's shortest (ops/moe.py held_chunk)
+HELD_SUMS = {
+    "qwen3next 16 pages": (32768, 2048, 89344, 128),
+    "qwen3next 3 pages": (6144, 2048, 16896, 128),
+    "keye 16 pages": (32768, 2048, 40960, 16),
+    "longcat 16 pages": (8192, 6144, 2560, 16),
+}
+
+
+@pytest.mark.parametrize("what", list(HELD_SUMS))
+def test_held_sum_compiles_at_the_cells_shapes(what, one_chip):
+    """The held rows' gather-sum for a described v5e: slabs of 16 rows
+    copied out of HBM on whole tiles (one row of ``[c, d]`` is refused), a
+    row of a slab and of the tile read and written at a traced sublane, a
+    chunk's tokens and weights in scalar memory (715 KB of 1 MiB at
+    Qwen3-Next's 16 pages) beside the table of runs, the token tile and the
+    ring in VMEM under the limit the call asks for; ``y`` is summed in place
+    and nothing of the rows' size is made beside it."""
+    from ray_tpu.ops import moe
+    from ray_tpu.ops.row_sum import held_sum
+
+    tokens, d, places, count = HELD_SUMS[what]
+    router = {"qwen3next": (10, 512), "keye": (8, 128),
+              "longcat": (12, 768)}[what.split()[0]]
+    assert moe.held_chunk(tokens * router[0], count, router[1]) == places
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(held_sum, donate_argnums=0).lower(
+        arg((tokens, d), jnp.float32), arg((places, d), jnp.bfloat16),
+        arg((places,), jnp.int32), arg((places,), jnp.float32),
+        arg((count + 1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%held_sum" in text and "sort(" not in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 4 * tokens * d
+    assert memory.temp_size_in_bytes < 2 ** 24  # the table's one-hots
