@@ -51,6 +51,10 @@ def init(
     ``ray.init(address="ray://...")``). ``cluster_key`` (hex; or env
     ``RAY_TPU_CLUSTER_KEY``) authenticates the channel."""
     global _head, _namespace
+    # imported here: ray_tpu.util's package imports this module's ``remote``
+    from ray_tpu.util import flight_recorder as _fr
+
+    _t_init = _fr.now()
     if is_initialized():
         if ignore_reinit_error:
             return runtime_mod.get_current_runtime()
@@ -108,6 +112,9 @@ def init(
         observe_jax_import()  # compile events from process start, not tick 1
         _head._device_telemetry_stop = start_device_telemetry(
             node_hex=_head.head_node.hex)
+    # the head started in this process, entry to return: one record a
+    # process (``timeline --attribute``'s set-up block)
+    _fr.register_span("runtime.init").end(_t_init)
     return rt
 
 

@@ -41,6 +41,9 @@ from .task_spec import TaskSpec
 from ray_tpu.experimental.channel import is_arraylike as _is_arraylike
 from ray_tpu.util import flight_recorder as _fr
 
+# a worker's way from the start of its process to its registration with the
+# node: one record a process (``timeline --attribute``'s set-up block)
+_sp_boot = _fr.register_span("worker.boot")
 _sp_dag_exec = _fr.register_span("dag.exec", tag_keys=("method",))
 _sp_batch_drain = _fr.register_span("dag.batch_drain", tag_keys=("method",))
 # a stream request's residency in the lane's ring: publish stamp -> read
@@ -1316,7 +1319,27 @@ class WorkerRuntime:
                           type(exc).__name__)
 
 
+def _process_start() -> float:
+    """When this process began, on the flight recorder's clock: the kernel's
+    record of it (``/proc/self/stat``, ticks since boot, against the time
+    since boot now), so that the interpreter's start and this module's
+    imports count as boot; where that cannot be read, now. 0.0 with the
+    recorder off, as ``now()`` is."""
+    t = _fr.now()
+    if not t:
+        return 0.0
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+        return t - age if 0.0 <= age < 600.0 else t
+    except Exception:  # noqa: BLE001 - no /proc, no such clock
+        return t
+
+
 def worker_main(argv=None) -> None:
+    _t_boot = _process_start()
     # SIGUSR1 -> all-thread dump to stderr (lands in the worker log file);
     # the debugging hook for wedged workers (reference: ray stack)
     import faulthandler
@@ -1352,6 +1375,7 @@ def worker_main(argv=None) -> None:
     tag, payload = channel.recv()
     assert tag == "init", tag
     runtime = WorkerRuntime(channel, payload[0])
+    _sp_boot.end(_t_boot)  # registered with the node, its Config adopted
     object_ref_mod.set_runtime(runtime)
     from . import runtime as runtime_mod
 

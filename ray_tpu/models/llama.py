@@ -165,6 +165,15 @@ _sp_decode_readback = _fr.register_span("engine.decode_readback",
 # engine.decode_upload's stretch of the call, microseconds
 _sp_window_slots = _fr.register_span("engine.window_slots",
                                      tag_keys=("pages",), floor_exempt=True)
+# the engine's constructor, one record an engine (``timeline --attribute``'s
+# set-up block): engine.build is entry to return; inside it engine.weights
+# (the ``serving_params`` jit or conversion, to the tree ready on the device)
+# and one engine.stores a store kind of ``served_stores`` (that kind's page,
+# slot or state arrays, to ready). What is left of engine.build is the pool,
+# the gauges and the copy programs.
+_sp_engine_build = _fr.register_span("engine.build")
+_sp_engine_weights = _fr.register_span("engine.weights")
+_sp_engine_stores = _fr.register_span("engine.stores", tag_keys=("kind",))
 
 
 # What a train step may KEEP of its forward pass where it would otherwise
@@ -3221,7 +3230,10 @@ class LlamaDecodeEngine:
         import numpy as np
 
         from ray_tpu.serve.kv_cache import PagePool, PrefixCache
+        from ray_tpu.util.device_telemetry import backend_devices
 
+        _t_build = _fr.now()
+        backend_devices()  # a replica's first touch: jax.backend_init
         self.cfg = cfg or LlamaConfig.debug()
         _no_latent_kinds(
             self.cfg, "LlamaDecodeEngine",
@@ -3246,6 +3258,7 @@ class LlamaDecodeEngine:
                 f"halves, a part or a mix of families, whole-projection "
                 f"QK-norm or an unpatterned routed block no test compares "
                 f"its logits with the reference")
+        _t = _fr.now()
         if params is None:
             # one jitted program, not a dozen eager ones: at 664.6M
             # parameters the eager form spends 67 s on a v5e, nearly all of
@@ -3256,6 +3269,9 @@ class LlamaDecodeEngine:
                     jax.random.PRNGKey(seed))
         else:
             params = serving_params(self.cfg, params)
+        if _t:  # recorder on: the span closes when the tree is on the device
+            jax.block_until_ready(params)
+        _sp_engine_weights.end(_t)
         self.params = params
         # both tags always: a float32 engine reads bfloat16 = 0, and not
         # what an earlier engine of this process left there
@@ -3286,9 +3302,15 @@ class LlamaDecodeEngine:
         self._free_slots = list(range(self.n_slots - 1, -1, -1))
         if self.n_slots:
             self.pool.release_hooks.append(self._free_slots_of)
-        self.stores = tuple(
-            jnp.zeros(s.shape(n_pages, self.n_slots, page_size), jnp.float32)
-            for s in layout)
+        stores = []
+        for kind, its in _by_kind(layout, layout).items():
+            _t = _fr.now()
+            stores += [jnp.zeros(s.shape(n_pages, self.n_slots, page_size),
+                                 jnp.float32) for s in its]
+            if _t:  # as the weights' span
+                jax.block_until_ready(stores)
+            _sp_engine_stores.end(_t, kind)
+        self.stores = tuple(stores)
         # every tag of the table always, as above: a row a position under
         # page_bytes, a row a sequence under state_bytes
         held = {(tag, TABLES[table].rows is None): 0
@@ -3336,6 +3358,7 @@ class LlamaDecodeEngine:
         # first prefix hit
         for ids in self._by_ids:
             self._copy(ids, 0, 0)
+        _sp_engine_build.end(_t_build)
 
     # ---- window slots (a stack with window layers; else n_slots is 0)
 

@@ -16,6 +16,12 @@ from .config import HTTPOptions
 from .deployment import Application, Deployment
 from .handle import DeploymentHandle
 from .proxy import HTTPProxy
+from ray_tpu.util import flight_recorder as _fr
+
+# ``run()`` entry to the handle returned (controller up, the ingress
+# deployment's replicas constructed): one record a ``run()``
+# (``timeline --attribute``'s set-up block)
+_sp_deploy = _fr.register_span("serve.deploy")
 
 _controller = None
 _proxy: Optional[HTTPProxy] = None
@@ -259,6 +265,7 @@ def run(target, *,
     to its ingress."""
     from .dag import DAGNode
 
+    _t_deploy = _fr.now()
     start()
     if isinstance(target, DAGNode):
         ingress = _deploy_graph(target, route_prefix,
@@ -271,6 +278,7 @@ def run(target, *,
             break
         time.sleep(0.05)
     handle = DeploymentHandle(_controller, ingress)
+    _sp_deploy.end(_t_deploy)
     if blocking:  # pragma: no cover - interactive use
         try:
             while True:

@@ -59,6 +59,11 @@ from ray_tpu.util import flight_recorder as _fr
 
 _sp_dispatch = _fr.register_span("serve.dispatch",
                                  tag_keys=("deployment",))
+# one replica's lane compiled (the DAG's rings laid, the replica's
+# ``__compiled_setup__`` answered), in the process that dispatches: one record
+# a lane. The first dispatch of a deployment pays it inline; until then its
+# streams ride the eager generator
+_sp_lane_build = _fr.register_span("dag.lane_build")
 
 logger = logging.getLogger("ray_tpu.serve")
 
@@ -644,10 +649,12 @@ class CompiledRouter:
                         continue
                 if not _actor_alive(actor):
                     continue  # record not up yet: retry next dispatch
+                _t_build = _fr.now()
                 try:
                     lane = lane_cls(actor, key, self._name,
                                     self._window(),
                                     cfg.serve_channel_slot_bytes)
+                    _sp_lane_build.end(_t_build)
                 except Exception as e:  # noqa: BLE001
                     # lane build failure must never fail the request
                     # — eager carries it; retry after a cooldown

@@ -100,6 +100,14 @@ _sp_fetch = _fr.register_span("spmd.fetch", tag_keys=("step",),
                               floor_exempt=True)
 _sp_report = _fr.register_span("spmd.report", tag_keys=("step",),
                                floor_exempt=True)
+# The loop's set-up, one record a loop each (``timeline --attribute``'s set-up
+# block): build is ``make_spmd_train_step`` (shardings, the jitted init and
+# step; nothing compiles yet: the step's compiles, ``_KeepingStep``'s second
+# among them, lie under spmd.compile), init_state is ``init(PRNGKey)`` to the
+# state ready on the device (its program's trace, lowering and load or
+# compile nest inside as jax.* spans).
+_sp_build = _fr.register_span("spmd.build")
+_sp_init_state = _fr.register_span("spmd.init_state")
 # a routed model's router, one instant a report (``timeline --attribute``
 # prints them): the two router losses, the heaviest expert's load over the
 # mean, and the assignments no expert computed (0: the routing is dropless)
@@ -1030,13 +1038,12 @@ def build_train_mesh(spec: str = "", devices=None):
     ``RAY_TPU_TRAIN_MESH`` knob / config key) or pure data-parallel
     over all local devices when empty. The same empty spec therefore
     runs devices=1 and devices=N unchanged."""
-    import jax
-
     from ray_tpu.parallel import make_mesh
-
     from ray_tpu.parallel.mesh import AXIS_ORDER
+    from ray_tpu.util.device_telemetry import backend_devices
 
-    devs = list(devices) if devices is not None else jax.devices()
+    # often a process's first touch of its backend: jax.backend_init
+    devs = list(devices) if devices is not None else backend_devices()
     axes = parse_mesh_spec(spec)
     unknown = [k for k in axes if k not in AXIS_ORDER]
     if unknown:
@@ -1143,9 +1150,15 @@ def spmd_train_loop(config: Optional[Dict[str, Any]] = None):
     if "lr" in config:
         optimizer = optax.adamw(float(config["lr"]), b1=0.9, b2=0.95,
                                 weight_decay=0.1)
+    _t = _fr.now()
     init, step_fn, data_sharding, _ = make_spmd_train_step(
         cfg, mesh, optimizer=optimizer, donate=donate, gather=gather)
+    _sp_build.end(_t)
+    _t = _fr.now()
     state = init(jax.random.PRNGKey(seed))
+    if _t:  # recorder on: the span closes when the state is on the device
+        jax.block_until_ready(state)
+    _sp_init_state.end(_t)
 
     try:
         shard = session.get_dataset_shard("train")

@@ -26,6 +26,12 @@ from ray_tpu.train.config import (
     RunConfig,
     ScalingConfig,
 )
+from ray_tpu.util import flight_recorder as _fr
+
+# ``fit()`` entry to the worker group up (placement group, every train
+# worker's process started and answering): one record a ``fit()``, its first
+# attempt's (``timeline --attribute``'s set-up block)
+_sp_place = _fr.register_span("trainer.place")
 
 
 @dataclass
@@ -119,6 +125,7 @@ class JaxTrainer:
         return shards
 
     def fit(self) -> Result:
+        _t_place = _fr.now()
         run_dir = self.run_config.resolved_storage_path()
         os.makedirs(run_dir, exist_ok=True)
         ckpt_mgr = _CheckpointManager(self.run_config.checkpoint_config, run_dir)
@@ -158,6 +165,8 @@ class JaxTrainer:
                                        run_dir)
             try:
                 executor.start(ckpt_mgr.latest(), self._dataset_shards())
+                _sp_place.end(_t_place)
+                _t_place = 0.0  # a restart's placement is recovery's
                 error = executor.run(self.train_loop, self.config, on_report)
             except ray_tpu.RayTpuError as e:
                 error = f"worker group failure: {e}"

@@ -9,6 +9,12 @@ process (worker / node daemon / driver):
   ``ray_tpu_jax_event_duration_seconds`` histograms from ``jax.monitoring``
   listeners (JIT compilations, compilation-cache hits/misses, ...).
 
+The listener of durations also records four of them as flight-recorder
+spans (``jax.trace``, ``jax.lower``, ``jax.cache_load``,
+``jax.backend_compile``), and this module stamps ``jax.import`` and
+``jax.backend_init``: a process's set-up, which ``python -m ray_tpu timeline
+--attribute`` cuts by them.
+
 Everything feeds the existing worker->head metrics channel (the local
 registry flushed by ``start_report_thread``), so the head's /metrics and
 /api/metrics/history expose cluster-wide device state with zero new wires.
@@ -29,11 +35,11 @@ the first train step's JIT, typically — would otherwise never be counted.
 
 from __future__ import annotations
 
-import os
 import sys
 import threading
 from typing import List, Optional
 
+from ray_tpu.util import flight_recorder as _fr
 from ray_tpu.util.metrics import Counter, Gauge, Histogram, registry
 
 _BYTES_IN_USE = Gauge("ray_tpu_device_bytes_in_use",
@@ -46,6 +52,28 @@ _JAX_DURATIONS = Histogram(
     "ray_tpu_jax_event_duration_seconds",
     "jax.monitoring event durations (e.g. JIT compile time)",
     boundaries=[0.01, 0.1, 1, 10, 60])
+
+# Set-up's spans that this module stamps, one record a process or a compiled
+# program (``timeline --attribute`` cuts a process's set-up by them). The four
+# duration events are spans too: jax tells the listener a duration when the
+# work has ENDED, so the span ends at the listener's call and began
+# ``duration`` before it. They nest inside ``xla.compile{program}`` /
+# ``spmd.compile{step}``, which is how a trace, a lowering or a load is put
+# down to its program. ``backend_compile_duration`` covers a load from the
+# persistent cache too (``compile_or_get_cached`` runs inside it), so
+# ``jax.cache_load`` nests in ``jax.backend_compile``.
+_sp_jax_import = _fr.register_span("jax.import")
+_sp_backend_init = _fr.register_span("jax.backend_init")
+_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        _fr.register_span("jax.trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        _fr.register_span("jax.lower"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        _fr.register_span("jax.cache_load"),
+    "/jax/core/compile/backend_compile_duration":
+        _fr.register_span("jax.backend_compile"),
+}
 
 _listener_lock = threading.Lock()
 _listeners_installed = False
@@ -79,6 +107,11 @@ def _on_jax_event_duration(event: str, duration: float,
                            *args, **kwargs) -> None:
     try:
         _JAX_DURATIONS.observe(float(duration), tags=_event_tags(event))
+        span = _DURATION_SPANS.get(event)
+        if span is not None:
+            t1 = _fr.now()
+            if t1:
+                span.end_at(t1 - float(duration), float(duration))
     except Exception:
         pass
 
@@ -140,10 +173,12 @@ class _ListenerInstallingLoader:
         return self._loader.create_module(spec)
 
     def exec_module(self, module):
+        _t = _fr.now()
         try:
             self._loader.exec_module(module)
         finally:
             _unobserve_jax_import()
+        _sp_jax_import.end(_t)
         install_jax_listeners(import_just_finished=True)
 
 
@@ -243,6 +278,21 @@ def jax_with_backend():
     return jax if bridge.backends_are_initialized() else None
 
 
+def backend_devices() -> List:
+    """``jax.devices()`` for code that may be the first in its process to
+    touch a backend (the train loop's mesh, the decode engine): the call
+    that brings the backend up lies under ``jax.backend_init``. On libtpu
+    that call takes the chips and holds the GIL for most of its seconds."""
+    import jax
+
+    if jax_with_backend() is not None:
+        return jax.devices()
+    _t = _fr.now()
+    devices = jax.devices()
+    _sp_backend_init.end(_t)
+    return devices
+
+
 def collect_once(node_hex: str = "") -> int:
     """One collection tick: install listeners if jax showed up, then read
     the memory stats of the devices this process already holds. A no-op
@@ -265,8 +315,8 @@ _EV_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 def process_device_report() -> dict:
     """What THIS process ran on and what compiling cost it so far: the
-    platform, device kind and device count as JAX reports them, the JAX
-    and libtpu versions, per-device memory stats, and the persistent
+    platform, device kind and device count as JAX reports them,
+    per-device memory stats, and the persistent
     compile-cache hits / misses and backend compile seconds the
     ``jax.monitoring`` listeners have counted. For a process that has
     already initialised its backend (it raises otherwise, rather than be
@@ -276,14 +326,7 @@ def process_device_report() -> dict:
         raise RuntimeError(
             "process_device_report() is for a process whose JAX backend is "
             "already initialised; it will not initialise one")
-    import importlib.metadata as md
-
     devices = jax.local_devices()
-    try:
-        libtpu = md.version("libtpu")
-    except md.PackageNotFoundError:
-        libtpu = None
-
     reg = registry()
 
     def of_event(metric: str, event: str) -> list:
@@ -305,9 +348,6 @@ def process_device_report() -> dict:
         "platform": devices[0].platform,
         "device_kind": devices[0].device_kind,
         "devices": len(devices),
-        "jax": jax.__version__,
-        "libtpu": libtpu,
-        "pid": os.getpid(),
         "cache_hits": event_count(_EV_CACHE_HIT),
         "cache_misses": event_count(_EV_CACHE_MISS),
         "compile_s": round(compile_s, 3),

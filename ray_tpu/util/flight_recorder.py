@@ -10,9 +10,14 @@ the seq stamped inside the record. Instrumented seams: ring-channel
 waits (``experimental/channel.py``, ``core/net_ring.py``), compiled-DAG
 driver dispatch and executor loops (``dag/__init__.py``,
 ``core/worker_runtime.py``), per-microbatch pipeline spans
-(``train/pipeline.py``), SPMD step phases (``train/spmd.py``), and the
+(``train/pipeline.py``), SPMD step phases (``train/spmd.py``), the
 serve compiled lane (``serve/compiled_dispatch.py``,
-``serve/replica.py``).
+``serve/replica.py``), and a process's SET-UP, one record a process or a
+compiled program (:data:`SETUP_SPANS`: the driver's ``init`` / ``fit`` /
+``serve.run``, a worker's boot, the jax import, the backend's bring-up, the
+loop's and the engine's builds, and a compile's trace / lower / cache load /
+backend compile from ``util/device_telemetry.py``'s duration listener),
+which :func:`attribute_trace` cuts by innermost span.
 
 Cross-host merge: timestamps are process-local ``time.monotonic()``
 plus a per-process ``(anchor_mono, anchor_wall)`` pair captured at
@@ -55,11 +60,13 @@ from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = [
     "ClockOffsetEstimator",
+    "SETUP_SPANS",
     "attribute_trace",
     "build_span_events",
     "cluster_span_payloads",
     "cluster_trace",
     "configure",
+    "cut_innermost",
     "drain",
     "dump",
     "enabled",
@@ -640,6 +647,163 @@ def _slowest_spmd_step(by_name: Dict[str, List[dict]],
     return out
 
 
+# A process's way from its start to its first productive span, once a process
+# or once a compiled program each. The four jax.* are the duration listener's
+# (util/device_telemetry.py); the two engine.* carry a warm-up call's first
+# run around its ``xla.compile``.
+_RUN_CARRIERS = ("engine.prefill_program", "engine.decode_program")
+SETUP_SPANS = (
+    "runtime.init", "trainer.place", "serve.deploy", "dag.lane_build",
+    "worker.boot", "jax.import", "jax.backend_init", "spmd.build",
+    "spmd.init_state", "engine.build", "engine.weights", "engine.stores",
+    "spmd.compile", "xla.compile", *_RUN_CARRIERS,
+    "jax.trace", "jax.lower", "jax.cache_load", "jax.backend_compile")
+# what the block calls a span whose line is what the spans inside it leave
+_SETUP_LABELS = {
+    "engine.build": "engine.build (rest)",
+    "xla.compile": "xla.compile (rest)",
+    "spmd.compile": "first step's run",
+    "engine.prefill_program": "first runs: prefill",
+    "engine.decode_program": "first runs: decode",
+    "jax.backend_compile": "compiled (less loads)",
+}
+# a process that records none of these brought nothing up: no block
+_SETUP_ONLY = frozenset(SETUP_SPANS) - {"spmd.compile", "xla.compile",
+                                        *_RUN_CARRIERS}
+# where set-up ends: the start of the first of these (a train step that did
+# not compile, a request's prefill), else of the first request in the lane
+_PRODUCTIVE = (("spmd.compute", "serve.prefill"), ("dag.stream_ingress",))
+# a program's row: the span whose cut seconds go to which column
+_PROGRAM_COLUMNS = {
+    "jax.trace": "trace_s", "jax.lower": "lower_s",
+    "jax.cache_load": "load_s", "jax.backend_compile": "compiled_s",
+    "spmd.compile": "first_run_s",
+    **{name: "first_run_s" for name in _RUN_CARRIERS}}
+
+
+def cut_innermost(spans, lo: float, hi: float) -> Dict[str, float]:
+    """``[lo, hi]`` cut by INNERMOST span: every instant goes to the span
+    covering it that started last (of two that started together, the one
+    that ends first), an instant nobody spans to ``"unattributed"``.
+    ``spans`` are ``(name, start, end)`` on one clock, in any unit; the
+    result, ``{name: length}``, sums to ``hi - lo``."""
+    import heapq
+
+    todo = sorted(((max(s, lo), min(e, hi), name) for name, s, e in spans
+                   if e > lo and s < hi and e > s), key=lambda t: t[:2])
+    edges = sorted({lo, hi, *(s for s, _e, _n in todo),
+                    *(e for _s, e, _n in todo)})
+    out: Dict[str, float] = {}
+    open_: List[tuple] = []  # (-start, end, k): the top is the innermost
+    k = 0
+    for a, b in zip(edges, edges[1:]):
+        while k < len(todo) and todo[k][0] <= a:
+            heapq.heappush(open_, (-todo[k][0], todo[k][1], k))
+            k += 1
+        while open_ and open_[0][1] <= a:
+            heapq.heappop(open_)
+        name = todo[open_[0][2]][2] if open_ else "unattributed"
+        out[name] = out.get(name, 0.0) + (b - a)
+    return out
+
+
+def _setup_attribution(events: List[Dict[str, Any]]
+                       ) -> Dict[str, Dict[str, Any]]:
+    """Per source process that recorded a set-up span: the stretch from its
+    first record to its first productive span (none: to its last set-up
+    span's end), cut by innermost span of :data:`SETUP_SPANS` into seconds
+    that sum to the stretch (``parts_s``), and the same cut by compiled
+    program (``programs``: how many, and the seconds of tracing, lowering,
+    loading from the persistent cache, compiling and of the first run).
+
+    The listener that records the ``jax.*`` spans is told no program, so
+    each is put down to the tightest ``xla.compile`` it lies in (its
+    ``program``), else to the ``spmd.compile`` it lies in (``"first
+    step"``), else to ``"other"`` (the weights' jit, a store's zeros, a
+    caller's own programs). ``compiled_s`` is ``jax.backend_compile`` less
+    the loads nested in it (in this jax a load runs inside it); a trace
+    nested in a trace (a jit called under a jit) counts once. A first run is
+    what the call AROUND a compile leaves when everything inside it is taken
+    out: ``spmd.compile``, or the engine's call around an ``xla.compile``."""
+    by_source: Dict[Any, List[dict]] = {}
+    for ev in events:
+        if ev.get("ph") == "X" and ev.get("cat") == "span":
+            by_source.setdefault((ev.get("args") or {}).get("source"),
+                                 []).append(ev)
+    slack = 2e3  # us: a jax.* span's start is its end less a duration
+
+    def end(ev) -> float:
+        return ev["ts"] + ev.get("dur", 0.0)
+
+    def tightest(ev, outers):
+        return min((o for o in outers if o["ts"] - slack <= ev["ts"]
+                    and end(ev) <= end(o) + slack),
+                   key=lambda o: o.get("dur", 0.0), default=None)
+
+    def program_of(ev) -> str:
+        return str((ev.get("args") or {}).get("program", "?"))
+
+    out: Dict[str, Dict[str, Any]] = {}
+    for source, evs in by_source.items():
+        if not any(ev["name"] in _SETUP_ONLY for ev in evs):
+            continue
+        lo = min(ev["ts"] for ev in evs)
+        hi, until = None, None
+        for names in _PRODUCTIVE:
+            first = min((ev for ev in evs if ev["name"] in names),
+                        key=lambda ev: ev["ts"], default=None)
+            if first is not None:
+                hi, until = first["ts"], first["name"]
+                break
+        if hi is None:
+            hi = max(end(ev) for ev in evs if ev["name"] in _SETUP_ONLY)
+        inside = [ev for ev in evs if ev["name"] in SETUP_SPANS
+                  and ev["ts"] < hi and end(ev) > lo]
+        compiles = [ev for ev in inside if ev["name"] == "xla.compile"]
+        steps = [ev for ev in inside if ev["name"] == "spmd.compile"]
+        rows: Dict[str, Dict[str, float]] = {}
+
+        def row(program: str) -> Dict[str, float]:
+            return rows.setdefault(program, dict(
+                {"programs": 0},
+                **{key: 0.0 for key in _PROGRAM_COLUMNS.values()}))
+
+        spans = []  # ((span name, its program or None), start, end)
+        for ev in inside:
+            name, program = ev["name"], None
+            if name == "xla.compile":
+                row(program_of(ev))["programs"] += 1
+            elif name.startswith("jax."):
+                holder = tightest(ev, compiles)
+                program = (program_of(holder) if holder is not None else
+                           "first step" if tightest(ev, steps) is not None
+                           else "other")
+                if program == "other" and name == "jax.backend_compile":
+                    row(program)["programs"] += 1
+            elif name in _PROGRAM_COLUMNS:  # a call around a compile
+                held = [c for c in compiles if tightest(c, [ev]) is not None]
+                if held:
+                    program = program_of(held[0])
+                elif name == "spmd.compile":  # the observatory is off
+                    program = "first step"
+                    row(program)["programs"] += 1
+            spans.append(((name, program), ev["ts"], end(ev)))
+        parts: Dict[str, float] = {}
+        for key, v in cut_innermost(spans, lo, hi).items():
+            name, program = key if isinstance(key, tuple) else (key, None)
+            parts[name] = parts.get(name, 0.0) + v / 1e6
+            if program is not None and name in _PROGRAM_COLUMNS:
+                row(program)[_PROGRAM_COLUMNS[name]] += v / 1e6
+        out[str(source)] = {
+            "stretch_s": round((hi - lo) / 1e6, 6), "until": until,
+            "parts_s": {name: round(v, 6) for name, v in parts.items()},
+            "programs": {
+                program: {key: int(v) if key == "programs" else round(v, 6)
+                          for key, v in rec.items()}
+                for program, rec in sorted(rows.items())}}
+    return out
+
+
 def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Fold a merged trace into a per-step budget: where did the step
     time go. Pipeline busy/bubble mirrors ``pipeline_stats()`` exactly
@@ -724,6 +888,9 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
     serving = _serving_attribution(by_name)
     if serving:
         report["serving"] = serving
+    setup = _setup_attribution(events)
+    if setup:
+        report["setup"] = setup
     # a routed model's router (train/spmd.py: one instant a report)
     instants = [ev for ev in events if ev.get("ph") == "i"
                 and str(ev.get("name", "")).startswith(("moe.", "mtp."))]
@@ -742,7 +909,14 @@ def attribute_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
 
 
 def format_attribution(report: Dict[str, Any]) -> str:
-    """Human-readable ``timeline --attribute`` rendering."""
+    """Human-readable ``timeline --attribute`` rendering: the step budget,
+    a block a decode deployment ("where did the time to first token go"),
+    a block a process that brought something up ("set-up : N s on
+    <source>": the stretch to its first productive span cut by innermost
+    span, a line a part, largest first, then a line a program), the
+    router's scalars. The step budget's "compile (1st step)" and "xla"
+    rows are totals over the whole trace; a set-up block's lines are a cut
+    of its stretch, each second in one line."""
     lines = ["where did my step time go", "-" * 26]
     if report.get("steps"):
         lines.append(f"steps observed     : {report['steps']} "
@@ -804,6 +978,20 @@ def format_attribution(report: Dict[str, Any]) -> str:
                 f"{'decode steps':<24}: {rec['decode_steps']}, "
                 f"{rec['decode_tokens'] / rec['decode_steps']:.2f} "
                 f"tokens a step")
+    for source, rec in sorted((report.get("setup") or {}).items()):
+        head = (f"set-up : {rec['stretch_s']:.3f} s on {source}"
+                + (f" (to its first {rec['until']})" if rec.get("until")
+                   else " (to its last set-up span's end)"))
+        lines += ["", head, "-" * len(head)]
+        for name, v in sorted(rec["parts_s"].items(),
+                              key=lambda kv: -kv[1]):
+            lines.append(f"{_SETUP_LABELS.get(name, name):<24}: {v:10.3f} s")
+        for program, row in rec["programs"].items():
+            lines.append(
+                f"  {program:<22}: {row['programs']} program(s), trace "
+                f"{row['trace_s']:.3f} lower {row['lower_s']:.3f} load "
+                f"{row['load_s']:.3f} compiled {row['compiled_s']:.3f} "
+                f"first run {row['first_run_s']:.3f} s")
     if report.get("router"):
         lines += ["", "the router (routed experts)", "-" * 27]
         for name, rec in report["router"].items():

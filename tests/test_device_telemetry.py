@@ -1,6 +1,8 @@
 """TPU/JAX device telemetry: memory_stats gauges + jax.monitoring
 listeners feeding the metrics registry."""
 
+import pytest
+
 import ray_tpu
 from ray_tpu.util import device_telemetry
 from ray_tpu.util.metrics import registry
@@ -95,6 +97,114 @@ def test_jit_compilation_is_counted_via_monitoring():
 
     f(jnp.arange(7)).block_until_ready()
     assert _total_jax_events() > before
+
+
+_FOUR = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
+}
+
+
+def _ring_spans():
+    from ray_tpu.util import flight_recorder as fr
+
+    payload = fr.snapshot_payload()
+    names = {int(sid): d["name"] for sid, d in payload["names"].items()}
+    return [(names[int(sid)], t0, dur)
+            for _seq, sid, kind, t0, dur, _tags in payload["events"]
+            if kind == fr.KIND_SPAN]
+
+
+@pytest.fixture()
+def recorder_on():
+    from ray_tpu.util import flight_recorder as fr
+
+    was = fr._on[0]
+    fr.configure(enabled=True)
+    fr.reset_for_tests()
+    yield fr
+    fr.reset_for_tests()
+    fr._on[0] = was
+
+
+@pytest.mark.parametrize("event,span", sorted(_FOUR.items()))
+def test_duration_listener_records_a_span_that_ends_at_the_call(
+        recorder_on, event, span):
+    """jax tells the listener a duration once the work has ended: the span
+    ends at the listener's call and began ``duration`` before it. The
+    histogram and ``compile_s`` keep reading what they read."""
+    import time
+
+    import jax
+
+    def histogram():  # over every series of the event (a node tag or none)
+        hv = registry().snapshot().get(
+            "ray_tpu_jax_event_duration_seconds", {"values": {}})["values"]
+        found = [v for k, v in hv.items() if ("event", event) in k]
+        return (sum(v["count"] for v in found), sum(v["sum"] for v in found))
+
+    jax.devices()  # process_device_report() is for a process with a backend
+    before = device_telemetry.process_device_report()["compile_s"]
+    count0, sum0 = histogram()
+    lo = time.monotonic()
+    device_telemetry._on_jax_event_duration(event, 1.25, fun_name="f")
+    hi = time.monotonic()
+    (name, t0, dur), = _ring_spans()
+    assert name == span and dur == 1.25
+    assert lo <= t0 + dur <= hi
+    count1, sum1 = histogram()
+    assert count1 - count0 == 1 and sum1 - sum0 == pytest.approx(1.25)
+    after = device_telemetry.process_device_report()["compile_s"]
+    moved = 1.25 if span == "jax.backend_compile" else 0.0
+    assert after - before == pytest.approx(moved, abs=2e-3)
+
+
+def test_duration_listener_records_no_span_for_another_event(recorder_on):
+    device_telemetry._on_jax_event_duration(
+        "/jax/compilation_cache/compile_time_saved_sec", 3.0)
+    device_telemetry._on_jax_event_duration("/raytpu/test/duration", 3.0)
+    assert _ring_spans() == []
+    recorder_on.configure(enabled=False)
+    try:  # recorder off: the histogram still counts, the ring stays empty
+        device_telemetry._on_jax_event_duration(
+            "/jax/core/compile/backend_compile_duration", 0.5)
+        assert recorder_on.snapshot_payload()["events"] == []
+    finally:
+        recorder_on.configure(enabled=True)
+
+
+def test_a_real_compile_nests_its_jax_spans(recorder_on):
+    """A jit's first call: trace, lower and backend compile as spans in
+    that order, each ending before the next one ends, from the listeners
+    the import hook installs."""
+    import jax
+    import jax.numpy as jnp
+
+    assert device_telemetry.install_jax_listeners()
+    recorder_on.configure(min_span_us=0)
+    try:
+        jax.jit(lambda x: jnp.tanh(x) @ x.T + 3)(
+            jnp.ones((5, 7))).block_until_ready()
+    finally:
+        recorder_on.configure(
+            min_span_us=ray_tpu.core.config.global_config()
+            .flight_recorder_min_span_us)
+    ends = {}
+    for name, t0, dur in _ring_spans():
+        ends.setdefault(name, t0 + dur)
+    assert {"jax.trace", "jax.lower", "jax.backend_compile"} <= set(ends)
+    assert ends["jax.trace"] <= ends["jax.lower"] <= \
+        ends["jax.backend_compile"]
+
+
+def test_backend_devices_spans_only_the_first_touch(recorder_on):
+    import jax
+
+    jax.devices()  # the backend is up: this call brings nothing up
+    assert device_telemetry.backend_devices() == jax.devices()
+    assert _ring_spans() == []
 
 
 def _total_jax_events() -> float:

@@ -493,6 +493,205 @@ def test_train_loop_metric_files_read_their_spans(metric, span):
 
 
 # --------------------------------------------------------------------------- #
+# Set-up taken apart: a process's way to its first productive span
+# --------------------------------------------------------------------------- #
+
+
+def _setup_trace():
+    """A driver and a train worker (seconds): nested ``jax.*`` inside
+    ``xla.compile`` inside ``spmd.compile``, a load inside a compile, three
+    gaps nobody spans, a second loop's build after the first compute."""
+
+    def ev(source, name, t0, t1, **args):
+        return {"ph": "X", "cat": "span", "name": name, "ts": t0 * 1e6,
+                "dur": (t1 - t0) * 1e6, "pid": "n", "tid": name,
+                "args": dict(args, source=source)}
+
+    d, w = "head:driver", "ab12cd:77"
+    return d, w, [
+        ev(d, "runtime.init", 0.0, 0.1), ev(d, "trainer.place", 0.1, 2.1),
+        ev(w, "worker.boot", 0.5, 1.5), ev(w, "jax.import", 2.0, 3.0),
+        ev(w, "jax.backend_init", 3.5, 6.5), ev(w, "spmd.build", 6.5, 6.6),
+        ev(w, "spmd.init_state", 6.6, 7.6), ev(w, "jax.trace", 6.6, 6.7),
+        ev(w, "jax.backend_compile", 6.8, 7.5),
+        ev(w, "jax.cache_load", 6.9, 7.4),
+        ev(w, "spmd.compile", 8.0, 12.0, step=1),
+        ev(w, "xla.compile", 8.0, 11.0, program="spmd.train_step"),
+        ev(w, "jax.trace", 8.0, 8.5), ev(w, "jax.lower", 8.5, 9.0),
+        ev(w, "jax.trace", 8.1, 8.3),  # a jit traced under a jit: once
+        ev(w, "jax.backend_compile", 9.0, 11.0),
+        ev(w, "jax.cache_load", 9.1, 10.9),
+        ev(w, "ring.wait_read", 2.0, 11.0, channel="c", role="r"),
+        ev(w, "spmd.compute", 12.0, 12.5, step=2),
+        ev(w, "spmd.build", 20.0, 20.1), ev(w, "spmd.compute", 21.0, 21.5),
+    ]
+
+
+def test_setup_block_cuts_the_stretch_by_innermost_span():
+    d, w, events = _setup_trace()
+    rep = fr.attribute_trace(events)["setup"]
+    assert set(rep) == {d, w}
+    # the driver has no productive span: to its last set-up span's end
+    assert rep[d]["until"] is None and rep[d]["stretch_s"] == 2.1
+    assert rep[d]["parts_s"] == {"runtime.init": 0.1, "trainer.place": 2.0}
+    got = rep[w]
+    assert got["until"] == "spmd.compute" and got["stretch_s"] == 11.5
+    want = {"worker.boot": 1.0, "jax.import": 1.0, "jax.backend_init": 3.0,
+            "spmd.build": 0.1, "spmd.init_state": 0.2, "jax.trace": 0.6,
+            "jax.lower": 0.5, "jax.cache_load": 2.3,
+            # innermost wins: a compile less the load nested in it, the
+            # first step less everything inside it, xla.compile all given away
+            "jax.backend_compile": 0.4, "spmd.compile": 1.0,
+            "xla.compile": 0.0,
+            # 1.5-2.0, 3.0-3.5, 7.6-8.0: nobody's (a ring wait is no part)
+            "unattributed": 1.4}
+    assert {k: v for k, v in got["parts_s"].items() if v} == pytest.approx(
+        {k: v for k, v in want.items() if v})
+    assert sum(got["parts_s"].values()) == pytest.approx(got["stretch_s"],
+                                                         abs=1e-3)
+    assert got["programs"] == {
+        "other": {"programs": 1, "first_run_s": 0.0, "trace_s": 0.1,
+                  "lower_s": 0.0, "load_s": 0.5, "compiled_s": 0.2},
+        "spmd.train_step": {"programs": 1, "first_run_s": 1.0,
+                            "trace_s": 0.5, "lower_s": 0.5, "load_s": 1.8,
+                            "compiled_s": 0.2}}
+
+
+def test_setup_block_is_printed_a_line_a_part_largest_first():
+    d, w, events = _setup_trace()
+    text = fr.format_attribution(fr.attribute_trace(events))
+    block = text[text.index(f"set-up : 11.500 s on {w}"):]
+    block = block[:block.index("\n\n")] if "\n\n" in block else block
+    lines = block.splitlines()
+    assert "(to its first spmd.compute)" in lines[0]
+    parts = [ln for ln in lines[2:] if not ln.startswith("  ")]
+    seconds = [float(ln.split(":")[1].split()[0]) for ln in parts]
+    assert seconds == sorted(seconds, reverse=True)
+    assert sum(seconds) == pytest.approx(11.5, abs=1e-3)
+    assert parts[0].startswith("jax.backend_init") and seconds[0] == 3.0
+    assert any(ln.startswith("unattributed") for ln in parts)
+    assert any("spmd.train_step" in ln and "load 1.800" in ln
+               and "first run 1.000" in ln for ln in lines)
+    assert f"set-up : 2.100 s on {d}" in text
+    # the step budget's own compile row is still there, once
+    assert text.count("compile (1st step)") == 1
+
+
+def test_setup_block_of_a_replica_and_none_without_setup_spans():
+    """A replica: the weights and the stores inside engine.build, a warm-up
+    call's first run around its xla.compile, the stretch to the first
+    request's prefill. A trace with no set-up span prints no block."""
+
+    def ev(name, t0, t1, **args):
+        return {"ph": "X", "cat": "span", "name": name, "ts": t0 * 1e6,
+                "dur": (t1 - t0) * 1e6, "pid": "n", "tid": name,
+                "args": dict(args, source="r:1")}
+
+    events = [
+        ev("worker.boot", 0.0, 1.0), ev("engine.build", 1.0, 9.0),
+        ev("jax.backend_init", 1.0, 4.0), ev("engine.weights", 4.0, 7.0),
+        ev("engine.stores", 7.0, 7.5, kind="b"),
+        ev("engine.stores", 7.5, 8.5, kind="W"),
+        ev("engine.prefill_program", 9.0, 12.0, pages=2),
+        ev("xla.compile", 9.0, 11.0, program="llama.prefill"),
+        ev("jax.backend_compile", 9.5, 11.0),
+        ev("engine.prefill_program", 12.0, 12.2, pages=2),  # compiled nothing
+        ev("dag.stream_ingress", 12.5, 12.6, method="m", corr=1),
+        ev("serve.prefill", 13.0, 13.5, deployment="LM", corr=1),
+    ]
+    rep = fr.attribute_trace(events)["setup"]["r:1"]
+    assert rep["until"] == "serve.prefill" and rep["stretch_s"] == 13.0
+    assert rep["parts_s"] == pytest.approx({
+        "worker.boot": 1.0, "jax.backend_init": 3.0, "engine.weights": 3.0,
+        "engine.stores": 1.5, "engine.build": 0.5, "xla.compile": 0.5,
+        "jax.backend_compile": 1.5, "engine.prefill_program": 1.2,
+        "unattributed": 0.8})
+    assert rep["programs"]["llama.prefill"] == {
+        "programs": 1, "first_run_s": 1.0, "trace_s": 0.0, "lower_s": 0.0,
+        "load_s": 0.0, "compiled_s": 1.5}
+    plain = [e for e in events if e["name"] in (
+        "serve.prefill", "dag.stream_ingress", "engine.prefill_program")]
+    assert "setup" not in fr.attribute_trace(plain)
+    assert "set-up" not in fr.format_attribution(fr.attribute_trace(plain))
+
+
+def test_cut_innermost_sums_to_the_interval():
+    cut = fr.cut_innermost(
+        [("a", 0, 10), ("b", 2, 5), ("c", 3, 4), ("d", 12, 20),
+         ("a", 2, 2.5), ("e", -5, -1)], 1, 15)
+    assert cut == {"a": 6.5, "b": 1.5, "c": 1.0, "unattributed": 2.0,
+                   "d": 3.0}
+    assert sum(cut.values()) == 14
+    assert fr.cut_innermost([], 0, 3) == {"unattributed": 3}
+
+
+@pytest.mark.parametrize("metric,counter", [
+    ("compile.cache_misses", "cache_misses"),
+    ("compile.cache_hits", "cache_hits"),
+])
+def test_compile_cache_metric_files_read_their_counters(metric, counter):
+    """The two counters of the compile plane that every cell's evidence
+    carried and no metric file read, through the benchmark's own loader and
+    reader; spelt as ``compile_s`` is (every cell: no ``workloads``)."""
+    import os
+
+    from benchmarks.lib import reducers, spec
+
+    file = spec.load_layer_metric(metric)
+    assert file["reader"] == "counter_value"
+    assert file["args"] == {"counter": counter}
+    with open(os.path.join(spec.ROOT, "BENCHMARK.json")) as f:
+        per_layer = json.load(f)["per_layer"]
+    entry, = [m for m in per_layer if m["name"] == metric]
+    like, = [m for m in per_layer if m["name"] == "compile_s"]
+    assert set(entry) == set(like) and "workloads" not in entry
+    assert (entry["unit"], entry["source"], entry["moves"], entry["layer"],
+            entry["better"]) == (file["unit"], file["source"], file["moves"],
+                                 file["layer"], "lower") == (
+        "programs", "program_counter", "setup_s", "compile plane", "lower")
+    counters = {"compile_s": 6.5, "cache_hits": 26, "cache_misses": 3}
+    assert reducers.read_metric(file, {"counters": counters}) == float(
+        counters[counter])
+    assert reducers.read_metric(file, {"counters": {}}) is None
+    assert reducers.wanted_spans([file]) == set()
+
+
+@pytest.mark.deadline(170)
+@pytest.mark.parametrize("cell,must_name", [
+    ("train-mistral7b-1chip",
+     ("runtime.init", "trainer.place", "worker.boot", "jax.import",
+      "spmd.compile", "jax.backend_compile", "harness.reference_check",
+      "harness.driver_imports")),
+    # the serving cell's run binds the serve plane's port, so it queues
+    # behind the ONE rehearsal lock, where three cells' rehearsals already
+    # run to within seconds of their deadlines (keye's 161 of 170 s in a
+    # whole run of PR 50): out of tier-1, as chip_smoke's rehearsal is
+    pytest.param(
+        "serve-internlm2-prefill-open",
+        ("runtime.init", "serve.deploy", "jax.import", "jax.backend_compile",
+         "harness.prepare.build", "harness.prepare.warm",
+         "harness.prepare.check"), marks=pytest.mark.slow),
+])
+def test_setup_parts_rehearsal_sums_to_setup_s(cell, must_name):
+    """``sweep/setup_parts.py --rehearsal`` on the smallest train and serve
+    cell: exits 0, its parts with ``unattributed_s`` sum to its ``setup_s``,
+    and both the program's spans and the harness's stamps are among them."""
+    import rehearse
+
+    out = rehearse.run_cell(cell, 5000000050, seconds=1,
+                            script="benchmarks/sweep/setup_parts.py",
+                            extra=(), serves=cell.startswith("serve-"))
+    assert out["device"]["platform"] == "cpu"
+    assert sum(out["parts_s"].values()) + out["unattributed_s"] == \
+        pytest.approx(out["setup_s"], abs=1e-3)
+    named = {name.split("@")[0] for name in out["inclusive_s"]}
+    assert set(must_name) <= named, named
+    assert out["unattributed_s"] < 0.1 * out["setup_s"]
+    worker, = out["programs"].values()  # the one process that compiled
+    assert all(row["programs"] >= 1 for row in worker.values())
+
+
+# --------------------------------------------------------------------------- #
 # Cluster plumbing: 2 separate-process daemons -> one merged trace
 # --------------------------------------------------------------------------- #
 

@@ -585,6 +585,51 @@ class TestLlamaEngine:
             assert all(inside(g, c) and g[2] == c[2] == {"pages": 2}
                        for g, c in zip(got, calls))
 
+    @pytest.mark.parametrize("pattern", ["", "FWWW"])
+    def test_the_constructor_is_taken_apart_into_spans(self, pattern):
+        """``engine.build`` is the constructor, entry to return; the
+        weights (given or made) and one ``engine.stores`` a store KIND of
+        ``served_stores`` lie inside it, in that order, none overlapping;
+        recorder off, the constructor records nothing."""
+        import dataclasses
+
+        from ray_tpu.models.llama import (LlamaConfig, LlamaDecodeEngine,
+                                          served_stores)
+        from ray_tpu.util import flight_recorder as fr
+
+        cfg = LlamaConfig.debug()
+        if pattern:  # full and window layers: two kinds, a store by slot
+            cfg = dataclasses.replace(
+                cfg, n_layers=4, layer_pattern=pattern, window=6,
+                num_experts=4, experts_per_token=2, mlp_act="reglu",
+                remat=False)
+        saved_on, saved_min = fr._on[0], fr._min_dur[0]
+        fr.configure(enabled=False)
+        fr.reset_for_tests()
+        try:
+            LlamaDecodeEngine(cfg, n_pages=16, page_size=4, seed=0)
+            assert fr.snapshot_payload()["events"] == []
+            fr.configure(enabled=True, min_span_us=0.0)
+            LlamaDecodeEngine(cfg, n_pages=16, page_size=4, seed=0)
+            events = fr.build_span_events([fr.snapshot_payload()])
+        finally:
+            fr.reset_for_tests()
+            fr._on[0], fr._min_dur[0] = saved_on, saved_min
+        spans = {}
+        for e in events:
+            spans.setdefault(e["name"], []).append(
+                (e["ts"], e["ts"] + e["dur"], e["args"].get("kind")))
+        (b0, b1, _), = spans["engine.build"]
+        (w0, w1, _), = spans["engine.weights"]
+        stores = sorted(spans["engine.stores"])
+        kinds = list(dict.fromkeys(s.kind for s in served_stores(cfg)))
+        assert [k for _, _, k in stores] == kinds
+        assert len(kinds) == (2 if pattern else 1)
+        inner = [(w0, w1)] + [(a, b) for a, b, _ in stores]
+        assert b0 - 10 <= inner[0][0] and inner[-1][1] <= b1 + 10
+        for (_, end), (start, _) in zip(inner, inner[1:]):
+            assert end <= start + 10  # microseconds
+
     # ---- the weights in the dtype they are multiplied in (PR 27)
 
     MATMUL_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")

@@ -707,8 +707,12 @@ def test_loop_records_the_hosts_phases_of_every_step(monkeypatch,
     def steps_of(name):
         return [tags[0] for _, _, tags in by_name.get(name, [])]
 
-    assert all(len(tags) == 1 for spans in by_name.values()
-               for _, _, tags in spans)  # every span: the one tag, ``step``
+    # every span of a step: the one tag, ``step`` (the loop's set-up spans
+    # and the compile listener's carry none)
+    once = {"spmd.build", "spmd.init_state", "jax.trace", "jax.lower",
+            "jax.cache_load", "jax.backend_compile", "xla.compile"}
+    assert all(len(tags) == 1 for name, spans in by_name.items()
+               if name not in once for _, _, tags in spans)
     assert steps_of("spmd.compile") == [1]
     assert steps_of("spmd.compute") == [2, 3, 4, 5]
     assert steps_of("spmd.ingest_wait") == [1, 2, 3, 4, 5]
@@ -736,8 +740,24 @@ def test_loop_records_the_hosts_phases_of_every_step(monkeypatch,
         assert lo <= t0 + dur <= hi
 
 
+def test_loop_records_its_set_up_once(monkeypatch):
+    """``spmd.build`` (``make_spmd_train_step``) and ``spmd.init_state``
+    (``init`` to the state on the device): one record a loop each, no tag,
+    build before init, both over before the first step is dispatched, and
+    the first step's compile after them."""
+    _events, _reports, by_name = _logged_loop(monkeypatch, 1, True)
+    (b0, bdur, btags), = by_name["spmd.build"]
+    (i0, idur, itags), = by_name["spmd.init_state"]
+    assert btags == () == itags
+    assert b0 + bdur <= i0
+    first_dispatch = min(t0 for t0, _, _ in by_name["spmd.dispatch"])
+    (c0, _cdur, _), = by_name["spmd.compile"]
+    assert i0 + idur <= c0 <= first_dispatch
+
+
 def test_loop_with_the_recorder_off_records_nothing(monkeypatch):
-    """Recorder off: no record, the same order of issues and reports."""
+    """Recorder off: no record (the set-up spans' sites stop at their flag
+    test as every other), the same order of issues and reports."""
     events, reports, by_name = _logged_loop(monkeypatch, 1, False)
     assert by_name == {}
     assert events == _LOOP_ORDER[1]
