@@ -343,20 +343,53 @@ def test_the_eight_held_ranges_routed_sums_add_up_to_the_uncut_layers(params):
     close(got[0], want, 2e-5)
 
 
-@pytest.mark.parametrize("seq,topk", [(256, 24), (384, 500), (128, 1)])
-def test_the_kernels_are_the_xla_path(seq, topk):
+def selection_operands(seq, planted="ties", heads=4):
+    """``(qi, ki, w)`` of one call, float32, seeded by ``seq``: random
+    normal, with what a search by counts can get wrong planted in them. A
+    score is ``sum_j w_j relu(qi_j . ki)``: a key scaled scales its scores,
+    a key repeated repeats them, a key of zeros scores zero; and with four
+    heads a key in sixteen scores exactly 0 unplanted (no head's product
+    is positive): a run of equal keys in the middle of every long row."""
+    k = jax.random.split(jax.random.PRNGKey(seq), 6)
+    qi = jax.random.normal(k[0], (1, seq, heads, 16))
+    ki = jax.random.normal(k[1], (1, seq, 16))
+    w = jax.random.normal(k[2], (1, seq, heads)) / 8
+    at = jnp.arange(seq)
+    if planted == "ties":  # two pairs of equal keys
+        ki = ki.at[0, 5].set(ki[0, 3]).at[0, 100].set(ki[0, 90])
+    elif planted == "all_equal":  # every score 0: the lowest positions
+        w = jnp.zeros_like(w)
+    elif planted == "forty_binades":  # of both signs (w's)
+        ki = ki * jnp.exp2((at * 41 % 40 - 20.0))[None, :, None]
+    elif planted == "outlier":
+        ki = ki.at[0, 7].multiply(1e30)
+    elif planted == "half_repeated":  # many ties AT the last place
+        ki = ki.at[0, seq // 2:].set(ki[0, :seq - seq // 2])
+    elif planted == "signed_zeros":  # 0 * a negative weight is -0.0
+        ki = jnp.where((at % 3 == 0)[None, :, None], 0.0, ki)
+        w = jnp.where((at % 2 == 0)[None, :, None], -jnp.abs(w), w)
+    else:
+        assert planted == "plain", planted
+    return qi, ki, w
+
+
+@pytest.mark.parametrize("seq,topk,planted", [
+    (256, 24, "ties"), (384, 500, "ties"), (128, 1, "ties"),
+    (256, 128, "plain"),   # row 127 sees exactly topk keys, row 128 one more
+    (1024, 300, "plain"),  # two key blocks; two query blocks under topk
+    (256, 24, "all_equal"), (384, 100, "forty_binades"),
+    (256, 24, "outlier"), (384, 100, "half_repeated"),
+    (256, 24, "signed_zeros")])
+def test_the_kernels_are_the_xla_path(seq, topk, planted):
     """``ops/sparse_prefill.py`` interpreted against ``_selected_tiles``:
     the mask entry for entry (planted ties included) and the attention."""
     from ray_tpu.ops import sparse_prefill as sp
 
-    k = jax.random.split(jax.random.PRNGKey(seq), 6)
-    qi = jax.random.normal(k[0], (1, seq, 4, 16))
-    ki = jax.random.normal(k[1], (1, seq, 16))
-    ki = ki.at[0, 5].set(ki[0, 3]).at[0, 100].set(ki[0, 90])
-    w = jax.random.normal(k[2], (1, seq, 4)) / 8
-    q = jax.random.normal(k[3], (1, seq, 4, 16))
-    kk = jax.random.normal(k[4], (1, seq, 2, 16))
-    v = jax.random.normal(k[5], (1, seq, 2, 16))
+    qi, ki, w = selection_operands(seq, planted)
+    k = jax.random.split(jax.random.PRNGKey(seq + 1), 3)
+    q = jax.random.normal(k[0], (1, seq, 4, 16))
+    kk = jax.random.normal(k[1], (1, seq, 2, 16))
+    v = jax.random.normal(k[2], (1, seq, 2, 16))
     mask = jax.jit(lambda *a: sp.index_select(*a, topk, interpret=True))(
         qi, ki, w)
     rows = sp.mask_rows(mask)[0, :, :seq]
@@ -369,6 +402,32 @@ def test_the_kernels_are_the_xla_path(seq, topk):
         q, kk, v, mask)
     close(got, jax.jit(lambda *a: llama._selected_tiles(
         *a, topk, jnp.float32, 64))(q, kk, v, qi, ki, w), 1e-5)
+
+
+@pytest.mark.parametrize("seq,topk,planted,heads,most", [
+    (1024, 300, "plain", 16, 20),   # bell-shaped scores stop early
+    (1024, 300, "plain", 4, None),  # runs of zeros at the last place
+    (256, 24, "all_equal", 4, None), (384, 100, "forty_binades", 4, None),
+    (256, 24, "outlier", 4, None), (384, 100, "half_repeated", 4, None),
+    (256, 24, "signed_zeros", 4, None)])
+def test_the_selection_stops_when_its_rows_have(seq, topk, planted, heads,
+                                                most):
+    """The passes a query block's search ran, which the call writes beside
+    the mask: none where no row sees more than ``topk`` keys, no more than
+    ``most`` on plain scores, and never more than the stated cap, whatever
+    the scores: the bound on the search is this test."""
+    from ray_tpu.ops import sparse_prefill as sp
+
+    ops = selection_operands(seq, planted, heads)
+    mask, passes = jax.jit(lambda *a: sp.index_select_passes(
+        *a, topk, interpret=True))(*ops)
+    assert bool(jnp.all(mask == jax.jit(lambda *a: sp.index_select(
+        *a, topk, interpret=True))(*ops)))
+    passes = [int(n) for n in passes[0]]
+    under = topk // sp.SELECT_BLOCK_Q  # blocks whose rows all take all
+    assert passes[:under] == [0] * under and min(passes[under:]) > 0
+    cap = sp.select_pass_cap(mask.shape[2] * sp.SELECT_BLOCK_K)
+    assert max(passes) <= (most or cap) <= cap, passes
 
 
 # --- (c) the decode engine --------------------------------------------------- #
