@@ -16,8 +16,11 @@ model definition runs dp/fsdp/tp/sp via GSPMD. Design choices for the MXU:
   and gated full attention in the others (:func:`gated_block`), or of
   latent-attention blocks outside the double layer, TRAINED through the
   flash kernel (:func:`latent_block`; a prediction module behind them:
-  :func:`add_mtp_loss`), weights stacked per kind and walked in the
-  pattern's order (:func:`pattern_layer`, :func:`pattern_stack`);
+  :func:`add_mtp_loss`) and SERVED over latent rows
+  (:func:`serve_latent_block`), their residual stream ONE row a token or
+  ``hc_mult`` rows mixed around every sublayer (:func:`hyper_connected`),
+  weights stacked per kind and walked in the pattern's order
+  (:func:`pattern_layer`, :func:`pattern_stack`);
 - bf16 matmuls with fp32 accumulation (``preferred_element_type``), params
   stored fp32, gradients/optimizer fp32;
 - ``jax.checkpoint`` per layer (remat) to trade FLOPs for HBM, its products
@@ -199,11 +202,15 @@ def keep_policy(keep):
 
 # a patterned stack's layer kinds: the character -> the name of the kind's
 # stacked weights under params["layers"]. "L" / "G" (LATENT_KINDS): latent
-# attention THEN the routed MLP ("L") or a dense SwiGLU ("G"). They are
-# TRAINED kinds: the train steps attend them with the flash kernel over
-# per-head keys and values at ONE width (forward, dQ and dK/dV are Pallas
-# calls), NOT with prefill's kernel, which is forward only and whose
-# transpose is the XLA tile loop; the decode engine refuses them by name
+# attention THEN the routed MLP ("L") or a dense SwiGLU ("G"). TRAINED: the
+# train steps attend them with the flash kernel over per-head keys and values
+# at ONE width (forward, dQ and dK/dV are Pallas calls), NOT with prefill's
+# kernel, which is forward only and whose transpose is the XLA tile loop.
+# SERVED too (rows of SERVED): prefill's kernel over expanded keys and
+# values, decode absorbed over ONE latent row a position. Their stream is
+# the only one that may be cfg.hc_mult rows a token ([B, T, hc_mult * dim],
+# widened behind the embedding and summed in front of the final norm; no
+# train step takes that)
 LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
                "F": "block", "W": "block", "I": "index", "D": "delta",
                "A": "gated", "L": "latent", "G": "latent_dense"}
@@ -347,7 +354,7 @@ class LlamaConfig:
     # rank) scale only where mla_scale_* sets it) then a dense SwiGLU of
     # dense_mlp_dim ("G": a stack's leading layers) or the routed MLP with
     # an ungated shared SwiGLU expert ("L"); TRAINED through the flash
-    # kernel (latent_block), not served. Empty:
+    # kernel (latent_block) and served (serve_latent_block). Empty:
     # every layer is the block (attention THEN MLP), as every dense and
     # every all-routed configuration has it.
     layer_pattern: str = ""
@@ -426,10 +433,10 @@ class LlamaConfig:
     mla_scale_q_lora: bool = True
     mla_scale_kv_lora: bool = True
     # the "L" / "G" layers (latent_block): latent attention as the "S"
-    # layer's (the ranks and head widths above; the score's width, nope +
-    # rope, has to be the value's: the trainer's flash kernel attends q, k
-    # and v of ONE width), then the routed MLP with ONE shared SwiGLU expert
-    # of width shared_mlp_dim and no gate in front of it ("L") or a dense
+    # layer's (the ranks and head widths above; a TRAIN step wants the
+    # score's width, nope + rope, to be the value's: its flash kernel attends
+    # q, k and v of ONE width), then the routed MLP with ONE shared SwiGLU
+    # expert of width shared_mlp_dim and no gate in front of it ("L") or a dense
     # SwiGLU of width dense_mlp_dim ("G"). mtp_layers: multi-token-prediction
     # modules behind the stack (0 or 1; add_mtp_loss): one more "L" block
     # that reads [RMSNorm(Emb(t_{i+1})) ; RMSNorm(h_i)] W_eh and predicts
@@ -437,9 +444,40 @@ class LlamaConfig:
     # mtp_loss_weight times
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # the residual stream as hc_mult ROWS a token (manifold-constrained
+    # hyper-connections; hyper_connected, at this file's end, has the
+    # equations): every sublayer of an "L" / "G" block reads ONE mixed row,
+    # and its output is written back onto all of them beside a doubly
+    # stochastic mix of the rows (hc_sinkhorn_iters normalisations of
+    # exp(clip(.., hc_res_clamp_min, hc_res_clamp_max)), hc_eps in the
+    # mix's norm and in every divisor). The programs carry the rows side by
+    # side, [B, T, hc_mult * dim] (widen_stream .. collapse_stream). 1: the
+    # plain residual stream, and nothing of this is traced
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp_min: float = -30.0
+    hc_res_clamp_max: float = 30.0
+    # a published ``rope_scaling`` group of type "yarn" (factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim), key for key; kept as sorted pairs (a config is
+    # hashed). Only the latent half reads it: yarn_frequencies turns its
+    # rope slice, yarn_softmax_factor multiplies its scores. Empty: the
+    # plain rotation at rope_theta
+    rope_yarn: Any = ()
+    # where an "L" / "G" block's SEEDED leaves start off the square root of
+    # their fan-in, the configuration file's data (SEEDED_SCALES has the
+    # names: the attention's ``wo``, the routed experts' ``w_down``); kept as
+    # sorted pairs. Weights alone: no program reads it, a checkpoint brings
+    # its own. Empty: every matrix over the square root of its fan-in
+    seeded_scales: Any = ()
 
     def __post_init__(self):
         object.__setattr__(self, "mrope_section", tuple(self.mrope_section))
+        object.__setattr__(self, "rope_yarn", tuple(sorted(
+            dict(self.rope_yarn or ()).items())))
+        object.__setattr__(self, "seeded_scales", tuple(sorted(
+            dict(self.seeded_scales or ()).items())))
         bad = set(self.layer_pattern) - set(LAYER_KINDS)
         if bad or 0 < len(self.layer_pattern) < self.n_layers:
             raise ValueError(
@@ -521,8 +559,7 @@ class LlamaConfig:
                 set(self.kinds) <= set(LATENT_KINDS)
                 and self.q_lora_rank and self.kv_lora_rank
                 and self.qk_rope_head_dim % 2 == 0
-                and self.qk_nope_head_dim + self.qk_rope_head_dim
-                == self.v_head_dim > 0
+                and self.qk_nope_head_dim > 0 and self.v_head_dim > 0
                 and ("G" not in latent or self.dense_mlp_dim)
                 and ("L" not in latent or (
                     self.num_experts and self.experts_per_token))
@@ -530,9 +567,10 @@ class LlamaConfig:
                 and not self.qk_norm and not self.zero_experts):
             raise ValueError(
                 "an 'L' / 'G' layer is latent attention (q_lora_rank, "
-                "kv_lora_rank, qk_nope_head_dim + qk_rope_head_dim = "
-                "v_head_dim: the flash kernel attends q, k and v of one "
-                "width) then a routed SwiGLU MLP ('L': num_experts, "
+                "kv_lora_rank, qk_nope_head_dim, an even qk_rope_head_dim, "
+                "v_head_dim: a train step's flash kernel wants the score's "
+                "width to be the value's and says so itself) then a routed "
+                "SwiGLU MLP ('L': num_experts, "
                 "experts_per_token; an ungated shared expert, "
                 "shared_mlp_dim, and a held range may be set) or a dense "
                 "one ('G': dense_mlp_dim): every built layer is one of the "
@@ -543,6 +581,33 @@ class LlamaConfig:
                 f"mtp_layers={self.mtp_layers}: one multi-token-prediction "
                 "module or none, and its block is an 'L' layer's: the stack "
                 "has to have one")
+        if self.hc_mult < 1 or (self.hc_mult > 1 and not (
+                latent and self.hc_sinkhorn_iters > 0 and not self.mtp_layers
+                and self.hc_res_clamp_min < self.hc_res_clamp_max)):
+            raise ValueError(
+                f"hc_mult={self.hc_mult}: a stream of several rows is mixed "
+                "around the sublayers of 'L' / 'G' blocks alone "
+                "(hc_sinkhorn_iters > 0, hc_res_clamp_min < "
+                "hc_res_clamp_max), and a prediction module has no form "
+                "for it")
+        if set(dict(self.seeded_scales)) - set(SEEDED_SCALES) or (
+                self.seeded_scales and not latent):
+            raise ValueError(
+                f"seeded_scales={dict(self.seeded_scales)}: starting scales "
+                f"of an 'L' / 'G' block's leaves, of {SEEDED_SCALES}")
+        yarn = dict(self.rope_yarn)
+        if yarn and not (
+                latent and yarn.get("type", yarn.get("rope_type")) == "yarn"
+                and yarn.get("factor", 0) >= 1
+                and yarn.get("original_max_position_embeddings", 0) > 0
+                and yarn.get("mscale", 1) == yarn.get("mscale_all_dim", 1)):
+            raise ValueError(
+                f"rope_yarn={yarn}: a rope_scaling group of type 'yarn' "
+                "(factor >= 1, original_max_position_embeddings, beta_fast, "
+                "beta_slow) whose mscale is its mscale_all_dim (the "
+                "rotation's amplitude is then 1: no other is held to a "
+                "reference), and only the 'L' / 'G' layers' latent half "
+                "reads it")
         blocks = set(self.kinds) & set(BLOCK_KINDS)
         if ("W" in self.kinds) != bool(self.window) or self.window < 0:
             raise ValueError(
@@ -615,7 +680,10 @@ class LlamaConfig:
 
     @property
     def rope_scaling(self):
-        """The rotation's sections as a published config nests them."""
+        """The rotation's sections, or its YaRN group, as a published config
+        nests them."""
+        if self.rope_yarn:
+            return dict(self.rope_yarn)
         if not self.mrope_section:
             return None
         return {"mrope_section": list(self.mrope_section),
@@ -699,10 +767,13 @@ class LlamaConfig:
                 per_kind["S"] = (
                     2 * mla + 2 * 3 * d * self.dense_mlp_dim + wide * (d + 1)
                     + self.num_experts * 3 * d * self.mlp_dim + 4 * d)
-                per_kind["G"] = mla + 3 * d * self.dense_mlp_dim + 2 * d
+                # a block's two hyper-connections: phi, b and three alphas
+                n = self.hc_mult
+                hc = 2 * ((n * d + 1) * (2 * n + n * n) + 3) if n > 1 else 0
+                per_kind["G"] = mla + 3 * d * self.dense_mlp_dim + 2 * d + hc
                 per_kind["L"] = (
                     mla + wide * (d + 1) + 3 * d * self.shared_mlp_dim
-                    + self.num_experts * 3 * d * self.mlp_dim + 2 * d)
+                    + self.num_experts * 3 * d * self.mlp_dim + 2 * d + hc)
                 # a prediction module: its block, W_eh and three norms
                 emb += self.mtp_layers * (per_kind["L"] + 2 * d * d + 3 * d)
             return emb + sum(per_kind[k] for k in self.kinds) + d
@@ -835,6 +906,11 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             kinds["latent"].update(shared_gate=("layers", "embed", "mlp"),
                                    shared_up=("layers", "embed", "mlp"),
                                    shared_down=("layers", "mlp", "embed"))
+        if cfg.hc_mult > 1:  # a sublayer's mix: small, whole on every device
+            for kind in ("latent", "latent_dense"):
+                kinds[kind].update(hc_phi=("layers", None, None, None),
+                                   hc_b=("layers", None, None),
+                                   hc_alpha=("layers", None, None))
         out = {
             "embedding": ("vocab", "embed"),
             "layers": {LAYER_KINDS[k]: kinds[LAYER_KINDS[k]]
@@ -1321,7 +1397,10 @@ def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
     all heads share.
     ``attend(q, latent, wkv_b)`` -> ``[B, T, heads, v_head_dim]`` decides
     whether ``c wkv_b = [k_nope | v]`` a head is ever made (prefill) or
-    absorbed into the query and the output (decode). Returns ``(y,
+    absorbed into the query and the output (decode). Under ``cfg.rope_yarn``
+    the rope slice turns at :func:`yarn_frequencies` and the scores' factor
+    (:func:`yarn_softmax_factor`) rides on the query's gain, so that every
+    ``attend`` keeps its ``1 / sqrt(score width)``. Returns ``(y,
     latent)``: ``latent`` ``[B, T, kv_lora_rank + qk_rope_head_dim]`` is
     ``[c | kr]``, normed, scaled and rotated: what a cache keeps."""
     cd, d, eps = cfg.dtype, cfg.dim, cfg.norm_eps
@@ -1332,15 +1411,20 @@ def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
             # a norm's weight times the scale: one rounding, not two
             return w * math.sqrt(d / rank) if scaled else w
 
-        qa = rms_norm(h @ p["wq_a"].astype(cd), gain(
-            p["q_norm"], cfg.mla_scale_q_lora, cfg.q_lora_rank), eps)
-        q = (qa @ p["wq_b"].astype(cd)).reshape(B, T, H, -1)
+        qa = h @ p["wq_a"].astype(cd)
+        q_gain = gain(p["q_norm"], cfg.mla_scale_q_lora, cfg.q_lora_rank)
+        turn = {}
+        if cfg.rope_yarn:  # the scores' factor rides on q's gain, as above
+            q_gain = q_gain * yarn_softmax_factor(cfg)
+            turn["inv_freq"] = yarn_frequencies(cfg)
+        q = (rms_norm(qa, q_gain, eps) @ p["wq_b"].astype(cd)).reshape(
+            B, T, H, -1)
         ckr = h @ p["wkv_a"].astype(cd)
         c = rms_norm(ckr[..., :r], gain(
             p["kv_norm"], cfg.mla_scale_kv_lora, r), eps)
         q_rope, kr = rotary_embedding(
             q[..., dn:], ckr[:, :, None, r:], positions, cfg.rope_theta,
-            interleaved=True)
+            interleaved=True, **turn)
         q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
         latent = jnp.concatenate([c, kr[:, :, 0]], axis=-1)
     with jax.named_scope("mla.attend"):
@@ -2171,13 +2255,13 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     :func:`_attn_half` over ``attend``). ``p``: this layer's weights, of its
     kind. Returns ``(x, stats)``, ``stats`` ``{}`` but for ``"E"`` and the
     served kinds."""
+    if kind in LATENT_KINDS:  # a whole block, over the CALLER's attend
+        return latent_block(cfg, kind, attend, x, p, stat_axes)
     if kind in SERVED:  # this layer's weights as a stack of one
         return SERVED[kind].block(
             cfg, x, jax.tree.map(lambda a: a[None], p), 0,
             positions_of(*x.shape[:2]),
             partial(SERVED[kind].prefill, cfg, None), stat_axes)[:2]
-    if kind in LATENT_KINDS:  # a whole block too, over the CALLER's attend
-        return latent_block(cfg, kind, attend, x, p, stat_axes)
     h = rms_norm(x, p["norm"], cfg.norm_eps).astype(cfg.dtype)
     stats = {}
     if kind == "M":
@@ -2292,9 +2376,10 @@ def _backbone(cfg: LlamaConfig, params, tokens, mesh=None):
     x = embed_tokens(cfg, params, tokens, mesh)
     if cfg.layer_pattern:
         x, stats = pattern_stack(
-            cfg, x, params["layers"],
+            cfg, widen_stream(cfg, x), params["layers"],
             lambda q, k, v: _attention(cfg, q, k, v, mesh))
-        return rms_norm(x, _final_gain(cfg, params["final_norm"]),
+        return rms_norm(collapse_stream(cfg, x),
+                        _final_gain(cfg, params["final_norm"]),
                         cfg.norm_eps), stats
     positions = positions_of(*tokens.shape)
 
@@ -2625,7 +2710,11 @@ class Served(NamedTuple):
     positions, attend)`` -> ``(x, stats, rows)``: the layer, ``which`` its
     number in the stack (the dense block: its own weights), ``rows`` what it
     keeps, an array a store (or the one array). ``f32``: the serving
-    stream is float32 and not ``cfg.dtype``. ``rows(cfg)``: ``[(tag,
+    stream is float32 and not ``cfg.dtype``; ``x`` is ``[B, T, dim]``, or
+    ``[B, T, hc_mult * dim]`` where the config has several rows a token (the
+    programs widen it behind the embedding and sum it in front of the head:
+    only a block that takes :func:`hyper_connected` reads such a stream).
+    ``rows(cfg)``: ``[(tag,
     sublayers, row shape, table), ...]``, what a layer keeps, a store each,
     found by the rule ``table`` names (:data:`TABLES`): a row a POSITION by
     the pool's page ids (``"page"``) or by the engine's map of a page to its
@@ -2797,6 +2886,21 @@ SERVED: Dict[str, Served] = {
         "delta", "gated", gated_block, True, partial(_kv_rows, tag="gated"),
         lambda cfg, last, *a: attend_tiles(*a, cfg.dtype, kind="gated"),
         _attend_pages),
+    # the latent blocks outside the double layer (serve_latent_block, at this
+    # file's end), a stack a kind, the routed one first (LATENT_KINDS' order:
+    # the engine reads its experts' groups off the first store's kind):
+    # latent rows as "S" keeps them, ONE a layer, a store a kind, both under
+    # the tag latent_block; prefill expands keys and values, decode never
+    # does. The stream is
+    # float32 (a top-k choice is a hard one, and with hc_mult rows a token it
+    # is mixed, not only added to, at every sublayer)
+    **{c: Served(
+        "latent_block", LAYER_KINDS[c],
+        lambda cfg, *a, c=c: serve_latent_block(cfg, c, *a), True,
+        lambda cfg: [("latent_block", 1, (cfg.latent_row,), "page")],
+        lambda cfg, last, *a: attend_latent_expanded(cfg, *a),
+        lambda cfg, call, l, mine, *a: _attend_latent_cached(
+            cfg, mine[0], call.pos, *a)) for c in LATENT_KINDS},
 }
 
 
@@ -2866,7 +2970,11 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
     depth; a longer one from two repetitions on, and in line below that.
 
     The ``l``-th layer of kind ``c`` runs ``SERVED[c].block`` and attends
-    through ``attends[c](l, mine, *the block's arguments)``. ``cache`` and
+    through ``attends[c](l, mine, *the block's arguments)``. ``x`` is the
+    stream as the programs carry it: ``[B, T, dim]``, or ``[B, T, hc_mult *
+    dim]`` (:func:`widen_stream`), float32 where a kind's row says so and
+    ``cfg.dtype`` otherwise; it leaves as it came, and the caller collapses
+    it. ``cache`` and
     ``keep`` have an entry a store (:func:`served_stores`): the store's
     view ``[layers, Tpad, *row]``, of which ``mine`` are the layer's own,
     or ``cache`` None (a prefill: nothing cached, ``mine`` None); and how
@@ -2874,7 +2982,8 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
     rows, shares)``: ``rows`` a store, ``[layers, B, n, *row]`` for a
     prefill and ``[layers, 1, *row]`` for a decode call's one new position;
     ``shares``: the routed assignments' shares, each averaged over the
-    layers that report it."""
+    layers that report it, and a mixed stream's ``hc_sinkhorn_error``, its
+    layers' largest."""
     kinds, layout = served_kinds(cfg), served_stores(cfg)
     subs = _by_kind(layout, [s.sub for s in layout])
     keep = _by_kind(layout, keep)
@@ -2910,8 +3019,8 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
         else:  # the positions' axis stands behind [sublayers,] B
             rows = tuple(a[:, :, -n:] if sub > 1 else a[:, -n:]
                          for a, sub, n in zip(rows, subs[c], keep[c]))
-        return x, (rows, {k: stats[k] for k in ("held_share", "zero_share")
-                          if k in stats})
+        # the block's statistics that OVER_LAYERS (this file's end) lists
+        return x, (rows, {k: stats[k] for k in OVER_LAYERS if k in stats})
 
     def run(x, some, first, start, cached):
         """Layers of kinds ``some`` in line; the first of them in stack
@@ -2971,7 +3080,9 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
     for _, share in out.values():
         for k, a in share.items():
             shares.setdefault(k, []).append(a.reshape(-1))
-    shares = {k: jnp.concatenate(a).mean() for k, a in shares.items()}
+    # each over its layers as OVER_LAYERS says (a share: their mean)
+    shares = {k: OVER_LAYERS[k](jnp.concatenate(a))
+              for k, a in shares.items()}
     rows = [a if sub == 1 else a.reshape(-1, *a.shape[2:])
             for c, (mine, _) in out.items() for a, sub in zip(mine, subs[c])]
     if cache is not None:  # the one new row: the batch's 1 for its position
@@ -2983,7 +3094,10 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
 def prefill_with_cache(cfg: LlamaConfig, params, *args):
     """Prefill one sequence into its pages, inside the program: embed, the
     layers (:func:`_serve_layers`), each store written through ITS table,
-    the head.
+    the head. With ``cfg.hc_mult > 1`` the stream between embedding and
+    head is ``hc_mult`` rows a token, ``[1, T, hc_mult * dim]`` float32
+    (:func:`widen_stream`: copies of the embedding), and the rows of the ONE
+    position the head reads are summed (:func:`collapse_stream`).
 
     ``args``: ``*stores, tokens, page_ids, last`` and, for a stack with a
     store by slot (window layers), ``slot_ids`` [min(n, k)] int32 behind
@@ -3002,14 +3116,14 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
     token's keys: finite, and masked by every decode until the sequence
     itself overwrites them), and the head is applied to position ``last``
     alone. ``shares``: a routed model's assignment shares (``{}`` for a
-    dense one)."""
+    dense one) and a mixed stream's ``hc_sinkhorn_error``."""
     layout, slot_ids = served_stores(cfg), None
     if any(s.table == "slot" for s in layout):
         *args, slot_ids = args
     *stores, tokens, page_ids, last = args
     ps = _page_size(stores, layout)
     ids = {"page": page_ids, "slot": slot_ids}
-    x = embed_tokens(cfg, params, tokens, None)
+    x = widen_stream(cfg, embed_tokens(cfg, params, tokens, None))
     positions = positions_of(*tokens.shape)
     keep = {"page": tokens.shape[1], "slot": 0 if slot_ids is None
             else slot_ids.shape[0] * ps}
@@ -3024,7 +3138,7 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
     stores = [_write_pages(pages, new[:, 0], ids[s.table])
               for pages, new, s in zip(stores, rows, layout)]
     # final_norm and the head are per position: one row, not T
-    x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+    x = collapse_stream(cfg, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1))
     logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
     return (*stores, logits[0, 0], shares)
 
@@ -3033,7 +3147,8 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
     """One decode step of one sequence against the page stores: each
     store gathered through ITS table where its kind's row says so
     (``SERVED[c].decode``), embed, the layers (:func:`_serve_layers`), the
-    head, the new position's rows written.
+    head, the new position's rows written. The stream is widened and
+    collapsed as :func:`prefill_with_cache` does it.
 
     ``args``: ``*stores, token, pos, page_ids`` and, for a stack with a
     store by slot (window layers), ``slot_ids, first`` behind them.
@@ -3063,7 +3178,7 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
         ids["state"] = page_ids[jnp.maximum(pos - 1, 0) // ps][None]
     cached = [_read_pages(pages, ids[s.table])
               for pages, s in zip(stores, layout)]
-    x = embed_tokens(cfg, params, token[None, :], None)
+    x = widen_stream(cfg, embed_tokens(cfg, params, token[None, :], None))
     positions = jnp.full((1, 1), pos, dtype=jnp.int32)
     call = SimpleNamespace(
         pos=pos, page_ids=page_ids, stores=_by_kind(layout, stores),
@@ -3072,7 +3187,8 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
         cfg, x, params["layers"], positions,
         {c: partial(SERVED[c].decode, cfg, call) for c in call.stores},
         cached, [1] * len(layout))
-    logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
+    logits = head_logits(cfg, collapse_stream(cfg, x), params["final_norm"],
+                         _head(cfg, params))
     page = page_ids[pos // ps]
     at = {"page": (0, page, pos % ps), "state": (0, page, 0)}
     if slot_ids is not None:
@@ -3116,7 +3232,10 @@ _MATMUL_LAYER = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                  # the delta rule's projections and the gated shared expert
                  # (its gate's vector w_sg, A_log, dt_bias and the
                  # convolution are used in float32)
-                 "w_qkvz", "w_ba", "w_out", "ws_gate", "ws_up", "ws_down")
+                 "w_qkvz", "w_ba", "w_out", "ws_gate", "ws_up", "ws_down",
+                 # the latent block's ungated shared expert (a sublayer's
+                 # hc_phi is used in float32, as a router is)
+                 "shared_gate", "shared_up", "shared_down")
 
 
 def serving_params(cfg: LlamaConfig, params) -> Dict[str, Any]:
@@ -3157,11 +3276,14 @@ class LlamaDecodeEngine:
     by which table, through which block, attended how in prefill and in
     decode is said there and nowhere here. The engine serves a stack whose
     kinds are ONE family's, all of them (dense blocks; all ``"S"``; ``"F"``
-    with ``"W"``; all ``"I"``; ``"D"`` with ``"A"``). A kind without a row
+    with ``"W"``; all ``"I"``; ``"D"`` with ``"A"``; ``"L"`` with ``"G"``,
+    whose stream may be ``hc_mult`` rows a token:
+    ``ray_tpu_serve_engine_stream_bytes``). A kind without a row
     (the ``"M"`` mixer, whose state would go by the ``"state"`` rule; the
     ``"E"`` / ``"*"`` halves), a part or a mix of families, whole-projection
-    QK-norm and the UNPATTERNED routed block are refused: no test holds
-    their logits to a reference here.
+    QK-norm, the UNPATTERNED routed block and a prediction module
+    (``mtp_layers``) are refused: no test holds their logits to a reference
+    here.
 
     ``params`` is the tree the programs run on, :func:`serving_params`': the
     matmul weights in ``cfg.dtype``, converted ONCE here and not inside
@@ -3237,9 +3359,8 @@ class LlamaDecodeEngine:
         self.cfg = cfg or LlamaConfig.debug()
         _no_latent_kinds(
             self.cfg, "LlamaDecodeEngine",
-            "serving them is not built: a latent layer outside 'S' has no "
-            "row in SERVED, and a prediction module's self-drafted decode "
-            "steps need a scheduler that takes more than one token a call")
+            "a prediction module's self-drafted decode steps need a "
+            "scheduler that takes more than one token a call", blocks=False)
         families: Dict[str, str] = {}  # the table's kinds, by family
         for c, kind in SERVED.items():
             families[kind.family] = families.get(kind.family, "") + c
@@ -3325,6 +3446,10 @@ class LlamaDecodeEngine:
             else:
                 _g_engine_state_bytes.set(float(nbytes), tags={"part": tag})
         self._note_slots()
+        _g_engine_stream_bytes.set(float(
+            self.cfg.hc_mult * self.cfg.dim * (4 if any(
+                SERVED[c].f32 for c in kinds) else jnp.dtype(
+                    self.cfg.dtype).itemsize)))
         groups = {"program": 0, "layer": 0}
         if self.cfg.num_experts:  # the routed kinds it serves
             from ray_tpu.ops.moe import expert_groups
@@ -3458,6 +3583,7 @@ class LlamaDecodeEngine:
         last, shares = jax.device_get((logits, shares))
         _sp_prefill_logits.end(_t, n_pages)
         self._note_selected("prefill", T)
+        _note_stream(shares)
         if shares or self.cfg.num_experts:  # every expert here: held is 1.0
             # where the assignments fell and, of a held range, the places a
             # row was made for (at this file's end; this block keeps its six
@@ -3555,6 +3681,10 @@ def make_train_step(cfg: LlamaConfig, mesh, optimizer=None, rules=None):
     # same params on every mesh layout because jax.random is
     # sharding-invariant (test_parallelism_consistency)
     rules = rules or DEFAULT_RULES
+    _no_wide_latent(
+        cfg, "make_train_step",
+        "no train step is held to a reference for the mixes' backward, and "
+        "its flash kernel attends q, k and v of one width")
     optimizer = optimizer or optax.adamw(3e-4, b1=0.9, b2=0.95,
                                          weight_decay=0.1)
     axes = param_logical_axes(cfg)
@@ -3713,6 +3843,10 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
 
     if "pipe" not in mesh.axis_names:
         raise ValueError("mesh has no 'pipe' axis")
+    _no_wide_latent(
+        cfg, "make_pipeline_train_step",
+        "its stages pass ONE row a token from stage to stage, and no train "
+        "step is held to a reference for the mixes' backward")
     _no_latent_kinds(
         cfg, "make_pipeline_train_step",
         "its stages run the dense block alone, and the prediction module's "
@@ -4009,15 +4143,17 @@ def _note_assignments(shares, places: int, cfg) -> None:
 # --------------------------------------------------------------------------- #
 
 
-def _no_latent_kinds(cfg: LlamaConfig, who: str, why: str) -> None:
+def _no_latent_kinds(cfg: LlamaConfig, who: str, why: str,
+                     blocks: bool = True) -> None:
     """A path that does not run the ``"L"`` / ``"G"`` kinds and the
     prediction module refuses them by name, as :func:`_no_delta_kinds`
-    refuses its kinds."""
-    if set(cfg.kinds) & set(LATENT_KINDS) or cfg.mtp_layers:
+    refuses its kinds. ``blocks`` False: a path that runs the blocks (the
+    decode engine) and refuses the module alone."""
+    if (blocks and set(cfg.kinds) & set(LATENT_KINDS)) or cfg.mtp_layers:
+        kinds = "no 'L' / 'G' layer and " if blocks else ""
         raise NotImplementedError(
-            f"{who} takes no 'L' / 'G' layer and no prediction module "
-            f"(layer_pattern={cfg.layer_pattern!r}, mtp_layers="
-            f"{cfg.mtp_layers}) yet: {why}")
+            f"{who} takes {kinds}no prediction module (layer_pattern="
+            f"{cfg.layer_pattern!r}, mtp_layers={cfg.mtp_layers}) yet: {why}")
 
 
 def _init_latent_kind(cfg: LlamaConfig, kind: str, L: int, key):
@@ -4025,7 +4161,9 @@ def _init_latent_kind(cfg: LlamaConfig, kind: str, L: int, key):
     own a kind. Every matrix over the square root of its fan-in (``wq_b`` /
     ``wkv_b`` over ``sqrt(dim)`` where the config multiplies their inputs by
     ``sqrt(dim / rank)``, as the ``"S"`` layer's): q, k and v then have unit
-    variance and a score is one unit wide. The choice bias starts at zero."""
+    variance and a score is one unit wide. The choice bias starts at zero.
+    ``cfg.seeded_scales`` (a configuration's data) multiplies ``wo`` and the
+    routed experts' ``w_down``."""
     d, f, H = cfg.dim, cfg.mlp_dim, cfg.n_heads
     rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
@@ -4045,6 +4183,12 @@ def _init_latent_kind(cfg: LlamaConfig, kind: str, L: int, key):
                        d if cfg.mla_scale_kv_lora else rkv),
         "wo": dense(next(k), (L, H * cfg.v_head_dim, d), H * cfg.v_head_dim),
     }
+    scales = dict(cfg.seeded_scales)  # the configuration's data, or none
+    if "wo" in scales:
+        out["wo"] = scales["wo"] * out["wo"]
+    if cfg.hc_mult > 1:  # a key of their own: the leaves above stay theirs
+        out.update(_init_hyper(cfg, L, jax.random.fold_in(
+            key, 9 + LATENT_KINDS.index(kind))))
     if kind == "G":
         fd = cfg.dense_mlp_dim
         out.update(w_gate=dense(next(k), (L, d, fd), d),
@@ -4057,6 +4201,8 @@ def _init_latent_kind(cfg: LlamaConfig, kind: str, L: int, key):
                w_gate=dense(next(k), (L, held, d, f), d),
                w_up=dense(next(k), (L, held, d, f), d),
                w_down=dense(next(k), (L, held, f, d), f))
+    if "expert_down" in scales:
+        out["w_down"] = scales["expert_down"] * out["w_down"]
     if cfg.shared_mlp_dim:
         fs = cfg.shared_mlp_dim
         out.update(shared_gate=dense(next(k), (L, d, fs), d),
@@ -4100,7 +4246,8 @@ def attend_latent_heads(cfg: LlamaConfig, attend, q, latent, wkv_b):
 
 def latent_block(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     """THE latent block (kinds ``"L"`` and ``"G"``), for the train steps and
-    the full forward::
+    the full forward (the serving programs': :func:`serve_latent_block`, over
+    the same :func:`_latent_sublayers`)::
 
         a   = x + MLA(N(x))          (:func:`_latent_half`, no sqrt(dim / rank)
                                       factor unless the config sets it)
@@ -4110,22 +4257,22 @@ def latent_block(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
                                      scores, the choice bias, a held range)
                                      with its ungated shared SwiGLU expert
 
+    each ``+`` a hyper-connection where ``cfg.hc_mult > 1``
+    (:func:`hyper_connected`; ``x`` is then ``[B, T, hc_mult * dim]``).
     ``attend(q, k, v)`` as :func:`decoder_block` takes it: the block makes
     per-head keys and values itself (:func:`attend_latent_heads`), so the
-    trainer's flash kernel runs forward, dQ and dK/dV on them. ``p``: this
-    layer's weights. Returns ``(x, stats)``."""
-    cd, eps = cfg.dtype, cfg.norm_eps
-    h = rms_norm(x, p["attn_norm"], eps).astype(cd)
-    y, _ = _latent_half(cfg, p, h, positions_of(*x.shape[:2]),
-                        partial(attend_latent_heads, cfg, attend))
-    x = x + checkpoint_name(y, "attn").astype(x.dtype)
-    h = rms_norm(x, p["mlp_norm"], eps).astype(cd)
-    if kind == "G":
-        with jax.named_scope("ffn.dense"):
-            y, stats = _dense_mlp(cfg, p, h), {}
-    else:
-        y, stats = _mlp_half(cfg, p, h, stat_axes)
-    return x + y.astype(x.dtype), stats
+    trainer's flash kernel runs forward, dQ and dK/dV on them; where the
+    score's width is not the value's (no train step takes that) the full
+    forward attends as prefill does (:func:`attend_latent_expanded`). ``p``:
+    this layer's weights. Returns ``(x, stats)``."""
+    one_width = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                 == cfg.v_head_dim)
+    x, stats, _ = _latent_sublayers(
+        cfg, kind, partial(attend_latent_heads, cfg, attend) if one_width
+        else partial(attend_latent_expanded, cfg), x, p,
+        positions_of(*x.shape[:2]), stat_axes, None)
+    # a dense layer's stats stay {}: pattern_stack stacks the routed ones'
+    return x, {k: v for k, v in stats.items() if k != "hc_sinkhorn_error"}
 
 
 def add_mtp_loss(cfg: LlamaConfig, mtp, x, tokens, nll, stats, *, embed,
@@ -4227,3 +4374,265 @@ def _watch_routed_calls() -> None:
 
     watch_stacked_calls(_note_expert_products)
     watch_held_sums(_note_held_sums)
+
+
+# --------------------------------------------------------------------------- #
+# The latent blocks SERVED, the stream as hc_mult rows a token
+# (manifold-constrained hyper-connections) and YaRN's rotation. At this
+# file's end for attend_delta's reason
+# --------------------------------------------------------------------------- #
+
+# bytes ONE position of the residual stream takes as the serving programs
+# carry it between sublayers: hc_mult rows of dim in the stream's type
+# (float32 for the kinds whose row of SERVED says so); set where an engine
+# is built
+_g_engine_stream_bytes = Gauge(
+    "ray_tpu_serve_engine_stream_bytes",
+    "Bytes one position of the residual stream takes as the decode engine's "
+    "programs carry it (hc_mult rows of dim in the stream's type)")
+# how far the last prefill's residual mixes were from doubly stochastic: the
+# largest |row sum - 1| or |column sum - 1| of H_res over its positions and
+# sublayers, read with the logits; an engine without hyper-connections sets
+# none
+_g_hc_sinkhorn_error = Gauge(
+    "ray_tpu_serve_hc_sinkhorn_error",
+    "Largest distance of a row or column sum of the last prefill's "
+    "hyper-connection residual mixes from 1")
+
+# Where the hyper-connections' own leaves start (seeded weights; a
+# checkpoint brings its own). ``phi`` is over its fan-in, so that ``m`` is
+# one unit wide; the three ``alpha`` start at ``alpha`` and not near zero
+# (the paper starts the dynamic part small, the mix nearly static): here the
+# DYNAMIC part has to carry enough of a logit that a comparison of logits
+# refuses a fault in it. ``b``: zero for the read-out and the write-back
+# (``H_pre`` around 1 / 2, ``H_post`` around 1), ``res_diag`` on the residual
+# mix's diagonal, so that a row keeps some 0.6 of itself and the rows stay
+# apart (at zero the doubly stochastic mix is near uniform and ten sublayers
+# make the rows one).
+HC_INIT = {"alpha": 1.0, "res_diag": 2.0}
+# The names ``LlamaConfig.seeded_scales`` may give, a latent block's leaves
+# that a CONFIGURATION starts off the square root of their fan-in
+# (_init_latent_kind): the attention's ``wo`` and the routed experts'
+# ``w_down`` (``expert_down``; the shared expert's and the dense layer's
+# stay). The values and their readings are the configuration file's
+# (``seeded_scales``, ``correct``), as BLOCK_INIT's reason has it: they set
+# the halves' shares of a logit. WHICH experts are chosen, their load and
+# every product's shape and time are the same at any of them.
+SEEDED_SCALES = ("wo", "expert_down")
+# a walker's statistic over its layers (_serve_layers): a share is their
+# mean, an error their largest
+OVER_LAYERS = {"held_share": jnp.mean, "zero_share": jnp.mean,
+               "hc_sinkhorn_error": jnp.max}
+
+
+def _init_hyper(cfg: LlamaConfig, L: int, key):
+    """A kind's ``L`` layers' hyper-connection leaves, ``[L, 2, ...]``: a
+    sublayer each, the attention's then the MLP's (:data:`HC_INIT`)."""
+    n, d = cfg.hc_mult, cfg.dim
+    b = jnp.concatenate([jnp.zeros((2 * n,), jnp.float32),
+                         HC_INIT["res_diag"] * jnp.eye(n).reshape(-1)])
+    return {"hc_phi": _dense_init(key, (L, 2, n * d, 2 * n + n * n), n * d),
+            "hc_b": jnp.broadcast_to(b, (L, 2, b.shape[0])),
+            "hc_alpha": jnp.full((L, 2, 3), HC_INIT["alpha"], jnp.float32)}
+
+
+def widen_stream(cfg: LlamaConfig, x):
+    """The embedding ``[B, T, dim]`` as the stream's first state: every one
+    of the ``hc_mult`` rows a token is a copy of it, side by side ``[B, T,
+    hc_mult * dim]`` (row ``j`` is ``[..., j * dim:(j + 1) * dim]``, whole
+    lanes; every program and the walker keep reading ``x.shape[:2]`` and
+    slicing positions as they did. The rows as a dimension of their own cost
+    the same on the chip, 12.6 against 12.3 ms a sublayer's passes at 16,384
+    positions: the compiler lays either out positions-minor;
+    ``sweep/xing4_check.md``). ``hc_mult`` 1: ``x`` as it is."""
+    return x if cfg.hc_mult == 1 else jnp.tile(x, (1, 1, cfg.hc_mult))
+
+
+def collapse_stream(cfg: LlamaConfig, x):
+    """The stream's end: its rows SUMMED (float32) to ``[B, T, dim]``, what
+    the final norm reads. ``hc_mult`` 1: ``x`` as it is."""
+    if cfg.hc_mult == 1:
+        return x
+    with jax.named_scope("hc.sum"):
+        rows = x.reshape(*x.shape[:2], cfg.hc_mult, -1)
+        return rows.astype(jnp.float32).sum(axis=2).astype(x.dtype)
+
+
+def hyper_mix(cfg: LlamaConfig, phi, b, alpha, x):
+    """One sublayer's three mixes from the stream ``x`` [B, T, n * dim], all
+    float32 whatever the stream's type, the positions LAST (lanes: twenty
+    normalisations of ``[n, n]`` minor would run on padded tiles)::
+
+        m      = (x~ phi) / sqrt(mean(x~ ** 2) + eps)        x~ = vec(X)
+        H_pre  = sigmoid(alpha_0 m[:n] + b[:n])              [n, B, T]
+        H_post = 2 sigmoid(alpha_1 m[n:2n] + b[n:2n])        [n, B, T]
+        H_res  = Sinkhorn(exp(clip(alpha_2 m[2n:] + b[2n:])))  [n, n, B, T]
+
+    ``phi`` [n * dim, 2n + n * n] carries the norm's gain; Sinkhorn:
+    ``hc_sinkhorn_iters`` times every row over its sum, then every column
+    over its sum, ``+ eps`` in each divisor. Returns ``(H_pre, H_post, H_res,
+    error)``: ``error`` the largest ``|row sum - 1|`` or ``|column sum - 1|``
+    of ``H_res``."""
+    f32, n, eps = jnp.float32, cfg.hc_mult, cfg.hc_eps
+    x32 = x.astype(f32)
+    m = jnp.einsum("btc,cm->mbt", x32, phi,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=f32)
+    m = m * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1) + eps)
+    b = b[:, None, None]
+    pre = jax.nn.sigmoid(alpha[0] * m[:n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + b[n:2 * n])
+    res = jnp.exp(jnp.clip(alpha[2] * m[2 * n:] + b[2 * n:],
+                           cfg.hc_res_clamp_min, cfg.hc_res_clamp_max))
+    res = res.reshape(n, n, *m.shape[1:])  # [i, j]: onto row i from row j
+    for _ in range(cfg.hc_sinkhorn_iters):
+        res = res / (res.sum(axis=1, keepdims=True) + eps)
+        res = res / (res.sum(axis=0, keepdims=True) + eps)
+    error = jnp.maximum(jnp.abs(res.sum(axis=1) - 1.0).max(),
+                        jnp.abs(res.sum(axis=0) - 1.0).max())
+    return pre, post, res, error
+
+
+def hyper_connected(cfg: LlamaConfig, hc, x, sublayer):
+    """ONE sublayer on the stream, for every kind that could take it:
+    ``sublayer(h) -> (y, aux)`` reads ``h`` [B, T, dim] (its own norm is
+    its own) and returns what it adds. ``hc`` None (``hc_mult`` 1)::
+
+        x' = x + y                         the plain residual block
+
+    ``hc = (phi, b, alpha)``, this sublayer's, on ``x`` [B, T, n * dim]
+    (:func:`widen_stream`'s layout; the mixes: :func:`hyper_mix`, scope
+    ``hc.mix``)::
+
+        h     = sum_j H_pre[j] X[j]                          ``hc.read``
+        X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y         ``hc.write``
+
+    in float32, stored in the stream's type. Returns ``(x', aux, error)``,
+    ``error`` :func:`hyper_mix`'s (None without ``hc``)."""
+    if hc is None:
+        y, aux = sublayer(x)
+        return x + y.astype(x.dtype), aux, None
+    f32, n = jnp.float32, cfg.hc_mult
+    d = x.shape[-1] // n
+    with jax.named_scope("hc.mix"):
+        pre, post, res, error = hyper_mix(cfg, *hc, x)
+    rows = [x[..., j * d:(j + 1) * d].astype(f32) for j in range(n)]
+    with jax.named_scope("hc.read"):
+        h = sum(pre[j][..., None] * rows[j] for j in range(n))
+    y, aux = sublayer(h.astype(x.dtype))
+    with jax.named_scope("hc.write"):
+        y = y.astype(f32)
+        out = jnp.concatenate([
+            sum(res[i, j][..., None] * rows[j] for j in range(n))
+            + post[i][..., None] * y for i in range(n)], axis=-1)
+    return out.astype(x.dtype), aux, error
+
+
+def _latent_sublayers(cfg: LlamaConfig, kind: str, attend, x, p, positions,
+                      stat_axes, layer):
+    """The latent block's two sublayers (:func:`latent_block` has the
+    equations), each through :func:`hyper_connected`. ``attend(q, latent,
+    wkv_b)`` as :func:`_latent_half` takes it; ``p``: ONE layer's leaves,
+    its experts that layer's (``layer`` None) or the kind's whole stack with
+    ``layer`` its number (``routed_mlp``). Returns ``(x, stats, latent)``:
+    ``latent`` ``[B, T, latent_row]``, what a cache keeps; ``stats`` with
+    ``hc_sinkhorn_error`` where the stream is mixed."""
+    cd, eps = cfg.dtype, cfg.norm_eps
+    hc = [None, None] if cfg.hc_mult == 1 else [
+        (p["hc_phi"][j], p["hc_b"][j], p["hc_alpha"][j]) for j in (0, 1)]
+
+    def mla(h):
+        y, latent = _latent_half(
+            cfg, p, rms_norm(h, p["attn_norm"], eps).astype(cd), positions,
+            attend)
+        return checkpoint_name(y, "attn"), latent
+
+    def mlp(h):
+        h = rms_norm(h, p["mlp_norm"], eps).astype(cd)
+        if kind == "G":
+            with jax.named_scope("ffn.dense"):
+                return _dense_mlp(cfg, p, h), {}
+        return _mlp_half(cfg, p, h, stat_axes, layer=layer)
+
+    x, latent, first = hyper_connected(cfg, hc[0], x, mla)
+    x, stats, second = hyper_connected(cfg, hc[1], x, mlp)
+    if first is not None:
+        stats = {**stats, "hc_sinkhorn_error": jnp.maximum(first, second)}
+    return x, stats, latent
+
+
+def serve_latent_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
+                       attend, stat_axes=()):
+    """:data:`SERVED`'s block of the kinds ``"L"`` and ``"G"``:
+    :func:`latent_block`'s arithmetic on the kind's stacked weights
+    ``layers`` ``[n, ...]``, layer ``i``, over ``attend(q, latent, wkv_b)``
+    (prefill: :func:`attend_latent_expanded`; decode:
+    :func:`_attend_latent_cached`). Every leaf is cut out ``[i]`` but the
+    routed experts, which go down whole with ``layer=i``
+    (:func:`shortcut_layer` says why). Returns ``(x, stats, latent)``:
+    the layer's ``[c | k_r]`` rows ``[B, T, latent_row]``."""
+    whole = () if kind == "G" else ("w_gate", "w_up", "w_down")
+    p = {w: a if w in whole else a[i] for w, a in layers.items()}
+    return _latent_sublayers(cfg, kind, attend, x, p, positions, stat_axes,
+                             None if kind == "G" else i)
+
+
+def _note_stream(shares) -> None:
+    """Take what a prefill says of its stream out of ``shares`` (the
+    walker's ``hc_sinkhorn_error``) and set its gauge."""
+    error = shares.pop("hc_sinkhorn_error", None)
+    if error is not None:
+        _g_hc_sinkhorn_error.set(float(error))
+
+
+def yarn_frequencies(cfg: LlamaConfig):
+    """The latent half's rotation frequencies under ``cfg.rope_yarn``
+    (``[qk_rope_head_dim / 2]`` float32, host arithmetic): frequency ``i``
+    of ``f_i = theta ** (-2i / D)`` is kept where it turns more than
+    ``beta_fast`` times within the original context, divided by ``factor``
+    where fewer than ``beta_slow`` times, and blended linearly between::
+
+        low  = floor(D ln(original / (beta_fast 2 pi)) / (2 ln theta))
+        high = ceil(D ln(original / (beta_slow 2 pi)) / (2 ln theta))
+        ramp_i = clip((i - low) / (high - low), 0, 1)   both within [0, D-1]
+        f_i (1 - ramp_i) + f_i / factor ramp_i"""
+    import numpy as np
+
+    y, D = dict(cfg.rope_yarn), cfg.qk_rope_head_dim
+    f = cfg.rope_theta ** (-np.arange(0, D, 2, dtype=np.float64) / D)
+
+    def turns(beta):
+        return (D * math.log(y["original_max_position_embeddings"]
+                             / (beta * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = min(max(math.floor(turns(y.get("beta_fast", 32))), 0), D - 1)
+    high = min(max(math.ceil(turns(y.get("beta_slow", 1))), 0), D - 1)
+    ramp = np.clip((np.arange(D // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (f * (1 - ramp) + f / y["factor"] * ramp).astype(np.float32)
+
+
+def yarn_softmax_factor(cfg: LlamaConfig) -> float:
+    """What multiplies the latent half's scores beside ``1 / sqrt(score
+    width)`` under ``cfg.rope_yarn``: ``(0.1 mscale_all_dim ln(factor) +
+    1) ** 2`` (1 without it)."""
+    y = dict(cfg.rope_yarn)
+    if not y or y["factor"] <= 1:
+        return 1.0
+    return (0.1 * y.get("mscale_all_dim", 1) * math.log(y["factor"])
+            + 1.0) ** 2
+
+
+def _no_wide_latent(cfg: LlamaConfig, who: str, why: str) -> None:
+    """A train step refuses a stream of several rows (``hc_mult > 1``) and a
+    latent block whose score is not as wide as its value by name, as
+    :func:`_no_delta_kinds` refuses its kinds."""
+    unequal = bool(set(cfg.kinds) & set(LATENT_KINDS)) and (
+        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim != cfg.v_head_dim)
+    if cfg.hc_mult > 1 or unequal:
+        raise NotImplementedError(
+            f"{who} takes no hyper-connections (hc_mult={cfg.hc_mult}) and "
+            f"no latent block whose score width (qk_nope_head_dim + "
+            f"qk_rope_head_dim = "
+            f"{cfg.qk_nope_head_dim + cfg.qk_rope_head_dim}) is not its "
+            f"v_head_dim={cfg.v_head_dim} yet: {why}")

@@ -20,19 +20,25 @@ def rms_norm(x, weight, eps: float = 1e-5):
 
 
 def rotary_embedding(q, k, positions, theta: float = 500000.0,
-                     interleaved: bool = False):
+                     interleaved: bool = False, inv_freq=None):
     """Apply RoPE to q,k of shape [B, T, H, D]; positions [B, T] or [T].
 
     theta=500000 is the Llama-3 base frequency. Frequency ``i`` turns the
     pair ``(i, i + D/2)`` (the half-split "rotate_half" convention), or,
     ``interleaved``, the pair ``(2i, 2i + 1)``; the result keeps the
-    layout it was given.
+    layout it was given. ``inv_freq`` ``[D / 2]``: the frequencies
+    themselves where they are not ``theta``'s powers (a scaled rotation's
+    blend, YaRN's).
     """
     dtype = q.dtype
     D = q.shape[-1]
     if positions.ndim == 1:
         positions = positions[None, :]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32)
+                                    / D))
+    else:
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B,T,D/2]
     cos = jnp.cos(angles)[:, :, None, :]  # [B,T,1,D/2]
     sin = jnp.sin(angles)[:, :, None, :]

@@ -683,6 +683,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     from ray_tpu.collective import pmean_tree
     from ray_tpu.models.llama import (
         _no_delta_kinds,
+        _no_wide_latent,
         _no_window_kinds,
         _plain_chunk_nll,
         add_mtp_loss,
@@ -722,6 +723,12 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         "no train step is held to a reference for the gated delta rule's "
         "backward (autodiff through ops/gdn.py's chunked form) or the gated "
         "attention's; models.llama.loss_fn runs the forward of both")
+    _no_wide_latent(
+        cfg, "make_spmd_train_step",
+        "no train step is held to a reference for the mixes' backward or "
+        "keeps a stream of several rows' recomputation in its account, and "
+        "its flash kernel attends q, k and v of one width; "
+        "models.llama.loss_fn runs the forward")
 
     tensor = ("tensor" if "tensor" in mesh.axis_names
               and mesh.shape["tensor"] > 1 else None)

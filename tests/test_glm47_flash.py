@@ -505,9 +505,11 @@ def test_the_engine_refuses_the_kinds_by_name():
     with pytest.raises(NotImplementedError) as e:
         llama.LlamaDecodeEngine(program_cfg())
     msg = str(e.value)
-    assert "'L' / 'G'" in msg and "prediction module" in msg
-    assert "serving them is not built" in msg
-    assert "L" not in llama.SERVED and "G" not in llama.SERVED
+    # the blocks are served since PR 52 (tests/test_xing4.py holds an engine
+    # of this kind to this file's reference); the module still is not
+    assert "prediction module" in msg and "mtp_layers=1" in msg
+    assert "more than one token a call" in msg
+    assert "L" in llama.SERVED and "G" in llama.SERVED
     assert llama.served_kinds(LlamaConfig.debug()) == "bb"
 
 
@@ -529,7 +531,7 @@ def test_the_spmd_step_refuses_it_on_fsdp_or_tensor(spec_str, n):
 
 
 @pytest.mark.parametrize("over,says", [
-    ({"v_head_dim": 16}, "one width"),
+    ({"v_head_dim": 0}, "v_head_dim"),
     ({"layer_pattern": "GLE"}, "every built layer is one of the two"),
     ({"mtp_layers": 2}, "one multi-token-prediction module or none"),
     ({"layer_pattern": "GGG"}, "its block is an 'L' layer's"),

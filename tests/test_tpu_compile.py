@@ -353,6 +353,9 @@ PREFILL = {
     # and values are 16 MB each at 32,768 positions, twice
     "qwen3next gated 3 pages": (6144, 16, 2, 256, 256, 0, 256),
     "qwen3next gated 16 pages": (32768, 16, 2, 256, 256, 0, 256),
+    # 32 heads of 128 + 64 that return 128, at the cell's two ends
+    "xing4 2 pages": (2048, 32, 32, 192, 128, 0),
+    "xing4 16 pages": (16384, 32, 32, 192, 128, 0),
 }
 
 
@@ -586,3 +589,34 @@ def test_held_sum_compiles_at_the_cells_shapes(what, one_chip):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == 4 * tokens * d
     assert memory.temp_size_in_bytes < 2 ** 24  # the table's one-hots
+
+
+@pytest.mark.parametrize("program,pages", [
+    ("prefill", 2), ("prefill", 16), ("decode", 3), ("decode", 16)])
+def test_xing4_cells_programs_compile_and_fit_the_chip(
+        program, pages, one_chip, as_on_the_chip):
+    """The hyper-connected latent blocks' WHOLE prefill and decode programs
+    at the cell's published widths, its two stores and its shortest and
+    longest page tables, from shapes alone: beside 9.05 GB of weights and
+    stores (every expert, the whole vocabulary) a 16-page prefill, whose
+    float32 stream is 0.94 GB a copy, must stay inside the chip. Prefill
+    holds the flash kernel once a layer (the split score: 128 + 64 against
+    128) and the experts' grouped kernel once a routed layer; decode holds
+    neither."""
+    lowered, stores = _cell_program("serve-xing4-prefill-open", program,
+                                    pages, one_chip)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes
+    assert 9.0e9 < held < 9.1e9
+    assert memory.alias_size_in_bytes >= sum(
+        4 * math.prod(a.shape) for a in stores)  # both stores in place
+    assert held + memory.temp_size_in_bytes < 13.0e9
+    text = compiled.as_text()
+    prefill = program == "prefill"
+    assert len(re.findall(r"%flash_prefill[.\d]* = ", text)) == 5 * prefill
+    assert len(re.findall(r"%moe_ffn[.\d]* = ", text)) == 4 * prefill
+    if prefill:
+        assert memory.temp_size_in_bytes < {2: 0.9e9, 16: 3.6e9}[pages]
+    else:
+        assert memory.temp_size_in_bytes < 0.6e9
