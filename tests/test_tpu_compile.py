@@ -4,7 +4,9 @@ interpret mode cannot refuse, at no chip time. Nothing runs, so nothing here
 is a result or a time. All such compiles live in THIS file: the process that
 describes the topology holds the TPU library until it exits."""
 
+import json
 import math
+import os
 import re
 
 import pytest
@@ -42,9 +44,12 @@ def as_on_the_chip(monkeypatch):
     compilation_cache.reset_cache()
 
 
-# (batch, sequence, query heads, key/value heads, the dK/dV call asks for a
-# VMEM limit): the cells' attention, and the longest sequence whose buffers
-# (15.0 MiB) still fit the default limit, with the kernel's own tiles
+# (batch, sequence, query heads, key/value heads, a call asks for a VMEM
+# limit): the cells' attention and two lengths 1,024 does not divide or
+# divides five times. What asks follows the loop step (pick_blocks): 512 x
+# 2,048 at 8,192 positions (every call asks: a backward step's five tiles are
+# 20 MiB), 512 x 1,024 at 4,096 (none: dK/dV's buffers and tiles are 16 MiB
+# to the byte) and at 5,120 (dQ and dK/dV), 512 x 512 at 2,048 and 4,608
 SHAPES = {
     "train-nemotron3nano-1chip": (2, 8192, 32, 2, True),
     "train-olmoe-1chip": (4, 4096, 16, 16, False),
@@ -57,10 +62,9 @@ SHAPES = {
 @pytest.mark.parametrize("cell", list(SHAPES))
 def test_flash_kernels_compile_at_the_cells_shapes(cell, one_chip,
                                                    as_on_the_chip):
-    """Forward, dQ and dK/dV at head width 128. At 8,192 positions the
-    dK/dV call's buffers are 25.5 MiB of VMEM, over the compiler's default
-    16 MiB: it asks for its limit. The calls that fit the default (every
-    cell the benchmark had before) ask for nothing, as they always did."""
+    """Forward, dQ and dK/dV at head width 128. A call whose resident blocks
+    and float32 tiles pass the compiler's default 16 MiB of VMEM asks for
+    its limit; the calls that fit the default ask for nothing."""
     from ray_tpu.ops.flash_attention import flash_attention
 
     b, t, hq, hkv, asks = SHAPES[cell]
@@ -78,15 +82,28 @@ def test_flash_kernels_compile_at_the_cells_shapes(cell, one_chip,
     # a limit that is asked for is the call's scoped_memory_configs
     assert ("scoped_memory_configs" in lowered.as_text()) == asks
     compiled = lowered.compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
-        >= 3
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    # the yardstick tells the three apart by result types, in the compiled
+    # program's own text: each of its patterns finds ONE of the calls
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "layer_metrics", "flash_roofline.json")) as f:
+        kernels = json.load(f)["args"]["kernels"]
+    found = {name: [c.split(" = ")[0] for c in calls
+                    if re.search(spec["pattern"], c)]
+             for name, spec in kernels.items()}
+    assert {name: len(hits) for name, hits in found.items()} == {
+        "flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}, found
+    assert all(name in hits[0] for name, hits in found.items()), found
 
 
 def test_flash_kernels_compile_at_head_width_256(one_chip, as_on_the_chip):
     """``train-glm47flash-1chip``'s attention, [2, 8192, 20, 256] on 20 KV
     heads: a head group's whole K and V are 17.5 MiB of VMEM in the forward
-    call (18.5 in dQ; a head's whole q, do, lse and delta 35 in dK/dV), so
-    ALL THREE ask for their limit, where at width 128 only dK/dV does."""
+    call (18.5 in dQ; a head's whole q and do 16.5 in dK/dV), so ALL THREE
+    ask for their limit whatever the loop step."""
     from ray_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
