@@ -48,7 +48,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.layers import rms_norm, rotary_embedding
+from ray_tpu.ops.layers import (layer_norm, rms_norm, rotary_embedding,
+                                rotate_pairs)
 from ray_tpu.parallel.ring_attention import plain_attention, ring_attention_local
 from ray_tpu.parallel.sharding import DEFAULT_RULES, logical_sharding
 from ray_tpu.util import flight_recorder as _fr
@@ -213,7 +214,8 @@ def keep_policy(keep):
 # train step takes that)
 LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
                "F": "block", "W": "block", "I": "index", "D": "delta",
-               "A": "gated", "L": "latent", "G": "latent_dense"}
+               "A": "gated", "L": "latent", "G": "latent_dense",
+               "P": "parallel", "R": "parallel"}
 # the kinds that are a WHOLE block (attention THEN the routed MLP, whose
 # router reads the attention's normed input; window_block): "F" attends over
 # every earlier position without rotation, "W" over the last cfg.window with
@@ -226,6 +228,14 @@ DELTA_KINDS = "DA"
 # the latent-attention blocks outside "S" (latent_block, at this file's end):
 # whole blocks, a stack each (their MLPs' leaves differ)
 LATENT_KINDS = "LG"
+# the PARALLEL blocks (parallel_block, at this file's end): ONE
+# mean-subtracting norm a layer feeds the attention AND the routed MLP, and
+# both are added to the stream. "P" attends over every earlier position
+# without rotation, "R" over the last cfg.window with rotation. The same
+# leaves, ONE stack in layer order (as "F" / "W")
+PARALLEL_KINDS = "PR"
+# the kinds whose attention has cfg.window (and rotates)
+WINDOW_KINDS = "WR"
 # Where their matrices start off the square root of their fan-in (seeded random
 # weights; a checkpoint brings its own): ``wo`` 12 times as wide, the router
 # 6 times, so that BOTH halves carry the logits and a comparison of logits
@@ -471,6 +481,20 @@ class LlamaConfig:
     # sorted pairs. Weights alone: no program reads it, a checkpoint brings
     # its own. Empty: every matrix over the square root of its fan-in
     seeded_scales: Any = ()
+    # the "P" / "R" layers (parallel_block): norm_kind "layer" is the
+    # mean-subtracting LayerNorm without a bias (ops/layers.py layer_norm),
+    # the blocks' ONE norm and the model's final one; rope_interleaved turns
+    # the pairs (2i, 2i + 1) of a head and not (i, i + D/2); shared_experts
+    # ungated SwiGLU experts of shared_mlp_dim EACH run on every token and
+    # their MEAN is added ("average", the one combination a reference holds;
+    # the program keeps them as one expert shared_experts times as wide whose
+    # down product is divided by their count); logit_scale multiplies the
+    # logits
+    norm_kind: str = "rms"
+    rope_interleaved: bool = False
+    shared_experts: int = 1
+    shared_combine: str = "average"
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         object.__setattr__(self, "mrope_section", tuple(self.mrope_section))
@@ -609,10 +633,42 @@ class LlamaConfig:
                 "reference), and only the 'L' / 'G' layers' latent half "
                 "reads it")
         blocks = set(self.kinds) & set(BLOCK_KINDS)
-        if ("W" in self.kinds) != bool(self.window) or self.window < 0:
+        if (bool(set(self.kinds) & set(WINDOW_KINDS)) != bool(self.window)
+                or self.window < 0):
             raise ValueError(
                 f"window={self.window} with kinds {self.kinds!r}: a 'W' "
-                "layer needs a window, and only a 'W' layer has one")
+                "layer needs a window, and only a 'W' layer has one (as an "
+                "'R' layer)")
+        parallel = set(self.kinds) & set(PARALLEL_KINDS)
+        if parallel and not (
+                set(self.kinds) <= set(PARALLEL_KINDS)
+                and self.norm_kind == "layer" and self.num_experts
+                and self.experts_per_token and self.mlp_act == "swiglu"
+                and self.rope and not self.qk_norm and not self.zero_experts
+                and self.routed_scale == 1.0 and self.shared_mlp_dim
+                and self.shared_experts >= 1
+                and self.shared_combine == "average"
+                and self.head_dim % 2 == 0):
+            raise ValueError(
+                "a 'P' / 'R' layer is ONE LayerNorm (norm_kind 'layer') that "
+                "feeds attention ('R': rotated, over cfg.window; 'P': not "
+                "rotated, over everything) and a routed SwiGLU MLP "
+                "(num_experts, experts_per_token; sigmoid or softmax scores "
+                "and a held range may be set) beside the mean of "
+                "shared_experts ungated shared experts of shared_mlp_dim "
+                "(shared_combine 'average'): every built layer is one of the "
+                "two, and it has no QK-norm, identity expert or weight scale")
+        if not parallel and (
+                self.norm_kind != "rms" or self.rope_interleaved
+                or self.shared_experts != 1 or self.logit_scale != 1.0
+                or self.shared_combine != "average"):
+            raise ValueError(
+                f"norm_kind={self.norm_kind!r}, rope_interleaved="
+                f"{self.rope_interleaved}, shared_experts="
+                f"{self.shared_experts}, shared_combine="
+                f"{self.shared_combine!r}, logit_scale={self.logit_scale}: "
+                "only a stack of 'P' / 'R' layers reads them (no test holds "
+                "another kind to a reference with any of them)")
         if blocks and (
                 not (self.num_experts and self.experts_per_token)
                 or self.mlp_act == "relu2" or not self.rope or self.qk_norm
@@ -644,12 +700,20 @@ class LlamaConfig:
     def window_layout(self):
         """The whole pattern as a published config lists it: 1 a layer
         whose attention has the window, else 0."""
-        return [int(kind == "W") for kind in self.layer_pattern]
+        return [int(kind in WINDOW_KINDS) for kind in self.layer_pattern]
 
     @property
     def rope_layout(self):
         """1 a layer of the whole pattern whose attention rotates."""
-        return [int(kind in "WSIALG" or (kind == "*" and self.rope))
+        return [int(kind in "WSIALGR" or (kind == "*" and self.rope))
+                for kind in self.layer_pattern]
+
+    @property
+    def layer_types(self):
+        """The whole pattern as a published config of window and full
+        attention layers names them."""
+        return ["sliding_attention" if kind in WINDOW_KINDS
+                else "full_attention"
                 for kind in self.layer_pattern]
 
     @property
@@ -736,6 +800,13 @@ class LlamaConfig:
             per_kind["F"] = per_kind["W"] = (
                 2 * d * q + 2 * d * kv + d * self.num_experts
                 + self.num_experts * 3 * d * self.mlp_dim + 2 * d)
+            # the parallel block: attention, the router, the held experts,
+            # shared_experts shared ones and ONE norm
+            per_kind["P"] = per_kind["R"] = (
+                2 * d * q + 2 * d * kv
+                + d * (self.router_experts or self.num_experts)
+                + self.num_experts * 3 * d * self.mlp_dim
+                + self.shared_experts * 3 * d * self.shared_mlp_dim + d)
             hi, di = self.index_heads, self.index_head_dim
             per_kind["I"] = (
                 2 * d * q + 2 * d * kv + 2 * self.head_dim
@@ -906,6 +977,14 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             kinds["latent"].update(shared_gate=("layers", "embed", "mlp"),
                                    shared_up=("layers", "embed", "mlp"),
                                    shared_down=("layers", "mlp", "embed"))
+        # the parallel block's: ONE norm, the whole block's attention, router
+        # and experts, and the shared experts side by side as one wide one
+        kinds["parallel"] = dict(
+            {w: ax for w, ax in kinds["block"].items()
+             if w not in ("attn_norm", "mlp_norm")},
+            norm=("layers", None), shared_gate=("layers", "embed", "mlp"),
+            shared_up=("layers", "embed", "mlp"),
+            shared_down=("layers", "mlp", "embed"))
         if cfg.hc_mult > 1:  # a sublayer's mix: small, whole on every device
             for kind in ("latent", "latent_dense"):
                 kinds[kind].update(hc_phi=("layers", None, None, None),
@@ -1084,6 +1163,8 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
     for c in LATENT_KINDS:
         if n[c]:
             out[LAYER_KINDS[c]] = _init_latent_kind(cfg, c, n[c], key)
+    if n["P"] + n["R"]:
+        out["parallel"] = _init_parallel(cfg, n["P"] + n["R"], key)
     return out
 
 
@@ -1254,6 +1335,9 @@ def _qkv(cfg: LlamaConfig, p, h, n_q: int, n_kv: int, positions,
     kk = project("wk", n_kv, "k_norm" if cfg.qk_norm else None)
     vv = project("wv", n_kv)
     if cfg.rope if rope is None else rope:
+        if cfg.rope_interleaved:
+            return (*(rotate_pairs(a, positions, cfg.rope_theta)
+                      for a in (q, kk)), vv)
         q, kk = rotary_embedding(q, kk, positions, cfg.rope_theta)
     return q, kk, vv
 
@@ -2378,9 +2462,8 @@ def _backbone(cfg: LlamaConfig, params, tokens, mesh=None):
         x, stats = pattern_stack(
             cfg, widen_stream(cfg, x), params["layers"],
             lambda q, k, v: _attention(cfg, q, k, v, mesh))
-        return rms_norm(collapse_stream(cfg, x),
-                        _final_gain(cfg, params["final_norm"]),
-                        cfg.norm_eps), stats
+        return _final_norm(cfg, collapse_stream(cfg, x),
+                           params["final_norm"]), stats
     positions = positions_of(*tokens.shape)
 
     layer_fn = partial(_layer, cfg, mesh)
@@ -2402,18 +2485,24 @@ def _head(cfg: LlamaConfig, params):
 def _logits(cfg: LlamaConfig, x, head):
     """Final-normed hidden states through ``head`` [dim, vocab] (whole or a
     vocabulary shard): the product in cfg.dtype, the logits float32."""
-    return checkpoint_name(x.astype(cfg.dtype) @ head.astype(cfg.dtype),
-                           "head").astype(jnp.float32)
+    logits = checkpoint_name(x.astype(cfg.dtype) @ head.astype(cfg.dtype),
+                             "head").astype(jnp.float32)
+    return logits if cfg.logit_scale == 1.0 else logits * cfg.logit_scale
 
 
 def _final_gain(cfg: LlamaConfig, final_norm):
     return 1.0 + final_norm if cfg.zero_centered else final_norm
 
 
+def _final_norm(cfg: LlamaConfig, x, final_norm):
+    """The model's last norm, of the kind ``cfg.norm_kind`` names."""
+    norm = layer_norm if cfg.norm_kind == "layer" else rms_norm
+    return norm(x, _final_gain(cfg, final_norm), cfg.norm_eps)
+
+
 def head_logits(cfg: LlamaConfig, x, final_norm, head):
     """The model's end: final norm -> head -> float32 logits."""
-    return _logits(cfg, rms_norm(x, _final_gain(cfg, final_norm),
-                                 cfg.norm_eps), head)
+    return _logits(cfg, _final_norm(cfg, x, final_norm), head)
 
 
 def forward(cfg: LlamaConfig, params, tokens, mesh=None):
@@ -2653,6 +2742,8 @@ def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv,
     Float32 scores."""
     cd = cfg.dtype
     Tpad = k_cache.shape[0]
+    _note_decode_attend("repeated", q, k_cache, "the kind's row of SERVED "
+                        "attends through _attend_cached")
     K = jnp.concatenate([k_cache.astype(cd)[None], kk], axis=1)
     V = jnp.concatenate([v_cache.astype(cd)[None], vv], axis=1)
     K, V = _gqa_repeat(cfg, K, V)
@@ -2733,7 +2824,9 @@ class Served(NamedTuple):
     whole}``). ``attended(cfg, program, n)`` -> ``(visible, attended)``
     keys of a prefill of ``n`` tokens or the decode of the ``n``-th, for a
     kind that attends fewer than it sees
-    (``ray_tpu_serve_engine_selected_share{program}``)."""
+    (``ray_tpu_serve_engine_selected_share{program}``). ``alone``: a stack
+    of SOME of the family's kinds is served too (a test holds such a stack's
+    logits to a reference)."""
     family: str
     stack: Optional[str]
     block: Callable
@@ -2742,6 +2835,7 @@ class Served(NamedTuple):
     prefill: Callable
     decode: Callable
     attended: Optional[Callable] = None
+    alone: bool = False
 
 
 class Table(NamedTuple):
@@ -2901,6 +2995,28 @@ SERVED: Dict[str, Served] = {
         lambda cfg, last, *a: attend_latent_expanded(cfg, *a),
         lambda cfg, call, l, mine, *a: _attend_latent_cached(
             cfg, mine[0], call.pos, *a)) for c in LATENT_KINDS},
+    # the parallel blocks (parallel_block, at this file's end), ONE stack in
+    # layer order: keys and values as "F" / "W" keep them, the full layers'
+    # by page id and the window layers' by SLOT, under tags of their own.
+    # Decode scores a KV head's query heads against its keys as ONE product
+    # and repeats no key (_attend_grouped). The stream is float32 (a top-k
+    # choice is a hard one). A stack of window layers alone is served too
+    # (a cell's rehearsal cuts the pattern inside its first period)
+    "P": Served(
+        "parallel", "parallel",
+        lambda cfg, *a: parallel_block(cfg, "P", *a), True,
+        partial(_kv_rows, tag="parallel_full"),
+        lambda cfg, last, *a: attend_parallel_tiles(cfg, "P", *a),
+        lambda cfg, call, l, mine, *a: _attend_grouped(
+            cfg, "parallel_full", *mine, call.pos, *a), alone=True),
+    "R": Served(
+        "parallel", "parallel",
+        lambda cfg, *a: parallel_block(cfg, "R", *a), True,
+        partial(_kv_rows, tag="parallel_window", table="slot"),
+        lambda cfg, last, *a: attend_parallel_tiles(cfg, "R", *a),
+        lambda cfg, call, l, mine, *a: _attend_grouped(
+            cfg, "parallel_window", *mine, call.pos - call.base, *a,
+            lowest=call.pos - cfg.window + 1 - call.base), alone=True),
 }
 
 
@@ -3365,8 +3481,10 @@ class LlamaDecodeEngine:
         for c, kind in SERVED.items():
             families[kind.family] = families.get(kind.family, "") + c
         kinds = set(served_kinds(self.cfg))
+        part = any(kinds <= set(family) and all(SERVED[c].alone for c in kinds)
+                   for family in families.values())
         if not (self.cfg.layer_pattern
-                and kinds in map(set, families.values())):
+                and (part or kinds in map(set, families.values()))):
             _dense_only(  # a dense stack's own refusals, or the pattern's
                 self.cfg, "LlamaDecodeEngine",
                 f"it serves the kinds of ONE family of its table (SERVED: "
@@ -3681,6 +3799,10 @@ def make_train_step(cfg: LlamaConfig, mesh, optimizer=None, rules=None):
     # same params on every mesh layout because jax.random is
     # sharding-invariant (test_parallelism_consistency)
     rules = rules or DEFAULT_RULES
+    _no_parallel_kinds(
+        cfg, "make_train_step",
+        "no train step is held to a reference for the parallel block's "
+        "backward; models.llama.loss_fn runs its forward")
     _no_wide_latent(
         cfg, "make_train_step",
         "no train step is held to a reference for the mixes' backward, and "
@@ -3852,6 +3974,9 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
         "its stages run the dense block alone, and the prediction module's "
         "second loss needs the last stage's stream AND the first stage's "
         "embedding")
+    _no_parallel_kinds(
+        cfg, "make_pipeline_train_step",
+        "its stages run the dense block alone")
     _no_window_kinds(
         cfg, "make_pipeline_train_step",
         "its stages run the dense block over the flash kernel, which has "
@@ -4636,3 +4761,225 @@ def _no_wide_latent(cfg: LlamaConfig, who: str, why: str) -> None:
             f"qk_rope_head_dim = "
             f"{cfg.qk_nope_head_dim + cfg.qk_rope_head_dim}) is not its "
             f"v_head_dim={cfg.v_head_dim} yet: {why}")
+
+
+# --------------------------------------------------------------------------- #
+# The parallel blocks (kinds "P" and "R"): one norm feeds attention AND the
+# routed MLP, window and full layers in one stack, a decode attend that
+# repeats no key. At this file's end for attend_delta's reason
+# --------------------------------------------------------------------------- #
+
+# which FORM a decode call's attention took, counted where a program is
+# traced: "grouped" (_attend_grouped: a KV head's query heads against its keys
+# as ONE product, the keys read once in the type the store keeps them in) or
+# "repeated" (_attend_cached: every key and value repeated for each query head
+# of its group and widened to float32). A kind's row of SERVED says which;
+# decode_attend_forms() keeps the shapes and the reason beside the count
+_g_engine_decode_attend = Gauge(
+    "ray_tpu_serve_engine_decode_attend",
+    "Decode attentions traced into the decode engine's programs, by form: "
+    "grouped (a KV head's query heads in one product, no key repeated) or "
+    "repeated (keys and values repeated per query head)",
+    tag_keys=("form",))
+
+# (form, q shape, view shape) -> {.., "calls"}
+_decode_attend_taken: Dict[tuple, dict] = {}
+
+
+def decode_attend_forms() -> list:
+    """Every distinct decode attention (form, shapes) traced in this
+    process, with its reason and how often: how a run proves that its
+    decode programs repeat no key (beside :func:`prefill_attend_paths`)."""
+    with _paths_lock:
+        return [dict(rec) for rec in _decode_attend_taken.values()]
+
+
+def _note_decode_attend(form, q, view, reason) -> None:
+    key = (form, q.shape, view.shape)
+    with _paths_lock:
+        rec = _decode_attend_taken.setdefault(key, {
+            "form": form, "q_shape": list(q.shape),
+            "view_shape": list(view.shape), "view_dtype": view.dtype.name,
+            "reason": reason, "calls": 0})
+        rec["calls"] += 1
+        counts = {way: sum(r["calls"] for r in _decode_attend_taken.values()
+                           if r["form"] == way)
+                  for way in ("grouped", "repeated")}
+    for way, n in counts.items():
+        _g_engine_decode_attend.set(float(n), tags={"form": way})
+
+
+# Where a parallel block's SEEDED matrices start off the square root of their
+# fan-in (a checkpoint brings its own), for BLOCK_INIT's reason: so that a
+# comparison of logits sees a fault in either half and does not trip over a
+# sound engine's moved choice. At fan-in scaling a layer adds the four shared
+# experts' mean (0.30 an element), ONE held expert's eighth where a token
+# chose one (0.075; a sigmoid router's renormalised weights are 1/8 each
+# however wide it starts, so no router scale helps) and an attention over
+# thousands of keys whose scores are one unit wide, nearly an average (0.025).
+# The 8th and 9th of 128 router logits lie 0.06 apart, a stream that differs
+# by bfloat16's rounding moves that choice in one token-layer of twenty, and
+# where a held expert is the one moved that is 0.075 on a stream of 0.6: 12%
+# of a logit in one run of six, while a rotation or a window would show
+# nothing. ``wq`` twice as wide makes scores two units wide (some 75 of 4,096
+# keys carry a query's weight and which keys a layer sees matters); ``wo``
+# sets the attention's share so that a moved choice stays near 1% of a logit.
+# WHICH experts are chosen, their load and every product's shape and time are
+# the same at any of them. Readings: the configuration file's ``correct``.
+PARALLEL_INIT = {"wq": 2.0, "wo": 24.0}
+
+
+def _no_parallel_kinds(cfg: LlamaConfig, who: str, why: str) -> None:
+    """A train step refuses the ``"P"`` / ``"R"`` kinds by name, as
+    :func:`_no_delta_kinds` refuses its kinds."""
+    if set(cfg.kinds) & set(PARALLEL_KINDS):
+        raise NotImplementedError(
+            f"{who} takes no 'P' / 'R' layer (layer_pattern="
+            f"{cfg.layer_pattern!r}) yet: {why}")
+
+
+def _init_parallel(cfg: LlamaConfig, L: int, key):
+    """The ``L`` stacked parallel blocks, keys of their own. Every matrix
+    over the square root of its fan-in but :data:`PARALLEL_INIT`'s; the norm
+    at one. The ``shared_experts`` shared experts lie side by side: gate and
+    up ``[dim, n * shared_mlp_dim]`` (expert ``j``: columns ``j * width ..``),
+    down ``[n * shared_mlp_dim, dim]`` (its rows), each drawn over ONE
+    expert's fan-in."""
+    d, hd, f = cfg.dim, cfg.head_dim, cfg.mlp_dim
+    nq, nkv, held = cfg.n_heads, cfg.n_kv_heads, cfg.num_experts
+    fs, n = cfg.shared_mlp_dim, cfg.shared_experts
+    k = iter(jax.random.split(jax.random.fold_in(key, 11), 12))
+    dense = _dense_init
+    return {
+        "norm": jnp.ones((L, d), jnp.float32),
+        "wq": PARALLEL_INIT["wq"] * dense(next(k), (L, d, nq * hd), d),
+        "wk": dense(next(k), (L, d, nkv * hd), d),
+        "wv": dense(next(k), (L, d, nkv * hd), d),
+        "wo": PARALLEL_INIT["wo"] * dense(next(k), (L, nq * hd, d), nq * hd),
+        "router": dense(next(k), (L, d, cfg.router_experts or held), d),
+        "w_gate": dense(next(k), (L, held, d, f), d),
+        "w_up": dense(next(k), (L, held, d, f), d),
+        "w_down": dense(next(k), (L, held, f, d), f),
+        "shared_gate": dense(next(k), (L, d, n * fs), d),
+        "shared_up": dense(next(k), (L, d, n * fs), d),
+        "shared_down": dense(next(k), (L, n * fs, d), fs),
+    }
+
+
+def parallel_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
+                   attend, stat_axes=()):
+    """THE parallel block of the kinds ``"P"`` and ``"R"``, for the full
+    forward and for the serving programs (``LN``: :func:`layer_norm`, the
+    mean subtracted, no bias)::
+
+        a   = LN(x)                       ONE norm a layer
+        A   = Attention(a) Wo             "R": q, k rotated in pairs (2i,
+                                          2i + 1), over the last cfg.window
+                                          positions; "P": not rotated, over
+                                          every earlier one
+        R   = sum_e w_e SwiGLU_e(a)       w = s / sum of the chosen s, s =
+                                          sigmoid(a Wr) in float32; the held
+                                          experts' part of it
+        S   = 1/n sum_j SwiGLU_j(a)       the n shared experts, as ONE
+                                          expert n times as wide
+        out = x + A + R + S               attention and MLP read the SAME a
+
+    ``layers``: the ``parallel`` stack ``[L, ...]`` both kinds share, ``i``:
+    which layer (a number, or traced in a scan); every matrix is cut out
+    ``[i]`` where it is used but the routed experts, which go down whole with
+    ``layer=i`` (:func:`shortcut_layer` says why). The stream keeps the type
+    it comes in (float32 in the serving programs), the router reads ``a`` in
+    that type and every product its rounding to ``cfg.dtype``.
+    ``attend(q, k, v)`` as :func:`decoder_block` takes it. Device scopes
+    ``par.norm``, ``par.qkv``, ``attn.window`` / ``attn.full``, ``par.out``,
+    ``moe.route`` / ``.dispatch`` / ``.experts`` / ``.combine`` and
+    ``moe.shared``. Returns ``(x, stats, (k, v))``: ``k`` rotated for
+    ``"R"`` and as projected for ``"P"``."""
+    from ray_tpu.ops.moe import routed_mlp
+
+    cd, hd = cfg.dtype, cfg.head_dim
+    B, T, _ = x.shape
+    with jax.named_scope("par.norm"):
+        a = layer_norm(x, layers["norm"][i], cfg.norm_eps)
+        h = a.astype(cd)
+    with jax.named_scope("moe.shared"):
+        g = jax.nn.silu((h @ layers["shared_gate"][i].astype(cd)
+                         ).astype(jnp.float32))
+        u = h @ layers["shared_up"][i].astype(cd)
+        s = ((g * u).astype(cd) @ layers["shared_down"][i].astype(cd)
+             ).astype(jnp.float32) / cfg.shared_experts
+    # ONE half at a time: both read ``h`` and nothing else ties them, and a
+    # scheduler that runs them side by side holds the shared experts' gate
+    # and up ([T, n * width] each) beside q, the attention's output and their
+    # transposes: 5.0 GB of temporaries at 16,384 positions against 3.0 in
+    # turn (3.2 without the second barrier below; compiled for a described
+    # v5e, PR 54)
+    out, h = jax.lax.optimization_barrier((x + s.astype(x.dtype), h))
+    with jax.named_scope("par.qkv"):
+        q, k, v = _qkv(cfg, {w: layers[w][i] for w in ("wq", "wk", "wv")}, h,
+                       cfg.n_heads, cfg.n_kv_heads, positions,
+                       rope=kind == "R")
+    with jax.named_scope("attn.window" if kind == "R" else "attn.full"):
+        o = attend(q, k, v)
+    with jax.named_scope("par.out"):
+        out = out + (o.reshape(B, T, cfg.n_heads * hd)
+                     @ layers["wo"][i].astype(cd)).astype(x.dtype)
+    out, h = jax.lax.optimization_barrier((out, h))
+    wide = cfg.router_experts or cfg.num_experts
+    # the float32 sum goes onto the stream as it is
+    y, stats = routed_mlp(
+        h, layers["router"][i], layers["w_gate"], layers["w_up"],
+        layers["w_down"], top_k=cfg.experts_per_token,
+        norm_topk_prob=cfg.norm_topk_prob, stat_axes=stat_axes,
+        scoring=cfg.router_scoring,
+        held=((cfg.first_expert, cfg.num_experts)
+              if wide != cfg.num_experts else None),
+        layer=i, router_input=a)
+    return out + y.astype(x.dtype), stats, (k, v)
+
+
+def attend_parallel_tiles(cfg: LlamaConfig, kind: str, q, k, v):
+    """:func:`parallel_block`'s ``attend`` over the call's own positions (the
+    full forward and prefill): :func:`attend_tiles` with the band for
+    ``"R"``, counted under kinds of their own."""
+    return attend_tiles(q, k, v, cfg.dtype,
+                        window=cfg.window if kind == "R" else 0,
+                        kind="parallel_window" if kind == "R"
+                        else "parallel_full")
+
+
+def _attend_grouped(cfg: LlamaConfig, tag: str, k_cache, v_cache, length, q,
+                    kk, vv, lowest=None):
+    """:func:`_attend_cached`'s attention, masks and arguments, WITHOUT its
+    copies: the ``n_heads / n_kv_heads`` query heads of a KV head score
+    against that head's keys as ONE product (``[n_kv, rep, D] x [Tpad, n_kv,
+    D]``), the views are read once in the type the store keeps them in
+    (float32 that holds the compute type's values: a program of this engine
+    wrote them), nothing is repeated per query head and nothing is
+    concatenated: the token's own key and value (``kk`` / ``vv``, not in the
+    store yet) enter the softmax as one more term. Query head ``h`` reads KV
+    head ``h // rep``. At 16 query heads a KV head and 16,385 positions the
+    repeated form writes and reads two ``[16385, 128, 128]`` float32 arrays
+    (2.1 GB) a layer a token. Float32 scores."""
+    f32 = jnp.float32
+    G, D = cfg.n_kv_heads, cfg.head_dim
+    Tpad = k_cache.shape[0]
+    _note_decode_attend(
+        "grouped", q, k_cache,
+        f"{tag}: {cfg.n_heads // G} query heads a KV head in one product, "
+        f"the views read as kept ({k_cache.dtype.name})")
+    qg, scale = q[0, 0].reshape(G, -1, D).astype(f32), 1.0 / math.sqrt(D)
+    s = jnp.einsum("grd,tgd->grt", qg, k_cache.astype(f32)) * scale
+    own = jnp.einsum("grd,gd->gr", qg,
+                     kk[0, 0].astype(f32))[..., None] * scale
+    idx = jnp.arange(Tpad)
+    valid = idx < length
+    if lowest is not None:
+        valid &= idx >= lowest
+    s = jnp.where(valid, s, -1e30)
+    top = jnp.maximum(s.max(axis=-1, keepdims=True), own)
+    p, p_own = jnp.exp(s - top), jnp.exp(own - top)
+    o = jnp.einsum("grt,tgd->grd", p, v_cache.astype(f32)) \
+        + p_own * vv[0, 0].astype(f32)[:, None, :]
+    o = o / (p.sum(axis=-1, keepdims=True) + p_own)
+    return o.reshape(1, 1, -1, D).astype(cfg.dtype)

@@ -62,3 +62,40 @@ def swiglu(x, w_gate, w_up, w_down, compute_dtype=jnp.bfloat16):
     g = jax.nn.silu(xc @ w_gate.astype(compute_dtype))
     u = xc @ w_up.astype(compute_dtype)
     return ((g * u) @ w_down.astype(compute_dtype)).astype(x.dtype)
+
+
+def layer_norm(x, weight, eps: float = 1e-5):
+    """LayerNorm without a bias, fp32 statistics: ``(x - mean(x)) /
+    sqrt(var(x) + eps) * weight`` over the last dimension (the variance is
+    the mean of the squared DEVIATIONS; :func:`rms_norm` subtracts no
+    mean)."""
+    dtype = x.dtype
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return ((x32 * scale) * weight.astype(jnp.float32)).astype(dtype)
+
+
+def rotate_pairs(x, positions, theta: float):
+    """RoPE of the pairs ``(2i, 2i + 1)`` of ``x`` [B, T, H, D] (what
+    :func:`rotary_embedding` computes with ``interleaved``) with no strided
+    slice and no stack: a pair's partner comes by ONE product with the
+    signed permutation ``[D, D]`` (exact in any float type: every output is
+    one input or its negative), ``out[2i] = x[2i] cos_i - x[2i+1] sin_i``,
+    ``out[2i+1] = x[2i+1] cos_i + x[2i] sin_i``, then one elementwise pass.
+    At 128 heads and 16,384 positions the sliced form leaves a float32 copy
+    of ``x`` (1.07 GB), its two halves and the stacked result in a prefill
+    program's memory, a layer; a rotation of the lanes (``jnp.roll``) leaves
+    five such copies (compiled for a described v5e, PR 54)."""
+    D = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    angles = jnp.repeat(positions[..., None].astype(jnp.float32) * inv_freq,
+                        2, axis=-1)[:, :, None, :]  # [B,T,1,D]: i, i, ...
+    at = jnp.arange(D)
+    # column 2i takes -x[2i + 1], column 2i + 1 takes x[2i]
+    swap = (jnp.where(at % 2 == 0, -1.0, 1.0)[None, :]
+            * (at[:, None] == (at ^ 1)[None, :])).astype(x.dtype)
+    partner = jnp.dot(x, swap, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * jnp.cos(angles)
+            + partner * jnp.sin(angles)).astype(x.dtype)

@@ -683,6 +683,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     from ray_tpu.collective import pmean_tree
     from ray_tpu.models.llama import (
         _no_delta_kinds,
+        _no_parallel_kinds,
         _no_wide_latent,
         _no_window_kinds,
         _plain_chunk_nll,
@@ -723,6 +724,11 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
         "no train step is held to a reference for the gated delta rule's "
         "backward (autodiff through ops/gdn.py's chunked form) or the gated "
         "attention's; models.llama.loss_fn runs the forward of both")
+    _no_parallel_kinds(
+        cfg, "make_spmd_train_step",
+        "no train step is held to a reference for the parallel block's "
+        "backward, and its flash kernel has no window; "
+        "models.llama.loss_fn runs the forward through attend_tiles")
     _no_wide_latent(
         cfg, "make_spmd_train_step",
         "no train step is held to a reference for the mixes' backward or "

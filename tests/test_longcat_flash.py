@@ -468,7 +468,8 @@ def test_the_store_is_one_array_of_latent_rows(engine):
 
     assert gauge("ray_tpu_serve_engine_page_bytes") == {
         "kv": 0.0, "latent": 4 * 24 * 4.0, "full": 0.0, "window": 0.0,
-        "index": 0.0, "gated": 0.0, "latent_block": 0.0}
+        "index": 0.0, "gated": 0.0, "latent_block": 0.0,
+        "parallel_full": 0.0, "parallel_window": 0.0}
     # the grouped products run over the stack's 2 x 4 groups
     assert gauge("ray_tpu_serve_engine_expert_groups") == {
         "program": 8.0, "layer": 4.0}
@@ -476,7 +477,8 @@ def test_the_store_is_one_array_of_latent_rows(engine):
     assert [s.shape for s in dense.stores] == [(2, 4, 4, 2, 16)] * 2
     assert gauge("ray_tpu_serve_engine_page_bytes") == {
         "kv": 2 * 2 * 2 * 16 * 4.0, "latent": 0.0, "full": 0.0,
-        "window": 0.0, "index": 0.0, "gated": 0.0, "latent_block": 0.0}
+        "window": 0.0, "index": 0.0, "gated": 0.0, "latent_block": 0.0,
+        "parallel_full": 0.0, "parallel_window": 0.0}
     assert gauge("ray_tpu_serve_engine_expert_groups") == {
         "program": 0.0, "layer": 0.0}
 
@@ -616,7 +618,7 @@ def test_benchmark_files_fit_together_with_the_new_cell():
 
     test_yardstick.test_benchmark_files_fit_together()
     bench = spec.load_benchmark()
-    assert len(bench["workloads"]) == 11
+    assert len(bench["workloads"]) == 12
     assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
     b = spec.cell_bundle(CELL)
     assert (b["cell"]["chips"], b["cell"]["traffic"]) == (

@@ -696,7 +696,7 @@ def test_the_engine_is_what_the_table_folds(family, n_pages, page, held):
         want[s.tag] += 4.0 * s.layers * math.prod(s.row)
     assert _gauge("ray_tpu_serve_engine_page_bytes") == want
     assert set(want) == {"kv", "latent", "full", "window", "index", "gated",
-                         "latent_block"}
+                         "latent_block", "parallel_full", "parallel_window"}
     # these families keep nothing a SEQUENCE (tests/test_qwen3_next.py)
     assert set(_gauge("ray_tpu_serve_engine_state_bytes").values()) == {0.0}
     for part in ("held", "zero", "elsewhere"):

@@ -174,6 +174,9 @@ EXPERTS = {
     "smallthinker 16 pages": (98304, 8, 64, 2560, 768, "reglu"),
     "smallthinker 5 pages": (30720, 8, 64, 2560, 768, "reglu"),
     "keye 16 pages, a block": (131072, 8, 16, 2048, 768, "swiglu"),
+    # experts 4,096 x 4,096 go in column blocks of 1,024 (pick_columns): a
+    # chunk of the held path's places at 16 pages (ops/moe.py held_chunk)
+    "commandaplus 16 pages, a chunk": (20480, 4, 16, 4096, 4096, "swiglu"),
 }
 
 
@@ -373,6 +376,10 @@ PREFILL = {
     # 32 heads of 128 + 64 that return 128, at the cell's two ends
     "xing4 2 pages": (2048, 32, 32, 192, 128, 0),
     "xing4 16 pages": (16384, 32, 32, 192, 128, 0),
+    # 128 query heads on 8: a KV head's keys stay while 16 query heads go by
+    "commandaplus full 16 pages": (16384, 128, 8, 128, 128, 0),
+    "commandaplus window 16 pages": (16384, 128, 8, 128, 128, 4096),
+    "commandaplus window 5 pages": (5120, 128, 8, 128, 128, 4096),
 }
 
 
@@ -479,17 +486,26 @@ def _cell_program(cell, program, pages, one_chip):
         lambda key: llama.serving_params(cfg, llama.init_params(cfg, key)),
         jax.random.PRNGKey(0)))
     layout = llama.served_stores(cfg)
-    stores = [jax.ShapeDtypeStruct(s.shape(n_pages, 0, ps), jnp.float32,
+    # a stack with stores by slot: the engine's rule for how many, and the
+    # slots of the last pages behind the other arguments
+    k = n_slots = 0
+    if any(s.table == "slot" for s in layout):
+        k = cfg.window_pages(ps)
+        n_slots = min(n_pages, -(-n_pages // -(-cfg.max_seq_len // ps))
+                      * (k + 2))
+    stores = [jax.ShapeDtypeStruct(s.shape(n_pages, n_slots, ps), jnp.float32,
                                    sharding=one_chip) for s in layout]
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
+    slots = (i32(min(pages, k)),) if n_slots else ()
     if program == "prefill":
         fn, args = llama.prefill_with_cache, (i32(1, pages * ps), i32(pages),
-                                              i32())
+                                              i32(), *slots)
     else:
-        fn, args = llama.decode_step_with_cache, (i32(1), i32(), i32(pages))
+        fn, args = llama.decode_step_with_cache, (
+            i32(1), i32(), i32(pages), *slots, *((i32(),) if slots else ()))
     return jax.jit(partial(fn, cfg), donate_argnums=tuple(
         range(1, 1 + len(layout)))).lower(params, *stores, *args), stores
 
@@ -573,6 +589,8 @@ HELD_SUMS = {
     "qwen3next 3 pages": (6144, 2048, 16896, 128),
     "keye 16 pages": (32768, 2048, 40960, 16),
     "longcat 16 pages": (8192, 6144, 2560, 16),
+    "commandaplus 16 pages": (16384, 4096, 20480, 16),
+    "commandaplus 5 pages": (5120, 4096, 6400, 16),
 }
 
 
@@ -590,7 +608,8 @@ def test_held_sum_compiles_at_the_cells_shapes(what, one_chip):
 
     tokens, d, places, count = HELD_SUMS[what]
     router = {"qwen3next": (10, 512), "keye": (8, 128),
-              "longcat": (12, 768)}[what.split()[0]]
+              "longcat": (12, 768),
+              "commandaplus": (8, 128)}[what.split()[0]]
     assert moe.held_chunk(tokens * router[0], count, router[1]) == places
 
     def arg(shape, dtype):

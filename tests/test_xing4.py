@@ -688,7 +688,7 @@ def test_benchmark_files_fit_together_with_the_new_cell(cell):
 
     test_yardstick.test_benchmark_files_fit_together()
     bench = spec.load_benchmark()
-    assert len(bench["workloads"]) == 11
+    assert len(bench["workloads"]) == 12
     assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
     assert (cell["cell"]["chips"], cell["cell"]["traffic"]) == (
         1, "prefill-open-2048-16000")
