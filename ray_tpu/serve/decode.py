@@ -2,13 +2,15 @@
 Orca/vLLM scheduling model, arXiv:2309.06180).
 
 The :class:`DecodeScheduler` owns a RUNNING batch of multi-step
-sequences. One ``step()`` call = one scheduling iteration: admit
-newly-arrived prefills (the compiled exec loop drains them from the ring
-backlog BETWEEN decode steps — admission is per-iteration, not
-per-batch), run one model step over every running sequence, emit token
-chunks, retire finished sequences immediately. A short request admitted
-while a long one is mid-decode therefore finishes first — batch
-membership is fluid.
+sequences. One ``step()`` call does ONE thing and hands its replies
+back: it admits the oldest waiting request (its prefill, or its
+prefix-cache hit: the compiled exec loop drains arrivals from the ring
+backlog BETWEEN steps) and returns the first token at once, or, when
+nothing can be admitted, runs one model step over every running
+sequence, emits token chunks and retires finished sequences
+immediately. A first token is therefore behind no later prefill and no
+decode call, and a short request admitted while a long one is
+mid-decode finishes first — batch membership is fluid.
 
 Engines implement a small duck-typed protocol over the paged KV cache
 (:mod:`ray_tpu.serve.kv_cache`):
@@ -190,22 +192,40 @@ class DecodeScheduler:
     # ----------------------------------------------------------- the loop
 
     def step(self) -> Tuple[List[tuple], bool]:
-        """One scheduling iteration. Returns ``(replies, active)`` in the
+        """One scheduling step. Returns ``(replies, active)`` in the
         stream exec-loop contract: replies for compiled corrs, active
-        while any sequence is running or waiting."""
+        while any sequence is running or waiting.
+
+        A step is an ADMIT step or a DECODE step, never both. It first
+        tries the oldest waiting request; if that answered anything (the
+        first chunk, a ``final`` where the first token ends the sequence,
+        an ``error`` for a request that can never fit) the step returns
+        it at once and no decode call runs. Only a step that admitted
+        nothing (nothing waits, the batch is full, pages or window slots
+        are short) runs one decode iteration over the running batch. The
+        caller calls straight back while ``active``, so as many
+        consecutive steps admit as there are waiting requests that fit
+        before the batch decodes again."""
         with self._lock:
             replies: List[tuple] = []
-            admitted: List[tuple] = []  # (corr, when its prefill ended)
-            self._admit_locked(replies, admitted)
-            self._decode_iteration_locked(replies)
+            admitted = self._admit_one_locked(replies)
+            if replies:
+                kind = "admit"
+            elif self._decode_iteration_locked(replies):
+                kind = "decode"
+            else:
+                kind = None  # no request taken, no sequence running
             self.steps += 1
+            if kind is not None and _obs.enabled():
+                _obs.DECODE_STEPS.inc(tag_key=_obs.dep_step_kind_key(
+                    self.deployment, kind))
             self._flush_gauges_locked()
             out = [r for r in replies if not self._route_eager(r)]
             active = bool(self.running) or bool(self.waiting)
-            # a first token leaves only now, with the whole step's
-            # replies: behind every later prefill of this step and one
-            # decode call per running sequence
-            for corr, t_first in admitted:
+            # the first token leaves now, behind nothing but this
+            # step's own bookkeeping
+            if admitted is not None:
+                corr, t_first = admitted
                 _sp_first_token_hold.end(t_first, self.deployment, corr)
             return out, active
 
@@ -229,95 +249,99 @@ class DecodeScheduler:
 
     # -------------------------------------------------------- admission
 
-    def _admit_locked(self, replies: List[tuple],
-                      admitted: List[tuple]) -> None:
-        """Admit waiting prefills into the RUNNING batch, prefix-cache
-        first. A prefill that cannot get pages (even after evicting idle
-        prefixes), or for whose WHOLE life the engine's own room beside the
-        pages is not there (a window layer's slots, counted here over the
-        running sequences so that no decode call can find none;
-        ``CacheOOM`` out of ``engine.prefill`` or ``engine.copy_page``
-        where something else holds them), stays queued — admission stops
-        for this iteration so
-        arrival order is preserved under memory pressure. ``admitted``
-        collects ``(corr, t)`` of each sequence admitted, ``t`` the
-        moment its first token existed."""
-        while self.waiting and len(self.running) < self.max_batch:
-            corr, req, eager, t_submit = self.waiting[0]
-            prompt = req["prompt"]
-            key = tuple(prompt)
-            n_prompt = len(prompt)
-            max_tokens = min(req["max_tokens"], self.max_tokens_cap)
-            # an engine with room of its own beside the pages says how much
-            # of it the sequence can hold over its whole life, and the
-            # running ones' is counted HERE: a decode call never finds none
-            slots = self._slots_needed(n_prompt, max_tokens)
-            if slots > self._slots_total:
+    def _admit_one_locked(self, replies: List[tuple]
+                          ) -> Optional[Tuple[object, float]]:
+        """Admit the OLDEST waiting request into the RUNNING batch,
+        prefix-cache first, and no other: the caller hands its first token
+        back before anything else runs. A prefill that cannot get pages
+        (even after evicting idle prefixes), or for whose WHOLE life the
+        engine's own room beside the pages is not there (a window layer's
+        slots, counted here over the running sequences so that no decode
+        call can find none; ``CacheOOM`` out of ``engine.prefill`` or
+        ``engine.copy_page`` where something else holds them), stays
+        queued and nothing behind it is tried, so arrival order is
+        preserved under memory pressure: ``replies`` is left empty and the
+        step decodes. A request that can never fit, or whose prefill
+        raised, is answered with its ``error`` and taken off the queue.
+        Returns ``(corr, t)`` of the sequence admitted, ``t`` the moment
+        its first token existed, else None."""
+        if not self.waiting or len(self.running) >= self.max_batch:
+            return None
+        corr, req, eager, t_submit = self.waiting[0]
+        prompt = req["prompt"]
+        key = tuple(prompt)
+        n_prompt = len(prompt)
+        max_tokens = min(req["max_tokens"], self.max_tokens_cap)
+        # an engine with room of its own beside the pages says how much
+        # of it the sequence can hold over its whole life, and the
+        # running ones' is counted HERE: a decode call never finds none
+        slots = self._slots_needed(n_prompt, max_tokens)
+        if slots > self._slots_total:
+            self.waiting.popleft()
+            replies.append((corr, "error", ValueError(
+                f"prompt of {n_prompt} tokens and {max_tokens} more can "
+                f"never fit: needs {slots} window slots, the engine "
+                f"has {self._slots_total}")))
+            return None
+        if slots + sum(s.slots for s in self.running.values()) \
+                > self._slots_total:
+            return None  # slot pressure: retry once one has retired
+        _t0 = _fr.now()
+        entry = self.prefix_cache.lookup(key)
+        was_hit = entry is not None
+        if entry is not None:
+            logits = entry.blob
+        else:
+            n_pages = pages_for(n_prompt, self.page_size)
+            # +1: a non-aligned prompt also needs the COW tail page
+            if n_pages + (1 if n_prompt % self.page_size else 0) \
+                    > self.pool.n_pages:
                 self.waiting.popleft()
                 replies.append((corr, "error", ValueError(
-                    f"prompt of {n_prompt} tokens and {max_tokens} more can "
-                    f"never fit: needs {slots} window slots, the engine "
-                    f"has {self._slots_total}")))
-                continue
-            if slots + sum(s.slots for s in self.running.values()) \
-                    > self._slots_total:
-                break  # slot pressure: retry once a sequence has retired
-            _t0 = _fr.now()
-            entry = self.prefix_cache.lookup(key)
-            was_hit = entry is not None
-            if entry is not None:
-                logits = entry.blob
-            else:
-                n_pages = pages_for(n_prompt, self.page_size)
-                # +1: a non-aligned prompt also needs the COW tail page
-                if n_pages + (1 if n_prompt % self.page_size else 0) \
-                        > self.pool.n_pages:
-                    self.waiting.popleft()
-                    replies.append((corr, "error", ValueError(
-                        f"prompt of {n_prompt} tokens can never fit: "
-                        f"needs {n_pages} pages, pool holds "
-                        f"{self.pool.n_pages}")))
-                    continue
-                pages = self.prefix_cache.alloc_with_evict(n_pages)
-                if pages is None:
-                    break  # pool pressure: retry next iteration
-                try:
-                    logits = self.engine.prefill(prompt, pages)
-                except CacheOOM:  # the engine's own room: retry later
-                    self.pool.release(pages)
-                    break
-                except Exception as e:  # noqa: BLE001 — fail one request
-                    self.pool.release(pages)
-                    self.waiting.popleft()
-                    replies.append((corr, "error", e))
-                    continue
-                entry = self.prefix_cache.insert(key, n_prompt, pages,
-                                                 blob=logits)
-            kv = self._sequence_kv(entry, n_prompt)
-            if kv is None:  # tail-page copy could not get a page
-                self.prefix_cache.release(entry)
-                break
-            self.waiting.popleft()
-            first = int(np.argmax(logits))
-            seq = _Seq(corr, prompt, max_tokens, req["eos"], kv, n_prompt,
-                       eager, cached_prefix=was_hit, slots=slots)
-            seq.generated.append(first)
-            self.running[corr] = seq
-            self.admitted += 1
-            if t_submit:
-                _sp_sched_wait.end_at(t_submit, _t0 - t_submit,
-                                      self.deployment, corr)
-            _sp_prefill.end(_t0, self.deployment, corr)
-            # the ITL anchor is the monotonic clock itself, not the
-            # recorder's (which reads 0.0 when the recorder is off)
-            seq.last_chunk_ts = time.monotonic()
-            admitted.append((corr, seq.last_chunk_ts))
-            if _obs.enabled():
-                _obs.TOKENS_GENERATED.inc(
-                    tag_key=_obs.dep_key(self.deployment))
-            replies.append((corr, "chunk", _chunk_payload(seq, first, 0)))
-            if self._finished(seq, first):
-                self._retire_locked(seq, replies)
+                    f"prompt of {n_prompt} tokens can never fit: "
+                    f"needs {n_pages} pages, pool holds "
+                    f"{self.pool.n_pages}")))
+                return None
+            pages = self.prefix_cache.alloc_with_evict(n_pages)
+            if pages is None:
+                return None  # pool pressure: retry after a decode
+            try:
+                logits = self.engine.prefill(prompt, pages)
+            except CacheOOM:  # the engine's own room: retry later
+                self.pool.release(pages)
+                return None
+            except Exception as e:  # noqa: BLE001 — fail one request
+                self.pool.release(pages)
+                self.waiting.popleft()
+                replies.append((corr, "error", e))
+                return None
+            entry = self.prefix_cache.insert(key, n_prompt, pages,
+                                             blob=logits)
+        kv = self._sequence_kv(entry, n_prompt)
+        if kv is None:  # tail-page copy could not get a page
+            self.prefix_cache.release(entry)
+            return None
+        self.waiting.popleft()
+        first = int(np.argmax(logits))
+        seq = _Seq(corr, prompt, max_tokens, req["eos"], kv, n_prompt,
+                   eager, cached_prefix=was_hit, slots=slots)
+        seq.generated.append(first)
+        self.running[corr] = seq
+        self.admitted += 1
+        if t_submit:
+            _sp_sched_wait.end_at(t_submit, _t0 - t_submit,
+                                  self.deployment, corr)
+        _sp_prefill.end(_t0, self.deployment, corr)
+        # the ITL anchor is the monotonic clock itself, not the
+        # recorder's (which reads 0.0 when the recorder is off)
+        seq.last_chunk_ts = time.monotonic()
+        if _obs.enabled():
+            _obs.TOKENS_GENERATED.inc(
+                tag_key=_obs.dep_key(self.deployment))
+        replies.append((corr, "chunk", _chunk_payload(seq, first, 0)))
+        if self._finished(seq, first):
+            self._retire_locked(seq, replies)
+        return corr, seq.last_chunk_ts
 
     def _sequence_kv(self, entry, n_prompt: int) -> Optional[SequenceKV]:
         """Build the sequence's page table over a prefix entry: full
@@ -342,10 +366,11 @@ class DecodeScheduler:
 
     # ----------------------------------------------------------- decode
 
-    def _decode_iteration_locked(self, replies: List[tuple]) -> None:
-        """One model step over every RUNNING sequence."""
+    def _decode_iteration_locked(self, replies: List[tuple]) -> bool:
+        """One model step over every RUNNING sequence; False when none
+        runs."""
         if not self.running:
-            return
+            return False
         _t0 = _fr.now()
         itl_samples: List[float] = []
         n_tokens = 0
@@ -386,6 +411,7 @@ class DecodeScheduler:
             _obs.TOKENS_GENERATED.inc(float(n_tokens), tag_key=key)
             for s in itl_samples:
                 _obs.ITL.observe(s, tag_key=key)
+        return True
 
     def _finished(self, seq: _Seq, token: int) -> bool:
         if seq.eos is not None and token == seq.eos:

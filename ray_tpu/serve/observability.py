@@ -98,6 +98,12 @@ TOKENS_GENERATED = Counter(
     "ray_tpu_serve_tokens_generated_total",
     "Tokens emitted by the generative-decode plane",
     tag_keys=("deployment",))
+DECODE_STEPS = Counter(
+    "ray_tpu_serve_decode_steps_total",
+    "Steps of the decode scheduler, by what the step did: admit (one "
+    "waiting request taken off the queue and answered, no decode call) "
+    "or decode (one model step over the running batch)",
+    tag_keys=("deployment", "kind"))
 SHED = Counter(
     "ray_tpu_serve_shed_total",
     "Requests shed at the dispatching process: concurrency budget "
@@ -168,6 +174,15 @@ def dep_plane_key(deployment: str, plane: str) -> tuple:
     if v is None:
         v = _key_cache[k] = tags_key(
             {"deployment": deployment, "plane": plane})
+    return v
+
+
+def dep_step_kind_key(deployment: str, kind: str) -> tuple:
+    k = ("dk", deployment, kind)
+    v = _key_cache.get(k)
+    if v is None:
+        v = _key_cache[k] = tags_key(
+            {"deployment": deployment, "kind": kind})
     return v
 
 

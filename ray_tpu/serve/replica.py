@@ -502,9 +502,9 @@ class ServeReplica:
     # ----------------------------------------------------- decode plane
     # Generative decode (serve/decode.py): the compiled stream lane
     # binds handle_request_decode with with_stream_batching — the exec
-    # loop drains new requests from the ring BETWEEN decode iterations
-    # and calls back in while any sequence is running, which is exactly
-    # the Orca iteration-level admission loop.
+    # loop drains new requests from the ring BETWEEN scheduler steps
+    # and calls back in while any sequence is running or waiting, which
+    # is exactly the Orca iteration-level admission loop.
 
     def _decode_scheduler(self):
         """Lazily build the scheduler from the callable's engine factory
@@ -534,10 +534,12 @@ class ServeReplica:
     def handle_request_decode(self, entries: List[tuple]):
         """One stream-exec round on the decode plane: submit this
         round's drained ring entries ``(corr, value)``, run ONE
-        scheduling iteration, return ``(replies, active)`` — the
-        worker's stream loop ships each reply as a TAG_STREAM frame and
-        keeps calling back (without blocking on the ring) while
-        ``active``."""
+        scheduler step (it admits one waiting request and returns its
+        first token, or decodes the running batch once: never both),
+        return ``(replies, active)`` — the worker's stream loop ships
+        each reply as a TAG_STREAM frame and keeps calling back (without
+        blocking on the ring) while ``active``, so a first token is on
+        the ring before the next prefill or decode call starts."""
         sched = self._decode_scheduler()
         replies: List[tuple] = []
         for corr, value in entries:
@@ -572,7 +574,9 @@ class ServeReplica:
                 sched.step()
                 frames = sched.drain_eager(corr)
                 if not frames:
-                    # pool pressure is holding admission back; don't spin
+                    # the step was another caller's (it admitted their
+                    # request), or pool pressure is holding admission
+                    # back; don't spin
                     time.sleep(0.001)
                     continue
                 for _corr, kind, payload in frames:

@@ -238,7 +238,8 @@ def test_a_prefix_hit_shares_pages_and_never_a_written_state(params,
         engine = _engine(params)
         sched = DecodeScheduler(engine, max_batch=4)
         sched.submit("a", {"prompt": prompt, "max_tokens": 6})
-        steps = [sched.step(), sched.step()]  # a is two tokens ahead
+        # admitted, then two decode steps: a is two tokens ahead
+        steps = [sched.step() for _ in range(3)]
         if twice:
             sched.submit("b", {"prompt": prompt, "max_tokens": 6})
         steps += [sched.step() for _ in range(8)]

@@ -111,15 +111,200 @@ class TestIterationLevelAdmission:
             "the long sequence must be unaffected by the mid-flight join"
 
     def test_admission_capped_by_max_batch(self):
+        """A step admits ONE request; the cap holds over the steps: the
+        third finds the batch full, admits nobody and decodes."""
         from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
 
-        sched = DecodeScheduler(ToyEngine(n_pages=64, page_size=4),
-                                max_batch=2)
+        eng = ToyEngine(n_pages=64, page_size=4)
+        sched = DecodeScheduler(eng, max_batch=2)
         for i in range(4):
             sched.submit(f"c{i}", {"prompt": [i + 1], "max_tokens": 8})
         sched.step()
         st = sched.stats()
+        assert st["running"] == 1 and st["waiting"] == 3
+        sched.step()
+        st = sched.stats()
         assert st["running"] == 2 and st["waiting"] == 2
+        assert eng.decode_calls == 0
+        out, active = sched.step()
+        st = sched.stats()
+        assert st["running"] == 2 and st["waiting"] == 2 and active
+        assert eng.prefill_calls == 2 and eng.decode_calls == 2
+        assert [c for c, _, _ in out] == ["c0", "c1"]
+
+    def test_interleaved_arrivals_decode_as_each_would_alone(self):
+        """Requests that arrive between steps (before an admit step, a
+        decode step, during another's life) each get the token stream
+        they get alone, chunk by chunk and in their ``final``."""
+        from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
+
+        reqs = {"a": ([1, 2, 3], 9), "b": ([4, 5], 3), "c": ([6], 6),
+                "d": ([7, 8, 9, 10, 11], 4), "e": ([1, 2, 3], 5)}
+        arrives = {0: ["a"], 1: ["b", "c"], 4: ["d"], 7: ["e"]}
+        sched = DecodeScheduler(ToyEngine(n_pages=64, page_size=4),
+                                max_batch=3)
+        chunks, finals = {}, {}
+        n, active = 0, True
+        while active or n <= max(arrives):
+            for corr in arrives.get(n, ()):
+                prompt, max_tokens = reqs[corr]
+                assert sched.submit(corr, {"prompt": prompt,
+                                           "max_tokens": max_tokens}) is None
+            out, active = sched.step()
+            n += 1
+            for corr, kind, payload in out:
+                body = json.loads(payload)
+                if kind == "chunk":
+                    assert body["i"] == len(chunks.setdefault(corr, []))
+                    chunks[corr].append(body["token"])
+                else:
+                    assert kind == "final", (corr, kind, payload)
+                    finals[corr] = body
+            assert n < 200
+        for corr, (prompt, max_tokens) in reqs.items():
+            want = _reference_tokens(prompt, max_tokens)
+            assert chunks[corr] == finals[corr]["tokens"] == want, corr
+        assert finals["e"]["cached_prefix"] and not finals["a"]["cached_prefix"]
+        assert sched.pool.used == sum(
+            len(e.pages) for e in sched.prefix_cache._entries.values())
+
+    def test_arrivals_before_every_step_starve_nobody(self):
+        """Prefill keeps its priority only while the batch has room: with
+        ``max_batch`` 2 and a request arriving before EVERY step, a full
+        batch still decodes (its sequences' tokens advance step by step),
+        every request ends, and they end in the order they came."""
+        from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
+
+        eng = ToyEngine(n_pages=64, page_size=4)
+        sched = DecodeScheduler(eng, max_batch=2)
+        n_reqs, seen, progress = 12, {}, []
+        n, active = 0, True
+        while active or n < n_reqs:
+            if n < n_reqs:
+                sched.submit(n, {"prompt": [n + 1, n + 2], "max_tokens": 4})
+            out, active = sched.step()
+            n += 1
+            for corr, kind, _payload in out:
+                seen.setdefault(corr, []).append(kind)
+            progress.append(sum(len(s.generated)
+                                for s in sched.running.values())
+                            + sum(g for _, g in sched.retired))
+            assert n < 200
+        assert all(b > a for a, b in zip(progress, progress[1:])), \
+            "a step made no token: something waited without working"
+        assert [c for c, _ in sched.retired] == list(range(n_reqs))
+        assert all(kinds == ["chunk"] * 4 + ["final"]
+                   for kinds in seen.values()) and len(seen) == n_reqs
+        # admissions never outran the cap, and decode was put off by at
+        # most the batch's free places
+        assert eng.prefill_calls == n_reqs
+        assert eng.decode_calls == n_reqs * 3
+
+    @pytest.mark.parametrize("pressure", ["pool", "slots"])
+    def test_a_step_that_cannot_admit_decodes(self, pressure):
+        """Pages or window slots short for the oldest waiting request:
+        nothing behind it is tried, and the SAME step decodes the running
+        batch (which is what frees the room)."""
+        from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
+
+        eng = ToyEngine(n_pages=4, page_size=2)
+        if pressure == "slots":  # an engine with room beside the pages
+            eng.n_slots = 3
+            eng.window_slots_needed = lambda n_prompt, max_tokens: 2
+        sched = DecodeScheduler(eng, max_batch=4)
+        # 3 pages now and a fourth at its second decode call
+        sched.submit("big", {"prompt": [1, 2, 3, 4, 5, 6], "max_tokens": 3})
+        sched.submit("next", {"prompt": [7, 8, 9], "max_tokens": 2})
+        sched.submit("small", {"prompt": [9], "max_tokens": 2})  # would fit
+        out, _ = sched.step()
+        assert [(c, k) for c, k, _ in out] == [("big", "chunk")]
+        assert eng.decode_calls == 0
+        out, active = sched.step()  # no room for "next": decodes "big"
+        assert [(c, k) for c, k, _ in out] == [("big", "chunk")]
+        assert eng.decode_calls == 1 and eng.prefill_calls == 1
+        assert list(sched.running) == ["big"] and active
+        assert [w[0] for w in sched.waiting] == ["next", "small"]
+        order = []
+        while active:
+            out, active = sched.step()
+            order += [c for c, k, _ in out if k == "final"]
+        assert order == ["big", "next", "small"]
+        assert [n for _, n in sched.retired] == [3, 2, 2]
+
+    def test_a_first_token_that_ends_its_request_leaves_with_its_final(self):
+        """``max_tokens`` 1: chunk and ``final`` come out of the one step
+        that admitted, no decode call runs for it, and ``active`` says
+        what is left."""
+        from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
+
+        eng = ToyEngine(n_pages=64, page_size=4)
+        sched = DecodeScheduler(eng)
+        sched.submit("one", {"prompt": [1, 2, 3], "max_tokens": 1})
+        out, active = sched.step()
+        assert [(c, k) for c, k, _ in out] == [("one", "chunk"),
+                                               ("one", "final")]
+        assert json.loads(out[1][2])["tokens"] \
+            == [json.loads(out[0][2])["token"]] \
+            == _reference_tokens([1, 2, 3], 1)
+        assert not active and not sched.running and eng.decode_calls == 0
+        # with another waiting behind it, and one running, it stays true
+        sched.submit("long", {"prompt": [4], "max_tokens": 3})
+        assert sched.step()[1]
+        sched.submit("one-more", {"prompt": [5, 6], "max_tokens": 1})
+        sched.submit("waits", {"prompt": [7], "max_tokens": 1})
+        out, active = sched.step()
+        assert [(c, k) for c, k, _ in out] == [("one-more", "chunk"),
+                                               ("one-more", "final")]
+        assert active and list(sched.running) == ["long"]
+        assert [w[0] for w in sched.waiting] == ["waits"]
+        assert eng.decode_calls == 0
+
+    def test_steps_are_counted_by_kind(self):
+        """``ray_tpu_serve_decode_steps_total{deployment, kind}``: one
+        ``admit`` a request taken off the queue (an error's included),
+        one ``decode`` an iteration over the running batch, nothing for
+        a step with nothing to do; no step is both."""
+        from ray_tpu.serve import observability as obs
+        from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
+        from ray_tpu.util.metrics import registry
+
+        assert obs.enabled()
+
+        def counts():
+            vals = registry().local_values(
+                "ray_tpu_serve_decode_steps_total")
+            return {kind: vals.get(obs.dep_step_kind_key("stepdep", kind),
+                                   0.0)
+                    for kind in ("admit", "decode")}
+
+        before = counts()
+        eng = ToyEngine(n_pages=8, page_size=2)
+        sched = DecodeScheduler(eng, deployment="stepdep", max_batch=2)
+        sched.step()  # idle: neither
+        sched.submit("a", {"prompt": [1, 2], "max_tokens": 4})
+        sched.submit("b", {"prompt": [3], "max_tokens": 2})
+        sched.submit("never", {"prompt": list(range(40)), "max_tokens": 2})
+        sched.submit("c", {"prompt": [4], "max_tokens": 1})
+        kinds, active = [], True
+        while active:
+            calls = (eng.prefill_calls, eng.decode_calls)
+            out, active = sched.step()
+            kinds.append("decode" if eng.decode_calls > calls[1]
+                         else "admit")
+            # never both in one step
+            assert (eng.prefill_calls > calls[0]) \
+                + (eng.decode_calls > calls[1]) <= 1
+        after = counts()
+        got = {k: after[k] - before[k] for k in after}
+        assert got == {"admit": float(kinds.count("admit")),
+                       "decode": float(kinds.count("decode"))}
+        assert got["admit"] == 4.0  # a, b, the error, c
+        assert got["decode"] == 3.0  # a's three tokens after its first
+        assert sched.steps == 1 + len(kinds)
+        assert set(registry().local_values(
+            "ray_tpu_serve_decode_steps_total")) >= {
+            (("deployment", "stepdep"), ("kind", "admit")),
+            (("deployment", "stepdep"), ("kind", "decode"))}
 
 
 # --------------------------------------------------------------------------
@@ -164,10 +349,10 @@ class TestTimeToFirstTokenSpans:
         return sched
 
     def test_second_request_waits_out_the_first_prefill(self, recorder):
-        """Two requests submitted together are prefilled back to back in
-        one step: the second's scheduler wait holds the first's whole
-        prefill, and the first's (an idle scheduler: microseconds) is
-        recorded all the same."""
+        """Two requests submitted together are prefilled in two steps,
+        back to back: the second's scheduler wait holds the first's whole
+        prefill (and no decode call), and the first's (an idle scheduler:
+        microseconds) is recorded all the same."""
         self._run_two()
         wait = {t["corr"]: d
                 for _, d, t in _recorded(recorder, "serve.sched_wait")}
@@ -175,28 +360,44 @@ class TestTimeToFirstTokenSpans:
                    for _, d, t in _recorded(recorder, "serve.prefill")}
         assert sorted(wait) == sorted(prefill) == [7, 8]
         assert wait[8] >= prefill[7] >= 0.01
+        assert wait[8] < prefill[7] + 0.01  # nothing decoded in between
         assert wait[7] < prefill[7]
         assert all(t["deployment"] == "toy"
                    for _, _, t in _recorded(recorder, "serve.sched_wait"))
 
-    def test_first_token_is_held_to_the_end_of_its_step(self, recorder):
+    def test_first_token_leaves_with_the_step_that_made_it(self, recorder):
         """One hold per request, from the end of its prefill to the
-        step's return: the first request's holds the second's prefill
-        and both decode calls of the step, the second's the decode calls
-        alone."""
-        self._run_two()
+        step's return, and nothing of the engine's inside it: shorter
+        than one engine call. The first request's chunk is returned by
+        the step that prefilled it, BEFORE the second's prefill starts."""
+        from ray_tpu.serve.decode import DecodeScheduler, ToyEngine
+
+        delay = 0.01
+        eng = ToyEngine(n_pages=64, page_size=4, step_delay_s=delay)
+        sched = DecodeScheduler(eng, deployment="toy", max_batch=4)
+        sched.submit(7, {"prompt": [1, 2, 3], "max_tokens": 3})
+        sched.submit(8, {"prompt": [4, 5], "max_tokens": 4})
+        out, active = sched.step()
+        assert [(c, k, json.loads(p)["i"]) for c, k, p in out] \
+            == [(7, "chunk", 0)]
+        assert active and eng.prefill_calls == 1 and eng.decode_calls == 0
+        out, active = sched.step()
+        assert [(c, k) for c, k, _ in out] == [(8, "chunk")]
+        assert eng.prefill_calls == 2 and eng.decode_calls == 0
+        while active:
+            _, active = sched.step()
         holds = _recorded(recorder, "serve.first_token_hold")
         assert sorted(t["corr"] for _, _, t in holds) == [7, 8]
         hold = {t["corr"]: (t0, d) for t0, d, t in holds}
-        ends = {t["corr"]: t0 + d
-                for t0, d, t in _recorded(recorder, "serve.prefill")}
-        for corr in (7, 8):  # it starts where the prefill ended
-            assert hold[corr][0] == pytest.approx(ends[corr], abs=1e-3)
-        assert hold[8][1] >= 2 * 0.01          # two decode calls
-        assert hold[7][1] >= hold[8][1] + 0.01  # and the other prefill
-        # both were handed back at the same moment
-        assert hold[7][0] + hold[7][1] == pytest.approx(
-            hold[8][0] + hold[8][1], abs=1e-3)
+        prefill = {t["corr"]: (t0, d)
+                   for t0, d, t in _recorded(recorder, "serve.prefill")}
+        for corr in (7, 8):
+            # it starts where the prefill ended, and holds no engine call
+            assert hold[corr][0] == pytest.approx(
+                sum(prefill[corr]), abs=1e-3)
+            assert hold[corr][1] < delay / 2
+        # 7's token was out before 8's prefill began
+        assert sum(hold[7]) <= prefill[8][0] + 1e-4
 
     def test_decode_step_spans_count_their_tokens(self, recorder):
         sched = self._run_two()
@@ -205,7 +406,9 @@ class TestTimeToFirstTokenSpans:
         # every token but each request's first came from a decode step
         assert sum(t["tokens"] for _, _, t in steps) \
             == generated - len(sched.retired) == 5
+        # and no step that admitted ran one: three iterations, not five
         assert [t["tokens"] for _, _, t in steps] == [2, 2, 1]
+        assert sched.steps == 2 + 3
 
     def test_itl_anchor_does_not_need_the_recorder(self, recorder):
         """The gap between tokens is taken from the monotonic clock: with
@@ -263,7 +466,7 @@ class TestTimeToFirstTokenSpans:
                     {"prompt": prompt, "max_tokens": 2}).encode(),
                     tag=chan.TAG_BYTES)
                 # the second lands mid-step: once the first's prefill
-                # has begun, its decode call (10 ms) is still to come
+                # (10 ms) has begun, nearly all of it is still to come
                 deadline = time.monotonic() + 30
                 while not sched.engine.prefill_calls:
                     assert time.monotonic() < deadline
@@ -289,8 +492,9 @@ class TestTimeToFirstTokenSpans:
             assert sorted(by_corr) == [0, 1], name
         ingress = _recorded(recorder, "dag.stream_ingress")
         assert all(t["method"] == "decode" for _, _, t in ingress)
-        # request 1 was published with a decode call of 10 ms to come
-        assert by_span["dag.stream_ingress"][1] >= 0.01
+        # request 1 was published with most of a 10 ms prefill to come
+        # (and is read as soon as that step returns: no decode call first)
+        assert by_span["dag.stream_ingress"][1] >= 0.005
         assert by_span["dag.stream_ingress"][0] \
             < by_span["dag.stream_ingress"][1]
 

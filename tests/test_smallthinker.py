@@ -478,15 +478,19 @@ def test_a_prefill_with_no_slot_stays_queued_until_a_sequence_retires(params):
                        "max_tokens": 4}) for i in range(4)]
     for corr, req in reqs:
         assert sched.submit(corr, req) is None
-    replies, _ = sched.step()
-    # two fit (4 slots and a copied tail's each); the third found no slot,
-    # holds no page and waits
+    # a step admits one: two fit (4 slots and a copied tail's each); the
+    # third step finds no slot for the third, which holds no page and
+    # waits, and decodes the two that run in that same step
+    replies = [r for _ in range(3) for r in sched.step()[0]]
     assert len(sched.running) == 2 and len(sched.waiting) == 2
     assert not [r for r in replies if r[1] == "error"]
+    assert [(c, json.loads(p)["i"]) for c, _, p in replies] \
+        == [("r0", 0), ("r1", 0), ("r0", 1), ("r1", 1)]
     out = {}
     for _ in range(60):
-        for corr, kind, payload in sched.step()[0]:
-            out.setdefault(corr, []).append((kind, payload))
+        replies += sched.step()[0]
+    for corr, kind, payload in replies:
+        out.setdefault(corr, []).append((kind, payload))
     done = {c: json.loads(v[-1][1]) for c, v in out.items()
             if v[-1][0] == "final"}
     assert sorted(done) == ["r0", "r1", "r2", "r3"]
@@ -513,7 +517,8 @@ def test_many_short_sequences_wait_for_slots_and_none_fails_in_decode(params):
                        "max_tokens": 8}) for i in range(8)]
     for corr, req in reqs:
         assert sched.submit(corr, req) is None
-    sched.step()
+    for _ in range(4):  # three admissions, then no slot: a decode step
+        sched.step()
     assert len(sched.running) == 3 and len(sched.waiting) == 5
     out = _run_all(sched, [])
     assert not [f for frames in out.values() for f in frames
