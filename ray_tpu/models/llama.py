@@ -225,10 +225,10 @@ BLOCK_KINDS = "FW"
 # of its own; "D" mixes through the gated delta rule, "A" through gated full
 # attention. A stack EACH (their mixers' leaves differ)
 DELTA_KINDS = "DA"
-# the latent-attention blocks outside "S" (latent_block, at this file's end):
+# the latent-attention blocks outside "S" (latent_block):
 # whole blocks, a stack each (their MLPs' leaves differ)
 LATENT_KINDS = "LG"
-# the PARALLEL blocks (parallel_block, at this file's end): ONE
+# the PARALLEL blocks (parallel_block): ONE
 # mean-subtracting norm a layer feeds the attention AND the routed MLP, and
 # both are added to the stream. "P" attends over every earlier position
 # without rotation, "R" over the last cfg.window with rotation. The same
@@ -308,6 +308,44 @@ INDEX_INIT = {"wo": 16.0, "q_norm": 0.7}
 # (my chip runs, PR 45). WHICH experts are chosen, their load and every
 # product's shape and time are the same at any width.
 DELTA_INIT = {"dt": (0.001, 0.1), "q_norm": 2.0, "wo": 8.0, "router": 4.0}
+# Where the hyper-connections' own leaves start (seeded weights; a
+# checkpoint brings its own). ``phi`` is over its fan-in, so that ``m`` is
+# one unit wide; the three ``alpha`` start at ``alpha`` and not near zero
+# (the paper starts the dynamic part small, the mix nearly static): here the
+# DYNAMIC part has to carry enough of a logit that a comparison of logits
+# refuses a fault in it. ``b``: zero for the read-out and the write-back
+# (``H_pre`` around 1 / 2, ``H_post`` around 1), ``res_diag`` on the residual
+# mix's diagonal, so that a row keeps some 0.6 of itself and the rows stay
+# apart (at zero the doubly stochastic mix is near uniform and ten sublayers
+# make the rows one).
+HC_INIT = {"alpha": 1.0, "res_diag": 2.0}
+# The names ``LlamaConfig.seeded_scales`` may give, a latent block's leaves
+# that a CONFIGURATION starts off the square root of their fan-in
+# (_init_latent_kind): the attention's ``wo`` and the routed experts'
+# ``w_down`` (``expert_down``; the shared expert's and the dense layer's
+# stay). The values and their readings are the configuration file's
+# (``seeded_scales``, ``correct``), as BLOCK_INIT's reason has it: they set
+# the halves' shares of a logit. WHICH experts are chosen, their load and
+# every product's shape and time are the same at any of them.
+SEEDED_SCALES = ("wo", "expert_down")
+# Where a parallel block's SEEDED matrices start off the square root of their
+# fan-in (a checkpoint brings its own), for BLOCK_INIT's reason: so that a
+# comparison of logits sees a fault in either half and does not trip over a
+# sound engine's moved choice. At fan-in scaling a layer adds the four shared
+# experts' mean (0.30 an element), ONE held expert's eighth where a token
+# chose one (0.075; a sigmoid router's renormalised weights are 1/8 each
+# however wide it starts, so no router scale helps) and an attention over
+# thousands of keys whose scores are one unit wide, nearly an average (0.025).
+# The 8th and 9th of 128 router logits lie 0.06 apart, a stream that differs
+# by bfloat16's rounding moves that choice in one token-layer of twenty, and
+# where a held expert is the one moved that is 0.075 on a stream of 0.6: 12%
+# of a logit in one run of six, while a rotation or a window would show
+# nothing. ``wq`` twice as wide makes scores two units wide (some 75 of 4,096
+# keys carry a query's weight and which keys a layer sees matters); ``wo``
+# sets the attention's share so that a moved choice stays near 1% of a logit.
+# WHICH experts are chosen, their load and every product's shape and time are
+# the same at any of them. Readings: the configuration file's ``correct``.
+PARALLEL_INIT = {"wq": 2.0, "wo": 24.0}
 
 
 @dataclass(frozen=True)
@@ -455,7 +493,7 @@ class LlamaConfig:
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.3
     # the residual stream as hc_mult ROWS a token (manifold-constrained
-    # hyper-connections; hyper_connected, at this file's end, has the
+    # hyper-connections; hyper_connected has the
     # equations): every sublayer of an "L" / "G" block reads ONE mixed row,
     # and its output is written back onto all of them beside a doubly
     # stochastic mix of the rows (hc_sinkhorn_iters normalisations of
@@ -1220,6 +1258,114 @@ def _init_delta_family(cfg: LlamaConfig, kind: str, L: int, key):
     return out
 
 
+def _init_latent_kind(cfg: LlamaConfig, kind: str, L: int, key):
+    """The ``L`` stacked layers of kind ``"L"`` or ``"G"``, keys of their
+    own a kind. Every matrix over the square root of its fan-in (``wq_b`` /
+    ``wkv_b`` over ``sqrt(dim)`` where the config multiplies their inputs by
+    ``sqrt(dim / rank)``, as the ``"S"`` layer's): q, k and v then have unit
+    variance and a score is one unit wide. The choice bias starts at zero.
+    ``cfg.seeded_scales`` (a configuration's data) multiplies ``wo`` and the
+    routed experts' ``w_down``."""
+    d, f, H = cfg.dim, cfg.mlp_dim, cfg.n_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    kv = cfg.qk_nope_head_dim + cfg.v_head_dim
+    k = iter(jax.random.split(
+        jax.random.fold_in(key, 6 + LATENT_KINDS.index(kind)), 16))
+    dense = _dense_init
+    ones = lambda width: jnp.ones((L, width), jnp.float32)  # noqa: E731
+    out = {
+        "attn_norm": ones(d), "mlp_norm": ones(d),
+        "wq_a": dense(next(k), (L, d, rq), d), "q_norm": ones(rq),
+        "wq_b": dense(next(k), (L, rq, H * qk),
+                      d if cfg.mla_scale_q_lora else rq),
+        "wkv_a": dense(next(k), (L, d, cfg.latent_row), d),
+        "kv_norm": ones(rkv),
+        "wkv_b": dense(next(k), (L, rkv, H * kv),
+                       d if cfg.mla_scale_kv_lora else rkv),
+        "wo": dense(next(k), (L, H * cfg.v_head_dim, d), H * cfg.v_head_dim),
+    }
+    scales = dict(cfg.seeded_scales)  # the configuration's data, or none
+    if "wo" in scales:
+        out["wo"] = scales["wo"] * out["wo"]
+    if cfg.hc_mult > 1:  # a key of their own: the leaves above stay theirs
+        out.update(_init_hyper(cfg, L, jax.random.fold_in(
+            key, 9 + LATENT_KINDS.index(kind))))
+    if kind == "G":
+        fd = cfg.dense_mlp_dim
+        out.update(w_gate=dense(next(k), (L, d, fd), d),
+                   w_up=dense(next(k), (L, d, fd), d),
+                   w_down=dense(next(k), (L, fd, d), fd))
+        return out
+    held, wide = cfg.num_experts, cfg.router_experts or cfg.num_experts
+    out.update(router=dense(next(k), (L, d, wide), d),
+               router_bias=jnp.zeros((L, wide), jnp.float32),
+               w_gate=dense(next(k), (L, held, d, f), d),
+               w_up=dense(next(k), (L, held, d, f), d),
+               w_down=dense(next(k), (L, held, f, d), f))
+    if "expert_down" in scales:
+        out["w_down"] = scales["expert_down"] * out["w_down"]
+    if cfg.shared_mlp_dim:
+        fs = cfg.shared_mlp_dim
+        out.update(shared_gate=dense(next(k), (L, d, fs), d),
+                   shared_up=dense(next(k), (L, d, fs), d),
+                   shared_down=dense(next(k), (L, fs, d), fs))
+    return out
+
+
+def _init_mtp(cfg: LlamaConfig, key):
+    """The prediction module's OWN leaves: the two norms in front of
+    ``eh_proj`` ([2 dim, dim]: the embedding's half first), its ``"L"``
+    blocks and its final norm. Embedding and head are the model's."""
+    d = cfg.dim
+    k_eh, k_block = jax.random.split(jax.random.fold_in(key, 8))
+    return {"enorm": jnp.ones((d,), jnp.float32),
+            "hnorm": jnp.ones((d,), jnp.float32),
+            "eh_proj": _dense_init(k_eh, (2 * d, d), 2 * d),
+            "layers": {"latent": _init_latent_kind(
+                cfg, "L", cfg.mtp_layers, k_block)},
+            "final_norm": jnp.ones((d,), jnp.float32)}
+
+
+def _init_hyper(cfg: LlamaConfig, L: int, key):
+    """A kind's ``L`` layers' hyper-connection leaves, ``[L, 2, ...]``: a
+    sublayer each, the attention's then the MLP's (:data:`HC_INIT`)."""
+    n, d = cfg.hc_mult, cfg.dim
+    b = jnp.concatenate([jnp.zeros((2 * n,), jnp.float32),
+                         HC_INIT["res_diag"] * jnp.eye(n).reshape(-1)])
+    return {"hc_phi": _dense_init(key, (L, 2, n * d, 2 * n + n * n), n * d),
+            "hc_b": jnp.broadcast_to(b, (L, 2, b.shape[0])),
+            "hc_alpha": jnp.full((L, 2, 3), HC_INIT["alpha"], jnp.float32)}
+
+
+def _init_parallel(cfg: LlamaConfig, L: int, key):
+    """The ``L`` stacked parallel blocks, keys of their own. Every matrix
+    over the square root of its fan-in but :data:`PARALLEL_INIT`'s; the norm
+    at one. The ``shared_experts`` shared experts lie side by side: gate and
+    up ``[dim, n * shared_mlp_dim]`` (expert ``j``: columns ``j * width ..``),
+    down ``[n * shared_mlp_dim, dim]`` (its rows), each drawn over ONE
+    expert's fan-in."""
+    d, hd, f = cfg.dim, cfg.head_dim, cfg.mlp_dim
+    nq, nkv, held = cfg.n_heads, cfg.n_kv_heads, cfg.num_experts
+    fs, n = cfg.shared_mlp_dim, cfg.shared_experts
+    k = iter(jax.random.split(jax.random.fold_in(key, 11), 12))
+    dense = _dense_init
+    return {
+        "norm": jnp.ones((L, d), jnp.float32),
+        "wq": PARALLEL_INIT["wq"] * dense(next(k), (L, d, nq * hd), d),
+        "wk": dense(next(k), (L, d, nkv * hd), d),
+        "wv": dense(next(k), (L, d, nkv * hd), d),
+        "wo": PARALLEL_INIT["wo"] * dense(next(k), (L, nq * hd, d), nq * hd),
+        "router": dense(next(k), (L, d, cfg.router_experts or held), d),
+        "w_gate": dense(next(k), (L, held, d, f), d),
+        "w_up": dense(next(k), (L, held, d, f), d),
+        "w_down": dense(next(k), (L, held, f, d), f),
+        "shared_gate": dense(next(k), (L, d, n * fs), d),
+        "shared_up": dense(next(k), (L, d, n * fs), d),
+        "shared_down": dense(next(k), (L, n * fs, d), fs),
+    }
+
+
 def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
     d, hd = cfg.dim, cfg.head_dim
     nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
@@ -1532,6 +1678,204 @@ def attend_latent_expanded(cfg: LlamaConfig, q, latent, wkv_b):
                         shared=latent[..., r:])
 
 
+# --------------------------------------------------------------------------- #
+# Latent blocks outside "S" (kinds "L" and "G"; TRAINED kinds, and SERVED)
+# and the stream as hc_mult rows a token (manifold-constrained
+# hyper-connections)
+# --------------------------------------------------------------------------- #
+
+
+def attend_latent_heads(cfg: LlamaConfig, attend, q, latent, wkv_b):
+    """``_latent_half``'s ``attend`` for a TRAIN step: every position's
+    per-head ``[k_nope | v]`` is made, ``c wkv_b``, the ONE rotated key
+    slice is repeated for every head, and ``attend(q, k, v)`` (the caller's:
+    the flash kernel, forward AND backward) sees plain attention of
+    ``n_heads`` on ``n_heads`` at the score's width, which is the value's.
+    Not :func:`attend_latent_expanded`: prefill's kernel takes the shared
+    slice as an operand of its own, but it is forward only, and its
+    transpose is the XLA tile loop. The repeat costs ``qk_rope_head_dim`` of
+    ``head width`` more key bytes, and buys the kernels' backward."""
+    B, T, H, _ = q.shape
+    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = (latent[..., :r] @ wkv_b).reshape(B, T, H, -1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        latent[:, :, None, r:], (B, T, H, cfg.qk_rope_head_dim))], axis=-1)
+    q, k, v = (checkpoint_name(a, "attn") for a in (q, k, kv[..., dn:]))
+    return attend(q, k, v)
+
+
+def widen_stream(cfg: LlamaConfig, x):
+    """The embedding ``[B, T, dim]`` as the stream's first state: every one
+    of the ``hc_mult`` rows a token is a copy of it, side by side ``[B, T,
+    hc_mult * dim]`` (row ``j`` is ``[..., j * dim:(j + 1) * dim]``, whole
+    lanes; every program and the walker keep reading ``x.shape[:2]`` and
+    slicing positions as they did. The rows as a dimension of their own cost
+    the same on the chip, 12.6 against 12.3 ms a sublayer's passes at 16,384
+    positions: the compiler lays either out positions-minor;
+    ``sweep/xing4_check.md``). ``hc_mult`` 1: ``x`` as it is."""
+    return x if cfg.hc_mult == 1 else jnp.tile(x, (1, 1, cfg.hc_mult))
+
+
+def collapse_stream(cfg: LlamaConfig, x):
+    """The stream's end: its rows SUMMED (float32) to ``[B, T, dim]``, what
+    the final norm reads. ``hc_mult`` 1: ``x`` as it is."""
+    if cfg.hc_mult == 1:
+        return x
+    with jax.named_scope("hc.sum"):
+        rows = x.reshape(*x.shape[:2], cfg.hc_mult, -1)
+        return rows.astype(jnp.float32).sum(axis=2).astype(x.dtype)
+
+
+def hyper_mix(cfg: LlamaConfig, phi, b, alpha, x):
+    """One sublayer's three mixes from the stream ``x`` [B, T, n * dim], all
+    float32 whatever the stream's type, the positions LAST (lanes: twenty
+    normalisations of ``[n, n]`` minor would run on padded tiles)::
+
+        m      = (x~ phi) / sqrt(mean(x~ ** 2) + eps)        x~ = vec(X)
+        H_pre  = sigmoid(alpha_0 m[:n] + b[:n])              [n, B, T]
+        H_post = 2 sigmoid(alpha_1 m[n:2n] + b[n:2n])        [n, B, T]
+        H_res  = Sinkhorn(exp(clip(alpha_2 m[2n:] + b[2n:])))  [n, n, B, T]
+
+    ``phi`` [n * dim, 2n + n * n] carries the norm's gain; Sinkhorn:
+    ``hc_sinkhorn_iters`` times every row over its sum, then every column
+    over its sum, ``+ eps`` in each divisor. Returns ``(H_pre, H_post, H_res,
+    error)``: ``error`` the largest ``|row sum - 1|`` or ``|column sum - 1|``
+    of ``H_res``."""
+    f32, n, eps = jnp.float32, cfg.hc_mult, cfg.hc_eps
+    x32 = x.astype(f32)
+    m = jnp.einsum("btc,cm->mbt", x32, phi,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=f32)
+    m = m * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1) + eps)
+    b = b[:, None, None]
+    pre = jax.nn.sigmoid(alpha[0] * m[:n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + b[n:2 * n])
+    res = jnp.exp(jnp.clip(alpha[2] * m[2 * n:] + b[2 * n:],
+                           cfg.hc_res_clamp_min, cfg.hc_res_clamp_max))
+    res = res.reshape(n, n, *m.shape[1:])  # [i, j]: onto row i from row j
+    for _ in range(cfg.hc_sinkhorn_iters):
+        res = res / (res.sum(axis=1, keepdims=True) + eps)
+        res = res / (res.sum(axis=0, keepdims=True) + eps)
+    error = jnp.maximum(jnp.abs(res.sum(axis=1) - 1.0).max(),
+                        jnp.abs(res.sum(axis=0) - 1.0).max())
+    return pre, post, res, error
+
+
+def hyper_connected(cfg: LlamaConfig, hc, x, sublayer):
+    """ONE sublayer on the stream, for every kind that could take it:
+    ``sublayer(h) -> (y, aux)`` reads ``h`` [B, T, dim] (its own norm is
+    its own) and returns what it adds. ``hc`` None (``hc_mult`` 1)::
+
+        x' = x + y                         the plain residual block
+
+    ``hc = (phi, b, alpha)``, this sublayer's, on ``x`` [B, T, n * dim]
+    (:func:`widen_stream`'s layout; the mixes: :func:`hyper_mix`, scope
+    ``hc.mix``)::
+
+        h     = sum_j H_pre[j] X[j]                          ``hc.read``
+        X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y         ``hc.write``
+
+    in float32, stored in the stream's type. Returns ``(x', aux, error)``,
+    ``error`` :func:`hyper_mix`'s (None without ``hc``)."""
+    if hc is None:
+        y, aux = sublayer(x)
+        return x + y.astype(x.dtype), aux, None
+    f32, n = jnp.float32, cfg.hc_mult
+    d = x.shape[-1] // n
+    with jax.named_scope("hc.mix"):
+        pre, post, res, error = hyper_mix(cfg, *hc, x)
+    rows = [x[..., j * d:(j + 1) * d].astype(f32) for j in range(n)]
+    with jax.named_scope("hc.read"):
+        h = sum(pre[j][..., None] * rows[j] for j in range(n))
+    y, aux = sublayer(h.astype(x.dtype))
+    with jax.named_scope("hc.write"):
+        y = y.astype(f32)
+        out = jnp.concatenate([
+            sum(res[i, j][..., None] * rows[j] for j in range(n))
+            + post[i][..., None] * y for i in range(n)], axis=-1)
+    return out.astype(x.dtype), aux, error
+
+
+def _latent_sublayers(cfg: LlamaConfig, kind: str, attend, x, p, positions,
+                      stat_axes, layer):
+    """The latent block's two sublayers (:func:`latent_block` has the
+    equations), each through :func:`hyper_connected`. ``attend(q, latent,
+    wkv_b)`` as :func:`_latent_half` takes it; ``p``: ONE layer's leaves,
+    its experts that layer's (``layer`` None) or the kind's whole stack with
+    ``layer`` its number (``routed_mlp``). Returns ``(x, stats, latent)``:
+    ``latent`` ``[B, T, latent_row]``, what a cache keeps; ``stats`` with
+    ``hc_sinkhorn_error`` where the stream is mixed."""
+    cd, eps = cfg.dtype, cfg.norm_eps
+    hc = [None, None] if cfg.hc_mult == 1 else [
+        (p["hc_phi"][j], p["hc_b"][j], p["hc_alpha"][j]) for j in (0, 1)]
+
+    def mla(h):
+        y, latent = _latent_half(
+            cfg, p, rms_norm(h, p["attn_norm"], eps).astype(cd), positions,
+            attend)
+        return checkpoint_name(y, "attn"), latent
+
+    def mlp(h):
+        h = rms_norm(h, p["mlp_norm"], eps).astype(cd)
+        if kind == "G":
+            with jax.named_scope("ffn.dense"):
+                return _dense_mlp(cfg, p, h), {}
+        return _mlp_half(cfg, p, h, stat_axes, layer=layer)
+
+    x, latent, first = hyper_connected(cfg, hc[0], x, mla)
+    x, stats, second = hyper_connected(cfg, hc[1], x, mlp)
+    if first is not None:
+        stats = {**stats, "hc_sinkhorn_error": jnp.maximum(first, second)}
+    return x, stats, latent
+
+
+def latent_block(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
+    """THE latent block (kinds ``"L"`` and ``"G"``), for the train steps and
+    the full forward (the serving programs': :func:`serve_latent_block`, over
+    the same :func:`_latent_sublayers`)::
+
+        a   = x + MLA(N(x))          (:func:`_latent_half`, no sqrt(dim / rank)
+                                      factor unless the config sets it)
+        out = a + MLP(N(a))          "G": the dense SwiGLU (dense_mlp_dim,
+                                     scope ``ffn.dense``); "L": the routed MLP
+                                     (:func:`_mlp_half`: sigmoid or softmax
+                                     scores, the choice bias, a held range)
+                                     with its ungated shared SwiGLU expert
+
+    each ``+`` a hyper-connection where ``cfg.hc_mult > 1``
+    (:func:`hyper_connected`; ``x`` is then ``[B, T, hc_mult * dim]``).
+    ``attend(q, k, v)`` as :func:`decoder_block` takes it: the block makes
+    per-head keys and values itself (:func:`attend_latent_heads`), so the
+    trainer's flash kernel runs forward, dQ and dK/dV on them; where the
+    score's width is not the value's (no train step takes that) the full
+    forward attends as prefill does (:func:`attend_latent_expanded`). ``p``:
+    this layer's weights. Returns ``(x, stats)``."""
+    one_width = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                 == cfg.v_head_dim)
+    x, stats, _ = _latent_sublayers(
+        cfg, kind, partial(attend_latent_heads, cfg, attend) if one_width
+        else partial(attend_latent_expanded, cfg), x, p,
+        positions_of(*x.shape[:2]), stat_axes, None)
+    # a dense layer's stats stay {}: pattern_stack stacks the routed ones'
+    return x, {k: v for k, v in stats.items() if k != "hc_sinkhorn_error"}
+
+
+def serve_latent_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
+                       attend, stat_axes=()):
+    """:data:`SERVED`'s block of the kinds ``"L"`` and ``"G"``:
+    :func:`latent_block`'s arithmetic on the kind's stacked weights
+    ``layers`` ``[n, ...]``, layer ``i``, over ``attend(q, latent, wkv_b)``
+    (prefill: :func:`attend_latent_expanded`; decode:
+    :func:`_attend_latent_cached`). Every leaf is cut out ``[i]`` but the
+    routed experts, which go down whole with ``layer=i``
+    (:func:`shortcut_layer` says why). Returns ``(x, stats, latent)``:
+    the layer's ``[c | k_r]`` rows ``[B, T, latent_row]``."""
+    whole = () if kind == "G" else ("w_gate", "w_up", "w_down")
+    p = {w: a if w in whole else a[i] for w, a in layers.items()}
+    return _latent_sublayers(cfg, kind, attend, x, p, positions, stat_axes,
+                             None if kind == "G" else i)
+
+
 # every distinct prefill attention traced in this process and the way it
 # went: (kind, shapes, window, path) -> {.., "reason", "calls"}; programs are
 # traced on whatever thread first calls them (one lock for this record and
@@ -1616,6 +1960,117 @@ def _note_expert_products(xs, w_up, path, reason) -> None:
                            if r["path"] == way) for way in ("kernel", "xla")}
     for way, n in counts.items():
         _g_engine_expert_products.set(float(n), tags={"path": way})
+
+
+# counted where a program is traced (ops/moe.py _held_chunks / _held_blocks
+# tell an engine's process through watch_held_sums): in an engine's process on
+# the chip a prefill program over more than one chunk of places reads
+# path=kernel (ops/row_sum.py) and a decode program (one straight block)
+# path=xla; held_sum_paths() keeps the reason beside the count
+_g_engine_held_sums = Gauge(
+    "ray_tpu_serve_engine_held_sums",
+    "Sums of the held experts' rows onto tokens traced in a decode engine's "
+    "process, by the path they took: the Pallas gather-sum or XLA's "
+    "scatter-add", tag_keys=("path",))
+
+# (rows, tokens, type, path, reason) -> {.., "calls"}
+_held_sums_taken: Dict[tuple, dict] = {}
+
+
+def held_sum_paths() -> list:
+    """Every distinct sum of the held path on stacked leaves (rows, tokens,
+    type, path) traced in this process since its first routed engine was
+    built, with its reason (``ops.moe.held_sum_path``'s) and how often."""
+    with _paths_lock:
+        return [dict(rec) for rec in _held_sums_taken.values()]
+
+
+def _note_held_sums(ys, n_tokens, path, reason) -> None:
+    dtype = jnp.dtype(ys.dtype).name
+    key = (ys.shape, n_tokens, dtype, path, reason)
+    with _paths_lock:
+        rec = _held_sums_taken.setdefault(key, {
+            "rows": list(ys.shape), "tokens": n_tokens, "dtype": dtype,
+            "path": path, "reason": reason, "calls": 0})
+        rec["calls"] += 1
+        counts = {way: sum(r["calls"] for r in _held_sums_taken.values()
+                           if r["path"] == way) for way in ("kernel", "xla")}
+    for way, n in counts.items():
+        _g_engine_held_sums.set(float(n), tags={"path": way})
+
+
+def _watch_routed_calls() -> None:
+    """What a routed engine registers with ``ops/moe.py`` where it is built:
+    the counts of its experts' products and of its held sums by path."""
+    from ray_tpu.ops.moe import watch_held_sums, watch_stacked_calls
+
+    watch_stacked_calls(_note_expert_products)
+    watch_held_sums(_note_held_sums)
+
+
+# what the held path made of the last prefill's assignments: the places with
+# a held expert (``live``: held_share of tokens x top_k, a routed layer's
+# mean) and the places a row was gathered, multiplied and summed for
+# (``made``: ops/moe.py held_places_made, whole chunks up to ``live``). Set by
+# an engine whose layers hold a range of the router's experts, by no other
+_g_moe_places = Gauge(
+    "ray_tpu_serve_moe_places",
+    "Sorted places of the last prefill's routed layers (a layer's mean) "
+    "that fell on experts held here, and the places a row was made for",
+    tag_keys=("state",))
+
+
+def _note_assignments(shares, places: int, cfg) -> None:
+    """Set ``ray_tpu_serve_moe_assignment_share{part}`` from a prefill's
+    ``shares`` (a routed layer's mean of ``routed_mlp``'s ``held_share`` /
+    ``zero_share``; neither: every expert is here) and, where a range is
+    held, ``ray_tpu_serve_moe_places{state}`` of its ``places`` (tokens x
+    ``top_k``, pads and all: the program routes them too)."""
+    from ray_tpu.ops.moe import held_places_made
+
+    held = float(shares.get("held_share", 1.0))
+    zero = float(shares.get("zero_share", 0.0))
+    for part, share in (("held", held), ("zero", zero),
+                        ("elsewhere", 1.0 - held - zero)):
+        _g_moe_assignment_share.set(share, tags={"part": part})
+    if "held_share" in shares:
+        live = round(held * places)
+        wide = (cfg.router_experts or cfg.num_experts) + cfg.zero_experts
+        made = held_places_made(places, live, cfg.num_experts, wide)
+        for state, n in (("live", live), ("made", made)):
+            _g_moe_places.set(float(n), tags={"state": state})
+
+
+# bytes ONE position of the residual stream takes as the serving programs
+# carry it between sublayers: hc_mult rows of dim in the stream's type
+# (float32 for the kinds whose row of SERVED says so); set where an engine
+# is built
+_g_engine_stream_bytes = Gauge(
+    "ray_tpu_serve_engine_stream_bytes",
+    "Bytes one position of the residual stream takes as the decode engine's "
+    "programs carry it (hc_mult rows of dim in the stream's type)")
+# how far the last prefill's residual mixes were from doubly stochastic: the
+# largest |row sum - 1| or |column sum - 1| of H_res over its positions and
+# sublayers, read with the logits; an engine without hyper-connections sets
+# none
+_g_hc_sinkhorn_error = Gauge(
+    "ray_tpu_serve_hc_sinkhorn_error",
+    "Largest distance of a row or column sum of the last prefill's "
+    "hyper-connection residual mixes from 1")
+
+
+def _note_stream(shares) -> None:
+    """Take what a prefill says of its stream out of ``shares`` (the
+    walker's ``hc_sinkhorn_error``) and set its gauge."""
+    error = shares.pop("hc_sinkhorn_error", None)
+    if error is not None:
+        _g_hc_sinkhorn_error.set(float(error))
+
+
+# a walker's statistic over its layers (_serve_layers): a share is their
+# mean, an error their largest
+OVER_LAYERS = {"held_share": jnp.mean, "zero_share": jnp.mean,
+               "hc_sinkhorn_error": jnp.max}
 
 
 def attend_tiles(q, k, v, cd, window: int = 0, shared=None, kind=None):
@@ -1886,6 +2341,44 @@ def mrope_rotate(x, positions, theta: float, sections=()):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                            axis=-1).astype(x.dtype)
+
+
+def yarn_frequencies(cfg: LlamaConfig):
+    """The latent half's rotation frequencies under ``cfg.rope_yarn``
+    (``[qk_rope_head_dim / 2]`` float32, host arithmetic): frequency ``i``
+    of ``f_i = theta ** (-2i / D)`` is kept where it turns more than
+    ``beta_fast`` times within the original context, divided by ``factor``
+    where fewer than ``beta_slow`` times, and blended linearly between::
+
+        low  = floor(D ln(original / (beta_fast 2 pi)) / (2 ln theta))
+        high = ceil(D ln(original / (beta_slow 2 pi)) / (2 ln theta))
+        ramp_i = clip((i - low) / (high - low), 0, 1)   both within [0, D-1]
+        f_i (1 - ramp_i) + f_i / factor ramp_i"""
+    import numpy as np
+
+    y, D = dict(cfg.rope_yarn), cfg.qk_rope_head_dim
+    f = cfg.rope_theta ** (-np.arange(0, D, 2, dtype=np.float64) / D)
+
+    def turns(beta):
+        return (D * math.log(y["original_max_position_embeddings"]
+                             / (beta * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = min(max(math.floor(turns(y.get("beta_fast", 32))), 0), D - 1)
+    high = min(max(math.ceil(turns(y.get("beta_slow", 1))), 0), D - 1)
+    ramp = np.clip((np.arange(D // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (f * (1 - ramp) + f / y["factor"] * ramp).astype(np.float32)
+
+
+def yarn_softmax_factor(cfg: LlamaConfig) -> float:
+    """What multiplies the latent half's scores beside ``1 / sqrt(score
+    width)`` under ``cfg.rope_yarn``: ``(0.1 mscale_all_dim ln(factor) +
+    1) ** 2`` (1 without it)."""
+    y = dict(cfg.rope_yarn)
+    if not y or y["factor"] <= 1:
+        return 1.0
+    return (0.1 * y.get("mscale_all_dim", 1) * math.log(y["factor"])
+            + 1.0) ** 2
 
 
 def _order_key(score):
@@ -2241,10 +2734,7 @@ def attend_delta(cfg: LlamaConfig, last, qkv, g, beta, conv_w):
     XLA form does for itself a segment.
 
     The tail is XLA's gather of ``lin_conv - 1`` rows of ``qkv`` at
-    ``last`` on both paths (:func:`_delta_tail`).
-
-    Both paths, the tail and the rule stand at this file's END: lines put in
-    here move the frames that every other program's Mosaic kernels record."""
+    ``last`` on both paths (:func:`_delta_tail`)."""
     (B, T, _), K = qkv.shape, conv_w.shape[0]
     path, reason = delta_prefill_path(cfg, qkv, g, beta)
     _note_prefill_attend("delta", qkv, g, 0, path, reason, "chunks")
@@ -2258,6 +2748,124 @@ def attend_delta(cfg: LlamaConfig, last, qkv, g, beta, conv_w):
     else:
         o, state, tail = _delta_chunks(cfg, last, qkv, g, beta, conv_w)
     return o, state[:, None], tail[:, None]
+
+
+# --- the delta rule's prefill: its rule and its two paths (attend_delta) --- #
+
+
+def delta_prefill_path(cfg: LlamaConfig, qkv, g, beta) -> Tuple[str, str]:
+    """``(path, reason)`` :func:`attend_delta` takes for these operands in
+    this process: ``"kernel"`` on a TPU backend for what
+    ``ops/gdn_prefill.py`` takes (``qkv`` in ``cfg.dtype``, ``g`` and
+    ``beta`` float32, key and value widths whole lanes, a key head's value
+    heads side by side in whole column blocks, positions a multiple of one
+    of its row tiles), ``"chunks"`` with what stands in the way otherwise.
+    Read from the backend and the shapes alone."""
+    platform = jax.default_backend()
+    if platform != "tpu":
+        return "chunks", f"backend is {platform!r}, not tpu"
+    from ray_tpu.ops.gdn_prefill import pick_rows
+
+    hk, dk = cfg.lin_key_heads, cfg.lin_key_dim
+    hv, dv = cfg.lin_value_heads, cfg.lin_value_dim
+    types = [a.dtype.name for a in (qkv, g, beta)]
+    if types != [jnp.dtype(cfg.dtype).name, "float32", "float32"]:
+        return "chunks", (f"[q | k | v], g, beta in {types}: not "
+                          f"{jnp.dtype(cfg.dtype).name} and float32 twice")
+    if dk % 128 or dv % 128:
+        return "chunks", (f"key width {dk} or value width {dv} is no "
+                          "multiple of 128 lanes")
+    if hv % hk or hv // hk > 2 or (2 * hk * dk) % (hv // hk * dv):
+        return "chunks", (f"{hv} value heads of {dv} on {hk} key heads of "
+                          f"{dk}: a key head's value heads (at most 2, a "
+                          "chunk of each side by side in 128 lanes) are no "
+                          "whole column block")
+    if pick_rows(qkv.shape[1]) is None:
+        return "chunks", (f"{qkv.shape[1]} positions are no multiple of a "
+                          "row tile of the kernel's")
+    return "kernel", "tpu backend"
+
+
+def _delta_chunks(cfg: LlamaConfig, last, qkv, g, beta, conv_w):
+    """:func:`attend_delta` in XLA: the causal convolution (``ops/ssm.py
+    causal_conv``, no bias), then the recurrence in chunks (``ops/gdn.py
+    gated_delta_chunked``), ``DELTA_SEGMENT`` positions at a time where that
+    divides them and in one piece where not: a ``lax.scan`` that carries
+    what a decode call would find, the state and the convolution's tail.
+    Returns ``(o [B, T, value heads, value dim] float32, state, tail)``."""
+    from ray_tpu.ops.gdn import gated_delta_chunked
+    from ray_tpu.ops.ssm import causal_conv
+
+    f32 = jnp.float32
+    (B, T, _), K = qkv.shape, conv_w.shape[0]
+    seg = DELTA_SEGMENT if T % DELTA_SEGMENT == 0 else T
+
+    def segment(carry, xs):
+        state, before = carry
+        qkv_s, g_s, beta_s, start = xs
+        with jax.named_scope("gdn.conv"):
+            rows = jnp.concatenate([before, qkv_s], axis=1).astype(f32)
+            mixed = jax.nn.silu(causal_conv(rows, conv_w, 0.0)[:, K - 1:])
+            q, k, v = _delta_heads(cfg, mixed)
+        with jax.named_scope("gdn.scan"):
+            o, state = gated_delta_chunked(
+                q, k, v, g_s, beta_s, cfg.lin_chunk, state,
+                None if last is None else last - start)
+        return (state, qkv_s[:, seg - (K - 1):]), o
+
+    def segments(a):  # [B, T, ...] -> [T / seg, B, seg, ...]
+        return jnp.moveaxis(a.reshape(B, -1, seg, *a.shape[2:]), 1, 0)
+
+    hv = cfg.lin_value_heads
+    start = (jnp.zeros((B, hv, cfg.lin_key_dim, cfg.lin_value_dim), f32),
+             jnp.zeros((B, K - 1, qkv.shape[-1]), qkv.dtype))
+    (state, _), o = jax.lax.scan(
+        segment, start, (segments(qkv), segments(g), segments(beta),
+                         jnp.arange(0, T, seg, dtype=jnp.int32)))
+    tail = _delta_tail(last, qkv, K)
+    return jnp.moveaxis(o, 0, 1).reshape(B, T, hv, -1), state, tail
+
+
+def _delta_tail(last, qkv, K: int):
+    """The convolution's tail at ``last`` (None: the last position): the
+    ``K - 1`` rows of ``qkv`` [B, T, width] that end there, zeros before the
+    sequence's first."""
+    T = qkv.shape[1]
+    with jax.named_scope("gdn.conv"):
+        at = (T - 1 if last is None else last) - (K - 2) + jnp.arange(K - 1)
+        return jnp.where((at >= 0)[None, :, None],
+                         qkv[:, jnp.maximum(at, 0)], 0)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _delta_kernel(qkv, g, beta, conv_w, cfg):
+    """``g`` and ``beta`` with the positions behind the last real one
+    already 0: from an empty state and tail, as :func:`_delta_chunks`."""
+    from ray_tpu.ops.gdn_prefill import gdn_prefill
+
+    B, K = qkv.shape[0], conv_w.shape[0]
+    # interpreted where a test has steered a CPU process onto this path
+    return gdn_prefill(
+        qkv, conv_w, g, beta,
+        jnp.zeros((B, cfg.lin_value_heads, cfg.lin_key_dim,
+                   cfg.lin_value_dim), jnp.float32),
+        jnp.zeros((B, K - 1, qkv.shape[-1]), qkv.dtype),
+        key_heads=cfg.lin_key_heads, key_dim=cfg.lin_key_dim,
+        interpret=jax.default_backend() != "tpu")
+
+
+def _delta_kernel_fwd(qkv, g, beta, conv_w, cfg):
+    return _delta_kernel(qkv, g, beta, conv_w, cfg), (qkv, g, beta, conv_w)
+
+
+def _delta_kernel_bwd(cfg, operands, cotangent):
+    # forward only: the kernel keeps nothing for a backward pass, so its
+    # transpose is the XLA path's, run again from the operands
+    return jax.vjp(lambda *a: _delta_chunks(cfg, None, *a)[:2],
+                   *operands)[1](cotangent)
+
+
+_delta_kernel.defvjp(_delta_kernel_fwd, _delta_kernel_bwd)
 
 
 def _attend_state(cfg: LlamaConfig, call, l, mine, qkv, g, beta, conv_w):
@@ -2320,6 +2928,88 @@ def gated_block(cfg: LlamaConfig, x, layers, i, positions, attend,
         cfg, x + (o @ p["wo"].astype(cd)).astype(x.dtype), layers, i,
         stat_axes)
     return out, stats, (k, v)
+
+
+def parallel_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
+                   attend, stat_axes=()):
+    """THE parallel block of the kinds ``"P"`` and ``"R"``, for the full
+    forward and for the serving programs (``LN``: :func:`layer_norm`, the
+    mean subtracted, no bias)::
+
+        a   = LN(x)                       ONE norm a layer
+        A   = Attention(a) Wo             "R": q, k rotated in pairs (2i,
+                                          2i + 1), over the last cfg.window
+                                          positions; "P": not rotated, over
+                                          every earlier one
+        R   = sum_e w_e SwiGLU_e(a)       w = s / sum of the chosen s, s =
+                                          sigmoid(a Wr) in float32; the held
+                                          experts' part of it
+        S   = 1/n sum_j SwiGLU_j(a)       the n shared experts, as ONE
+                                          expert n times as wide
+        out = x + A + R + S               attention and MLP read the SAME a
+
+    ``layers``: the ``parallel`` stack ``[L, ...]`` both kinds share, ``i``:
+    which layer (a number, or traced in a scan); every matrix is cut out
+    ``[i]`` where it is used but the routed experts, which go down whole with
+    ``layer=i`` (:func:`shortcut_layer` says why). The stream keeps the type
+    it comes in (float32 in the serving programs), the router reads ``a`` in
+    that type and every product its rounding to ``cfg.dtype``.
+    ``attend(q, k, v)`` as :func:`decoder_block` takes it. Device scopes
+    ``par.norm``, ``par.qkv``, ``attn.window`` / ``attn.full``, ``par.out``,
+    ``moe.route`` / ``.dispatch`` / ``.experts`` / ``.combine`` and
+    ``moe.shared``. Returns ``(x, stats, (k, v))``: ``k`` rotated for
+    ``"R"`` and as projected for ``"P"``."""
+    from ray_tpu.ops.moe import routed_mlp
+
+    cd, hd = cfg.dtype, cfg.head_dim
+    B, T, _ = x.shape
+    with jax.named_scope("par.norm"):
+        a = layer_norm(x, layers["norm"][i], cfg.norm_eps)
+        h = a.astype(cd)
+    with jax.named_scope("moe.shared"):
+        g = jax.nn.silu((h @ layers["shared_gate"][i].astype(cd)
+                         ).astype(jnp.float32))
+        u = h @ layers["shared_up"][i].astype(cd)
+        s = ((g * u).astype(cd) @ layers["shared_down"][i].astype(cd)
+             ).astype(jnp.float32) / cfg.shared_experts
+    # ONE half at a time: both read ``h`` and nothing else ties them, and a
+    # scheduler that runs them side by side holds the shared experts' gate
+    # and up ([T, n * width] each) beside q, the attention's output and their
+    # transposes: 5.0 GB of temporaries at 16,384 positions against 3.0 in
+    # turn (3.2 without the second barrier below; compiled for a described
+    # v5e, PR 54)
+    out, h = jax.lax.optimization_barrier((x + s.astype(x.dtype), h))
+    with jax.named_scope("par.qkv"):
+        q, k, v = _qkv(cfg, {w: layers[w][i] for w in ("wq", "wk", "wv")}, h,
+                       cfg.n_heads, cfg.n_kv_heads, positions,
+                       rope=kind == "R")
+    with jax.named_scope("attn.window" if kind == "R" else "attn.full"):
+        o = attend(q, k, v)
+    with jax.named_scope("par.out"):
+        out = out + (o.reshape(B, T, cfg.n_heads * hd)
+                     @ layers["wo"][i].astype(cd)).astype(x.dtype)
+    out, h = jax.lax.optimization_barrier((out, h))
+    wide = cfg.router_experts or cfg.num_experts
+    # the float32 sum goes onto the stream as it is
+    y, stats = routed_mlp(
+        h, layers["router"][i], layers["w_gate"], layers["w_up"],
+        layers["w_down"], top_k=cfg.experts_per_token,
+        norm_topk_prob=cfg.norm_topk_prob, stat_axes=stat_axes,
+        scoring=cfg.router_scoring,
+        held=((cfg.first_expert, cfg.num_experts)
+              if wide != cfg.num_experts else None),
+        layer=i, router_input=a)
+    return out + y.astype(x.dtype), stats, (k, v)
+
+
+def attend_parallel_tiles(cfg: LlamaConfig, kind: str, q, k, v):
+    """:func:`parallel_block`'s ``attend`` over the call's own positions (the
+    full forward and prefill): :func:`attend_tiles` with the band for
+    ``"R"``, counted under kinds of their own."""
+    return attend_tiles(q, k, v, cfg.dtype,
+                        window=cfg.window if kind == "R" else 0,
+                        kind="parallel_window" if kind == "R"
+                        else "parallel_full")
 
 
 def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
@@ -2570,6 +3260,56 @@ def chunked_nll_mean(cfg: LlamaConfig, x, targets, chunk_nll, policy=None,
     return total / count
 
 
+def add_mtp_loss(cfg: LlamaConfig, mtp, x, tokens, nll, stats, *, embed,
+                 attend, chunk_nll, stat_axes=(), policy=None):
+    """The multi-token-prediction module's loss beside the main one, for
+    :func:`loss_parts` and ``make_spmd_train_step`` alike. ``x`` [B, T, dim]:
+    the main model's FINAL-NORMED stream over ``tokens[:, :-1]`` (``tokens``
+    [B, T + 1] = t_0 .. t_T); ``nll`` / ``stats``: the main cross-entropy and
+    the stack's router stats. The caller lends what the module SHARES with
+    the model: ``embed(ids)`` (the embedding table), ``chunk_nll`` (the
+    head), and its ``attend``::
+
+        h'_i = [N_e(Emb(t_{i+1})) ; N_h(x_i)] W_eh      scope ``mtp.merge``
+        y    = the module's "L" block(s) over all T positions  ``mtp.block``
+        L_mtp = mean_{i < T - 1} -log p(t_{i+2} | N(y_i) head)  ``mtp.head``
+
+    Position ``T - 1`` has no target and is left out of the mean
+    (``chunked_nll_mean``'s ``live``). Returns ``(nll + mtp_loss_weight *
+    L_mtp, stats with the module's block's behind the stack's, {"main_loss":
+    nll, "mtp_loss": L_mtp})``; without a module what it was given and
+    ``{}``."""
+    if not cfg.mtp_layers:
+        return nll, stats, {}
+    cd, eps = cfg.dtype, cfg.norm_eps
+    B, T, _ = x.shape
+    # t_{i+2}; the last position's is any id: it does not count
+    targets = jnp.concatenate(
+        [tokens[:, 2:], jnp.zeros((B, 1), tokens.dtype)], axis=1)
+
+    def merge(x, ids, enorm, hnorm, eh_proj):
+        both = jnp.concatenate(
+            [rms_norm(embed(ids), enorm, eps).astype(cd),
+             rms_norm(x, hnorm, eps).astype(cd)], axis=-1)
+        return (both @ eh_proj.astype(cd)).astype(x.dtype)
+
+    with jax.named_scope("mtp.merge"):
+        y = (jax.checkpoint(merge) if cfg.remat else merge)(
+            x, tokens[:, 1:], mtp["enorm"], mtp["hnorm"], mtp["eh_proj"])
+    with jax.named_scope("mtp.block"):
+        y, block_stats = pattern_stack(
+            cfg, y, mtp["layers"], attend, stat_axes, policy,
+            kinds="L" * cfg.mtp_layers)
+    with jax.named_scope("mtp.head"):
+        mtp_nll = chunked_nll_mean(
+            cfg, rms_norm(y, mtp["final_norm"], eps), targets, chunk_nll,
+            policy, live=T - 1)
+    stats = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), stats,
+                         block_stats)
+    return (nll + cfg.mtp_loss_weight * mtp_nll, stats,
+            {"main_loss": nll, "mtp_loss": mtp_nll})
+
+
 def loss_parts(cfg: LlamaConfig, params, tokens, mesh=None):
     """``(total, report)``: what is trained on, and for a routed model the
     router's scalars apart (:func:`add_router_losses`; the cross-entropy
@@ -2596,35 +3336,6 @@ def loss_fn(cfg: LlamaConfig, params, tokens, mesh=None):
     weighted router losses. tokens [B, T+1]. See :func:`chunked_nll_mean`
     for the chunked-head memory story."""
     return loss_parts(cfg, params, tokens, mesh)[0]
-
-
-def _dense_only(cfg: LlamaConfig, who: str, why: str) -> None:
-    """Routed experts and QK-norm are the block's (:func:`decoder_block`),
-    so every path COMPUTES them; a path that cannot yet answer for the
-    result says what it lacks instead of running."""
-    if cfg.num_experts or cfg.qk_norm or cfg.layer_pattern:
-        raise NotImplementedError(
-            f"{who} takes no config with num_experts={cfg.num_experts}, "
-            f"qk_norm={cfg.qk_norm}, layer_pattern={cfg.layer_pattern!r} "
-            f"yet: {why}")
-
-
-def _no_window_kinds(cfg: LlamaConfig, who: str, why: str) -> None:
-    """A path whose attention cannot band refuses the ``"F"`` / ``"W"``
-    kinds by name, as :func:`_dense_only` refuses what it lacks."""
-    if set(cfg.kinds) & set(BLOCK_KINDS):
-        raise NotImplementedError(
-            f"{who} takes no 'F' / 'W' layer (layer_pattern="
-            f"{cfg.layer_pattern!r}, window={cfg.window}) yet: {why}")
-
-
-def _no_delta_kinds(cfg: LlamaConfig, who: str, why: str) -> None:
-    """A path no reference holds the ``"D"`` / ``"A"`` kinds' gradients on
-    refuses them by name, as :func:`_no_window_kinds` refuses its kinds."""
-    if set(cfg.kinds) & set(DELTA_KINDS):
-        raise NotImplementedError(
-            f"{who} takes no 'D' / 'A' layer (layer_pattern="
-            f"{cfg.layer_pattern!r}) yet: {why}")
 
 
 # --------------------------------------------------------------------------- #
@@ -2732,6 +3443,46 @@ def _attend_selected_cached(cfg: LlamaConfig, k_pages, v_pages, page_ids,
     return o.reshape(1, 1, G * rep, -1).astype(cd)
 
 
+# which FORM a decode call's attention took, counted where a program is
+# traced: "grouped" (_attend_grouped: a KV head's query heads against its keys
+# as ONE product, the keys read once in the type the store keeps them in) or
+# "repeated" (_attend_cached: every key and value repeated for each query head
+# of its group and widened to float32). A kind's row of SERVED says which;
+# decode_attend_forms() keeps the shapes and the reason beside the count
+_g_engine_decode_attend = Gauge(
+    "ray_tpu_serve_engine_decode_attend",
+    "Decode attentions traced into the decode engine's programs, by form: "
+    "grouped (a KV head's query heads in one product, no key repeated) or "
+    "repeated (keys and values repeated per query head)",
+    tag_keys=("form",))
+
+# (form, q shape, view shape) -> {.., "calls"}
+_decode_attend_taken: Dict[tuple, dict] = {}
+
+
+def decode_attend_forms() -> list:
+    """Every distinct decode attention (form, shapes) traced in this
+    process, with its reason and how often: how a run proves that its
+    decode programs repeat no key (beside :func:`prefill_attend_paths`)."""
+    with _paths_lock:
+        return [dict(rec) for rec in _decode_attend_taken.values()]
+
+
+def _note_decode_attend(form, q, view, reason) -> None:
+    key = (form, q.shape, view.shape)
+    with _paths_lock:
+        rec = _decode_attend_taken.setdefault(key, {
+            "form": form, "q_shape": list(q.shape),
+            "view_shape": list(view.shape), "view_dtype": view.dtype.name,
+            "reason": reason, "calls": 0})
+        rec["calls"] += 1
+        counts = {way: sum(r["calls"] for r in _decode_attend_taken.values()
+                           if r["form"] == way)
+                  for way in ("grouped", "repeated")}
+    for way, n in counts.items():
+        _g_engine_decode_attend.set(float(n), tags={"form": way})
+
+
 def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv,
                    lowest=None):
     """One new token (``q``, ``kk``, ``vv``: the block's ``attend``
@@ -2758,6 +3509,43 @@ def _attend_cached(cfg: LlamaConfig, k_cache, v_cache, length, q, kk, vv,
     probs = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs,
                       V.astype(jnp.float32)).astype(cd)
+
+
+def _attend_grouped(cfg: LlamaConfig, tag: str, k_cache, v_cache, length, q,
+                    kk, vv, lowest=None):
+    """:func:`_attend_cached`'s attention, masks and arguments, WITHOUT its
+    copies: the ``n_heads / n_kv_heads`` query heads of a KV head score
+    against that head's keys as ONE product (``[n_kv, rep, D] x [Tpad, n_kv,
+    D]``), the views are read once in the type the store keeps them in
+    (float32 that holds the compute type's values: a program of this engine
+    wrote them), nothing is repeated per query head and nothing is
+    concatenated: the token's own key and value (``kk`` / ``vv``, not in the
+    store yet) enter the softmax as one more term. Query head ``h`` reads KV
+    head ``h // rep``. At 16 query heads a KV head and 16,385 positions the
+    repeated form writes and reads two ``[16385, 128, 128]`` float32 arrays
+    (2.1 GB) a layer a token. Float32 scores."""
+    f32 = jnp.float32
+    G, D = cfg.n_kv_heads, cfg.head_dim
+    Tpad = k_cache.shape[0]
+    _note_decode_attend(
+        "grouped", q, k_cache,
+        f"{tag}: {cfg.n_heads // G} query heads a KV head in one product, "
+        f"the views read as kept ({k_cache.dtype.name})")
+    qg, scale = q[0, 0].reshape(G, -1, D).astype(f32), 1.0 / math.sqrt(D)
+    s = jnp.einsum("grd,tgd->grt", qg, k_cache.astype(f32)) * scale
+    own = jnp.einsum("grd,gd->gr", qg,
+                     kk[0, 0].astype(f32))[..., None] * scale
+    idx = jnp.arange(Tpad)
+    valid = idx < length
+    if lowest is not None:
+        valid &= idx >= lowest
+    s = jnp.where(valid, s, -1e30)
+    top = jnp.maximum(s.max(axis=-1, keepdims=True), own)
+    p, p_own = jnp.exp(s - top), jnp.exp(own - top)
+    o = jnp.einsum("grt,tgd->grd", p, v_cache.astype(f32)) \
+        + p_own * vv[0, 0].astype(f32)[:, None, :]
+    o = o / (p.sum(axis=-1, keepdims=True) + p_own)
+    return o.reshape(1, 1, -1, D).astype(cfg.dtype)
 
 
 def _attend_latent_cached(cfg: LlamaConfig, cache, length, q, latent, wkv_b):
@@ -2980,8 +3768,8 @@ SERVED: Dict[str, Served] = {
         "delta", "gated", gated_block, True, partial(_kv_rows, tag="gated"),
         lambda cfg, last, *a: attend_tiles(*a, cfg.dtype, kind="gated"),
         _attend_pages),
-    # the latent blocks outside the double layer (serve_latent_block, at this
-    # file's end), a stack a kind, the routed one first (LATENT_KINDS' order:
+    # the latent blocks outside the double layer (serve_latent_block), a
+    # stack a kind, the routed one first (LATENT_KINDS' order:
     # the engine reads its experts' groups off the first store's kind):
     # latent rows as "S" keeps them, ONE a layer, a store a kind, both under
     # the tag latent_block; prefill expands keys and values, decode never
@@ -2995,7 +3783,7 @@ SERVED: Dict[str, Served] = {
         lambda cfg, last, *a: attend_latent_expanded(cfg, *a),
         lambda cfg, call, l, mine, *a: _attend_latent_cached(
             cfg, mine[0], call.pos, *a)) for c in LATENT_KINDS},
-    # the parallel blocks (parallel_block, at this file's end), ONE stack in
+    # the parallel blocks (parallel_block), ONE stack in
     # layer order: keys and values as "F" / "W" keep them, the full layers'
     # by page id and the window layers' by SLOT, under tags of their own.
     # Decode scores a KV head's query heads against its keys as ONE product
@@ -3068,6 +3856,168 @@ def page_rows(cfg: LlamaConfig):
             [(s.layers, s.row) for s in stores])
 
 
+# --- which builder answers for which kind: ONE table, ONE refusal ---------- #
+#
+# Every kind is the block's or the pattern's, so every path COMPUTES it; a
+# builder that no reference holds to the result says what it lacks instead
+# of running. A row is one thing a builder may lack: whether ``cfg`` has it,
+# the words that name it, and per builder the reason it gives, a text or a
+# function of ``cfg`` that gives one (None: that stack it runs). A builder
+# that is not in a row's ``why`` RUNS what the row names. A new kind adds a
+# row (or its builders to a row) here and touches no builder. Rows are
+# walked in this order; those that can meet in one configuration (a stream
+# of several rows with the latent kinds, any row with the last) stand in the
+# order the builders named them.
+
+BUILDERS = ("make_spmd_train_step", "make_train_step",
+            "make_pipeline_train_step", "the MPMD pipeline",
+            "LlamaDecodeEngine")
+
+
+class Refused(NamedTuple):
+    present: Callable  # cfg -> the configuration has it
+    words: Callable    # cfg -> "no ... (the fields that say so)"
+    why: Dict[str, Any]
+
+
+def _has(kinds: str) -> Callable:
+    return lambda cfg: bool(set(cfg.kinds) & set(kinds))
+
+
+def _module_words(kinds: str) -> Callable:
+    return lambda cfg: (f"{kinds}no prediction module (layer_pattern="
+                        f"{cfg.layer_pattern!r}, mtp_layers={cfg.mtp_layers})")
+
+
+def _engine_lacks(cfg: LlamaConfig) -> Optional[str]:
+    """What the decode engine says of a stack it does not serve (a dense
+    stack's own refusals, or the pattern's); None where the pattern is ONE
+    family of :data:`SERVED`, or a part of one that is served alone."""
+    by_family: Dict[str, str] = {}
+    for c, kind in SERVED.items():
+        by_family[kind.family] = by_family.get(kind.family, "") + c
+    families = by_family.values()
+    kinds = set(served_kinds(cfg))
+    part = any(kinds <= set(family) and all(SERVED[c].alone for c in kinds)
+               for family in families)
+    if cfg.layer_pattern and (part or kinds in map(set, families)):
+        return None
+    return (f"it serves the kinds of ONE family of its table (SERVED: "
+            f"{' | '.join(families)}), all of them (both full "
+            f"and window layers), and this stack has "
+            f"{' '.join(sorted(kinds))}, of which the table lacks "
+            f"{' '.join(sorted(kinds - set(SERVED))) or 'none'} (the "
+            f"'M' mixer has no row: its state would go by the 'state' "
+            f"rule the 'D' layers use); for the 'E' / '*' "
+            f"halves, a part or a mix of families, whole-projection "
+            f"QK-norm or an unpatterned routed block no test compares "
+            f"its logits with the reference")
+
+
+REFUSED: Dict[str, Refused] = {
+    # a path whose attention cannot band
+    "window": Refused(
+        _has(BLOCK_KINDS),
+        lambda cfg: (f"no 'F' / 'W' layer (layer_pattern="
+                     f"{cfg.layer_pattern!r}, window={cfg.window})"),
+        {"make_spmd_train_step":
+         "its layers attend through the flash kernel, which masks the "
+         "causal triangle and has no window (forward and backward), and "
+         "under fsdp / tensor a patterned stack has no per-kind gather; "
+         "models.llama.loss_fn runs these kinds through attend_tiles",
+         "make_pipeline_train_step":
+         "its stages run the dense block over the flash kernel, which has "
+         "no window, and pass no router's losses on"}),
+    # a path no reference holds the kinds' gradients on
+    "delta": Refused(
+        _has(DELTA_KINDS),
+        lambda cfg: (f"no 'D' / 'A' layer (layer_pattern="
+                     f"{cfg.layer_pattern!r})"),
+        {"make_spmd_train_step":
+         "no train step is held to a reference for the gated delta rule's "
+         "backward (autodiff through ops/gdn.py's chunked form) or the gated "
+         "attention's; models.llama.loss_fn runs the forward of both"}),
+    "parallel": Refused(
+        _has(PARALLEL_KINDS),
+        lambda cfg: (f"no 'P' / 'R' layer (layer_pattern="
+                     f"{cfg.layer_pattern!r})"),
+        {"make_spmd_train_step":
+         "no train step is held to a reference for the parallel block's "
+         "backward, and its flash kernel has no window; "
+         "models.llama.loss_fn runs the forward through attend_tiles",
+         "make_train_step":
+         "no train step is held to a reference for the parallel block's "
+         "backward; models.llama.loss_fn runs its forward",
+         "make_pipeline_train_step":
+         "its stages run the dense block alone"}),
+    # a stream of several rows a token (``hc_mult > 1``), and a latent block
+    # whose score is not as wide as its value
+    "wide": Refused(
+        lambda cfg: cfg.hc_mult > 1 or (
+            _has(LATENT_KINDS)(cfg) and cfg.qk_nope_head_dim
+            + cfg.qk_rope_head_dim != cfg.v_head_dim),
+        lambda cfg: (
+            f"no hyper-connections (hc_mult={cfg.hc_mult}) and "
+            f"no latent block whose score width (qk_nope_head_dim + "
+            f"qk_rope_head_dim = "
+            f"{cfg.qk_nope_head_dim + cfg.qk_rope_head_dim}) is not its "
+            f"v_head_dim={cfg.v_head_dim}"),
+        {"make_spmd_train_step":
+         "no train step is held to a reference for the mixes' backward or "
+         "keeps a stream of several rows' recomputation in its account, and "
+         "its flash kernel attends q, k and v of one width; "
+         "models.llama.loss_fn runs the forward",
+         "make_train_step":
+         "no train step is held to a reference for the mixes' backward, and "
+         "its flash kernel attends q, k and v of one width",
+         "make_pipeline_train_step":
+         "its stages pass ONE row a token from stage to stage, and no train "
+         "step is held to a reference for the mixes' backward"}),
+    # a path that runs neither the ``"L"`` / ``"G"`` kinds nor the
+    # prediction module
+    "latent": Refused(
+        lambda cfg: _has(LATENT_KINDS)(cfg) or bool(cfg.mtp_layers),
+        _module_words("no 'L' / 'G' layer and "),
+        {"make_pipeline_train_step":
+         "its stages run the dense block alone, and the prediction module's "
+         "second loss needs the last stage's stream AND the first stage's "
+         "embedding"}),
+    # a path that runs the blocks and refuses the module alone
+    "module": Refused(
+        lambda cfg: bool(cfg.mtp_layers), _module_words(""),
+        {"LlamaDecodeEngine":
+         "a prediction module's self-drafted decode steps need a "
+         "scheduler that takes more than one token a call"}),
+    # routed experts, QK-norm and every pattern, for a dense-only path
+    "dense": Refused(
+        lambda cfg: bool(cfg.num_experts or cfg.qk_norm or cfg.layer_pattern),
+        lambda cfg: (f"no config with num_experts={cfg.num_experts}, "
+                     f"qk_norm={cfg.qk_norm}, layer_pattern="
+                     f"{cfg.layer_pattern!r}"),
+        {"make_pipeline_train_step":
+         "its stages pass the residual stream alone, so a router's losses "
+         "have no way out, and its layer specs name the dense leaves only",
+         "the MPMD pipeline":
+         "its stages pass the residual stream alone, so a router's "
+         "losses have no way out, and no test runs QK-norm through it",
+         "LlamaDecodeEngine": _engine_lacks}),
+}
+
+
+def held_to(cfg: LlamaConfig, who: str) -> None:
+    """Raise for the first row of :data:`REFUSED` that ``cfg`` has and the
+    builder ``who`` (one of :data:`BUILDERS`) gives a reason against."""
+    assert who in BUILDERS, who
+    for row in REFUSED.values():
+        why = row.why.get(who)
+        if why is None or not row.present(cfg):
+            continue
+        why = why(cfg) if callable(why) else why
+        if why:
+            raise NotImplementedError(
+                f"{who} takes {row.words(cfg)} yet: {why}")
+
+
 def _period(kinds: str) -> Tuple[str, int]:
     """``(unit, times)``: the shortest ``unit`` whose repetition ``kinds``
     is a prefix of, and how many whole units ``kinds`` holds."""
@@ -3135,7 +4085,7 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
         else:  # the positions' axis stands behind [sublayers,] B
             rows = tuple(a[:, :, -n:] if sub > 1 else a[:, -n:]
                          for a, sub, n in zip(rows, subs[c], keep[c]))
-        # the block's statistics that OVER_LAYERS (this file's end) lists
+        # the block's statistics that OVER_LAYERS lists
         return x, (rows, {k: stats[k] for k in OVER_LAYERS if k in stats})
 
     def run(x, some, first, start, cached):
@@ -3473,30 +4423,8 @@ class LlamaDecodeEngine:
         _t_build = _fr.now()
         backend_devices()  # a replica's first touch: jax.backend_init
         self.cfg = cfg or LlamaConfig.debug()
-        _no_latent_kinds(
-            self.cfg, "LlamaDecodeEngine",
-            "a prediction module's self-drafted decode steps need a "
-            "scheduler that takes more than one token a call", blocks=False)
-        families: Dict[str, str] = {}  # the table's kinds, by family
-        for c, kind in SERVED.items():
-            families[kind.family] = families.get(kind.family, "") + c
+        held_to(self.cfg, "LlamaDecodeEngine")
         kinds = set(served_kinds(self.cfg))
-        part = any(kinds <= set(family) and all(SERVED[c].alone for c in kinds)
-                   for family in families.values())
-        if not (self.cfg.layer_pattern
-                and (part or kinds in map(set, families.values()))):
-            _dense_only(  # a dense stack's own refusals, or the pattern's
-                self.cfg, "LlamaDecodeEngine",
-                f"it serves the kinds of ONE family of its table (SERVED: "
-                f"{' | '.join(families.values())}), all of them (both full "
-                f"and window layers), and this stack has "
-                f"{' '.join(sorted(kinds))}, of which the table lacks "
-                f"{' '.join(sorted(kinds - set(SERVED))) or 'none'} (the "
-                f"'M' mixer has no row: its state would go by the 'state' "
-                f"rule the 'D' layers use); for the 'E' / '*' "
-                f"halves, a part or a mix of families, whole-projection "
-                f"QK-norm or an unpatterned routed block no test compares "
-                f"its logits with the reference")
         _t = _fr.now()
         if params is None:
             # one jitted program, not a dozen eager ones: at 664.6M
@@ -3704,8 +4632,7 @@ class LlamaDecodeEngine:
         _note_stream(shares)
         if shares or self.cfg.num_experts:  # every expert here: held is 1.0
             # where the assignments fell and, of a held range, the places a
-            # row was made for (at this file's end; this block keeps its six
-            # lines: one more would move the frames the kernels below record)
+            # row was made for
             _note_assignments(shares, tpad * self.cfg.experts_per_token,
                               self.cfg)
         return last
@@ -3799,14 +4726,7 @@ def make_train_step(cfg: LlamaConfig, mesh, optimizer=None, rules=None):
     # same params on every mesh layout because jax.random is
     # sharding-invariant (test_parallelism_consistency)
     rules = rules or DEFAULT_RULES
-    _no_parallel_kinds(
-        cfg, "make_train_step",
-        "no train step is held to a reference for the parallel block's "
-        "backward; models.llama.loss_fn runs its forward")
-    _no_wide_latent(
-        cfg, "make_train_step",
-        "no train step is held to a reference for the mixes' backward, and "
-        "its flash kernel attends q, k and v of one width")
+    held_to(cfg, "make_train_step")
     optimizer = optimizer or optax.adamw(3e-4, b1=0.9, b2=0.95,
                                          weight_decay=0.1)
     axes = param_logical_axes(cfg)
@@ -3965,26 +4885,7 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
 
     if "pipe" not in mesh.axis_names:
         raise ValueError("mesh has no 'pipe' axis")
-    _no_wide_latent(
-        cfg, "make_pipeline_train_step",
-        "its stages pass ONE row a token from stage to stage, and no train "
-        "step is held to a reference for the mixes' backward")
-    _no_latent_kinds(
-        cfg, "make_pipeline_train_step",
-        "its stages run the dense block alone, and the prediction module's "
-        "second loss needs the last stage's stream AND the first stage's "
-        "embedding")
-    _no_parallel_kinds(
-        cfg, "make_pipeline_train_step",
-        "its stages run the dense block alone")
-    _no_window_kinds(
-        cfg, "make_pipeline_train_step",
-        "its stages run the dense block over the flash kernel, which has "
-        "no window, and pass no router's losses on")
-    _dense_only(
-        cfg, "make_pipeline_train_step",
-        "its stages pass the residual stream alone, so a router's losses "
-        "have no way out, and its layer specs name the dense leaves only")
+    held_to(cfg, "make_pipeline_train_step")
     n_stages = mesh.shape["pipe"]
     if cfg.n_layers % n_stages:
         raise ValueError(f"{cfg.n_layers} layers not divisible by "
@@ -4105,881 +5006,3 @@ def make_pipeline_train_step(cfg: LlamaConfig, mesh, num_microbatches: int,
         ),
         "llama.pipe_train_step")
     return init_jit, train_step, data_sharding, state_shardings
-
-
-# --- the delta rule's prefill: its rule and its two paths (attend_delta) --- #
-# Here, behind every frame a compiled program records, and not beside
-# attend_delta: a Mosaic kernel's bytecode holds the call stack it was traced
-# under, so lines put in above move every other program out of the compile
-# cache (ROADMAP Speed, setup_s).
-
-
-def delta_prefill_path(cfg: LlamaConfig, qkv, g, beta) -> Tuple[str, str]:
-    """``(path, reason)`` :func:`attend_delta` takes for these operands in
-    this process: ``"kernel"`` on a TPU backend for what
-    ``ops/gdn_prefill.py`` takes (``qkv`` in ``cfg.dtype``, ``g`` and
-    ``beta`` float32, key and value widths whole lanes, a key head's value
-    heads side by side in whole column blocks, positions a multiple of one
-    of its row tiles), ``"chunks"`` with what stands in the way otherwise.
-    Read from the backend and the shapes alone."""
-    platform = jax.default_backend()
-    if platform != "tpu":
-        return "chunks", f"backend is {platform!r}, not tpu"
-    from ray_tpu.ops.gdn_prefill import pick_rows
-
-    hk, dk = cfg.lin_key_heads, cfg.lin_key_dim
-    hv, dv = cfg.lin_value_heads, cfg.lin_value_dim
-    types = [a.dtype.name for a in (qkv, g, beta)]
-    if types != [jnp.dtype(cfg.dtype).name, "float32", "float32"]:
-        return "chunks", (f"[q | k | v], g, beta in {types}: not "
-                          f"{jnp.dtype(cfg.dtype).name} and float32 twice")
-    if dk % 128 or dv % 128:
-        return "chunks", (f"key width {dk} or value width {dv} is no "
-                          "multiple of 128 lanes")
-    if hv % hk or hv // hk > 2 or (2 * hk * dk) % (hv // hk * dv):
-        return "chunks", (f"{hv} value heads of {dv} on {hk} key heads of "
-                          f"{dk}: a key head's value heads (at most 2, a "
-                          "chunk of each side by side in 128 lanes) are no "
-                          "whole column block")
-    if pick_rows(qkv.shape[1]) is None:
-        return "chunks", (f"{qkv.shape[1]} positions are no multiple of a "
-                          "row tile of the kernel's")
-    return "kernel", "tpu backend"
-
-
-def _delta_chunks(cfg: LlamaConfig, last, qkv, g, beta, conv_w):
-    """:func:`attend_delta` in XLA: the causal convolution (``ops/ssm.py
-    causal_conv``, no bias), then the recurrence in chunks (``ops/gdn.py
-    gated_delta_chunked``), ``DELTA_SEGMENT`` positions at a time where that
-    divides them and in one piece where not: a ``lax.scan`` that carries
-    what a decode call would find, the state and the convolution's tail.
-    Returns ``(o [B, T, value heads, value dim] float32, state, tail)``."""
-    from ray_tpu.ops.gdn import gated_delta_chunked
-    from ray_tpu.ops.ssm import causal_conv
-
-    f32 = jnp.float32
-    (B, T, _), K = qkv.shape, conv_w.shape[0]
-    seg = DELTA_SEGMENT if T % DELTA_SEGMENT == 0 else T
-
-    def segment(carry, xs):
-        state, before = carry
-        qkv_s, g_s, beta_s, start = xs
-        with jax.named_scope("gdn.conv"):
-            rows = jnp.concatenate([before, qkv_s], axis=1).astype(f32)
-            mixed = jax.nn.silu(causal_conv(rows, conv_w, 0.0)[:, K - 1:])
-            q, k, v = _delta_heads(cfg, mixed)
-        with jax.named_scope("gdn.scan"):
-            o, state = gated_delta_chunked(
-                q, k, v, g_s, beta_s, cfg.lin_chunk, state,
-                None if last is None else last - start)
-        return (state, qkv_s[:, seg - (K - 1):]), o
-
-    def segments(a):  # [B, T, ...] -> [T / seg, B, seg, ...]
-        return jnp.moveaxis(a.reshape(B, -1, seg, *a.shape[2:]), 1, 0)
-
-    hv = cfg.lin_value_heads
-    start = (jnp.zeros((B, hv, cfg.lin_key_dim, cfg.lin_value_dim), f32),
-             jnp.zeros((B, K - 1, qkv.shape[-1]), qkv.dtype))
-    (state, _), o = jax.lax.scan(
-        segment, start, (segments(qkv), segments(g), segments(beta),
-                         jnp.arange(0, T, seg, dtype=jnp.int32)))
-    tail = _delta_tail(last, qkv, K)
-    return jnp.moveaxis(o, 0, 1).reshape(B, T, hv, -1), state, tail
-
-
-def _delta_tail(last, qkv, K: int):
-    """The convolution's tail at ``last`` (None: the last position): the
-    ``K - 1`` rows of ``qkv`` [B, T, width] that end there, zeros before the
-    sequence's first."""
-    T = qkv.shape[1]
-    with jax.named_scope("gdn.conv"):
-        at = (T - 1 if last is None else last) - (K - 2) + jnp.arange(K - 1)
-        return jnp.where((at >= 0)[None, :, None],
-                         qkv[:, jnp.maximum(at, 0)], 0)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _delta_kernel(qkv, g, beta, conv_w, cfg):
-    """``g`` and ``beta`` with the positions behind the last real one
-    already 0: from an empty state and tail, as :func:`_delta_chunks`."""
-    from ray_tpu.ops.gdn_prefill import gdn_prefill
-
-    B, K = qkv.shape[0], conv_w.shape[0]
-    # interpreted where a test has steered a CPU process onto this path
-    return gdn_prefill(
-        qkv, conv_w, g, beta,
-        jnp.zeros((B, cfg.lin_value_heads, cfg.lin_key_dim,
-                   cfg.lin_value_dim), jnp.float32),
-        jnp.zeros((B, K - 1, qkv.shape[-1]), qkv.dtype),
-        key_heads=cfg.lin_key_heads, key_dim=cfg.lin_key_dim,
-        interpret=jax.default_backend() != "tpu")
-
-
-def _delta_kernel_fwd(qkv, g, beta, conv_w, cfg):
-    return _delta_kernel(qkv, g, beta, conv_w, cfg), (qkv, g, beta, conv_w)
-
-
-def _delta_kernel_bwd(cfg, operands, cotangent):
-    # forward only: the kernel keeps nothing for a backward pass, so its
-    # transpose is the XLA path's, run again from the operands
-    return jax.vjp(lambda *a: _delta_chunks(cfg, None, *a)[:2],
-                   *operands)[1](cotangent)
-
-
-_delta_kernel.defvjp(_delta_kernel_fwd, _delta_kernel_bwd)
-
-
-# what the held path made of the last prefill's assignments: the places with
-# a held expert (``live``: held_share of tokens x top_k, a routed layer's
-# mean) and the places a row was gathered, multiplied and summed for
-# (``made``: ops/moe.py held_places_made, whole chunks up to ``live``). Set by
-# an engine whose layers hold a range of the router's experts, by no other
-_g_moe_places = Gauge(
-    "ray_tpu_serve_moe_places",
-    "Sorted places of the last prefill's routed layers (a layer's mean) "
-    "that fell on experts held here, and the places a row was made for",
-    tag_keys=("state",))
-
-
-def _note_assignments(shares, places: int, cfg) -> None:
-    """Set ``ray_tpu_serve_moe_assignment_share{part}`` from a prefill's
-    ``shares`` (a routed layer's mean of ``routed_mlp``'s ``held_share`` /
-    ``zero_share``; neither: every expert is here) and, where a range is
-    held, ``ray_tpu_serve_moe_places{state}`` of its ``places`` (tokens x
-    ``top_k``, pads and all: the program routes them too)."""
-    from ray_tpu.ops.moe import held_places_made
-
-    held = float(shares.get("held_share", 1.0))
-    zero = float(shares.get("zero_share", 0.0))
-    for part, share in (("held", held), ("zero", zero),
-                        ("elsewhere", 1.0 - held - zero)):
-        _g_moe_assignment_share.set(share, tags={"part": part})
-    if "held_share" in shares:
-        live = round(held * places)
-        wide = (cfg.router_experts or cfg.num_experts) + cfg.zero_experts
-        made = held_places_made(places, live, cfg.num_experts, wide)
-        for state, n in (("live", live), ("made", made)):
-            _g_moe_places.set(float(n), tags={"state": state})
-
-
-# --------------------------------------------------------------------------- #
-# Latent blocks outside "S" (kinds "L" and "G") and the prediction module:
-# TRAINED kinds. At this file's end for attend_delta's reason
-# --------------------------------------------------------------------------- #
-
-
-def _no_latent_kinds(cfg: LlamaConfig, who: str, why: str,
-                     blocks: bool = True) -> None:
-    """A path that does not run the ``"L"`` / ``"G"`` kinds and the
-    prediction module refuses them by name, as :func:`_no_delta_kinds`
-    refuses its kinds. ``blocks`` False: a path that runs the blocks (the
-    decode engine) and refuses the module alone."""
-    if (blocks and set(cfg.kinds) & set(LATENT_KINDS)) or cfg.mtp_layers:
-        kinds = "no 'L' / 'G' layer and " if blocks else ""
-        raise NotImplementedError(
-            f"{who} takes {kinds}no prediction module (layer_pattern="
-            f"{cfg.layer_pattern!r}, mtp_layers={cfg.mtp_layers}) yet: {why}")
-
-
-def _init_latent_kind(cfg: LlamaConfig, kind: str, L: int, key):
-    """The ``L`` stacked layers of kind ``"L"`` or ``"G"``, keys of their
-    own a kind. Every matrix over the square root of its fan-in (``wq_b`` /
-    ``wkv_b`` over ``sqrt(dim)`` where the config multiplies their inputs by
-    ``sqrt(dim / rank)``, as the ``"S"`` layer's): q, k and v then have unit
-    variance and a score is one unit wide. The choice bias starts at zero.
-    ``cfg.seeded_scales`` (a configuration's data) multiplies ``wo`` and the
-    routed experts' ``w_down``."""
-    d, f, H = cfg.dim, cfg.mlp_dim, cfg.n_heads
-    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
-    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    kv = cfg.qk_nope_head_dim + cfg.v_head_dim
-    k = iter(jax.random.split(
-        jax.random.fold_in(key, 6 + LATENT_KINDS.index(kind)), 16))
-    dense = _dense_init
-    ones = lambda width: jnp.ones((L, width), jnp.float32)  # noqa: E731
-    out = {
-        "attn_norm": ones(d), "mlp_norm": ones(d),
-        "wq_a": dense(next(k), (L, d, rq), d), "q_norm": ones(rq),
-        "wq_b": dense(next(k), (L, rq, H * qk),
-                      d if cfg.mla_scale_q_lora else rq),
-        "wkv_a": dense(next(k), (L, d, cfg.latent_row), d),
-        "kv_norm": ones(rkv),
-        "wkv_b": dense(next(k), (L, rkv, H * kv),
-                       d if cfg.mla_scale_kv_lora else rkv),
-        "wo": dense(next(k), (L, H * cfg.v_head_dim, d), H * cfg.v_head_dim),
-    }
-    scales = dict(cfg.seeded_scales)  # the configuration's data, or none
-    if "wo" in scales:
-        out["wo"] = scales["wo"] * out["wo"]
-    if cfg.hc_mult > 1:  # a key of their own: the leaves above stay theirs
-        out.update(_init_hyper(cfg, L, jax.random.fold_in(
-            key, 9 + LATENT_KINDS.index(kind))))
-    if kind == "G":
-        fd = cfg.dense_mlp_dim
-        out.update(w_gate=dense(next(k), (L, d, fd), d),
-                   w_up=dense(next(k), (L, d, fd), d),
-                   w_down=dense(next(k), (L, fd, d), fd))
-        return out
-    held, wide = cfg.num_experts, cfg.router_experts or cfg.num_experts
-    out.update(router=dense(next(k), (L, d, wide), d),
-               router_bias=jnp.zeros((L, wide), jnp.float32),
-               w_gate=dense(next(k), (L, held, d, f), d),
-               w_up=dense(next(k), (L, held, d, f), d),
-               w_down=dense(next(k), (L, held, f, d), f))
-    if "expert_down" in scales:
-        out["w_down"] = scales["expert_down"] * out["w_down"]
-    if cfg.shared_mlp_dim:
-        fs = cfg.shared_mlp_dim
-        out.update(shared_gate=dense(next(k), (L, d, fs), d),
-                   shared_up=dense(next(k), (L, d, fs), d),
-                   shared_down=dense(next(k), (L, fs, d), fs))
-    return out
-
-
-def _init_mtp(cfg: LlamaConfig, key):
-    """The prediction module's OWN leaves: the two norms in front of
-    ``eh_proj`` ([2 dim, dim]: the embedding's half first), its ``"L"``
-    blocks and its final norm. Embedding and head are the model's."""
-    d = cfg.dim
-    k_eh, k_block = jax.random.split(jax.random.fold_in(key, 8))
-    return {"enorm": jnp.ones((d,), jnp.float32),
-            "hnorm": jnp.ones((d,), jnp.float32),
-            "eh_proj": _dense_init(k_eh, (2 * d, d), 2 * d),
-            "layers": {"latent": _init_latent_kind(
-                cfg, "L", cfg.mtp_layers, k_block)},
-            "final_norm": jnp.ones((d,), jnp.float32)}
-
-
-def attend_latent_heads(cfg: LlamaConfig, attend, q, latent, wkv_b):
-    """``_latent_half``'s ``attend`` for a TRAIN step: every position's
-    per-head ``[k_nope | v]`` is made, ``c wkv_b``, the ONE rotated key
-    slice is repeated for every head, and ``attend(q, k, v)`` (the caller's:
-    the flash kernel, forward AND backward) sees plain attention of
-    ``n_heads`` on ``n_heads`` at the score's width, which is the value's.
-    Not :func:`attend_latent_expanded`: prefill's kernel takes the shared
-    slice as an operand of its own, but it is forward only, and its
-    transpose is the XLA tile loop. The repeat costs ``qk_rope_head_dim`` of
-    ``head width`` more key bytes, and buys the kernels' backward."""
-    B, T, H, _ = q.shape
-    r, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    kv = (latent[..., :r] @ wkv_b).reshape(B, T, H, -1)
-    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-        latent[:, :, None, r:], (B, T, H, cfg.qk_rope_head_dim))], axis=-1)
-    q, k, v = (checkpoint_name(a, "attn") for a in (q, k, kv[..., dn:]))
-    return attend(q, k, v)
-
-
-def latent_block(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
-    """THE latent block (kinds ``"L"`` and ``"G"``), for the train steps and
-    the full forward (the serving programs': :func:`serve_latent_block`, over
-    the same :func:`_latent_sublayers`)::
-
-        a   = x + MLA(N(x))          (:func:`_latent_half`, no sqrt(dim / rank)
-                                      factor unless the config sets it)
-        out = a + MLP(N(a))          "G": the dense SwiGLU (dense_mlp_dim,
-                                     scope ``ffn.dense``); "L": the routed MLP
-                                     (:func:`_mlp_half`: sigmoid or softmax
-                                     scores, the choice bias, a held range)
-                                     with its ungated shared SwiGLU expert
-
-    each ``+`` a hyper-connection where ``cfg.hc_mult > 1``
-    (:func:`hyper_connected`; ``x`` is then ``[B, T, hc_mult * dim]``).
-    ``attend(q, k, v)`` as :func:`decoder_block` takes it: the block makes
-    per-head keys and values itself (:func:`attend_latent_heads`), so the
-    trainer's flash kernel runs forward, dQ and dK/dV on them; where the
-    score's width is not the value's (no train step takes that) the full
-    forward attends as prefill does (:func:`attend_latent_expanded`). ``p``:
-    this layer's weights. Returns ``(x, stats)``."""
-    one_width = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-                 == cfg.v_head_dim)
-    x, stats, _ = _latent_sublayers(
-        cfg, kind, partial(attend_latent_heads, cfg, attend) if one_width
-        else partial(attend_latent_expanded, cfg), x, p,
-        positions_of(*x.shape[:2]), stat_axes, None)
-    # a dense layer's stats stay {}: pattern_stack stacks the routed ones'
-    return x, {k: v for k, v in stats.items() if k != "hc_sinkhorn_error"}
-
-
-def add_mtp_loss(cfg: LlamaConfig, mtp, x, tokens, nll, stats, *, embed,
-                 attend, chunk_nll, stat_axes=(), policy=None):
-    """The multi-token-prediction module's loss beside the main one, for
-    :func:`loss_parts` and ``make_spmd_train_step`` alike. ``x`` [B, T, dim]:
-    the main model's FINAL-NORMED stream over ``tokens[:, :-1]`` (``tokens``
-    [B, T + 1] = t_0 .. t_T); ``nll`` / ``stats``: the main cross-entropy and
-    the stack's router stats. The caller lends what the module SHARES with
-    the model: ``embed(ids)`` (the embedding table), ``chunk_nll`` (the
-    head), and its ``attend``::
-
-        h'_i = [N_e(Emb(t_{i+1})) ; N_h(x_i)] W_eh      scope ``mtp.merge``
-        y    = the module's "L" block(s) over all T positions  ``mtp.block``
-        L_mtp = mean_{i < T - 1} -log p(t_{i+2} | N(y_i) head)  ``mtp.head``
-
-    Position ``T - 1`` has no target and is left out of the mean
-    (``chunked_nll_mean``'s ``live``). Returns ``(nll + mtp_loss_weight *
-    L_mtp, stats with the module's block's behind the stack's, {"main_loss":
-    nll, "mtp_loss": L_mtp})``; without a module what it was given and
-    ``{}``."""
-    if not cfg.mtp_layers:
-        return nll, stats, {}
-    cd, eps = cfg.dtype, cfg.norm_eps
-    B, T, _ = x.shape
-    # t_{i+2}; the last position's is any id: it does not count
-    targets = jnp.concatenate(
-        [tokens[:, 2:], jnp.zeros((B, 1), tokens.dtype)], axis=1)
-
-    def merge(x, ids, enorm, hnorm, eh_proj):
-        both = jnp.concatenate(
-            [rms_norm(embed(ids), enorm, eps).astype(cd),
-             rms_norm(x, hnorm, eps).astype(cd)], axis=-1)
-        return (both @ eh_proj.astype(cd)).astype(x.dtype)
-
-    with jax.named_scope("mtp.merge"):
-        y = (jax.checkpoint(merge) if cfg.remat else merge)(
-            x, tokens[:, 1:], mtp["enorm"], mtp["hnorm"], mtp["eh_proj"])
-    with jax.named_scope("mtp.block"):
-        y, block_stats = pattern_stack(
-            cfg, y, mtp["layers"], attend, stat_axes, policy,
-            kinds="L" * cfg.mtp_layers)
-    with jax.named_scope("mtp.head"):
-        mtp_nll = chunked_nll_mean(
-            cfg, rms_norm(y, mtp["final_norm"], eps), targets, chunk_nll,
-            policy, live=T - 1)
-    stats = jax.tree.map(lambda a, b: jnp.concatenate([a, b]), stats,
-                         block_stats)
-    return (nll + cfg.mtp_loss_weight * mtp_nll, stats,
-            {"main_loss": nll, "mtp_loss": mtp_nll})
-
-
-# --------------------------------------------------------------------------- #
-# Which way the held experts' sums went (ops/moe.py held_sum_path). At this
-# file's end for attend_delta's reason
-# --------------------------------------------------------------------------- #
-
-# counted where a program is traced (ops/moe.py _held_chunks / _held_blocks
-# tell an engine's process through watch_held_sums): in an engine's process on
-# the chip a prefill program over more than one chunk of places reads
-# path=kernel (ops/row_sum.py) and a decode program (one straight block)
-# path=xla; held_sum_paths() keeps the reason beside the count
-_g_engine_held_sums = Gauge(
-    "ray_tpu_serve_engine_held_sums",
-    "Sums of the held experts' rows onto tokens traced in a decode engine's "
-    "process, by the path they took: the Pallas gather-sum or XLA's "
-    "scatter-add", tag_keys=("path",))
-
-# (rows, tokens, type, path, reason) -> {.., "calls"}
-_held_sums_taken: Dict[tuple, dict] = {}
-
-
-def held_sum_paths() -> list:
-    """Every distinct sum of the held path on stacked leaves (rows, tokens,
-    type, path) traced in this process since its first routed engine was
-    built, with its reason (``ops.moe.held_sum_path``'s) and how often."""
-    with _paths_lock:
-        return [dict(rec) for rec in _held_sums_taken.values()]
-
-
-def _note_held_sums(ys, n_tokens, path, reason) -> None:
-    dtype = jnp.dtype(ys.dtype).name
-    key = (ys.shape, n_tokens, dtype, path, reason)
-    with _paths_lock:
-        rec = _held_sums_taken.setdefault(key, {
-            "rows": list(ys.shape), "tokens": n_tokens, "dtype": dtype,
-            "path": path, "reason": reason, "calls": 0})
-        rec["calls"] += 1
-        counts = {way: sum(r["calls"] for r in _held_sums_taken.values()
-                           if r["path"] == way) for way in ("kernel", "xla")}
-    for way, n in counts.items():
-        _g_engine_held_sums.set(float(n), tags={"path": way})
-
-
-def _watch_routed_calls() -> None:
-    """What a routed engine registers with ``ops/moe.py`` where it is built:
-    the counts of its experts' products and of its held sums by path."""
-    from ray_tpu.ops.moe import watch_held_sums, watch_stacked_calls
-
-    watch_stacked_calls(_note_expert_products)
-    watch_held_sums(_note_held_sums)
-
-
-# --------------------------------------------------------------------------- #
-# The latent blocks SERVED, the stream as hc_mult rows a token
-# (manifold-constrained hyper-connections) and YaRN's rotation. At this
-# file's end for attend_delta's reason
-# --------------------------------------------------------------------------- #
-
-# bytes ONE position of the residual stream takes as the serving programs
-# carry it between sublayers: hc_mult rows of dim in the stream's type
-# (float32 for the kinds whose row of SERVED says so); set where an engine
-# is built
-_g_engine_stream_bytes = Gauge(
-    "ray_tpu_serve_engine_stream_bytes",
-    "Bytes one position of the residual stream takes as the decode engine's "
-    "programs carry it (hc_mult rows of dim in the stream's type)")
-# how far the last prefill's residual mixes were from doubly stochastic: the
-# largest |row sum - 1| or |column sum - 1| of H_res over its positions and
-# sublayers, read with the logits; an engine without hyper-connections sets
-# none
-_g_hc_sinkhorn_error = Gauge(
-    "ray_tpu_serve_hc_sinkhorn_error",
-    "Largest distance of a row or column sum of the last prefill's "
-    "hyper-connection residual mixes from 1")
-
-# Where the hyper-connections' own leaves start (seeded weights; a
-# checkpoint brings its own). ``phi`` is over its fan-in, so that ``m`` is
-# one unit wide; the three ``alpha`` start at ``alpha`` and not near zero
-# (the paper starts the dynamic part small, the mix nearly static): here the
-# DYNAMIC part has to carry enough of a logit that a comparison of logits
-# refuses a fault in it. ``b``: zero for the read-out and the write-back
-# (``H_pre`` around 1 / 2, ``H_post`` around 1), ``res_diag`` on the residual
-# mix's diagonal, so that a row keeps some 0.6 of itself and the rows stay
-# apart (at zero the doubly stochastic mix is near uniform and ten sublayers
-# make the rows one).
-HC_INIT = {"alpha": 1.0, "res_diag": 2.0}
-# The names ``LlamaConfig.seeded_scales`` may give, a latent block's leaves
-# that a CONFIGURATION starts off the square root of their fan-in
-# (_init_latent_kind): the attention's ``wo`` and the routed experts'
-# ``w_down`` (``expert_down``; the shared expert's and the dense layer's
-# stay). The values and their readings are the configuration file's
-# (``seeded_scales``, ``correct``), as BLOCK_INIT's reason has it: they set
-# the halves' shares of a logit. WHICH experts are chosen, their load and
-# every product's shape and time are the same at any of them.
-SEEDED_SCALES = ("wo", "expert_down")
-# a walker's statistic over its layers (_serve_layers): a share is their
-# mean, an error their largest
-OVER_LAYERS = {"held_share": jnp.mean, "zero_share": jnp.mean,
-               "hc_sinkhorn_error": jnp.max}
-
-
-def _init_hyper(cfg: LlamaConfig, L: int, key):
-    """A kind's ``L`` layers' hyper-connection leaves, ``[L, 2, ...]``: a
-    sublayer each, the attention's then the MLP's (:data:`HC_INIT`)."""
-    n, d = cfg.hc_mult, cfg.dim
-    b = jnp.concatenate([jnp.zeros((2 * n,), jnp.float32),
-                         HC_INIT["res_diag"] * jnp.eye(n).reshape(-1)])
-    return {"hc_phi": _dense_init(key, (L, 2, n * d, 2 * n + n * n), n * d),
-            "hc_b": jnp.broadcast_to(b, (L, 2, b.shape[0])),
-            "hc_alpha": jnp.full((L, 2, 3), HC_INIT["alpha"], jnp.float32)}
-
-
-def widen_stream(cfg: LlamaConfig, x):
-    """The embedding ``[B, T, dim]`` as the stream's first state: every one
-    of the ``hc_mult`` rows a token is a copy of it, side by side ``[B, T,
-    hc_mult * dim]`` (row ``j`` is ``[..., j * dim:(j + 1) * dim]``, whole
-    lanes; every program and the walker keep reading ``x.shape[:2]`` and
-    slicing positions as they did. The rows as a dimension of their own cost
-    the same on the chip, 12.6 against 12.3 ms a sublayer's passes at 16,384
-    positions: the compiler lays either out positions-minor;
-    ``sweep/xing4_check.md``). ``hc_mult`` 1: ``x`` as it is."""
-    return x if cfg.hc_mult == 1 else jnp.tile(x, (1, 1, cfg.hc_mult))
-
-
-def collapse_stream(cfg: LlamaConfig, x):
-    """The stream's end: its rows SUMMED (float32) to ``[B, T, dim]``, what
-    the final norm reads. ``hc_mult`` 1: ``x`` as it is."""
-    if cfg.hc_mult == 1:
-        return x
-    with jax.named_scope("hc.sum"):
-        rows = x.reshape(*x.shape[:2], cfg.hc_mult, -1)
-        return rows.astype(jnp.float32).sum(axis=2).astype(x.dtype)
-
-
-def hyper_mix(cfg: LlamaConfig, phi, b, alpha, x):
-    """One sublayer's three mixes from the stream ``x`` [B, T, n * dim], all
-    float32 whatever the stream's type, the positions LAST (lanes: twenty
-    normalisations of ``[n, n]`` minor would run on padded tiles)::
-
-        m      = (x~ phi) / sqrt(mean(x~ ** 2) + eps)        x~ = vec(X)
-        H_pre  = sigmoid(alpha_0 m[:n] + b[:n])              [n, B, T]
-        H_post = 2 sigmoid(alpha_1 m[n:2n] + b[n:2n])        [n, B, T]
-        H_res  = Sinkhorn(exp(clip(alpha_2 m[2n:] + b[2n:])))  [n, n, B, T]
-
-    ``phi`` [n * dim, 2n + n * n] carries the norm's gain; Sinkhorn:
-    ``hc_sinkhorn_iters`` times every row over its sum, then every column
-    over its sum, ``+ eps`` in each divisor. Returns ``(H_pre, H_post, H_res,
-    error)``: ``error`` the largest ``|row sum - 1|`` or ``|column sum - 1|``
-    of ``H_res``."""
-    f32, n, eps = jnp.float32, cfg.hc_mult, cfg.hc_eps
-    x32 = x.astype(f32)
-    m = jnp.einsum("btc,cm->mbt", x32, phi,
-                   precision=jax.lax.Precision.HIGHEST,
-                   preferred_element_type=f32)
-    m = m * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1) + eps)
-    b = b[:, None, None]
-    pre = jax.nn.sigmoid(alpha[0] * m[:n] + b[:n])
-    post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + b[n:2 * n])
-    res = jnp.exp(jnp.clip(alpha[2] * m[2 * n:] + b[2 * n:],
-                           cfg.hc_res_clamp_min, cfg.hc_res_clamp_max))
-    res = res.reshape(n, n, *m.shape[1:])  # [i, j]: onto row i from row j
-    for _ in range(cfg.hc_sinkhorn_iters):
-        res = res / (res.sum(axis=1, keepdims=True) + eps)
-        res = res / (res.sum(axis=0, keepdims=True) + eps)
-    error = jnp.maximum(jnp.abs(res.sum(axis=1) - 1.0).max(),
-                        jnp.abs(res.sum(axis=0) - 1.0).max())
-    return pre, post, res, error
-
-
-def hyper_connected(cfg: LlamaConfig, hc, x, sublayer):
-    """ONE sublayer on the stream, for every kind that could take it:
-    ``sublayer(h) -> (y, aux)`` reads ``h`` [B, T, dim] (its own norm is
-    its own) and returns what it adds. ``hc`` None (``hc_mult`` 1)::
-
-        x' = x + y                         the plain residual block
-
-    ``hc = (phi, b, alpha)``, this sublayer's, on ``x`` [B, T, n * dim]
-    (:func:`widen_stream`'s layout; the mixes: :func:`hyper_mix`, scope
-    ``hc.mix``)::
-
-        h     = sum_j H_pre[j] X[j]                          ``hc.read``
-        X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y         ``hc.write``
-
-    in float32, stored in the stream's type. Returns ``(x', aux, error)``,
-    ``error`` :func:`hyper_mix`'s (None without ``hc``)."""
-    if hc is None:
-        y, aux = sublayer(x)
-        return x + y.astype(x.dtype), aux, None
-    f32, n = jnp.float32, cfg.hc_mult
-    d = x.shape[-1] // n
-    with jax.named_scope("hc.mix"):
-        pre, post, res, error = hyper_mix(cfg, *hc, x)
-    rows = [x[..., j * d:(j + 1) * d].astype(f32) for j in range(n)]
-    with jax.named_scope("hc.read"):
-        h = sum(pre[j][..., None] * rows[j] for j in range(n))
-    y, aux = sublayer(h.astype(x.dtype))
-    with jax.named_scope("hc.write"):
-        y = y.astype(f32)
-        out = jnp.concatenate([
-            sum(res[i, j][..., None] * rows[j] for j in range(n))
-            + post[i][..., None] * y for i in range(n)], axis=-1)
-    return out.astype(x.dtype), aux, error
-
-
-def _latent_sublayers(cfg: LlamaConfig, kind: str, attend, x, p, positions,
-                      stat_axes, layer):
-    """The latent block's two sublayers (:func:`latent_block` has the
-    equations), each through :func:`hyper_connected`. ``attend(q, latent,
-    wkv_b)`` as :func:`_latent_half` takes it; ``p``: ONE layer's leaves,
-    its experts that layer's (``layer`` None) or the kind's whole stack with
-    ``layer`` its number (``routed_mlp``). Returns ``(x, stats, latent)``:
-    ``latent`` ``[B, T, latent_row]``, what a cache keeps; ``stats`` with
-    ``hc_sinkhorn_error`` where the stream is mixed."""
-    cd, eps = cfg.dtype, cfg.norm_eps
-    hc = [None, None] if cfg.hc_mult == 1 else [
-        (p["hc_phi"][j], p["hc_b"][j], p["hc_alpha"][j]) for j in (0, 1)]
-
-    def mla(h):
-        y, latent = _latent_half(
-            cfg, p, rms_norm(h, p["attn_norm"], eps).astype(cd), positions,
-            attend)
-        return checkpoint_name(y, "attn"), latent
-
-    def mlp(h):
-        h = rms_norm(h, p["mlp_norm"], eps).astype(cd)
-        if kind == "G":
-            with jax.named_scope("ffn.dense"):
-                return _dense_mlp(cfg, p, h), {}
-        return _mlp_half(cfg, p, h, stat_axes, layer=layer)
-
-    x, latent, first = hyper_connected(cfg, hc[0], x, mla)
-    x, stats, second = hyper_connected(cfg, hc[1], x, mlp)
-    if first is not None:
-        stats = {**stats, "hc_sinkhorn_error": jnp.maximum(first, second)}
-    return x, stats, latent
-
-
-def serve_latent_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
-                       attend, stat_axes=()):
-    """:data:`SERVED`'s block of the kinds ``"L"`` and ``"G"``:
-    :func:`latent_block`'s arithmetic on the kind's stacked weights
-    ``layers`` ``[n, ...]``, layer ``i``, over ``attend(q, latent, wkv_b)``
-    (prefill: :func:`attend_latent_expanded`; decode:
-    :func:`_attend_latent_cached`). Every leaf is cut out ``[i]`` but the
-    routed experts, which go down whole with ``layer=i``
-    (:func:`shortcut_layer` says why). Returns ``(x, stats, latent)``:
-    the layer's ``[c | k_r]`` rows ``[B, T, latent_row]``."""
-    whole = () if kind == "G" else ("w_gate", "w_up", "w_down")
-    p = {w: a if w in whole else a[i] for w, a in layers.items()}
-    return _latent_sublayers(cfg, kind, attend, x, p, positions, stat_axes,
-                             None if kind == "G" else i)
-
-
-def _note_stream(shares) -> None:
-    """Take what a prefill says of its stream out of ``shares`` (the
-    walker's ``hc_sinkhorn_error``) and set its gauge."""
-    error = shares.pop("hc_sinkhorn_error", None)
-    if error is not None:
-        _g_hc_sinkhorn_error.set(float(error))
-
-
-def yarn_frequencies(cfg: LlamaConfig):
-    """The latent half's rotation frequencies under ``cfg.rope_yarn``
-    (``[qk_rope_head_dim / 2]`` float32, host arithmetic): frequency ``i``
-    of ``f_i = theta ** (-2i / D)`` is kept where it turns more than
-    ``beta_fast`` times within the original context, divided by ``factor``
-    where fewer than ``beta_slow`` times, and blended linearly between::
-
-        low  = floor(D ln(original / (beta_fast 2 pi)) / (2 ln theta))
-        high = ceil(D ln(original / (beta_slow 2 pi)) / (2 ln theta))
-        ramp_i = clip((i - low) / (high - low), 0, 1)   both within [0, D-1]
-        f_i (1 - ramp_i) + f_i / factor ramp_i"""
-    import numpy as np
-
-    y, D = dict(cfg.rope_yarn), cfg.qk_rope_head_dim
-    f = cfg.rope_theta ** (-np.arange(0, D, 2, dtype=np.float64) / D)
-
-    def turns(beta):
-        return (D * math.log(y["original_max_position_embeddings"]
-                             / (beta * 2 * math.pi))
-                / (2 * math.log(cfg.rope_theta)))
-
-    low = min(max(math.floor(turns(y.get("beta_fast", 32))), 0), D - 1)
-    high = min(max(math.ceil(turns(y.get("beta_slow", 1))), 0), D - 1)
-    ramp = np.clip((np.arange(D // 2) - low) / max(high - low, 1e-3), 0, 1)
-    return (f * (1 - ramp) + f / y["factor"] * ramp).astype(np.float32)
-
-
-def yarn_softmax_factor(cfg: LlamaConfig) -> float:
-    """What multiplies the latent half's scores beside ``1 / sqrt(score
-    width)`` under ``cfg.rope_yarn``: ``(0.1 mscale_all_dim ln(factor) +
-    1) ** 2`` (1 without it)."""
-    y = dict(cfg.rope_yarn)
-    if not y or y["factor"] <= 1:
-        return 1.0
-    return (0.1 * y.get("mscale_all_dim", 1) * math.log(y["factor"])
-            + 1.0) ** 2
-
-
-def _no_wide_latent(cfg: LlamaConfig, who: str, why: str) -> None:
-    """A train step refuses a stream of several rows (``hc_mult > 1``) and a
-    latent block whose score is not as wide as its value by name, as
-    :func:`_no_delta_kinds` refuses its kinds."""
-    unequal = bool(set(cfg.kinds) & set(LATENT_KINDS)) and (
-        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim != cfg.v_head_dim)
-    if cfg.hc_mult > 1 or unequal:
-        raise NotImplementedError(
-            f"{who} takes no hyper-connections (hc_mult={cfg.hc_mult}) and "
-            f"no latent block whose score width (qk_nope_head_dim + "
-            f"qk_rope_head_dim = "
-            f"{cfg.qk_nope_head_dim + cfg.qk_rope_head_dim}) is not its "
-            f"v_head_dim={cfg.v_head_dim} yet: {why}")
-
-
-# --------------------------------------------------------------------------- #
-# The parallel blocks (kinds "P" and "R"): one norm feeds attention AND the
-# routed MLP, window and full layers in one stack, a decode attend that
-# repeats no key. At this file's end for attend_delta's reason
-# --------------------------------------------------------------------------- #
-
-# which FORM a decode call's attention took, counted where a program is
-# traced: "grouped" (_attend_grouped: a KV head's query heads against its keys
-# as ONE product, the keys read once in the type the store keeps them in) or
-# "repeated" (_attend_cached: every key and value repeated for each query head
-# of its group and widened to float32). A kind's row of SERVED says which;
-# decode_attend_forms() keeps the shapes and the reason beside the count
-_g_engine_decode_attend = Gauge(
-    "ray_tpu_serve_engine_decode_attend",
-    "Decode attentions traced into the decode engine's programs, by form: "
-    "grouped (a KV head's query heads in one product, no key repeated) or "
-    "repeated (keys and values repeated per query head)",
-    tag_keys=("form",))
-
-# (form, q shape, view shape) -> {.., "calls"}
-_decode_attend_taken: Dict[tuple, dict] = {}
-
-
-def decode_attend_forms() -> list:
-    """Every distinct decode attention (form, shapes) traced in this
-    process, with its reason and how often: how a run proves that its
-    decode programs repeat no key (beside :func:`prefill_attend_paths`)."""
-    with _paths_lock:
-        return [dict(rec) for rec in _decode_attend_taken.values()]
-
-
-def _note_decode_attend(form, q, view, reason) -> None:
-    key = (form, q.shape, view.shape)
-    with _paths_lock:
-        rec = _decode_attend_taken.setdefault(key, {
-            "form": form, "q_shape": list(q.shape),
-            "view_shape": list(view.shape), "view_dtype": view.dtype.name,
-            "reason": reason, "calls": 0})
-        rec["calls"] += 1
-        counts = {way: sum(r["calls"] for r in _decode_attend_taken.values()
-                           if r["form"] == way)
-                  for way in ("grouped", "repeated")}
-    for way, n in counts.items():
-        _g_engine_decode_attend.set(float(n), tags={"form": way})
-
-
-# Where a parallel block's SEEDED matrices start off the square root of their
-# fan-in (a checkpoint brings its own), for BLOCK_INIT's reason: so that a
-# comparison of logits sees a fault in either half and does not trip over a
-# sound engine's moved choice. At fan-in scaling a layer adds the four shared
-# experts' mean (0.30 an element), ONE held expert's eighth where a token
-# chose one (0.075; a sigmoid router's renormalised weights are 1/8 each
-# however wide it starts, so no router scale helps) and an attention over
-# thousands of keys whose scores are one unit wide, nearly an average (0.025).
-# The 8th and 9th of 128 router logits lie 0.06 apart, a stream that differs
-# by bfloat16's rounding moves that choice in one token-layer of twenty, and
-# where a held expert is the one moved that is 0.075 on a stream of 0.6: 12%
-# of a logit in one run of six, while a rotation or a window would show
-# nothing. ``wq`` twice as wide makes scores two units wide (some 75 of 4,096
-# keys carry a query's weight and which keys a layer sees matters); ``wo``
-# sets the attention's share so that a moved choice stays near 1% of a logit.
-# WHICH experts are chosen, their load and every product's shape and time are
-# the same at any of them. Readings: the configuration file's ``correct``.
-PARALLEL_INIT = {"wq": 2.0, "wo": 24.0}
-
-
-def _no_parallel_kinds(cfg: LlamaConfig, who: str, why: str) -> None:
-    """A train step refuses the ``"P"`` / ``"R"`` kinds by name, as
-    :func:`_no_delta_kinds` refuses its kinds."""
-    if set(cfg.kinds) & set(PARALLEL_KINDS):
-        raise NotImplementedError(
-            f"{who} takes no 'P' / 'R' layer (layer_pattern="
-            f"{cfg.layer_pattern!r}) yet: {why}")
-
-
-def _init_parallel(cfg: LlamaConfig, L: int, key):
-    """The ``L`` stacked parallel blocks, keys of their own. Every matrix
-    over the square root of its fan-in but :data:`PARALLEL_INIT`'s; the norm
-    at one. The ``shared_experts`` shared experts lie side by side: gate and
-    up ``[dim, n * shared_mlp_dim]`` (expert ``j``: columns ``j * width ..``),
-    down ``[n * shared_mlp_dim, dim]`` (its rows), each drawn over ONE
-    expert's fan-in."""
-    d, hd, f = cfg.dim, cfg.head_dim, cfg.mlp_dim
-    nq, nkv, held = cfg.n_heads, cfg.n_kv_heads, cfg.num_experts
-    fs, n = cfg.shared_mlp_dim, cfg.shared_experts
-    k = iter(jax.random.split(jax.random.fold_in(key, 11), 12))
-    dense = _dense_init
-    return {
-        "norm": jnp.ones((L, d), jnp.float32),
-        "wq": PARALLEL_INIT["wq"] * dense(next(k), (L, d, nq * hd), d),
-        "wk": dense(next(k), (L, d, nkv * hd), d),
-        "wv": dense(next(k), (L, d, nkv * hd), d),
-        "wo": PARALLEL_INIT["wo"] * dense(next(k), (L, nq * hd, d), nq * hd),
-        "router": dense(next(k), (L, d, cfg.router_experts or held), d),
-        "w_gate": dense(next(k), (L, held, d, f), d),
-        "w_up": dense(next(k), (L, held, d, f), d),
-        "w_down": dense(next(k), (L, held, f, d), f),
-        "shared_gate": dense(next(k), (L, d, n * fs), d),
-        "shared_up": dense(next(k), (L, d, n * fs), d),
-        "shared_down": dense(next(k), (L, n * fs, d), fs),
-    }
-
-
-def parallel_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
-                   attend, stat_axes=()):
-    """THE parallel block of the kinds ``"P"`` and ``"R"``, for the full
-    forward and for the serving programs (``LN``: :func:`layer_norm`, the
-    mean subtracted, no bias)::
-
-        a   = LN(x)                       ONE norm a layer
-        A   = Attention(a) Wo             "R": q, k rotated in pairs (2i,
-                                          2i + 1), over the last cfg.window
-                                          positions; "P": not rotated, over
-                                          every earlier one
-        R   = sum_e w_e SwiGLU_e(a)       w = s / sum of the chosen s, s =
-                                          sigmoid(a Wr) in float32; the held
-                                          experts' part of it
-        S   = 1/n sum_j SwiGLU_j(a)       the n shared experts, as ONE
-                                          expert n times as wide
-        out = x + A + R + S               attention and MLP read the SAME a
-
-    ``layers``: the ``parallel`` stack ``[L, ...]`` both kinds share, ``i``:
-    which layer (a number, or traced in a scan); every matrix is cut out
-    ``[i]`` where it is used but the routed experts, which go down whole with
-    ``layer=i`` (:func:`shortcut_layer` says why). The stream keeps the type
-    it comes in (float32 in the serving programs), the router reads ``a`` in
-    that type and every product its rounding to ``cfg.dtype``.
-    ``attend(q, k, v)`` as :func:`decoder_block` takes it. Device scopes
-    ``par.norm``, ``par.qkv``, ``attn.window`` / ``attn.full``, ``par.out``,
-    ``moe.route`` / ``.dispatch`` / ``.experts`` / ``.combine`` and
-    ``moe.shared``. Returns ``(x, stats, (k, v))``: ``k`` rotated for
-    ``"R"`` and as projected for ``"P"``."""
-    from ray_tpu.ops.moe import routed_mlp
-
-    cd, hd = cfg.dtype, cfg.head_dim
-    B, T, _ = x.shape
-    with jax.named_scope("par.norm"):
-        a = layer_norm(x, layers["norm"][i], cfg.norm_eps)
-        h = a.astype(cd)
-    with jax.named_scope("moe.shared"):
-        g = jax.nn.silu((h @ layers["shared_gate"][i].astype(cd)
-                         ).astype(jnp.float32))
-        u = h @ layers["shared_up"][i].astype(cd)
-        s = ((g * u).astype(cd) @ layers["shared_down"][i].astype(cd)
-             ).astype(jnp.float32) / cfg.shared_experts
-    # ONE half at a time: both read ``h`` and nothing else ties them, and a
-    # scheduler that runs them side by side holds the shared experts' gate
-    # and up ([T, n * width] each) beside q, the attention's output and their
-    # transposes: 5.0 GB of temporaries at 16,384 positions against 3.0 in
-    # turn (3.2 without the second barrier below; compiled for a described
-    # v5e, PR 54)
-    out, h = jax.lax.optimization_barrier((x + s.astype(x.dtype), h))
-    with jax.named_scope("par.qkv"):
-        q, k, v = _qkv(cfg, {w: layers[w][i] for w in ("wq", "wk", "wv")}, h,
-                       cfg.n_heads, cfg.n_kv_heads, positions,
-                       rope=kind == "R")
-    with jax.named_scope("attn.window" if kind == "R" else "attn.full"):
-        o = attend(q, k, v)
-    with jax.named_scope("par.out"):
-        out = out + (o.reshape(B, T, cfg.n_heads * hd)
-                     @ layers["wo"][i].astype(cd)).astype(x.dtype)
-    out, h = jax.lax.optimization_barrier((out, h))
-    wide = cfg.router_experts or cfg.num_experts
-    # the float32 sum goes onto the stream as it is
-    y, stats = routed_mlp(
-        h, layers["router"][i], layers["w_gate"], layers["w_up"],
-        layers["w_down"], top_k=cfg.experts_per_token,
-        norm_topk_prob=cfg.norm_topk_prob, stat_axes=stat_axes,
-        scoring=cfg.router_scoring,
-        held=((cfg.first_expert, cfg.num_experts)
-              if wide != cfg.num_experts else None),
-        layer=i, router_input=a)
-    return out + y.astype(x.dtype), stats, (k, v)
-
-
-def attend_parallel_tiles(cfg: LlamaConfig, kind: str, q, k, v):
-    """:func:`parallel_block`'s ``attend`` over the call's own positions (the
-    full forward and prefill): :func:`attend_tiles` with the band for
-    ``"R"``, counted under kinds of their own."""
-    return attend_tiles(q, k, v, cfg.dtype,
-                        window=cfg.window if kind == "R" else 0,
-                        kind="parallel_window" if kind == "R"
-                        else "parallel_full")
-
-
-def _attend_grouped(cfg: LlamaConfig, tag: str, k_cache, v_cache, length, q,
-                    kk, vv, lowest=None):
-    """:func:`_attend_cached`'s attention, masks and arguments, WITHOUT its
-    copies: the ``n_heads / n_kv_heads`` query heads of a KV head score
-    against that head's keys as ONE product (``[n_kv, rep, D] x [Tpad, n_kv,
-    D]``), the views are read once in the type the store keeps them in
-    (float32 that holds the compute type's values: a program of this engine
-    wrote them), nothing is repeated per query head and nothing is
-    concatenated: the token's own key and value (``kk`` / ``vv``, not in the
-    store yet) enter the softmax as one more term. Query head ``h`` reads KV
-    head ``h // rep``. At 16 query heads a KV head and 16,385 positions the
-    repeated form writes and reads two ``[16385, 128, 128]`` float32 arrays
-    (2.1 GB) a layer a token. Float32 scores."""
-    f32 = jnp.float32
-    G, D = cfg.n_kv_heads, cfg.head_dim
-    Tpad = k_cache.shape[0]
-    _note_decode_attend(
-        "grouped", q, k_cache,
-        f"{tag}: {cfg.n_heads // G} query heads a KV head in one product, "
-        f"the views read as kept ({k_cache.dtype.name})")
-    qg, scale = q[0, 0].reshape(G, -1, D).astype(f32), 1.0 / math.sqrt(D)
-    s = jnp.einsum("grd,tgd->grt", qg, k_cache.astype(f32)) * scale
-    own = jnp.einsum("grd,gd->gr", qg,
-                     kk[0, 0].astype(f32))[..., None] * scale
-    idx = jnp.arange(Tpad)
-    valid = idx < length
-    if lowest is not None:
-        valid &= idx >= lowest
-    s = jnp.where(valid, s, -1e30)
-    top = jnp.maximum(s.max(axis=-1, keepdims=True), own)
-    p, p_own = jnp.exp(s - top), jnp.exp(own - top)
-    o = jnp.einsum("grt,tgd->grd", p, v_cache.astype(f32)) \
-        + p_own * vv[0, 0].astype(f32)[:, None, :]
-    o = o / (p.sum(axis=-1, keepdims=True) + p_own)
-    return o.reshape(1, 1, -1, D).astype(cfg.dtype)

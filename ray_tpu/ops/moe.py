@@ -462,186 +462,13 @@ def _held_rows(hf, top_w, order, starts, end, weights, experts,
     nothing from a dead place. On the TPU the grouped product leaves the
     rows of no group UNWRITTEN (whatever the buffer held): places from
     ``end`` on are set to zero going in and coming out (the kernel never
-    adds them), and so are, transposed, their cotangents (:func:`_place_rows`).
-
-    These functions stand at the END of this file but for this one, which
-    keeps the lines it had: a Mosaic kernel's recorded frames are part of
-    its program's text, and the all-here path's (``routed_mlp`` ->
-    :func:`_expert_ffn`) must not move for a change of this path."""
+    adds them), and so are, transposed, their cotangents (:func:`_place_rows`)."""
     if order.shape[0] <= held_chunk(order.shape[0], starts.shape[0], experts):
         return _held_blocks(hf, top_w, order, starts, end, weights, 1,
                             layer, act)
     return _held_chunks(act, experts, hf, top_w, order, starts, end, weights,
                         None if layer is None else jnp.asarray(layer,
                                                                jnp.int32))
-
-
-def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
-               norm_topk_prob: bool = False,
-               stat_axes: Sequence[str] = (),
-               scoring: str = "softmax", choice_bias=None,
-               scale: float = 1.0, held: Optional[Tuple[int, int]] = None,
-               shared=None, zero_experts: int = 0, layer=None,
-               router_input=None, act: str = "swiglu", shared_gated=None
-               ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Dropless top-k routed experts. ``h`` [..., d] in the compute type.
-
-    ``router_w`` [d, E]; ``w_gate`` / ``w_up`` [E, d, f]; ``w_down``
-    [E, f, d], as stored. Router product and scores in float32; the
-    ``top_k`` weights stay as the scores gave them unless
-    ``norm_topk_prob``; the expert products in ``h``'s type with float32
-    accumulation. What a configuration may switch on, each by itself:
-
-    - ``scoring="sigmoid"``: ``sigmoid(logits)`` an expert in place of the
-      softmax over experts. The router losses below are the softmax
-      router's; a sigmoid router reports none.
-    - ``choice_bias`` [E]: added to the scores for the CHOICE of the
-      ``top_k`` alone; the weights are the unbiased scores of the chosen,
-      and no gradient reaches the bias.
-    - ``scale``: multiplies the weights, after ``norm_topk_prob``'s
-      renormalisation (``w_j = s_j / (sum_j s_j + 1e-20) * scale``).
-    - ``w_gate=None``: two-matrix experts, ``relu(x W_up) ** 2 W_down``.
-    - ``act="reglu"``: gated experts whose gate goes through relu,
-      ``relu(x W_gate) * (x W_up) W_down``, in place of SwiGLU's silu.
-    - ``router_input`` [..., d]: what the ROUTER reads where that is not
-      what its experts read (a block whose router sits before its
-      attention): logits, scores and the choice come from it, the experts'
-      rows from ``h``.
-    - ``shared = (w_up [d, fs], w_down [fs, d])``: one expert of that form
-      (no gate) every token runs, added to the routed sum once.
-    - ``shared_gated = (w_sg [d], w_gate [d, fs], w_up [d, fs], w_down
-      [fs, d])``: one SwiGLU expert every token runs, behind the token's own
-      gate ``sigmoid(h . w_sg)`` (float32), added to the routed sum once.
-      ``w_sg=None``: no gate in front of it, the expert's output as it is.
-    - ``held = (first, count)``: this device holds the ``count`` experts
-      from ``first`` on of the router's ``E`` (``w_up`` [count, d, f]):
-      router and ``top_k`` run over all ``E``; assignments to experts held
-      elsewhere sort behind the held experts' and get no row, no product
-      and no part in the sum (:func:`_held_rows`: rows up to the held
-      experts' last place); ``y`` is the partial sum of the held experts
-      (plus the shared one). With ``held=None`` every expert is here.
-    - ``zero_experts = n``: the LAST ``n`` of the router's ``E`` outputs
-      are identity experts, which have no weights and run where the token
-      is: an assignment to one adds ``w_j h`` and gets no row, no place
-      among the experts' groups and no product (it sorts behind the held
-      experts', as one held elsewhere does). ``w_gate`` / ``w_up`` /
-      ``w_down`` hold the ``E - n`` real experts, or ``held``'s range.
-
-    Returns ``(y [..., d] float32, stats)``; ``stats`` are float32 scalars:
-
-    - ``lb_loss``: ``E * sum_e fraction_e * mean_prob_e``, ``fraction_e``
-      the assignments expert ``e`` got over the TOKENS (the fractions sum
-      to ``top_k``), no gradient through it (softmax scoring only);
-    - ``z_loss``: ``mean(logsumexp(logits) ** 2)`` (softmax scoring only);
-    - ``max_load_ratio``: the heaviest (held) expert's assignments over the
-      mean of all ``E``;
-    - ``dropped``: assignments to experts here that no group holds (0 by
-      construction: every assignment has a place);
-    - ``held_share`` (with ``held`` only): the share of all assignments
-      that fell on held experts;
-    - ``zero_share`` (with ``zero_experts`` only): the share that fell on
-      identity experts. What neither share counts fell elsewhere.
-
-    ``stat_axes``: inside a ``shard_map`` whose axes split the tokens, the
-    axis names to average ``fraction_e`` over, so that ``lb_loss``, averaged
-    over those axes by the caller, is the loss of the whole batch and its
-    gradient the whole batch's (the fractions carry no gradient, so no
-    collective is differentiated)."""
-    if (w_up.ndim == 4) != (layer is not None):
-        raise ValueError(
-            f"expert weights {w_up.shape} with layer={layer!r}: stacked "
-            "leaves [L, count, d, f] come with their layer's number, one "
-            "layer's [count, d, f] without")
-    cd = h.dtype
-    lead, d = h.shape[:-1], h.shape[-1]
-    E, K = router_w.shape[-1], top_k
-    hf = h.reshape(-1, d)
-    N = hf.shape[0]
-    A = N * K
-    rf = hf if router_input is None else router_input.reshape(-1, d)
-    with jax.named_scope("moe.route"):
-        logits = jnp.dot(rf.astype(jnp.float32), router_w.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
-        if scoring == "softmax":
-            probs = jax.nn.softmax(logits, axis=-1)
-        else:
-            probs = jax.nn.sigmoid(logits)
-        if choice_bias is None:
-            top_w, top_e = jax.lax.top_k(probs, K)
-        else:
-            _, top_e = jax.lax.top_k(
-                probs + jax.lax.stop_gradient(choice_bias), K)
-            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
-        if norm_topk_prob:
-            top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
-        if scale != 1.0:
-            top_w = top_w * scale
-    if zero_experts and held is None:  # the real experts, all here
-        held = (0, E - zero_experts)
-    first, count = (0, E) if held is None else held
-    with jax.named_scope("moe.dispatch"):
-        ids = jnp.arange(A, dtype=jnp.int32)
-        group = top_e.reshape(A).astype(jnp.int32)
-        if held is not None:  # experts held elsewhere sort behind these
-            here = (group >= first) & (group < first + count)
-            group = jnp.where(here, group - first, count)
-        sorted_e, order = jax.lax.sort_key_val(group, ids)  # stable
-        starts = jnp.searchsorted(sorted_e,
-                                  jnp.arange(count, dtype=jnp.int32))
-        end = jnp.int32(A) if held is None else jnp.searchsorted(
-            sorted_e, jnp.int32(count)).astype(jnp.int32)
-        counts = jnp.diff(starts.astype(jnp.int32), append=end)
-        if held is None:
-            _, inverse = jax.lax.sort_key_val(order, ids)
-            xs = _dispatch(hf, order, inverse)
-    if held is None:
-        with jax.named_scope("moe.experts"):
-            ys = _expert_ffn(xs, w_gate, w_up, w_down, counts, layer, act)
-        with jax.named_scope("moe.combine"):
-            y = _combine(ys, top_w, order, inverse)
-    else:
-        y = _held_rows(hf, top_w, order, starts, end, (w_gate, w_up, w_down),
-                       E, layer, act)
-    if zero_experts:
-        with jax.named_scope("moe.zero"):
-            to_zero = top_e >= E - zero_experts
-            y = y + (jnp.sum(jnp.where(to_zero, top_w, 0.0), axis=-1,
-                             keepdims=True) * hf.astype(jnp.float32))
-    if shared is not None:
-        with jax.named_scope("moe.shared"):
-            a = jnp.square(jax.nn.relu(hf @ shared[0].astype(cd)))
-            y = y + (a @ shared[1].astype(cd)).astype(jnp.float32)
-    if shared_gated is not None:
-        with jax.named_scope("moe.shared"):
-            w_sg, s_gate, s_up, s_down = shared_gated
-            a = (jax.nn.silu((hf @ s_gate.astype(cd)).astype(jnp.float32))
-                 * (hf @ s_up.astype(cd))).astype(cd)
-            open_ = None if w_sg is None else jax.nn.sigmoid(jnp.dot(
-                hf.astype(jnp.float32), w_sg.astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))[:, None]
-            out = (a @ s_down.astype(cd)).astype(jnp.float32)
-            y = y + (out if open_ is None else open_ * out)
-    with jax.named_scope("moe.route"):
-        fraction = jax.lax.stop_gradient(counts.astype(jnp.float32) / N)
-        for ax in stat_axes:
-            fraction = jax.lax.pmean(fraction, ax)
-        stats = {}
-        if scoring == "softmax":
-            mean_prob = jnp.mean(probs, axis=0)
-            if held is not None:  # this device's terms of the sum over E
-                mean_prob = mean_prob[first:first + count]
-            stats = {
-                "lb_loss": E * jnp.sum(fraction * mean_prob),
-                "z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
-            }
-        stats["max_load_ratio"] = jnp.max(fraction) * (E / K)
-        stats["dropped"] = ((A if held is None else jnp.sum(here))
-                            - jnp.sum(counts)).astype(jnp.float32)
-        if held is not None:
-            stats["held_share"] = jnp.sum(fraction) / K
-        if zero_experts:
-            stats["zero_share"] = jnp.mean(to_zero.astype(jnp.float32))
-    return y.reshape(*lead, d), stats
 
 
 # --------------------------------------------------------------------------- #
@@ -845,3 +672,171 @@ def held_sum_path(ys, n_tokens: int, layer=None, loop: bool = True
         return "xla", (f"a chunk of {c} places: their tokens and weights pass "
                        f"the scalar memory ({MAX_PLACES})")
     return "kernel", "tpu backend"
+
+
+def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
+               norm_topk_prob: bool = False,
+               stat_axes: Sequence[str] = (),
+               scoring: str = "softmax", choice_bias=None,
+               scale: float = 1.0, held: Optional[Tuple[int, int]] = None,
+               shared=None, zero_experts: int = 0, layer=None,
+               router_input=None, act: str = "swiglu", shared_gated=None
+               ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Dropless top-k routed experts. ``h`` [..., d] in the compute type.
+
+    ``router_w`` [d, E]; ``w_gate`` / ``w_up`` [E, d, f]; ``w_down``
+    [E, f, d], as stored. Router product and scores in float32; the
+    ``top_k`` weights stay as the scores gave them unless
+    ``norm_topk_prob``; the expert products in ``h``'s type with float32
+    accumulation. What a configuration may switch on, each by itself:
+
+    - ``scoring="sigmoid"``: ``sigmoid(logits)`` an expert in place of the
+      softmax over experts. The router losses below are the softmax
+      router's; a sigmoid router reports none.
+    - ``choice_bias`` [E]: added to the scores for the CHOICE of the
+      ``top_k`` alone; the weights are the unbiased scores of the chosen,
+      and no gradient reaches the bias.
+    - ``scale``: multiplies the weights, after ``norm_topk_prob``'s
+      renormalisation (``w_j = s_j / (sum_j s_j + 1e-20) * scale``).
+    - ``w_gate=None``: two-matrix experts, ``relu(x W_up) ** 2 W_down``.
+    - ``act="reglu"``: gated experts whose gate goes through relu,
+      ``relu(x W_gate) * (x W_up) W_down``, in place of SwiGLU's silu.
+    - ``router_input`` [..., d]: what the ROUTER reads where that is not
+      what its experts read (a block whose router sits before its
+      attention): logits, scores and the choice come from it, the experts'
+      rows from ``h``.
+    - ``shared = (w_up [d, fs], w_down [fs, d])``: one expert of that form
+      (no gate) every token runs, added to the routed sum once.
+    - ``shared_gated = (w_sg [d], w_gate [d, fs], w_up [d, fs], w_down
+      [fs, d])``: one SwiGLU expert every token runs, behind the token's own
+      gate ``sigmoid(h . w_sg)`` (float32), added to the routed sum once.
+      ``w_sg=None``: no gate in front of it, the expert's output as it is.
+    - ``held = (first, count)``: this device holds the ``count`` experts
+      from ``first`` on of the router's ``E`` (``w_up`` [count, d, f]):
+      router and ``top_k`` run over all ``E``; assignments to experts held
+      elsewhere sort behind the held experts' and get no row, no product
+      and no part in the sum (:func:`_held_rows`: rows up to the held
+      experts' last place); ``y`` is the partial sum of the held experts
+      (plus the shared one). With ``held=None`` every expert is here.
+    - ``zero_experts = n``: the LAST ``n`` of the router's ``E`` outputs
+      are identity experts, which have no weights and run where the token
+      is: an assignment to one adds ``w_j h`` and gets no row, no place
+      among the experts' groups and no product (it sorts behind the held
+      experts', as one held elsewhere does). ``w_gate`` / ``w_up`` /
+      ``w_down`` hold the ``E - n`` real experts, or ``held``'s range.
+
+    Returns ``(y [..., d] float32, stats)``; ``stats`` are float32 scalars:
+
+    - ``lb_loss``: ``E * sum_e fraction_e * mean_prob_e``, ``fraction_e``
+      the assignments expert ``e`` got over the TOKENS (the fractions sum
+      to ``top_k``), no gradient through it (softmax scoring only);
+    - ``z_loss``: ``mean(logsumexp(logits) ** 2)`` (softmax scoring only);
+    - ``max_load_ratio``: the heaviest (held) expert's assignments over the
+      mean of all ``E``;
+    - ``dropped``: assignments to experts here that no group holds (0 by
+      construction: every assignment has a place);
+    - ``held_share`` (with ``held`` only): the share of all assignments
+      that fell on held experts;
+    - ``zero_share`` (with ``zero_experts`` only): the share that fell on
+      identity experts. What neither share counts fell elsewhere.
+
+    ``stat_axes``: inside a ``shard_map`` whose axes split the tokens, the
+    axis names to average ``fraction_e`` over, so that ``lb_loss``, averaged
+    over those axes by the caller, is the loss of the whole batch and its
+    gradient the whole batch's (the fractions carry no gradient, so no
+    collective is differentiated)."""
+    if (w_up.ndim == 4) != (layer is not None):
+        raise ValueError(
+            f"expert weights {w_up.shape} with layer={layer!r}: stacked "
+            "leaves [L, count, d, f] come with their layer's number, one "
+            "layer's [count, d, f] without")
+    cd = h.dtype
+    lead, d = h.shape[:-1], h.shape[-1]
+    E, K = router_w.shape[-1], top_k
+    hf = h.reshape(-1, d)
+    N = hf.shape[0]
+    A = N * K
+    rf = hf if router_input is None else router_input.reshape(-1, d)
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(rf.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        if scoring == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        else:
+            probs = jax.nn.sigmoid(logits)
+        if choice_bias is None:
+            top_w, top_e = jax.lax.top_k(probs, K)
+        else:
+            _, top_e = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(choice_bias), K)
+            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
+        if norm_topk_prob:
+            top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+        if scale != 1.0:
+            top_w = top_w * scale
+    if zero_experts and held is None:  # the real experts, all here
+        held = (0, E - zero_experts)
+    first, count = (0, E) if held is None else held
+    with jax.named_scope("moe.dispatch"):
+        ids = jnp.arange(A, dtype=jnp.int32)
+        group = top_e.reshape(A).astype(jnp.int32)
+        if held is not None:  # experts held elsewhere sort behind these
+            here = (group >= first) & (group < first + count)
+            group = jnp.where(here, group - first, count)
+        sorted_e, order = jax.lax.sort_key_val(group, ids)  # stable
+        starts = jnp.searchsorted(sorted_e,
+                                  jnp.arange(count, dtype=jnp.int32))
+        end = jnp.int32(A) if held is None else jnp.searchsorted(
+            sorted_e, jnp.int32(count)).astype(jnp.int32)
+        counts = jnp.diff(starts.astype(jnp.int32), append=end)
+        if held is None:
+            _, inverse = jax.lax.sort_key_val(order, ids)
+            xs = _dispatch(hf, order, inverse)
+    if held is None:
+        with jax.named_scope("moe.experts"):
+            ys = _expert_ffn(xs, w_gate, w_up, w_down, counts, layer, act)
+        with jax.named_scope("moe.combine"):
+            y = _combine(ys, top_w, order, inverse)
+    else:
+        y = _held_rows(hf, top_w, order, starts, end, (w_gate, w_up, w_down),
+                       E, layer, act)
+    if zero_experts:
+        with jax.named_scope("moe.zero"):
+            to_zero = top_e >= E - zero_experts
+            y = y + (jnp.sum(jnp.where(to_zero, top_w, 0.0), axis=-1,
+                             keepdims=True) * hf.astype(jnp.float32))
+    if shared is not None:
+        with jax.named_scope("moe.shared"):
+            a = jnp.square(jax.nn.relu(hf @ shared[0].astype(cd)))
+            y = y + (a @ shared[1].astype(cd)).astype(jnp.float32)
+    if shared_gated is not None:
+        with jax.named_scope("moe.shared"):
+            w_sg, s_gate, s_up, s_down = shared_gated
+            a = (jax.nn.silu((hf @ s_gate.astype(cd)).astype(jnp.float32))
+                 * (hf @ s_up.astype(cd))).astype(cd)
+            open_ = None if w_sg is None else jax.nn.sigmoid(jnp.dot(
+                hf.astype(jnp.float32), w_sg.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))[:, None]
+            out = (a @ s_down.astype(cd)).astype(jnp.float32)
+            y = y + (out if open_ is None else open_ * out)
+    with jax.named_scope("moe.route"):
+        fraction = jax.lax.stop_gradient(counts.astype(jnp.float32) / N)
+        for ax in stat_axes:
+            fraction = jax.lax.pmean(fraction, ax)
+        stats = {}
+        if scoring == "softmax":
+            mean_prob = jnp.mean(probs, axis=0)
+            if held is not None:  # this device's terms of the sum over E
+                mean_prob = mean_prob[first:first + count]
+            stats = {
+                "lb_loss": E * jnp.sum(fraction * mean_prob),
+                "z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
+            }
+        stats["max_load_ratio"] = jnp.max(fraction) * (E / K)
+        stats["dropped"] = ((A if held is None else jnp.sum(here))
+                            - jnp.sum(counts)).astype(jnp.float32)
+        if held is not None:
+            stats["held_share"] = jnp.sum(fraction) / K
+        if zero_experts:
+            stats["zero_share"] = jnp.mean(to_zero.astype(jnp.float32))
+    return y.reshape(*lead, d), stats

@@ -175,11 +175,9 @@ def _llama_stage_fwd(cfg, sparams, x):
     stage's decoder blocks via lax.scan over the sliced layer stack."""
     import jax
 
-    from ray_tpu.models.llama import _dense_only, _layer, positions_of
+    from ray_tpu.models.llama import _layer, held_to, positions_of
 
-    _dense_only(cfg, "the MPMD pipeline",
-                "its stages pass the residual stream alone, so a router's "
-                "losses have no way out, and no test runs QK-norm through it")
+    held_to(cfg, "the MPMD pipeline")
     if "embedding" in sparams:
         x = sparams["embedding"].astype(cfg.dtype)[x]
     positions = positions_of(*x.shape[:2])

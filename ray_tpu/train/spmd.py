@@ -682,16 +682,13 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
 
     from ray_tpu.collective import pmean_tree
     from ray_tpu.models.llama import (
-        _no_delta_kinds,
-        _no_parallel_kinds,
-        _no_wide_latent,
-        _no_window_kinds,
         _plain_chunk_nll,
         add_mtp_loss,
         add_router_losses,
         chunked_nll_mean,
         decoder_block,
         flash_causal,
+        held_to,
         init_params,
         keep_policy,
         pattern_stack,
@@ -713,28 +710,7 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     if gather not in ("streamed", "upfront"):
         raise ValueError(
             f"gather must be 'streamed' or 'upfront', got {gather!r}")
-    _no_window_kinds(
-        cfg, "make_spmd_train_step",
-        "its layers attend through the flash kernel, which masks the "
-        "causal triangle and has no window (forward and backward), and "
-        "under fsdp / tensor a patterned stack has no per-kind gather; "
-        "models.llama.loss_fn runs these kinds through attend_tiles")
-    _no_delta_kinds(
-        cfg, "make_spmd_train_step",
-        "no train step is held to a reference for the gated delta rule's "
-        "backward (autodiff through ops/gdn.py's chunked form) or the gated "
-        "attention's; models.llama.loss_fn runs the forward of both")
-    _no_parallel_kinds(
-        cfg, "make_spmd_train_step",
-        "no train step is held to a reference for the parallel block's "
-        "backward, and its flash kernel has no window; "
-        "models.llama.loss_fn runs the forward through attend_tiles")
-    _no_wide_latent(
-        cfg, "make_spmd_train_step",
-        "no train step is held to a reference for the mixes' backward or "
-        "keeps a stream of several rows' recomputation in its account, and "
-        "its flash kernel attends q, k and v of one width; "
-        "models.llama.loss_fn runs the forward")
+    held_to(cfg, "make_spmd_train_step")
 
     tensor = ("tensor" if "tensor" in mesh.axis_names
               and mesh.shape["tensor"] > 1 else None)

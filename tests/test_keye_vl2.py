@@ -597,8 +597,6 @@ def test_benchmark_files_fit_together_with_the_new_cell():
 
     test_yardstick.test_benchmark_files_fit_together()
     bench = spec.load_benchmark()
-    assert len(bench["workloads"]) == 12
-    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
     b = spec.cell_bundle(CELL)
     assert (b["cell"]["chips"], b["cell"]["traffic"], b["cell"]["config"]) \
         == (1, "prefill-open-6400-32000", "Keye-VL-2.0-30B-A3B")

@@ -458,6 +458,7 @@ def test_prefill_then_decode_through_pages_is_the_references(engine, params, n):
 
 def test_the_store_is_one_array_of_latent_rows(engine):
     from ray_tpu.util.metrics import registry
+    from test_olmoe import no_page_bytes
 
     assert llama.page_rows(engine.cfg) == ("latent", [(4, (24,))])
     (store,) = engine.stores
@@ -467,18 +468,14 @@ def test_the_store_is_one_array_of_latent_rows(engine):
         return {k[0][1]: v for k, v in registry().local_values(name).items()}
 
     assert gauge("ray_tpu_serve_engine_page_bytes") == {
-        "kv": 0.0, "latent": 4 * 24 * 4.0, "full": 0.0, "window": 0.0,
-        "index": 0.0, "gated": 0.0, "latent_block": 0.0,
-        "parallel_full": 0.0, "parallel_window": 0.0}
+        **no_page_bytes(engine.cfg), "latent": 4 * 24 * 4.0}
     # the grouped products run over the stack's 2 x 4 groups
     assert gauge("ray_tpu_serve_engine_expert_groups") == {
         "program": 8.0, "layer": 4.0}
     dense = llama.LlamaDecodeEngine(n_pages=4, page_size=4)
     assert [s.shape for s in dense.stores] == [(2, 4, 4, 2, 16)] * 2
     assert gauge("ray_tpu_serve_engine_page_bytes") == {
-        "kv": 2 * 2 * 2 * 16 * 4.0, "latent": 0.0, "full": 0.0,
-        "window": 0.0, "index": 0.0, "gated": 0.0, "latent_block": 0.0,
-        "parallel_full": 0.0, "parallel_window": 0.0}
+        **no_page_bytes(dense.cfg), "kv": 2 * 2 * 2 * 16 * 4.0}
     assert gauge("ray_tpu_serve_engine_expert_groups") == {
         "program": 0.0, "layer": 0.0}
 
@@ -617,9 +614,6 @@ def test_benchmark_files_fit_together_with_the_new_cell():
     from benchmarks.lib.serve_cell import check_prompt_len, shapes_of
 
     test_yardstick.test_benchmark_files_fit_together()
-    bench = spec.load_benchmark()
-    assert len(bench["workloads"]) == 12
-    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
     b = spec.cell_bundle(CELL)
     assert (b["cell"]["chips"], b["cell"]["traffic"]) == (
         1, "prefill-open-2048-8192")

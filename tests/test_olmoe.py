@@ -665,6 +665,15 @@ def _gauge(name):
     return {k[0][1]: v for k, v in registry().local_values(name).items()}
 
 
+def no_page_bytes(cfg):
+    """``ray_tpu_serve_engine_page_bytes`` of an engine that keeps nothing:
+    zero for every tag of ``SERVED`` whose store keeps a row a POSITION.
+    Another model's test puts its own values over it."""
+    return {tag: 0.0 for kind in llama.SERVED.values()
+            for tag, _, _, table in kind.rows(cfg)
+            if llama.TABLES[table].rows is None}
+
+
 @pytest.mark.parametrize("family,n_pages,page,held", [
     ("dense", 16, 4, None),          # no routed kind: the share is not set
     ("longcat_flash", 12, 8, 0.0),   # from the program's shares
@@ -689,12 +698,11 @@ def test_the_engine_is_what_the_table_folds(family, n_pages, page, held):
     assert [s.shape for s in engine.stores] == [
         (s.layers, engine.n_slots if s.table == "slot" else n_pages, page,
          *s.row) for s in layout]
-    want = {tag: 0.0 for kind in llama.SERVED.values()
-            for tag, _, _, table in kind.rows(cfg)
-            if llama.TABLES[table].rows is None}  # a row a POSITION
+    want = no_page_bytes(cfg)
     for s in layout:
         want[s.tag] += 4.0 * s.layers * math.prod(s.row)
     assert _gauge("ray_tpu_serve_engine_page_bytes") == want
+    # the literal set of tags, in THIS place alone: a new kind adds its own
     assert set(want) == {"kv", "latent", "full", "window", "index", "gated",
                          "latent_block", "parallel_full", "parallel_window"}
     # these families keep nothing a SEQUENCE (tests/test_qwen3_next.py)

@@ -389,6 +389,8 @@ def test_a_decode_that_reads_a_wrong_slot_lies_far_off(engine, params):
 
 
 def test_the_stores_are_two_pairs_and_the_window_pair_counts_slots(params):
+    from test_olmoe import no_page_bytes
+
     engine = new_engine(params)  # its gauges are the last engine's
     cfg = engine.cfg
     assert llama.page_rows(cfg) == ("window", [(2, (2, 16))] * 2
@@ -399,9 +401,8 @@ def test_the_stores_are_two_pairs_and_the_window_pair_counts_slots(params):
     assert [s.shape for s in engine.stores] == [(2, 24, 5, 2, 16)] * 2 \
         + [(4, 12, 5, 2, 16)] * 2
     assert gauge("ray_tpu_serve_engine_page_bytes") == {
-        "kv": 0.0, "latent": 0.0, "full": 2 * 2 * 2 * 16 * 4.0,
-        "window": 2 * 4 * 2 * 16 * 4.0, "index": 0.0, "gated": 0.0, "latent_block": 0.0,
-        "parallel_full": 0.0, "parallel_window": 0.0}
+        **no_page_bytes(cfg), "full": 2 * 2 * 2 * 16 * 4.0,
+        "window": 2 * 4 * 2 * 16 * 4.0}
     assert gauge("ray_tpu_serve_engine_expert_groups") == {
         "program": 6 * 8.0, "layer": 8.0}
     assert gauge("ray_tpu_serve_engine_window_slots")["total"] == 12.0
@@ -597,9 +598,6 @@ def test_benchmark_files_fit_together_with_the_new_cell():
     from benchmarks.lib.serve_cell import check_prompt_len, shapes_of
 
     test_yardstick.test_benchmark_files_fit_together()
-    bench = spec.load_benchmark()
-    assert len(bench["workloads"]) == 12
-    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
     b = spec.cell_bundle(CELL)
     assert (b["cell"]["chips"], b["cell"]["traffic"]) == (
         1, "prefill-open-4608-16000")
