@@ -1545,7 +1545,7 @@ def add_router_losses(cfg: LlamaConfig, nll, stats):
                   "z_loss": stats["z_loss"].mean()}
     report.update(max_load_ratio=stats["max_load_ratio"].max(),
                   dropped=stats["dropped"].sum())
-    for share in ("held_share", "zero_share"):
+    for share in ("held_share", "held_chunks", "zero_share"):
         if share in stats:  # the layers hold a range; have identity experts
             report[share] = stats[share].mean()
     if "lb_loss" not in stats:

@@ -453,9 +453,13 @@ def _held_rows(hf, top_w, order, starts, end, weights, experts,
       EVEN share of the places and a margin: a balanced router's load is one
       chunk with a short dead tail, a heavier one more chunks, none can outrun
       it, and a program holds one expert (and sum) kernel a routed block;
-    - a larger DIFFERENTIATED call (the trainer's step): fixed blocks, the
-      first always made, a further one under a ``cond``
+    - a larger DIFFERENTIATED call (the trainer's step): TIERS of static,
+      uneven edges (:func:`held_tiers`), the first, one chunk of the loop's,
+      always made, those behind it under nested ``cond`` s
       (:func:`_held_chunks_jvp`): a loop of traced length has no transpose.
+      ``train-glm47flash-1chip``'s step makes 11,264 rows a routed block for
+      some 8,192 live places of 65,536, ``train-nemotron3nano-1chip``'s 8,448
+      for 6,144 of 98,304 (32,768 and 24,576 before PR 57).
 
     Whichever runs: float32 rows times float32 weights added in float32
     onto ``[N, d]``, every live assignment's row whatever the routing, and
@@ -464,8 +468,8 @@ def _held_rows(hf, top_w, order, starts, end, weights, experts,
     ``end`` on are set to zero going in and coming out (the kernel never
     adds them), and so are, transposed, their cotangents (:func:`_place_rows`)."""
     if order.shape[0] <= held_chunk(order.shape[0], starts.shape[0], experts):
-        return _held_blocks(hf, top_w, order, starts, end, weights, 1,
-                            layer, act)
+        return _held_blocks(hf, top_w, order, starts, end, weights,
+                            (0, order.shape[0]), layer, act)
     return _held_chunks(act, experts, hf, top_w, order, starts, end, weights,
                         None if layer is None else jnp.asarray(layer,
                                                                jnp.int32))
@@ -489,14 +493,48 @@ def held_chunk(places: int, count: int, experts: int) -> int:
     return math.ceil(even * (1 + count ** -0.5) / HELD_TILE) * HELD_TILE
 
 
-def held_places_made(places: int, live, count: int, experts: int) -> int:
-    """The places :func:`_held_rows` makes a row for (gathers, multiplies
-    and adds onto its token) in a FORWARD call over ``places`` sorted places
-    of which the first ``live`` fell on the ``count`` held of ``experts``
-    experts: all of them where the call is one straight block, else whole
-    chunks up to ``live``."""
+def held_tiers(places: int, count: int, experts: int) -> Tuple[int, ...]:
+    """The static edges ``(0, e_1, ..., places)`` of the TIERS a
+    DIFFERENTIATED call of :func:`_held_rows` makes rows by
+    (:func:`_held_blocks`): tier ``[0, e_1)`` in every step, tier ``[e_i,
+    e_i+1)`` only in a step whose router sends places past ``e_i``. The first
+    is ONE chunk of the forward loop's (:func:`held_chunk`: the even share
+    and its margin, what a balanced router fills), the others end where the
+    EVEN BLOCKS the form had until PR 57 ended (a block: four even shares of
+    the ``experts`` experts' assignments, two where four would be all of
+    them, at least 128 places), so that no tier is larger than such a block
+    (a compiled step's worst branch holds the temporaries it held) and no
+    step makes more places than it made then: ``(0, 11264, 32768, 65536)``
+    for 8 of 64 experts on 65,536 places (``train-glm47flash-1chip``), ``(0,
+    8448, 24576, 49152, 73728, 98304)`` for 8 of 128 on 98,304
+    (``train-nemotron3nano-1chip``). Where a chunk is no smaller than a
+    block the first tier is the block. Strictly increasing, a function of
+    the call's shapes and no knob."""
+    blocks = max(1, min(max(experts // (4 * count),
+                            min(2, experts // (2 * count))), places // 128))
+    if places % blocks:
+        blocks = 1
+    block = places // blocks
     c = held_chunk(places, count, experts)
-    return places if places <= c else -(-int(live) // c) * c
+    return (0,) + ((c,) if c < block else ()) + tuple(
+        range(block, places + 1, block))
+
+
+def held_places_made(places: int, live, count: int, experts: int,
+                     differentiated: bool = False) -> int:
+    """The places :func:`_held_rows` makes a row for (gathers, multiplies
+    and adds onto its token) in a call over ``places`` sorted places of which
+    the first ``live`` fell on the ``count`` held of ``experts`` experts:
+    all of them where the call is one straight block; else in a FORWARD call
+    whole chunks up to ``live``, in a ``differentiated`` one whole tiers
+    (:func:`held_tiers`), the first whatever ``live`` is."""
+    c = held_chunk(places, count, experts)
+    if places <= c:
+        return places
+    if not differentiated:
+        return -(-int(live) // c) * c
+    edges = held_tiers(places, count, experts)
+    return next(e for e in edges[1:] if e >= min(int(live), places))
 
 
 def _place_outputs(hf, flat_w, order, edges, end, weights, layer, act, lo, n):
@@ -527,32 +565,66 @@ def _place_rows(hf, flat_w, order, *place):
             live[:, None], ys, 0).astype(jnp.float32)
 
 
-def _held_blocks(hf, top_w, order, starts, end, weights, blocks, layer, act):
-    """:func:`_held_rows` over ``blocks`` fixed blocks of ``A / blocks``
-    places, in operations that have a transpose. The first block's rows are
-    always made; a further block's only in a step whose router sends places
-    into it (recomputed in the backward: it is the rare step), so no
-    assignment is ever left out whatever the routing, and a common step
-    makes ``A / blocks`` rows, live or dead."""
+def _place_sum(hf, flat_w, order, starts_end, end, weights, layer, lo, *, n,
+               act):
+    """:func:`_place_rows` of the ``n`` sorted places from ``lo`` on, added
+    onto their tokens: float32 ``[N, d]``."""
+    token, ys = _place_rows(hf, flat_w, order, starts_end, end, weights,
+                            layer, act, lo, n)
+    with jax.named_scope("moe.combine"):
+        return jnp.zeros(hf.shape, jnp.float32).at[token].add(ys)
+
+
+# a tier of the trainer's step: jitted, so that a tier's body is traced (and
+# differentiated, and lowered) ONCE a size in a process, whatever ``lo`` and
+# however many routed blocks and programs hold it (a step is built twice,
+# train/spmd.py _KeepingStep; GLM's has the module's block beside the
+# stack's): with a third tier's body GLM's two programs trace in 9.1 s where
+# the even blocks' two bodies took 10.0 and the tiers' three unjitted 11.2
+# (the CPU, PR 57: a count of Python's work, no device's)
+_tier_sum = jax.jit(_place_sum, static_argnames=("n", "act"))
+
+
+def _held_blocks(hf, top_w, order, starts, end, weights, edges, layer, act):
+    """:func:`_held_rows` over the tiers ``[edges[i], edges[i + 1])`` of the
+    sorted places (``edges``: static, from 0 to ``A``), in operations that
+    have a transpose. The first tier's rows are always made; a further
+    tier's only in a step whose router sends places into it (recomputed in
+    the backward: it is the rare step), so no assignment is ever left out
+    whatever the routing, and a common step makes ``edges[1]`` rows, live or
+    dead. The ``cond`` s are NESTED, a tier's branch holds the next tier's,
+    and a branch is ONE checkpoint over everything behind its edge: the
+    common step evaluates one predicate, adds one ``[N, d]`` of zeros and
+    carries one set of a ``cond``'s residuals (a differentiated ``cond``
+    hands its branch's inputs on as outputs, zeros where the branch was not
+    taken: the held experts' weights, 0.3 GB a routed block in both train
+    cells) however many tiers there are."""
     (N, d), A = hf.shape, order.shape[0]
-    if A % blocks:
-        blocks = 1
-    n = A // blocks
-    _tell_held_sum(jax.ShapeDtypeStruct((n, d), hf.dtype), N, layer, False)
-    edges = jnp.append(starts, end).astype(jnp.int32)
+    assert edges[0] == 0 and edges[-1] == A and all(
+        lo < hi for lo, hi in zip(edges, edges[1:])), edges
+    _tell_held_sum(jax.ShapeDtypeStruct((edges[1], d), hf.dtype), N, layer,
+                   False)
+    starts_end = jnp.append(starts, end).astype(jnp.int32)
     flat_w = top_w.reshape(A)
+    # the trainer's tiers (one layer's weights) through the jitted form; one
+    # straight block (a decode program's text is what it was) and a served
+    # kind's stacked leaves, whose calls are counted where they are traced
+    # (_expert_ffn's watchers), are traced where they stand
+    place_sum = _tier_sum if len(edges) > 2 and layer is None else _place_sum
 
-    def block(i):
-        token, ys = _place_rows(hf, flat_w, order, edges, end, weights,
-                                layer, act, i * n, n)
-        with jax.named_scope("moe.combine"):
-            return jnp.zeros((N, d), jnp.float32).at[token].add(ys)
+    def tier(i):
+        return place_sum(hf, flat_w, order, starts_end, end, weights, layer,
+                         edges[i], n=edges[i + 1] - edges[i], act=act)
 
-    y = block(0)
-    for i in range(1, blocks):  # behind the first: only if places fall in it
-        y = y + jax.lax.cond(i * n < end, jax.checkpoint(partial(block, i)),
-                             lambda: jnp.zeros((N, d), jnp.float32))
-    return y
+    def behind(i):  # tier i and, if places fall behind it, those tiers
+        y = tier(i)
+        if i + 2 < len(edges):
+            y = y + jax.lax.cond(edges[i + 1] < end,
+                                 jax.checkpoint(partial(behind, i + 1)),
+                                 lambda: jnp.zeros((N, d), jnp.float32))
+        return y
+
+    return behind(0)
 
 
 @partial(jax.custom_jvp, nondiff_argnums=(0, 1))
@@ -594,20 +666,20 @@ def _held_chunks(act, experts, hf, top_w, order, starts, end, weights, layer):
 
 @_held_chunks.defjvp
 def _held_chunks_jvp(act, experts, primals, tangents):
-    """A differentiated :func:`_held_chunks` is :func:`_held_blocks`, traced
-    where the call stands, and the loop is in no such program at all (a
-    ``custom_jvp`` and not a ``custom_vjp``: a block's transposes then add
-    to the cotangents one by one in the order they always did, and the
-    trainer's step lowers to the text it had). A block is four even shares
-    of the ``experts`` experts' assignments (two where four would be all of
-    them: a quarter of the experts held) and at least 128 places."""
+    """A differentiated :func:`_held_chunks` is :func:`_held_blocks` over
+    :func:`held_tiers`' edges, traced where the call stands, and the loop is
+    in no such program at all (a ``custom_jvp`` and not a ``custom_vjp``: a
+    tier's transposes then add to the cotangents one by one, and the
+    experts' float32 gradients are carried through no loop). The first tier
+    is the loop's chunk, so the common step makes the rows a forward call's
+    one trip makes (``train-glm47flash-1chip``: 11,264 a routed block where
+    it made 32,768; ``train-nemotron3nano-1chip``: 8,448 for 24,576) and
+    ``stats["held_chunks"]`` reads 1.0 exactly when it did."""
     hf, top_w, order, starts, end, weights, layer = primals
-    A, count = order.shape[0], starts.shape[0]
-    blocks = max(1, min(max(experts // (4 * count),
-                            min(2, experts // (2 * count))), A // 128))
+    edges = held_tiers(order.shape[0], starts.shape[0], experts)
     return jax.jvp(
         lambda hf, top_w, weights: _held_blocks(
-            hf, top_w, order, starts, end, weights, blocks, layer, act),
+            hf, top_w, order, starts, end, weights, edges, layer, act),
         (hf, top_w, weights), (tangents[0], tangents[1], tangents[5]))
 
 
@@ -656,7 +728,7 @@ def held_sum_path(ys, n_tokens: int, layer=None, loop: bool = True
     c, d = ys.shape
     if not loop:
         return "xla", (f"one straight block of {c} places (a decode call, or "
-                       "a differentiated call's fixed blocks)")
+                       "a differentiated call's first tier)")
     dtype = jnp.dtype(ys.dtype)
     if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize not in (2, 4):
         return "xla", f"rows in {dtype.name}, not a 2- or 4-byte float type"
@@ -716,8 +788,10 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
       router and ``top_k`` run over all ``E``; assignments to experts held
       elsewhere sort behind the held experts' and get no row, no product
       and no part in the sum (:func:`_held_rows`: rows up to the held
-      experts' last place); ``y`` is the partial sum of the held experts
-      (plus the shared one). With ``held=None`` every expert is here.
+      experts' last place, in a forward call by chunks of the even share,
+      in a differentiated one by tiers whose first is that chunk); ``y`` is
+      the partial sum of the held experts (plus the shared one). With
+      ``held=None`` every expert is here.
     - ``zero_experts = n``: the LAST ``n`` of the router's ``E`` outputs
       are identity experts, which have no weights and run where the token
       is: an assignment to one adds ``w_j h`` and gets no row, no place
@@ -737,6 +811,10 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
       construction: every assignment has a place);
     - ``held_share`` (with ``held`` only): the share of all assignments
       that fell on held experts;
+    - ``held_chunks`` (with ``held`` only): ``ceil(end / held_chunk(..))``,
+      the chunks of the even share the held experts' live places fill: the
+      forward loop's trip count, and 1.0 exactly when a differentiated
+      call's first tier (:func:`held_tiers`) held every live place;
     - ``zero_share`` (with ``zero_experts`` only): the share that fell on
       identity experts. What neither share counts fell elsewhere.
 
@@ -837,6 +915,8 @@ def routed_mlp(h, router_w, w_gate, w_up, w_down, *, top_k: int,
                             - jnp.sum(counts)).astype(jnp.float32)
         if held is not None:
             stats["held_share"] = jnp.sum(fraction) / K
+            c = held_chunk(A, count, E)
+            stats["held_chunks"] = ((end + (c - 1)) // c).astype(jnp.float32)
         if zero_experts:
             stats["zero_share"] = jnp.mean(to_zero.astype(jnp.float32))
     return y.reshape(*lead, d), stats

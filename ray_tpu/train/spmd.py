@@ -121,6 +121,11 @@ _ROUTER_GAUGES = {
     # all assignments that fell on the held ones
     "held_share": _fr.register_span("moe.held_share",
                                     tag_keys=("value", "step")),
+    # and the chunks of the even share their live places fill, a routed
+    # layer's mean: 1.0 when every layer's step made its first tier of rows
+    # and no other (ops/moe.py held_tiers)
+    "held_chunks": _fr.register_span("moe.held_chunks",
+                                     tag_keys=("value", "step")),
     # a model with a prediction module (LlamaConfig.mtp_layers): its
     # cross-entropy and the main one apart, as they stand in the loss before
     # the module's weight (the report's ``mtp_loss`` / ``main_loss``)
@@ -631,8 +636,8 @@ def make_spmd_train_step(cfg, mesh, optimizer=None, rules=None,
     batch axes only, its layers walked in the pattern's order
     (``models.llama.pattern_stack``); a live ``fsdp`` or ``tensor`` axis is
     refused. Its routed layers may hold a range of the router's experts:
-    the step's router scalars then carry ``held_share``. Latent-attention
-    blocks (``"L"`` / ``"G"``, ``models.llama.latent_block``) are attended
+    the step's router scalars then carry ``held_share`` and ``held_chunks``.
+    Latent-attention blocks (``"L"`` / ``"G"``, ``latent_block``) are attended
     with the flash kernel over per-head keys and values of ONE width (the
     score's, which is the value's), forward, dQ and dK/dV, and NOT with
     prefill's kernel, which has no backward of its own. With a prediction

@@ -139,9 +139,9 @@ def test_loss_parts_reports_both_losses_apart(params, tokens):
     assert rel(float(report["mtp_loss"]), float(ahead)) < 1e-5
     assert float(total) == pytest.approx(
         float(main) + 0.3 * float(ahead), rel=1e-5)
-    # five scalars: the router's three of a held sigmoid router, and the two
+    # six scalars: the router's four of a held sigmoid router, and the two
     assert set(report) == {"max_load_ratio", "dropped", "held_share",
-                           "main_loss", "mtp_loss"}
+                           "held_chunks", "main_loss", "mtp_loss"}
     assert float(report["dropped"]) == 0.0
 
 
@@ -481,7 +481,7 @@ def test_spmd_step_one_device_against_data2_and_loss_fn(tokens):
     for (l1, s1), (l2, s2) in zip(one, two):
         assert l1 == pytest.approx(l2, rel=2e-5)
         assert set(s1) == {"max_load_ratio", "dropped", "held_share",
-                           "main_loss", "mtp_loss"}
+                           "held_chunks", "main_loss", "mtp_loss"}
         assert l1 == pytest.approx(s1["main_loss"] + 0.3 * s1["mtp_loss"],
                                    rel=1e-6)
         assert s1["mtp_loss"] == pytest.approx(s2["mtp_loss"], rel=2e-5)
@@ -570,8 +570,8 @@ def test_loop_reports_the_module_loss_and_sets_the_stack_gauge():
     payload.update(source="test", node_hex="", offset_s=0.0)
     rep = fr.attribute_trace(fr.build_span_events([payload]))
     assert set(rep["router"]) == {
-        "moe.max_load_ratio", "moe.dropped", "moe.held_share", "mtp.loss",
-        "mtp.main_loss"}
+        "moe.max_load_ratio", "moe.dropped", "moe.held_share",
+        "moe.held_chunks", "mtp.loss", "mtp.main_loss"}
     assert rep["router"]["mtp.loss"]["last"] == pytest.approx(
         last["mtp_loss"])
     gauge = registry().local_values("ray_tpu_train_stack")

@@ -33,7 +33,8 @@ def test_loss_and_every_gradient_leaf_match_reference(params, tokens):
         lambda p: ref.loss(FILE, p, jnp.asarray(tokens)), params)
     assert float(loss) == pytest.approx(float(want), rel=2e-6)
     assert_trees_close(grads, g_want, rtol=2e-3, atol=2e-5)
-    assert set(report) == {"max_load_ratio", "dropped", "held_share"}
+    assert set(report) == {"max_load_ratio", "dropped", "held_share",
+                           "held_chunks"}
     assert float(report["dropped"]) == 0.0
     # remat is the same program's values
     again = loss_fn(dataclasses.replace(cfg, remat=False), params, tokens)

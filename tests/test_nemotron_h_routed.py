@@ -47,7 +47,8 @@ def test_routed_layer_matches_reference_outputs_and_gradients(params):
     assert float(stats["held_share"]) == pytest.approx(here)
     assert 0.02 < here < 0.4
     assert float(stats["dropped"]) == 0.0
-    assert set(stats) == {"max_load_ratio", "dropped", "held_share"}
+    assert set(stats) == {"max_load_ratio", "dropped", "held_share",
+                          "held_chunks"}
 
 
 # --- the router: bias, renormalisation, scale --------------------------- #

@@ -39,7 +39,8 @@ def test_spmd_step_one_device_against_data2(tokens):
         assert l1 == pytest.approx(l2, rel=2e-5)
         assert r1["held_share"] == pytest.approx(r2["held_share"])
         assert r1["dropped"] == r2["dropped"] == 0.0
-        assert set(r1) == {"max_load_ratio", "dropped", "held_share"}
+        assert set(r1) == {"max_load_ratio", "dropped", "held_share",
+                           "held_chunks"}
     assert one[1][0] < one[0][0]  # adamw learns
     assert_trees_close(state2["params"], state1["params"], rtol=1e-3,
                        atol=1e-4)
@@ -111,15 +112,22 @@ def test_loop_reports_held_share_and_sets_the_stack_gauge():
     payload.update(source="test", node_hex="", offset_s=0.0)
     rep = fr.attribute_trace(fr.build_span_events([payload]))
     assert set(rep["router"]) == {"moe.max_load_ratio", "moe.dropped",
-                                  "moe.held_share"}
+                                  "moe.held_share", "moe.held_chunks"}
     assert rep["router"]["moe.held_share"]["last"] == pytest.approx(
         reports[-1]["moe_held_share"])
+    # the chunks the live places fill, a routed layer's mean, as an instant
+    # of its own (a stat without one is a KeyError in the first report): at
+    # this size a call's places are under one chunk, a layer reads 0 or 1
+    assert rep["router"]["moe.held_chunks"]["last"] == pytest.approx(
+        reports[-1]["moe_held_chunks"])
+    assert 0.0 < reports[-1]["moe_held_chunks"] <= 1.0
     # the slowest step's line holds the held share OF THAT STEP
     slow = rep["slowest_step"]
     assert slow["router"]["moe.held_share"] == pytest.approx(
         reports[slow["step"] - 1]["moe_held_share"])
-    assert "moe.held_share" in fr.format_attribution(rep).split(
-        "slowest step")[1].split("compile")[0]
+    slowest = fr.format_attribution(rep).split("slowest step")[1].split(
+        "compile")[0]
+    assert "moe.held_share" in slowest and "moe.held_chunks" in slowest
     gauge = registry().local_values("ray_tpu_train_stack")
     assert {k[0][1]: v for k, v in gauge.items()} == {
         "block_layers": 0.0, "mamba_layers": 4.0, "moe_layers": 4.0,

@@ -118,6 +118,29 @@ def test_flash_kernels_compile_at_head_width_256(one_chip, as_on_the_chip):
         'custom_call_target="tpu_custom_call"') == 3
 
 
+def _whole_step(cell, one_chip):
+    """``(compiled, bytes)``: a train cell's WHOLE step that keeps nothing
+    (batch 2 x 8192) compiled for the described chip, and what it needs of
+    the device by the compiler's account (``spmd.program_bytes``)."""
+    from benchmarks.lib import spec
+    from ray_tpu.train.spmd import (build_train_mesh, make_spmd_train_step,
+                                    program_bytes)
+
+    cfg = spec.program_config(spec.cell_bundle(cell)["config"])
+    init, step, *_ = make_spmd_train_step(
+        cfg, build_train_mesh("", list(one_chip.device_set)), keep=())
+    state = jax.eval_shape(init._fn, jax.random.PRNGKey(0))
+    compiled = step._fn.lower(
+        state, jax.ShapeDtypeStruct((2, 8193), jnp.int32)).compile()
+    m = compiled.memory_analysis()
+    return compiled, program_bytes({
+        "peak": getattr(m, "peak_memory_in_bytes", 0),
+        "argument": m.argument_size_in_bytes,
+        "output": m.output_size_in_bytes, "alias": m.alias_size_in_bytes,
+        "temp": m.temp_size_in_bytes,
+        "code": m.generated_code_size_in_bytes})
+
+
 def test_the_glm47flash_cells_whole_step_fits_the_chip(one_chip,
                                                        as_on_the_chip):
     """The cell's WHOLE train step (706.5M parameters, batch 2 x 8192, every
@@ -126,24 +149,7 @@ def test_the_glm47flash_cells_whole_step_fits_the_chip(one_chip,
     blocks' and the module's attention are the flash kernels forward and
     backward (six latent attentions: a forward, its remat and dQ / dK/dV
     each), and no tile loop of prefill's is in it."""
-    from benchmarks.lib import spec
-    from ray_tpu.train.spmd import (build_train_mesh, make_spmd_train_step,
-                                    program_bytes)
-
-    cfg = spec.program_config(
-        spec.cell_bundle("train-glm47flash-1chip")["config"])
-    init, step, *_ = make_spmd_train_step(
-        cfg, build_train_mesh("", list(one_chip.device_set)), keep=())
-    state = jax.eval_shape(init._fn, jax.random.PRNGKey(0))
-    compiled = step._fn.lower(
-        state, jax.ShapeDtypeStruct((2, 8193), jnp.int32)).compile()
-    m = compiled.memory_analysis()
-    need = program_bytes({
-        "peak": getattr(m, "peak_memory_in_bytes", 0),
-        "argument": m.argument_size_in_bytes,
-        "output": m.output_size_in_bytes, "alias": m.alias_size_in_bytes,
-        "temp": m.temp_size_in_bytes,
-        "code": m.generated_code_size_in_bytes})
+    compiled, need = _whole_step("train-glm47flash-1chip", one_chip)
     assert 12 * 2 ** 30 < need < 15.75 * 2 ** 30, need
     text = compiled.as_text()
     assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 12
