@@ -430,6 +430,14 @@ def _pipeline_text(**axes):
         LlamaConfig.debug(), make_mesh(axis_sizes=axes), 2)[:2])
 
 
+def _patterned_text():
+    """The SPMD step of ``tests/nemotron_h_small.py``'s stack (``MEMEM*EME``:
+    the Mamba-2 mixer of ``ops/ssm.py`` in four layers)."""
+    from nemotron_h_small import program_cfg as patterned_cfg
+
+    return _spmd_text(patterned_cfg(), "", 1)
+
+
 def _serving_text(program, n):
     """``program`` of the decode engine at ``n`` pages, lowered as the
     engine jits it."""
@@ -483,7 +491,12 @@ def _gspmd_text():
 # numbers. PR 41 meant to change the two ROUTED steps and replaced their
 # hashes (and the routed one of the three below): ``ops/moe.py``'s row maps
 # (``_sum_rows``; the weights' cotangent made where the rows lie); the ten
-# programs that run no ``routed_mlp`` keep theirs.
+# programs that run no ``routed_mlp`` keep theirs. The patterned step (the
+# ``M`` / ``E`` / ``*`` halves at ``tests/nemotron_h_small.py``'s sizes:
+# ``train-nemotron3nano-1chip``'s program, small) was taken on 7e19dcb, the
+# commit before PR 58 gave ``ops/ssm.py``'s scan a start state, ``last`` and
+# a returned state for the decode engine: the trainer's program is the one
+# it was.
 PROGRAMS = {
     "dense spmd, one device": (
         "90f52bb7182988719693b307dea1b7af700e18813e501c295d3f917e99fe4f41",
@@ -521,6 +534,9 @@ PROGRAMS = {
     "pipeline, pipe=2 tensor=2": (
         "44b3a4ca71797b37d385ba93e44dcbae037328c2518c14d023942ea78641f8ef",
         lambda: _pipeline_text(pipe=2, tensor=2)),
+    "patterned M spmd, one device": (
+        "5b126790328a47f71e0d8102125958029a40efa047c5d98057d02ccfab4b039b",
+        lambda: _patterned_text()),
 }
 
 
