@@ -215,7 +215,8 @@ def keep_policy(keep):
 LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
                "F": "block", "W": "block", "I": "index", "D": "delta",
                "A": "gated", "L": "latent", "G": "latent_dense",
-               "P": "parallel", "R": "parallel"}
+               "P": "parallel", "R": "parallel",
+               "H": "hybrid_mamba", "N": "hybrid_attn"}
 # the kinds that are a WHOLE block (attention THEN the routed MLP, whose
 # router reads the attention's normed input; window_block): "F" attends over
 # every earlier position without rotation, "W" over the last cfg.window with
@@ -236,6 +237,12 @@ LATENT_KINDS = "LG"
 PARALLEL_KINDS = "PR"
 # the kinds whose attention has cfg.window (and rotates)
 WINDOW_KINDS = "WR"
+# the state-space hybrid's WHOLE blocks (hybrid_block): a mixer, then a dense
+# SwiGLU, each added ``residual_multiplier`` times. "H" mixes through Mamba-2
+# (ops/ssm.py: a STATE a sequence, no row a position), "N" through GQA without
+# rotation whose scores are scaled by ``attention_multiplier``. A stack EACH
+# (their mixers' leaves differ)
+HYBRID_KINDS = "HN"
 # Where their matrices start off the square root of their fan-in (seeded random
 # weights; a checkpoint brings its own): ``wo`` 12 times as wide, the router
 # 6 times, so that BOTH halves carry the logits and a comparison of logits
@@ -346,6 +353,35 @@ SEEDED_SCALES = ("wo", "expert_down")
 # WHICH experts are chosen, their load and every product's shape and time are
 # the same at any of them. Readings: the configuration file's ``correct``.
 PARALLEL_INIT = {"wq": 2.0, "wo": 24.0}
+# Where the "H" / "N" blocks' SEEDED leaves start (a checkpoint brings its
+# own), for BLOCK_INIT's reason: every mechanism has to carry enough of a
+# logit that a comparison of logits refuses a fault in it. ``dt``: the
+# time step's range (log-uniform; ``dt_bias`` is its inverse softplus) and
+# ``A``: the decay rate's (uniform; ``A_log`` its logarithm), in place of
+# init_mamba2's 0.001-0.1 and 1-16: there ``S C`` is a tenth of the skip ``D
+# x`` beside it (its width over ``x``'s goes as sqrt(state dt / A)), so the
+# state, its page and its position change a logit by next to nothing. At
+# these every head's ``S C`` is within 0.5-3 times its ``D x`` and it
+# remembers 20-50 tokens, so that BOTH carry the logits: a state read from
+# zeros moves them by a fifth, and the skip dropped, the tail zeroed or two
+# pad tokens taken for real ones by 4-11% (at 0.05-0.5 and 0.05-1.0, tried
+# first, the slowest heads' states were a hundred times the others', the
+# gated norm over all 4,096 followed them alone, and the skip dropped read
+# 1.9-4.4%).
+# ``conv_b``: the convolution's bias starts normal at this width (zeros as
+# published would make it no mechanism at all). ``wq``: the attention's
+# query projection starts this many times as wide: the published score
+# scale is 1/64 on heads 64 wide, scores an eighth of a unit wide over
+# thousands of keys, an average; at 16 they are two units wide. ``wo``: its
+# output product starts this many times as wide, so that the FOUR attention
+# layers of forty carry a share of the logits that a rotation or a wrong
+# scale shows in. ``embedding``: the table starts this many times as wide
+# (the tied head with it), so that ``embedding_multiplier`` times it is a
+# part of the stream that leaving the multiplier out changes. Which tokens go
+# where and every product's shape and time depend on none of them. Readings:
+# the configuration file's ``correct``.
+HYBRID_INIT = {"dt": (0.02, 0.2), "A": (0.25, 1.0), "conv_b": 1.0,
+               "wq": 16.0, "wo": 12.0, "embedding": 2.0}
 
 
 @dataclass(frozen=True)
@@ -533,6 +569,16 @@ class LlamaConfig:
     shared_experts: int = 1
     shared_combine: str = "average"
     logit_scale: float = 1.0
+    # the "H" / "N" layers (hybrid_block), key for key a published config's:
+    # the embedding's rows times embedding_multiplier; attention scores times
+    # attention_multiplier in place of 1 / sqrt(head_dim) (0: that); a
+    # mixer's and an MLP's output times residual_multiplier where it is added
+    # to the stream. The fourth, the logits divided by ``logits_scaling``, is
+    # logit_scale (its inverse). "H": the ssm_* fields above size its mixer;
+    # both: mlp_dim is the dense SwiGLU's width
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
 
     def __post_init__(self):
         object.__setattr__(self, "mrope_section", tuple(self.mrope_section))
@@ -696,16 +742,44 @@ class LlamaConfig:
                 "shared_experts ungated shared experts of shared_mlp_dim "
                 "(shared_combine 'average'): every built layer is one of the "
                 "two, and it has no QK-norm, identity expert or weight scale")
+        hybrid = set(self.kinds) & set(HYBRID_KINDS)
+        if hybrid and not (
+                set(self.kinds) <= set(HYBRID_KINDS) and self.mlp_dim
+                and ("H" not in hybrid or (
+                    self.ssm_heads and self.ssm_head_dim and self.ssm_state
+                    and self.ssm_conv > 1
+                    and self.ssm_heads % self.ssm_groups == 0))
+                and not self.num_experts and not self.qk_norm
+                and self.attention_multiplier >= 0
+                and self.n_heads % self.n_kv_heads == 0):
+            raise ValueError(
+                "an 'H' / 'N' layer is a Mamba-2 mixer (ssm_heads, "
+                "ssm_head_dim, ssm_state, ssm_conv, ssm_groups that divide "
+                "the heads) or unrotated GQA (n_heads on n_kv_heads, scores "
+                "times attention_multiplier), then a dense SwiGLU of mlp_dim: "
+                "every built layer is one of the two, and it has no expert "
+                "and no QK-norm")
+        if not hybrid and (
+                self.embedding_multiplier != 1.0 or self.attention_multiplier
+                or self.residual_multiplier != 1.0):
+            raise ValueError(
+                f"embedding_multiplier={self.embedding_multiplier}, "
+                f"attention_multiplier={self.attention_multiplier}, "
+                f"residual_multiplier={self.residual_multiplier}: only a "
+                "stack of 'H' / 'N' layers reads them (no test holds another "
+                "kind to a reference with any of them)")
         if not parallel and (
                 self.norm_kind != "rms" or self.rope_interleaved
-                or self.shared_experts != 1 or self.logit_scale != 1.0
+                or self.shared_experts != 1
+                or (self.logit_scale != 1.0 and not hybrid)
                 or self.shared_combine != "average"):
             raise ValueError(
                 f"norm_kind={self.norm_kind!r}, rope_interleaved="
                 f"{self.rope_interleaved}, shared_experts="
                 f"{self.shared_experts}, shared_combine="
                 f"{self.shared_combine!r}, logit_scale={self.logit_scale}: "
-                "only a stack of 'P' / 'R' layers reads them (no test holds "
+                "only a stack of 'P' / 'R' layers reads them, and one of "
+                "'H' / 'N' layers logit_scale (no test holds "
                 "another kind to a reference with any of them)")
         if blocks and (
                 not (self.num_experts and self.experts_per_token)
@@ -748,11 +822,19 @@ class LlamaConfig:
 
     @property
     def layer_types(self):
-        """The whole pattern as a published config of window and full
-        attention layers names them."""
-        return ["sliding_attention" if kind in WINDOW_KINDS
-                else "full_attention"
+        """The whole pattern as a published config names its layers: window
+        and full attention layers, or a state-space hybrid's ``mamba`` and
+        ``attention`` ones ("H" / "N")."""
+        hybrid = {"H": "mamba", "N": "attention"}
+        return [hybrid.get(kind, "sliding_attention" if kind in WINDOW_KINDS
+                           else "full_attention")
                 for kind in self.layer_pattern]
+
+    @property
+    def logits_scaling(self) -> float:
+        """What a published config DIVIDES the logits by: ``logit_scale``'s
+        inverse."""
+        return 1.0 / self.logit_scale
 
     @property
     def full_attention_interval(self):
@@ -865,6 +947,12 @@ class LlamaConfig:
                 + self.lin_value_dim + vd * d + routed)
             per_kind["A"] = (2 * d * q + 2 * d * kv + q * d
                              + 2 * self.head_dim + routed)
+            # the hybrid's whole blocks: the "M" mixer's leaves or plain GQA
+            # (each with ONE of the block's two norms), then the dense SwiGLU
+            # and the other norm
+            dense = 3 * d * self.mlp_dim + d
+            per_kind["H"] = per_kind["M"] + dense
+            per_kind["N"] = per_kind["*"] + dense
             if set(self.kinds) & set("S" + LATENT_KINDS):
                 rq, rkv, h = self.q_lora_rank, self.kv_lora_rank, self.n_heads
                 qk = self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -1023,6 +1111,17 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             norm=("layers", None), shared_gate=("layers", "embed", "mlp"),
             shared_up=("layers", "embed", "mlp"),
             shared_down=("layers", "mlp", "embed"))
+        # the hybrid's whole blocks: the "M" mixer's leaves or the block's
+        # attention, then the dense block's MLP
+        dense = {"attn_norm": ("layers", None), "mlp_norm": ("layers", None),
+                 "w_gate": ("layers", "embed", "mlp"),
+                 "w_up": ("layers", "embed", "mlp"),
+                 "w_down": ("layers", "mlp", "embed")}
+        kinds["hybrid_mamba"] = dict(
+            {w: ax for w, ax in kinds["mamba"].items() if w != "norm"},
+            **dense)
+        kinds["hybrid_attn"] = dict(
+            {w: kinds["block"][w] for w in ("wq", "wk", "wv", "wo")}, **dense)
         if cfg.hc_mult > 1:  # a sublayer's mix: small, whole on every device
             for kind in ("latent", "latent_dense"):
                 kinds[kind].update(hc_phi=("layers", None, None, None),
@@ -1203,6 +1302,9 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
             out[LAYER_KINDS[c]] = _init_latent_kind(cfg, c, n[c], key)
     if n["P"] + n["R"]:
         out["parallel"] = _init_parallel(cfg, n["P"] + n["R"], key)
+    for c in HYBRID_KINDS:
+        if n[c]:
+            out[LAYER_KINDS[c]] = _init_hybrid(cfg, c, n[c], key)
     return out
 
 
@@ -1366,13 +1468,54 @@ def _init_parallel(cfg: LlamaConfig, L: int, key):
     }
 
 
+def _init_hybrid(cfg: LlamaConfig, kind: str, L: int, key):
+    """The ``L`` stacked layers of kind ``"H"`` or ``"N"``, keys of their
+    own a kind. ``"H"``: :func:`ray_tpu.ops.ssm.init_mamba2`'s leaves (its
+    ``norm`` is the block's ``attn_norm``) with :data:`HYBRID_INIT`'s time
+    steps, decay rates and convolution bias; ``"N"``: the block's attention,
+    ``wq`` and ``wo`` at HYBRID_INIT's widths; both: the dense SwiGLU over
+    the square root of its fan-in and the norms at one."""
+    from ray_tpu.ops.ssm import init_mamba2
+
+    d, hd, f = cfg.dim, cfg.head_dim, cfg.mlp_dim
+    k = iter(jax.random.split(
+        jax.random.fold_in(key, 12 + HYBRID_KINDS.index(kind)), 10))
+    dense = _dense_init
+    out = {"attn_norm": jnp.ones((L, d), jnp.float32),
+           "mlp_norm": jnp.ones((L, d), jnp.float32),
+           "w_gate": dense(next(k), (L, d, f), d),
+           "w_up": dense(next(k), (L, d, f), d),
+           "w_down": dense(next(k), (L, f, d), f)}
+    if kind == "N":
+        nq, nkv = cfg.n_heads, cfg.n_kv_heads
+        out.update(
+            wq=HYBRID_INIT["wq"] * dense(next(k), (L, d, nq * hd), d),
+            wk=dense(next(k), (L, d, nkv * hd), d),
+            wv=dense(next(k), (L, d, nkv * hd), d),
+            wo=HYBRID_INIT["wo"] * dense(next(k), (L, nq * hd, d), nq * hd))
+        return out
+    (dt_lo, dt_hi), (a_lo, a_hi) = HYBRID_INIT["dt"], HYBRID_INIT["A"]
+    mixer = init_mamba2(
+        next(k), L, d, heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+        groups=cfg.ssm_groups, state=cfg.ssm_state, conv=cfg.ssm_conv,
+        dt_min=dt_lo, dt_max=dt_hi, dt_floor=cfg.ssm_dt_floor)
+    del mixer["norm"]
+    mixer["A_log"] = jnp.log(jax.random.uniform(
+        next(k), mixer["A_log"].shape, jnp.float32, a_lo, a_hi))
+    mixer["conv_b"] = HYBRID_INIT["conv_b"] * jax.random.normal(
+        next(k), mixer["conv_b"].shape, jnp.float32)
+    return {**out, **mixer}
+
+
 def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
     d, hd = cfg.dim, cfg.head_dim
     nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
     dense = _dense_init
     if cfg.layer_pattern:
         k_emb, k_head, k_layers = jax.random.split(key, 3)
-        params = {"embedding": dense(k_emb, (cfg.vocab_size, d), d),
+        wide = (HYBRID_INIT["embedding"]
+                if set(cfg.kinds) & set(HYBRID_KINDS) else 1.0)
+        params = {"embedding": wide * dense(k_emb, (cfg.vocab_size, d), d),
                   "layers": _init_pattern_layers(cfg, k_layers),
                   "final_norm": (jnp.zeros if cfg.zero_centered
                                  else jnp.ones)((d,), jnp.float32)}
@@ -2830,11 +2973,10 @@ def _delta_tail(last, qkv, K: int):
     """The convolution's tail at ``last`` (None: the last position): the
     ``K - 1`` rows of ``qkv`` [B, T, width] that end there, zeros before the
     sequence's first."""
-    T = qkv.shape[1]
+    from ray_tpu.ops.ssm import conv_tail
+
     with jax.named_scope("gdn.conv"):
-        at = (T - 1 if last is None else last) - (K - 2) + jnp.arange(K - 1)
-        return jnp.where((at >= 0)[None, :, None],
-                         qkv[:, jnp.maximum(at, 0)], 0)
+        return conv_tail(qkv, K, last)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -3012,6 +3154,138 @@ def attend_parallel_tiles(cfg: LlamaConfig, kind: str, q, k, v):
                         else "parallel_full")
 
 
+def hybrid_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
+                 attend, stat_axes=()):
+    """THE state-space hybrid's whole block of the kinds ``"H"`` and
+    ``"N"``, for the full forward and for the serving programs (``r``:
+    ``cfg.residual_multiplier``; ``N``: RMSNorm, float32 statistics)::
+
+        a   = N(x; attn_norm)
+        "H" [z | xBC | dt] = a W_in                  ops/ssm.py project_in
+            y, state, tail = attend(xBC, dt, the layer's conv_w, conv_b,
+                                    dt_bias, A_log, D)
+            m = (N_d_inner(y * silu(z)) * gate_norm) W_out        gate_out
+        "N" q, k, v = a Wq, a Wk, a Wv               no bias, NO rotation
+            m = attend(q, k, v) Wo      softmax(attention_multiplier q k^T)
+        h   = x + r m
+        out = h + r SwiGLU(N(h; mlp_norm))           the dense MLP
+
+    ``attend`` is all that knows where the call stands in its sequence. For
+    ``"H"``: the causal convolution, ``silu``, the split and the scan over
+    the call's own positions from an empty state (:func:`attend_ssm`:
+    ``ops/ssm.py scan_positions``) or one token on from a kept state and
+    tail (:func:`_attend_ssm_state`: ``ops/ssm.py step``); it returns ``y``
+    [B, T, H, P] float32 and what a cache keeps A SEQUENCE: the state after
+    the last real position ``[B, 1, H, P, N]`` float32 and the convolution's
+    tail, the last ``ssm_conv - 1`` rows of ``xBC`` ``[B, 1, ssm_conv - 1,
+    width]``. For ``"N"``: ``attend(q, k, v)`` as :func:`decoder_block`
+    takes it, but ``q`` comes in float32 and UNSCALED: the attend scales it
+    for the width ITS product divides by and rounds it once
+    (:func:`_hybrid_query`). ``layers``: the kind's stack ``[L, ...]``,
+    ``i``: which layer (a number, or traced in a scan). The stream keeps the
+    type it comes in (float32 in the serving programs). Device scopes
+    ``ssm.in_proj``, ``ssm.conv``, ``ssm.scan`` / ``ssm.step``,
+    ``ssm.gate_norm``, ``ssm.out_proj`` (ops/ssm.py's, the trainer's names);
+    ``hyb.qkv``, ``attn.full``, ``hyb.out``; ``hyb.mlp``. Returns ``(x, {},
+    (state, tail))`` or ``(x, {}, (k, v))``."""
+    from ray_tpu.ops import ssm
+
+    cd, eps, r = cfg.dtype, cfg.norm_eps, cfg.residual_multiplier
+    B, T, _ = x.shape
+    a = rms_norm(x, layers["attn_norm"][i], eps).astype(cd)
+    if kind == "H":
+        z, xbc, dt = ssm.project_in(a, layers["w_in"][i], cfg.ssm_heads,
+                                    cfg.ssm_heads * cfg.ssm_head_dim)
+        y, *kept = attend(xbc, dt, {w: layers[w][i] for w in (
+            "conv_w", "conv_b", "dt_bias", "A_log", "D")})
+        m = ssm.gate_out(y, z, layers["gate_norm"][i], layers["w_out"][i],
+                         groups=cfg.ssm_groups, eps=eps)
+    else:
+        hd = cfg.head_dim
+        with jax.named_scope("hyb.qkv"):
+            q = jnp.dot(a, layers["wq"][i].astype(cd),
+                        preferred_element_type=jnp.float32
+                        ).reshape(B, T, -1, hd)
+            k, v = ((a @ layers[w][i].astype(cd)).reshape(B, T, -1, hd)
+                    for w in ("wk", "wv"))
+        with jax.named_scope("attn.full"):
+            o = attend(q, k, v)
+        with jax.named_scope("hyb.out"):
+            m = o.reshape(B, T, -1) @ layers["wo"][i].astype(cd)
+        kept = (k, v)
+    h = x + r * m.astype(x.dtype)
+    with jax.named_scope("hyb.mlp"):
+        u = rms_norm(h, layers["mlp_norm"][i], eps).astype(cd)
+        y = _dense_mlp(cfg, {w: layers[w][i] for w in (
+            "w_gate", "w_up", "w_down")}, u)
+    return h + r * y.astype(x.dtype), {}, tuple(kept)
+
+
+def ssm_prefill_path(cfg: LlamaConfig, xbc, dt) -> Tuple[str, str]:
+    """``(path, reason)`` :func:`attend_ssm` takes for these operands in
+    this process (:func:`delta_prefill_path`'s sibling): ``"chunks"``
+    everywhere today. The rule is where a fused scan's conditions would
+    stand."""
+    return "chunks", ("ops/ssm.py has no kernel: the chunked scan in XLA on "
+                      f"every backend (this one: {jax.default_backend()!r})")
+
+
+def attend_ssm(cfg: LlamaConfig, last, xbc, dt, p):
+    """:func:`hybrid_block`'s ``"H"`` ``attend`` over the call's own
+    positions from an empty state (the full forward and prefill):
+    ``ops/ssm.py scan_positions``, ``SEGMENT`` positions at a time where a
+    prompt is longer, the positions behind ``last`` identity updates, the
+    tail taken at ``last``. Counted as kind ``ssm`` where
+    :func:`attend_tiles`' kinds are (:func:`ssm_prefill_path`)."""
+    from ray_tpu.ops import ssm
+
+    path, reason = ssm_prefill_path(cfg, xbc, dt)
+    _note_prefill_attend("ssm", xbc, dt, 0, path, reason, "chunks")
+    y, state, tail = ssm.scan_positions(
+        xbc, dt, p, last=last, segment=ssm.SEGMENT, heads=cfg.ssm_heads,
+        head_dim=cfg.ssm_head_dim, groups=cfg.ssm_groups,
+        state=cfg.ssm_state, chunk=cfg.ssm_chunk)
+    return y, state[:, None], tail[:, None]
+
+
+def _attend_ssm_state(cfg: LlamaConfig, call, l, mine, xbc, dt, p):
+    """A decode call's token through its layer's kept state and tail
+    (``mine``: the views ``[1, *row]`` of the page that holds position ``pos
+    - 1``, the batch's one sequence): ``ops/ssm.py step``, and what the page
+    that holds ``pos`` keeps. A sequence's first position starts from zeros
+    whatever its page held."""
+    from ray_tpu.ops import ssm
+
+    state, tail = (jnp.where(call.pos > 0, a, 0) for a in mine)
+    y, state, tail = ssm.step(xbc, dt, p, state, tail, groups=cfg.ssm_groups)
+    return y, state[:, None], tail[:, None]
+
+
+def _hybrid_query(cfg: LlamaConfig, q, width: int):
+    """An ``"N"`` layer's float32 query for a product that divides its
+    scores by ``sqrt(width)``: times what makes the scale
+    ``cfg.attention_multiplier`` (0: ``1 / sqrt(head_dim)``), rounded to
+    ``cfg.dtype`` ONCE. At the published 1/64 on heads 64 wide that is 0.125
+    in front of a product that divides by 8: exact."""
+    scale = cfg.attention_multiplier or 1.0 / math.sqrt(cfg.head_dim)
+    return (q * (scale * math.sqrt(width))).astype(cfg.dtype)
+
+
+def attend_hybrid_tiles(cfg: LlamaConfig, q, k, v):
+    """:func:`hybrid_block`'s ``"N"`` ``attend`` over the call's own
+    positions (the full forward and prefill): :func:`attend_tiles`, counted
+    under a kind of its own. A head narrower than the 128 lanes prefill's
+    kernel takes is filled up with zeros on every backend (they add nothing
+    to a score and their value columns are cut off again), so that a TPU
+    backend runs the kernel: the scale then counts the filled width."""
+    D = q.shape[-1]
+    fill = [(0, 0)] * 3 + [(0, -D % 128)]
+    q = _hybrid_query(cfg, q, D + fill[-1][1])
+    o = attend_tiles(*(jnp.pad(a, fill) for a in (q, k, v)), cfg.dtype,
+                     kind="hybrid")
+    return o[..., :D]
+
+
 def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     """One layer of a patterned stack (``cfg.layer_pattern``). A kind the
     decode engine serves (a row of :data:`SERVED`: ``"S"``, ``"F"`` /
@@ -3137,6 +3411,8 @@ def embed_tokens(cfg, params, tokens, mesh=None, table_sharded=None):
         hot = constraint(hot, ("batch", "seq", "vocab"), mesh)
         x = jnp.einsum("btv,vd->btd", hot, emb,
                        preferred_element_type=jnp.float32).astype(cfg.dtype)
+    if cfg.embedding_multiplier != 1.0:  # an 'H' / 'N' stack's alone
+        x = x * cfg.embedding_multiplier
     if mesh is not None:
         from ray_tpu.parallel.sharding import constraint
 
@@ -3805,6 +4081,37 @@ SERVED: Dict[str, Served] = {
         lambda cfg, call, l, mine, *a: _attend_grouped(
             cfg, "parallel_window", *mine, call.pos - call.base, *a,
             lowest=call.pos - cfg.window + 1 - call.base), alone=True),
+    # the state-space hybrid's whole blocks (hybrid_block), a stack a kind.
+    # "H" (Mamba-2) keeps NO row a position: its state and its convolution's
+    # tail, a SEQUENCE, by the table rule "state" (as "D"). "N" (unrotated
+    # GQA) keeps keys and values by page id; decode scores a KV head's query
+    # heads against its keys as ONE product (_attend_grouped). The stream is
+    # float32 though no router makes a hard choice here: eighty sublayers
+    # each add residual_multiplier of a vector to a stream
+    # embedding_multiplier embeddings wide, and in bfloat16 every add rounds
+    # the WHOLE stream (a hundredth of it by the end), which is the size of
+    # the faults a comparison of logits has to see. A stack of state-space
+    # layers alone is served too (a cell's rehearsal cuts the pattern inside
+    # its first period)
+    "H": Served(
+        "hybrid", "hybrid_mamba",
+        lambda cfg, *a: hybrid_block(cfg, "H", *a), True,
+        lambda cfg: [
+            ("ssm_state", 1, (cfg.ssm_heads, cfg.ssm_head_dim,
+                              cfg.ssm_state), "state"),
+            ("ssm_conv", 1, (cfg.ssm_conv - 1,
+                             cfg.ssm_heads * cfg.ssm_head_dim
+                             + 2 * cfg.ssm_groups * cfg.ssm_state),
+             "state")],
+        attend_ssm, _attend_ssm_state, alone=True),
+    "N": Served(
+        "hybrid", "hybrid_attn",
+        lambda cfg, *a: hybrid_block(cfg, "N", *a), True,
+        partial(_kv_rows, tag="hybrid"),
+        lambda cfg, last, *a: attend_hybrid_tiles(cfg, *a),
+        lambda cfg, call, l, mine, q, *a: _attend_grouped(
+            cfg, "hybrid", *mine, call.pos,
+            _hybrid_query(cfg, q, cfg.head_dim), *a)),
 }
 
 
@@ -3906,9 +4213,8 @@ def _engine_lacks(cfg: LlamaConfig) -> Optional[str]:
             f"{' | '.join(families)}), all of them (both full "
             f"and window layers), and this stack has "
             f"{' '.join(sorted(kinds))}, of which the table lacks "
-            f"{' '.join(sorted(kinds - set(SERVED))) or 'none'} (the "
-            f"'M' mixer has no row: its state would go by the 'state' "
-            f"rule the 'D' layers use); for the 'E' / '*' "
+            f"{' '.join(sorted(kinds - set(SERVED))) or 'none'}; for the "
+            f"'M' / 'E' / '*' "
             f"halves, a part or a mix of families, whole-projection "
             f"QK-norm or an unpatterned routed block no test compares "
             f"its logits with the reference")
@@ -3948,6 +4254,21 @@ REFUSED: Dict[str, Refused] = {
          "make_train_step":
          "no train step is held to a reference for the parallel block's "
          "backward; models.llama.loss_fn runs its forward",
+         "make_pipeline_train_step":
+         "its stages run the dense block alone"}),
+    "hybrid": Refused(
+        _has(HYBRID_KINDS),
+        lambda cfg: (f"no 'H' / 'N' layer (layer_pattern="
+                     f"{cfg.layer_pattern!r}; embedding_multiplier, "
+                     f"attention_multiplier, residual_multiplier)"),
+        {"make_spmd_train_step":
+         "no train step is held to a reference for the hybrid blocks' "
+         "backward or their three multipliers' (the Mamba-2 mixer's own is "
+         "held as the 'M' half); models.llama.loss_fn runs the forward",
+         "make_train_step":
+         "no train step is held to a reference for the hybrid blocks' "
+         "backward or their three multipliers'; models.llama.loss_fn runs "
+         "the forward",
          "make_pipeline_train_step":
          "its stages run the dense block alone"}),
     # a stream of several rows a token (``hc_mult > 1``), and a latent block
@@ -4157,7 +4478,7 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
     return x, rows, shares
 
 
-def prefill_with_cache(cfg: LlamaConfig, params, *args):
+def prefill_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
     """Prefill one sequence into its pages, inside the program: embed, the
     layers (:func:`_serve_layers`), each store written through ITS table,
     the head. With ``cfg.hc_mult > 1`` the stream between embedding and
@@ -4165,6 +4486,8 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
     (:func:`widen_stream`: copies of the embedding), and the rows of the ONE
     position the head reads are summed (:func:`collapse_stream`).
 
+    ``page_size``: a page's positions where no store says them (a stack
+    whose every store keeps a row a SEQUENCE); the engine binds it.
     ``args``: ``*stores, tokens, page_ids, last`` and, for a stack with a
     store by slot (window layers), ``slot_ids`` [min(n, k)] int32 behind
     them: the slots of the LAST pages, the only ones whose window rows are
@@ -4187,7 +4510,7 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
     if any(s.table == "slot" for s in layout):
         *args, slot_ids = args
     *stores, tokens, page_ids, last = args
-    ps = _page_size(stores, layout)
+    ps = _page_size(stores, layout, page_size)
     ids = {"page": page_ids, "slot": slot_ids}
     x = widen_stream(cfg, embed_tokens(cfg, params, tokens, None))
     positions = positions_of(*tokens.shape)
@@ -4209,7 +4532,7 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args):
     return (*stores, logits[0, 0], shares)
 
 
-def decode_step_with_cache(cfg: LlamaConfig, params, *args):
+def decode_step_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
     """One decode step of one sequence against the page stores: each
     store gathered through ITS table where its kind's row says so
     (``SERVED[c].decode``), embed, the layers (:func:`_serve_layers`), the
@@ -4238,7 +4561,7 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
         *args, slot_ids, first = args
     *stores, token, pos, page_ids = args
     ids = {"page": page_ids, "slot": slot_ids}
-    ps = _page_size(stores, layout)
+    ps = _page_size(stores, layout, page_size)
     if any(s.table == "state" for s in layout):
         # the state BEFORE this position: the page of the one before it
         ids["state"] = page_ids[jnp.maximum(pos - 1, 0) // ps][None]
@@ -4266,10 +4589,12 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args):
     return (*stores, logits[0, 0])
 
 
-def _page_size(stores, layout) -> int:
-    """A page's positions, read off a store that keeps a row a position."""
-    return next(pages.shape[2] for pages, s in zip(stores, layout)
-                if TABLES[s.table].rows is None)
+def _page_size(stores, layout, given=None) -> int:
+    """A page's positions, read off a store that keeps a row a position;
+    ``given`` (the programs' ``page_size``, which the engine binds) where
+    every store keeps a row a SEQUENCE and none says it."""
+    return next((pages.shape[2] for pages, s in zip(stores, layout)
+                 if TABLES[s.table].rows is None), given)
 
 
 def copy_page_in_stores(stores, src, dst):
@@ -4299,6 +4624,10 @@ _MATMUL_LAYER = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                  # (its gate's vector w_sg, A_log, dt_bias and the
                  # convolution are used in float32)
                  "w_qkvz", "w_ba", "w_out", "ws_gate", "ws_up", "ws_down",
+                 # the Mamba-2 mixer's in-projection (w_out is the delta
+                 # rule's name too; its convolution and bias, dt_bias, A_log,
+                 # D and the gated norm are used in float32)
+                 "w_in",
                  # the latent block's ungated shared expert (a sublayer's
                  # hc_phi is used in float32, as a router is)
                  "shared_gate", "shared_up", "shared_down")
@@ -4344,9 +4673,10 @@ class LlamaDecodeEngine:
     kinds are ONE family's, all of them (dense blocks; all ``"S"``; ``"F"``
     with ``"W"``; all ``"I"``; ``"D"`` with ``"A"``; ``"L"`` with ``"G"``,
     whose stream may be ``hc_mult`` rows a token:
-    ``ray_tpu_serve_engine_stream_bytes``). A kind without a row
-    (the ``"M"`` mixer, whose state would go by the ``"state"`` rule; the
-    ``"E"`` / ``"*"`` halves), a part or a mix of families, whole-projection
+    ``ray_tpu_serve_engine_stream_bytes``; ``"P"`` with ``"R"``; ``"H"``
+    with ``"N"``). A kind without a row
+    (the ``"M"`` / ``"E"`` / ``"*"`` halves), a part or a mix of families,
+    whole-projection
     QK-norm, the UNPATTERNED routed block and a prediction module
     (``mtp_layers``) are refused: no test holds their logits to a reference
     here.
@@ -4511,11 +4841,13 @@ class LlamaDecodeEngine:
                           if SERVED[c].attended]
         donated = tuple(range(1, 1 + len(layout)))  # the stores, every call
         self._prefill_fn = observe_compiled(
-            jax.jit(partial(prefill_with_cache, self.cfg),
+            jax.jit(partial(prefill_with_cache, self.cfg,
+                            page_size=self.page_size),
                     donate_argnums=donated),
             "llama.prefill")
         self._decode_fn = observe_compiled(
-            jax.jit(partial(decode_step_with_cache, self.cfg),
+            jax.jit(partial(decode_step_with_cache, self.cfg,
+                            page_size=self.page_size),
                     donate_argnums=donated),
             "llama.decode")
         self._copy_fn = observe_compiled(

@@ -607,9 +607,10 @@ def test_engine_refuses_a_mix_of_families(params):
     with pytest.raises(ValueError, match="every built layer is one of"):
         program_cfg(layer_pattern="RRRW" * 2)
     # "F" / "W" still want both of theirs: only these rows say ``alone``
+    # (and, since PR 58, the state-space layers' "H")
     assert llama.SERVED["P"].alone and llama.SERVED["R"].alone
     assert not any(kind.alone for c, kind in llama.SERVED.items()
-                   if c not in "PR")
+                   if c not in "PRH")
 
 
 # --- (d) the benchmark's files ---------------------------------------------- #

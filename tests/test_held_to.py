@@ -50,6 +50,11 @@ OLD = {
     "_no_parallel_kinds": lambda cfg: (
         _kinds(cfg, "PR"),
         f"no 'P' / 'R' layer (layer_pattern={cfg.layer_pattern!r})"),
+    # PR 58's row, which never was a function: the table's own words
+    "_no_hybrid_kinds": lambda cfg: (
+        _kinds(cfg, "HN"),
+        f"no 'H' / 'N' layer (layer_pattern={cfg.layer_pattern!r}; "
+        f"embedding_multiplier, attention_multiplier, residual_multiplier)"),
 }
 
 
@@ -70,9 +75,8 @@ def _engine_dense_only(cfg):
             f"{' | '.join(families.values())}), all of them (both full "
             f"and window layers), and this stack has "
             f"{' '.join(sorted(kinds))}, of which the table lacks "
-            f"{' '.join(sorted(kinds - set(llama.SERVED))) or 'none'} (the "
-            f"'M' mixer has no row: its state would go by the 'state' "
-            f"rule the 'D' layers use); for the 'E' / '*' "
+            f"{' '.join(sorted(kinds - set(llama.SERVED))) or 'none'}; for the "
+            f"'M' / 'E' / '*' "
             f"halves, a part or a mix of families, whole-projection "
             f"QK-norm or an unpatterned routed block no test compares "
             f"its logits with the reference")
@@ -94,6 +98,10 @@ CALLS = {
          "no train step is held to a reference for the parallel block's "
          "backward, and its flash kernel has no window; "
          "models.llama.loss_fn runs the forward through attend_tiles"),
+        ("_no_hybrid_kinds",
+         "no train step is held to a reference for the hybrid blocks' "
+         "backward or their three multipliers' (the Mamba-2 mixer's own is "
+         "held as the 'M' half); models.llama.loss_fn runs the forward"),
         ("_no_wide_latent",
          "no train step is held to a reference for the mixes' backward or "
          "keeps a stream of several rows' recomputation in its account, and "
@@ -103,6 +111,10 @@ CALLS = {
         ("_no_parallel_kinds",
          "no train step is held to a reference for the parallel block's "
          "backward; models.llama.loss_fn runs its forward"),
+        ("_no_hybrid_kinds",
+         "no train step is held to a reference for the hybrid blocks' "
+         "backward or their three multipliers'; models.llama.loss_fn runs "
+         "the forward"),
         ("_no_wide_latent",
          "no train step is held to a reference for the mixes' backward, and "
          "its flash kernel attends q, k and v of one width")],
@@ -115,6 +127,7 @@ CALLS = {
          "second loss needs the last stage's stream AND the first stage's "
          "embedding"),
         ("_no_parallel_kinds", "its stages run the dense block alone"),
+        ("_no_hybrid_kinds", "its stages run the dense block alone"),
         ("_no_window_kinds",
          "its stages run the dense block over the flash kernel, which has "
          "no window, and pass no router's losses on"),
@@ -161,6 +174,9 @@ CONFIGS = {
     "F W (smallthinker)": _of("test_smallthinker"),
     "D A (qwen3-next)": _of("test_qwen3_next"),
     "P R (command-a-plus)": _of("test_command_a_plus"),
+    "H N (granite-4.0-h)": _of("test_granite_hybrid"),
+    "H alone": lambda: __import__("test_granite_hybrid").program_cfg(
+        num_hidden_layers=2),
     "L G and the module (glm)": _of("test_glm47_flash"),
     "L G, one row a token": _glm,
     "L G in part": lambda: _glm(layer_pattern="LLL"),

@@ -598,7 +598,7 @@ def test_scheduler_drives_the_latent_engine(engine):
 
 @pytest.mark.parametrize("cfg,why", [
     (dict(layer_pattern="M*", n_layers=2, ssm_heads=2, ssm_head_dim=8,
-          ssm_state=8), "'M' mixer has no row"),
+          ssm_state=8), r"the table lacks \* M; for the 'M' / 'E'"),
     (dict(qk_norm=True), "QK-norm")])
 def test_engine_refuses_what_it_does_not_serve(cfg, why):
     with pytest.raises(NotImplementedError, match=why):

@@ -61,12 +61,13 @@ def test_ssd_scan_is_the_recurrence_whatever_the_chunk():
                  + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None, :])
         return state, jnp.einsum("bhpn,bhn->bhp", state, c_t)
 
-    _, want = jax.jit(lambda *now: jax.lax.scan(
+    after, want = jax.jit(lambda *now: jax.lax.scan(
         step, jnp.zeros((2, 8, 16, 16)), jax.tree.map(
             lambda v: jnp.moveaxis(v, 1, 0), now)))(x, dt, b_in, c_in)
-    for got in by_chunk:
+    for got, state in by_chunk:  # the outputs, and the state it ends in
         np.testing.assert_allclose(got, jnp.moveaxis(want, 0, 1), rtol=1e-4,
                                    atol=1e-5)
+        np.testing.assert_allclose(state, after, rtol=1e-4, atol=1e-5)
 
 
 def test_attention_layer_without_rotation_matches_reference(params):

@@ -663,6 +663,23 @@ SERVED_PROGRAMS = {
     "I decode past topk, 3 pages": (
         "c7e009f784b970ab5b7b622f460a7f01bacdea6c8f33df65a565f5482c53371f",
         lambda: _family_text("keye_vl2", "decode", 3, 24, 4)),
+    # the state-space hybrid ("H" with "N"; tests/test_granite_hybrid.py),
+    # taken on the PR that brought it (58): a period of TEN layers, in line
+    # at 12 layers and scanned at 22 (two periods, a rest of two in line)
+    "HN in line, prefill, 2 pages": (
+        "47c5f2d51f5e3c29b19bd964004cbb62afc87414ad05e1b261bffd9a562e4cad",
+        lambda: _family_text("granite_hybrid", "prefill", 2, 12, 8)),
+    "HN in line, decode, 2 pages": (
+        "c698811845aca190a8cdade4655e71f9e2eb384be8fe8e85fffe9a0455c851d6",
+        lambda: _family_text("granite_hybrid", "decode", 2, 12, 8)),
+    "HN scanned, prefill, 2 pages": (
+        "142364806268c7417fe6f0b01ff7f77c32fad8cb2c139269f31ffd4664fe34ce",
+        lambda: _family_text("granite_hybrid", "prefill", 2, 12, 8,
+                             num_hidden_layers=22)),
+    "HN scanned, decode, 2 pages": (
+        "efadb937919cec61ab2b2519ad03a96bc5485eed93f6bd0f3c3825c7874209b5",
+        lambda: _family_text("granite_hybrid", "decode", 2, 12, 8,
+                             num_hidden_layers=22)),
 }
 
 
@@ -720,7 +737,8 @@ def test_the_engine_is_what_the_table_folds(family, n_pages, page, held):
     assert _gauge("ray_tpu_serve_engine_page_bytes") == want
     # the literal set of tags, in THIS place alone: a new kind adds its own
     assert set(want) == {"kv", "latent", "full", "window", "index", "gated",
-                         "latent_block", "parallel_full", "parallel_window"}
+                         "latent_block", "parallel_full", "parallel_window",
+                         "hybrid"}
     # these families keep nothing a SEQUENCE (tests/test_qwen3_next.py)
     assert set(_gauge("ray_tpu_serve_engine_state_bytes").values()) == {0.0}
     for part in ("held", "zero", "elsewhere"):
@@ -738,10 +756,10 @@ def test_the_engine_is_what_the_table_folds(family, n_pages, page, held):
 
 @pytest.mark.parametrize("keys,lacks", [
     (dict(layer_pattern="M*", n_layers=2, ssm_heads=2, ssm_head_dim=8,
-          ssm_state=8), r"lacks \* M \("),
+          ssm_state=8), r"lacks \* M; for"),
     (dict(layer_pattern="E*", n_layers=2, num_experts=4,
-          experts_per_token=2), r"lacks \* E \("),
-    ("a mix", r"has F S W, of which the table lacks none \(")])
+          experts_per_token=2), r"lacks \* E; for"),
+    ("a mix", r"has F S W, of which the table lacks none; for")])
 def test_the_refusal_names_the_kinds_the_table_lacks(keys, lacks):
     if keys == "a mix":  # one family's kinds with another's
         import test_smallthinker

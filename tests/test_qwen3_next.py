@@ -343,8 +343,8 @@ def test_the_engine_refuses_a_mix_and_a_part():
         program_cfg(), layer_pattern="DDDADDDA")
     llama.LlamaDecodeEngine(mix, n_pages=4, page_size=PAGE)  # the family
     with pytest.raises(NotImplementedError,
-                       match=r"has A, of which the table lacks none \(the "
-                             r"'M' mixer has no row"):
+                       match=r"has A, of which the table lacks none; for "
+                             r"the 'M' / 'E' / '\*' halves"):
         llama.LlamaDecodeEngine(
             dataclasses.replace(program_cfg(), layer_pattern="AAAAAAAA"),
             n_pages=4, page_size=PAGE)
@@ -381,7 +381,8 @@ def test_the_state_goes_by_its_table_rule(params):
         (2, 12, PAGE, 2, 16)]
     assert engine.n_slots == 0
     assert _gauge("ray_tpu_serve_engine_state_bytes") == {
-        "state": 4.0 * 6 * 4 * 8 * 8, "conv": 4.0 * 6 * 3 * 64}
+        "state": 4.0 * 6 * 4 * 8 * 8, "conv": 4.0 * 6 * 3 * 64,
+        "ssm_state": 0.0, "ssm_conv": 0.0}  # every tag of the table, always
     page_bytes = _gauge("ray_tpu_serve_engine_page_bytes")
     assert page_bytes["gated"] == 2 * 4.0 * 2 * 2 * 16
     assert "state" not in page_bytes and "conv" not in page_bytes
