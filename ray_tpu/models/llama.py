@@ -3223,28 +3223,83 @@ def hybrid_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
 
 def ssm_prefill_path(cfg: LlamaConfig, xbc, dt) -> Tuple[str, str]:
     """``(path, reason)`` :func:`attend_ssm` takes for these operands in
-    this process (:func:`delta_prefill_path`'s sibling): ``"chunks"``
-    everywhere today. The rule is where a fused scan's conditions would
-    stand."""
-    return "chunks", ("ops/ssm.py has no kernel: the chunked scan in XLA on "
-                      f"every backend (this one: {jax.default_backend()!r})")
+    this process (:func:`delta_prefill_path`'s sibling): ``"kernel"`` on a
+    TPU backend for what ``ops/ssm_prefill.py`` takes (``xbc`` in
+    ``cfg.dtype`` and ``dt`` float32; ONE group; the state's width and
+    ``d_inner + 2 N`` whole 128-lane tiles; heads 64 or a multiple of 128
+    wide, eight of them a step of its loop; positions a multiple of its row
+    tile, one chunk of 128 or 256), ``"chunks"`` with what stands in the way
+    otherwise. Read from the backend and the shapes alone."""
+    platform = jax.default_backend()
+    if platform != "tpu":
+        return "chunks", f"backend is {platform!r}, not tpu"
+    from ray_tpu.ops.ssm_prefill import pick_rows
+
+    H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                  cfg.ssm_state)
+    types = [a.dtype.name for a in (xbc, dt)]
+    if types != [jnp.dtype(cfg.dtype).name, "float32"]:
+        return "chunks", (f"xBC, dt in {types}: not "
+                          f"{jnp.dtype(cfg.dtype).name} and float32")
+    if G != 1:
+        return "chunks", (f"{G} groups: the kernel reads ONE B and C a row "
+                          "tile for all heads")
+    if N % 128 or (H * P + 2 * G * N) % 128:
+        return "chunks", (f"state width {N} or xBC's {H * P + 2 * G * N} "
+                          "columns are no whole 128-lane tiles")
+    if not (P == 64 or P % 128 == 0) or H % 8:
+        return "chunks", (f"{H} heads of {P}: not 64 or a multiple of 128 "
+                          "wide (two heads, or one, fill a 128-lane block), "
+                          "or no multiple of 8 heads")
+    if pick_rows(xbc.shape[1], cfg.ssm_chunk) is None:
+        return "chunks", (f"{xbc.shape[1]} positions in chunks of "
+                          f"{cfg.ssm_chunk} are no multiple of a row tile "
+                          "of the kernel's (one chunk of 128 or 256)")
+    return "kernel", "tpu backend"
 
 
 def attend_ssm(cfg: LlamaConfig, last, xbc, dt, p):
     """:func:`hybrid_block`'s ``"H"`` ``attend`` over the call's own
-    positions from an empty state (the full forward and prefill):
-    ``ops/ssm.py scan_positions``, ``SEGMENT`` positions at a time where a
-    prompt is longer, the positions behind ``last`` identity updates, the
-    tail taken at ``last``. Counted as kind ``ssm`` where
-    :func:`attend_tiles`' kinds are (:func:`ssm_prefill_path`)."""
+    positions from an empty state (the full forward and prefill), the
+    positions behind ``last`` identity updates, the tail taken at ``last``.
+
+    Two paths, ONE arithmetic (:func:`ssm_prefill_path` says which and why;
+    counted as kind ``ssm``, path ``kernel`` or ``chunks``, where
+    :func:`attend_tiles`' kinds are). On a TPU backend one Pallas call,
+    forward only (``ops/ssm_prefill.py``, imported here and nowhere else;
+    its transpose is the XLA path's): the rows are read as the in-projection
+    wrote them, the state and the convolution's last rows stay in VMEM, and
+    the prompt is ONE piece whatever its pages. On every other backend, for
+    what the kernel does not take and as its oracle, ``ops/ssm.py
+    scan_positions`` in XLA, ``SEGMENT`` positions at a time where a prompt
+    is longer. Device scopes ``ssm.conv`` / ``ssm.scan``; the kernel runs
+    under ``ssm.scan``. The tail is XLA's gather of ``ssm_conv - 1`` rows of
+    ``xbc`` at ``last`` on both paths (``ops/ssm.py conv_tail``)."""
     from ray_tpu.ops import ssm
 
     path, reason = ssm_prefill_path(cfg, xbc, dt)
     _note_prefill_attend("ssm", xbc, dt, 0, path, reason, "chunks")
-    y, state, tail = ssm.scan_positions(
-        xbc, dt, p, last=last, segment=ssm.SEGMENT, heads=cfg.ssm_heads,
-        head_dim=cfg.ssm_head_dim, groups=cfg.ssm_groups,
-        state=cfg.ssm_state, chunk=cfg.ssm_chunk)
+    dims = dict(heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+                chunk=cfg.ssm_chunk)
+    if path == "kernel":
+        from ray_tpu.ops.ssm_prefill import ssm_prefill
+
+        (B, _, width), K = xbc.shape, p["conv_w"].shape[0]
+        with jax.named_scope("ssm.scan"):
+            # interpreted where a test has steered a CPU process onto this
+            # path
+            y, state = ssm_prefill(
+                xbc, dt, p,
+                jnp.zeros((B, cfg.ssm_heads, cfg.ssm_head_dim,
+                           cfg.ssm_state), jnp.float32),
+                jnp.zeros((B, K - 1, width), xbc.dtype), last,
+                interpret=jax.default_backend() != "tpu", **dims)
+        with jax.named_scope("ssm.conv"):
+            tail = ssm.conv_tail(xbc, K, last)
+    else:
+        y, state, tail = ssm.scan_positions(
+            xbc, dt, p, last=last, segment=ssm.SEGMENT,
+            groups=cfg.ssm_groups, state=cfg.ssm_state, **dims)
     return y, state[:, None], tail[:, None]
 
 

@@ -42,12 +42,16 @@ trainer) / ``ssm.step`` (decode), ``ssm.gate_norm``, ``ssm.out_proj``.
 No kernel HERE: the trainer's scan is a train cell's step, and the
 benchmark's ``flash_roofline`` (the train cells' alone) tells Pallas kernels
 apart by result type, so one more ``tpu_custom_call`` in a train step would
-be counted as ``flash_dq``: a Pallas scan waits for **kernels by name**
-(ROADMAP Reach B1(a)). The served prefill takes the same XLA chunks today
-(``models/llama.py ssm_prefill_path`` says so, ``"chunks"`` everywhere, and
-counts it); a fused scan for it is a later PR's, as ``ops/gdn_prefill.py``
-followed the delta rule's XLA path. :func:`causal_conv` stays the delta
-rule's XLA path's too (``models/llama.py _delta_chunks``).
+be counted as ``flash_dq``: a Pallas scan there waits for **kernels by name**
+(ROADMAP Reach B1(a)) and a backward. The SERVED prefill has its kernel: on a
+TPU backend ``models/llama.py attend_ssm`` runs ``ops/ssm_prefill.py`` (the
+convolution, ``silu``, the split, ``softplus`` and the scan in ONE
+forward-only Pallas call, the state in VMEM, the prompt one piece;
+``ssm_prefill_path`` says which path and why, and counts it), as
+``ops/gdn_prefill.py`` followed the delta rule's XLA path. These functions
+are that kernel's oracle, every other backend's path and the path of what it
+does not take. :func:`causal_conv` stays the delta rule's XLA path's too
+(``models/llama.py _delta_chunks``).
 """
 
 from __future__ import annotations

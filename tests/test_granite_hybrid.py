@@ -409,7 +409,7 @@ def test_the_state_goes_by_its_table_rule(params):
     paths = {p["kind"]: p for p in llama.prefill_attend_paths()
              if p["kind"] in ("ssm", "hybrid")}
     assert paths["ssm"]["path"] == "chunks" \
-        and "no kernel" in paths["ssm"]["reason"]
+        and "'cpu', not tpu" in paths["ssm"]["reason"]
     assert paths["hybrid"]["path"] == "tiles"
     # the attention's heads were filled up to the kernel's 128 lanes
     assert paths["hybrid"]["q_shape"][-1] == 128

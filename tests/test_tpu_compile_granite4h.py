@@ -7,6 +7,8 @@ and not behind them."""
 import math
 import re
 
+import jax
+import jax.numpy as jnp
 import pytest
 
 from test_tpu_compile import (  # noqa: F401 - the two fixtures are used by name
@@ -23,11 +25,14 @@ def test_granite4h_cells_programs_compile_and_fit_the_chip(
     convolution tail ONE row a page, the 4 attention layers' keys and values
     a row a position) and its shortest checked and longest page tables, from
     shapes alone: beside 10.8 GB of weights and stores a 16,384-position
-    prefill, whose scan goes 4,096 positions at a time, must stay inside the
-    chip. Prefill holds the flash kernel (heads filled up to 128 lanes, GQA
-    at a group of 4 by index map) in its period's ONE attention layer and no
-    other Pallas call (the scan is XLA's chunks); decode holds none and makes
-    no copy of a store's view a query head."""
+    prefill must stay inside the chip. A prefill's period holds the flash
+    kernel (heads filled up to 128 lanes, GQA at a group of 4 by index map)
+    in its ONE attention layer and the state-space kernel
+    (``ops/ssm_prefill.py``) in each of its nine Mamba-2 layers, by name, and
+    no other Pallas call; nothing of XLA's chunked scan is left beside them
+    (no float32 ``[chunks, 64, 256, 256]`` mask, no float32 layout copy of a
+    stretch's ``[.., 4352]`` rows or of its heads); decode holds no Pallas
+    call and makes no copy of a store's view a query head."""
     lowered, stores = _cell_program("serve-granite4hmicro-prefill-open",
                                     program, pages, one_chip)
     assert [s.shape for s in stores] == [
@@ -45,8 +50,13 @@ def test_granite4h_cells_programs_compile_and_fit_the_chip(
     calls = re.findall(r"custom_call_target=\"tpu_custom_call\"", text)
     if program == "prefill":
         assert len(re.findall(r"%flash_prefill[.\d]* = ", text)) == 1
-        assert len(calls) == 1  # no other kernel: the scan is XLA's
-        assert memory.temp_size_in_bytes < {2: 1.0e9, 8: 2.2e9}[pages]
+        assert len(re.findall(r"%ssm_prefill[.\d]* = ", text)) == 9
+        assert len(calls) == 10  # and no other kernel
+        assert not re.findall(r"f32\[[\d,]*64,256,256\]", text)
+        assert not re.findall(r"f32\[[\d,]*4352\]\S* copy\(", text)
+        assert not re.findall(r"f32\[\d+,8,32,256\]", text)
+        # 0.69 and 1.60 GB (1.85 at 8 pages with the scan in XLA, PR 58)
+        assert memory.temp_size_in_bytes < {2: 0.75e9, 8: 1.7e9}[pages]
     else:
         assert not calls
         assert memory.temp_size_in_bytes < 0.7e9
@@ -54,3 +64,37 @@ def test_granite4h_cells_programs_compile_and_fit_the_chip(
         assert not re.findall(r"f32\[\d+,32,64\]", text)
     assert held + memory.temp_size_in_bytes < 14.8e9
     print(program, pages, "held", held, "temp", memory.temp_size_in_bytes)
+
+
+# (heads, their width, the state's width, chunk, positions, type): the cell's
+# mixer at every other page count's tile count is the same kernel; these are
+# the OTHER shapes ``llama.ssm_prefill_path`` lets through, which no cell runs
+OTHER_WIDTHS = {
+    "heads of 128": (32, 128, 128, 256, 4096, jnp.bfloat16),
+    "heads of 256, chunks of 128": (16, 256, 128, 128, 2048, jnp.bfloat16),
+    "a state of 256": (64, 64, 256, 256, 2048, jnp.bfloat16),
+    "float32 operands": (64, 64, 128, 128, 2048, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("what", list(OTHER_WIDTHS))
+def test_ssm_prefill_compiles_at_the_widths_its_rule_takes(
+        what, one_chip, as_on_the_chip):
+    """Interpret mode cannot refuse what Mosaic refuses (a broadcast along
+    sublanes and lanes at once, found so at heads of 128): the kernel alone,
+    two sequences, compiled for the described chip."""
+    from ray_tpu.ops import ssm_prefill as sp
+
+    H, P, N, chunk, T, cd = OTHER_WIDTHS[what]
+    width, taps = H * P + 2 * N, 4
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(lambda *a: sp._call(
+        *a, heads=H, head_dim=P, chunk=chunk, interpret=False)).lower(
+        arg((2, T, width), cd), arg((2, T, H)), arg((taps, width)),
+        arg((width,)), arg((H,)), arg((H,)), arg((H,)), arg((2, H, P, N)),
+        arg((2, taps - 1, width), cd), arg((), jnp.int32)
+    ).compile().as_text()
+    assert len(re.findall(r"%ssm_prefill[.\d]* = ", text)) == 1
