@@ -37,6 +37,7 @@ here it is the in-framework model that Train, Serve and the benchmark
 
 from __future__ import annotations
 
+import itertools
 import math
 import threading
 from dataclasses import dataclass
@@ -72,6 +73,14 @@ _g_engine_weight_bytes = Gauge(
     "ray_tpu_serve_engine_weight_bytes",
     "Bytes of model weights the decode engine keeps on the device, by "
     "the dtype they are held in", tag_keys=("dtype",))
+# the layer bodies ONE program of the engine traces, set once beside it: the
+# walker scans a stack's whole periods and, where it walks in line, a run of
+# one kind (_segments), so a body is traced once however often it is scanned.
+# A program's time to trace, lower, compile and load follows it, not the depth
+_g_engine_traced_layers = Gauge(
+    "ray_tpu_serve_engine_traced_layers",
+    "Layer bodies one serving program of the decode engine traces: a "
+    "scanned period's layers or a scanned run's one, and each layer in line")
 
 # what ONE position (in a store by slot: one SLOT position) leaves in the
 # engine's page stores, over all layers, summed under the tag each store has
@@ -210,8 +219,8 @@ def keep_policy(keep):
 # SERVED too (rows of SERVED): prefill's kernel over expanded keys and
 # values, decode absorbed over ONE latent row a position. Their stream is
 # the only one that may be cfg.hc_mult rows a token ([B, T, hc_mult * dim],
-# widened behind the embedding and summed in front of the final norm; no
-# train step takes that)
+# or the rows apart where the engine serves them, widened behind the
+# embedding and summed in front of the final norm; no train step takes that)
 LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
                "F": "block", "W": "block", "I": "index", "D": "delta",
                "A": "gated", "L": "latent", "G": "latent_dense",
@@ -534,9 +543,10 @@ class LlamaConfig:
     # and its output is written back onto all of them beside a doubly
     # stochastic mix of the rows (hc_sinkhorn_iters normalisations of
     # exp(clip(.., hc_res_clamp_min, hc_res_clamp_max)), hc_eps in the
-    # mix's norm and in every divisor). The programs carry the rows side by
-    # side, [B, T, hc_mult * dim] (widen_stream .. collapse_stream). 1: the
-    # plain residual stream, and nothing of this is traced
+    # mix's norm and in every divisor). The full forward carries the rows
+    # side by side, [B, T, hc_mult * dim], the serving programs apart
+    # (widen_stream .. collapse_stream). 1: the plain residual stream, and
+    # nothing of this is traced
     hc_mult: int = 1
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -1847,32 +1857,44 @@ def attend_latent_heads(cfg: LlamaConfig, attend, q, latent, wkv_b):
     return attend(q, k, v)
 
 
-def widen_stream(cfg: LlamaConfig, x):
+def widen_stream(cfg: LlamaConfig, x, apart=False):
     """The embedding ``[B, T, dim]`` as the stream's first state: every one
     of the ``hc_mult`` rows a token is a copy of it, side by side ``[B, T,
     hc_mult * dim]`` (row ``j`` is ``[..., j * dim:(j + 1) * dim]``, whole
-    lanes; every program and the walker keep reading ``x.shape[:2]`` and
-    slicing positions as they did. The rows as a dimension of their own cost
-    the same on the chip, 12.6 against 12.3 ms a sublayer's passes at 16,384
-    positions: the compiler lays either out positions-minor;
-    ``sweep/xing4_check.md``). ``hc_mult`` 1: ``x`` as it is."""
-    return x if cfg.hc_mult == 1 else jnp.tile(x, (1, 1, cfg.hc_mult))
+    lanes; a train step and the full forward keep reading ``x.shape[:2]``
+    and slicing positions as they did. The rows as a dimension of their own
+    cost the same on the chip, 12.6 against 12.3 ms a sublayer's passes at
+    16,384 positions: the compiler lays either out positions-minor;
+    ``sweep/xing4_check.md``), or ``apart`` as a TUPLE of ``hc_mult`` rows
+    ``[B, T, dim]``, which is how the serving programs carry it: rows side
+    by side are put together by every sublayer's write-back and cut apart by
+    the next, a pass over 0.94 GB each at 16,384 positions and, across a
+    layer scan's carry, a copy more (PERF.md, PR 61). ``hc_mult`` 1: ``x``
+    as it is."""
+    if cfg.hc_mult == 1:
+        return x
+    return (x,) * cfg.hc_mult if apart else jnp.tile(x, (1, 1, cfg.hc_mult))
 
 
 def collapse_stream(cfg: LlamaConfig, x):
-    """The stream's end: its rows SUMMED (float32) to ``[B, T, dim]``, what
-    the final norm reads. ``hc_mult`` 1: ``x`` as it is."""
+    """The stream's end, in either of :func:`widen_stream`'s forms: its rows
+    SUMMED (float32) to ``[B, T, dim]``, what the final norm reads.
+    ``hc_mult`` 1: ``x`` as it is."""
     if cfg.hc_mult == 1:
         return x
     with jax.named_scope("hc.sum"):
+        if isinstance(x, tuple):
+            return sum(a.astype(jnp.float32) for a in x).astype(x[0].dtype)
         rows = x.reshape(*x.shape[:2], cfg.hc_mult, -1)
         return rows.astype(jnp.float32).sum(axis=2).astype(x.dtype)
 
 
 def hyper_mix(cfg: LlamaConfig, phi, b, alpha, x):
-    """One sublayer's three mixes from the stream ``x`` [B, T, n * dim], all
-    float32 whatever the stream's type, the positions LAST (lanes: twenty
-    normalisations of ``[n, n]`` minor would run on padded tiles)::
+    """One sublayer's three mixes from the stream ``x`` [B, T, n * dim] (or
+    its ``n`` rows ``[B, T, dim]``, a tuple: the product and the mean square
+    are then summed over the rows), all float32 whatever the stream's type,
+    the positions LAST (lanes: twenty normalisations of ``[n, n]`` minor
+    would run on padded tiles)::
 
         m      = (x~ phi) / sqrt(mean(x~ ** 2) + eps)        x~ = vec(X)
         H_pre  = sigmoid(alpha_0 m[:n] + b[:n])              [n, B, T]
@@ -1885,11 +1907,20 @@ def hyper_mix(cfg: LlamaConfig, phi, b, alpha, x):
     error)``: ``error`` the largest ``|row sum - 1|`` or ``|column sum - 1|``
     of ``H_res``."""
     f32, n, eps = jnp.float32, cfg.hc_mult, cfg.hc_eps
-    x32 = x.astype(f32)
-    m = jnp.einsum("btc,cm->mbt", x32, phi,
-                   precision=jax.lax.Precision.HIGHEST,
-                   preferred_element_type=f32)
-    m = m * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1) + eps)
+
+    def product(x32, phi):
+        return jnp.einsum("btc,cm->mbt", x32, phi,
+                          precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=f32)
+
+    if isinstance(x, tuple):
+        rows = [a.astype(f32) for a in x]
+        m = sum(map(product, rows, jnp.split(phi, n)))
+        square = sum((a * a).sum(axis=-1) for a in rows) / phi.shape[0]
+    else:
+        x32 = x.astype(f32)
+        m, square = product(x32, phi), jnp.mean(x32 * x32, axis=-1)
+    m = m * jax.lax.rsqrt(square + eps)
     b = b[:, None, None]
     pre = jax.nn.sigmoid(alpha[0] * m[:n] + b[:n])
     post = 2.0 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + b[n:2 * n])
@@ -1911,32 +1942,34 @@ def hyper_connected(cfg: LlamaConfig, hc, x, sublayer):
 
         x' = x + y                         the plain residual block
 
-    ``hc = (phi, b, alpha)``, this sublayer's, on ``x`` [B, T, n * dim]
-    (:func:`widen_stream`'s layout; the mixes: :func:`hyper_mix`, scope
-    ``hc.mix``)::
+    ``hc = (phi, b, alpha)``, this sublayer's, on ``x`` in either of
+    :func:`widen_stream`'s forms, [B, T, n * dim] or the ``n`` rows apart
+    (the mixes: :func:`hyper_mix`, scope ``hc.mix``)::
 
         h     = sum_j H_pre[j] X[j]                          ``hc.read``
         X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y         ``hc.write``
 
-    in float32, stored in the stream's type. Returns ``(x', aux, error)``,
-    ``error`` :func:`hyper_mix`'s (None without ``hc``)."""
+    in float32, stored in the stream's type and form. Returns ``(x', aux,
+    error)``, ``error`` :func:`hyper_mix`'s (None without ``hc``)."""
     if hc is None:
         y, aux = sublayer(x)
         return x + y.astype(x.dtype), aux, None
     f32, n = jnp.float32, cfg.hc_mult
-    d = x.shape[-1] // n
+    apart = isinstance(x, tuple)
     with jax.named_scope("hc.mix"):
         pre, post, res, error = hyper_mix(cfg, *hc, x)
-    rows = [x[..., j * d:(j + 1) * d].astype(f32) for j in range(n)]
+    x = x if apart else jnp.split(x, n, axis=-1)
+    dtype, rows = x[0].dtype, [a.astype(f32) for a in x]
     with jax.named_scope("hc.read"):
         h = sum(pre[j][..., None] * rows[j] for j in range(n))
-    y, aux = sublayer(h.astype(x.dtype))
+    y, aux = sublayer(h.astype(dtype))
     with jax.named_scope("hc.write"):
         y = y.astype(f32)
-        out = jnp.concatenate([
-            sum(res[i, j][..., None] * rows[j] for j in range(n))
-            + post[i][..., None] * y for i in range(n)], axis=-1)
-    return out.astype(x.dtype), aux, error
+        out = tuple(sum(res[i, j][..., None] * rows[j] for j in range(n))
+                    + post[i][..., None] * y for i in range(n))
+        if not apart:
+            out = jnp.concatenate(out, axis=-1)
+    return jax.tree.map(lambda a: a.astype(dtype), out), aux, error
 
 
 def _latent_sublayers(cfg: LlamaConfig, kind: str, attend, x, p, positions,
@@ -3921,9 +3954,10 @@ class Served(NamedTuple):
     number in the stack (the dense block: its own weights), ``rows`` what it
     keeps, an array a store (or the one array). ``f32``: the serving
     stream is float32 and not ``cfg.dtype``; ``x`` is ``[B, T, dim]``, or
-    ``[B, T, hc_mult * dim]`` where the config has several rows a token (the
-    programs widen it behind the embedding and sum it in front of the head:
-    only a block that takes :func:`hyper_connected` reads such a stream).
+    a tuple of ``hc_mult`` of them where the config has several rows a token
+    (the programs widen it behind the embedding and sum it in front of the
+    head: only a block that takes :func:`hyper_connected` reads such a
+    stream).
     ``rows(cfg)``: ``[(tag,
     sublayers, row shape, table), ...]``, what a layer keeps, a store each,
     found by the rule ``table`` names (:data:`TABLES`): a row a POSITION by
@@ -4403,21 +4437,54 @@ def _period(kinds: str) -> Tuple[str, int]:
     return "", 0
 
 
+def _segments(kinds: str, least: int = 2) -> list:
+    """How the walker (:func:`_serve_layers`) takes a stack, ``[(unit, times,
+    scanned), ...]`` in layer order: the pattern's whole periods where there
+    are ``least`` of them or the period is ONE layer (a stack of one layer is
+    a scan of one: the dense block's weights reach it as a scan's ``xs``),
+    then, of what is left (a last period cut short, or the whole of a stack
+    with fewer periods), each run of ``least`` consecutive layers of one kind
+    or more as ``(kind, n, True)``, and the layers between such runs together
+    as ``(their letters, 1, False)``. ``least`` is 2; a test passes more than
+    a stack has to get every layer in line."""
+    unit, times = _period(kinds)
+    if times < least and len(unit) > 1:
+        times = 0
+    out = [(unit, times, True)] if times else []
+    for c, run in itertools.groupby(kinds[times * len(unit):]):
+        n = len(list(run))
+        if n >= least:
+            out.append((c, n, True))
+        elif out and not out[-1][2]:
+            out[-1] = (out[-1][0] + c * n, 1, False)
+        else:
+            out.append((c * n, 1, False))
+    return out
+
+
+def traced_layers(cfg: LlamaConfig) -> int:
+    """The layer bodies ONE serving program of ``cfg`` traces
+    (``ray_tpu_serve_engine_traced_layers``): a segment's ``unit`` once,
+    however often it is scanned."""
+    return sum(len(unit) for unit, *_ in _segments(served_kinds(cfg)))
+
+
 def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
                   keep):
-    """THE served layers, whatever their kinds: the pattern's whole periods
-    scanned (one compiled body of a period's layers; the scan carries the
-    period's number and not a patterned kind's weights), what a last period
-    cut short leaves in line. A period of ONE layer is scanned whatever the
-    depth; a longer one from two repetitions on, and in line below that.
+    """THE served layers, whatever their kinds, a segment at a time
+    (:func:`_segments`): the pattern's whole periods scanned (one compiled
+    body of a period's layers; the scan carries the period's number and not
+    a patterned kind's weights), and wherever layers stand IN LINE (a stack
+    of fewer than two periods, what a last period cut short leaves) a run of
+    two or more layers of ONE kind scanned too, one body a run.
 
     The ``l``-th layer of kind ``c`` runs ``SERVED[c].block`` and attends
     through ``attends[c](l, mine, *the block's arguments)``. ``x`` is the
-    stream as the programs carry it: ``[B, T, dim]``, or ``[B, T, hc_mult *
-    dim]`` (:func:`widen_stream`), float32 where a kind's row says so and
-    ``cfg.dtype`` otherwise; it leaves as it came, and the caller collapses
-    it. ``cache`` and
-    ``keep`` have an entry a store (:func:`served_stores`): the store's
+    stream as the programs carry it: ``[B, T, dim]``, or ``hc_mult`` rows
+    of that apart (:func:`widen_stream`; whatever pytree it is, a scan
+    carries it), float32 where a kind's row says so and ``cfg.dtype``
+    otherwise; it leaves as it came, and the caller collapses it. ``cache``
+    and ``keep`` have an entry a store (:func:`served_stores`): the store's
     view ``[layers, Tpad, *row]``, of which ``mine`` are the layer's own,
     or ``cache`` None (a prefill: nothing cached, ``mine`` None); and how
     many of the call's LAST positions a layer's rows leave. Returns ``(x,
@@ -4431,24 +4498,14 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
     keep = _by_kind(layout, keep)
     table = {c: SERVED[c] for c in subs}
     if any(kind.f32 for kind in table.values()):
-        x = x.astype(jnp.float32)
+        x = jax.tree.map(lambda a: a.astype(jnp.float32), x)
     if cache is not None:  # a layer's rows together: [n_c, sub, Tpad, *row]
         cache = {c: [a if sub == 1 else a.reshape(-1, sub, *a.shape[1:])
                      for a, sub in zip(views, subs[c])]
                  for c, views in _by_kind(layout, cache).items()}
-    unit, times = _period(kinds)
-    if times < 2 and len(unit) > 1:
-        times = 0
-    # a period of one layer IS that layer: the scan's own stacking is the
-    # layers', with no stack of one in the body and no reshape around it
-    one = len(unit) == 1
-    per = {c: unit.count(c) for c in table}
-    done = {c: times * per[c] for c in table}
     # a layer's number in its STACK: its number among the layers of the
     # kinds that share the stack (one stack a family, or one a kind)
     stacks = {c: table[c].stack for c in table}
-    per_stack = {k: sum(per[c] for c in table if stacks[c] == k)
-                 for k in stacks.values()}
 
     def layer(x, c, which, l, mine):
         kind = table[c]
@@ -4464,58 +4521,66 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
         # the block's statistics that OVER_LAYERS lists
         return x, (rows, {k: stats[k] for k in OVER_LAYERS if k in stats})
 
-    def run(x, some, first, start, cached):
-        """Layers of kinds ``some`` in line; the first of them in stack
-        ``k`` is layer ``first[k]`` there, the first of kind ``c`` is
-        ``start[c]`` among ``c``'s; ``cached``: ``{c: views}`` over THESE
-        layers. Returns ``(x, {c: (rows, shares)})``, a leading dimension
-        over ``c``'s layers."""
-        out = {c: [] for c in table}
-        met = dict.fromkeys(first, 0)
-        for c in some:
-            n = len(out[c])
-            mine = None if cached is None else [a[n] for a in cached[c]]
-            x, new = layer(x, c, first[stacks[c]] + met[stacks[c]],
-                           start[c] + n, mine)
-            met[stacks[c]] += 1
-            out[c].append(new)
-        return x, {c: jax.tree.map(lambda *a: jnp.stack(a), *out[c])
-                   for c in table if out[c]}
+    def segment(x, unit, times, scanned, first, start):
+        """``times`` of the layers ``unit``, scanned or (``times`` 1) in
+        line; the first of them in stack ``k`` is layer ``first[k]`` there,
+        the first of kind ``c`` is ``start[c]`` among ``c``'s. Returns
+        ``(x, {c: (rows, shares)})``, a leading dimension over ``c``'s
+        layers."""
+        # a scanned kind IS its layers: the scan's own stacking is theirs,
+        # with no stack of one in the body and no reshape around it
+        one = scanned and len(unit) == 1
+        per = {c: unit.count(c) for c in table if c in unit}
+        per_stack = {k: sum(n for c, n in per.items() if stacks[c] == k)
+                     for k in stacks.values()}
 
-    def views(lo, hi, *lead):
-        """The cached views of each kind's layers ``lo[c] .. hi[c]`` (that
-        dimension as ``[*lead, the rest]``)."""
-        return None if cache is None else {
-            c: [a[lo[c]:hi[c]].reshape(*lead, -1, *a.shape[1:])
-                for a in cache[c]] for c in table}
+        def past(number, n):  # no operation where the layers are the first:
+            return number + n if n else number  # the texts from before runs
 
-    out = {c: [] for c in table}
-    if times:
-        def period(x, xs):
+        def body(x, xs):
             number, own, cached = xs
             if one:
-                x, new = layer(x, unit, number if own is None else own,
-                               number,
-                               None if cached is None else cached[unit])
+                x, new = layer(
+                    x, unit, past(number, first[stacks[unit]])
+                    if own is None else own, past(number, start[unit]),
+                    None if cached is None else cached[unit])
                 return x, {unit: new}
-            return run(x, unit,
-                       {k: number * n for k, n in per_stack.items()},
-                       {c: number * per[c] for c in table}, cached)
+            out, met = {c: [] for c in per}, dict(first)
+            # the layers of the repetitions before this one
+            which = {k: number * n for k, n in per_stack.items()}
+            l = {c: number * n for c, n in per.items()}
+            for c in unit:
+                n, k = len(out[c]), stacks[c]
+                mine = None if cached is None else [a[n] for a in cached[c]]
+                x, new = layer(x, c, which[k] + met[k],
+                               l[c] + (start[c] + n), mine)
+                met[k] += 1
+                out[c].append(new)
+            return x, {c: jax.tree.map(lambda *a: jnp.stack(a), *out[c])
+                       for c in per}
 
-        cached = views(dict.fromkeys(table, 0), done,
-                       *(() if one else (times,)))
-        x, new = jax.lax.scan(period, x, (
+        # these layers' cached views; a scanned period's, a period's together
+        lead = (times,) if scanned and not one else ()
+        cached = None if cache is None else {
+            c: [a[start[c]:start[c] + times * n].reshape(
+                *lead, -1, *a.shape[1:]) for a in cache[c]]
+            for c, n in per.items()}
+        if not scanned:
+            return body(x, (0, None, cached))
+        x, new = jax.lax.scan(body, x, (
             jnp.arange(times, dtype=jnp.int32),
             layers if one and table[unit].stack is None else None, cached))
-        for c in table:
-            out[c].append(new[c] if one else jax.tree.map(
-                lambda a: a.reshape(-1, *a.shape[2:]), new[c]))
-    rest = kinds[times * len(unit):]
-    if rest:
-        x, new = run(x, rest, {k: times * n for k, n in per_stack.items()},
-                     done, views(done, dict.fromkeys(table)))
+        return x, {c: new[c] if one else jax.tree.map(
+            lambda a: a.reshape(-1, *a.shape[2:]), new[c]) for c in per}
+
+    out = {c: [] for c in table}
+    first, start = dict.fromkeys(stacks.values(), 0), dict.fromkeys(table, 0)
+    for unit, times, scanned in _segments(kinds):
+        x, new = segment(x, unit, times, scanned, first, start)
         for c in new:
             out[c].append(new[c])
+            start[c] += times * unit.count(c)
+            first[stacks[c]] += times * unit.count(c)
     out = {c: jax.tree.map(lambda *a: jnp.concatenate(a), *out[c])
            for c in table}
     shares: Dict[str, list] = {}
@@ -4537,9 +4602,9 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
     """Prefill one sequence into its pages, inside the program: embed, the
     layers (:func:`_serve_layers`), each store written through ITS table,
     the head. With ``cfg.hc_mult > 1`` the stream between embedding and
-    head is ``hc_mult`` rows a token, ``[1, T, hc_mult * dim]`` float32
-    (:func:`widen_stream`: copies of the embedding), and the rows of the ONE
-    position the head reads are summed (:func:`collapse_stream`).
+    head is ``hc_mult`` rows a token, each ``[1, T, dim]`` float32
+    (:func:`widen_stream`, apart: copies of the embedding), and the rows of
+    the ONE position the head reads are summed (:func:`collapse_stream`).
 
     ``page_size``: a page's positions where no store says them (a stack
     whose every store keeps a row a SEQUENCE); the engine binds it.
@@ -4567,7 +4632,7 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
     *stores, tokens, page_ids, last = args
     ps = _page_size(stores, layout, page_size)
     ids = {"page": page_ids, "slot": slot_ids}
-    x = widen_stream(cfg, embed_tokens(cfg, params, tokens, None))
+    x = widen_stream(cfg, embed_tokens(cfg, params, tokens, None), True)
     positions = positions_of(*tokens.shape)
     keep = {"page": tokens.shape[1], "slot": 0 if slot_ids is None
             else slot_ids.shape[0] * ps}
@@ -4582,7 +4647,8 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
     stores = [_write_pages(pages, new[:, 0], ids[s.table])
               for pages, new, s in zip(stores, rows, layout)]
     # final_norm and the head are per position: one row, not T
-    x = collapse_stream(cfg, jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1))
+    x = collapse_stream(cfg, jax.tree.map(
+        lambda a: jax.lax.dynamic_slice_in_dim(a, last, 1, axis=1), x))
     logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
     return (*stores, logits[0, 0], shares)
 
@@ -4622,7 +4688,8 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
         ids["state"] = page_ids[jnp.maximum(pos - 1, 0) // ps][None]
     cached = [_read_pages(pages, ids[s.table])
               for pages, s in zip(stores, layout)]
-    x = widen_stream(cfg, embed_tokens(cfg, params, token[None, :], None))
+    x = widen_stream(cfg, embed_tokens(cfg, params, token[None, :], None),
+                     True)
     positions = jnp.full((1, 1), pos, dtype=jnp.int32)
     call = SimpleNamespace(
         pos=pos, page_ids=page_ids, stores=_by_kind(layout, stores),
@@ -4833,6 +4900,7 @@ class LlamaDecodeEngine:
                                          + leaf.nbytes)
         for name, nbytes in by_dtype.items():
             _g_engine_weight_bytes.set(float(nbytes), tags={"dtype": name})
+        _g_engine_traced_layers.set(float(traced_layers(self.cfg)))
         self.page_size = int(page_size)
         self.pool = PagePool(n_pages, page_size)
         self.prefix_cache = PrefixCache(self.pool)

@@ -21,7 +21,8 @@ import pytest
 
 from benchmarks.lib import spec
 from benchmarks.reference import cohere2_moe_decoder as ref
-from jitted import forward, init_params, loss_fn, reference
+from jitted import assert_served_alike, forward, init_params, loss_fn, \
+    reference, walked_both_ways
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.layers import layer_norm, rms_norm, rotary_embedding, \
@@ -494,6 +495,29 @@ def test_a_cut_stack_serves_the_references_logits(params, n_layers, kinds):
     got = served(eng, toks, 18, [3, 1, 4, 7, 5])
     close(got, logits_one(params, toks, num_hidden_layers=n_layers)[17:],
           5e-5)
+
+
+@pytest.mark.parametrize("n_layers,segments,traced", [
+    # the cell's ONE period: a run scanned
+    (4, [("R", 3, True), ("P", 1, False)], 2),
+    (6, [("R", 3, True), ("P", 1, False), ("R", 2, True)], 3),
+    # two periods: scanned as a period's body
+    (8, [("RRRP", 2, True)], 4)])
+def test_a_scanned_run_of_window_layers_is_those_layers_in_line(
+        params, n_layers, segments, traced, monkeypatch):
+    """A stack of fewer than two periods has its runs of ``R`` scanned, ONE
+    body a run, and one of two periods is scanned by periods as it always
+    was (no run is looked for inside a period's body): either way the logits
+    of a prefill past the window and of decode calls across page boundaries,
+    the rows of the four stores (pages and SLOTS) and the assignment shares
+    are those of the same layers each in line."""
+    cfg = program_cfg(num_hidden_layers=n_layers)
+    assert llama._segments(llama.served_kinds(cfg)) == segments
+    toks = np.random.RandomState(n_layers).randint(0, 128, size=24)
+    scanned, in_line = walked_both_ways(
+        lambda: new_engine(params, n_layers), toks, 18, monkeypatch)
+    assert (scanned["traced"], in_line["traced"]) == (traced, n_layers)
+    assert_served_alike(scanned, in_line)
 
 
 def test_a_decode_that_reads_a_wrong_slot_lies_far_off(engine, params):

@@ -372,6 +372,22 @@ def _gauge(name):
     return {k[0][1]: v for k, v in registry().local_values(name).items()}
 
 
+@pytest.mark.parametrize("n_layers,traced", [
+    (12, 3),     # fewer than two periods: H x 5, N, H x 6, a body a run
+    (20, 10),    # two periods (the cell has four): ONE period's ten layers
+    (22, 11)])   # and the rest's run of two behind them
+def test_a_program_traces_a_periods_body_once(n_layers, traced):
+    """``ray_tpu_serve_engine_traced_layers``: the bodies of the walker's
+    segments, set where the engine is built."""
+    from ray_tpu.util.metrics import registry
+
+    cfg = program_cfg(num_hidden_layers=n_layers)
+    llama.LlamaDecodeEngine(cfg, n_pages=4, page_size=PAGE)
+    assert llama.traced_layers(cfg) == traced
+    assert registry().local_values(
+        "ray_tpu_serve_engine_traced_layers")[()] == traced
+
+
 def test_the_state_goes_by_its_table_rule(params):
     """``served_stores`` / ``page_rows`` of the family, the engine's stores
     as the rule lays them out (ONE row a page), the gauges under the new

@@ -633,6 +633,13 @@ def _family_text(family, program, n, n_pages, page, **file_keys):
 # (Keye: a decode call whose pages hold no more than ``index_topk`` = 8
 # positions attends them all, one that holds more picks and gathers rows).
 # As ``PROGRAMS``: a PR that means to change a program replaces its hash.
+# PR 61 (on b8ab1b2; the walker scans a RUN of one kind wherever it walked in
+# line, ``llama._segments``) replaced six on purpose and touched no other:
+# "FW by runs" (was "FW in line": ``FWWWFW`` is now F, ONE body of W scanned
+# three times, FW), "HN by runs" (was "HN in line": H x 5, N, H x 6) and "HN
+# scanned" (its rest ``HH`` is a run of two). "FW scanned"'s rest ``FW`` has
+# no run, and a scanned period's BODY stays in line: those texts stood.
+# "GL run" (added by PR 61) holds the serving stream as a tuple of rows.
 SERVED_PROGRAMS = {
     "S prefill, 2 pages": (
         "47c781408cf89d9ba82926eb02c646b4310eb462249b5181722237379b42881e",
@@ -640,11 +647,11 @@ SERVED_PROGRAMS = {
     "S decode, 2 pages": (
         "84c78555f2a06494ce220c31c1161bbbf02602f78cdda188c3ec85b814a4a022",
         lambda: _family_text("longcat_flash", "decode", 2, 12, 8)),
-    "FW in line, prefill, 5 pages": (
-        "de55a5c509d7f729080ee8d4bf304a5b8775858e71ddfe645719fd3f4b371776",
+    "FW by runs, prefill, 5 pages": (
+        "669f6816989e28b9429191c61c4b0d0e980b0da41d774aac9f9fe15581d91118",
         lambda: _family_text("smallthinker", "prefill", 5, 24, 5)),
-    "FW in line, decode, 5 pages": (
-        "8a1e7d4c312707e51e00f6c2fe91a40087139c2071780f917a8e330dbf3b1d27",
+    "FW by runs, decode, 5 pages": (
+        "c3d35299b69ba5a9d4b27f2d4d7e96069a02c7a9ef19339ad04fe93c5aa2970d",
         lambda: _family_text("smallthinker", "decode", 5, 24, 5)),
     "FW scanned, prefill, 5 pages": (
         "3a07dcddccc72f9c56274e6274cbcd3f38dd323ee81eba9bff7977e4c2a887ed",
@@ -664,22 +671,33 @@ SERVED_PROGRAMS = {
         "c7e009f784b970ab5b7b622f460a7f01bacdea6c8f33df65a565f5482c53371f",
         lambda: _family_text("keye_vl2", "decode", 3, 24, 4)),
     # the state-space hybrid ("H" with "N"; tests/test_granite_hybrid.py),
-    # taken on the PR that brought it (58): a period of TEN layers, in line
-    # at 12 layers and scanned at 22 (two periods, a rest of two in line)
-    "HN in line, prefill, 2 pages": (
-        "47c5f2d51f5e3c29b19bd964004cbb62afc87414ad05e1b261bffd9a562e4cad",
+    # brought by PR 58, these four taken on PR 61: a period of TEN layers,
+    # walked by runs at 12 layers and scanned at 22 (two periods, and the
+    # rest of two a run)
+    "HN by runs, prefill, 2 pages": (
+        "749a49dad56d81d1e2a5e25dc76ea5e8c3700f29e11ba4b30dd39d489fda6523",
         lambda: _family_text("granite_hybrid", "prefill", 2, 12, 8)),
-    "HN in line, decode, 2 pages": (
-        "c698811845aca190a8cdade4655e71f9e2eb384be8fe8e85fffe9a0455c851d6",
+    "HN by runs, decode, 2 pages": (
+        "2e68ccd952d4c93b94ab4c75959498a9ed332b85a9696470a0b6509d7b6df6c7",
         lambda: _family_text("granite_hybrid", "decode", 2, 12, 8)),
     "HN scanned, prefill, 2 pages": (
-        "142364806268c7417fe6f0b01ff7f77c32fad8cb2c139269f31ffd4664fe34ce",
+        "0892db420630ca891050ab5f94349feefa767c686758c4b3823caa5edc6e2c98",
         lambda: _family_text("granite_hybrid", "prefill", 2, 12, 8,
                              num_hidden_layers=22)),
     "HN scanned, decode, 2 pages": (
-        "efadb937919cec61ab2b2519ad03a96bc5485eed93f6bd0f3c3825c7874209b5",
+        "71cb572a47ed216a9cc5cd71d38fbfaa754c6fe887b60f26c9918f124fc45f4f",
         lambda: _family_text("granite_hybrid", "decode", 2, 12, 8,
                              num_hidden_layers=22)),
+    # the latent blocks under a stream of four rows ("G" with "L";
+    # tests/test_xing4.py), taken on PR 61: ``GLL`` is G in line and ONE body
+    # of L scanned twice, the stream a tuple of four rows from the embedding
+    # to the head
+    "GL run, prefill, 2 pages": (
+        "006ad30e596060c7366c67817181e7ac2bf871b86be57c12aa43865b778d497b",
+        lambda: _family_text("xing4", "prefill", 2, 12, 8)),
+    "GL run, decode, 2 pages": (
+        "12b2600029c256015422185270a803ff11360ffbd5384ca81342ed2a0436498f",
+        lambda: _family_text("xing4", "decode", 2, 12, 8)),
 }
 
 
@@ -707,22 +725,28 @@ def no_page_bytes(cfg):
             if llama.TABLES[table].rows is None}
 
 
-@pytest.mark.parametrize("family,n_pages,page,held", [
-    ("dense", 16, 4, None),          # no routed kind: the share is not set
-    ("longcat_flash", 12, 8, 0.0),   # from the program's shares
-    ("smallthinker", 24, 5, 1.0),    # every expert here
-    ("keye_vl2", 24, 4, 0.0)])
-def test_the_engine_is_what_the_table_folds(family, n_pages, page, held):
+@pytest.mark.parametrize("family,n_pages,page,held,traced", [
+    ("dense", 16, 4, None, 1),         # no routed kind: the share is not set
+    ("longcat_flash", 12, 8, 0.0, 1),  # from the program's shares
+    ("smallthinker", 24, 5, 1.0, 4),   # every expert here; F, W x 3, FW
+    ("keye_vl2", 24, 4, 0.0, 1)])
+def test_the_engine_is_what_the_table_folds(family, n_pages, page, held,
+                                            traced):
     """Stores, slots and the ``page_bytes`` tags are ``served_stores``'
     (``SERVED`` folded over the stack), for every served family at its
     tests' tiny config; the assignment shares are set for a routed stack
-    and for no other."""
+    and for no other; ``traced_layers`` is the bodies the walker's segments
+    hold, whatever the depth."""
     import importlib
     import math
+
+    from ray_tpu.util.metrics import registry
 
     cfg = (LlamaConfig.debug() if family == "dense" else
            importlib.import_module(f"test_{family}").program_cfg())
     engine = llama.LlamaDecodeEngine(cfg, n_pages=n_pages, page_size=page)
+    assert registry().local_values(
+        "ray_tpu_serve_engine_traced_layers")[()] == traced
     layout = llama.served_stores(cfg)
     assert llama.page_rows(cfg)[1] == [(s.layers, s.row) for s in layout]
     assert {llama.SERVED[s.kind].family for s in layout} \

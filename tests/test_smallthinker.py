@@ -294,6 +294,40 @@ def test_a_pattern_is_whole_periods_and_a_rest(kinds, unit, times):
     assert llama._period(kinds) == (unit, times)
 
 
+@pytest.mark.parametrize("kinds,segments", [
+    # fewer than two periods: every run of one kind scanned, the rest in line
+    ("GLLLL", ["G", ("L", 4)]),
+    ("GGLLLL", [("G", 2), ("L", 4)]),
+    ("RRRP", [("R", 3), "P"]),
+    ("FWWWFW", ["F", ("W", 3), "FW"]),
+    ("HHHHHNHHHHHH", [("H", 5), "N", ("H", 6)]),
+    ("GL", ["GL"]),
+    # two periods or more: scanned by periods, a period's BODY stays in
+    # line, and the rest behind them is walked by runs
+    ("FWWWFWWW", [("FWWW", 2)]),
+    ("FWWWFWWWFW", [("FWWW", 2), "FW"]),
+    ("HHHHHNHHHH" * 2 + "HH", [("HHHHHNHHHH", 2), ("H", 2)]),
+    ("HHHHHNHHHH" * 4, [("HHHHHNHHHH", 4)]),
+    ("DDDA" * 2, [("DDDA", 2)]),
+    # a period of ONE layer, whatever the depth: one layer is a scan of one
+    ("b" * 24, [("b", 24)]), ("S", [("S", 1)]), ("WWW", [("W", 3)])])
+def test_a_stack_is_walked_by_segments(kinds, segments):
+    """``(unit, times, scanned)`` in layer order, here a pair where scanned
+    and the letters alone where in line: scanned from two repetitions on,
+    in line at one; together they are the stack; and with ``least`` out of
+    reach nothing but a period of one layer is scanned."""
+    got = llama._segments(kinds)
+    assert [(unit, times) if scanned else unit
+            for unit, times, scanned in got] == segments
+    assert "".join(unit * times for unit, times, _ in got) == kinds
+    assert all(times >= 2 or kinds == unit for unit, times, scanned in got
+               if scanned)
+    assert all(times == 1 for _, times, scanned in got if not scanned)
+    in_line = llama._segments(kinds, least=10 ** 6)
+    assert in_line == ([(kinds[0], len(kinds), True)] if len(set(kinds)) == 1
+                       else [(kinds, 1, False)])
+
+
 # --- (c) the engine: two kinds of store, slots ------------------------------ #
 
 

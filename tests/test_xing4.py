@@ -167,6 +167,28 @@ def test_prefill_then_decode_through_pages_is_the_references(
     assert worst(got, reference_logits(params, toks)[n - 1:]) < 2e-5
 
 
+@pytest.mark.parametrize("layers,segments", [
+    (3, [("G", 1, False), ("L", 2, True)]),    # this file's stack
+    (5, [("G", 1, False), ("L", 4, True)])])   # the cell's
+def test_a_scanned_run_of_routed_layers_is_those_layers_in_line(
+        layers, segments, tokens, monkeypatch):
+    """``G`` in line and ONE body of ``L`` scanned over its run
+    (``llama._segments``) against the same layers each in line: the logits
+    of a prefill and of decode calls across a page boundary, the rows of both
+    stores, the assignment shares and ``hc_sinkhorn_error`` of the stream of
+    four rows."""
+    cfg = program_cfg(dict(FILE, num_hidden_layers=layers))
+    assert llama._segments(llama.served_kinds(cfg)) == segments
+    p = seeded(cfg)
+    scanned, in_line = jitted.walked_both_ways(
+        lambda: llama.LlamaDecodeEngine(cfg, p, n_pages=12, page_size=8),
+        tokens[0, :19], 14, monkeypatch)
+    assert (scanned["traced"], in_line["traced"]) == (2, layers)
+    # the layers' LARGEST, out of a scan's ys as out of a stack
+    assert scanned["stats"]["ray_tpu_serve_hc_sinkhorn_error"][()] > 0.0
+    jitted.assert_served_alike(scanned, in_line)
+
+
 def test_bfloat16_engine_stays_near_the_reference(params, tokens):
     """As the cell runs it: bfloat16 products, a float32 stream, against the
     float32 reference on the engine's own (rounded) weights."""
@@ -251,13 +273,19 @@ def _hc(params, kind="latent", layer=0, sub=0):
     return tuple(tree[w][layer, sub] for w in ("hc_phi", "hc_b", "hc_alpha"))
 
 
+# the two forms a program carries the stream in (``llama.widen_stream``): the
+# full forward's rows side by side, the serving programs' tuple of rows
+FORMS = {"side by side": lambda X: X.reshape(1, X.shape[0], -1),
+         "apart": lambda X: tuple(X[None, :, j] for j in range(X.shape[1]))}
+
+
+@pytest.mark.parametrize("form", list(FORMS))
 @pytest.mark.parametrize("sub", [0, 1])
 def test_the_mix_is_the_references_and_lies_on_its_manifold(params, stream,
-                                                            sub):
+                                                            sub, form):
     cfg, hc = program_cfg(), _hc(params, sub=sub)
-    T = stream.shape[0]
     pre, post, res, error = jax.jit(partial(llama.hyper_mix, cfg))(
-        *hc, stream.reshape(1, T, -1))
+        *hc, FORMS[form](stream))
     want = jitted.reference(partial(ref.hyper_mix, FILE), stream, *hc)
     # the program keeps the positions last
     for got, w in zip((pre[:, 0].T, post[:, 0].T,
@@ -286,7 +314,9 @@ def test_sinkhorns_error_falls_with_its_iterations(params, stream, iters,
         assert float(error) > 5e-3  # and one is not enough
 
 
-def test_read_out_and_write_back_are_the_references(params, stream):
+@pytest.mark.parametrize("form", list(FORMS))
+def test_read_out_and_write_back_are_the_references(params, stream, form):
+    """In either form, and the stream leaves in the form it came in."""
     cfg, hc = program_cfg(), _hc(params)
     T, n, d = stream.shape
     y = stream[:, 1] * 0.5 + 1.0
@@ -296,10 +326,13 @@ def test_read_out_and_write_back_are_the_references(params, stream):
         seen["h"] = h
         return y[None], None
 
-    got, _, _ = llama.hyper_connected(cfg, hc, stream.reshape(1, T, -1), sub)
+    got, _, _ = llama.hyper_connected(cfg, hc, FORMS[form](stream), sub)
     pre, post, res = jitted.reference(partial(ref.hyper_mix, FILE), stream,
                                       *hc)
     assert worst(seen["h"][0], ref.read_out(pre, stream)) < 1e-5
+    if form == "apart":
+        assert isinstance(got, tuple) and len(got) == n
+        got = jnp.stack(got, axis=2)
     assert worst(got.reshape(T, n, d),
                  ref.write_back(res, post, stream, y)) < 1e-5
 
@@ -312,6 +345,7 @@ def test_without_rows_a_sublayer_is_the_plain_residual(stream):
     np.testing.assert_array_equal(got, x + 2.0 * x)
     assert aux == "aux" and error is None
     assert llama.widen_stream(cfg, x) is x
+    assert llama.widen_stream(cfg, x, apart=True) is x
     assert llama.collapse_stream(cfg, x) is x
 
 
@@ -322,10 +356,13 @@ def test_the_stream_starts_as_copies_and_ends_as_the_sum(stream):
     wide = llama.widen_stream(cfg, x)
     np.testing.assert_array_equal(wide.reshape(T, n, d),
                                   jnp.broadcast_to(x[0, :, None], (T, n, d)))
-    got = llama.collapse_stream(cfg, stream.reshape(1, T, -1))[0]
-    assert worst(got, stream.sum(axis=1)) < 1e-6
-    # not the mean (which a final norm would hide from the logits)
-    assert worst(got, stream.mean(axis=1)) > 0.5
+    apart = llama.widen_stream(cfg, x, apart=True)
+    assert len(apart) == n and all(row is x for row in apart)
+    for form in FORMS.values():
+        got = llama.collapse_stream(cfg, form(stream))[0]
+        assert worst(got, stream.sum(axis=1)) < 1e-6
+        # not the mean (which a final norm would hide from the logits)
+        assert worst(got, stream.mean(axis=1)) > 0.5
 
 
 def test_yarn_keeps_the_fast_frequencies_and_divides_the_slow():
