@@ -150,6 +150,17 @@ _g_engine_selected_share = Gauge(
     "Keys the decode engine's indexed blocks attended over the keys "
     "visible, last call, by program", tag_keys=("program",))
 
+# the layers of the engine's stack that MAKE a selection (role=select: an
+# indexed block, a full layer under DSA_KINDS: index scores, an index-key
+# store) against those that attend under the one made below them
+# (role=reuse: no indexer weights, no index keys, no score), set once beside
+# the engine; a stack that selects nothing reads 0 and 0
+_g_engine_selecting_layers = Gauge(
+    "ray_tpu_serve_engine_selecting_layers",
+    "Layers of the decode engine's stack that make a selection of the keys "
+    "they attend, and layers that reuse the selection of a layer below them",
+    tag_keys=("role",))
+
 # LlamaDecodeEngine's calls taken apart (one registration site per name):
 # the device program against what the host does on either side of it. The
 # three prefill spans lie inside the scheduler's serve.prefill; the three
@@ -225,7 +236,8 @@ LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
                "F": "block", "W": "block", "I": "index", "D": "delta",
                "A": "gated", "L": "latent", "G": "latent_dense",
                "P": "parallel", "R": "parallel",
-               "H": "hybrid_mamba", "N": "hybrid_attn"}
+               "H": "hybrid_mamba", "N": "hybrid_attn",
+               "Y": "dsa_full", "Z": "dsa_shared", "X": "dsa_dense"}
 # the kinds that are a WHOLE block (attention THEN the routed MLP, whose
 # router reads the attention's normed input; window_block): "F" attends over
 # every earlier position without rotation, "W" over the last cfg.window with
@@ -252,6 +264,16 @@ WINDOW_KINDS = "WR"
 # rotation whose scores are scaled by ``attention_multiplier``. A stack EACH
 # (their mixers' leaves differ)
 HYBRID_KINDS = "HN"
+# latent attention UNDER a learned selection (dsa_block): a latent block
+# ("L" / "G"'s attention and MLPs) that attends, a query, the cfg.index_topk
+# latent rows an indexer picks. "Y" (routed MLP) and "X" (dense SwiGLU: a
+# stack's leading layers) are FULL: each has an indexer, which reads the
+# query's own latent, and makes the selection. "Z" (routed MLP) is SHARED: it
+# has NO indexer and attends what the nearest full layer below it selected.
+# A stack EACH (their leaves differ), the routed ones first (LATENT_KINDS'
+# order and reason). SERVED only
+DSA_KINDS = "YZX"
+DSA_FULL = "YX"
 # Where their matrices start off the square root of their fan-in (seeded random
 # weights; a checkpoint brings its own): ``wo`` 12 times as wide, the router
 # 6 times, so that BOTH halves carry the logits and a comparison of logits
@@ -447,10 +469,16 @@ class LlamaConfig:
     # rank) scale only where mla_scale_* sets it) then a dense SwiGLU of
     # dense_mlp_dim ("G": a stack's leading layers) or the routed MLP with
     # an ungated shared SwiGLU expert ("L"); TRAINED through the flash
-    # kernel (latent_block) and served (serve_latent_block). Empty:
+    # kernel (latent_block) and served (serve_latent_block). "Y" / "Z" /
+    # "X": a latent block that attends under a learned selection
+    # (DSA_KINDS, dsa_block), SERVED only. Empty:
     # every layer is the block (attention THEN MLP), as every dense and
     # every all-routed configuration has it.
     layer_pattern: str = ""
+    # the pattern's first BUILT layer: the stack is layer_pattern[first_layer:
+    # first_layer + n_layers], one pipeline stage's share of a published
+    # stack that the pattern spells whole (0: the pattern's first n_layers)
+    first_layer: int = 0
     window: int = 0  # a "W" layer's position i sees j <= i with j > i - window
     ssm_heads: int = 0      # H; d_inner = ssm_heads * ssm_head_dim
     ssm_head_dim: int = 0   # P
@@ -597,11 +625,14 @@ class LlamaConfig:
         object.__setattr__(self, "seeded_scales", tuple(sorted(
             dict(self.seeded_scales or ()).items())))
         bad = set(self.layer_pattern) - set(LAYER_KINDS)
-        if bad or 0 < len(self.layer_pattern) < self.n_layers:
+        if bad or self.first_layer < 0 or 0 < len(self.layer_pattern) \
+                < self.first_layer + self.n_layers or (
+                    self.first_layer and not self.layer_pattern):
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r}: one of "
-                f"{sorted(LAYER_KINDS)} a layer, at least n_layers="
-                f"{self.n_layers} of them (or empty)")
+                f"{sorted(LAYER_KINDS)} a layer, at least first_layer + "
+                f"n_layers = {self.first_layer} + {self.n_layers} of them "
+                f"(or empty)")
         if (self.router_scoring not in ("softmax", "sigmoid")
                 or self.mlp_act not in ("swiglu", "relu2", "reglu")):
             raise ValueError(f"router_scoring={self.router_scoring!r}, "
@@ -672,6 +703,34 @@ class LlamaConfig:
                 "qk_norm, identity or shared expert, or weight scale")
         if self.mrope_section and "I" not in self.kinds:
             raise ValueError("only an 'I' layer reads mrope_section")
+        dsa = set(self.kinds) & set(DSA_KINDS)
+        if dsa and not (
+                set(self.kinds) <= set(DSA_KINDS)
+                and self.kinds[0] in DSA_FULL
+                and self.q_lora_rank and self.kv_lora_rank
+                and self.qk_rope_head_dim % 2 == 0
+                and self.qk_nope_head_dim > 0 and self.v_head_dim > 0
+                and self.index_heads and self.index_head_dim
+                and self.index_head_dim % 4 == 0 and self.index_topk
+                and ("X" not in dsa or self.dense_mlp_dim)
+                and (dsa == {"X"} or (
+                    self.num_experts and self.experts_per_token))
+                and self.mlp_act == "swiglu" and self.rope
+                and not self.qk_norm and not self.zero_experts
+                and self.hc_mult == 1 and not self.mtp_layers
+                and not self.rope_yarn):
+            raise ValueError(
+                "a 'Y' / 'Z' / 'X' layer is latent attention (q_lora_rank, "
+                "kv_lora_rank, qk_nope_head_dim, an even qk_rope_head_dim, "
+                "v_head_dim) over the index_topk positions an indexer picks "
+                "(index_heads, index_head_dim a multiple of 4, index_topk), "
+                "then a routed SwiGLU MLP ('Y', 'Z': num_experts, "
+                "experts_per_token; an ungated shared expert and a held "
+                "range may be set) or a dense one ('X': dense_mlp_dim): "
+                "every built layer is one of the three, the FIRST makes a "
+                "selection ('Y' or 'X': a 'Z' layer reads the one made "
+                "below it), and it has no QK-norm, identity expert, "
+                "prediction module, YaRN or stream of several rows")
         latent = set(self.kinds) & set(LATENT_KINDS)
         if latent and not (
                 set(self.kinds) <= set(LATENT_KINDS)
@@ -709,10 +768,11 @@ class LlamaConfig:
                 "hc_res_clamp_max), and a prediction module has no form "
                 "for it")
         if set(dict(self.seeded_scales)) - set(SEEDED_SCALES) or (
-                self.seeded_scales and not latent):
+                self.seeded_scales and not (latent or dsa)):
             raise ValueError(
                 f"seeded_scales={dict(self.seeded_scales)}: starting scales "
-                f"of an 'L' / 'G' block's leaves, of {SEEDED_SCALES}")
+                f"of a latent block's leaves ('L' / 'G', 'Y' / 'Z' / 'X'), "
+                f"of {SEEDED_SCALES}")
         yarn = dict(self.rope_yarn)
         if yarn and not (
                 latent and yarn.get("type", yarn.get("rope_type")) == "yarn"
@@ -816,7 +876,8 @@ class LlamaConfig:
     @property
     def kinds(self) -> str:
         """The built layers' kinds, in order ('' for a stack of blocks)."""
-        return self.layer_pattern[:self.n_layers]
+        return self.layer_pattern[self.first_layer:
+                                  self.first_layer + self.n_layers]
 
     @property
     def window_layout(self):
@@ -827,7 +888,8 @@ class LlamaConfig:
     @property
     def rope_layout(self):
         """1 a layer of the whole pattern whose attention rotates."""
-        return [int(kind in "WSIALGR" or (kind == "*" and self.rope))
+        return [int(kind in "WSIALGR" + DSA_KINDS
+                    or (kind == "*" and self.rope))
                 for kind in self.layer_pattern]
 
     @property
@@ -839,6 +901,31 @@ class LlamaConfig:
         return [hybrid.get(kind, "sliding_attention" if kind in WINDOW_KINDS
                            else "full_attention")
                 for kind in self.layer_pattern]
+
+    @property
+    def indexer_types(self):
+        """The whole pattern as a published config names its layers'
+        indexers: ``full`` (the layer makes its selection) or ``shared`` (it
+        reads the one made below it)."""
+        return ["shared" if kind == "Z" else "full"
+                for kind in self.layer_pattern]
+
+    @property
+    def mlp_layer_types(self):
+        """The whole pattern as a published config names its layers' MLPs:
+        ``dense`` or ``sparse`` (routed)."""
+        return ["dense" if kind in "GX" else "sparse"
+                for kind in self.layer_pattern]
+
+    @property
+    def qk_head_dim(self) -> int:
+        """A latent head's score width, as a published config sums it."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def rope_parameters(self):
+        """The plain rotation as a published config nests it."""
+        return {"rope_theta": self.rope_theta, "rope_type": "default"}
 
     @property
     def logits_scaling(self) -> float:
@@ -963,7 +1050,7 @@ class LlamaConfig:
             dense = 3 * d * self.mlp_dim + d
             per_kind["H"] = per_kind["M"] + dense
             per_kind["N"] = per_kind["*"] + dense
-            if set(self.kinds) & set("S" + LATENT_KINDS):
+            if set(self.kinds) & set("S" + LATENT_KINDS + DSA_KINDS):
                 rq, rkv, h = self.q_lora_rank, self.kv_lora_rank, self.n_heads
                 qk = self.qk_nope_head_dim + self.qk_rope_head_dim
                 mla = (d * rq + rq + rq * h * qk + d * self.latent_row + rkv
@@ -981,6 +1068,13 @@ class LlamaConfig:
                 per_kind["L"] = (
                     mla + wide * (d + 1) + 3 * d * self.shared_mlp_dim
                     + self.num_experts * 3 * d * self.mlp_dim + 2 * d + hc)
+                # under a selection: the latent blocks', and where the layer
+                # is FULL its indexer: the query's [q_lora_rank, heads x
+                # width], ONE key head, a weight a head, a LayerNorm
+                indexer = (rq * hi * di + d * di + d * hi + 2 * di)
+                per_kind["Z"] = per_kind["L"]
+                per_kind["Y"] = per_kind["L"] + indexer
+                per_kind["X"] = per_kind["G"] + indexer
                 # a prediction module: its block, W_eh and three norms
                 emb += self.mtp_layers * (per_kind["L"] + 2 * d * d + 3 * d)
             return emb + sum(per_kind[k] for k in self.kinds) + d
@@ -1113,6 +1207,14 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             kinds["latent"].update(shared_gate=("layers", "embed", "mlp"),
                                    shared_up=("layers", "embed", "mlp"),
                                    shared_down=("layers", "mlp", "embed"))
+        # under a selection: the latent blocks' leaves, and a FULL layer's
+        # indexer (its query reads the query's latent, [q_lora_rank, ...])
+        indexer = dict(wqi=("layers", None, None), wki=("layers", "embed", None),
+                       ww=("layers", "embed", None), ki_norm=("layers", None),
+                       ki_bias=("layers", None))
+        kinds["dsa_full"] = dict(kinds["latent"], **indexer)
+        kinds["dsa_shared"] = dict(kinds["latent"])
+        kinds["dsa_dense"] = dict(kinds["latent_dense"], **indexer)
         # the parallel block's: ONE norm, the whole block's attention, router
         # and experts, and the shared experts side by side as one wide one
         kinds["parallel"] = dict(
@@ -1315,6 +1417,9 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
     for c in HYBRID_KINDS:
         if n[c]:
             out[LAYER_KINDS[c]] = _init_hybrid(cfg, c, n[c], key)
+    for c in DSA_KINDS:
+        if n[c]:
+            out[LAYER_KINDS[c]] = _init_dsa_kind(cfg, c, n[c], key)
     return out
 
 
@@ -1423,6 +1528,35 @@ def _init_latent_kind(cfg: LlamaConfig, kind: str, L: int, key):
                    shared_up=dense(next(k), (L, d, fs), d),
                    shared_down=dense(next(k), (L, fs, d), fs))
     return out
+
+
+def _init_dsa_kind(cfg: LlamaConfig, kind: str, L: int, key):
+    """The ``L`` stacked layers of kind ``"Y"``, ``"Z"`` or ``"X"``, keys of
+    their own a kind: the latent block's leaves (:func:`_init_latent_kind`,
+    the routed one's or the dense one's, ``cfg.seeded_scales`` with them)
+    and, for a FULL layer, the indexer's (:func:`_init_indexer`). A shared
+    layer has none of them."""
+    own = jax.random.fold_in(key, 14 + DSA_KINDS.index(kind))
+    out = _init_latent_kind(cfg, "G" if kind == "X" else "L", L, own)
+    if kind in DSA_FULL:
+        out.update(_init_indexer(cfg, L, jax.random.fold_in(own, 1)))
+    return out
+
+
+def _init_indexer(cfg: LlamaConfig, L: int, key):
+    """``L`` full layers' indexer leaves, over the square root of their
+    fan-in as :data:`INDEX_INIT`'s reason has it: ``wqi`` reads the query's
+    normed latent, ``wki`` and ``ww`` the normed stream; the LayerNorm at
+    one and zero."""
+    hi, di = cfg.index_heads, cfg.index_head_dim
+    k = iter(jax.random.split(key, 3))
+    return {
+        "wqi": _dense_init(next(k), (L, cfg.q_lora_rank, hi * di),
+                           cfg.q_lora_rank),
+        "wki": _dense_init(next(k), (L, cfg.dim, di), cfg.dim),
+        "ww": _dense_init(next(k), (L, cfg.dim, hi), cfg.dim),
+        "ki_norm": jnp.ones((L, di), jnp.float32),
+        "ki_bias": jnp.zeros((L, di), jnp.float32)}
 
 
 def _init_mtp(cfg: LlamaConfig, key):
@@ -1786,6 +1920,20 @@ def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
     ``attend`` keeps its ``1 / sqrt(score width)``. Returns ``(y,
     latent)``: ``latent`` ``[B, T, kv_lora_rank + qk_rope_head_dim]`` is
     ``[c | kr]``, normed, scaled and rotated: what a cache keeps."""
+    cd = cfg.dtype
+    q, latent, _ = _latent_project(cfg, p, h, positions)
+    with jax.named_scope("mla.attend"):
+        o = attend(q, latent, p["wkv_b"].astype(cd))
+    with jax.named_scope("mla.out"):
+        y = o.reshape(*h.shape[:2], -1) @ p["wo"].astype(cd)
+    return y, latent
+
+
+def _latent_project(cfg: LlamaConfig, p, h, positions):
+    """:func:`_latent_half`'s projections (scope ``mla.project``): ``(q,
+    latent, cq)``, the rotated query ``[B, T, heads, nope + rope]``, the
+    row a cache keeps and ``cq`` ``[B, T, q_lora_rank]``, the query's normed
+    latent (what an indexer's query reads, :func:`dsa_block`)."""
     cd, d, eps = cfg.dtype, cfg.dim, cfg.norm_eps
     B, T, _ = h.shape
     H, r, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
@@ -1800,8 +1948,8 @@ def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
         if cfg.rope_yarn:  # the scores' factor rides on q's gain, as above
             q_gain = q_gain * yarn_softmax_factor(cfg)
             turn["inv_freq"] = yarn_frequencies(cfg)
-        q = (rms_norm(qa, q_gain, eps) @ p["wq_b"].astype(cd)).reshape(
-            B, T, H, -1)
+        cq = rms_norm(qa, q_gain, eps)
+        q = (cq @ p["wq_b"].astype(cd)).reshape(B, T, H, -1)
         ckr = h @ p["wkv_a"].astype(cd)
         c = rms_norm(ckr[..., :r], gain(
             p["kv_norm"], cfg.mla_scale_kv_lora, r), eps)
@@ -1810,11 +1958,7 @@ def _latent_half(cfg: LlamaConfig, p, h, positions, attend):
             interleaved=True, **turn)
         q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
         latent = jnp.concatenate([c, kr[:, :, 0]], axis=-1)
-    with jax.named_scope("mla.attend"):
-        o = attend(q, latent, p["wkv_b"].astype(cd))
-    with jax.named_scope("mla.out"):
-        y = o.reshape(B, T, -1) @ p["wo"].astype(cd)
-    return y, latent
+    return q, latent, cq
 
 
 def attend_latent_expanded(cfg: LlamaConfig, q, latent, wkv_b):
@@ -2615,18 +2759,22 @@ def index_scores(qi, ki, w):
                    axis=1)
 
 
-def _indexer(cfg: LlamaConfig, p, a, positions):
+def _indexer(cfg: LlamaConfig, p, a, positions, cq=None):
     """The indexer's three projections of ``a`` [B, T, dim] (normed, the
     stream's type): ``qi`` [B, T, Hi, Di] and ``ki`` [B, T, Di] =
     LayerNorm(a wki) (scale and bias), their FIRST HALF rotated as a rotary
     of its own (``Di / 4`` frequencies, half-split, by the temporal
     position), both in the compute type; ``w`` [B, T, Hi] float32 = ``a ww
-    / sqrt(Hi Di)``. ``ki`` is what a cache keeps (as float32)."""
+    / sqrt(Hi Di)``. ``ki`` is what a cache keeps (as float32). ``cq`` [B,
+    T, q_lora_rank]: the query's normed latent, which ``qi`` reads INSTEAD
+    of ``a`` where the attention has one (:func:`dsa_block`); the first half
+    then turns in pairs ``(2i, 2i + 1)``, as that attention's rope slice."""
     cd = cfg.dtype
     B, T, _ = a.shape
     hi, di = cfg.index_heads, cfg.index_head_dim
     h = a.astype(cd)
-    qi = (h @ p["wqi"].astype(cd)).reshape(B, T, hi, di)
+    qi = ((h if cq is None else cq.astype(cd))
+          @ p["wqi"].astype(cd)).reshape(B, T, hi, di)
     ki = (h @ p["wki"].astype(cd)).astype(jnp.float32)
     mean = jnp.mean(ki, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
@@ -2635,7 +2783,10 @@ def _indexer(cfg: LlamaConfig, p, a, positions):
     when = positions[0] if positions.ndim == 3 else positions
 
     def rotated(x):  # [B, T, H, Di]: the first half turns
-        turned = mrope_rotate(x[..., :di // 2], when, cfg.rope_theta)
+        if cq is None:
+            turned = mrope_rotate(x[..., :di // 2], when, cfg.rope_theta)
+        else:
+            turned = rotate_pairs(x[..., :di // 2], when, cfg.rope_theta)
         return jnp.concatenate([turned, x[..., di // 2:]], axis=-1)
 
     w = jnp.dot(a.astype(jnp.float32), p["ww"].astype(jnp.float32),
@@ -2766,6 +2917,187 @@ def _selected_tiles(q, k, v, qi, ki, w, topk: int, cd, chunk: int):
 
     o = jax.lax.map(query_block, jnp.arange(T // block, dtype=jnp.int32))
     return jnp.moveaxis(o, 0, 1).reshape(B, T, H, -1)
+
+
+# --------------------------------------------------------------------------- #
+# Latent attention UNDER a selection that layers share (kinds "Y", "Z", "X")
+# --------------------------------------------------------------------------- #
+
+
+class Selecting(NamedTuple):
+    """The serving stream of the kinds that attend under a selection which
+    ANOTHER layer may have made (:data:`DSA_KINDS`): ``stream`` ``[B, T,
+    dim]`` and, beside it, ``chosen``, the newest selection: what a FULL
+    layer writes and every layer behind it reads until the next full one.
+    A pytree like any other stream: the walker's scans carry it (through a
+    scanned run of shared layers it is the scan's constant), and the
+    programs make it behind the embedding (:func:`selecting_stream`) and
+    drop it in front of the head (:func:`stream_of`). ``chosen`` of a
+    prefill: the mask of the call's (query, key) pairs, int8, ``[B, T, T]``
+    or, where the Pallas calls run, their tiles
+    (``ops/sparse_prefill.py index_select``); of a decode call: ``(where
+    [index_topk] int32, seen [index_topk + 1] bool)``, the chosen EARLIER
+    positions in order (:func:`_pick_rows`)."""
+    stream: Any
+    chosen: Any
+
+
+def selected_prefill_path(cfg: LlamaConfig, T: int) -> Tuple[str, str]:
+    """:func:`selected_attend_path` for a latent block's prefill of ``T``
+    positions, from the sizes alone (the stream's first selection has to be
+    shaped before any layer has made a query)."""
+    width = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return selected_attend_path(jax.ShapeDtypeStruct(
+        (1, T, cfg.n_heads, width), jnp.dtype(cfg.dtype)), cfg.dtype)
+
+
+def selecting_stream(cfg: LlamaConfig, x, decode: bool = False):
+    """The stream ``x`` as the serving programs carry it through a stack of
+    :data:`DSA_KINDS`: :class:`Selecting`, with a selection of the right
+    shape and nothing chosen (the stack's first layer is a full one and
+    writes it; a scanned period carries it in). Any other stack: ``x``."""
+    if not set(cfg.kinds) & set(DSA_KINDS):
+        return x
+    B, T, _ = x.shape
+    K = cfg.index_topk
+    if decode:
+        return Selecting(x, (jnp.zeros((K,), jnp.int32),
+                             jnp.zeros((K + 1,), bool)))
+    if selected_prefill_path(cfg, T)[0] == "kernel":
+        from ray_tpu.ops.sparse_prefill import mask_tiles_shape
+
+        return Selecting(x, jnp.zeros(mask_tiles_shape(B, T), jnp.int8))
+    return Selecting(x, jnp.zeros((B, T, T), jnp.int8))
+
+
+def stream_of(x):
+    """The stream alone: :class:`Selecting`'s, or ``x`` as it is."""
+    return x.stream if isinstance(x, Selecting) else x
+
+
+def dsa_block(cfg: LlamaConfig, kind: str, x, layers, i, positions, attend,
+              stat_axes=()):
+    """THE latent block under a selection (kinds ``"Y"``, ``"Z"``, ``"X"``),
+    for the serving programs. ``x``: :class:`Selecting`::
+
+        a        = N(x)
+        q, [c|kr], cq = the latent projections (:func:`_latent_project`)
+        FULL:    qI, kI, w = the indexer's (:func:`_indexer`; qI reads cq)
+                 S(t) = the index_topk positions s <= t of largest
+                        sum_j w_j(t) relu(qI_j(t) . kI(s))        float32
+        SHARED:  S(t) = the S(t) that came with the stream
+        h        = x + Wo softmax_{s in S(t)}(q(t) . k(s) / sqrt(nope + rope))
+                   v(s),  k = [c Wkb | kr], v = c Wvb a head
+        out      = h + MLP(N(h))   "X": the dense SwiGLU; "Y" / "Z": the
+                   routed MLP with its ungated shared expert (:func:`_mlp_half`)
+
+    ``layers``: the kind's stack ``[n, ...]``, layer ``i``; every leaf is cut
+    out ``[i]`` but the routed experts (:func:`shortcut_layer` says why).
+    ``attend(q, latent, wkv_b, index, chosen)`` -> ``(o [B, T, heads,
+    v_head_dim], chosen)``: ``index`` is ``(qI, kI, w)`` in a full layer
+    (the selection is made, and returned) and None in a shared one (the one
+    given is used, and returned). A shared layer computes no index score and
+    keeps no index key. Device scopes: ``mla.project``, ``dsa.index``,
+    ``dsa.select`` (full layers only), ``dsa.attend`` / ``dsa.attend_shared``,
+    ``mla.out``. Returns ``(Selecting, stats, rows)``: ``rows`` ``(latent,
+    kI)`` or ``latent`` alone, what a cache keeps."""
+    cd, eps = cfg.dtype, cfg.norm_eps
+    x, chosen = x
+    B, T, _ = x.shape
+    whole = () if kind == "X" else ("w_gate", "w_up", "w_down")
+    p = {w: a if w in whole else a[i] for w, a in layers.items()}
+    a = rms_norm(x, p["attn_norm"], eps)
+    q, latent, cq = _latent_project(cfg, p, a.astype(cd), positions)
+    index = None
+    if kind in DSA_FULL:
+        with jax.named_scope("dsa.index"):
+            index = _indexer(cfg, p, a, positions, cq=cq)
+    o, chosen = attend(q, latent, p["wkv_b"].astype(cd), index, chosen)
+    with jax.named_scope("mla.out"):
+        y = o.reshape(B, T, -1) @ p["wo"].astype(cd)
+    h = x + y.astype(x.dtype)
+    m = rms_norm(h, p["mlp_norm"], eps).astype(cd)
+    if kind == "X":
+        with jax.named_scope("ffn.dense"):
+            y, stats = _dense_mlp(cfg, p, m), {}
+    else:
+        y, stats = _mlp_half(cfg, p, m, stat_axes, layer=i)
+    rows = latent if index is None else (latent, index[1])
+    return Selecting(h + y.astype(x.dtype), chosen), stats, rows
+
+
+def _latent_heads(cfg: LlamaConfig, latent, wkv_b):
+    """Every position's per-head key ``[c Wkb | kr]`` and value ``c Wvb``
+    from its latent row, ``[B, T, heads, nope + rope]`` and ``[B, T, heads,
+    v_head_dim]``: the ONE rotated slice repeated for every head."""
+    B, T, _ = latent.shape
+    H, r, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = (latent[..., :r] @ wkv_b).reshape(B, T, H, -1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        latent[:, :, None, r:], (B, T, H, cfg.qk_rope_head_dim))], axis=-1)
+    return k, kv[..., dn:]
+
+
+def attend_latent_selected(cfg: LlamaConfig, q, latent, wkv_b, index, chosen):
+    """:func:`dsa_block`'s ``attend`` over the call's own positions
+    (prefill): per-head keys and values expanded from the latent rows
+    (:func:`_latent_heads`), softmax attention under the selection, which a
+    full layer makes here (``index`` given: :func:`attend_selected`'s two
+    paths and ONE arithmetic, counted as kind ``latent_selected``) and a
+    shared layer takes as it came. Returns ``(o, chosen)``."""
+    path, reason = selected_prefill_path(cfg, q.shape[1])
+    _note_prefill_attend("latent_selected", q, latent, 0, path, reason)
+    k, v = _latent_heads(cfg, latent, wkv_b)
+    if path == "kernel":
+        from ray_tpu.ops.sparse_prefill import index_select, masked_flash
+
+        if index is not None:
+            with jax.named_scope("dsa.select"):  # scores and selection
+                chosen = index_select(*index, cfg.index_topk)
+        with jax.named_scope("dsa.attend" if index is not None
+                             else "dsa.attend_shared"):
+            return masked_flash(q, k, v, chosen), chosen
+    return _latent_selected_tiles(q, k, v, index, chosen, cfg.index_topk,
+                                  cfg.dtype, cfg.index_chunk)
+
+
+def _latent_selected_tiles(q, k, v, index, chosen, topk: int, cd, chunk: int):
+    """:func:`attend_latent_selected` in XLA, as :func:`_selected_tiles`
+    but for the selection, which is an OUTPUT (``[B, T, T]`` int8) where
+    ``index`` is given and an input where it is None."""
+    f32 = jnp.float32
+    B, T, H, D = q.shape
+    block = math.gcd(T, chunk)
+    at = jnp.arange(block, dtype=jnp.int32)
+    keys = jnp.arange(T, dtype=jnp.int32)
+
+    def query_block(n):
+        def cut(a):
+            return jax.lax.dynamic_slice_in_dim(a, n * block, block, axis=1)
+
+        if index is None:
+            mine = cut(chosen) != 0
+        else:
+            qi, ki, w = index
+            visible = keys[None, :] <= (n * block + at)[:, None]
+            with jax.named_scope("dsa.index"):
+                score = index_scores(cut(qi), ki, cut(w))
+            with jax.named_scope("dsa.select"):
+                mine = select_top(score, visible[None], topk)
+        with jax.named_scope("dsa.attend" if index is not None
+                             else "dsa.attend_shared"):
+            s = jnp.einsum("bqhd,bkhd->bhqk", cut(q), k,
+                           preferred_element_type=f32) / math.sqrt(D)
+            probs = jax.nn.softmax(jnp.where(mine[:, None], s, -1e30),
+                                   axis=-1)
+            o = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(cd), v,
+                           preferred_element_type=f32)
+        return o.astype(cd), mine.astype(jnp.int8)
+
+    o, mask = jax.lax.map(query_block,
+                          jnp.arange(T // block, dtype=jnp.int32))
+    return (jnp.moveaxis(o, 0, 1).reshape(B, T, H, -1),
+            jnp.moveaxis(mask, 0, 1).reshape(B, T, T))
 
 
 # --------------------------------------------------------------------------- #
@@ -3393,6 +3725,11 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     served kinds."""
     if kind in LATENT_KINDS:  # a whole block, over the CALLER's attend
         return latent_block(cfg, kind, attend, x, p, stat_axes)
+    if kind in DSA_KINDS:
+        raise NotImplementedError(
+            f"the full forward takes no {kind!r} layer yet: only the "
+            f"serving programs carry a selection from the layer that makes "
+            f"it to the layers that read it (prefill_with_cache)")
     if kind in SERVED:  # this layer's weights as a stack of one
         return SERVED[kind].block(
             cfg, x, jax.tree.map(lambda a: a[None], p), 0,
@@ -3771,10 +4108,36 @@ def _attend_selected_cached(cfg: LlamaConfig, k_pages, v_pages, page_ids,
     :func:`_read_pages` met the same in PR 25), and an
     ``optimization_barrier`` between the two did not hold it back. The
     products are 32 query heads by 2,049 rows: six passes cost nothing."""
-    cd, f32, i32 = cfg.dtype, jnp.float32, jnp.int32
-    Tpad, K = ki_cache.shape[0], cfg.index_topk
+    cd, f32 = cfg.dtype, jnp.float32
     ps = k_pages.shape[2]
     G, rep = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    where, real, own = _pick_rows(cfg, pos, ki_cache, qi, ki, w)
+    with jax.named_scope("dsa.attend"):
+        page, row = page_ids[where // ps], where % ps
+        K_all = jnp.concatenate([k_pages[layer, page, row],
+                                 kk[0].astype(f32)])
+        V_all = jnp.concatenate([v_pages[layer, page, row],
+                                 vv[0].astype(f32)])
+        seen = jnp.append(real, own)
+        exact = jax.lax.Precision.HIGHEST
+        s = jnp.einsum("grd,kgd->grk",
+                       q[0, 0].astype(f32).reshape(G, rep, -1), K_all,
+                       precision=exact) / math.sqrt(cfg.head_dim)
+        probs = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+        o = jnp.einsum("grk,kgd->grd", probs, V_all, precision=exact)
+    return o.reshape(1, 1, G * rep, -1).astype(cd)
+
+
+def _pick_rows(cfg: LlamaConfig, pos, ki_cache, qi, ki, w):
+    """A decode call's selection at position ``pos``: index scores over the
+    sequence's index keys ``ki_cache`` ``[Tpad, Di]`` (rows from ``pos`` on
+    are masked, the token's own key ``ki`` stands in at ``pos``; scope
+    ``dsa.index``), the ``index_topk`` best (:func:`select_top`; scope
+    ``dsa.select``). Returns ``(where [index_topk] int32, real
+    [index_topk] bool, own)``: the chosen EARLIER positions in order, which
+    of the places hold one, and whether ``pos`` itself is chosen."""
+    cd, i32 = cfg.dtype, jnp.int32
+    Tpad, K = ki_cache.shape[0], cfg.index_topk
     at = jnp.arange(Tpad, dtype=i32)
     with jax.named_scope("dsa.index"):
         keys = jax.lax.dynamic_update_slice(
@@ -3791,20 +4154,7 @@ def _attend_selected_cached(cfg: LlamaConfig, k_pages, v_pages, page_ids,
         where = jnp.sum(under[None, :] <= place[:, None], axis=1, dtype=i32)
         real = place < under[-1]
         where = jnp.where(real, where, 0)
-    with jax.named_scope("dsa.attend"):
-        page, row = page_ids[where // ps], where % ps
-        K_all = jnp.concatenate([k_pages[layer, page, row],
-                                 kk[0].astype(f32)])
-        V_all = jnp.concatenate([v_pages[layer, page, row],
-                                 vv[0].astype(f32)])
-        seen = jnp.append(real, own)
-        exact = jax.lax.Precision.HIGHEST
-        s = jnp.einsum("grd,kgd->grk",
-                       q[0, 0].astype(f32).reshape(G, rep, -1), K_all,
-                       precision=exact) / math.sqrt(cfg.head_dim)
-        probs = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
-        o = jnp.einsum("grk,kgd->grd", probs, V_all, precision=exact)
-    return o.reshape(1, 1, G * rep, -1).astype(cd)
+    return where, real, own
 
 
 # which FORM a decode call's attention took, counted where a program is
@@ -3912,10 +4262,13 @@ def _attend_grouped(cfg: LlamaConfig, tag: str, k_cache, v_cache, length, q,
     return o.reshape(1, 1, -1, D).astype(cfg.dtype)
 
 
-def _attend_latent_cached(cfg: LlamaConfig, cache, length, q, latent, wkv_b):
+def _attend_latent_cached(cfg: LlamaConfig, cache, length, q, latent, wkv_b,
+                          seen=None):
     """One new token (``_latent_half``'s ``attend`` arguments) against ONE
     sublayer's gathered, page-padded latent rows ``[Tpad, latent_row]``,
-    masked as :func:`_attend_cached` masks. No key or value is expanded:
+    masked as :func:`_attend_cached` masks, or by ``seen`` ``[Tpad + 1]``
+    where the rows are a SELECTION of the sequence's (the last: the token's
+    own). No key or value is expanded:
     ``wkv_b``'s key half is absorbed into the query (``q_abs[h] = q_nope[h]
     Wk[h]^T``, as wide as a latent row's ``c``), the scores are taken
     against the rows themselves, and its value half is applied to the
@@ -3930,11 +4283,45 @@ def _attend_latent_cached(cfg: LlamaConfig, cache, length, q, latent, wkv_b):
     q_row = jnp.concatenate([q_abs, q[..., dn:].astype(f32)], axis=-1)
     s = jnp.einsum("bqhc,kc->bhqk", q_row, rows) / math.sqrt(q.shape[-1])
     idx = jnp.arange(Tpad + 1)
-    valid = (idx < length) | (idx == Tpad)  # history + the token itself
+    valid = seen
+    if seen is None:
+        valid = (idx < length) | (idx == Tpad)  # history + the token itself
     probs = jax.nn.softmax(jnp.where(valid, s, -1e30), axis=-1)
     o = jnp.einsum("bhqk,kr->bqhr", probs, rows[:, :r])
     return jnp.einsum("bqhr,rhd->bqhd", o.astype(cd), w[..., dn:],
                       preferred_element_type=f32).astype(cd)
+
+
+def _attend_dsa_cached(cfg: LlamaConfig, call, l, mine, q, latent, wkv_b,
+                       index, chosen):
+    """:func:`dsa_block`'s ``attend`` for a decode call's one token against
+    ``mine``, the layer's OWN views: its latent rows and, a FULL layer's,
+    its index keys. While the table holds no more than ``index_topk``
+    positions every visible one is attended and no selection is made
+    (:func:`_attend_picked`'s rule). Past that a full layer (``index``
+    given) scores the index keys' view and picks (:func:`_pick_rows`), a
+    SHARED one takes the row numbers that came with the stream, and either
+    attends those ``index_topk`` rows of its own latent view (scope
+    ``dsa.gather``) and the token's own in :func:`_attend_latent_cached`'s
+    absorbed form: no key or value is expanded. The rows come from the
+    VIEW (the sequence's pages, 37.7 MB a layer at 16,384 positions) and not
+    from the store: a store 576 wide lies positions-minor on the chip, and
+    a row gather from it is first a copy of the whole store (3.65 of a
+    decode call's 8.0 ms; my chip run, PR 63). Returns ``(o, chosen)``."""
+    if mine[0].shape[0] <= cfg.index_topk:
+        return _attend_latent_cached(cfg, mine[0], call.pos, q, latent,
+                                     wkv_b), chosen
+    if index is not None:
+        where, real, own = _pick_rows(cfg, call.pos, mine[1], *index)
+        chosen = (where, jnp.append(real, own))
+    where, seen = chosen
+    with jax.named_scope("dsa.attend" if index is not None
+                         else "dsa.attend_shared"):
+        with jax.named_scope("dsa.gather"):
+            rows = mine[0][where]
+        o = _attend_latent_cached(cfg, rows, None, q, latent, wkv_b,
+                                  seen=seen)
+    return o, chosen
 
 
 # --- the served kinds: ONE row a layer letter ------------------------------ #
@@ -4201,6 +4588,25 @@ SERVED: Dict[str, Served] = {
         lambda cfg, call, l, mine, q, *a: _attend_grouped(
             cfg, "hybrid", *mine, call.pos,
             _hybrid_query(cfg, q, cfg.head_dim), *a)),
+    # latent attention under a selection that layers SHARE (dsa_block), a
+    # stack a kind, the routed ones first (as the latent blocks'). Every
+    # layer keeps a latent row, a store a kind under the tag dsa_latent; a
+    # FULL layer ("Y", "X") keeps its indexer's ONE key head too (dsa_index),
+    # a SHARED one ("Z") has no such store. The stream is Selecting: the
+    # selection travels beside it from a full layer to the layers behind it
+    # (float32, as the latent blocks'). Prefill expands keys and values under
+    # the mask; decode gathers index_topk rows of the layer's OWN view and
+    # never expands. Any part of the family whose first layer is full is
+    # served alone
+    **{c: Served(
+        "dsa", LAYER_KINDS[c],
+        lambda cfg, *a, c=c: dsa_block(cfg, c, *a), True,
+        lambda cfg, c=c: [("dsa_latent", 1, (cfg.latent_row,), "page")]
+        + [("dsa_index", 1, (cfg.index_head_dim,), "page")]
+        * (c in DSA_FULL),
+        lambda cfg, last, *a: attend_latent_selected(cfg, *a),
+        _attend_dsa_cached, _index_attended, alone=True)
+       for c in DSA_KINDS},
 }
 
 
@@ -4360,6 +4766,27 @@ REFUSED: Dict[str, Refused] = {
          "the forward",
          "make_pipeline_train_step":
          "its stages run the dense block alone"}),
+    # a path on which no selection travels from layer to layer
+    "selected": Refused(
+        _has(DSA_KINDS),
+        lambda cfg: (f"no 'Y' / 'Z' / 'X' layer (layer_pattern="
+                     f"{cfg.layer_pattern!r}, first_layer={cfg.first_layer}, "
+                     f"index_topk={cfg.index_topk})"),
+        {"make_spmd_train_step":
+         "no train step carries a selection from the layer that makes it to "
+         "the layers that read it, masks its flash kernel by one, or is held "
+         "to a reference for an indexer's backward; LlamaDecodeEngine "
+         "serves these kinds",
+         "make_train_step":
+         "no train step carries a selection from the layer that makes it to "
+         "the layers that read it or is held to a reference for an "
+         "indexer's backward; LlamaDecodeEngine serves these kinds",
+         "make_pipeline_train_step":
+         "its stages pass the residual stream alone: a selection made on "
+         "one stage has no way to the shared layers of the next",
+         "the MPMD pipeline":
+         "its stages pass the residual stream alone: a selection made on "
+         "one stage has no way to the shared layers of the next"}),
     # a stream of several rows a token (``hc_mult > 1``), and a latent block
     # whose score is not as wide as its value
     "wide": Refused(
@@ -4481,7 +4908,8 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
     The ``l``-th layer of kind ``c`` runs ``SERVED[c].block`` and attends
     through ``attends[c](l, mine, *the block's arguments)``. ``x`` is the
     stream as the programs carry it: ``[B, T, dim]``, or ``hc_mult`` rows
-    of that apart (:func:`widen_stream`; whatever pytree it is, a scan
+    of that apart (:func:`widen_stream`), or with a selection beside it
+    (:class:`Selecting`; whatever pytree it is, a scan
     carries it), float32 where a kind's row says so and ``cfg.dtype``
     otherwise; it leaves as it came, and the caller collapses it. ``cache``
     and ``keep`` have an entry a store (:func:`served_stores`): the store's
@@ -4497,8 +4925,9 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
     subs = _by_kind(layout, [s.sub for s in layout])
     keep = _by_kind(layout, keep)
     table = {c: SERVED[c] for c in subs}
-    if any(kind.f32 for kind in table.values()):
-        x = jax.tree.map(lambda a: a.astype(jnp.float32), x)
+    if any(kind.f32 for kind in table.values()):  # a selection stays whole
+        x = jax.tree.map(lambda a: a.astype(jnp.float32) if jnp.issubdtype(
+            a.dtype, jnp.floating) else a, x)
     if cache is not None:  # a layer's rows together: [n_c, sub, Tpad, *row]
         cache = {c: [a if sub == 1 else a.reshape(-1, sub, *a.shape[1:])
                      for a, sub in zip(views, subs[c])]
@@ -4632,7 +5061,8 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
     *stores, tokens, page_ids, last = args
     ps = _page_size(stores, layout, page_size)
     ids = {"page": page_ids, "slot": slot_ids}
-    x = widen_stream(cfg, embed_tokens(cfg, params, tokens, None), True)
+    x = selecting_stream(cfg, widen_stream(
+        cfg, embed_tokens(cfg, params, tokens, None), True))
     positions = positions_of(*tokens.shape)
     keep = {"page": tokens.shape[1], "slot": 0 if slot_ids is None
             else slot_ids.shape[0] * ps}
@@ -4648,7 +5078,8 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
               for pages, new, s in zip(stores, rows, layout)]
     # final_norm and the head are per position: one row, not T
     x = collapse_stream(cfg, jax.tree.map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, last, 1, axis=1), x))
+        lambda a: jax.lax.dynamic_slice_in_dim(a, last, 1, axis=1),
+        stream_of(x)))
     logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
     return (*stores, logits[0, 0], shares)
 
@@ -4688,8 +5119,8 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
         ids["state"] = page_ids[jnp.maximum(pos - 1, 0) // ps][None]
     cached = [_read_pages(pages, ids[s.table])
               for pages, s in zip(stores, layout)]
-    x = widen_stream(cfg, embed_tokens(cfg, params, token[None, :], None),
-                     True)
+    x = selecting_stream(cfg, widen_stream(
+        cfg, embed_tokens(cfg, params, token[None, :], None), True), True)
     positions = jnp.full((1, 1), pos, dtype=jnp.int32)
     call = SimpleNamespace(
         pos=pos, page_ids=page_ids, stores=_by_kind(layout, stores),
@@ -4698,8 +5129,8 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
         cfg, x, params["layers"], positions,
         {c: partial(SERVED[c].decode, cfg, call) for c in call.stores},
         cached, [1] * len(layout))
-    logits = head_logits(cfg, collapse_stream(cfg, x), params["final_norm"],
-                         _head(cfg, params))
+    logits = head_logits(cfg, collapse_stream(cfg, stream_of(x)),
+                         params["final_norm"], _head(cfg, params))
     page = page_ids[pos // ps]
     at = {"page": (0, page, pos % ps), "state": (0, page, 0)}
     if slot_ids is not None:
@@ -4796,7 +5227,9 @@ class LlamaDecodeEngine:
     with ``"W"``; all ``"I"``; ``"D"`` with ``"A"``; ``"L"`` with ``"G"``,
     whose stream may be ``hc_mult`` rows a token:
     ``ray_tpu_serve_engine_stream_bytes``; ``"P"`` with ``"R"``; ``"H"``
-    with ``"N"``). A kind without a row
+    with ``"N"``; ``"Y"`` / ``"Z"`` / ``"X"``, whose stream carries a
+    selection from the layers that make one to those that reuse it:
+    ``ray_tpu_serve_engine_selecting_layers{role}``). A kind without a row
     (the ``"M"`` / ``"E"`` / ``"*"`` halves), a part or a mix of families,
     whole-projection
     QK-norm, the UNPATTERNED routed block and a prediction module
@@ -4901,6 +5334,11 @@ class LlamaDecodeEngine:
         for name, nbytes in by_dtype.items():
             _g_engine_weight_bytes.set(float(nbytes), tags={"dtype": name})
         _g_engine_traced_layers.set(float(traced_layers(self.cfg)))
+        stack = served_kinds(self.cfg)
+        for role, letters in (("select", "I" + DSA_FULL), ("reuse", "Z")):
+            _g_engine_selecting_layers.set(
+                float(sum(stack.count(c) for c in letters)),
+                tags={"role": role})
         self.page_size = int(page_size)
         self.pool = PagePool(n_pages, page_size)
         self.prefix_cache = PrefixCache(self.pool)

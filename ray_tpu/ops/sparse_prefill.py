@@ -489,6 +489,12 @@ def index_select_passes(qi, ki, w, topk: int, *, interpret: bool = False):
     return _index_select(qi, ki, w, topk, interpret)
 
 
+def mask_tiles_shape(B: int, T: int) -> tuple:
+    """The shape of :func:`index_select`'s tiles for ``T`` positions."""
+    return (B, T // SELECT_BLOCK_Q, -(-T // SELECT_BLOCK_K), SELECT_BLOCK_Q,
+            SELECT_BLOCK_K)
+
+
 def mask_rows(mask):
     """:func:`index_select`'s tiles as ``[B, T, Tk]``."""
     B, nq, nk, blk_q, blk_k = mask.shape

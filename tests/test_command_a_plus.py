@@ -631,10 +631,11 @@ def test_engine_refuses_a_mix_of_families(params):
     with pytest.raises(ValueError, match="every built layer is one of"):
         program_cfg(layer_pattern="RRRW" * 2)
     # "F" / "W" still want both of theirs: only these rows say ``alone``
-    # (and, since PR 58, the state-space layers' "H")
+    # (and, since PR 58, the state-space layers' "H"; since PR 63 the
+    # layers under a shared selection, any part whose first layer is full)
     assert llama.SERVED["P"].alone and llama.SERVED["R"].alone
     assert not any(kind.alone for c, kind in llama.SERVED.items()
-                   if c not in "PRH")
+                   if c not in "PRH" + llama.DSA_KINDS)
 
 
 # --- (d) the benchmark's files ---------------------------------------------- #

@@ -528,8 +528,7 @@ def test_benchmark_files_fit_together_with_the_new_cell():
 
     test_yardstick.test_benchmark_files_fit_together()
     bench = spec.load_benchmark()
-    assert len(bench["workloads"]) == 13
-    assert sum(c["chips"] == 4 for c in bench["workloads"]) == 1
+    # how many cells there are: tests/test_benchmark_cells.py, and there alone
     b = spec.cell_bundle(CELL)
     assert (b["cell"]["chips"], b["cell"]["traffic"], b["cell"]["config"]) \
         == (1, "prefill-open-2048-16000-granite4h", "granite-4.0-h-micro")

@@ -55,7 +55,16 @@ OLD = {
         _kinds(cfg, "HN"),
         f"no 'H' / 'N' layer (layer_pattern={cfg.layer_pattern!r}; "
         f"embedding_multiplier, attention_multiplier, residual_multiplier)"),
+    # PR 63's row, likewise the table's own words
+    "_no_selected_kinds": lambda cfg: (
+        _kinds(cfg, "YZX"),
+        f"no 'Y' / 'Z' / 'X' layer (layer_pattern={cfg.layer_pattern!r}, "
+        f"first_layer={cfg.first_layer}, index_topk={cfg.index_topk})"),
 }
+
+_NO_WAY_ACROSS_STAGES = (
+    "its stages pass the residual stream alone: a selection made on "
+    "one stage has no way to the shared layers of the next")
 
 
 def _engine_dense_only(cfg):
@@ -102,6 +111,11 @@ CALLS = {
          "no train step is held to a reference for the hybrid blocks' "
          "backward or their three multipliers' (the Mamba-2 mixer's own is "
          "held as the 'M' half); models.llama.loss_fn runs the forward"),
+        ("_no_selected_kinds",
+         "no train step carries a selection from the layer that makes it to "
+         "the layers that read it, masks its flash kernel by one, or is held "
+         "to a reference for an indexer's backward; LlamaDecodeEngine "
+         "serves these kinds"),
         ("_no_wide_latent",
          "no train step is held to a reference for the mixes' backward or "
          "keeps a stream of several rows' recomputation in its account, and "
@@ -115,6 +129,10 @@ CALLS = {
          "no train step is held to a reference for the hybrid blocks' "
          "backward or their three multipliers'; models.llama.loss_fn runs "
          "the forward"),
+        ("_no_selected_kinds",
+         "no train step carries a selection from the layer that makes it to "
+         "the layers that read it or is held to a reference for an "
+         "indexer's backward; LlamaDecodeEngine serves these kinds"),
         ("_no_wide_latent",
          "no train step is held to a reference for the mixes' backward, and "
          "its flash kernel attends q, k and v of one width")],
@@ -131,10 +149,12 @@ CALLS = {
         ("_no_window_kinds",
          "its stages run the dense block over the flash kernel, which has "
          "no window, and pass no router's losses on"),
+        ("_no_selected_kinds", _NO_WAY_ACROSS_STAGES),
         ("_dense_only",
          "its stages pass the residual stream alone, so a router's losses "
          "have no way out, and its layer specs name the dense leaves only")],
     "the MPMD pipeline": [
+        ("_no_selected_kinds", _NO_WAY_ACROSS_STAGES),
         ("_dense_only",
          "its stages pass the residual stream alone, so a router's "
          "losses have no way out, and no test runs QK-norm through it")],
@@ -182,6 +202,7 @@ CONFIGS = {
     "L G in part": lambda: _glm(layer_pattern="LLL"),
     "the module alone": lambda: _glm(mtp_layers=1),
     "four rows a token and a wide score (xing4)": _of("test_xing4"),
+    "Y Z X (glm-5.2)": _of("test_glm_moe_dsa"),
 }
 
 

@@ -729,7 +729,8 @@ def no_page_bytes(cfg):
     ("dense", 16, 4, None, 1),         # no routed kind: the share is not set
     ("longcat_flash", 12, 8, 0.0, 1),  # from the program's shares
     ("smallthinker", 24, 5, 1.0, 4),   # every expert here; F, W x 3, FW
-    ("keye_vl2", 24, 4, 0.0, 1)])
+    ("keye_vl2", 24, 4, 0.0, 1),
+    ("glm_moe_dsa", 24, 8, 0.0, 3)])   # X, ONE body of Z scanned, Y
 def test_the_engine_is_what_the_table_folds(family, n_pages, page, held,
                                             traced):
     """Stores, slots and the ``page_bytes`` tags are ``served_stores``'
@@ -762,7 +763,7 @@ def test_the_engine_is_what_the_table_folds(family, n_pages, page, held,
     # the literal set of tags, in THIS place alone: a new kind adds its own
     assert set(want) == {"kv", "latent", "full", "window", "index", "gated",
                          "latent_block", "parallel_full", "parallel_window",
-                         "hybrid"}
+                         "hybrid", "dsa_latent", "dsa_index"}
     # these families keep nothing a SEQUENCE (tests/test_qwen3_next.py)
     assert set(_gauge("ray_tpu_serve_engine_state_bytes").values()) == {0.0}
     for part in ("held", "zero", "elsewhere"):
