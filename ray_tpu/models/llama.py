@@ -2238,12 +2238,17 @@ def prefill_attend_paths() -> list:
         return [dict(rec) for rec in _prefill_attend_taken.values()]
 
 
-def _note_prefill_attend(kind, q, k, window, path, reason, xla="tiles"):
+def _note_prefill_attend(kind, q, k, window, path, reason, xla="tiles",
+                         step=None):
+    """``step``: what a grid step of the kind's kernel holds, where the
+    kernel chooses it from the shapes (``ops/sparse_prefill.py
+    flash_step``)."""
     key = (kind, q.shape, k.shape, window, path)
     with _paths_lock:
         rec = _prefill_attend_taken.setdefault(key, {
             "kind": kind, "q_shape": list(q.shape), "k_shape": list(k.shape),
-            "window": window, "path": path, "reason": reason, "calls": 0})
+            "window": window, "path": path, "reason": reason, "calls": 0,
+            **(step or {})})
         rec["calls"] += 1
         counts = {way: sum(r["calls"] for r in _prefill_attend_taken.values()
                            if (r["kind"], r["path"]) == (kind, way))
@@ -2859,6 +2864,17 @@ def selected_attend_path(q, cd) -> Tuple[str, str]:
     return "kernel", "tpu backend"
 
 
+def _masked_flash_step(path, q, k, v):
+    """What a grid step of the selection's attention kernel holds for these
+    operands on the kernel's ``path`` (``ops/sparse_prefill.py flash_step``,
+    which ``masked_flash`` itself calls), None on the other."""
+    if path != "kernel":
+        return None
+    from ray_tpu.ops.sparse_prefill import flash_step
+
+    return flash_step(q, k, v)
+
+
 def attend_selected(cfg: LlamaConfig, q, k, v, qi, ki, w):
     """:func:`index_block`'s ``attend`` over the call's own positions (the
     full forward and prefill): index scores of every visible pair, the
@@ -2873,7 +2889,8 @@ def attend_selected(cfg: LlamaConfig, q, k, v, qi, ki, w):
     stacked into one product. On every other backend, and as the oracle,
     :func:`_selected_tiles` in XLA."""
     path, reason = selected_attend_path(q, cfg.dtype)
-    _note_prefill_attend("selected", q, k, 0, path, reason)
+    _note_prefill_attend("selected", q, k, 0, path, reason,
+                         step=_masked_flash_step(path, q, k, v))
     if path == "kernel":
         from ray_tpu.ops.sparse_prefill import index_select, masked_flash
 
@@ -3046,8 +3063,9 @@ def attend_latent_selected(cfg: LlamaConfig, q, latent, wkv_b, index, chosen):
     paths and ONE arithmetic, counted as kind ``latent_selected``) and a
     shared layer takes as it came. Returns ``(o, chosen)``."""
     path, reason = selected_prefill_path(cfg, q.shape[1])
-    _note_prefill_attend("latent_selected", q, latent, 0, path, reason)
     k, v = _latent_heads(cfg, latent, wkv_b)
+    _note_prefill_attend("latent_selected", q, latent, 0, path, reason,
+                         step=_masked_flash_step(path, q, k, v))
     if path == "kernel":
         from ray_tpu.ops.sparse_prefill import index_select, masked_flash
 

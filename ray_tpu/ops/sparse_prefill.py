@@ -44,7 +44,10 @@ that see no more than ``topk`` keys select all of them.
 heads are stacked into the rows of one score product, so the mask tile is
 read once a group and not once a head; a group's whole keys and values lie
 in VMEM while its query blocks go by (``ops/flash_prefill.py``'s layout),
-and a query block's loop over key blocks ends at its diagonal. The mask
+and a query block's loop over key blocks ends at its diagonal. A grid
+step holds up to 1,024 stacked rows whatever the group's size
+(:func:`flash_step`): one query block of a group of eight heads, eight
+consecutive query blocks where a key head has ONE query head. The mask
 already holds causality. Every tile under the diagonal is computed: with
 2,048 of up to 32,768 keys chosen by a random indexer no tile is empty.
 
@@ -79,9 +82,19 @@ SELECT_STUCK = (4, 256)
 # to stand in a run of equal keys
 SELECT_STILL = 3
 SELECT_EDGES = 4   # the most edge passes of a query block
-FLASH_BLOCK_Q = 128    # queries (of every head of a group) a grid step
+FLASH_BLOCK_Q = 128    # queries a mask tile holds
 FLASH_BLOCK_K = 512    # keys a mask tile holds; masked_flash takes
-FLASH_TILES = 2        # this many tiles a loop step
+# this many tiles (1,024 keys) a loop step: what a step costs beside its
+# products (the running maximum and sum of its stacked rows, a lane each, and
+# the rescaling) is paid once for twice the keys: 67.3 ms against 126.7 at
+# 32,768 positions, 18.6 against 33.4 at 16,384; four tiles a step 72.8 and
+# 20.5 (32 heads on 4 of 128; my chip run, PR 40)
+FLASH_TILES = 2
+FLASH_ROWS = 1024      # stacked query rows a grid step of masked_flash holds
+# what a call of masked_flash asks of the chip's 128 MiB of VMEM at most:
+# 1,024 rows at 16,384 positions and width 256 ask 100 MiB and run (my chip
+# runs, PR 64)
+FLASH_VMEM = 104 * 2 ** 20
 
 
 def _lanes(n: int) -> int:
@@ -501,14 +514,60 @@ def mask_rows(mask):
     return jnp.swapaxes(mask, 2, 3).reshape(B, nq * blk_q, nk * blk_k)
 
 
+def flash_step(q, k, v) -> dict:
+    """What a grid step of :func:`masked_flash` holds for these operands
+    (their shapes and type alone: ``rep`` query heads a key head, ``T``
+    positions, head widths ``D`` / ``dv``): ``rows_a_step`` stacked query
+    rows, ``rep`` heads of ``n`` consecutive query blocks, of
+    ``heads_a_step`` key heads, and the ``vmem_bytes`` the call asks for.
+    ``n`` is the largest of 8, 4, 2, 1 that keeps the rows within
+    ``FLASH_ROWS``, divides the query blocks and asks no more than
+    ``FLASH_VMEM`` (``n`` 1 asks what it asks). A product of 128 rows
+    leaves the MXU loading a weight tile as long as it streams rows through
+    it: with ONE query head a key head at width 256, 16,384 positions, 128
+    rows a step 92.0 ms, 256: 73.4, 512: 69.1, 1,024: 67.1 (66.3 with the
+    bias spread over the heads where it is added, as the body has it),
+    which is what a group of eight heads takes for the same pairs at 32,768
+    positions and width 128 (67.4); the mask's way to a bias is not the
+    cost (taken out: 92.3 ms at 128 rows, 68.4 for 69.1 at 512), so ONE key
+    head a step: two of 128 rows each run 83.7, two of 256 70.3; nor is the
+    keys' transpose (handed over transposed: 92.2 and 68.7; my chip runs,
+    PR 64). Up to
+    eight consecutive query blocks that start on a multiple of their count
+    end in the same loop step of 1,024 keys, so a step computes no tile its
+    blocks did not compute alone."""
+    T, D, dv = q.shape[1], q.shape[3], v.shape[3]
+    rep, item = q.shape[2] // k.shape[2], jnp.dtype(q.dtype).itemsize
+    blk_q, keys = FLASH_BLOCK_Q, FLASH_TILES * FLASH_BLOCK_K
+    Tk = -(-T // keys) * keys
+
+    def vmem(n):
+        # q, o, a head's whole keys and values and the mask row twice
+        # (double buffering), the scratch, and the kernel's own tiles: the
+        # stacked scores [rows, keys] float32 a few times
+        rows = n * rep * blk_q
+        return (2 * (rows * _lanes(D) * item + Tk * _lanes(D) * item
+                     + Tk * _lanes(dv) * item + n * blk_q * Tk
+                     + rows * _lanes(dv) * item)
+                + rows * (2 * _LANE + _lanes(dv)) * 4 + 8 * rows * keys * 4)
+
+    n = next(n for n in (8, 4, 2, 1) if n == 1 or (
+        n * rep * blk_q <= FLASH_ROWS and T // blk_q % n == 0
+        and vmem(n) <= FLASH_VMEM))
+    return {"rows_a_step": n * rep * blk_q, "heads_a_step": 1,
+            "vmem_bytes": vmem(n)}
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
                   acc_ref, *, scale, rep, blk_q, blk_k, tiles):
-    """q (1,1,1,rep*blk_q,D) a group's query heads stacked, head-major;
-    k, v (1,1,Tk,D); mask (1,1,n_blocks,blk_q,blk_k) int8; o as q; scratch: running
-    maximum and sum (rep*blk_q,1), weighted values (rep*blk_q,Dv), f32."""
+    """q (1,1,1,rows,D): ``rows`` = n*rep*blk_q, a group's query heads
+    stacked, head-major, a query block after another; k, v (1,1,Tk,D); mask
+    (1,n,n_blocks,blk_q,blk_k) int8; o as q; scratch: running maximum and
+    sum (rows,1), weighted values (rows,Dv), f32."""
     f32 = jnp.float32
     i = pl.program_id(2)
     q = q_ref[0, 0, 0]
+    n = mask_ref.shape[1]
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, f32)
     l_ref[...] = jnp.zeros(l_ref.shape, f32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
@@ -520,11 +579,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
         s = jax.lax.dot_general(q, k_ref[0, 0, at, :],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=f32) * scale
-        seen = [mask_ref[0, 0, j * tiles + n].astype(jnp.int32)
-                for n in range(tiles)]
-        seen = (seen[0] if tiles == 1 else jnp.concatenate(seen, axis=1)) != 0
-        bias = jnp.where(seen, 0.0, NEG_INF)
-        s = s + jnp.concatenate([bias] * rep, axis=0)
+        seen = [mask_ref[0, :, j * tiles + t] for t in range(tiles)]
+        seen = (seen[0] if tiles == 1
+                else jnp.concatenate(seen, axis=2)).astype(jnp.int32) != 0
+        # a query block's bias under each of its heads' scores: spread over
+        # the heads where it is added, never laid out ``rep`` times
+        bias = jnp.where(seen, 0.0, NEG_INF)[:, None]
+        s = (s.reshape(n, rep, blk_q, keys) + bias).reshape(s.shape)
         m = m_ref[...]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -537,45 +598,43 @@ def _flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref,
             preferred_element_type=f32)
         return carry
 
-    # up to the block that holds the last query's own position; a row whose
-    # first blocks hold none of its keys carries exp(0) sums until its first
+    # up to the block that holds the step's last query's own position (the
+    # mask holds zeros past an earlier block's diagonal); a row whose first
+    # blocks hold none of its keys carries exp(0) sums until its first
     # chosen key rescales them by 0 (every row has chosen its own block's)
-    jax.lax.fori_loop(0, ((i + 1) * blk_q - 1) // keys + 1, step, 0)
+    jax.lax.fori_loop(0, ((i + 1) * n * blk_q - 1) // keys + 1, step, 0)
     o_ref[0, 0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def masked_flash(q, k, v, mask, *, tiles: int = FLASH_TILES,
-                 interpret: bool = False):
+def masked_flash(q, k, v, mask, *, interpret: bool = False):
     """Attention of ``q`` [B, T, H, D] over ``k`` / ``v`` [B, T, Hkv, D / Dv]
     (``Hkv`` divides ``H``; one type) under ``mask`` (:func:`index_select`'s
     tiles: non-zero where query ``t`` attends key ``s``,
     nothing beyond ``s = t``; every row attends at least one key of its own
     key block or an earlier one). Scale ``1 / sqrt(D)``, float32 scores and
-    softmax. ``tiles`` mask tiles (1,024 keys) a loop step: what a step
-    costs beside its products (the running maximum and sum of 1,024 stacked
-    rows, a lane each, and the rescaling) is paid once for twice the keys:
-    67.3 ms against 126.7 at 32,768 positions, 18.6 against 33.4 at 16,384;
-    four tiles a step 72.8 and 20.5 (my chip run, PR 40). Returns [B, T, H,
-    Dv] in the operands' type."""
+    softmax. A grid step is :func:`flash_step`'s, chosen from the operands'
+    shapes. Returns [B, T, H, Dv] in the operands' type."""
     B, T, H, D = q.shape
     G, dv = k.shape[2], v.shape[3]
     rep = H // G
-    blk_q, blk_k = FLASH_BLOCK_Q, FLASH_BLOCK_K
-    nq, nk = T // blk_q, mask.shape[2]
-    if T % blk_q or H % G or mask.shape != (B, nq, nk, blk_q, blk_k) \
-            or nk * blk_k < T:
+    blk_q, blk_k, tiles = FLASH_BLOCK_Q, FLASH_BLOCK_K, FLASH_TILES
+    if T % blk_q or H % G or mask.shape != mask_tiles_shape(B, T):
         raise ValueError(f"q {q.shape}, k {k.shape}, mask {mask.shape}")
+    nq, nk = mask.shape[1:3]
     if nk % tiles:  # whole loop steps: tiles of zeros, never seen
         mask = jnp.pad(mask, ((0, 0), (0, 0), (0, -nk % tiles), (0, 0),
                               (0, 0)))
         nk = mask.shape[2]
     Tk = nk * blk_k
+    took = flash_step(q, k, v)
+    rows = took["rows_a_step"]
+    n = rows // (rep * blk_q)   # query blocks a grid step
 
-    def stacked(a):  # [B, T, G * rep, d] -> [B, G, nq, rep * blk_q, d]
+    def stacked(a):  # [B, T, G * rep, d] -> [B, G, nq / n, n * rep * blk_q, d]
         d = a.shape[-1]
         a = a.reshape(B, nq, blk_q, G, rep, d)
         return jnp.transpose(a, (0, 3, 1, 4, 2, 5)).reshape(
-            B, G, nq, rep * blk_q, d)
+            B, G, nq // n, rows, d)
 
     def keys(a):  # [B, T, G, d] -> [B, G, Tk, d]
         return jnp.swapaxes(jnp.pad(
@@ -584,32 +643,25 @@ def masked_flash(q, k, v, mask, *, tiles: int = FLASH_TILES,
     kernel = functools.partial(_flash_kernel, scale=1.0 / math.sqrt(D),
                                rep=rep, blk_q=blk_q, blk_k=blk_k,
                                tiles=tiles)
-    item = q.dtype.itemsize
-    rows = rep * blk_q
-    vmem = (2 * (rows * _lanes(D) * item + Tk * _lanes(D) * item
-                 + Tk * _lanes(dv) * item + blk_q * Tk
-                 + rows * _lanes(dv) * item)
-            + rows * (2 * _LANE + _lanes(dv)) * 4
-            + 8 * rows * tiles * blk_k * 4)
     o = pl.pallas_call(
         kernel,
-        grid=(B, G, nq),
+        grid=(B, G, nq // n),
         in_specs=[
             pl.BlockSpec((1, 1, 1, rows, D), lambda b, g, i: (b, g, i, 0, 0)),
             pl.BlockSpec((1, 1, Tk, D), lambda b, g, i: (b, g, 0, 0)),
             pl.BlockSpec((1, 1, Tk, dv), lambda b, g, i: (b, g, 0, 0)),
-            pl.BlockSpec((1, 1, nk, blk_q, blk_k),
+            pl.BlockSpec((1, n, nk, blk_q, blk_k),
                          lambda b, g, i: (b, i, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, rows, dv),
                                lambda b, g, i: (b, g, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, G, nq, rows, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G, nq // n, rows, dv), q.dtype),
         scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                         pltpu.VMEM((rows, 1), jnp.float32),
                         pltpu.VMEM((rows, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
-            vmem_limit_bytes=int(vmem)),
+            vmem_limit_bytes=int(took["vmem_bytes"])),
         interpret=interpret,
         name="dsa_masked_flash",
     )(stacked(q), keys(k), keys(v), mask)

@@ -8,6 +8,7 @@ persistent cache (``conftest.py``) serves it to every later run."""
 from functools import partial
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import llama
@@ -84,3 +85,25 @@ def assert_served_alike(got, want, tol=1e-5):
         assert values.keys() == got["stats"][name].keys(), name
         for tags, value in values.items():
             alike(got["stats"][name][tags], value, (name, tags))
+
+
+def traced_prefill_step(cfg, kind, monkeypatch, positions=256):
+    """``prefill_attend_paths()``'s record of ``kind`` after a prefill of
+    ``positions`` tokens is TRACED (shapes alone, nothing lowered) with the
+    selection's kernels steered on, as a TPU backend takes them; in a
+    record of the test's own, so that no other test reads a steered path."""
+    monkeypatch.setattr(llama, "selected_attend_path",
+                        lambda q, cd: ("kernel", "steered by a test"))
+    monkeypatch.setattr(llama, "_prefill_attend_taken", {})
+    params = jax.eval_shape(lambda key: llama.serving_params(
+        cfg, llama.init_params(cfg, key)), jax.random.PRNGKey(0))
+    stores = [jax.ShapeDtypeStruct(s.shape(2, 0, positions), jnp.float32)
+              for s in llama.served_stores(cfg)]
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    jax.eval_shape(partial(llama.prefill_with_cache, cfg), params, *stores,
+                   i32(1, positions), i32(1), i32())
+    mine, = [r for r in llama.prefill_attend_paths() if r["kind"] == kind]
+    return mine

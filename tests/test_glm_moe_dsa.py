@@ -350,12 +350,18 @@ def test_the_sixteen_shares_routed_parts_add_up_to_the_uncut_layers():
 # --- (c) the kernels at the published indexer's shape ---------------------- #
 
 
-@pytest.mark.parametrize("seq,topk,ties", [(256, 24, True), (384, 100, False)])
-def test_the_pallas_mask_is_select_tops_at_32_heads_of_128(seq, topk, ties):
+@pytest.mark.parametrize("seq,topk,ties,a_step", [
+    (256, 24, True, 256), (384, 100, False, 128),
+    # the step holds up to eight query blocks where a key head has ONE query
+    # head: 3 blocks no count divides, 6 in three steps of 2, 8 in one step
+    (384, 24, True, 128), (768, 200, False, 256), (1024, 300, True, 1024)])
+def test_the_pallas_mask_is_select_tops_at_32_heads_of_128(seq, topk, ties,
+                                                           a_step):
     """``index_select`` interpreted at 32 x 128 (4,096 stacked rows a query
     block) against ``select_top`` entry for entry, planted ties included,
-    and ``masked_flash`` at head width 256 with a key head a query head
-    against the XLA path under the same mask."""
+    and ``masked_flash`` at head width 256 with a key head a query head, in
+    grid steps of ``a_step`` rows, against the XLA path under the same
+    mask."""
     from ray_tpu.ops import sparse_prefill as sp
 
     k = jax.random.split(jax.random.PRNGKey(seq), 6)
@@ -374,12 +380,30 @@ def test_the_pallas_mask_is_select_tops_at_32_heads_of_128(seq, topk, ties):
     q = jax.random.normal(k[3], (1, seq, 2, 256))
     kk = jax.random.normal(k[4], (1, seq, 2, 256))
     v = jax.random.normal(k[5], (1, seq, 2, 256))
+    step = sp.flash_step(q, kk, v)
+    assert (step["rows_a_step"], step["heads_a_step"]) == (a_step, 1)
     got = jax.jit(lambda *a: sp.masked_flash(*a, interpret=True))(
         q, kk, v, mask)
     tiles, _ = jax.jit(lambda *a: llama._latent_selected_tiles(
         *a, None, want[None].astype(jnp.int8), topk, jnp.float32, 64))(
             q, kk, v)
     assert worst(got, tiles) < 1e-5
+
+
+def test_a_traced_prefill_says_the_step_its_kernel_chose(monkeypatch):
+    """A ``Y`` / ``Z`` stack's expanded keys are a key head a query head
+    (``rep`` 1): a full and a shared layer's ``masked_flash`` hold the two
+    query blocks of 256 positions in ONE grid step, of ONE key head."""
+    from ray_tpu.ops import sparse_prefill as sp
+
+    cfg = program_cfg(small_file("YZ", 0, 2))
+    rec = jitted.traced_prefill_step(cfg, "latent_selected", monkeypatch)
+    assert rec["path"] == "kernel" and rec["calls"] >= 2
+    shapes = [jax.ShapeDtypeStruct((1, 256, 4, 24), jnp.float32)] * 3
+    assert rec["q_shape"] == list(shapes[0].shape)
+    assert {k: rec[k] for k in sp.flash_step(*shapes)} \
+        == sp.flash_step(*shapes)
+    assert (rec["rows_a_step"], rec["heads_a_step"]) == (256, 1)
 
 
 # --- (d) what the engine holds ---------------------------------------------- #

@@ -18,7 +18,8 @@ import pytest
 
 from benchmarks.lib import spec
 from benchmarks.reference import keye_vl2_decoder as ref
-from jitted import forward, init_params, loss_fn, reference, value_and_grad
+from jitted import (forward, init_params, loss_fn, reference,
+                    traced_prefill_step, value_and_grad)
 from ray_tpu.models import llama
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.moe import routed_mlp
@@ -402,6 +403,56 @@ def test_the_kernels_are_the_xla_path(seq, topk, planted):
         q, kk, v, mask)
     close(got, jax.jit(lambda *a: llama._selected_tiles(
         *a, topk, jnp.float32, 64))(q, kk, v, qi, ki, w), 1e-5)
+
+
+# sha256 of the bytes of ``masked_flash`` interpreted on the CPU at 16 query
+# heads on 2 (``rep`` 8), 384 positions, ``topk`` 100, planted ties, taken on
+# 04073f6: before a grid step was chosen from ``rep``
+REP_8_OUTPUT = "3cb2771b95deb0a23f9bf9943bb2f213b16e3ea18cf9d69050d3df46f2294441"
+
+
+def test_a_group_of_eight_heads_keeps_its_step_and_its_bits():
+    """``flash_step`` gives Keye's shape (32 query heads on 4 key heads of
+    128: ``rep`` 8) ONE query block of 1,024 stacked rows a grid step at
+    every page count of the cell, what a step was before it was chosen from
+    ``rep``, and the kernel's output at ``rep`` 8 is the one it gave then,
+    to the last bit."""
+    import hashlib
+
+    from ray_tpu.ops import sparse_prefill as sp
+
+    for pages in range(4, 17):
+        q, kk, v = (jax.ShapeDtypeStruct((1, pages * 2048, h, 128),
+                                         jnp.bfloat16) for h in (32, 4, 4))
+        step = sp.flash_step(q, kk, v)
+        assert (step["rows_a_step"], step["heads_a_step"]) == (1024, 1)
+    seq, topk = 384, 100
+    k = jax.random.split(jax.random.PRNGKey(seq + 1), 3)
+    q = jax.random.normal(k[0], (1, seq, 16, 16))
+    kk = jax.random.normal(k[1], (1, seq, 2, 16))
+    v = jax.random.normal(k[2], (1, seq, 2, 16))
+    assert sp.flash_step(q, kk, v)["rows_a_step"] == 1024
+    mask = jax.jit(lambda *a: sp.index_select(*a, topk, interpret=True))(
+        *selection_operands(seq, "ties"))
+    got = jax.jit(lambda *a: sp.masked_flash(*a, interpret=True))(
+        q, kk, v, mask)
+    assert hashlib.sha256(np.ascontiguousarray(got).tobytes()).hexdigest() \
+        == REP_8_OUTPUT
+
+
+def test_a_traced_prefill_says_the_step_its_kernel_chose(monkeypatch):
+    """An indexed stack of 16 query heads on 2 (``rep`` 8): ONE query block
+    of 8 x 128 stacked rows a grid step of ``masked_flash``, ONE key head."""
+    from ray_tpu.ops import sparse_prefill as sp
+
+    cfg = program_cfg(num_attention_heads=16, max_position_embeddings=256)
+    rec = traced_prefill_step(cfg, "selected", monkeypatch)
+    assert rec["path"] == "kernel" and rec["q_shape"] == [1, 256, 16, 16]
+    shapes = [jax.ShapeDtypeStruct((1, 256, h, 16), jnp.float32)
+              for h in (16, 2, 2)]
+    assert {k: rec[k] for k in sp.flash_step(*shapes)} \
+        == sp.flash_step(*shapes)
+    assert (rec["rows_a_step"], rec["heads_a_step"]) == (1024, 1)
 
 
 @pytest.mark.parametrize("seq,topk,planted,heads,most", [

@@ -1,12 +1,16 @@
-"""``serve-glm52-prefill-open``'s WHOLE programs compiled for a described
-v5e, with ``tests/test_tpu_compile.py``'s helpers and fixtures; a file of its
-own for the reason ``tests/test_tpu_compile_commandaplus.py`` gives (the
-driver hands a FILE to one worker)."""
+"""``serve-glm52-prefill-open``'s WHOLE programs, and its attention kernel
+alone, compiled for a described v5e, with ``tests/test_tpu_compile.py``'s
+helpers and fixtures; a file of its own for the reason
+``tests/test_tpu_compile_commandaplus.py`` gives (the driver hands a FILE to
+one worker)."""
 
 import math
 import re
 
 import pytest
+
+jax = pytest.importorskip("jax")
+jnp = jax.numpy
 
 from test_tpu_compile import (  # noqa: F401 - the two fixtures are used by name
     _cell_program, as_on_the_chip, one_chip)
@@ -50,3 +54,32 @@ def test_glm52_cells_programs_compile_and_fit_the_chip(
         # no key or value is expanded: nothing [positions, 64, 256]
         assert not re.findall(r"\[\d{4,},64,256\]", text)
     assert held + memory.temp_size_in_bytes < 15.0e9
+
+
+@pytest.mark.parametrize("pages", [3, 8])
+def test_masked_flash_compiles_at_glm52s_shape(pages, one_chip,
+                                               as_on_the_chip):
+    """``masked_flash`` ALONE at the cell's shape (64 query heads on 64
+    expanded key heads of 256) and its shortest and longest page tables: a
+    grid step of eight query blocks (1,024 rows: ``flash_step``) beside a
+    head's whole keys and values and eight rows of mask tiles, each twice, is
+    60 MiB of VMEM at 3 pages and 100 at 8, asked for and under the chip's
+    128. One Mosaic call: what interpret mode cannot refuse."""
+    from ray_tpu.ops import sparse_prefill as sp
+
+    t = pages * 2048
+
+    def arg(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = arg(1, t, 64, 256)
+    step = sp.flash_step(q, q, q)
+    assert (step["rows_a_step"], step["heads_a_step"]) == (1024, 1)
+    assert step["vmem_bytes"] == {3: 60, 8: 100}[pages] * 2 ** 20 \
+        <= sp.FLASH_VMEM < 128 * 2 ** 20
+    lowered = jax.jit(sp.masked_flash).lower(
+        q, q, q, arg(*sp.mask_tiles_shape(1, t), dtype=jnp.int8))
+    assert "scoped_memory_configs" in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"bf16[1,64,{t // 1024},1024,256]" in text
