@@ -40,6 +40,7 @@ from __future__ import annotations
 import itertools
 import math
 import threading
+from contextlib import nullcontext
 from dataclasses import dataclass
 from functools import partial
 from types import SimpleNamespace
@@ -81,6 +82,16 @@ _g_engine_traced_layers = Gauge(
     "ray_tpu_serve_engine_traced_layers",
     "Layer bodies one serving program of the decode engine traces: a "
     "scanned period's layers or a scanned run's one, and each layer in line")
+
+# where a prefill's stream is cut to the last real position (stream_cut): the
+# layers it runs over ALL positions and over ONE. Every family whose every
+# layer keeps a row reads its depth and 0
+_g_engine_prefill_layers = Gauge(
+    "ray_tpu_serve_engine_prefill_layers",
+    "Layers a prefill of the decode engine runs over every position of the "
+    "prompt (positions=all) and over the last real one alone (positions=one: "
+    "the layers behind the last that keeps a row)",
+    tag_keys=("positions",))
 
 # what ONE position (in a store by slot: one SLOT position) leaves in the
 # engine's page stores, over all layers, summed under the tag each store has
@@ -237,7 +248,9 @@ LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "S": "scmoe",
                "A": "gated", "L": "latent", "G": "latent_dense",
                "P": "parallel", "R": "parallel",
                "H": "hybrid_mamba", "N": "hybrid_attn",
-               "Y": "dsa_full", "Z": "dsa_shared", "X": "dsa_dense"}
+               "Y": "dsa_full", "Z": "dsa_shared", "X": "dsa_dense",
+               "m": "memory_mamba", "w": "memory_attn", "f": "memory_attn",
+               "g": "memory_gate", "c": "memory_cross"}
 # the kinds that are a WHOLE block (attention THEN the routed MLP, whose
 # router reads the attention's normed input; window_block): "F" attends over
 # every earlier position without rotation, "W" over the last cfg.window with
@@ -256,8 +269,8 @@ LATENT_KINDS = "LG"
 # without rotation, "R" over the last cfg.window with rotation. The same
 # leaves, ONE stack in layer order (as "F" / "W")
 PARALLEL_KINDS = "PR"
-# the kinds whose attention has cfg.window (and rotates)
-WINDOW_KINDS = "WR"
+# the kinds whose attention has cfg.window ("W" and "R" rotate, "w" does not)
+WINDOW_KINDS = "WRw"
 # the state-space hybrid's WHOLE blocks (hybrid_block): a mixer, then a dense
 # SwiGLU, each added ``residual_multiplier`` times. "H" mixes through Mamba-2
 # (ops/ssm.py: a STATE a sequence, no row a position), "N" through GQA without
@@ -274,6 +287,19 @@ HYBRID_KINDS = "HN"
 # order and reason). SERVED only
 DSA_KINDS = "YZX"
 DSA_FULL = "YX"
+# the decoder-hybrid-decoder's WHOLE blocks (memory_block, arXiv:2507.06607):
+# a mixer, then a dense SwiGLU, each under a LayerNorm with gain AND bias.
+# "m" mixes through Mamba-1 (ops/s6.py: a STATE a sequence, no row a
+# position) and hands its scan's output on beside the stream, THE MEMORY.
+# "w" / "f": DIFFERENTIAL attention without rotation (two softmax maps over
+# one value, their difference normed), over the last cfg.window positions or
+# over every earlier one; "f" hands its keys and values on beside the
+# stream. "g" (a gated memory unit) gates the newest memory at its own
+# position, "c" (cross attention) attends the newest "f" layer's keys and
+# values with a query of its own and has no key or value projection: NEITHER KEEPS A ROW.
+# "w" and "f" have the same leaves and share ONE stack in layer order, the
+# others a stack each. SERVED only
+MEMORY_KINDS = "mwfgc"
 # Where their matrices start off the square root of their fan-in (seeded random
 # weights; a checkpoint brings its own): ``wo`` 12 times as wide, the router
 # 6 times, so that BOTH halves carry the logits and a comparison of logits
@@ -413,6 +439,44 @@ PARALLEL_INIT = {"wq": 2.0, "wo": 24.0}
 # the configuration file's ``correct``.
 HYBRID_INIT = {"dt": (0.02, 0.2), "A": (0.25, 1.0), "conv_b": 1.0,
                "wq": 16.0, "wo": 12.0, "embedding": 2.0}
+# Where the "m" / "w" / "f" / "g" / "c" blocks' SEEDED leaves start (a
+# checkpoint brings its own), for BLOCK_INIT's reason: every mechanism has to
+# carry enough of a logit that a comparison of logits refuses a fault in it,
+# and a SOUND engine must not trip over its own rounding. ``embedding``: the
+# table's rows start THIS wide an element (the tied head with them), not one
+# over the square root of the width. Thirty-two undamped blocks of seeded
+# weights are a chaotic map: each block rounds some ten bfloat16 operands
+# (0.35% of its output), every later block reads that through a LayerNorm,
+# and an attention whose scores are several units wide multiplies it: at the
+# fan-in start the sound bfloat16 engine read 4-15% of a logit against the
+# float32 reference on the chip, a window of 511 19-30% (my chip run, PR 65).
+# With the rows 6 wide the stream is the token's own embedding plus the
+# blocks' sums (the published family has no such scale; granite's
+# embedding_multiplier 12 and residual_multiplier 0.22 do the same there): a
+# block reads nearly clean inputs, its rounding and its faults reach the
+# logits by its own share and do not compound. ``dt`` and ``A``: HYBRID_INIT's
+# reasoning for a state that is [channels, 16]: the time step's range
+# (log-uniform; dt_bias its inverse softplus, r W_dt scatters each position's
+# around it) and the decay rate's (uniform, a channel AND a state; the
+# published start A[:, n] = n + 1 forgets a token inside a step or two), so
+# that S C stands beside the skip D u and a state read from zeros, the skip
+# dropped or the tail zeroed each move a logit. ``conv_b``: the convolution's
+# bias, normal at this width. ``wq``: the WINDOW layers' query projections
+# start this many times as wide: scores that many units wide, so that ONE or
+# two of a window's 512 keys carry a query's weight and a window of 511 or 513
+# shows where the key at its edge is one of them (at one unit the softmax
+# over 512 keys is an average, one key a five-hundredth of it). ``wq_full``:
+# the full and the cross layers' (whose faults are whole key sets, which show
+# at any width). ``wo``: the attention's output product, so that the 9 + 7
+# attention layers carry their share beside the Mamba and memory layers'.
+# ``lam``: the four learned 64-vectors a layer start normal at this width
+# (lq . lk is then some 64 lam^2 wide): lam differs from lam0 by a learned
+# amount and lam at 0 or lam0 alone shows. The biases on Wq, Wkv and Wo start
+# normal at ``bias``. Which tokens go where and every product's shape and time
+# depend on none of them. Readings: the configuration file's ``correct``.
+MEMORY_INIT = {"dt": (0.02, 0.2), "A": (0.25, 1.0), "conv_b": 1.0,
+               "wq": 8.0, "wq_full": 4.0, "wo": 2.0, "lam": 0.1, "bias": 0.1,
+               "embedding": 4.0, "out": 0.25}
 
 
 @dataclass(frozen=True)
@@ -471,7 +535,9 @@ class LlamaConfig:
     # an ungated shared SwiGLU expert ("L"); TRAINED through the flash
     # kernel (latent_block) and served (serve_latent_block). "Y" / "Z" /
     # "X": a latent block that attends under a learned selection
-    # (DSA_KINDS, dsa_block), SERVED only. Empty:
+    # (DSA_KINDS, dsa_block), SERVED only. "m" / "w" / "f" / "g" / "c":
+    # the decoder-hybrid-decoder's whole blocks (MEMORY_KINDS, memory_block),
+    # SERVED only. Empty:
     # every layer is the block (attention THEN MLP), as every dense and
     # every all-routed configuration has it.
     layer_pattern: str = ""
@@ -617,6 +683,15 @@ class LlamaConfig:
     embedding_multiplier: float = 1.0
     attention_multiplier: float = 0.0
     residual_multiplier: float = 1.0
+    # the "m" layers (memory_block; ops/s6.py): a Mamba-1 mixer ssm_expand x
+    # dim channels wide with ssm_state states a channel, a convolution of
+    # ssm_conv and a time step through a bottleneck of ssm_dt_rank (0: the
+    # published "auto", ceil(dim / 16)). The family's other sizes are the
+    # block's: n_heads query heads on n_kv_heads of head_dim, taken in PAIRS
+    # (differential attention), window, mlp_dim; norm_kind is "layer" and
+    # every LayerNorm has a bias
+    ssm_expand: int = 0
+    ssm_dt_rank: int = 0
 
     def __post_init__(self):
         object.__setattr__(self, "mrope_section", tuple(self.mrope_section))
@@ -829,6 +904,31 @@ class LlamaConfig:
                 "times attention_multiplier), then a dense SwiGLU of mlp_dim: "
                 "every built layer is one of the two, and it has no expert "
                 "and no QK-norm")
+        memory = set(self.kinds) & set(MEMORY_KINDS)
+        if memory and not (
+                set(self.kinds) <= set(MEMORY_KINDS) and self.mlp_dim
+                and self.norm_kind == "layer" and not self.num_experts
+                and not self.qk_norm and self.n_heads % 2 == 0
+                and self.n_kv_heads % 2 == 0
+                and self.n_heads % self.n_kv_heads == 0
+                and ("m" not in memory or (
+                    self.ssm_expand and self.ssm_state and self.ssm_conv > 1))
+                and all({"g": "m", "c": "f"}[c] in self.kinds[:i]
+                        for i, c in enumerate(self.kinds) if c in "gc")):
+            raise ValueError(
+                "an 'm' / 'w' / 'f' / 'g' / 'c' layer is a Mamba-1 mixer "
+                "(ssm_expand, ssm_state, ssm_conv, ssm_dt_rank), differential "
+                "attention without rotation (an even n_heads on an even "
+                "n_kv_heads, over cfg.window or over everything), a gated "
+                "memory unit or cross attention, then a dense SwiGLU of "
+                "mlp_dim under LayerNorms with a bias (norm_kind 'layer'): "
+                "every built layer is one of the five, an 'm' layer stands "
+                "below every 'g' (whose memory it gates) and an 'f' layer "
+                "below every 'c' (whose keys and values it reads), and it "
+                "has no expert and no QK-norm")
+        if not memory and (self.ssm_expand or self.ssm_dt_rank):
+            raise ValueError("only an 'm' layer reads ssm_expand and "
+                             "ssm_dt_rank")
         if not hybrid and (
                 self.embedding_multiplier != 1.0 or self.attention_multiplier
                 or self.residual_multiplier != 1.0):
@@ -839,7 +939,8 @@ class LlamaConfig:
                 "stack of 'H' / 'N' layers reads them (no test holds another "
                 "kind to a reference with any of them)")
         if not parallel and (
-                self.norm_kind != "rms" or self.rope_interleaved
+                (self.norm_kind != "rms" and not memory)
+                or self.rope_interleaved
                 or self.shared_experts != 1
                 or (self.logit_scale != 1.0 and not hybrid)
                 or self.shared_combine != "average"):
@@ -848,8 +949,9 @@ class LlamaConfig:
                 f"{self.rope_interleaved}, shared_experts="
                 f"{self.shared_experts}, shared_combine="
                 f"{self.shared_combine!r}, logit_scale={self.logit_scale}: "
-                "only a stack of 'P' / 'R' layers reads them, and one of "
-                "'H' / 'N' layers logit_scale (no test holds "
+                "only a stack of 'P' / 'R' layers reads them, one of "
+                "'H' / 'N' layers logit_scale and one of 'm' / 'w' / 'f' / "
+                "'g' / 'c' layers norm_kind (no test holds "
                 "another kind to a reference with any of them)")
         if blocks and (
                 not (self.num_experts and self.experts_per_token)
@@ -896,8 +998,11 @@ class LlamaConfig:
     def layer_types(self):
         """The whole pattern as a published config names its layers: window
         and full attention layers, or a state-space hybrid's ``mamba`` and
-        ``attention`` ones ("H" / "N")."""
-        hybrid = {"H": "mamba", "N": "attention"}
+        ``attention`` ones ("H" / "N"), or a decoder-hybrid-decoder's
+        ``mamba``, ``gated_memory`` and ``cross_attention`` ones beside its
+        attention layers ("m" / "g" / "c")."""
+        hybrid = {"H": "mamba", "N": "attention", "m": "mamba",
+                  "g": "gated_memory", "c": "cross_attention"}
         return [hybrid.get(kind, "sliding_attention" if kind in WINDOW_KINDS
                            else "full_attention")
                 for kind in self.layer_pattern]
@@ -932,6 +1037,34 @@ class LlamaConfig:
         """What a published config DIVIDES the logits by: ``logit_scale``'s
         inverse."""
         return 1.0 / self.logit_scale
+
+    @property
+    def s6_inner(self) -> int:
+        """An "m" layer's channels."""
+        return self.ssm_expand * self.dim
+
+    @property
+    def s6_dt_rank(self) -> int:
+        """An "m" layer's time-step bottleneck: ``ssm_dt_rank``, or the
+        published "auto"."""
+        return self.ssm_dt_rank or -(-self.dim // 16)
+
+    @property
+    def mb_per_layer(self):
+        """``n`` where every ``n``-th layer in front of the pattern's full
+        attention layer is an "m" layer, as a published config counts it
+        (None for any other pattern)."""
+        front = self.layer_pattern.split("f")[0]
+        n = front.find("m", 1)
+        fits = n > 0 and all((kind == "m") == (i % n == 0)
+                             for i, kind in enumerate(front))
+        return n if fits else None
+
+    def lambda_init(self, kinds: str):
+        """``lam0 = 0.8 - 0.6 exp(-0.3 i)`` of the built layers of ``kinds``,
+        in order, ``i`` the layer's depth in the published stack."""
+        return [0.8 - 0.6 * math.exp(-0.3 * (self.first_layer + i))
+                for i, c in enumerate(self.kinds) if c in kinds]
 
     @property
     def full_attention_interval(self):
@@ -1050,6 +1183,23 @@ class LlamaConfig:
             dense = 3 * d * self.mlp_dim + d
             per_kind["H"] = per_kind["M"] + dense
             per_kind["N"] = per_kind["*"] + dense
+            # the decoder-hybrid-decoder's blocks: a mixer (Mamba-1: in, the
+            # convolution and its bias, x, dt and its bias, A, D, out;
+            # differential attention with its biases, four lambda vectors
+            # and the 2 head_dim gain; cross: the same without keys and
+            # values; a memory unit: in and out), the dense SwiGLU and two
+            # LayerNorms with gain AND bias
+            inner, n, r = self.s6_inner, self.ssm_state, self.s6_dt_rank
+            block = 3 * d * self.mlp_dim + 4 * d
+            per_kind["m"] = (
+                2 * d * inner + (self.ssm_conv + 1) * inner
+                + inner * (r + 2 * n) + (r + 1) * inner + inner * n + inner
+                + inner * d + block)
+            per_kind["c"] = (d * q + q + q * d + d + 6 * self.head_dim
+                             + block)
+            per_kind["w"] = per_kind["f"] = (
+                per_kind["c"] + 2 * d * kv + 2 * kv)
+            per_kind["g"] = 2 * d * inner + block
             if set(self.kinds) & set("S" + LATENT_KINDS + DSA_KINDS):
                 rq, rkv, h = self.q_lora_rank, self.kv_lora_rank, self.n_heads
                 qk = self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -1077,6 +1227,8 @@ class LlamaConfig:
                 per_kind["X"] = per_kind["G"] + indexer
                 # a prediction module: its block, W_eh and three norms
                 emb += self.mtp_layers * (per_kind["L"] + 2 * d * d + 3 * d)
+            if set(self.kinds) & set(MEMORY_KINDS):
+                emb += d  # the final LayerNorm's bias
             return emb + sum(per_kind[k] for k in self.kinds) + d
         attn = d * q + 2 * d * kv + q * d
         if self.qk_norm:
@@ -1234,6 +1386,27 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             **dense)
         kinds["hybrid_attn"] = dict(
             {w: kinds["block"][w] for w in ("wq", "wk", "wv", "wo")}, **dense)
+        # the decoder-hybrid-decoder's: served only, whole on every device
+        # but for the products every block has
+        norms = {w: ("layers", None) for w in (
+            "attn_norm", "attn_norm_b", "mlp_norm", "mlp_norm_b")}
+        memory = dict(dense, **norms)
+        kinds["memory_mamba"] = dict(
+            memory, w_in=("layers", "embed", None),
+            conv_w=("layers", None, None), conv_b=("layers", None),
+            w_x=("layers", None, None), w_dt=("layers", None, None),
+            dt_bias=("layers", None), A_log=("layers", None, None),
+            D=("layers", None), w_out=("layers", None, "embed"))
+        kinds["memory_cross"] = dict(
+            memory, wq=("layers", "embed", "heads"), bq=("layers", None),
+            wo=("layers", "heads", "embed"), bo=("layers", None),
+            lam=("layers", None, None), sub_norm=("layers", None))
+        kinds["memory_attn"] = dict(
+            kinds["memory_cross"], wkv=("layers", "embed", "kv_heads"),
+            bkv=("layers", None))
+        kinds["memory_gate"] = dict(
+            memory, wg_in=("layers", "embed", None),
+            wg_out=("layers", None, "embed"))
         if cfg.hc_mult > 1:  # a sublayer's mix: small, whole on every device
             for kind in ("latent", "latent_dense"):
                 kinds[kind].update(hc_phi=("layers", None, None, None),
@@ -1245,6 +1418,8 @@ def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
                        for k in LAYER_KINDS if k in cfg.kinds},
             "final_norm": (None,),
         }
+        if set(cfg.kinds) & set(MEMORY_KINDS):
+            out["final_norm_b"] = (None,)
         if not cfg.tie_embeddings:
             out["lm_head"] = ("embed", "vocab")
         if cfg.mtp_layers:  # embedding and head are the model's own
@@ -1420,6 +1595,10 @@ def _init_pattern_layers(cfg: LlamaConfig, key) -> Dict[str, Any]:
     for c in DSA_KINDS:
         if n[c]:
             out[LAYER_KINDS[c]] = _init_dsa_kind(cfg, c, n[c], key)
+    for c in "mwgc":  # "w" and "f": ONE stack
+        L = n[c] + (n["f"] if c == "w" else 0)
+        if L:
+            out[LAYER_KINDS[c]] = _init_memory(cfg, c, L, key)
     return out
 
 
@@ -1651,6 +1830,62 @@ def _init_hybrid(cfg: LlamaConfig, kind: str, L: int, key):
     return {**out, **mixer}
 
 
+def _init_memory(cfg: LlamaConfig, kind: str, L: int, key):
+    """The ``L`` stacked layers of one of the decoder-hybrid-decoder's
+    stacks (``kind``: ``"m"``, ``"w"`` for the ``"w"`` / ``"f"`` layers in
+    layer order, ``"g"``, ``"c"``), keys of their own a stack: the mixer's
+    leaves at :data:`MEMORY_INIT`'s widths (``"m"``: :func:`ray_tpu.ops.s6.
+    init_s6`), the dense SwiGLU over the square root of its fan-in, the
+    LayerNorms' gains at one and their biases at zero. ``wq``'s columns are
+    ``[q1 | q2]`` (a pair's first and second head, n_heads / 2 pairs of
+    head_dim each), ``wkv``'s ``[k1 | k2 | v]`` (n_kv_heads / 2 pairs; a
+    pair's value ONE head 2 head_dim wide): with seeded weights any consistent
+    pairing is one model."""
+    from ray_tpu.ops.s6 import init_s6
+
+    d, hd, f = cfg.dim, cfg.head_dim, cfg.mlp_dim
+    nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    k = iter(jax.random.split(
+        jax.random.fold_in(key, 20 + "mwgc".index(kind)), 16))
+    dense, init = _dense_init, MEMORY_INIT
+    out = {"attn_norm": jnp.ones((L, d), jnp.float32),
+           "attn_norm_b": jnp.zeros((L, d), jnp.float32),
+           "mlp_norm": jnp.ones((L, d), jnp.float32),
+           "mlp_norm_b": jnp.zeros((L, d), jnp.float32),
+           "w_gate": dense(next(k), (L, d, f), d),
+           "w_up": dense(next(k), (L, d, f), d),
+           "w_down": dense(next(k), (L, f, d), f)}
+    if kind == "m":
+        mixer = init_s6(
+            next(k), L, d, inner=cfg.s6_inner, state=cfg.ssm_state,
+            conv=cfg.ssm_conv, dt_rank=cfg.s6_dt_rank, dt=init["dt"],
+            decay=init["A"], conv_b=init["conv_b"])
+        return {**out, **mixer, "w_out": init["out"] * mixer["w_out"]}
+    if kind == "g":
+        return {**out, "wg_in": dense(next(k), (L, d, cfg.s6_inner), d),
+                "wg_out": init["out"] * dense(
+                    next(k), (L, cfg.s6_inner, d), cfg.s6_inner)}
+
+    def normal(shape, width):
+        return width * jax.random.normal(next(k), shape, jnp.float32)
+
+    # a window layer's queries and a full or cross layer's: a width each
+    wide = jnp.asarray([init["wq" if c == "w" else "wq_full"]
+                        for c in cfg.kinds if c in ("wf" if kind == "w"
+                                                    else "c")])
+    out.update(
+        wq=wide[:, None, None] * dense(next(k), (L, d, nq), d),
+        bq=normal((L, nq), init["bias"]),
+        wo=init["wo"] * dense(next(k), (L, nq, d), nq),
+        bo=normal((L, d), init["bias"]),
+        lam=normal((L, 4, hd), init["lam"]),
+        sub_norm=jnp.ones((L, 2 * hd), jnp.float32))
+    if kind == "w":
+        out.update(wkv=dense(next(k), (L, d, 2 * nkv), d),
+                   bkv=normal((L, 2 * nkv), init["bias"]))
+    return out
+
+
 def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
     d, hd = cfg.dim, cfg.head_dim
     nq, nkv, L = cfg.n_heads, cfg.n_kv_heads, cfg.n_layers
@@ -1658,11 +1893,15 @@ def init_params(cfg: LlamaConfig, key) -> Dict[str, Any]:
     if cfg.layer_pattern:
         k_emb, k_head, k_layers = jax.random.split(key, 3)
         wide = (HYBRID_INIT["embedding"]
-                if set(cfg.kinds) & set(HYBRID_KINDS) else 1.0)
+                if set(cfg.kinds) & set(HYBRID_KINDS)
+                else MEMORY_INIT["embedding"] * math.sqrt(d)
+                if set(cfg.kinds) & set(MEMORY_KINDS) else 1.0)
         params = {"embedding": wide * dense(k_emb, (cfg.vocab_size, d), d),
                   "layers": _init_pattern_layers(cfg, k_layers),
                   "final_norm": (jnp.zeros if cfg.zero_centered
                                  else jnp.ones)((d,), jnp.float32)}
+        if set(cfg.kinds) & set(MEMORY_KINDS):  # LayerNorm WITH a bias
+            params["final_norm_b"] = jnp.zeros((d,), jnp.float32)
         if not cfg.tie_embeddings:
             params["lm_head"] = dense(k_head, (d, cfg.vocab_size), d)
         if cfg.mtp_layers:
@@ -2988,8 +3227,9 @@ def selecting_stream(cfg: LlamaConfig, x, decode: bool = False):
 
 
 def stream_of(x):
-    """The stream alone: :class:`Selecting`'s, or ``x`` as it is."""
-    return x.stream if isinstance(x, Selecting) else x
+    """The stream alone: :class:`Selecting`'s or :class:`Remembering`'s, or
+    ``x`` as it is."""
+    return x.stream if isinstance(x, (Selecting, Remembering)) else x
 
 
 def dsa_block(cfg: LlamaConfig, kind: str, x, layers, i, positions, attend,
@@ -3724,6 +3964,301 @@ def attend_hybrid_tiles(cfg: LlamaConfig, q, k, v):
     return o[..., :D]
 
 
+# --- the decoder-hybrid-decoder's blocks (arXiv:2507.06607) ---------------- #
+
+
+class Remembering(NamedTuple):
+    """The serving stream of the kinds that read what ANOTHER layer made
+    (:data:`MEMORY_KINDS`): ``stream`` ``[B, T, dim]`` and, beside it, the
+    newest ``memory`` (an ``"m"`` layer's scan output ``Y``, ``[B, T,
+    ssm_expand x dim]`` float32, before its gate, the skip ``D u`` included:
+    what a ``"g"`` layer gates AT ITS OWN POSITION) and the newest ``keys``
+    and ``values`` (an ``"f"`` layer's, ``[B, T, n_kv_heads, head_dim]``
+    each: what a ``"c"`` layer attends). A pytree as
+    :class:`Selecting` is: the walker's scans carry it, the programs make it
+    behind the embedding (:func:`remembering_stream`) and drop it in front of
+    the head (:func:`stream_of`). BEHIND THE CUT of a prefill
+    (:func:`cut_stream`) ``stream`` and ``memory`` hold ONE position, the
+    last real one, and ``keys`` / ``values`` every position of the call."""
+    stream: Any
+    memory: Any
+    keys: Any
+    values: Any
+
+
+def remembering_stream(cfg: LlamaConfig, x):
+    """The stream ``x`` as the serving programs carry it through a stack of
+    :data:`MEMORY_KINDS`: :class:`Remembering`, nothing remembered (the
+    layers that write stand in front of those that read: ``LlamaConfig``
+    holds the pattern to that). Any other stack: ``x``."""
+    if not set(cfg.kinds) & set(MEMORY_KINDS):
+        return x
+    B, T, _ = x.shape
+    rows = jnp.zeros((B, T, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+    return Remembering(x, jnp.zeros((B, T, cfg.s6_inner), jnp.float32),
+                       rows, rows)
+
+
+def cut_stream(x, last):
+    """A prefill's stream cut to position ``last``, the one the head reads:
+    every leaf ``[B, T, ...]`` becomes ``[B, 1, ...]`` but what a layer
+    behind the cut reads of ALL positions (:class:`Remembering`'s keys and
+    values)."""
+    def one(a):
+        return jax.lax.dynamic_slice_in_dim(a, last, 1, axis=1)
+
+    if isinstance(x, Remembering):
+        return x._replace(stream=one(x.stream), memory=one(x.memory))
+    return jax.tree.map(one, x)
+
+
+def memory_block(cfg: LlamaConfig, kind: str, x, layers, i, positions,
+                 attend, stat_axes=()):
+    """THE decoder-hybrid-decoder's whole block of the kinds ``"m"``,
+    ``"w"``, ``"f"``, ``"g"``, ``"c"``, for the serving programs. ``x``:
+    :class:`Remembering` (``LN``: the mean-subtracting LayerNorm with gain
+    AND bias, float32 statistics)::
+
+        a   = LN(x; attn_norm)
+        "m" [u | z] = a W_in                            ops/s6.py project_in
+            Y, state, tail = attend(u, the layer's leaves)   Y IS THE MEMORY
+            mix = (Y * silu(z)) W_out
+        "g" mix = (silu(a Wg_in) * memory) Wg_out   memory AT THIS POSITION
+        "w" / "f" / "c": q = a Wq + bq           n_heads / 2 PAIRS (q1, q2)
+            "w" / "f": [k1 | k2 | v] = a Wkv + bkv     n_kv_heads / 2 pairs;
+                a pair's values ONE head 2 head_dim wide; NO rotation; an
+                "f" layer's ARE the keys and values a "c" layer reads
+            o1, o2 = attend(q, keys, values):  o_j = softmax_j(q_j k_j^T /
+                sqrt(head_dim)) v over the visible keys ("w": t - window < s
+                <= t; "f", "c": s <= t); query pair p reads key pair p //
+                (n_heads / n_kv_heads)
+            lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0,  lam0 = 0.8 - 0.6
+                exp(-0.3 depth): a per-layer VALUE (``cfg.lambda_init``
+                indexed by ``i``, which a scan traces)
+            mix = (RMSNorm_{2 head_dim}(o1 - lam o2) * sub_norm * (1 - lam0))
+                  Wo + bo
+        h   = x + mix
+        out = h + SwiGLU(LN(h; mlp_norm))                   the dense MLP
+
+    ``attend`` is all that knows where the call stands in its sequence
+    (:func:`attend_s6` / :func:`_attend_s6_state`; :func:`attend_diff_tiles`,
+    :func:`attend_cross` / :func:`_attend_diff_cached`). ``layers``: the
+    kind's stack ``[L, ...]`` (``"w"`` and ``"f"`` share one), ``i``: which
+    layer. Device scopes ``s6.*`` (ops/s6.py), ``gmu.gate``, ``attn.qkv``,
+    ``attn.diff_window`` / ``attn.diff_full`` / ``attn.cross``,
+    ``attn.diff_norm``, ``attn.out``, ``mem.mlp``. Returns ``(Remembering,
+    {}, rows)``: ``(state, tail)``, ``(keys, values)`` or ``()``: a ``"g"``
+    or ``"c"`` layer keeps NOTHING."""
+    from ray_tpu.ops import s6
+
+    f32, cd, eps = jnp.float32, cfg.dtype, cfg.norm_eps
+    x, memory, keys, values = x
+    B, T, _ = x.shape
+    p = {w: a[i] for w, a in layers.items()}
+    a = (layer_norm(x, p["attn_norm"], eps) + p["attn_norm_b"]).astype(cd)
+    kept = ()
+    if kind == "m":
+        u, z = s6.project_in(a, p["w_in"])
+        memory, *kept = attend(u, p)
+        mix = s6.gate_out(memory, z, p["w_out"])
+    elif kind == "g":
+        with jax.named_scope("gmu.gate"):
+            gate = jax.nn.silu(jnp.dot(a, p["wg_in"].astype(cd),
+                                       preferred_element_type=f32))
+            mix = (gate * memory).astype(cd) @ p["wg_out"].astype(cd)
+    else:
+        hd = cfg.head_dim
+        with jax.named_scope("attn.qkv"):
+            q = (jnp.dot(a, p["wq"].astype(cd), preferred_element_type=f32)
+                 + p["bq"]).reshape(B, T, -1, hd)
+            if kind != "c":
+                kv = (jnp.dot(a, p["wkv"].astype(cd),
+                              preferred_element_type=f32)
+                      + p["bkv"]).astype(cd)
+                kept = [half.reshape(B, T, -1, hd)
+                        for half in jnp.split(kv, 2, -1)]
+            own = kept or (keys.astype(cd), values.astype(cd))
+            if kind == "f":  # handed on in the type the stream carries them
+                # in (float32 that holds the compute type's values, as the
+                # stores do)
+                keys, values = (new.astype(old.dtype) for new, old in zip(
+                    kept, (keys, values)))
+        with jax.named_scope({"w": "attn.diff_window", "f": "attn.diff_full",
+                              "c": "attn.cross"}[kind]):
+            o1, o2 = attend(q, *own)
+        with jax.named_scope("attn.diff_norm"):
+            lam0 = jnp.asarray(cfg.lambda_init("c" if kind == "c" else "wf"),
+                               f32)[i]
+            lq1, lk1, lq2, lk2 = p["lam"]
+            lam = (jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2))
+                   + lam0)
+            o = o1.astype(f32) - lam * o2.astype(f32)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps)
+            o = o * (p["sub_norm"] * (1.0 - lam0))
+        with jax.named_scope("attn.out"):
+            mix = jnp.dot(o.astype(cd).reshape(B, T, -1), p["wo"].astype(cd),
+                          preferred_element_type=f32) + p["bo"]
+    h = x + mix.astype(x.dtype)
+    with jax.named_scope("mem.mlp"):
+        m = (layer_norm(h, p["mlp_norm"], eps) + p["mlp_norm_b"]).astype(cd)
+        y = _dense_mlp(cfg, p, m)
+    return (Remembering(h + y.astype(x.dtype), memory, keys, values), {},
+            tuple(kept))
+
+
+def s6_prefill_path(cfg: LlamaConfig, u) -> Tuple[str, str]:
+    """``(path, reason)`` :func:`attend_s6` takes for these operands in this
+    process (:func:`ssm_prefill_path`'s sibling): ``"kernel"`` on a TPU
+    backend for what ``ops/s6_prefill.py`` takes (channels in whole blocks of
+    1,024, positions a multiple of its row tile), ``"scan"`` with what
+    stands in the way otherwise. Read from the backend and the shapes
+    alone."""
+    platform = jax.default_backend()
+    if platform != "tpu":
+        return "scan", f"backend is {platform!r}, not tpu"
+    from ray_tpu.ops.s6_prefill import CHANNELS, pick_rows
+
+    if u.shape[-1] % CHANNELS:
+        return "scan", (f"{u.shape[-1]} channels are no whole blocks of "
+                        f"{CHANNELS}")
+    if pick_rows(u.shape[1]) is None:
+        return "scan", (f"{u.shape[1]} positions are no multiple of a row "
+                        "tile of the kernel's")
+    return "kernel", "tpu backend"
+
+
+def attend_s6(cfg: LlamaConfig, last, u, p):
+    """:func:`memory_block`'s ``"m"`` ``attend`` over the call's own
+    positions from an empty state (prefill), the positions behind ``last``
+    identity updates, the tail taken at ``last``: the convolution and
+    ``silu`` (``s6.conv``), the projections to ``r W_dt``, ``B`` and ``C``
+    (``s6.x_proj``), then the scan (``s6.scan``).
+
+    Two paths, ONE arithmetic (:func:`s6_prefill_path` says which and why;
+    counted as kind ``s6``, path ``kernel`` or ``scan``, where
+    :func:`attend_tiles`' kinds are). On a TPU backend one Pallas call,
+    forward only (``ops/s6_prefill.py``, imported here and nowhere else):
+    ``softplus``, the decays' exponentials, the update, the read-out and the
+    skip in one pass over the prompt, the ``[channels, states]`` state in
+    registers, no ``[T, channels, states]`` array anywhere. On every other
+    backend, for what the kernel does not take and as its oracle, ``ops/
+    s6.py scan``: a ``lax.scan`` a position. Returns ``(Y [B, T, D]
+    float32, state [B, 1, D, N], tail [B, 1, K - 1, D])``."""
+    from ray_tpu.ops import s6
+    from ray_tpu.ops.ssm import conv_tail
+
+    B, T, D = u.shape
+    with jax.named_scope("s6.conv"):
+        tail = conv_tail(u, p["conv_w"].shape[0], last)
+    mixed = s6.convolve(u, p)
+    r, b_in, c_in = s6.select(mixed, p, cfg.dtype)
+    path, reason = s6_prefill_path(cfg, u)
+    _note_prefill_attend("s6", u, b_in, 0, path, reason, "scan")
+    with jax.named_scope("s6.scan"):
+        if path == "kernel":
+            from ray_tpu.ops.s6_prefill import s6_prefill
+
+            # interpreted where a test has steered a CPU process onto this
+            # path
+            y, state = s6_prefill(
+                mixed, r, b_in, c_in, p,
+                jnp.zeros((B, D, cfg.ssm_state), jnp.float32),
+                T - 1 if last is None else last,
+                interpret=jax.default_backend() != "tpu")
+        else:
+            y, state = s6.scan(mixed, r, b_in, c_in, p, None, last)
+    return y, state[:, None], tail[:, None]
+
+
+def _attend_s6_state(cfg: LlamaConfig, call, l, mine, u, p):
+    """A decode call's token through its ``"m"`` layer's kept state and
+    tail (``mine``: the views ``[1, *row]`` of the page that holds position
+    ``pos - 1``): ``ops/s6.py step``, and what the page that holds ``pos``
+    keeps. A sequence's first position starts from zeros whatever its page
+    held."""
+    from ray_tpu.ops import s6
+
+    state, tail = (jnp.where(call.pos > 0, a, 0) for a in mine)
+    y, state, tail = s6.step(u, p, state, tail, cfg.dtype)
+    return y, state[:, None], tail[:, None]
+
+
+def _attends_nothing(*a):
+    """The ``attend`` of a kind whose block calls none (``"g"``)."""
+    raise AssertionError("a gated memory unit attends nothing")
+
+
+def _diff_halves(cfg: LlamaConfig, q, k, v):
+    """Differential attention's operands as their two maps take them:
+    ``((q1, k1), (q2, k2)), v``: the pairs' first and second heads (the
+    leaves' columns are ``[first heads | second heads]``) and a key pair's
+    values as ONE head ``2 head_dim`` wide. ``q`` ``[.., n_heads, head_dim]``,
+    ``k`` / ``v`` ``[.., n_kv_heads, head_dim]``."""
+    return (zip(jnp.split(q, 2, axis=-2), jnp.split(k, 2, axis=-2)),
+            v.reshape(*v.shape[:-2], -1, 2 * cfg.head_dim))
+
+
+def attend_diff_tiles(cfg: LlamaConfig, kind: str, q, k, v):
+    """:func:`memory_block`'s ``"w"`` / ``"f"`` ``attend`` over the call's
+    own positions (prefill; a ``"c"`` layer's too where every position
+    brings a query): differential attention's TWO softmax maps as two calls
+    of :func:`attend_tiles`, ``(q1, k1)`` and ``(q2, k2)``, each against the
+    pair's ONE value ``2 head_dim`` wide, counted under ``diff_window`` /
+    ``diff_full`` / ``diff_cross``. The subtraction, the norm and the scale
+    are the block's (XLA). ``q`` comes in float32: it is scaled for the width
+    the product divides by and rounded ONCE (:func:`attend_hybrid_tiles`'
+    fill: a 64-wide score is filled up to the 128 lanes prefill's kernel
+    takes, which makes score and value one width). Returns ``(o1, o2)``,
+    ``[B, T, n_heads / 2, 2 head_dim]`` each."""
+    D = q.shape[-1]
+    fill = [(0, 0)] * 3 + [(0, -D % 128)]
+    scale = math.sqrt((D + fill[-1][1]) / D)
+    name = {"w": "diff_window", "f": "diff_full", "c": "diff_cross"}[kind]
+    maps, v = _diff_halves(cfg, q, k, v)
+    return [attend_tiles(jnp.pad((qi * scale).astype(cfg.dtype), fill),
+                         jnp.pad(ki, fill), v, cfg.dtype,
+                         window=cfg.window if kind == "w" else 0, kind=name)
+            for qi, ki in maps]
+
+
+def attend_cross(cfg: LlamaConfig, last, q, k, v):
+    """:func:`memory_block`'s ``"c"`` ``attend`` in a prefill. BEHIND THE
+    CUT (``q`` holds ONE position, ``last``; ``k`` / ``v`` every position's,
+    made by the ``"f"`` layer in this same call): two softmax maps of one
+    query over the keys ``s <= last``, float32, in XLA. Where every position
+    brings its query (a stack run without the cut): :func:`attend_diff_tiles`.
+    Read from the shapes."""
+    if q.shape[1] == k.shape[1]:
+        return attend_diff_tiles(cfg, "c", q, k, v)
+    f32 = jnp.float32
+    B, T, hd = k.shape[0], k.shape[1], cfg.head_dim
+    visible = jnp.arange(T) <= last
+    maps, v = _diff_halves(cfg, q.astype(cfg.dtype), k, v)
+    out = []
+    for qi, ki in maps:
+        qg = qi[:, 0].reshape(B, ki.shape[2], -1, hd).astype(f32)
+        s = jnp.einsum("bgrd,btgd->bgrt", qg, ki.astype(f32)) / math.sqrt(hd)
+        w = jax.nn.softmax(jnp.where(visible, s, -1e30), axis=-1)
+        out.append(jnp.einsum("bgrt,btge->bgre", w, v.astype(f32)).reshape(
+            B, 1, -1, 2 * hd).astype(cfg.dtype))
+    return out
+
+
+def _attend_diff_cached(cfg: LlamaConfig, tag: str, k_cache, v_cache, length,
+                        q, kk, vv, lowest=None):
+    """A decode call's token through differential attention:
+    :func:`_attend_grouped` TWICE over the same views (``k_cache`` /
+    ``v_cache`` ``[Tpad, n_kv_heads, head_dim]``: slots for ``"w"``, pages
+    for ``"f"`` and ``"c"``), the first heads' keys and the second heads',
+    each against the pairs' values ``2 head_dim`` wide. Returns ``(o1,
+    o2)``."""
+    maps, v_cache = _diff_halves(cfg, q.astype(cfg.dtype), k_cache, v_cache)
+    own, vv = _diff_halves(cfg, q, kk, vv)  # the token's own key and value
+    return [_attend_grouped(cfg, tag, ki, v_cache, length, qi, kki, vv,
+                            lowest=lowest)
+            for (qi, ki), (_, kki) in zip(maps, own)]
+
+
 def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
     """One layer of a patterned stack (``cfg.layer_pattern``). A kind the
     decode engine serves (a row of :data:`SERVED`: ``"S"``, ``"F"`` /
@@ -3748,6 +4283,11 @@ def pattern_layer(cfg: LlamaConfig, kind: str, attend, x, p, stat_axes=()):
             f"the full forward takes no {kind!r} layer yet: only the "
             f"serving programs carry a selection from the layer that makes "
             f"it to the layers that read it (prefill_with_cache)")
+    if kind in MEMORY_KINDS:
+        raise NotImplementedError(
+            f"the full forward takes no {kind!r} layer yet: only the "
+            f"serving programs carry a memory and a layer's keys and values "
+            f"to the layers that read them (prefill_with_cache)")
     if kind in SERVED:  # this layer's weights as a stack of one
         return SERVED[kind].block(
             cfg, x, jax.tree.map(lambda a: a[None], p), 0,
@@ -3903,15 +4443,17 @@ def _final_gain(cfg: LlamaConfig, final_norm):
     return 1.0 + final_norm if cfg.zero_centered else final_norm
 
 
-def _final_norm(cfg: LlamaConfig, x, final_norm):
-    """The model's last norm, of the kind ``cfg.norm_kind`` names."""
+def _final_norm(cfg: LlamaConfig, x, final_norm, bias=None):
+    """The model's last norm, of the kind ``cfg.norm_kind`` names, plus its
+    ``bias`` where the family's LayerNorm has one (``final_norm_b``)."""
     norm = layer_norm if cfg.norm_kind == "layer" else rms_norm
-    return norm(x, _final_gain(cfg, final_norm), cfg.norm_eps)
+    x = norm(x, _final_gain(cfg, final_norm), cfg.norm_eps)
+    return x if bias is None else x + bias.astype(x.dtype)
 
 
-def head_logits(cfg: LlamaConfig, x, final_norm, head):
+def head_logits(cfg: LlamaConfig, x, final_norm, head, bias=None):
     """The model's end: final norm -> head -> float32 logits."""
-    return _logits(cfg, _final_norm(cfg, x, final_norm), head)
+    return _logits(cfg, _final_norm(cfg, x, final_norm, bias), head)
 
 
 def forward(cfg: LlamaConfig, params, tokens, mesh=None):
@@ -4257,11 +4799,10 @@ def _attend_grouped(cfg: LlamaConfig, tag: str, k_cache, v_cache, length, q,
     repeated form writes and reads two ``[16385, 128, 128]`` float32 arrays
     (2.1 GB) a layer a token. Float32 scores."""
     f32 = jnp.float32
-    G, D = cfg.n_kv_heads, cfg.head_dim
-    Tpad = k_cache.shape[0]
+    Tpad, G, D = k_cache.shape  # the views', whose values may be wider
     _note_decode_attend(
         "grouped", q, k_cache,
-        f"{tag}: {cfg.n_heads // G} query heads a KV head in one product, "
+        f"{tag}: {q.shape[2] // G} query heads a KV head in one product, "
         f"the views read as kept ({k_cache.dtype.name})")
     qg, scale = q[0, 0].reshape(G, -1, D).astype(f32), 1.0 / math.sqrt(D)
     s = jnp.einsum("grd,tgd->grt", qg, k_cache.astype(f32)) * scale
@@ -4277,7 +4818,7 @@ def _attend_grouped(cfg: LlamaConfig, tag: str, k_cache, v_cache, length, q,
     o = jnp.einsum("grt,tgd->grd", p, v_cache.astype(f32)) \
         + p_own * vv[0, 0].astype(f32)[:, None, :]
     o = o / (p.sum(axis=-1, keepdims=True) + p_own)
-    return o.reshape(1, 1, -1, D).astype(cfg.dtype)
+    return o.reshape(1, 1, -1, v_cache.shape[-1]).astype(cfg.dtype)
 
 
 def _attend_latent_cached(cfg: LlamaConfig, cache, length, q, latent, wkv_b,
@@ -4384,7 +4925,15 @@ class Served(NamedTuple):
     kind that attends fewer than it sees
     (``ray_tpu_serve_engine_selected_share{program}``). ``alone``: a stack
     of SOME of the family's kinds is served too (a test holds such a stack's
-    logits to a reference)."""
+    logits to a reference).
+
+    A ROW MAY KEEP NOTHING (``rows(cfg)`` is ``[]``): such a layer has no
+    store, no view and no rows to write, and mixes positions only through
+    what the layers in front of it kept or handed on beside the stream, so a
+    PREFILL runs it on the last real position alone (:func:`stream_cut`).
+    And it MAY READ ANOTHER KIND'S VIEW: ``reads`` names a kind of the same
+    family, and ``mine`` of its ``decode`` is then THAT kind's views, all its
+    layers' (``[n, Tpad, *row]`` a store), in place of its own."""
     family: str
     stack: Optional[str]
     block: Callable
@@ -4394,6 +4943,7 @@ class Served(NamedTuple):
     decode: Callable
     attended: Optional[Callable] = None
     alone: bool = False
+    reads: Optional[str] = None
 
 
 class Table(NamedTuple):
@@ -4464,7 +5014,8 @@ def _index_attended(cfg: LlamaConfig, program: str, n: int):
     return n * (n + 1) // 2, few * (few + 1) // 2 + (n - few) * few
 
 
-# The table, a comment a row. HOW A LAYER'S WEIGHTS REACH ITS BLOCK: a
+# The table, a comment a row (a row may keep NOTHING and may read another
+# kind's view: Served's last paragraph). HOW A LAYER'S WEIGHTS REACH ITS BLOCK: a
 # patterned kind's stay stacked under ``params["layers"][stack]`` and every
 # matrix is cut out by the layer's number where it is used, the experts never
 # (:func:`shortcut_layer` has the readings: a whole layer cut out first is a
@@ -4625,6 +5176,53 @@ SERVED: Dict[str, Served] = {
         lambda cfg, last, *a: attend_latent_selected(cfg, *a),
         _attend_dsa_cached, _index_attended, alone=True)
        for c in DSA_KINDS},
+    # the decoder-hybrid-decoder's whole blocks (memory_block), a stack a
+    # kind but "w" / "f", which share one in layer order. "m" (Mamba-1)
+    # keeps NO row a position: its state [channels, states] and its
+    # convolution's tail, a SEQUENCE, by the table rule "state" (as "H",
+    # "D"). "w" keeps keys and values by SLOT (as "W", "R"), "f" by page id:
+    # ONE layer's for the whole model. "g" and "c" keep NOTHING: "g" gates the
+    # memory that came beside the stream, "c" attends the keys and values of
+    # the "f" layer in front of it, a prefill's from the stream and a decode
+    # call's from that layer's views (``reads``). The stream is Remembering,
+    # float32 (thirty-two blocks each add to it, and a LayerNorm's mean is a
+    # difference of large numbers). Decode: _attend_grouped twice, a map
+    # each. Any part of the family that LlamaConfig takes is served alone (a
+    # cell's rehearsal is the first two layers)
+    "m": Served(
+        "memory", "memory_mamba",
+        lambda cfg, *a: memory_block(cfg, "m", *a), True,
+        lambda cfg: [
+            ("s6_state", 1, (cfg.s6_inner, cfg.ssm_state), "state"),
+            ("s6_conv", 1, (cfg.ssm_conv - 1, cfg.s6_inner), "state")],
+        attend_s6, _attend_s6_state, alone=True),
+    "w": Served(
+        "memory", "memory_attn",
+        lambda cfg, *a: memory_block(cfg, "w", *a), True,
+        partial(_kv_rows, tag="memory_window", table="slot"),
+        lambda cfg, last, *a: attend_diff_tiles(cfg, "w", *a),
+        lambda cfg, call, l, mine, *a: _attend_diff_cached(
+            cfg, "memory_window", *mine, call.pos - call.base, *a,
+            lowest=call.pos - cfg.window + 1 - call.base), alone=True),
+    "f": Served(
+        "memory", "memory_attn",
+        lambda cfg, *a: memory_block(cfg, "f", *a), True,
+        partial(_kv_rows, tag="memory_full"),
+        lambda cfg, last, *a: attend_diff_tiles(cfg, "f", *a),
+        lambda cfg, call, l, mine, *a: _attend_diff_cached(
+            cfg, "memory_full", *mine, call.pos, *a), alone=True),
+    "g": Served(
+        "memory", "memory_gate",
+        lambda cfg, *a: memory_block(cfg, "g", *a), True,
+        lambda cfg: [], _attends_nothing, _attends_nothing, alone=True),
+    "c": Served(
+        "memory", "memory_cross",
+        lambda cfg, *a: memory_block(cfg, "c", *a), True,
+        lambda cfg: [], attend_cross,
+        # the newest "f" layer's views
+        lambda cfg, call, l, mine, *a: _attend_diff_cached(
+            cfg, "memory_cross", *(view[-1] for view in mine), call.pos,
+            *a), alone=True, reads="f"),
 }
 
 
@@ -4805,6 +5403,30 @@ REFUSED: Dict[str, Refused] = {
          "the MPMD pipeline":
          "its stages pass the residual stream alone: a selection made on "
          "one stage has no way to the shared layers of the next"}),
+    # a path on which no memory and no layer's keys and values travel from
+    # the layer that makes them to the layers that read them
+    "memory": Refused(
+        _has(MEMORY_KINDS),
+        lambda cfg: (f"no 'm' / 'w' / 'f' / 'g' / 'c' layer (layer_pattern="
+                     f"{cfg.layer_pattern!r}, ssm_expand={cfg.ssm_expand}, "
+                     f"window={cfg.window})"),
+        {"make_spmd_train_step":
+         "no train step carries a state-space layer's scan output or an "
+         "attention layer's keys and values to the layers that read them, "
+         "has a backward for the Mamba-1 scan (ops/s6_prefill.py is forward "
+         "only) or is held to a reference for differential attention's; "
+         "LlamaDecodeEngine serves these kinds",
+         "make_train_step":
+         "no train step carries a state-space layer's scan output or an "
+         "attention layer's keys and values to the layers that read them or "
+         "is held to a reference for the Mamba-1 scan's backward; "
+         "LlamaDecodeEngine serves these kinds",
+         "make_pipeline_train_step":
+         "its stages pass the residual stream alone: a memory or keys and "
+         "values made on one stage have no way to the layers of the next",
+         "the MPMD pipeline":
+         "its stages pass the residual stream alone: a memory or keys and "
+         "values made on one stage have no way to the layers of the next"}),
     # a stream of several rows a token (``hc_mult > 1``), and a latent block
     # whose score is not as wide as its value
     "wide": Refused(
@@ -4888,34 +5510,67 @@ def _segments(kinds: str, least: int = 2) -> list:
     are ``least`` of them or the period is ONE layer (a stack of one layer is
     a scan of one: the dense block's weights reach it as a scan's ``xs``),
     then, of what is left (a last period cut short, or the whole of a stack
-    with fewer periods), each run of ``least`` consecutive layers of one kind
-    or more as ``(kind, n, True)``, and the layers between such runs together
+    with fewer periods), from each layer on the SHORTEST unit that stands
+    there ``least`` times or more in a row as ``(unit, n, True)`` (a run of
+    one kind: ``GLLLL`` -> ``G``, ``L`` x 4; a period inside the stack: ``mw``
+    x 8, ``mf``, ``gc`` x 7), and the layers between such stretches together
     as ``(their letters, 1, False)``. ``least`` is 2; a test passes more than
     a stack has to get every layer in line."""
     unit, times = _period(kinds)
     if times < least and len(unit) > 1:
         times = 0
     out = [(unit, times, True)] if times else []
-    for c, run in itertools.groupby(kinds[times * len(unit):]):
-        n = len(list(run))
-        if n >= least:
-            out.append((c, n, True))
-        elif out and not out[-1][2]:
-            out[-1] = (out[-1][0] + c * n, 1, False)
+    at = times * len(unit)
+    while at < len(kinds):
+        for n in range(1, (len(kinds) - at) // max(least, 2) + 1):
+            unit, reps = kinds[at:at + n], 1
+            while kinds[at + reps * n:at + (reps + 1) * n] == unit:
+                reps += 1
+            if reps >= least:
+                out.append((unit, reps, True))
+                at += reps * n
+                break
         else:
-            out.append((c * n, 1, False))
+            if out and not out[-1][2]:
+                out[-1] = (out[-1][0] + kinds[at], 1, False)
+            else:
+                out.append((kinds[at], 1, False))
+            at += 1
     return out
+
+
+def stream_cut(cfg: LlamaConfig) -> int:
+    """The layers a PREFILL runs over all positions: behind the last layer
+    that KEEPS a row the stream is cut to the last real position
+    (:func:`cut_stream`). A layer that keeps nothing (``SERVED[c].rows`` is
+    ``[]``) has no earlier position of its own to attend in a decode call,
+    so it mixes positions only through what the layers in front of it kept
+    or handed on, and the first token needs it at ONE position (the
+    decoder-hybrid-decoder's "linear-time prefill": 18 of 32 layers over the
+    prompt, 14 over one position). Every other family's every layer keeps
+    rows: the cut is the stack's depth, in front of the head, where it
+    always was (``ray_tpu_serve_engine_prefill_layers{positions}``)."""
+    kinds = served_kinds(cfg)
+    return 1 + max(i for i, c in enumerate(kinds) if SERVED[c].rows(cfg))
+
+
+def _serve_segments(cfg: LlamaConfig) -> list:
+    """:func:`_segments` of the layers in front of :func:`stream_cut` and of
+    those behind it: no segment spans the cut."""
+    kinds, cut = served_kinds(cfg), stream_cut(cfg)
+    return _segments(kinds[:cut]) + (_segments(kinds[cut:])
+                                     if cut < len(kinds) else [])
 
 
 def traced_layers(cfg: LlamaConfig) -> int:
     """The layer bodies ONE serving program of ``cfg`` traces
     (``ray_tpu_serve_engine_traced_layers``): a segment's ``unit`` once,
     however often it is scanned."""
-    return sum(len(unit) for unit, *_ in _segments(served_kinds(cfg)))
+    return sum(len(unit) for unit, *_ in _serve_segments(cfg))
 
 
 def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
-                  keep):
+                  keep, cut=None):
     """THE served layers, whatever their kinds, a segment at a time
     (:func:`_segments`): the pattern's whole periods scanned (one compiled
     body of a period's layers; the scan carries the period's number and not
@@ -4933,29 +5588,40 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
     and ``keep`` have an entry a store (:func:`served_stores`): the store's
     view ``[layers, Tpad, *row]``, of which ``mine`` are the layer's own,
     or ``cache`` None (a prefill: nothing cached, ``mine`` None); and how
-    many of the call's LAST positions a layer's rows leave. Returns ``(x,
+    many of the call's LAST positions a layer's rows leave. A kind that
+    keeps nothing has no entry, no ``mine`` (``[]``) and no rows; a kind
+    whose row ``reads`` another gets THAT kind's views, whole, as ``mine``.
+    ``cut(x, positions)`` -> ``(x, positions)`` (a prefill's; None: none):
+    applied behind layer :func:`stream_cut` where layers stand behind it, so
+    that they run on the last real position alone. Returns ``(x,
     rows, shares)``: ``rows`` a store, ``[layers, B, n, *row]`` for a
     prefill and ``[layers, 1, *row]`` for a decode call's one new position;
     ``shares``: the routed assignments' shares, each averaged over the
     layers that report it, and a mixed stream's ``hc_sinkhorn_error``, its
     layers' largest."""
     kinds, layout = served_kinds(cfg), served_stores(cfg)
-    subs = _by_kind(layout, [s.sub for s in layout])
-    keep = _by_kind(layout, keep)
-    table = {c: SERVED[c] for c in subs}
+    # a kind a store of the layout, in its order, then those that keep none
+    table = {c: SERVED[c] for c in SERVED if c in kinds}
+
+    def by_kind(values):  # {kind: its stores' own}, [] where it keeps none
+        return {**dict.fromkeys(table, []), **_by_kind(layout, values)}
+
+    subs, keep = by_kind([s.sub for s in layout]), by_kind(keep)
     if any(kind.f32 for kind in table.values()):  # a selection stays whole
         x = jax.tree.map(lambda a: a.astype(jnp.float32) if jnp.issubdtype(
             a.dtype, jnp.floating) else a, x)
     if cache is not None:  # a layer's rows together: [n_c, sub, Tpad, *row]
         cache = {c: [a if sub == 1 else a.reshape(-1, sub, *a.shape[1:])
                      for a, sub in zip(views, subs[c])]
-                 for c, views in _by_kind(layout, cache).items()}
+                 for c, views in by_kind(cache).items()}
     # a layer's number in its STACK: its number among the layers of the
     # kinds that share the stack (one stack a family, or one a kind)
     stacks = {c: table[c].stack for c in table}
 
     def layer(x, c, which, l, mine):
         kind = table[c]
+        if kind.reads and cache is not None:  # another kind's views, whole
+            mine = cache[kind.reads]
         x, stats, rows = kind.block(
             cfg, x, layers if kind.stack is None else layers[kind.stack],
             which, positions, partial(attends[c], l, mine))
@@ -5022,8 +5688,15 @@ def _serve_layers(cfg: LlamaConfig, x, layers, positions, attends, cache,
 
     out = {c: [] for c in table}
     first, start = dict.fromkeys(stacks.values(), 0), dict.fromkeys(table, 0)
-    for unit, times, scanned in _segments(kinds):
-        x, new = segment(x, unit, times, scanned, first, start)
+    behind, done = stream_cut(cfg), 0
+    for unit, times, scanned in _serve_segments(cfg):
+        if cut is not None and done == behind:
+            x, positions = cut(x, positions)
+        # device scope: what a prefill runs on the ONE position
+        with jax.named_scope("one_position") if (
+                cut is not None and done >= behind) else nullcontext():
+            x, new = segment(x, unit, times, scanned, first, start)
+        done += times * len(unit)
         for c in new:
             out[c].append(new[c])
             start[c] += times * unit.count(c)
@@ -5071,7 +5744,19 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
     written, the pad positions of the last page included (they hold the pad
     token's keys: finite, and masked by every decode until the sequence
     itself overwrites them), and the head is applied to position ``last``
-    alone. ``shares``: a routed model's assignment shares (``{}`` for a
+    alone.
+
+    WHERE THE STREAM IS CUT to position ``last``: behind the last layer that
+    keeps a row (:func:`stream_cut`). In every family but one every layer
+    keeps rows (its later positions attend its earlier ones through them), so
+    all layers run over all positions and the cut stands in front of the
+    head, one row into the final norm. The decoder-hybrid-decoder's layers
+    behind its ONE full attention layer keep nothing (``"g"`` reads the
+    memory at its own position, ``"c"`` the full layer's keys and values):
+    the first token needs them at ``last`` alone, the walker cuts the stream
+    there (:func:`cut_stream`) and they run on ONE position, ``positions =
+    last``, the cross layers attending the rows the full layer made in this
+    same call. ``shares``: a routed model's assignment shares (``{}`` for a
     dense one) and a mixed stream's ``hc_sinkhorn_error``."""
     layout, slot_ids = served_stores(cfg), None
     if any(s.table == "slot" for s in layout):
@@ -5079,26 +5764,31 @@ def prefill_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
     *stores, tokens, page_ids, last = args
     ps = _page_size(stores, layout, page_size)
     ids = {"page": page_ids, "slot": slot_ids}
-    x = selecting_stream(cfg, widen_stream(
-        cfg, embed_tokens(cfg, params, tokens, None), True))
+    x = remembering_stream(cfg, selecting_stream(cfg, widen_stream(
+        cfg, embed_tokens(cfg, params, tokens, None), True)))
     positions = positions_of(*tokens.shape)
     keep = {"page": tokens.shape[1], "slot": 0 if slot_ids is None
             else slot_ids.shape[0] * ps}
     if any(s.table == "state" for s in layout):
         # ONE row a sequence: into the page of the last real position
         ids["state"], keep["state"] = page_ids[last // ps][None], 1
+    # the layers behind the last one that keeps a row run on ONE position
+    early = stream_cut(cfg) < len(served_kinds(cfg))
     x, rows, shares = _serve_layers(
         cfg, x, params["layers"], positions,
         {c: lambda l, mine, *a, c=c: SERVED[c].prefill(cfg, last, *a)
-         for c in {s.kind for s in layout}},
-        None, [keep[s.table] for s in layout])
+         for c in set(served_kinds(cfg))},
+        None, [keep[s.table] for s in layout],
+        (lambda x, positions: (cut_stream(x, last), jnp.full(
+            (tokens.shape[0], 1), last, jnp.int32))) if early else None)
     stores = [_write_pages(pages, new[:, 0], ids[s.table])
               for pages, new, s in zip(stores, rows, layout)]
     # final_norm and the head are per position: one row, not T
-    x = collapse_stream(cfg, jax.tree.map(
+    x = collapse_stream(cfg, stream_of(x) if early else jax.tree.map(
         lambda a: jax.lax.dynamic_slice_in_dim(a, last, 1, axis=1),
         stream_of(x)))
-    logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params))
+    logits = head_logits(cfg, x, params["final_norm"], _head(cfg, params),
+                         params.get("final_norm_b"))
     return (*stores, logits[0, 0], shares)
 
 
@@ -5137,18 +5827,20 @@ def decode_step_with_cache(cfg: LlamaConfig, params, *args, page_size=None):
         ids["state"] = page_ids[jnp.maximum(pos - 1, 0) // ps][None]
     cached = [_read_pages(pages, ids[s.table])
               for pages, s in zip(stores, layout)]
-    x = selecting_stream(cfg, widen_stream(
-        cfg, embed_tokens(cfg, params, token[None, :], None), True), True)
+    x = remembering_stream(cfg, selecting_stream(cfg, widen_stream(
+        cfg, embed_tokens(cfg, params, token[None, :], None), True), True))
     positions = jnp.full((1, 1), pos, dtype=jnp.int32)
     call = SimpleNamespace(
         pos=pos, page_ids=page_ids, stores=_by_kind(layout, stores),
         base=None if slot_ids is None else first * ps)
     x, rows, _ = _serve_layers(
         cfg, x, params["layers"], positions,
-        {c: partial(SERVED[c].decode, cfg, call) for c in call.stores},
+        {c: partial(SERVED[c].decode, cfg, call)
+         for c in set(served_kinds(cfg))},
         cached, [1] * len(layout))
     logits = head_logits(cfg, collapse_stream(cfg, stream_of(x)),
-                         params["final_norm"], _head(cfg, params))
+                         params["final_norm"], _head(cfg, params),
+                         params.get("final_norm_b"))
     page = page_ids[pos // ps]
     at = {"page": (0, page, pos % ps), "state": (0, page, 0)}
     if slot_ids is not None:
@@ -5201,7 +5893,12 @@ _MATMUL_LAYER = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                  "w_in",
                  # the latent block's ungated shared expert (a sublayer's
                  # hc_phi is used in float32, as a router is)
-                 "shared_gate", "shared_up", "shared_down")
+                 "shared_gate", "shared_up", "shared_down",
+                 # the Mamba-1 mixer's two small products, differential
+                 # attention's ONE key-and-value projection and the gated
+                 # memory unit's two (the biases, the lambda vectors, A_log,
+                 # dt_bias, D and the convolution are used in float32)
+                 "w_x", "w_dt", "wkv", "wg_in", "wg_out")
 
 
 def serving_params(cfg: LlamaConfig, params) -> Dict[str, Any]:
@@ -5247,7 +5944,11 @@ class LlamaDecodeEngine:
     ``ray_tpu_serve_engine_stream_bytes``; ``"P"`` with ``"R"``; ``"H"``
     with ``"N"``; ``"Y"`` / ``"Z"`` / ``"X"``, whose stream carries a
     selection from the layers that make one to those that reuse it:
-    ``ray_tpu_serve_engine_selecting_layers{role}``). A kind without a row
+    ``ray_tpu_serve_engine_selecting_layers{role}``; ``"m"`` / ``"w"`` /
+    ``"f"`` / ``"g"`` / ``"c"``, whose stream carries a memory and one
+    layer's keys and values to layers that keep nothing, and whose prefill
+    runs those on one position:
+    ``ray_tpu_serve_engine_prefill_layers{positions}``). A kind without a row
     (the ``"M"`` / ``"E"`` / ``"*"`` halves), a part or a mix of families,
     whole-projection
     QK-norm, the UNPATTERNED routed block and a prediction module
@@ -5353,6 +6054,10 @@ class LlamaDecodeEngine:
             _g_engine_weight_bytes.set(float(nbytes), tags={"dtype": name})
         _g_engine_traced_layers.set(float(traced_layers(self.cfg)))
         stack = served_kinds(self.cfg)
+        cut = stream_cut(self.cfg)
+        for positions, n in (("all", cut), ("one", len(stack) - cut)):
+            _g_engine_prefill_layers.set(float(n),
+                                         tags={"positions": positions})
         for role, letters in (("select", "I" + DSA_FULL), ("reuse", "Z")):
             _g_engine_selecting_layers.set(
                 float(sum(stack.count(c) for c in letters)),

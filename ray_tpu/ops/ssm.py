@@ -1,6 +1,9 @@
 """The Mamba-2 mixer (state-space duality, arXiv:2405.21060), plain
 ``jax.numpy`` / ``lax``: the half of a layer that ``models/llama.py
-pattern_layer`` runs where the layer pattern says ``M``.
+pattern_layer`` runs where the layer pattern says ``M``. (The Mamba-1 mixer,
+the selective scan S6 whose decay is a channel's AND a state's, is ANOTHER
+module, ``ops/s6.py`` with its kernel ``ops/s6_prefill.py``: it shares
+:func:`causal_conv` and :func:`conv_tail` with this one and nothing else.)
 
     [z | xBC | dt] = h W_in                       # widths d_inner | d_inner + 2 G N | H
     xBC = silu(conv1d_causal_depthwise(xBC) + b)  # kernel K, over time

@@ -632,10 +632,11 @@ def test_engine_refuses_a_mix_of_families(params):
         program_cfg(layer_pattern="RRRW" * 2)
     # "F" / "W" still want both of theirs: only these rows say ``alone``
     # (and, since PR 58, the state-space layers' "H"; since PR 63 the
-    # layers under a shared selection, any part whose first layer is full)
+    # layers under a shared selection, any part whose first layer is full;
+    # since PR 65 the decoder-hybrid-decoder's, any part LlamaConfig takes)
     assert llama.SERVED["P"].alone and llama.SERVED["R"].alone
     assert not any(kind.alone for c, kind in llama.SERVED.items()
-                   if c not in "PRH" + llama.DSA_KINDS)
+                   if c not in "PRH" + llama.DSA_KINDS + llama.MEMORY_KINDS)
 
 
 # --- (d) the benchmark's files ---------------------------------------------- #

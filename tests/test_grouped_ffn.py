@@ -284,6 +284,9 @@ def test_a_smallthinker_prefill_takes_the_kernel_and_its_decode_xla(
     import test_smallthinker as st
     from ray_tpu.models import llama
 
+    # a record of the test's own: tests/test_tpu_compile.py traces the same
+    # widths steered, and a worker that ran it first would read its rows here
+    monkeypatch.setattr(llama, "_expert_products_taken", {})
     keys = dict(hidden_size=128, moe_ffn_hidden_size=128, head_dim=32,
                 num_hidden_layers=4)
     cfg = st.program_cfg(**keys)

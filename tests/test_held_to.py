@@ -60,11 +60,20 @@ OLD = {
         _kinds(cfg, "YZX"),
         f"no 'Y' / 'Z' / 'X' layer (layer_pattern={cfg.layer_pattern!r}, "
         f"first_layer={cfg.first_layer}, index_topk={cfg.index_topk})"),
+    # PR 65's row, likewise
+    "_no_memory_kinds": lambda cfg: (
+        _kinds(cfg, "mwfgc"),
+        f"no 'm' / 'w' / 'f' / 'g' / 'c' layer (layer_pattern="
+        f"{cfg.layer_pattern!r}, ssm_expand={cfg.ssm_expand}, "
+        f"window={cfg.window})"),
 }
 
 _NO_WAY_ACROSS_STAGES = (
     "its stages pass the residual stream alone: a selection made on "
     "one stage has no way to the shared layers of the next")
+_NO_MEMORY_ACROSS_STAGES = (
+    "its stages pass the residual stream alone: a memory or keys and "
+    "values made on one stage have no way to the layers of the next")
 
 
 def _engine_dense_only(cfg):
@@ -116,6 +125,12 @@ CALLS = {
          "the layers that read it, masks its flash kernel by one, or is held "
          "to a reference for an indexer's backward; LlamaDecodeEngine "
          "serves these kinds"),
+        ("_no_memory_kinds",
+         "no train step carries a state-space layer's scan output or an "
+         "attention layer's keys and values to the layers that read them, "
+         "has a backward for the Mamba-1 scan (ops/s6_prefill.py is forward "
+         "only) or is held to a reference for differential attention's; "
+         "LlamaDecodeEngine serves these kinds"),
         ("_no_wide_latent",
          "no train step is held to a reference for the mixes' backward or "
          "keeps a stream of several rows' recomputation in its account, and "
@@ -133,6 +148,11 @@ CALLS = {
          "no train step carries a selection from the layer that makes it to "
          "the layers that read it or is held to a reference for an "
          "indexer's backward; LlamaDecodeEngine serves these kinds"),
+        ("_no_memory_kinds",
+         "no train step carries a state-space layer's scan output or an "
+         "attention layer's keys and values to the layers that read them or "
+         "is held to a reference for the Mamba-1 scan's backward; "
+         "LlamaDecodeEngine serves these kinds"),
         ("_no_wide_latent",
          "no train step is held to a reference for the mixes' backward, and "
          "its flash kernel attends q, k and v of one width")],
@@ -150,11 +170,13 @@ CALLS = {
          "its stages run the dense block over the flash kernel, which has "
          "no window, and pass no router's losses on"),
         ("_no_selected_kinds", _NO_WAY_ACROSS_STAGES),
+        ("_no_memory_kinds", _NO_MEMORY_ACROSS_STAGES),
         ("_dense_only",
          "its stages pass the residual stream alone, so a router's losses "
          "have no way out, and its layer specs name the dense leaves only")],
     "the MPMD pipeline": [
         ("_no_selected_kinds", _NO_WAY_ACROSS_STAGES),
+        ("_no_memory_kinds", _NO_MEMORY_ACROSS_STAGES),
         ("_dense_only",
          "its stages pass the residual stream alone, so a router's "
          "losses have no way out, and no test runs QK-norm through it")],
@@ -203,6 +225,9 @@ CONFIGS = {
     "the module alone": lambda: _glm(mtp_layers=1),
     "four rows a token and a wide score (xing4)": _of("test_xing4"),
     "Y Z X (glm-5.2)": _of("test_glm_moe_dsa"),
+    "m w f g c (phi-4-mini-flash)": _of("test_phi4_flash"),
+    "m w alone": lambda: __import__("test_phi4_flash").program_cfg(
+        num_hidden_layers=2),
 }
 
 

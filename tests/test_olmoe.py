@@ -763,7 +763,8 @@ def test_the_engine_is_what_the_table_folds(family, n_pages, page, held,
     # the literal set of tags, in THIS place alone: a new kind adds its own
     assert set(want) == {"kv", "latent", "full", "window", "index", "gated",
                          "latent_block", "parallel_full", "parallel_window",
-                         "hybrid", "dsa_latent", "dsa_index"}
+                         "hybrid", "dsa_latent", "dsa_index",
+                         "memory_window", "memory_full"}
     # these families keep nothing a SEQUENCE (tests/test_qwen3_next.py)
     assert set(_gauge("ray_tpu_serve_engine_state_bytes").values()) == {0.0}
     for part in ("held", "zero", "elsewhere"):

@@ -382,7 +382,8 @@ def test_the_state_goes_by_its_table_rule(params):
     assert engine.n_slots == 0
     assert _gauge("ray_tpu_serve_engine_state_bytes") == {
         "state": 4.0 * 6 * 4 * 8 * 8, "conv": 4.0 * 6 * 3 * 64,
-        "ssm_state": 0.0, "ssm_conv": 0.0}  # every tag of the table, always
+        "ssm_state": 0.0, "ssm_conv": 0.0, "s6_state": 0.0,
+        "s6_conv": 0.0}  # every tag of the table, always
     page_bytes = _gauge("ray_tpu_serve_engine_page_bytes")
     assert page_bytes["gated"] == 2 * 4.0 * 2 * 2 * 16
     assert "state" not in page_bytes and "conv" not in page_bytes
